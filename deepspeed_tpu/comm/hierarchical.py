@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.mesh import DATA_AXIS, shard_map
+from ..parallel.mesh import DATA_AXIS
 from ..utils.cluster import named_scope as ds_named_scope
 from ..runtime.custom_collectives import _signs_collective, padded_size
 from .topology import CommTopology
@@ -331,8 +331,8 @@ def two_level_allreduce(mesh: Mesh, x, topo: CommTopology,
         total = two_level_sum(x_row[0], topo, axis_name)
         return total / dp
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis_name, None),),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis_name, None),),
+                       out_specs=P(), check_vma=False)
     return fn(x)
 
 
@@ -366,8 +366,8 @@ def two_level_compressed_allreduce(mesh: Mesh, x, worker_error, server_error,
             x_row[0], we_row[0], se_row[0], topo, seg_const, n_segs, axis_name)
         return out, new_we[None], new_se[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name, None),) * 3,
-                   out_specs=(P(), P(axis_name, None), P(axis_name, None)),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis_name, None),) * 3,
+                       out_specs=(P(), P(axis_name, None), P(axis_name, None)),
+                       check_vma=False)
     return fn(x, worker_error, server_error)

@@ -39,7 +39,9 @@ class GPT2Config:
     # anatomy roofline flags as HBM-bound). Takes precedence over
     # use_flash_attention when eligible; requires dropout == 0 and no
     # sparse_attention, and falls back to the unfused path under manual TP /
-    # sequence parallelism (the kernel is single-chip, whole-row K/V).
+    # sequence parallelism (the kernel is single-chip, whole-row K/V). Interpreter
+    # only so far: the chip's compiler refuses it at model widths (VMEM, see the
+    # kernel's docstring).
     fused_block: bool = False
     remat: bool = False            # activation checkpointing over blocks
     remat_policy: Any = None       # None=full recompute; "dots"=save matmul outputs
@@ -216,9 +218,8 @@ class GPT2Model:
 
             args = (params, tokens, labels) + (() if rng is None else (rng,))
             in_specs = (P(), tok_spec, tok_spec) + (() if rng is None else (P(),))
-            from ..parallel.mesh import shard_map
-            return shard_map(local, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_vma=False)(*args)
+            return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False)(*args)
 
         return model_fn
 
@@ -449,8 +450,7 @@ class GPT2Model:
             if self.seq_schedule == "zigzag":
                 # zigzag layout: this rank holds global chunks (rank, 2n-1-rank)
                 # of size T/2 — positions follow the interleave
-                from ..parallel.mesh import axis_size
-                n = axis_size(self.seq_axis)
+                n = jax.lax.axis_size(self.seq_axis)
                 assert T % 2 == 0, f"zigzag needs an even local seq, got {T}"
                 C = T // 2
                 pos = jnp.concatenate([rank * C + jnp.arange(C),
@@ -561,9 +561,8 @@ class GPT2Model:
         input -> scan carry -> output instead of double-buffering the caches.
         Without the donation the caller's cache stays live across the call —
         at 1.5B batch-8 decode that is an extra 2x [L, B, nh, max_len, hd]
-        (~5.7 GB) held through the prompt-forward activation peak, which is
-        what pushed the relay-kill repros (tests/perf/decode_crash_repro.py)
-        over the HBM cliff at execution time.
+        (~5.7 GB) held through the prompt-forward activation peak, which put
+        1.5B batch-8 decode over the 16 GB HBM cliff at execution time.
 
         The serving stack applies the same discipline to its paged pools:
         serve/paged.py donates the target KV pool through decode/prefill/
@@ -860,7 +859,7 @@ class GPT2Model:
         per-model program cache, then hands the cached jitted functions back
         with FRESH example arguments — the lint capture only lowers/compiles,
         nothing executes, but the arrays the tiny runs donated are dead. The
-        manifests pin the invariant the relay-kill crashes violated: every
+        manifests pin the invariant an undonated cache violates: every
         declared cache donation must actually alias (check_unusable), no
         cache-sized input may ride un-donated (min_undonated_bytes), and the
         single-host decode programs carry zero large collectives."""

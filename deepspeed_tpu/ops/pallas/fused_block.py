@@ -17,9 +17,11 @@ Design:
   attention's streamed k/v, with the projection fused in front.
 - per-head attention runs over the resident K/V with an fp32 softmax; the
   [block_q, T] score tile lives only in registers/VMEM.
-- the whole block's weights (w_qkv [E, 3E], w_proj [E, E]) are VMEM-resident,
-  which caps the kernel at moderate widths: bf16 GPT-2 base (E=768, T=1024)
-  uses ~10 MB of the ~16 MB scope; past that, keep the unfused path.
+- the whole block's weights (w_qkv [E, 3E], w_proj [E, E]) are VMEM-resident
+  beside the two [T, E] K/V scratch buffers. Compiled for a v5e (PR 21) that is
+  too much: the chip's compiler refuses the kernel with VMEM exhausted at
+  [2,1024,768]/12 heads, [2,1024,1024]/16 heads and [2,1024,1600]/25 heads, so it
+  has run only in the interpreter. ROADMAP C6: re-tile or delete.
 - backward: ``custom_vjp`` whose bwd differentiates the pure-jnp reference
   (``fused_block_reference``) at the saved primals — fused forward, XLA
   backward. Gradients are exactly the reference's; the forward values differ
@@ -38,12 +40,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # importable on CPU too (interpret mode), but guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _MASK_VALUE = -1e9  # matches the model's dense causal mask (python scalar:
 # a jnp constant would be captured by the kernel closure, which pallas rejects)

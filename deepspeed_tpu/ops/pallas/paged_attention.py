@@ -32,12 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # python float: a jnp scalar would be a captured constant
 
@@ -56,34 +51,32 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[:, 0, :].astype(jnp.float32)                 # [nh, hd]
+    q = q_ref[...].astype(jnp.float32)                     # [nh, hd]
     k = k_ref[...].astype(jnp.float32)                     # [BS, nh, hd]
     v = v_ref[...].astype(jnp.float32)
 
-    # scores [nh, BS]: batch over heads, contract head_dim
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) / math.sqrt(head_dim)
+    # One query row per head: the scores are a multiply and a lane reduction on
+    # the page as it lies in the pool, [BS, nh, hd]. (A dot_general batched over
+    # the middle axis has no non-contracting dimension on the query side, which
+    # the TPU lowering refuses.) The page axis stays leading throughout, so the
+    # reductions over it are plain vector adds.
+    scores = jnp.sum(k * q[None], axis=-1, keepdims=True) / math.sqrt(head_dim)  # [BS, nh, 1]
 
     # causal frontier: token index within the whole history
-    idx = b * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)                     # [1, BS]
+    idx = b * block_size + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
     scores = jnp.where(idx < lengths_ref[s], scores, _NEG_INF)
 
     m_prev = m_ref[...]                                    # [nh, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                            # [nh, BS]
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    # pv [nh, hd]: batch over heads, contract the page dimension
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    p = jnp.exp(scores - m_new[None])                      # [BS, nh, 1]
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)   # [nh, hd]
     m_ref[...] = m_new
 
     @pl.when(b == num_pages - 1)
     def _finalize():
-        o_ref[:, 0, :] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -98,15 +91,14 @@ def _paged_decode(q, k_pool, v_pool, tables, lengths, *, li, block_size,
         num_scalar_prefetch=2,                 # tables, lengths steer the DMA
         grid=(S, MB),
         in_specs=[
-            pl.BlockSpec((None, nh, 1, hd), lambda s, b, t, ln: (s, 0, 0, 0)),
+            pl.BlockSpec((None, nh, hd), lambda s, b, t, ln: (s, 0, 0)),
             # the paged gather: page (li, tables[s, b]) of the pool
             pl.BlockSpec((None, None, BS, nh, hd),
                          lambda s, b, t, ln: (li, t[s, b], 0, 0, 0)),
             pl.BlockSpec((None, None, BS, nh, hd),
                          lambda s, b, t, ln: (li, t[s, b], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, nh, 1, hd),
-                               lambda s, b, t, ln: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, nh, hd), lambda s, b, t, ln: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nh, 1), jnp.float32),   # running max
             pltpu.VMEM((nh, 1), jnp.float32),   # running denominator
@@ -114,14 +106,17 @@ def _paged_decode(q, k_pool, v_pool, tables, lengths, *, li, block_size,
         ],
     )
     kernel = functools.partial(_decode_kernel, block_size=BS, head_dim=hd)
-    return pl.pallas_call(
+    # the kernel sees the single query row as [nh, hd]: a block whose last two
+    # dimensions are (1, hd) cannot be stored from a [nh, hd] vector on the TPU
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nh, 1, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, lengths, q, k_pool, v_pool)
+    )(tables, lengths, q.reshape(S, nh, hd), k_pool, v_pool)
+    return out.reshape(S, nh, 1, hd)
 
 
 def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
@@ -133,8 +128,6 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, lengths, *,
     layer; tables [slots, max_blocks] int32 page ids; lengths [slots] valid
     history lengths (pos + 1). Returns [slots, n_head, 1, head_dim] in
     q.dtype. ``interpret`` defaults to True off-TPU."""
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas tpu backend unavailable")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _paged_decode(q, k_pool, v_pool,

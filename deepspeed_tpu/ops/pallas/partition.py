@@ -1,0 +1,51 @@
+"""Pallas kernels under a multi-device jit.
+
+XLA's SPMD partitioner refuses a compiled Pallas kernel ("Mosaic kernels cannot
+be automatically partitioned. Please wrap the call in a shard_map") — which the
+interpreter on a CPU mesh never shows, because an interpreted kernel is plain HLO.
+So a kernel call traced under a mesh whose axes XLA partitions automatically is
+split here instead: attention is independent per batch row and per head, so the
+batch dimension goes over the ``data`` axis and the head dimension over the
+``model`` axis wherever they divide, and every other axis sees replicas. The
+kernel's lowering wants every axis of the mesh manual, those of size one included.
+
+The mesh is the one in context (``jax.sharding.get_abstract_mesh``): the engine
+traces its step programs under its own mesh. With no mesh in context, on one
+device, or inside a ``shard_map`` that already made every axis manual, the call
+is direct.
+"""
+
+import jax
+from jax.sharding import PartitionSpec as P
+
+
+def shard_over_mesh(fn, operands, dims):
+    """``fn(*operands)`` with every operand split over the context mesh.
+
+    ``dims[i]`` says which leading dimensions operand ``i`` has: ``"bh"`` (batch,
+    heads, ...; the first operand is one), ``"b"`` (batch, ...) or ``""``
+    (neither: replicated). ``fn`` returns an array or a tuple of arrays, all
+    ``"bh"``. It is also handed the index of its shard among the batch-and-head
+    shards (0 when the call is direct)."""
+    from ...parallel.mesh import DATA_AXIS, MODEL_AXIS  # parallel/ imports this package
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or all(mesh.shape[a] == 1 for a in mesh.auto_axes):
+        return fn(0, *operands)
+
+    def axis_for(name, dim):
+        ok = name in mesh.auto_axes and mesh.shape[name] > 1 and dim % mesh.shape[name] == 0
+        return name if ok else None
+
+    batch, heads = operands[0].shape[:2]
+    b_axis, h_axis = axis_for(DATA_AXIS, batch), axis_for(MODEL_AXIS, heads)
+    spec = {"bh": P(b_axis, h_axis), "b": P(b_axis), "": P()}
+
+    def body(*local):
+        index = 0
+        for axis in (b_axis, h_axis):
+            if axis is not None:
+                index = index * mesh.shape[axis] + jax.lax.axis_index(axis)
+        return fn(index, *local)
+
+    return jax.shard_map(body, in_specs=tuple(spec[d] for d in dims), out_specs=spec["bh"],
+                         axis_names=frozenset(mesh.auto_axes), check_vma=False)(*operands)

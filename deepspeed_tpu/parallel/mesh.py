@@ -22,33 +22,6 @@ MODEL_AXIS = "model"
 PIPE_AXIS = "pipe"
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: the top-level API when this jax has it,
-    else the ``jax.experimental`` spelling (where ``check_vma`` was ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def set_mesh(mesh: Mesh):
-    """``jax.set_mesh`` across jax versions: on older jax the ``Mesh`` object is
-    itself the context manager that installs the global resource env."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` across jax versions — older ones use the psum-of-one
-    idiom, which constant-folds to the same static size under tracing."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def build_mesh(data: Optional[int] = None,
                model: int = 1,
                pipe: int = 1,

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import MODEL_AXIS, axis_size, shard_map
+from .mesh import MODEL_AXIS
 
 
 class MoELayer:
@@ -255,7 +255,7 @@ class MoELayer:
             return y.reshape(orig_shape), aux
 
         axis = self.expert_axis
-        ep = axis_size(axis)
+        ep = jax.lax.axis_size(axis)
         assert E % ep == 0, \
             f"num_experts {E} must be divisible by the expert-parallel degree {ep}"
         e_local = E // ep
@@ -315,6 +315,6 @@ def moe_apply_sharded(layer: MoELayer, mesh: Mesh, params, x,
             aux = jax.lax.pmean(aux, tokens_axis)
         return y, aux
 
-    fn = shard_map(local, mesh=mesh, in_specs=(pspecs, x_spec),
-                   out_specs=(x_spec, P()), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pspecs, x_spec),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(jax.device_put(params, shardings), x)

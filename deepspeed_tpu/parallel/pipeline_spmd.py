@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import DATA_AXIS, PIPE_AXIS, axis_size, shard_map
+from .mesh import DATA_AXIS, PIPE_AXIS
 
 
 def stack_stage_params(per_stage_params):
@@ -215,9 +215,9 @@ def _streamed_apply(stage_fn, stacked_params, x_microbatches, cap, *, mesh,
         loss = jax.lax.psum(jnp.where(is_last, loss_acc, 0.0), PIPE_AXIS) / M
         return jax.lax.pmean(loss, DATA_AXIS)
 
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(stacked_spec, x_spec, last_spec, first_spec),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(stacked_spec, x_spec, last_spec, first_spec),
+                       out_specs=P(), check_vma=False)
     return fn(stacked_params, x_microbatches, last_stage_args, first_stage_args)
 
 
@@ -382,7 +382,7 @@ def pipeline_apply(stage_fn: Callable,
                 last_stage_collective=last_stage_collective)
 
     def inner(stacked_local, x_mb, last_args, first_args):
-        S = axis_size(PIPE_AXIS)
+        S = jax.lax.axis_size(PIPE_AXIS)
         s = jax.lax.axis_index(PIPE_AXIS)
         is_first = s == 0
         is_last = s == S - 1
@@ -473,8 +473,8 @@ def pipeline_apply(stage_fn: Callable,
         last_stage_args_specs, first_stage_args_specs, stacked_param_specs, M)
     out_spec = P() if last_stage_fn is not None else x_spec
 
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(stacked_spec, x_spec, last_spec, first_spec),
-                   out_specs=out_spec,
-                   check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(stacked_spec, x_spec, last_spec, first_spec),
+                       out_specs=out_spec,
+                       check_vma=False)
     return fn(stacked_params, x_microbatches, last_stage_args, first_stage_args)

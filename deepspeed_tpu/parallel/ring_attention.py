@@ -54,7 +54,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.pallas.flash_attention import _merge_partial, flash_attention_with_lse
-from .mesh import DATA_AXIS, axis_size, shard_map
+from .mesh import DATA_AXIS
 
 SCHEDULES = ("zigzag", "masked")
 
@@ -141,7 +141,7 @@ def ring_work_schedule(n: int, schedule: str = "zigzag"):
 # ------------------------------------------------------------------------- schedules
 def _masked_ring(q, k, v, axis_name, causal, sm_scale, interpret, rate, seed):
     """Contiguous-layout ring: rank r holds positions [r*T_local, (r+1)*T_local)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     T_local = q.shape[2]
     # chunks step to the NEXT rank each rotation: after r steps rank i holds the
@@ -181,7 +181,7 @@ def _zigzag_ring(q, k, v, axis_name, sm_scale, interpret, rate, seed):
     """Zigzag-layout causal ring: rank i holds global chunks (i, 2n-1-i), each of
     size C = T_local/2. See the module docstring for the schedule; the masked
     schedule above is the oracle it must match after ``zigzag_unshard``."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     T_local = q.shape[2]
     assert T_local % 2 == 0, f"zigzag needs an even local seq, got {T_local}"
@@ -305,7 +305,7 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, seq_axis: str = DATA_AXIS,
     sharding = NamedSharding(mesh, spec)
     q, k, v = (x if getattr(x, "sharding", None) == sharding else
                jax.device_put(x, sharding) for x in (q, k, v))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal,
                           sm_scale=sm_scale, interpret=interpret,
                           dropout_rate=dropout_rate, dropout_seed=dropout_seed,

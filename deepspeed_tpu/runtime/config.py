@@ -159,8 +159,6 @@ class DeepSpeedConfig:
                                  f"{allowed} (got {self.communication_data_type!r})")
         self.prescale_gradients = get_scalar_param(param_dict, PRESCALE_GRADIENTS, PRESCALE_GRADIENTS_DEFAULT)
         self.fused_step = get_scalar_param(param_dict, FUSED_STEP, FUSED_STEP_DEFAULT)
-        self.compilation_cache_dir = get_scalar_param(param_dict, COMPILATION_CACHE_DIR,
-                                                      COMPILATION_CACHE_DIR_DEFAULT)
         self.gradient_predivide_factor = get_scalar_param(param_dict, GRADIENT_PREDIVIDE_FACTOR,
                                                           GRADIENT_PREDIVIDE_FACTOR_DEFAULT)
         self.sparse_gradients_enabled = get_scalar_param(param_dict, SPARSE_GRADIENTS, SPARSE_GRADIENTS_DEFAULT)

@@ -34,7 +34,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.mesh import DATA_AXIS, shard_map
+from ..parallel.mesh import DATA_AXIS
 
 def _pack_signs(signs):
     """(..., m) int8 in {-1, +1} -> (..., m/8) uint8, 8 signs per byte (set bit
@@ -132,10 +132,10 @@ def compressed_allreduce(mesh: Mesh, x, worker_error, server_error,
         out = (all_signs.astype(jnp.float32) * per_elem_sscale).reshape(n)
         return out, new_we[None], new_se[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis_name, None), P(axis_name, None), P(axis_name, None)),
-                   out_specs=(P(), P(axis_name, None), P(axis_name, None)),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis_name, None), P(axis_name, None), P(axis_name, None)),
+                       out_specs=(P(), P(axis_name, None), P(axis_name, None)),
+                       check_vma=False)
     return fn(x, worker_error, server_error)
 
 

@@ -97,8 +97,7 @@ def row_sparse_allreduce(dense_local: jnp.ndarray, axis_name: str, capacity: int
                             st.dense_shape)
     dense = gathered.to_dense()
     if mean:
-        from ..parallel.mesh import axis_size
-        dense = dense / axis_size(axis_name)
+        dense = dense / jax.lax.axis_size(axis_name)
     return dense.astype(dense_local.dtype)
 
 

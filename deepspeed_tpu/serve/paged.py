@@ -19,8 +19,8 @@ exact zeros), identical reduction orders. tests/unit/test_paged_attention.py
 pins this against ``_build_cached_forward`` directly; serve/oracle.py carries
 the per-slot-position dense mirror for mixed traces.
 
-All cache/pool arguments are donated (the lesson of the relay-kill crashes,
-models/gpt2.py): XLA aliases one pool buffer through every program, so serving
+All cache/pool arguments are donated (the lesson of the dense decode path,
+models/gpt2.py ``_cached_jit``): XLA aliases one pool buffer through every program, so serving
 HBM is params + pool + activations — never 2x pool.
 
 **Model-axis sharding** (``mesh=`` a Mesh carrying a ``model`` axis of size
@@ -362,7 +362,7 @@ def _build_paged_programs(model, *, num_slots, block_size, max_blocks,
     # ------------------------------------------------- model-axis sharding
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
-    from ..parallel.mesh import MODEL_AXIS, shard_map
+    from ..parallel.mesh import MODEL_AXIS
 
     tp = mesh.shape[MODEL_AXIS]
     if nh % tp:
@@ -434,9 +434,9 @@ def _build_paged_programs(model, *, num_slots, block_size, max_blocks,
             x = _blocks_forward(p, x, attn)
             return _logits(x[:, -1], p), pools["k"], pools["v"]
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(REP, REP, REP, REP, REP, POOL, POOL),
-                         out_specs=(REP, POOL, POOL))(
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(REP, REP, REP, REP, REP, POOL, POOL),
+                             out_specs=(REP, POOL, POOL), check_vma=False)(
             p, toks, pos, tables, active, k_pool, v_pool)
 
     def sharded_prefill_chunk(p, toks, pos, n_valid, table, k_pool, v_pool):
@@ -470,9 +470,9 @@ def _build_paged_programs(model, *, num_slots, block_size, max_blocks,
                                          (1, 1, x.shape[-1]))[:, 0]
             return _logits(last, p), pools["k"], pools["v"]
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(REP, REP, REP, REP, REP, POOL, POOL),
-                         out_specs=(REP, POOL, POOL))(
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(REP, REP, REP, REP, REP, POOL, POOL),
+                             out_specs=(REP, POOL, POOL), check_vma=False)(
             p, toks, pos, n_valid, table, k_pool, v_pool)
 
     # copy_blocks scatters along the (unsharded) block axis only — GSPMD

@@ -72,17 +72,21 @@ _KIND_PATTERNS = (("v6", "tpu-v6e"), ("v5p", "tpu-v5p"), ("v5 lite", "tpu-v5e"),
 
 
 def detect_chip() -> str:
-    """Spec-table key for the local accelerator (``cpu-test`` for anything
-    the table doesn't know, including the CPU backend)."""
-    try:
-        import jax
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:
+    """Spec-table key for the local device: ``cpu-test`` on the CPU backend,
+    the matching table entry on an accelerator. An accelerator whose
+    ``device_kind`` matches no pattern raises — a device that is not in the
+    table is an error, not a default."""
+    import jax
+    device = jax.local_devices()[0]
+    if device.platform == "cpu":
         return "cpu-test"
+    kind = device.device_kind.lower()
     for pattern, name in _KIND_PATTERNS:
         if pattern in kind:
             return name
-    return "cpu-test"
+    raise ValueError(f"no chip spec for {device.platform} device_kind "
+                     f"{device.device_kind!r}; add it to CHIP_SPECS and "
+                     "_KIND_PATTERNS (utils/roofline.py)")
 
 
 def resolve_spec(chip: str = "", peak_tflops: float = 0.0,

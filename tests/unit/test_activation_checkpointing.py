@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.parallel.mesh import MODEL_AXIS, build_mesh, set_mesh
+from deepspeed_tpu.parallel.mesh import MODEL_AXIS, build_mesh
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ckpt
 
 
@@ -70,7 +70,7 @@ def test_partition_activations_grad_parity(xw):
         build_mesh(data=1, model=len(jax.devices()), pipe=1)
     ckpt.configure(partition_activations=True, mesh=mesh)
     ref = _grads(_block, x, w)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = _grads(ckpt.checkpoint_wrapper(_block), x, w)
     for r, g in zip(ref, got):
         # sharded matmul reduction order shifts the last few ulps
@@ -125,7 +125,6 @@ def test_model_parallel_manual_seed_parity_api():
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs multi-device mesh")
 def test_model_parallel_seed_differs_per_rank():
-    from deepspeed_tpu.parallel.mesh import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = build_mesh(data=1, model=len(jax.devices()), pipe=1)
 
@@ -133,9 +132,9 @@ def test_model_parallel_seed_differs_per_rank():
         key = ckpt.model_parallel_seed(7, axis=MODEL_AXIS)
         return jax.random.uniform(key, (1,))
 
-    with set_mesh(mesh):
-        out = jax.jit(shard_map(f, mesh=mesh, in_specs=(), out_specs=P(MODEL_AXIS),
-                                check_vma=False))()
+    with jax.set_mesh(mesh):
+        out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(), out_specs=P(MODEL_AXIS),
+                                    check_vma=False))()
     vals = np.asarray(out)
     assert len(np.unique(vals)) == len(vals), "per-rank dropout keys must differ"
 

@@ -53,6 +53,27 @@ def test_resolve_spec_table_and_overrides():
         resolve_spec("tpu-v9000")
 
 
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", "cpu-test"),
+    ("tpu", "TPU v5 lite", "tpu-v5e"),       # the string a v5e chip reports (PR 21)
+    ("tpu", "TPU v6 lite", "tpu-v6e"),
+    ("tpu", "TPU v9000", None),
+    ("gpu", "NVIDIA H100", None),
+])
+def test_detect_chip_knows_the_device_or_raises(monkeypatch, platform, kind, expect):
+    """``cpu-test`` is for the CPU alone: an accelerator the table does not know
+    is an error, not a default that prices a roofline off made-up rates."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.utils.roofline import detect_chip
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [SimpleNamespace(platform=platform, device_kind=kind)])
+    if expect is None:
+        with pytest.raises(ValueError, match="no chip spec"):
+            detect_chip()
+    else:
+        assert detect_chip() == expect
+
+
 def test_roofline_floor_and_ceiling_arithmetic():
     spec = ChipSpec("t", peak_tflops=1.0, hbm_gbps=1.0, ici_gbps=1.0,
                     dcn_gbps=1.0)

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from oldjax import grad_through_shard_map_xfail
 from simple_model import SimpleModel, random_dataset, simple_config
 
 HIDDEN = 16
@@ -219,7 +218,6 @@ def test_checkpoint_elastic_zero3(tmp_path, eight_devices):
             assert leaf.addressable_shards[0].data.size * 4 == leaf.size
 
 
-@grad_through_shard_map_xfail
 def test_checkpoint_pipe_topology_change(tmp_path):
     """Pipeline checkpoints are layer-keyed, so stage boundaries can move between
     save and load (reference pipe/module.py:536-567, test_checkpointing.py:617+)."""

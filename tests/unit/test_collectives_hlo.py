@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oldjax import grad_through_shard_map_xfail
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import DATA_AXIS, build_mesh
@@ -90,7 +89,6 @@ def test_ring_attention_emits_collective_permute():
 
 
 # --------------------------------------------------------------------------- pipeline
-@grad_through_shard_map_xfail
 def test_public_api_pipeline_train_step_emits_collective_permute():
     """deepspeed.initialize(model=PipelineModule) routes homogeneous stages onto the
     SPMD executor: the jitted train step must move activations over the pipe axis

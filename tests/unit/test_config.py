@@ -132,29 +132,6 @@ def test_sparse_attention_block():
     assert cfg.sparse_attention.num_local_blocks == 4
 
 
-def test_compilation_cache_dir_config(tmp_path):
-    """compilation_cache_dir flows from JSON to jax.config at engine construction."""
-    import jax
-    import deepspeed_tpu
-    from simple_model import SimpleModel, simple_config
-
-    cache = str(tmp_path / "xla_cache")
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        model = SimpleModel(16)
-        engine, _, _, _ = deepspeed_tpu.initialize(
-            model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
-            config_params=simple_config(compilation_cache_dir=cache))
-        assert engine.config.compilation_cache_dir == cache
-        assert jax.config.jax_compilation_cache_dir == cache
-    finally:
-        # process-global jax config: restore so later tests don't inherit a
-        # cache pointed at this test's (soon-deleted) tmp dir
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
-
-
 def _capture_warnings(monkeypatch):
     from deepspeed_tpu.utils import logger
     msgs = []

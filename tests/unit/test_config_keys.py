@@ -76,7 +76,6 @@ SWEEP = {
     ),
     "prescale_gradients": (True, ("attr", "prescale_gradients", True)),
     "fused_step": (True, ("attr", "fused_step", True)),
-    "compilation_cache_dir": ("/tmp/xla-cache", ("attr", "compilation_cache_dir", "/tmp/xla-cache")),
     "gradient_predivide_factor": (2.0, ("attr", "gradient_predivide_factor", 2.0)),
     "disable_allgather": (True, ("warn", "no effect")),
     "allreduce_always_fp32": (True, ("attr", "allreduce_always_fp32", True)),

@@ -249,3 +249,36 @@ def test_long_context_dispatch_raises_when_chunk_ineligible(monkeypatch):
     t = jnp.zeros((1, 1, 8704, D), jnp.bfloat16)
     with pytest.raises(ValueError, match="divisor"):
         flash_attention(t, t, t)
+
+
+def test_kernel_splits_itself_over_the_context_mesh(eight_devices):
+    """Under a jit whose mesh is in context (the engine's), the kernel runs per
+    batch-and-head shard (ops/pallas/partition.py): same values and gradients as
+    one device, bias and all, and no collective — XLA cannot partition a compiled
+    Pallas kernel, so nothing may be left for it to partition."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.utils.hlo import collective_counts
+
+    mesh = build_mesh(data=4, model=2, pipe=1)
+    shape = (4, 2, 128, 32)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32)
+               for kk in jax.random.split(jax.random.PRNGKey(0), 3))
+    bias = jnp.zeros((4, 1, 128), jnp.float32).at[1, :, -40:].set(-1e9)
+
+    def loss(q, k, v, bias):
+        return jnp.sum(flash_attention(q, k, v, True, bias=bias, interpret=True) ** 2)
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    want = step(q, k, v, bias)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
+    args = (*(put(x, P("data", "model")) for x in (q, k, v)), put(bias, P("data")))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        got = step(*args)
+        text = step.lower(*args).compile().as_text()
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                                rtol=2e-5, atol=2e-4), got, want)
+    # the scalar loss is summed across shards; the kernel's tensors never move
+    assert set(collective_counts(text)) <= {"all-reduce"}
+    assert got[1][0].sharding.is_equivalent_to(args[0].sharding, 4)

@@ -8,7 +8,6 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.parallel.pipe import LayerSpec, TiedLayerSpec, PipelineModule
 from deepspeed_tpu.runtime.pipe.engine import PipelineEngine, PipelineError
-from oldjax import grad_through_shard_map_xfail
 
 HIDDEN = 8
 
@@ -68,11 +67,7 @@ def data_iter(hidden=HIDDEN, batch=4, seed=0):
         yield x, np.tanh(x @ w_true)
 
 
-@pytest.mark.parametrize("num_stages", [
-    1,
-    pytest.param(2, marks=grad_through_shard_map_xfail),
-    pytest.param(4, marks=grad_through_shard_map_xfail),
-])
+@pytest.mark.parametrize("num_stages", [1, 2, 4])
 def test_pipe_training_loss_decreases(num_stages):
     module, params = make_pipe(num_layers=4, num_stages=num_stages)
     engine, _, _, _ = deepspeed_tpu.initialize(model=module, model_parameters=params,
@@ -83,7 +78,6 @@ def test_pipe_training_loss_decreases(num_stages):
     assert losses[-1] < losses[0] * 0.8, f"{losses[0]} -> {losses[-1]}"
 
 
-@grad_through_shard_map_xfail
 def test_pipe_matches_sequential():
     """The same layers trained with 2 pipeline stages (SPMD executor) vs 1 stage give
     identical weights at fp32 — compared in the canonical layer-keyed representation.
@@ -109,7 +103,6 @@ def test_pipe_matches_sequential():
                                    err_msg=f"mismatch in {k}")
 
 
-@grad_through_shard_map_xfail
 def test_spmd_loss_matches_instruction_executor_fp32():
     """VERDICT r3 #1 acceptance: under the SAME public API and config, the SPMD
     executor's per-step losses equal the instruction executor's at fp32."""
@@ -129,7 +122,6 @@ def test_spmd_loss_matches_instruction_executor_fp32():
                                err_msg=f"{losses}")
 
 
-@grad_through_shard_map_xfail
 def test_pipe_tied_weights():
     module, params = make_pipe(num_layers=4, num_stages=2, tied=True)
     engine, _, _, _ = deepspeed_tpu.initialize(model=module, model_parameters=params,
@@ -170,7 +162,6 @@ def test_partition_balanced_by_parameters():
     assert module.parts == [0, 2, 4]
 
 
-@grad_through_shard_map_xfail
 def test_pipe_deep_schedule_many_microbatches():
     """4 stages x 8 micro-batches: stages have UNEQUAL buffer ring sizes, exercising the
     micro-batch-keyed channels (regression: receiver-local buffer ids don't align)."""
@@ -189,7 +180,6 @@ def test_pipe_deep_schedule_many_microbatches():
     assert losses[-1] < losses[0]
 
 
-@grad_through_shard_map_xfail
 def test_pipe_activation_checkpoint_interval():
     """activation_checkpoint_interval remats chunks of stage layers and must be a
     pure memory/compute tradeoff — identical training results."""
@@ -228,7 +218,6 @@ def test_pipe_eval_batch_inference_schedule_parity():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
-@grad_through_shard_map_xfail
 def test_pipe_fp16_loss_scale_parity():
     """fp16 pipeline grads are loss-scaled in the stage backward and unscaled in the
     update: the first-step weights must match an fp32 run to fp16 resolution."""
@@ -251,7 +240,6 @@ def test_pipe_fp16_loss_scale_parity():
         results["fp16"][1], results["fp32"][1])
 
 
-@grad_through_shard_map_xfail
 def test_pipe_fp16_overflow_skips_step():
     module, params = make_pipe(num_layers=4, num_stages=2)
     cfg = pipe_config()
@@ -308,7 +296,6 @@ def test_instruction_path_buffer_bound_m_much_greater_than_s():
     assert np.isfinite(losses).all()
 
 
-@grad_through_shard_map_xfail
 def test_spmd_pipe_composes_with_zero2():
     """Public-API pipeline + ZeRO-2: merge_zero_into claims a free data-divisible
     axis on the pipe-stacked master/optimizer state, so 2-D (pipe x data) state

@@ -11,10 +11,6 @@ from deepspeed_tpu.parallel.pipeline_spmd import (pipeline_apply, stack_stage_pa
 
 S, M, B, H = 2, 4, 8, 16
 
-# Forward-only pipeline paths work on every jax; grad-through-pipeline needs
-# the top-level jax.shard_map (see tests/unit/oldjax.py).
-from oldjax import grad_through_shard_map_xfail as grad_through_pipeline_xfail
-
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -60,7 +56,6 @@ def test_pipeline_forward_matches_sequential(mesh, toy):
     np.testing.assert_allclose(np.asarray(outs), np.asarray(ref), rtol=1e-5, atol=1e-6)
 
 
-@grad_through_pipeline_xfail
 def test_pipeline_loss_and_grads_match_sequential(mesh, toy):
     stacked, x_mb, labels_mb = toy
 
@@ -117,7 +112,6 @@ def test_stacked_params_actually_pipe_sharded(mesh, toy):
     assert not sh.is_fully_replicated
 
 
-@grad_through_pipeline_xfail
 def test_gpt2_pipe_trains(mesh):
     """Full 3D slice: GPT2Pipe (pipe=2 stages x data=4 DP x ZeRO-2) through the engine."""
     from deepspeed_tpu.models.gpt2 import GPT2Config
@@ -252,7 +246,6 @@ def test_gpt2_pipe_to_dense_roundtrip(tp):
     assert restacked["io"]["wte"].shape[0] == 132
 
 
-@grad_through_pipeline_xfail
 @pytest.mark.parametrize("streamed", [True, False])
 def test_auto_flush_split_matches_single_flush(mesh, streamed):
     """M = 8S must auto-split into rematerialized segments (VERDICT r2 next #5) with

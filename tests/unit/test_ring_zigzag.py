@@ -31,7 +31,7 @@ from deepspeed_tpu.ops.pallas.flash_attention import (DEFAULT_MASK_VALUE,
                                                       dense_attention,
                                                       dropout_keep_reference,
                                                       flash_attention_with_lse)
-from deepspeed_tpu.parallel.mesh import build_mesh, shard_map
+from deepspeed_tpu.parallel.mesh import build_mesh
 from deepspeed_tpu.parallel.ring_attention import (ring_attention,
                                                    ring_attention_sharded,
                                                    ring_work_schedule,
@@ -236,7 +236,7 @@ def test_zigzag_dropout_matches_global_oracle(mesh):
 # ------------------------------------------------------------------ collectives
 def _local_ring_fn(mesh, schedule):
     spec = P(None, None, "data", None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(ring_attention, axis_name="data", causal=True,
                           interpret=True, schedule=schedule),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)

@@ -8,7 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu
-from deepspeed_tpu.parallel.mesh import DATA_AXIS, build_mesh, set_mesh, shard_map
+from deepspeed_tpu.parallel.mesh import DATA_AXIS, build_mesh
 from deepspeed_tpu.runtime.sparse_tensor import (SparseTensor, match_sparse_paths,
                                                  row_sparse_allreduce)
 
@@ -84,9 +84,9 @@ def test_row_sparse_allreduce_matches_pmean():
     def local(x):
         return row_sparse_allreduce(x[0], DATA_AXIS, capacity=k)
 
-    with set_mesh(mesh):
-        out = jax.jit(shard_map(local, mesh=mesh, in_specs=P(DATA_AXIS),
-                                out_specs=P(), check_vma=False))(stacked)
+    with jax.set_mesh(mesh):
+        out = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(DATA_AXIS),
+                                    out_specs=P(), check_vma=False))(stacked)
     expected = np.mean(np.stack(per_shard), axis=0)
     np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-6)
 
@@ -142,11 +142,8 @@ def test_engine_sparse_gradients_parity(zero_stage):
 
     # dense path differentiates over the global batch, sparse path over local shards
     # + pmean — same math, different fp32 reduction order, so allow ~1e-4 drift.
-    # jax.experimental.shard_map (pre-0.5) lowers the pmean with a different
-    # reduction tree and 3 Adam steps amplify the ulps to a few e-4.
-    atol = 1e-4 if hasattr(jax, "shard_map") else 5e-4
     jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=atol),
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4),
         results[False], results[True])
 
 

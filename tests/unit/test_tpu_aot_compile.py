@@ -1,0 +1,114 @@
+"""The chip's compiler, without the chip: every Pallas kernel of the main paths is
+compiled at real widths for a described ``v5e:2x2`` device.
+
+Interpret mode (the rest of the suite) cannot see what the TPU compiler refuses:
+a slice off the tiling, too much VMEM, an API the installed JAX has dropped. Each
+case passes ``interpret=False`` explicitly (``jax.default_backend()`` is the CPU
+here) and asserts the compiled program holds a ``tpu_custom_call``. Nothing runs,
+so these say nothing about results or times; ``chip_smoke.py`` does that on the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas.block_sparse_attention import block_sparse_attention
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+from deepspeed_tpu.ops.sparse_attention import BSLongformerSparsityConfig
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # tests/conftest.py has the persistent compile cache off: a compile for a
+    # described device can be written to it but not read back without a chip
+    assert not jax.config.jax_enable_compilation_cache
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def sumsq_grad(attn):
+    return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2))
+
+
+FLASH_SHAPES = [(3, 25, 1024, 64),    # GPT-2 XL heads, chip_smoke phase A
+                (8, 16, 1024, 64),    # GPT-2 medium heads
+                (1, 16, 8192, 64)]    # long sequence
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_flash_attention_compiles_for_v5e(chip, shape, backward):
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, interpret=False)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn) if backward else attn, x, x, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+def test_block_sparse_attention_compiles_for_v5e(chip, backward):
+    heads, seq, block = 16, 8192, 128
+    layout = np.asarray(BSLongformerSparsityConfig(
+        num_heads=heads, block=block, num_sliding_window_blocks=9,
+        global_block_indices=[0]).make_layout(seq))
+
+    def attn(q, k, v):
+        return block_sparse_attention(q, k, v, layout, block, causal=True, interpret=False)
+
+    x = jax.ShapeDtypeStruct((1, heads, seq, 64), jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn) if backward else attn, x, x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_attention_compiles_for_v5e(chip):
+    slots, heads, head_dim, block_size, max_blocks = 8, 16, 64, 16, 64
+    pool = jax.ShapeDtypeStruct((24, 256, block_size, heads, head_dim), jnp.bfloat16,
+                                sharding=chip)
+    q = jax.ShapeDtypeStruct((slots, heads, 1, head_dim), jnp.bfloat16, sharding=chip)
+    tables = jax.ShapeDtypeStruct((slots, max_blocks), jnp.int32, sharding=chip)
+    lengths = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+
+    def decode(q, k_pool, v_pool, tables, lengths):
+        return paged_decode_attention(q, k_pool, v_pool, 3, tables, lengths,
+                                      block_size=block_size, interpret=False)
+
+    assert "tpu_custom_call" in compiled_text(decode, q, pool, pool, tables, lengths)
+
+
+def test_flash_attention_compiles_under_a_four_chip_mesh(topo):
+    """The data-parallel path: XLA refuses to partition a compiled Pallas kernel, so
+    under a mesh the kernel splits itself (ops/pallas/partition.py). Compiled for all
+    four chips of the described host with the batch sharded over ``data``."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1), ("pipe", "data", "model"))
+    x = jax.ShapeDtypeStruct((4, 25, 1024, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, interpret=False)
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = compiled_text(sumsq_grad(attn), x, x, x)
+    assert "tpu_custom_call" in text
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        compiled_text(sumsq_grad(attn), x, x, x)   # no mesh in context: XLA is asked

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from oldjax import grad_through_shard_map_xfail
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import DATA_AXIS, build_mesh
@@ -120,7 +119,6 @@ def test_zero3_composes_with_offload():
             assert not leaf.sharding.is_fully_replicated, name
 
 
-@grad_through_shard_map_xfail
 def test_zero3_composes_with_spmd_pipeline():
     """Public-API PipelineModule + stage 3: ZeRO claims a free data-divisible axis
     ON TOP of the pipe-stacked stage layout for the compute params too (true
