@@ -411,32 +411,37 @@ class GPT2Model:
         k_attn = k_res1 = k_res2 = None
         if rng is not None and c.dropout > 0:
             k_attn, k_res1, k_res2 = jax.random.split(rng, 3)
-        if (c.fused_block and self.tp_axis is None and self.seq_axis is None
-                and k_attn is None):
-            # whole attention half (LN + qkv + attention + proj + residual) in
-            # one Pallas kernel; the parallel model copies fall through to the
-            # unfused path (the kernel needs the full row on one chip)
-            from ..ops.pallas.fused_block import fused_transformer_block
-            ap = bp["attn"]
-            x = fused_transformer_block(
-                x, bp["ln_1"]["scale"], bp["ln_1"]["bias"],
-                ap["c_attn_w"], ap["c_attn_b"], ap["c_proj_w"], ap["c_proj_b"],
-                c.n_head, causal=True, eps=c.layer_norm_epsilon)
-        else:
-            a = self._attention(
-                self._layer_norm(x, bp["ln_1"], c.layer_norm_epsilon),
-                bp["attn"], dropout_rng=k_attn)
-            if k_res1 is not None:
-                a = self._dropout(a, k_res1)
-            x = x + a
-        h = self._layer_norm(x, bp["ln_2"], c.layer_norm_epsilon)
-        if "moe" in bp:
-            m, aux = self._moe.apply(bp["moe"], h)
-        else:
-            m, aux = self._mlp(h, bp["mlp"]), jnp.zeros((), jnp.float32)
-        if k_res2 is not None:
-            m = self._dropout(m, k_res2)
-        return x + m, aux
+        # ds_attn, ds_mlp (and ds_embed, ds_loss below) are metadata on the compiled
+        # program, no instruction: the device table reads a step's parts from them.
+        # A layer norm falls under the part it feeds.
+        with jax.named_scope("ds_attn"):
+            if (c.fused_block and self.tp_axis is None and self.seq_axis is None
+                    and k_attn is None):
+                # whole attention half (LN + qkv + attention + proj + residual) in
+                # one Pallas kernel; the parallel model copies fall through to the
+                # unfused path (the kernel needs the full row on one chip)
+                from ..ops.pallas.fused_block import fused_transformer_block
+                ap = bp["attn"]
+                x = fused_transformer_block(
+                    x, bp["ln_1"]["scale"], bp["ln_1"]["bias"],
+                    ap["c_attn_w"], ap["c_attn_b"], ap["c_proj_w"], ap["c_proj_b"],
+                    c.n_head, causal=True, eps=c.layer_norm_epsilon)
+            else:
+                a = self._attention(
+                    self._layer_norm(x, bp["ln_1"], c.layer_norm_epsilon),
+                    bp["attn"], dropout_rng=k_attn)
+                if k_res1 is not None:
+                    a = self._dropout(a, k_res1)
+                x = x + a
+        with jax.named_scope("ds_mlp"):
+            h = self._layer_norm(x, bp["ln_2"], c.layer_norm_epsilon)
+            if "moe" in bp:
+                m, aux = self._moe.apply(bp["moe"], h)
+            else:
+                m, aux = self._mlp(h, bp["mlp"]), jnp.zeros((), jnp.float32)
+            if k_res2 is not None:
+                m = self._dropout(m, k_res2)
+            return x + m, aux
 
     # ------------------------------------------------------------- apply
     def _backbone(self, params, tokens, rng=None):
@@ -458,11 +463,13 @@ class GPT2Model:
             else:
                 # contiguous: this rank holds global positions [r*T, (r+1)*T)
                 pos = pos + rank * T
-        x = params["wte"][tokens].astype(c.compute_dtype) + params["wpe"][pos].astype(c.compute_dtype)
         use_dropout = rng is not None and c.dropout > 0
-        if use_dropout:
-            rng, k_embd = jax.random.split(rng)
-            x = self._dropout(x, k_embd)
+        with jax.named_scope("ds_embed"):
+            x = (params["wte"][tokens].astype(c.compute_dtype)
+                 + params["wpe"][pos].astype(c.compute_dtype))
+            if use_dropout:
+                rng, k_embd = jax.random.split(rng)
+                x = self._dropout(x, k_embd)
 
         block_fn = self._block
         if c.remat:
@@ -477,14 +484,16 @@ class GPT2Model:
             else:
                 x, aux = block_fn(x, bp)
             aux_total = aux_total + aux
-        return self._layer_norm(x, params["ln_f"], c.layer_norm_epsilon), aux_total
+        with jax.named_scope("ds_loss"):      # the last layer norm feeds the head
+            return self._layer_norm(x, params["ln_f"], c.layer_norm_epsilon), aux_total
 
     def logits(self, params, tokens, rng=None):
         x, _ = self._backbone(params, tokens, rng=rng)
         # tied LM head: logits = x @ wte.T, contracted without materializing the
         # transposed table (153 MB HBM at 1.5B — see _chunked_ce)
-        return jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("ds_loss"):
+            return jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
 
     def _chunked_ce(self, x, wte, labels, chunk):
         """Fused LM-head + softmax cross-entropy, scanned over sequence chunks so the
@@ -526,19 +535,20 @@ class GPT2Model:
         x, aux = self._backbone(params, tokens, rng=rng)
         aux = (c.moe_aux_weight * aux if self._moe is not None
                else jnp.zeros((), jnp.float32))
-        T = x.shape[1]
-        if c.loss_chunk:
-            # largest divisor of T not exceeding loss_chunk (static shapes for XLA)
-            chunk = next(cc for cc in range(min(c.loss_chunk, T), 0, -1) if T % cc == 0)
-            if chunk < T:
-                return self._chunked_ce(x, params["wte"], labels, chunk), aux
-        logits = jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
-                            preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        valid = (labels >= 0).astype(jnp.float32)  # < 0 = ignored (BERT's -100)
-        ll = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None],
-                                 axis=-1)[..., 0]
-        return -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1.0), aux
+        with jax.named_scope("ds_loss"):
+            T = x.shape[1]
+            if c.loss_chunk:
+                # largest divisor of T not exceeding loss_chunk (static shapes for XLA)
+                chunk = next(cc for cc in range(min(c.loss_chunk, T), 0, -1) if T % cc == 0)
+                if chunk < T:
+                    return self._chunked_ce(x, params["wte"], labels, chunk), aux
+            logits = jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            valid = (labels >= 0).astype(jnp.float32)  # < 0 = ignored (BERT's -100)
+            ll = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None],
+                                     axis=-1)[..., 0]
+            return -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1.0), aux
 
     def apply(self, params, tokens, labels=None, rng=None):
         """With labels: mean token cross-entropy loss (the training objective);

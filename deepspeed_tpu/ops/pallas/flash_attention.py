@@ -289,7 +289,7 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
                                rate=rate, threshold=_keep_threshold(rate),
                                has_seed=seed is not None, seg=_is_segmented(seed))
     aux, aux_specs = _aux_operands(seed, bias, B, H, T, rate)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=aux_specs + [
@@ -308,7 +308,10 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         interpret=interpret,
-    )(*aux, q3, k3, v3)
+        name="ds_flash_fwd",
+    )
+    with jax.named_scope("ds_flash_fwd"):
+        out, lse = call(*aux, q3, k3, v3)
     return out.reshape(B, H, T, D), lse.reshape(B, H, T)
 
 
@@ -488,7 +491,7 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     has_bias = bias is not None
 
     aux, aux_specs = _aux_operands(seed, bias, B, H, T, rate)
-    dq = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_k=block_k, seq_len=T, has_bias=has_bias, rate=rate,
                           threshold=_keep_threshold(rate),
@@ -505,13 +508,16 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         interpret=interpret,
-    )(*aux, q3, k3, v3, do3, lse3, delta3)
+        name="ds_flash_bwd_dq",
+    )
+    with jax.named_scope("ds_flash_bwd_dq"):
+        dq = call(*aux, q3, k3, v3, do3, lse3, delta3)
 
     # the dkv grid iterates k-blocks, so its bias operand is tiled per k-block
     aux2, aux2_specs = _aux_operands(
         seed, bias, B, H, T, rate,
         block_k_map=(block_k, lambda b, i, H=H: (b // H, 0, i)))
-    dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, seq_len=T, has_bias=has_bias, rate=rate,
                           threshold=_keep_threshold(rate),
@@ -534,7 +540,10 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         ],
         interpret=interpret,
-    )(*aux2, q3, k3, v3, do3, lse3, delta3)
+        name="ds_flash_bwd_dkv",
+    )
+    with jax.named_scope("ds_flash_bwd_dkv"):
+        dk, dv = call(*aux2, q3, k3, v3, do3, lse3, delta3)
 
     return dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D)
 

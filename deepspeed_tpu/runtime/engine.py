@@ -33,7 +33,7 @@ from ..ops import adam as adam_opt
 from ..ops import lamb as lamb_opt
 from ..ops import sgd as sgd_opt
 from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_from_mpu
-from ..utils import ThroughputTimer, SynchronizedWallClockTimer, log_dist, logger
+from ..utils import SynchronizedWallClockTimer, log_dist, logger, spans
 from ..utils.cluster import named_scope as ds_named_scope
 from ..utils.compile_cache import configure_compile_cache
 from .config import DeepSpeedConfig
@@ -468,11 +468,13 @@ class DeepSpeedEngine:
 
         # ---- timers ----
         self.timers = SynchronizedWallClockTimer()
-        self.tput_timer = ThroughputTimer(
-            batch_size=self.train_micro_batch_size_per_gpu() * self.dp_size,
-            num_workers=1,
-            steps_per_output=self.steps_per_print(),
-            monitor_memory=False)
+
+        # ---- spans (utils/spans.py, docs/telemetry.md): the process's recorder, always
+        # on; ``train.step`` stays open from a window's first forward() to _finish_step
+        self._spans = spans.recorder()
+        self._step_programs = spans.Programs()      # held here, so it goes with the engine
+        self._span_engine = self._spans.new_engine(self._step_programs)
+        self._step_span = None
 
         # module-level activation-checkpointing config (reference engine.py:385-400).
         # Only push settings into the process-global module when THIS config carries
@@ -822,6 +824,20 @@ class DeepSpeedEngine:
         if self.telemetry is None or jitted is None:
             return jitted
         return self.telemetry.watch(name, jitted)
+
+    def _call_program(self, span, program, jitted, *args):
+        """Call one step program under its span. A call that built or loaded an
+        executable (``compile.*`` spans inside it) leaves the program's shapes in
+        ``_step_programs``, for the catalog ``spans.Recorder.programs`` makes on request."""
+        with self._spans.span(span, engine=self._span_engine, program=program) as sp:
+            out = jitted(*args)
+        if "builds" in sp.attrs:
+            self._step_programs.keep(program, jitted, args)
+        return out
+
+    def _host_fetch(self):
+        """The span around a place where the engine waits for the device by design."""
+        return self._spans.span("train.host_fetch", engine=self._span_engine)
 
     def dynamic_loss_scale(self):
         return self._dynamic_scale
@@ -1527,7 +1543,8 @@ class DeepSpeedEngine:
                 def run_fused(batch):
                     step_no = jnp.asarray(self.global_steps + 1 - self.skipped_steps,
                                           jnp.int32)
-                    outs = jit_fused(
+                    outs = self._call_program(
+                        "train.grad_program", "fused_step", jit_fused,
                         self.opt_state, self.scaler_state, self.params, step_no,
                         self.optimizer.current_hyper(), *batch)
                     if sentinel_index is not None:
@@ -1579,7 +1596,8 @@ class DeepSpeedEngine:
             def run_fused_std(batch):
                 step_no = jnp.asarray(self.global_steps + 1 - self.skipped_steps,
                                       jnp.int32)
-                outs = jit_fused_std(
+                outs = self._call_program(
+                    "train.grad_program", "fused_step", jit_fused_std,
                     self.master_params, self.opt_state, self.scaler_state,
                     self.params, step_no, self.optimizer.current_hyper(), *batch)
                 if sentinel_index is not None:
@@ -1936,7 +1954,14 @@ class DeepSpeedEngine:
             self._goodput_close_init()
         if self.wall_clock_breakdown():
             self.timers("forward_microstep").start()
-        batch = tuple(self.shard_batch(x) if not isinstance(x, jax.Array) else x for x in inputs)
+        if self._in_training and self.micro_steps % self.gradient_accumulation_steps() == 0:
+            if self._step_span is not None:      # a window that never reached step()
+                self._spans.end(self._step_span)
+            self._step_span = self._spans.begin(
+                "train.step", engine=self._span_engine, step=self.global_steps, root=True)
+        with self._spans.span("train.put_batch", engine=self._span_engine):
+            batch = tuple(self.shard_batch(x) if not isinstance(x, jax.Array) else x
+                          for x in inputs)
         if self._in_training:
             use_fused = self._run_fused_step is not None
             if use_fused and self._cpu_checkpointing_active():
@@ -1967,15 +1992,16 @@ class DeepSpeedEngine:
                 # compressed phase of hierarchical_compressed: host-side step
                 # switch (the two-phase warmup rule) — cheaper than a traced
                 # cond around two full backward programs
-                loss, grads, self._comm_we, self._comm_se = \
-                    self._jit_loss_and_grad_comm(
-                        self.params, self.scaler_state.cur_scale,
-                        self._comm_we, self._comm_se, *batch)
+                loss, grads, self._comm_we, self._comm_se = self._call_program(
+                    "train.grad_program", "loss_and_grad_comm",
+                    self._jit_loss_and_grad_comm, self.params,
+                    self.scaler_state.cur_scale, self._comm_we, self._comm_se, *batch)
                 self._pending_grads = grads
                 self._pending_loss = loss
             else:
-                loss, grads = self._jit_loss_and_grad(self.params,
-                                                      self.scaler_state.cur_scale, *batch)
+                loss, grads = self._call_program(
+                    "train.grad_program", "loss_and_grad", self._jit_loss_and_grad,
+                    self.params, self.scaler_state.cur_scale, *batch)
                 self._pending_grads = grads
                 self._pending_loss = loss
         else:
@@ -2004,15 +2030,17 @@ class DeepSpeedEngine:
             if self.wall_clock_breakdown():
                 self.timers("backward_microstep").stop()
             return loss
-        if self._grad_acc is None:
-            # First micro-batch of the window: adopt the grads directly (they already have
-            # the right sharding/dtype) instead of paying a zeros+add pass. With
-            # gradient_accumulation_steps == 1 this removes the accumulate kernel entirely.
-            # (Offload with accumulation > 1 upcasts to the fp32 accumulator dtype here.)
-            self._grad_acc = (self._pending_grads if self._jit_adopt_acc is None
-                              else self._jit_adopt_acc(self._pending_grads))
-        else:
-            self._grad_acc = self._jit_accumulate(self._grad_acc, self._pending_grads)
+        with self._spans.span("train.accumulate", engine=self._span_engine):
+            if self._grad_acc is None:
+                # First micro-batch of the window: adopt the grads directly (they already
+                # have the right sharding/dtype) instead of paying a zeros+add pass. With
+                # gradient_accumulation_steps == 1 this removes the accumulate kernel
+                # entirely. (Offload with accumulation > 1 upcasts to the fp32
+                # accumulator dtype here.)
+                self._grad_acc = (self._pending_grads if self._jit_adopt_acc is None
+                                  else self._jit_adopt_acc(self._pending_grads))
+            else:
+                self._grad_acc = self._jit_accumulate(self._grad_acc, self._pending_grads)
         self._pending_grads = None
         if self._pending_loss is not None:
             # Defer the device sync: keep the per-micro-batch loss arrays and average at
@@ -2060,16 +2088,19 @@ class DeepSpeedEngine:
             self._fused_pending = None
             self._last_grad_norm = norm
             self._pending_sentinel = sent
-            self._finish_step(self.fp16_enabled() and bool(jax.device_get(overflow)))
+            self._finish_step(self._overflowed(overflow))
             return
         if self._offload is not None:
-            overflow_bool = self._offload_step()
+            with self._spans.span("train.update_program", engine=self._span_engine,
+                                  program="offload_step"):
+                overflow_bool = self._offload_step()
             self._finish_step(overflow_bool)
             return
         hyper = self.optimizer.current_hyper()
         step = jnp.asarray(self.global_steps + 1 - self.skipped_steps, jnp.int32)
         if self._external_master:
-            outs = self._jit_apply_update(
+            outs = self._call_program(
+                "train.update_program", "apply_update", self._jit_apply_update,
                 self.opt_state, self.scaler_state, self._grad_acc, step, hyper)
             if self._sentinel_index is not None:
                 (self.opt_state, self.scaler_state, overflow,
@@ -2077,9 +2108,10 @@ class DeepSpeedEngine:
             else:
                 (self.opt_state, self.scaler_state, overflow,
                  self._last_grad_norm) = outs
-            self._finish_step(self.fp16_enabled() and bool(jax.device_get(overflow)))
+            self._finish_step(self._overflowed(overflow))
             return
-        outs = self._jit_apply_update(
+        outs = self._call_program(
+            "train.update_program", "apply_update", self._jit_apply_update,
             self.master_params, self.opt_state, self.scaler_state, self._grad_acc,
             self.params, step, hyper)
         if self._sentinel_index is not None:
@@ -2088,7 +2120,14 @@ class DeepSpeedEngine:
         else:
             (self.master_params, self.opt_state, self.scaler_state, self.params,
              overflow, self._last_grad_norm) = outs
-        self._finish_step(self.fp16_enabled() and bool(jax.device_get(overflow)))
+        self._finish_step(self._overflowed(overflow))
+
+    def _overflowed(self, overflow) -> bool:
+        """Whether the update skipped: known without asking the device unless fp16 is on."""
+        if not self.fp16_enabled():
+            return False
+        with self._host_fetch():
+            return bool(jax.device_get(overflow))
 
     def _offload_step(self) -> bool:
         """Host-tier optimizer step (ZeRO-Offload), partitioned and overlapped.
@@ -2112,8 +2151,9 @@ class DeepSpeedEngine:
         else:
             norm_dev, overflow_dev = self._jit_grad_stats(self._grad_acc)
             sent_dev = None
-        scale = float(jax.device_get(self.scaler_state.cur_scale))
-        overflow = bool(jax.device_get(overflow_dev)) if self.fp16_enabled() else False
+        with self._host_fetch():
+            scale = float(jax.device_get(self.scaler_state.cur_scale))
+            overflow = bool(jax.device_get(overflow_dev)) if self.fp16_enabled() else False
 
         factor = 1.0
         if scale != 1.0 and scale > 0:
@@ -2121,7 +2161,8 @@ class DeepSpeedEngine:
         predivide = float(self.config.gradient_predivide_factor or 1.0)
         if self.config.prescale_gradients and predivide != 1.0:
             factor *= predivide
-        norm = float(jax.device_get(norm_dev)) * factor
+        with self._host_fetch():
+            norm = float(jax.device_get(norm_dev)) * factor
         self._last_grad_norm = norm
         # sumsq of the raw (still loss-scaled) grads; factor**2 converts to the
         # post-unscale semantics the standard path's sentinel reports. Captured
@@ -2153,7 +2194,8 @@ class DeepSpeedEngine:
         if sent_dev is not None:
             # this path already blocked on overflow/norm above, so the fetch
             # rides the existing sync — no new barrier
-            host = jax.device_get(sent_dev)
+            with self._host_fetch():
+                host = jax.device_get(sent_dev)
             self._pending_sentinel = {
                 "grad_sumsq": host["grad_sumsq"] * unscale_sq,
                 "grad_nonfinite": host["grad_nonfinite"],
@@ -2176,30 +2218,34 @@ class DeepSpeedEngine:
             # reference scalars: Train/Samples/train_loss + lr + loss_scale
             # (engine.py:779-790, 920-936)
             samples = self.global_steps * self.train_batch_size()
-            if self._window_losses:
+            with self._host_fetch():
                 window = [float(l) for l in jax.device_get(self._window_losses)]
+                scale = self.loss_scale() if self.fp16_enabled() else None
+                norm = (None if self._last_grad_norm is None
+                        else float(jax.device_get(self._last_grad_norm)))
+            if window:
                 self.monitor.add_scalar("Train/Samples/train_loss",
                                         sum(window) / len(window), samples)
             lr = self.get_lr()
             if lr:
                 self.monitor.add_scalar("Train/Samples/lr", lr[0], samples)
-            if self.fp16_enabled():
-                self.monitor.add_scalar("Train/Samples/loss_scale", self.loss_scale(),
-                                        samples)
-            if self._last_grad_norm is not None:
-                self.monitor.add_scalar("Train/Samples/grad_norm",
-                                        float(jax.device_get(self._last_grad_norm)), samples)
+            if scale is not None:
+                self.monitor.add_scalar("Train/Samples/loss_scale", scale, samples)
+            if norm is not None:
+                self.monitor.add_scalar("Train/Samples/grad_norm", norm, samples)
             self.monitor.flush()  # reference flushes per emission (engine.py:790)
         numerics_host = None
         if self.telemetry is not None:
             # non-perturbing step boundary: rides the loss fetch (above, or here
             # when no monitor is attached) — no extra barrier enters the step
-            numerics_host = self.telemetry.end_step(
-                self.global_steps, self.train_batch_size(),
-                pending=self._window_losses, numerics=self._pending_sentinel,
-                run_goodput=self._goodput_scalars())
+            with self._host_fetch():
+                numerics_host = self.telemetry.end_step(
+                    self.global_steps, self.train_batch_size(),
+                    pending=self._window_losses, numerics=self._pending_sentinel,
+                    run_goodput=self._goodput_scalars())
         elif self._pending_sentinel is not None:
-            numerics_host = jax.device_get(self._pending_sentinel)
+            with self._host_fetch():
+                numerics_host = jax.device_get(self._pending_sentinel)
         if self._numerics is not None:
             self._commit_numerics(numerics_host, overflowed, self._window_losses)
         if self._cluster is not None:
@@ -2223,6 +2269,8 @@ class DeepSpeedEngine:
             self.timers("step_microstep").stop()
             self.timers.log(["forward_microstep", "backward_microstep", "step_microstep"],
                             memory_breakdown=self.config.memory_breakdown)
+        self._spans.end(self._step_span)
+        self._step_span = None
 
     def _report_progress(self, step):
         lr = self.get_lr()

@@ -396,6 +396,12 @@ def module_name(hlo_text):
     return m.group(1) if m else ""
 
 
+def instruction_names(hlo_text):
+    """The name of every instruction definition, across all computations, in the
+    order of the text (a scheduled module lists a computation in schedule order)."""
+    return [m.group(1) for m in map(_DEF_NAME_RE.match, hlo_text.splitlines()) if m]
+
+
 def instruction_op_names(hlo_text):
     """{instruction name: metadata op_name} over every definition line that
     carries ``op_name`` metadata, across all computations. The op_name is the

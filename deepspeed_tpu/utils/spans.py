@@ -1,0 +1,244 @@
+"""The program's own span and counter recorder.
+
+One process-wide ``Recorder`` (``spans.recorder()``): a bounded in-memory ring of spans
+on ``time.perf_counter`` and a dict of counters that are never evicted. It is always on.
+It has no config key, no environment variable and writes no file: a handful of spans a
+step costs microseconds, and the last minutes of any run are there for whoever asks.
+
+Every span is also entered as a ``jax.profiler.TraceAnnotation`` of the same name. That
+is inert unless a profiler session runs; when one does, the span lands in the profiler's
+own file on the device's clock, above the device rows. Nothing here waits for the device,
+fetches from it or adds to a compiled program.
+
+    rec = spans.recorder()
+    with rec.span("train.put_batch", engine=eid, step=n):
+        ...
+    rec.spans()        # the ring, oldest first
+    rec.counters(eid)  # {"program.builds[loss_and_grad]": 2, ...}
+    rec.programs(eid)  # lazy: {program: {"module": ..., "ops": {instruction: op_name}}}
+
+A span's parent is the innermost span of its engine that was open on the same thread
+when it began (two engines may take turns on one thread), or of any engine if it names
+none. The four ``compile.*`` spans come from ``jax.monitoring``'s duration events, which
+arrive when the work is over: each is put down as ``[now - seconds, now]`` under whichever
+span is open, so a build lands in the step and the program call that caused it.
+"""
+
+import collections
+import itertools
+import threading
+import time
+import weakref
+
+import jax
+
+clock = time.perf_counter
+
+RING_SPANS = 8192                   # some five minutes of one-program-pair steps
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+BUILD_SPAN = "compile.backend"      # one a built or loaded executable
+MIN_COMPILE_SPAN_S = 1e-3           # shorter ones are counted and not kept: the inner jits
+                                    # of one trace come by the thousand and would empty the ring
+
+
+class Span:
+    __slots__ = ("id", "parent", "engine", "name", "start", "end", "step", "attrs",
+                 "_annotation")
+
+    def __init__(self, id, parent, engine, name, start, step, attrs):
+        self.id, self.parent, self.engine, self.name = id, parent, engine, name
+        self.start, self.end, self.step, self.attrs = start, None, step, attrs
+        self._annotation = None
+
+    def as_dict(self):
+        return {"id": self.id, "parent": self.parent, "engine": self.engine,
+                "name": self.name, "start": self.start, "end": self.end,
+                "step": self.step, "attrs": dict(self.attrs) if self.attrs else {}}
+
+
+class _Scope:
+    """``with recorder.span(...) as span``."""
+    __slots__ = ("_recorder", "_span")
+
+    def __init__(self, recorder, span):
+        self._recorder, self._span = recorder, span
+
+    def __enter__(self):
+        return self._span
+
+    def __exit__(self, *exc):
+        self._recorder.end(self._span)
+        return False
+
+
+class Recorder:
+    def __init__(self, capacity=RING_SPANS):
+        self._ring = collections.deque(maxlen=capacity)
+        self._ids = itertools.count(1)
+        self._engines = itertools.count(1)
+        self._open = threading.local()
+        self._lock = threading.Lock()
+        self._counters = {}          # (engine, name) -> int
+        self._programs = weakref.WeakValueDictionary()   # engine -> the Programs it holds
+
+    # ------------------------------------------------------------------ spans
+    def new_engine(self, programs=None):
+        """An id for one engine's spans and counters; several engines share a process.
+        ``programs`` is the engine's own ``Programs``: the recorder only refers to it
+        weakly, so the compiled step programs go when their engine goes."""
+        engine = next(self._engines)
+        if programs is not None:
+            self._programs[engine] = programs
+        return engine
+
+    def _stack(self):
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    def begin(self, name, engine=None, step=None, root=False, **attrs):
+        """Open a span that ``end`` closes; for spans that outlive one function. A
+        ``root`` span has no parent, whatever another caller left open."""
+        stack = self._stack()
+        parent = None
+        if not root:
+            parent = next((s for s in reversed(stack)
+                           if engine is None or s.engine == engine), None)
+        if parent is not None:
+            if engine is None:
+                engine = parent.engine
+            if step is None:
+                step = parent.step
+        span = Span(next(self._ids), parent.id if parent else None, engine, name,
+                    clock(), step, attrs or None)
+        span._annotation = jax.profiler.TraceAnnotation(name)
+        span._annotation.__enter__()
+        stack.append(span)
+        return span
+
+    def end(self, span):
+        """Close ``span`` and whatever was left open inside it: its descendants, and not
+        another engine's step that was opened after it."""
+        stack = self._stack()
+        if span not in stack:
+            return
+        at = stack.index(span)
+        inside, others = {span.id}, []
+        for s in stack[at + 1:]:
+            if s.parent in inside:
+                inside.add(s.id)
+            else:
+                others.append(s)
+        closing = [s for s in stack[at:] if s.id in inside]
+        stack[at:] = others
+        for s in reversed(closing):
+            s._annotation.__exit__(None, None, None)
+            s._annotation = None
+            s.end = clock()
+            self._ring.append(s)
+
+    def span(self, name, engine=None, step=None, **attrs):
+        return _Scope(self, self.begin(name, engine, step, **attrs))
+
+    def spans(self, engine=None):
+        """The closed spans in the ring, oldest first, as dicts."""
+        return [s.as_dict() for s in list(self._ring) if engine is None or s.engine == engine]
+
+    # --------------------------------------------------------------- counters
+    def count(self, engine, name):
+        with self._lock:
+            self._counters[engine, name] = self._counters.get((engine, name), 0) + 1
+
+    def counters(self, engine):
+        with self._lock:
+            return {name: v for (e, name), v in self._counters.items() if e == engine}
+
+    # ---------------------------------------------------------------- compiles
+    def on_compile_event(self, event, seconds, **kwargs):
+        """A ``jax.monitoring`` duration listener: one ``compile.*`` span an event, and a
+        build counted against the step program whose call is open."""
+        name = COMPILE_EVENTS.get(event)
+        if name is None:
+            return
+        stack = self._stack()
+        if seconds >= MIN_COMPILE_SPAN_S:
+            parent = stack[-1] if stack else None
+            now = clock()
+            span = Span(next(self._ids), parent.id if parent else None,
+                        parent.engine if parent else None, name, now - seconds,
+                        parent.step if parent else None,
+                        {"fun_name": kwargs["fun_name"]} if kwargs.get("fun_name") else None)
+            span.end = now
+            self._ring.append(span)
+        if name == BUILD_SPAN:
+            owner = next((s for s in reversed(stack) if s.attrs and "program" in s.attrs), None)
+            if owner is not None:
+                owner.attrs["builds"] = owner.attrs.get("builds", 0) + 1
+                self.count(owner.engine, f"program.builds[{owner.attrs['program']}]")
+
+    # ---------------------------------------------------------------- programs
+    def programs(self, engine):
+        """The catalog of ``engine``'s step programs (``Programs.catalog``); nothing for
+        an engine that is gone or never gave its ``Programs``."""
+        held = self._programs.get(engine)
+        return held.catalog() if held is not None else {}
+
+
+class Programs:
+    """One engine's step programs as last built, and the catalog made from them on
+    request. The engine holds it (``Recorder.new_engine``): it goes with its engine."""
+
+    def __init__(self):
+        self._kept = {}              # program -> (jitted, abstract arguments)
+        self._catalog = {}           # program -> {"module", "ops"}
+
+    def keep(self, program, jitted, args):
+        """Remember how ``program`` was last built, so that ``catalog`` can ask the
+        compiler for its text later. Shapes only: no argument is kept alive."""
+        def abstract(x):
+            if not isinstance(x, jax.Array):
+                return x
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, weak_type=x.aval.weak_type,
+                                        sharding=x.sharding if x.committed else None)
+
+        self._kept[program] = (jitted, jax.tree_util.tree_map(abstract, args))
+        self._catalog.pop(program, None)
+
+    def catalog(self):
+        """``{program: {"module": HloModule name, "ops": {instruction: op_name}}}`` for the
+        step programs the engine has run: every instruction of the optimized program
+        with the scope path JAX gave it ("" where the compiler made it up). Computed on
+        request and kept: it compiles (or loads from the persistent cache), so nobody
+        asks inside a measured window."""
+        from . import hlo
+        for program, (jitted, args) in self._kept.items():
+            if program not in self._catalog:
+                text = jitted.lower(*args).compile().as_text()
+                ops = dict.fromkeys(hlo.instruction_names(text), "")
+                ops.update(hlo.instruction_op_names(text))
+                self._catalog[program] = {"module": hlo.module_name(text), "ops": ops}
+        return dict(self._catalog)
+
+
+_RECORDER = None
+_RECORDER_LOCK = threading.Lock()
+
+
+def recorder():
+    """The process's recorder; the first call makes it and registers its compile
+    listener with ``jax.monitoring``, once."""
+    global _RECORDER
+    if _RECORDER is None:
+        with _RECORDER_LOCK:
+            if _RECORDER is None:
+                made = Recorder()
+                jax.monitoring.register_event_duration_secs_listener(made.on_compile_event)
+                _RECORDER = made
+    return _RECORDER

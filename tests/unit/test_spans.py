@@ -1,0 +1,249 @@
+"""The program's span and counter recorder (``deepspeed_tpu/utils/spans.py``) and the
+spans the engine's main path records with it (docs/telemetry.md)."""
+
+import contextlib
+import gc
+import weakref
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+from deepspeed_tpu.utils import spans
+from deepspeed_tpu.utils.hlo import instruction_count, optimized_hlo
+from simple_model import SimpleModel, random_dataset, simple_config
+
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+def test_nesting_parents_engine_and_step_are_inherited():
+    rec = spans.Recorder()
+    eid = rec.new_engine()
+    step = rec.begin("train.step", engine=eid, step=7, root=True)
+    with rec.span("train.grad_program", program="loss_and_grad") as grad:
+        assert rec._stack() == [step, grad]
+        with rec.span("inner"):
+            pass
+    rec.end(step)
+    got = {s["name"]: s for s in rec.spans()}
+    assert [s["name"] for s in rec.spans()] == ["inner", "train.grad_program", "train.step"]
+    assert got["train.step"]["parent"] is None
+    assert got["train.grad_program"]["parent"] == got["train.step"]["id"]
+    assert got["inner"]["parent"] == got["train.grad_program"]["id"]
+    assert {s["engine"] for s in got.values()} == {eid} and {s["step"] for s in got.values()} == {7}
+    assert got["train.grad_program"]["attrs"] == {"program": "loss_and_grad"}
+    assert all(s["end"] >= s["start"] for s in got.values())
+    assert rec._stack() == []
+    assert rec.spans(engine=eid + 1) == []
+
+
+def test_a_root_span_has_no_parent_and_end_closes_what_was_left_open():
+    rec = spans.Recorder()
+    stale = rec.begin("train.step", engine=1, root=True)
+    rec.begin("train.grad_program")                 # never closed: its call raised
+    fresh = rec.begin("train.step", engine=2, root=True)
+    rec.end(fresh)
+    rec.end(stale)
+    rec.end(stale)                                  # a second end is nothing
+    got = rec.spans()
+    assert [(s["name"], s["engine"], s["parent"]) for s in got] == [
+        ("train.step", 2, None), ("train.grad_program", 1, stale.id), ("train.step", 1, None)]
+    assert rec._stack() == []
+
+
+def test_two_engines_taking_turns_on_one_thread_keep_their_own_steps():
+    rec = spans.Recorder()
+    a, b = rec.new_engine(), rec.new_engine()
+    step_a = rec.begin("train.step", engine=a, step=0, root=True)
+    step_b = rec.begin("train.step", engine=b, step=0, root=True)
+    with rec.span("train.grad_program", engine=b, program="loss_and_grad") as grad_b:
+        pass
+    with rec.span("train.update_program", engine=a, program="apply_update") as update_a:
+        assert update_a.parent == step_a.id          # not the span on top, which is b's
+        rec.on_compile_event(BACKEND, 0.01)
+        rec.begin("left.open")                       # no engine: under the span on top
+    rec.end(step_a)
+    assert rec._stack() == [step_b]                  # a's end leaves b's step open
+    with rec.span("train.update_program", engine=b, program="apply_update") as update_b:
+        pass
+    rec.end(step_b)
+    assert grad_b.parent == update_b.parent == step_b.id
+    got = rec.spans()
+    assert [(s["name"], s["engine"]) for s in got] == [
+        ("train.grad_program", b), ("compile.backend", a), ("left.open", a),
+        ("train.update_program", a), ("train.step", a), ("train.update_program", b),
+        ("train.step", b)]
+    assert rec.counters(a) == {"program.builds[apply_update]": 1} and rec.counters(b) == {}
+    assert rec._stack() == []
+
+
+def test_ring_eviction_leaves_counters_whole():
+    rec = spans.Recorder(capacity=8)
+    eid = rec.new_engine()
+    for n in range(50):
+        with rec.span("train.step", engine=eid, step=n):
+            rec.count(eid, "program.builds[x]")
+            if n % 2:
+                rec.count(eid, "program.builds[y]")
+    kept = rec.spans()
+    assert len(kept) == 8 and [s["step"] for s in kept] == list(range(42, 50))
+    assert rec.counters(eid) == {"program.builds[x]": 50, "program.builds[y]": 25}
+    assert rec.counters(eid + 1) == {}
+
+
+def test_a_compile_event_lands_under_the_open_program_span():
+    rec = spans.Recorder()
+    eid = rec.new_engine()
+    with rec.span("train.step", engine=eid, step=0, root=True):
+        with rec.span("train.grad_program", program="loss_and_grad") as grad:
+            rec.on_compile_event("/jax/core/compile/jaxpr_trace_duration", 1e-5, fun_name="add")
+            rec.on_compile_event("/jax/compilation_cache/cache_retrieval_time_sec", 0.2)
+            rec.on_compile_event(BACKEND, 0.25, fun_name="jit(loss_and_grad)")
+            rec.on_compile_event("/jax/some/other_event", 3.0)
+        with rec.span("train.update_program", program="apply_update") as update:
+            pass
+        rec.on_compile_event(BACKEND, 0.01, fun_name="jit(convert_element_type)")
+    got = rec.spans()
+    by_name = {}
+    for s in got:
+        by_name.setdefault(s["name"], []).append(s)
+    # the inner jit of a trace is too short to keep; the unknown event is no span
+    assert sorted(by_name) == ["compile.backend", "compile.cache_load", "train.grad_program",
+                               "train.step", "train.update_program"]
+    load, = by_name["compile.cache_load"]
+    in_program, under_step = sorted(by_name["compile.backend"], key=lambda s: s["start"])
+    assert load["parent"] == in_program["parent"] == grad.id
+    assert in_program["attrs"] == {"fun_name": "jit(loss_and_grad)"}
+    assert in_program["end"] - in_program["start"] == pytest.approx(0.25)
+    assert under_step["parent"] == by_name["train.step"][0]["id"]
+    assert grad.attrs["builds"] == 1 and "builds" not in update.attrs
+    assert rec.counters(eid) == {"program.builds[loss_and_grad]": 1}
+
+
+def test_the_process_recorder_is_one_and_hears_jax_compile():
+    rec = spans.recorder()
+    assert spans.recorder() is rec
+    eid = rec.new_engine()
+    x = jnp.arange(7.0)                      # an eager operation is a program of its own
+    with rec.span("train.grad_program", engine=eid, program="probe"):
+        jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+    assert rec.counters(eid) == {"program.builds[probe]": 1}
+
+
+# ------------------------------------------------------------------ the engine
+def _children(spans_):
+    out = {}
+    for s in spans_:
+        out.setdefault(s["parent"], []).append(s)
+    return out
+
+
+def _gpt2_engine():
+    model = GPT2Model(GPT2Config(vocab_size=128, n_positions=32, n_embd=32, n_layer=2, n_head=2,
+                                 use_flash_attention=True, loss_chunk=16,
+                                 compute_dtype=jnp.bfloat16))
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
+        config_params={"train_batch_size": 8, "bf16": {"enabled": True},
+                       "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                       "zero_optimization": {"stage": 2}, "steps_per_print": 10 ** 9})
+    tokens = np.random.default_rng(0).integers(0, 128, (8, 32)).astype(np.int32)
+    return engine, tokens
+
+
+def _program_hlos(engine, tokens):
+    args = (engine.params, engine.scaler_state.cur_scale, *engine.shard_batch((tokens, tokens)))
+    grad = optimized_hlo(engine._jit_loss_and_grad, *args)
+    _, grads = engine._jit_loss_and_grad(*args)
+    update = optimized_hlo(engine._jit_apply_update, engine.master_params, engine.opt_state,
+                           engine.scaler_state, grads, engine.params,
+                           jnp.asarray(1, jnp.int32), engine.optimizer.current_hyper())
+    return grad, update
+
+
+def test_three_steps_give_three_roots_with_their_children():
+    engine, tokens = _gpt2_engine()
+    for _ in range(3):
+        loss = engine(tokens, tokens)
+        engine.backward(loss)
+        engine.step()
+    rec = spans.recorder()
+    eid = engine._span_engine
+    mine = rec.spans(engine=eid)
+    steps = [s for s in mine if s["name"] == "train.step"]
+    assert [s["step"] for s in steps] == [0, 1, 2] and all(s["parent"] is None for s in steps)
+    tree = _children(mine)
+    for step in steps:
+        kids = [c for c in tree[step["id"]] if c["name"].startswith("train.")]
+        assert [c["name"] for c in sorted(kids, key=lambda c: c["start"])] == [
+            "train.put_batch", "train.grad_program", "train.accumulate", "train.update_program"]
+        assert all(step["start"] <= c["start"] and c["end"] <= step["end"] for c in kids)
+        programs = {c["name"]: c["attrs"].get("program") for c in kids}
+        assert programs["train.grad_program"] == "loss_and_grad"
+        assert programs["train.update_program"] == "apply_update"
+    # the first step built both programs, under the call that asked for each
+    first = {c["name"]: c for c in tree[steps[0]["id"]]}
+    for name in ("train.grad_program", "train.update_program"):
+        assert first[name]["attrs"]["builds"] >= 1
+        assert any(c["name"] == "compile.backend" for c in tree[first[name]["id"]])
+    last = {c["name"]: c for c in tree[steps[2]["id"]]}
+    assert "builds" not in last["train.grad_program"]["attrs"]
+    assert not any(s["name"] == "train.host_fetch" for s in mine)   # bf16, no monitor
+    counters = rec.counters(eid)
+    assert set(counters) == {"program.builds[loss_and_grad]", "program.builds[apply_update]"}
+    assert min(counters.values()) >= 1
+    assert engine._step_span is None and rec._stack() == []
+    # the catalog, on request: every instruction of each program, scope paths where JAX gave one
+    catalog = rec.programs(eid)
+    assert set(catalog) == {"loss_and_grad", "apply_update"}
+    grad_ops = catalog["loss_and_grad"]["ops"]
+    assert catalog["loss_and_grad"]["module"].startswith("jit_")
+    paths = " ".join(grad_ops.values())
+    for scope in ("ds_embed", "ds_attn", "ds_mlp", "ds_loss", "ds_flash_fwd", "ds_flash_bwd_dq",
+                  "ds_flash_bwd_dkv", "transpose(jvp(ds_mlp))"):
+        assert scope in paths, scope
+    assert "ds_apply_update" in " ".join(catalog["apply_update"]["ops"].values())
+    assert rec.programs(eid) is not catalog and rec.programs(eid) == catalog   # kept, not rebuilt
+    # the engine holds its programs and the recorder only refers to them: they go with it
+    kept = weakref.ref(engine._step_programs)
+    del engine, catalog
+    gc.collect()
+    assert kept() is None and rec.programs(eid) == {}
+    assert len(rec.spans(engine=eid)) == len(mine)      # the spans stay in the ring
+
+
+def test_fp16_overflow_fetch_is_one_host_fetch_span_a_step():
+    model = SimpleModel(16)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
+        config_params=simple_config(fp16={"enabled": True, "initial_scale_power": 4}))
+    data = random_dataset(8, 16)
+    xs, ys = np.stack([d[0] for d in data]), np.stack([d[1] for d in data])
+    for scale in (1.0, np.inf):
+        loss = engine((xs * scale).astype(np.float16), ys.astype(np.float16))
+        engine.backward(loss)
+        engine.step()
+    rec = spans.recorder()
+    mine = rec.spans(engine=engine._span_engine)
+    tree = _children(mine)
+    steps = [s for s in mine if s["name"] == "train.step"]
+    assert len(steps) == 2
+    for step in steps:
+        assert [c["name"] for c in tree[step["id"]] if c["name"] == "train.host_fetch"] == [
+            "train.host_fetch"]
+    assert engine.skipped_steps == 1 and [s["step"] for s in steps] == [0, 1]
+
+
+def test_scopes_and_spans_add_no_instruction_to_either_program(monkeypatch):
+    engine, tokens = _gpt2_engine()
+    with_scopes = _program_hlos(engine, tokens)
+    assert "ds_mlp" in with_scopes[0] and "ds_flash_fwd" in with_scopes[0]
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare_engine, _ = _gpt2_engine()
+    bare = _program_hlos(bare_engine, tokens)
+    assert "ds_mlp" not in bare[0] and "ds_fwd_bwd" not in bare[0]
+    for a, b in zip(with_scopes, bare):
+        assert instruction_count(a) == instruction_count(b) > 0
