@@ -4,14 +4,23 @@ TPU-native replacement for the reference's fused attention-softmax CUDA kernels
 (``csrc/transformer/softmax_kernels.cu``, ``general_kernels.cu`` attention-score path of
 N1): a blocked online-softmax attention that never materializes the [T, T] score matrix.
 
-Design (v5e):
-- grid over (batch*heads, q-blocks); the k/v stream is a ``lax.fori_loop`` over k-blocks
-  with running (m, l, acc) online-softmax state — classic FlashAttention-2 structure.
-- blocks default to 256x512 (tuned on v5e: ~1.7x over 128x128); head_dim <= 256 in VMEM.
-- causal masking prunes whole k-blocks above the diagonal (loop bound), and applies the
-  triangular mask only on the single diagonal block.
-- backward is the standard two-pass flash backward (dq pass over k-blocks; dk/dv pass
-  over q-blocks) using the saved LSE; residuals are (q, k, v, out, lse) — O(T) memory.
+Design (v5e; measured rows in ``_resolve`` and PERF.md, PR 25):
+- every tile is computed TRANSPOSED, S^T = K.Q^T as [block_k, block_q]: the softmax's
+  per-query statistics (m, l, lse, delta) are [1, block_q] rows, reduced down the
+  sublanes, never across the lanes of each 8 query rows, and never re-laid-out.
+- forward: grid over (batch*heads, q-tiles); the k/v stream is a ``lax.fori_loop`` over
+  k-tiles with running (m, l, acc^T) online-softmax state (FlashAttention-2 order).
+- backward: ONE pass, grid over (batch*heads, k-tiles) with a loop over the q-tiles
+  that see the k-tile. A visit computes the tile once (five matmuls, one exp2) and
+  feeds all three gradients: dK and dV of the k-tile ride the loop, dQ^T accumulates in
+  a float32 VMEM scratch [D, T] across the k-tiles of one (batch, head) and is rounded
+  once. Residuals are (q, k, v, out, lse) — O(T) memory.
+- causal: ``causal_k_tiles`` / ``causal_q_tiles`` are the schedule — every tile the
+  triangle touches is visited once, none wholly above the diagonal, and the mask runs
+  only on tiles the diagonal crosses. ``_resolve`` picks square tiles (512; 1024 for
+  non-causal calls and from T = 8192), so a diagonal tile holds nothing wholly masked.
+- each kernel computes its VMEM budget from T, D and the tile sizes (``_vmem_limit``);
+  head_dim <= 256.
 - ``interpret=True`` fallback keeps CPU tests honest; a dense reference implementation
   (``dense_attention``) is the numerics oracle.
 """
@@ -60,7 +69,7 @@ def dense_attention(q, k, v, causal=False, sm_scale=None, bias=None, dropout_kee
 # Stateless counter-based dropout: a lowbias32-style integer avalanche over the
 # ABSOLUTE coordinate (batch*head, q position, k position) plus the step seed. Because
 # the bits depend only on coordinates — never on block shapes or grid order — the
-# forward kernel and both backward kernels regenerate bit-identical masks, remat
+# forward kernel and the backward kernel regenerate bit-identical masks, remat
 # replays them exactly (the seed is a traced operand), and a pure-jnp oracle
 # (``dropout_keep_reference``) exists for parity tests. This replaces the reference's
 # CUDA RNG state tracker + curand path (csrc/transformer/dropout_kernels.cu).
@@ -126,20 +135,68 @@ def _read_seed_ref(seed_ref, seg):
     return seed_u32, map_q, map_k
 
 
+def causal_k_tiles(q_tile, block_q, block_k):
+    """The k-tiles a causal q-tile visits, as ``(n_full, last)``: tiles ``[0, n_full)``
+    lie wholly on or below the diagonal (largest key <= smallest query: no mask),
+    tiles ``[n_full, last)`` are crossed by it (masked body), and no tile from
+    ``last`` on holds an unmasked element. Plain integer arithmetic, so it takes a
+    Python int (the schedule test) or a traced ``program_id`` (the forward kernel);
+    ``last`` never passes the number of k-tiles when both tile sizes divide T.
+
+    The indices are LOCAL — exact for segmented layouts too, because causal segmented
+    calls require identical, monotone q/k segment maps (zigzag: both sides are the
+    same [chunk i, chunk 2n-1-i] interleave), under which local order equals global
+    order."""
+    n_full = (q_tile * block_q + 1) // block_k
+    last = ((q_tile + 1) * block_q + block_k - 1) // block_k
+    return n_full, last
+
+
+def causal_q_tiles(k_tile, block_q, block_k):
+    """The q-tiles that visit a causal k-tile, as ``(first, full_from)``: no q-tile
+    before ``first`` holds an unmasked element against it, tiles ``[first, full_from)``
+    are crossed by the diagonal, tiles from ``full_from`` on lie wholly on or below it
+    (smallest query >= largest key). The backward kernel's view of the same schedule
+    as ``causal_k_tiles``."""
+    first = (k_tile * block_k) // block_q
+    full_from = ((k_tile + 1) * block_k + block_q - 2) // block_q
+    return first, full_from
+
+
+_NT = (((1,), (1,)), ((), ()))     # dot_general dimension numbers of A.B^T
+_TN = (((0,), (0,)), ((), ()))     # ... and of A^T.B
+
+
+def _tile_positions(k_start, q_start, shape, maps):
+    """(q, k) sequence coordinates of every element of a transposed tile [keys, queries]
+    that starts at local key ``k_start`` and query ``q_start``; ``maps`` = (map_q, map_k)
+    of ``_read_seed_ref`` takes them to global coordinates, ``None`` leaves them local."""
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (maps[0](q_pos), maps[1](k_pos)) if maps else (q_pos, k_pos)
+
+
+def _split_refs(refs, has_seed, has_bias):
+    """(seed_ref, bias_ref, the rest): the optional SMEM seed/offset operand and the
+    bias row lead a kernel's refs, in that order, when present."""
+    refs = list(refs)
+    seed_ref = refs.pop(0) if has_seed else None
+    bias_ref = refs.pop(0) if has_bias else None
+    return seed_ref, bias_ref, refs
+
+
 def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, threshold,
                 has_seed, seg):
-    i = 0
-    seed_ref = None
-    bias_ref = None
-    if has_seed:
-        seed_ref = refs[i]
-        i += 1
-    if has_bias:
-        bias_ref = refs[i]
-        i += 1
-    q_ref, k_ref, v_ref, o_ref, lse_ref = refs[i:]
-    bq = q_ref.shape[0]
-    d = q_ref.shape[1]
+    """Grid cell ``(b, i)`` holds q-tile ``i`` and walks the k-tiles it sees with the
+    running (m, l, acc) of the online softmax. The tile is computed TRANSPOSED,
+    S^T = K.Q^T as [block_k, block_q], so the softmax's per-query statistics are
+    [1, block_q] rows: the max and the sum over keys run down the sublanes (elementwise
+    across vregs) instead of across the lanes of every 8 query rows, and ``lse`` is
+    stored as the row it is kept as. The accumulator is acc^T = V^T.P^T [D, block_q]
+    (the V tile contracts its leading axis), turned once, when the q-tile is written."""
+    seed_ref, bias_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref) = _split_refs(
+        refs, has_seed, has_bias)
+    bq, d = q_ref.shape
     q_blk_idx = pl.program_id(1)
     # keep MXU operands in the input dtype (bf16): bf16-in/fp32-accumulate is the MXU's
     # native mode — upcasting to fp32 before the dot ran the matmuls many times slower.
@@ -151,60 +208,45 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
         # zigzag layout, the causal mask), so chunked long-context tiles and
         # ring-attention shards regenerate the same bit stream / mask a single
         # whole-sequence kernel would.
-        seed_u32, map_q, map_k = _read_seed_ref(seed_ref, seg)
+        seed_u32, *maps = _read_seed_ref(seed_ref, seg)
         bh_u32 = pl.program_id(0).astype(jnp.uint32)
+    else:
+        maps = None
     if rate > 0:
         inv_keep = 1.0 / (1.0 - rate)
-
-    num_k_blocks = pl.cdiv(seq_len, block_k)
     if causal:
-        # process k blocks up to and including the diagonal block. The bounds use
-        # LOCAL indices — exact for segmented layouts too, because causal segmented
-        # calls require identical, monotone q/k segment maps (zigzag: both sides are
-        # the same [chunk i, chunk 2n-1-i] interleave), under which local order
-        # equals global order.
-        last_blk = jnp.minimum(num_k_blocks, (q_blk_idx * bq + bq + block_k - 1) // block_k)
-        # blocks strictly below the diagonal need no mask: max k_pos <= min q_pos
-        n_full = jnp.minimum(last_blk, (q_blk_idx * bq + 1) // block_k)
+        n_full, last_blk = causal_k_tiles(q_blk_idx, bq, block_k)
     else:
-        last_blk = num_k_blocks
-        n_full = num_k_blocks
+        n_full = last_blk = seq_len // block_k
 
-    m0 = jnp.full((bq, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    m0 = jnp.full((1, bq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((1, bq), jnp.float32)
+    acc0 = jnp.zeros((d, bq), jnp.float32)
 
     def make_body(masked):
         def body(kb, carry):
             m, l, acc = carry
-            k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)  # [bq, bk] base-2
+            keys = pl.ds(kb * block_k, block_k)
+            s = jax.lax.dot_general(k_ref[keys, :], q, _NT,
+                                    preferred_element_type=jnp.float32)  # [bk, bq] base-2
             if has_bias:
-                s = s + bias_ref[:, pl.ds(kb * block_k, block_k)] * LOG2E
+                s = s + (bias_ref[:, keys] * LOG2E).reshape(block_k, 1)
             if masked or rate > 0:
-                q_pos = q_blk_idx * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-                if has_seed:
-                    q_glob, k_glob = map_q(q_pos), map_k(k_pos)
-                else:
-                    q_glob, k_glob = q_pos, k_pos
+                q_glob, k_glob = _tile_positions(kb * block_k, q_blk_idx * bq,
+                                                 (block_k, bq), maps)
             if masked:
                 s = jnp.where(q_glob >= k_glob, s, DEFAULT_MASK_VALUE)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp2(s - m_new)
             alpha = jnp.exp2(m - m_new)
             # the normalizer uses the UNdropped probabilities (torch dropout(softmax(s)))
-            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
             if rate > 0:
                 bits = _dropout_bits(seed_u32, bh_u32, q_glob, k_glob)
-                keep = (bits >= jnp.uint32(threshold)).astype(jnp.float32) * inv_keep
-                p_eff = p * keep
-            else:
-                p_eff = p
-            acc_new = acc * alpha + jnp.dot(p_eff.astype(v_blk.dtype), v_blk,
-                                            preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+                p = p * ((bits >= jnp.uint32(threshold)).astype(jnp.float32) * inv_keep)
+            pv = jax.lax.dot_general(v_ref[keys, :], p.astype(v_ref.dtype), _TN,
+                                     preferred_element_type=jnp.float32)     # [d, bq]
+            return m_new, l_new, acc * alpha + pv
         return body
 
     carry = jax.lax.fori_loop(0, n_full, make_body(False), (m0, l0, acc0))
@@ -212,9 +254,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
         carry = jax.lax.fori_loop(n_full, last_blk, make_body(True), carry)
     m, l, acc = carry
     l = jnp.maximum(l, 1e-30)
-    o_ref[...] = (acc / l).astype(o_ref.dtype)
+    o_ref[...] = (acc / l).T.astype(o_ref.dtype)
     # stored LSE stays in natural-log units (m is base-2)
-    lse_ref[...] = (m / LOG2E + jnp.log(l)).reshape(1, bq)
+    lse_ref[...] = m / LOG2E + jnp.log(l)
 
 
 def _is_segmented(seed) -> bool:
@@ -269,6 +311,43 @@ def _per_shard(kernel_fn, arrays, seed, bias, rate):
     return shard_over_mesh(local, operands, dims)
 
 
+# ---------------------------------------------------------------------------
+# VMEM budget
+# ---------------------------------------------------------------------------
+# Each kernel tells the compiler how much VMEM its blocks and working tiles take,
+# computed from T, D and the tile sizes; below the compiler's own default nothing
+# changes. A v5e core has 128 MiB, of which the compiler grants 16 MiB unasked.
+
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_limit(need):
+    return max(_DEFAULT_SCOPED_VMEM, need + need // 4)
+
+
+def _padded(rows, D, itemsize):
+    """Bytes of a [rows, D] block in VMEM: the head dimension pads to the 128 lanes."""
+    return rows * (-(-D // 128) * 128) * itemsize
+
+
+def _fwd_vmem_bytes(T, D, block_q, block_k, itemsize):
+    """K and V whole (two pipeline buffers each), the q and out tiles and the lse row,
+    and four float32 tiles of working set (s, p and the mask/dropout temporaries)."""
+    return (2 * 2 * _padded(T, D, itemsize) + 2 * 2 * _padded(block_q, D, itemsize)
+            + 2 * 8 * block_q * 4 + 4 * block_q * block_k * 4)
+
+
+def _bwd_vmem_bytes(T, D, block_q, block_k, itemsize):
+    """q and dO whole (two pipeline buffers each), the lse and delta rows (a [1, T]
+    float32 block pads to 8 sublanes), the k/v/dk/dv tiles, the dq block and its
+    float32 accumulator [D, T], and six float32 tiles of working set (s, p, dp, ds and
+    the mask/dropout temporaries)."""
+    return (2 * 2 * _padded(T, D, itemsize) + 2 * 2 * 8 * T * 4
+            + 4 * 2 * _padded(block_k, D, itemsize)
+            + 2 * _padded(T, D, itemsize) + max(D, 8) * T * 4
+            + 6 * block_q * block_k * 4)
+
+
 def _flash_fwd(q, k, v, seed, bias, sm_scale, causal, rate, block_q, block_k, interpret):
     return _per_shard(
         functools.partial(_flash_fwd_local, sm_scale=sm_scale, causal=causal, rate=rate,
@@ -307,6 +386,10 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
             # tileable, so the per-row scalar rides in a (1, block_q) lane layout
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_vmem_limit(_fwd_vmem_bytes(T, D, block_q, block_k,
+                                                         q.dtype.itemsize))),
         interpret=interpret,
         name="ds_flash_fwd",
     )
@@ -316,149 +399,97 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, threshold,
-                   has_seed, seg):
-    i = 0
-    seed_ref = bias_ref = None
-    if has_seed:
-        seed_ref = refs[i]
-        i += 1
-    if has_bias:
-        bias_ref = refs[i]
-        i += 1
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs[i:]
-    bq, d = q_ref.shape
-    q_blk_idx = pl.program_id(1)
-    # base-2 softmax with sm_scale*log2e folded into q (see _fwd_kernel)
-    q = (q_ref[...].astype(jnp.float32) * (sm_scale * LOG2E)).astype(q_ref.dtype)
-    do = do_ref[...]
-    lse2 = lse_ref[...].reshape(bq, 1) * LOG2E  # natural -> base-2
-    delta = delta_ref[...].reshape(bq, 1)
-    if has_seed:
-        seed_u32, map_q, map_k = _read_seed_ref(seed_ref, seg)
-        bh_u32 = pl.program_id(0).astype(jnp.uint32)
-    if rate > 0:
-        inv_keep = 1.0 / (1.0 - rate)
+def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, threshold,
+                has_seed, seg):
+    """One pass over the tiles of one (batch, head): grid cell ``(b, j)`` holds k-tile
+    ``j`` and walks the q-tiles that see it; every visit computes the tile once (five
+    matmuls, one ``exp2``) and feeds all three gradients. dK and dV of the k-tile ride
+    the loop; dQ accumulates TRANSPOSED in the float32 scratch ``dq_acc`` [D, T] across
+    the cells of one ``b`` (the k axis of the grid is sequential) and is turned and
+    rounded once, after the last k-tile. No partial dQ goes through HBM.
 
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-    if causal:
-        last_blk = jnp.minimum(num_k_blocks, (q_blk_idx * bq + bq + block_k - 1) // block_k)
-        n_full = jnp.minimum(last_blk, (q_blk_idx * bq + 1) // block_k)
-    else:
-        last_blk = num_k_blocks
-        n_full = num_k_blocks
-
-    def make_body(masked):
-        def body(kb, dq):
-            k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-            if has_bias:
-                s = s + bias_ref[:, pl.ds(kb * block_k, block_k)] * LOG2E
-            if masked or rate > 0:
-                q_pos = q_blk_idx * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-                if has_seed:
-                    q_glob, k_glob = map_q(q_pos), map_k(k_pos)
-                else:
-                    q_glob, k_glob = q_pos, k_pos
-            if masked:
-                s = jnp.where(q_glob >= k_glob, s, DEFAULT_MASK_VALUE)
-            p = jnp.exp2(s - lse2)
-            dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-            if rate > 0:
-                bits = _dropout_bits(seed_u32, bh_u32, q_glob, k_glob)
-                dp = dp * ((bits >= jnp.uint32(threshold)).astype(jnp.float32) * inv_keep)
-            ds = p * (dp - delta)
-            return dq + jnp.dot(ds.astype(k_blk.dtype), k_blk, preferred_element_type=jnp.float32)
-        return body
-
-    dq = jax.lax.fori_loop(0, n_full, make_body(False), jnp.zeros((bq, d), jnp.float32))
-    if causal:
-        dq = jax.lax.fori_loop(n_full, last_blk, make_body(True), dq)
-    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, threshold,
-                    has_seed, seg):
-    i = 0
-    seed_ref = bias_ref = None
-    if has_seed:
-        seed_ref = refs[i]
-        i += 1
-    if has_bias:
-        bias_ref = refs[i]
-        i += 1
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs[i:]
+    The tile is computed TRANSPOSED, S^T = K.Q^T as [block_k, block_q]: ``lse`` and
+    ``delta`` are used as the [1, block_q] rows they are stored as, and dS^T is the
+    left operand of dK += dS^T.Q and the right operand of dQ^T += K^T.dS^T as it
+    stands (as P^T is of dV += P^T.dO); only the small K tile contracts its leading
+    axis."""
+    seed_ref, bias_ref, rest = _split_refs(refs, has_seed, has_bias)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dq_ref, dk_ref, dv_ref, dq_acc) = rest
     bk, d = k_ref.shape
     k_blk_idx = pl.program_id(1)
-    # base-2 softmax: fold sm_scale*log2e into K here (q stays raw in this kernel)
-    k = (k_ref[...].astype(jnp.float32) * (sm_scale * LOG2E)).astype(k_ref.dtype)
+    num_q_blocks = seq_len // block_q
+
+    @pl.when(k_blk_idx == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[...]
     v = v_ref[...]
+    if has_bias:
+        bias2 = (bias_ref[...] * LOG2E).reshape(bk, 1)     # this k-tile's keys, once a cell
     if has_seed:
-        seed_u32, map_q, map_k = _read_seed_ref(seed_ref, seg)
+        seed_u32, *maps = _read_seed_ref(seed_ref, seg)
         bh_u32 = pl.program_id(0).astype(jnp.uint32)
+    else:
+        maps = None
     if rate > 0:
         inv_keep = 1.0 / (1.0 - rate)
-
-    num_q_blocks = pl.cdiv(seq_len, block_q)
-    if causal:
-        first_blk = (k_blk_idx * bk) // block_q
-        # q blocks whose min q_pos covers this k block's max k_pos need no mask
-        full_from = jnp.minimum(num_q_blocks,
-                                ((k_blk_idx + 1) * bk - 1 + block_q - 1) // block_q)
-    else:
-        first_blk = 0
-        full_from = 0
 
     def make_body(masked):
         def body(qb, carry):
             dk, dv = carry
-            q_blk = q_ref[pl.ds(qb * block_q, block_q), :]
-            do_blk = do_ref[pl.ds(qb * block_q, block_q), :]
-            lse2_blk = lse_ref[0, pl.ds(qb * block_q, block_q)].reshape(block_q, 1) * LOG2E
-            delta_blk = delta_ref[0, pl.ds(qb * block_q, block_q)].reshape(block_q, 1)
-            s = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32)  # [bq, bk] base-2
+            rows = pl.ds(qb * block_q, block_q)
+            q_blk = q_ref[rows, :]
+            do_blk = do_ref[rows, :]
+            lse2 = lse_ref[:, rows] * LOG2E                # [1, bq] natural -> base-2
+            delta = delta_ref[:, rows]
+            # base-2 softmax with sm_scale*log2e folded into q, exactly as the forward
+            # rounds it: the scores here are the scores ``lse`` was taken from. dK = dS^T.Q
+            # takes q as it came.
+            q_s = (q_blk.astype(jnp.float32) * (sm_scale * LOG2E)).astype(q_blk.dtype)
+            s = jax.lax.dot_general(k, q_s, _NT, preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do_blk, _NT, preferred_element_type=jnp.float32)
             if has_bias:
-                s = s + bias_ref[...] * LOG2E  # [1, bk]: this k-block's bias tile
+                s = s + bias2
             if masked or rate > 0:
-                q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-                k_pos = k_blk_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-                if has_seed:
-                    q_glob, k_glob = map_q(q_pos), map_k(k_pos)
-                else:
-                    q_glob, k_glob = q_pos, k_pos
+                q_glob, k_glob = _tile_positions(k_blk_idx * bk, qb * block_q,
+                                                 (bk, block_q), maps)
             if masked:
                 s = jnp.where(q_glob >= k_glob, s, DEFAULT_MASK_VALUE)
-            p = jnp.exp2(s - lse2_blk)
+            p = jnp.exp2(s - lse2)
             if rate > 0:
                 bits = _dropout_bits(seed_u32, bh_u32, q_glob, k_glob)
                 keep = (bits >= jnp.uint32(threshold)).astype(jnp.float32) * inv_keep
                 p_drop = p * keep
+                dp = dp * keep
             else:
                 p_drop = p
-            dv_new = dv + jnp.dot(p_drop.T.astype(do_blk.dtype), do_blk,
-                                  preferred_element_type=jnp.float32)
-            dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
-            if rate > 0:
-                dp = dp * keep
-            ds = p * (dp - delta_blk)
-            dk_new = dk + jnp.dot(ds.T.astype(q_blk.dtype), q_blk,
-                                  preferred_element_type=jnp.float32)
-            return dk_new, dv_new
+            ds = (p * (dp - delta)).astype(q_blk.dtype)
+            p_drop = p_drop.astype(do_blk.dtype)
+            dv = dv + jnp.dot(p_drop, do_blk, preferred_element_type=jnp.float32)
+            dk = dk + jnp.dot(ds, q_blk, preferred_element_type=jnp.float32)
+            dq_acc[:, rows] += jax.lax.dot_general(k, ds, _TN,
+                                                   preferred_element_type=jnp.float32)
+            return dk, dv
         return body
 
     init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32))
     if causal:
+        first_blk, full_from = causal_q_tiles(k_blk_idx, block_q, bk)
         carry = jax.lax.fori_loop(first_blk, full_from, make_body(True), init)
         dk, dv = jax.lax.fori_loop(full_from, num_q_blocks, make_body(False), carry)
     else:
         dk, dv = jax.lax.fori_loop(0, num_q_blocks, make_body(False), init)
     dk_ref[...] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(k_blk_idx == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...].T * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, interpret,
@@ -488,63 +519,33 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     do3 = do.reshape(B * H, T, D)
     lse3 = lse.reshape(B * H, 1, T)
     delta3 = delta.reshape(B * H, 1, T)
-    has_bias = bias is not None
 
-    aux, aux_specs = _aux_operands(seed, bias, B, H, T, rate)
-    call = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=block_k, seq_len=T, has_bias=has_bias, rate=rate,
-                          threshold=_keep_threshold(rate),
-                          has_seed=seed is not None, seg=_is_segmented(seed)),
-        grid=(B * H, pl.cdiv(T, block_q)),
-        in_specs=aux_specs + [
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        interpret=interpret,
-        name="ds_flash_bwd_dq",
-    )
-    with jax.named_scope("ds_flash_bwd_dq"):
-        dq = call(*aux, q3, k3, v3, do3, lse3, delta3)
-
-    # the dkv grid iterates k-blocks, so its bias operand is tiled per k-block
-    aux2, aux2_specs = _aux_operands(
+    # the grid walks k-tiles, so the bias operand is tiled per k-tile
+    aux, aux_specs = _aux_operands(
         seed, bias, B, H, T, rate,
-        block_k_map=(block_k, lambda b, i, H=H: (b // H, 0, i)))
+        block_k_map=(block_k, lambda b, j, H=H: (b // H, 0, j)))
+    whole = pl.BlockSpec((None, T, D), lambda b, j: (b, 0, 0))
+    row = pl.BlockSpec((None, 1, T), lambda b, j: (b, 0, 0))
+    tile = pl.BlockSpec((None, block_k, D), lambda b, j: (b, j, 0))
     call = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, seq_len=T, has_bias=has_bias, rate=rate,
-                          threshold=_keep_threshold(rate),
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
+                          block_q=block_q, seq_len=T, has_bias=bias is not None,
+                          rate=rate, threshold=_keep_threshold(rate),
                           has_seed=seed is not None, seg=_is_segmented(seed)),
-        grid=(B * H, pl.cdiv(T, block_k)),
-        in_specs=aux2_specs + [
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 1, T), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        ],
+        grid=(B * H, T // block_k),
+        in_specs=aux_specs + [whole, tile, tile, whole, row, row],
+        out_specs=[whole, tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, D), q.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((D, T), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(_bwd_vmem_bytes(T, D, block_q, block_k,
+                                                         q.dtype.itemsize))),
         interpret=interpret,
         name="ds_flash_bwd_dkv",
     )
     with jax.named_scope("ds_flash_bwd_dkv"):
-        dk, dv = call(*aux2, q3, k3, v3, do3, lse3, delta3)
-
+        dq, dk, dv = call(*aux, q3, k3, v3, do3, lse3, delta3)
     return dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D)
 
 
@@ -565,18 +566,20 @@ def _resolve(q, sm_scale, block_q, block_k, causal, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if block_q is None or block_k is None:
-        # Measured on v5e before PR 1 (slope-timed, tests/perf/flash_sweep):
-        # non-causal T=4096: (1024,1024) 101.6 TF/s vs (256,512) 56.2 — the bigger
-        # q tile amortizes per-cell K/V residency; T=8192: (512,1024) 67.6 vs 58.9.
-        # Causal prefers small q blocks (diagonal work balance): (256,512).
-        if causal or T < 4096:
-            dq_, dk_ = 256, 512
-        elif T < 8192:
-            dq_, dk_ = 1024, 1024
-        else:
-            dq_, dk_ = 512, 1024
-        block_q = block_q or dq_
-        block_k = block_k or dk_
+        # Square tiles, so a causal diagonal tile holds nothing wholly masked. Sizes from
+        # tests/perf/flash_sweep.py on a v5e (PR 25; device ms a call, D = 64, bf16,
+        # forward / backward):
+        #   [4, 25, 1024] causal: 128 1.37 / 1.60   256 0.59 / 0.70   512 0.32 / 0.71
+        #                         1024 0.37 / 0.75 (one tile, half of it masked)
+        #   [1, 16, 4096] causal: 512 0.54 / 1.14   1024 0.53 / 1.17
+        #   [1, 16, 8192] causal: 512 1.99 / 4.14   1024 1.84 / 4.03
+        #   [1, 16, 4096] full:   512 0.94 / 1.86   1024 0.80 / 1.73
+        #   [1, 16, 8192] full:   512 3.70 / 7.34   1024 3.14 / 6.81
+        # A tile under 512 leaves the MXU waiting on the loop; past 512 a causal call
+        # pays for the masked half of ever larger diagonal tiles until T is long.
+        side = 1024 if not causal or T >= 8192 else 512
+        block_q = block_q or side
+        block_k = block_k or side
 
     def fit(b):
         # largest power-of-two-reduced block that divides the sequence length

@@ -1,10 +1,29 @@
-"""Flash-attention block-size sweep on the real TPU (slope-timed; see devtime.py).
+"""Flash-attention tile sweep on the chip: device milliseconds a call of each kernel,
+forward and backward apart, read from a profiler trace by the kernels' own names.
 
-    python tests/perf/flash_sweep.py [--bwd]
+    python tests/perf/flash_sweep.py [--rows cell,long,other] [--picked] [--out chiprun_out/flash_sweep.jsonl]
+
+Run it from the root of a checkout; from the root of another checkout (a parent
+unpacked beside this one) it measures that tree's kernels with the same rows:
+
+    (cd _parent && python ../tests/perf/flash_sweep.py --out ../chiprun_out/parent.jsonl)
+
+A row is a shape [B, H, T, D] in bf16, causal or not, and a list of (block_q, block_k);
+``None`` is what ``_resolve`` picks. The share of the roofline is the required
+operations (4.B.H.T^2.D a forward, half of it causal; twice that a backward, as
+``benchmarks/flops.py`` counts) over 197 TF/s over the measured time.
 """
 
+import argparse
+import collections
+import glob
+import importlib
+import json
 import os
+import re
+import shutil
 import sys
+import tempfile
 
 import numpy as np
 
@@ -12,39 +31,118 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, ".")
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 
-from devtime import timeit_slope  # noqa: E402
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
+PEAK_FLOPS = 197e12     # TPU v5e, bf16
+SQUARE = [(128, 128), (256, 256), (512, 512)]
+ROWS = {
+    # the benchmark's cell: GPT-2 XL heads, 4 x 1024 tokens a step
+    "cell": [((4, 25, 1024, 64), True,
+              [None] + SQUARE + [(1024, 1024), (256, 512), (512, 256)])],
+    # the long sequences the tiles were first chosen at (and the chunks of T > 8192)
+    "long": [((1, 16, T, 64), causal, [None, (256, 512), (512, 512), (512, 1024), (1024, 1024)])
+             for T in (4096, 8192) for causal in (True, False)],
+    # BERT-large (non-causal, every tile full), GPT-class at T = 2048, head width 128
+    "other": [((8, 16, 512, 64), False, [None] + SQUARE[1:] + [(256, 512)]),
+              ((4, 16, 2048, 64), True, [None] + SQUARE[1:] + [(1024, 1024), (256, 512)]),
+              ((2, 8, 2048, 128), True, [None] + SQUARE[1:] + [(1024, 1024)])],
+}
+
+
+def kernel_ms(fn, args, calls=8):
+    """{kernel name: device ms a call} for the Pallas kernels ``fn(*args)`` runs."""
+    from jax.profiler import ProfileData
+    f = jax.jit(fn)
+    jax.block_until_ready(f(*args))
+    trace_dir = tempfile.mkdtemp(prefix="flash_sweep_")
+    try:
+        jax.profiler.start_trace(trace_dir)
+        out = None
+        for _ in range(calls):
+            out = f(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        path = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        seconds, calls_seen, others = (collections.Counter() for _ in range(3))
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/device:TPU:"):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for e in line.events:
+                    m = re.search(r"ds_flash_\w+?(?=\.\d+|$|[^\w])", e.name)
+                    if m:
+                        seconds[m.group(0)] += e.duration_ns * 1e-9
+                        calls_seen[m.group(0)] += 1
+                    else:
+                        others[e.name] += 1
+        if not seconds or any(n % calls for n in calls_seen.values()):
+            raise RuntimeError(
+                f"the trace names no ds_flash_* kernel {calls} times over: {dict(calls_seen)}; "
+                f"it holds {others.most_common(6)}")
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return {name: 1e3 * s / calls for name, s in seconds.items()}
+
+
+def sweep_row(shape, causal, tiles, emit, tag="", passes=("fwd", "bwd")):
+    B, H, T, D = shape
+    rng = np.random.default_rng(0)
+    q, k, v, do = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(4))
+    need = 4.0 * B * H * T * T * D * (0.5 if causal else 1.0)
+    for tile in tiles:
+        sm_scale, bq, bk, _ = fa._resolve(q, None, *(tile or (None, None)), causal, False)
+        common = dict(shape=list(shape), causal=causal, block_q=bq, block_k=bk,
+                      picked=tile is None, tag=tag)
+        try:
+            fwd = lambda q, k, v: fa._flash_fwd(q, k, v, None, None, sm_scale, causal, 0.0,
+                                                bq, bk, False)
+            if "fwd" in passes:
+                ms = kernel_ms(fwd, (q, k, v))
+                total = sum(ms.values())
+                emit(dict(common, pass_="fwd", ms=total, kernels=ms,
+                          roofline=100 * need / PEAK_FLOPS / (total * 1e-3)))
+            if "bwd" not in passes:
+                continue
+            out, lse = jax.jit(fwd)(q, k, v)
+            delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+            bwd = lambda q, k, v, do, lse, delta: fa._flash_bwd_local(
+                q, k, v, do, lse, delta, None, None, sm_scale=sm_scale, causal=causal,
+                rate=0.0, block_q=bq, block_k=bk, interpret=False)
+            ms = kernel_ms(bwd, (q, k, v, do, lse, delta))
+            total = sum(ms.values())
+            emit(dict(common, pass_="bwd", ms=total, kernels=ms,
+                      roofline=100 * 2 * need / PEAK_FLOPS / (total * 1e-3)))
+        except Exception as e:  # a tile the compiler refuses is a row of the table too
+            emit(dict(common, pass_="error", error=f"{type(e).__name__}: {str(e)[:300]}"))
 
 
 def main():
-    do_bwd = "--bwd" in sys.argv
-    B, H, D = 1, 16, 64
-    rng = np.random.default_rng(0)
-    for T, causal in ((4096, False), (4096, True), (8192, False), (8192, True)):
-        q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.bfloat16)
-        flops = 4.0 * B * H * T * T * D * (0.5 if causal else 1.0)
-        for bq, bk in ((None, None), (256, 512), (512, 1024), (1024, 1024)):
-            label = "auto" if bq is None else f"bq={bq} bk={bk}"
-            try:
-                dt = timeit_slope(lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                    q, k, v, causal=causal, block_q=bq, block_k=bk), q, k, v,
-                    n1=20, n2=100)
-                print(f"T={T} causal={int(causal)} {label}: {dt*1e3:7.3f} ms "
-                      f"{flops/dt/1e12:6.1f} TF/s")
-                if do_bwd:
-                    g = lambda q, k, v, bq=bq, bk=bk: jax.grad(
-                        lambda q: jnp.sum(flash_attention(
-                            q, k, v, causal=causal, block_q=bq,
-                            block_k=bk).astype(jnp.float32)))(q)
-                    dt = timeit_slope(g, q, k, v, n1=5, n2=30)
-                    print(f"T={T} causal={int(causal)} {label} +bwd: {dt*1e3:7.3f} ms "
-                          f"{3.5*flops/dt/1e12:6.1f} TF/s")
-            except Exception as e:
-                print(f"T={T} causal={int(causal)} {label}: {type(e).__name__}: {e}")
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", default="cell,long,other")
+    ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
+    ap.add_argument("--picked", action="store_true", help="only the tiles _resolve picks")
+    args = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        sys.exit("flash_sweep.py measures the compiled kernels: it needs a TPU")
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        def emit(rec):
+            f.write(json.dumps(rec) + "\n")
+            f.flush()
+            if rec["pass_"] == "error":
+                print(f"{rec['shape']} causal={int(rec['causal'])} bq={rec['block_q']} "
+                      f"bk={rec['block_k']}: {rec['error']}", flush=True)
+                return
+            print(f"{rec['shape']} causal={int(rec['causal'])} {rec['pass_']} "
+                  f"bq={rec['block_q']:5d} bk={rec['block_k']:5d}{' *' if rec['picked'] else '  '} "
+                  f"{rec['ms']:8.4f} ms {rec['roofline']:6.2f} % {rec['tag']} "
+                  + " ".join(f"{n}={t:.4f}" for n, t in sorted(rec["kernels"].items())),
+                  flush=True)
+        for name in args.rows.split(","):
+            for shape, causal, tiles in ROWS[name]:
+                sweep_row(shape, causal, [None] if args.picked else tiles, emit)
 
 
 if __name__ == "__main__":

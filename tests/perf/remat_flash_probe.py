@@ -1,8 +1,8 @@
 """Which remat policy avoids replaying the flash fwd kernel in backward?
 
 Compiles value_and_grad of a 2-layer rematted GPT-2 on the TPU and counts
-pallas custom-calls in the HLO, classified by kernel (fwd vs bwd_dq vs
-bwd_dkv). A policy that saves the kernel's (out, lse) should show ONE fwd
+pallas custom-calls in the HLO, classified by kernel (fwd vs the one-pass
+bwd). A policy that saves the kernel's (out, lse) should show ONE fwd
 kernel per layer; dots shows TWO (one fwd + one backward replay).
 
 Usage: python tests/perf/remat_flash_probe.py [policy ...]
@@ -31,19 +31,17 @@ def count_kernels(policy):
     txt = f.lower(params).compile().as_text()
     calls = [c for c in re.findall(r'.*custom-call[^\n]*', txt)
              if "tpu_custom_call" in c]
-    # classify by output signature: fwd = (bf16 out, f32 lse) pair; dkv = (bf16,
-    # bf16) pair; dq = single bf16. A fwd call inside a rematted_computation is
-    # the backward-pass REPLAY the policy is supposed to eliminate.
+    # classify by output signature: fwd = (bf16 out, f32 lse) pair; bwd = the (dq, dk,
+    # dv) bf16 triple. A fwd call inside a rematted_computation is the
+    # backward-pass REPLAY the policy is supposed to eliminate.
     def sig(c):
         m = re.search(r"= (\(.*?\)|\S+) custom-call", c)
         return tuple(re.findall(r"(bf16|f32)\[", m.group(1))) if m else ()
     fwd = [c for c in calls if sig(c) == ("bf16", "f32")]
-    dkv = [c for c in calls if sig(c) == ("bf16", "bf16")]
-    dq = [c for c in calls if sig(c) == ("bf16",)]
+    bwd = [c for c in calls if sig(c) == ("bf16", "bf16", "bf16")]
     replay = [c for c in fwd if "remat" in c]
-    return {"fwd_total": len(fwd), "fwd_replayed": len(replay),
-            "bwd_dq": len(dq), "bwd_dkv": len(dkv),
-            "unclassified": len(calls) - len(fwd) - len(dkv) - len(dq)}
+    return {"fwd_total": len(fwd), "fwd_replayed": len(replay), "bwd": len(bwd),
+            "unclassified": len(calls) - len(fwd) - len(bwd)}
 
 
 if __name__ == "__main__":

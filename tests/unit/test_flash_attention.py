@@ -29,15 +29,23 @@ def test_forward_parity(causal, shape, dtype, fwd_tol, bwd_tol):
                                rtol=fwd_tol, atol=fwd_tol)
 
 
+# the one-pass backward at 1, 2 and 4 tiles a side (dQ accumulates across the k-tiles of
+# the grid, dK/dV across the q-tiles of the loop), and at the benchmark cell's T = 1024,
+# D = 64 with the tiles _resolve picks
+_BWD_CASES = [((2, 3, 256, 64), 256), ((2, 3, 256, 64), 128), ((2, 3, 256, 64), 64),
+              ((1, 2, 1024, 64), None)]
+
+
 @pytest.mark.parametrize("dtype,fwd_tol,bwd_tol", _DTYPES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_backward_parity(causal, dtype, fwd_tol, bwd_tol):
-    shape = (2, 3, 256, 64)
+@pytest.mark.parametrize("shape,block", _BWD_CASES,
+                         ids=[f"T{s[2]}-block{b}" for s, b in _BWD_CASES])
+def test_backward_parity(causal, shape, block, dtype, fwd_tol, bwd_tol):
     q, k, v = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
                for kk in jax.random.split(jax.random.PRNGKey(0), 3))
     g = jax.random.normal(jax.random.PRNGKey(9), shape, jnp.float32).astype(dtype)
-    gf = jax.grad(lambda q, k, v: jnp.sum((flash_attention(q, k, v, causal, None, 128, 128, True)
-                                           * g).astype(jnp.float32)),
+    gf = jax.grad(lambda q, k, v: jnp.sum((flash_attention(q, k, v, causal, None, block, block,
+                                                           True) * g).astype(jnp.float32)),
                   argnums=(0, 1, 2))(q, k, v)
     f32 = (q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32))
     gd = jax.grad(lambda q, k, v: jnp.sum(dense_attention(q, k, v, causal=causal)
@@ -67,10 +75,12 @@ def test_sm_scale_override():
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("block", [128, 64])
 @pytest.mark.parametrize("causal", [False, True])
-def test_bias_mask_parity(causal):
+def test_bias_mask_parity(causal, block):
     """Additive key bias (the BERT padding mask) fused in-kernel must match the dense
-    oracle in forward and all three gradients."""
+    oracle in forward and all three gradients, at one tile and at two tiles a side (the
+    backward takes the bias a k-tile at a time)."""
     B, H, T, D = 2, 3, 128, 32
     q, k, v = (jax.random.normal(kk, (B, H, T, D), jnp.float32)
                for kk in jax.random.split(jax.random.PRNGKey(1), 3))
@@ -82,7 +92,7 @@ def test_bias_mask_parity(causal):
     bias = jnp.asarray(bias)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal, None, 128, 128, True,
+        return jnp.sum(flash_attention(q, k, v, causal, None, block, block, True,
                                        bias=bias) ** 2)
 
     def f_dense(q, k, v):
@@ -129,20 +139,106 @@ def test_dropout_parity_vs_oracle(causal):
                                    err_msg=f"d{name}")
 
 
-def test_dropout_block_shape_invariance():
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_dropout_block_shape_invariance(what):
     """The coordinate-hash mask must not depend on block configuration (this is what
-    guarantees fwd/bwd agreement when block_q != block_k)."""
+    guarantees fwd/bwd agreement when block_q != block_k): the forward's output, and the
+    gradients of the backward that regenerates the bits on its transposed tiles."""
     B, H, T, D = 1, 2, 256, 32
     q, k, v = (jax.random.normal(kk, (B, H, T, D), jnp.float32)
                for kk in jax.random.split(jax.random.PRNGKey(3), 3))
-    o1 = flash_attention(q, k, v, False, None, 64, 128, True,
-                         dropout_rate=0.1, dropout_seed=7)
-    o2 = flash_attention(q, k, v, False, None, 256, 64, True,
-                         dropout_rate=0.1, dropout_seed=7)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5, atol=1e-5)
-    o3 = flash_attention(q, k, v, False, None, 64, 128, True,
-                         dropout_rate=0.1, dropout_seed=8)
-    assert np.abs(np.asarray(o1) - np.asarray(o3)).max() > 1e-3  # seed actually matters
+
+    def run(block_q, block_k, seed):
+        attn = lambda q, k, v: flash_attention(q, k, v, False, None, block_q, block_k, True,
+                                               dropout_rate=0.1, dropout_seed=seed)
+        if what == "forward":
+            return [attn(q, k, v)]
+        return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    r1, r2, r3 = run(64, 128, 7), run(256, 64, 7), run(64, 128, 8)
+    for a, b in zip(r1, r2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(r1[0]) - np.asarray(r3[0])).max() > 1e-3  # seed actually matters
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["contiguous", "segmented"])
+def test_lse_cotangent_and_segments_backward_parity(segmented):
+    """``flash_attention_with_lse`` differentiates through BOTH outputs (the lse cotangent
+    folds into delta), causal with dropout, at two tiles a side; segmented, the local
+    sequence is two global chunks (the zigzag ring's (7,) operand) and the dropout bits
+    are those of the global coordinates."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (DEFAULT_MASK_VALUE,
+                                                          dropout_keep_reference,
+                                                          flash_attention_with_lse)
+    B, H, T, D = 1, 2, 128, 32
+    rate, seed = 0.15, 31
+    q, k, v, g = (jax.random.normal(kk, (B, H, T, D), jnp.float32)
+                  for kk in jax.random.split(jax.random.PRNGKey(5), 4))
+    g_lse = jax.random.normal(jax.random.PRNGKey(6), (B, H, T), jnp.float32)
+    if segmented:   # chunks 0 and 3 of a global sequence of 4 x 64
+        segments = (0, 192)
+        where = np.concatenate([np.arange(0, 64), np.arange(192, 256)])
+    else:
+        segments, where = None, np.arange(T)
+    keep = dropout_keep_reference(seed, B, H, 256, 256, rate)[:, :, where][:, :, :, where]
+
+    def f_flash(q, k, v):
+        out, lse = flash_attention_with_lse(q, k, v, True, None, 64, 64, True,
+                                            dropout_rate=rate, dropout_seed=seed,
+                                            q_segments=segments, k_segments=segments)
+        return jnp.sum(out * g) + jnp.sum(lse * g_lse)
+
+    def f_dense(q, k, v):
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, DEFAULT_MASK_VALUE)
+        out = dense_attention(q, k, v, causal=True, dropout_keep=keep)
+        return jnp.sum(out * g) + jnp.sum(jax.nn.logsumexp(scores, axis=-1) * g_lse)
+
+    np.testing.assert_allclose(float(f_flash(q, k, v)), float(f_dense(q, k, v)), rtol=2e-5)
+    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("blocks", [None, (256, 512), (128, 64)],
+                         ids=["picked", "256x512", "128x64"])
+@pytest.mark.parametrize("T", [128, 192, 1024, 4096, 8192])
+def test_causal_tile_schedule(T, blocks):
+    """The schedule itself, no kernel run: the forward's bounds (k-tiles of a q-tile) and
+    the backward's (q-tiles of a k-tile) visit every tile that holds an unmasked element
+    exactly once, none that holds none, and run the masked body on exactly the tiles the
+    diagonal crosses — at the tiles ``_resolve`` picks and at two uneven pairs."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (_resolve, causal_k_tiles,
+                                                          causal_q_tiles)
+    _, bq, bk, _ = _resolve(jax.ShapeDtypeStruct((1, 1, T, 64), jnp.bfloat16), None,
+                            *(blocks or (None, None)), True, True)
+    assert T % bq == 0 and T % bk == 0
+    nq, nk = T // bq, T // bk
+    q_lo, k_lo = np.arange(nq)[:, None] * bq, np.arange(nk)[None, :] * bk
+    some = q_lo + bq - 1 >= k_lo                 # largest query sees smallest key
+    every = q_lo >= k_lo + bk - 1                # smallest query sees largest key
+    want = {(i, j): not every[i, j] for i in range(nq) for j in range(nk) if some[i, j]}
+
+    forward = {}
+    for i in range(nq):
+        n_full, last = causal_k_tiles(i, bq, bk)
+        assert 0 <= n_full <= last <= nk
+        for j in range(last):
+            assert (i, j) not in forward
+            forward[i, j] = j >= n_full
+    backward = {}
+    for j in range(nk):
+        first, full_from = causal_q_tiles(j, bq, bk)
+        assert 0 <= first <= full_from <= nq
+        for i in range(first, nq):
+            assert (i, j) not in backward
+            backward[i, j] = i < full_from
+    assert forward == want
+    assert backward == want
+    if blocks is None:      # the picked tiles are square: only the diagonal is masked
+        assert bq == bk and sum(want.values()) == nq
 
 
 def test_transformer_layer_masked_dropout_uses_flash(monkeypatch):

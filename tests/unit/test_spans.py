@@ -202,9 +202,10 @@ def test_three_steps_give_three_roots_with_their_children():
     grad_ops = catalog["loss_and_grad"]["ops"]
     assert catalog["loss_and_grad"]["module"].startswith("jit_")
     paths = " ".join(grad_ops.values())
-    for scope in ("ds_embed", "ds_attn", "ds_mlp", "ds_loss", "ds_flash_fwd", "ds_flash_bwd_dq",
-                  "ds_flash_bwd_dkv", "transpose(jvp(ds_mlp))"):
+    for scope in ("ds_embed", "ds_attn", "ds_mlp", "ds_loss", "ds_flash_fwd", "ds_flash_bwd_dkv",
+                  "transpose(jvp(ds_mlp))"):
         assert scope in paths, scope
+    assert "ds_flash_bwd_dq" not in paths     # the backward is one kernel, one pass
     assert "ds_apply_update" in " ".join(catalog["apply_update"]["ops"].values())
     assert rec.programs(eid) is not catalog and rec.programs(eid) == catalog   # kept, not rebuilt
     # the engine holds its programs and the recorder only refers to them: they go with it
