@@ -20,6 +20,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from .layers import chunked_cross_entropy, loss_chunk_for
+
 
 @dataclass
 class GPT2Config:
@@ -490,40 +492,10 @@ class GPT2Model:
     def logits(self, params, tokens, rng=None):
         x, _ = self._backbone(params, tokens, rng=rng)
         # tied LM head: logits = x @ wte.T, contracted without materializing the
-        # transposed table (153 MB HBM at 1.5B — see _chunked_ce)
+        # transposed table (153 MB HBM at 1.5B — see layers.chunked_cross_entropy)
         with jax.named_scope("ds_loss"):
             return jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
                               preferred_element_type=jnp.float32)
-
-    def _chunked_ce(self, x, wte, labels, chunk):
-        """Fused LM-head + softmax cross-entropy, scanned over sequence chunks so the
-        (B, T, vocab) fp32 logits tensor never materializes — at GPT-2 vocab (50k) full
-        logits for a 16×1024 batch are 3.3 GB and dominate HBM. The rematted scan body
-        recomputes each chunk's logits in backward from the (tiny) hidden states."""
-        B, T, H = x.shape
-        n = T // chunk
-        xs = x.reshape(B, n, chunk, H).swapaxes(0, 1)     # (n, B, C, H)
-        ls = labels.reshape(B, n, chunk).swapaxes(0, 1)   # (n, B, C)
-        w = wte.astype(x.dtype)                           # (V, H)
-
-        def body(tot, xc_lc):
-            xc, lc = xc_lc
-            # contract against the UNtransposed table (dot_general picks the dim):
-            # a materialized wte.T costs a 153 MB HBM temp at GPT-2 1.5B — measured
-            # as an AllocateBuffer in the fused-step OOM breakdown
-            logits = jnp.einsum("bch,vh->bcv", xc, w,
-                                preferred_element_type=jnp.float32)  # (B, C, V)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            valid = (lc >= 0).astype(jnp.float32)  # < 0 = ignored (BERT's -100)
-            gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[..., None],
-                                       axis=-1)[..., 0]
-            return (tot[0] + jnp.sum((lse - gold) * valid),
-                    tot[1] + jnp.sum(valid)), None
-
-        (total, n_valid), _ = jax.lax.scan(
-            jax.checkpoint(body),
-            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), (xs, ls))
-        return total / jnp.maximum(n_valid, 1.0)
 
     def apply_parts(self, params, tokens, labels, rng=None):
         """``(ce_mean, weighted_aux)`` — the two training-loss components kept
@@ -539,9 +511,9 @@ class GPT2Model:
             T = x.shape[1]
             if c.loss_chunk:
                 # largest divisor of T not exceeding loss_chunk (static shapes for XLA)
-                chunk = next(cc for cc in range(min(c.loss_chunk, T), 0, -1) if T % cc == 0)
+                chunk = loss_chunk_for(T, c.loss_chunk)
                 if chunk < T:
-                    return self._chunked_ce(x, params["wte"], labels, chunk), aux
+                    return chunked_cross_entropy(x, params["wte"], labels, chunk), aux
             logits = jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
                                 preferred_element_type=jnp.float32)
             logp = jax.nn.log_softmax(logits, axis=-1)

@@ -36,6 +36,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import MODEL_AXIS
@@ -318,3 +319,219 @@ def moe_apply_sharded(layer: MoELayer, mesh: Mesh, params, x,
     fn = jax.shard_map(local, mesh=mesh, in_specs=(pspecs, x_spec),
                        out_specs=(x_spec, P()), check_vma=False)
     return fn(jax.device_put(params, shardings), x)
+
+
+# ===================================================================== dropless top-k
+# The layer OLMoE (and the expert models queued behind it) trains with: softmax routing
+# in float32, top-k without capacity, assignments sorted by expert, one grouped matmul
+# over the experts. Nothing above this line is called from here.
+
+@jax.custom_vjp
+def _take_rows(x, tok, inverse):
+    """Dispatch: ``xs[m] = x[tok[m]]`` for the ``n * k`` sorted assignments. ``inverse``
+    ``[n, k]`` is the row that holds token n's j-th assignment, so that the cotangent is
+    one row gather and a sum over k, never a scatter."""
+    return x[tok]
+
+
+def _take_rows_fwd(x, tok, inverse):
+    return x[tok], inverse
+
+
+def _take_rows_bwd(inverse, dxs):
+    n, k = inverse.shape
+    dx = jnp.sum(dxs[inverse.reshape(-1)].reshape(n, k, -1).astype(jnp.float32), axis=1)
+    return dx.astype(dxs.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, weights, inverse, order):
+    """Combine: ``y[n] = sum_j weights[n, j] * ys[inverse[n, j]]``; ``order`` ``[n * k]`` is
+    the flat ``n * k + j`` of each sorted row, for the cotangent's one gather."""
+    return _combine_rows_fwd(ys, weights, inverse, order)[0]
+
+
+def _combine_rows_fwd(ys, weights, inverse, order):
+    n, k = inverse.shape
+    # each token's k expert outputs side by side: what the backward keeps, not ``ys``
+    mine = checkpoint_name(ys[inverse.reshape(-1)].reshape(n, k, -1), "ds_moe_out")
+    y = jnp.einsum("nkh,nk->nh", mine.astype(jnp.float32), weights)
+    return y.astype(ys.dtype), (mine, weights, order)
+
+
+def _combine_rows_bwd(res, dy):
+    mine, weights, order = res
+    dyf = dy.astype(jnp.float32)
+    spread = (dyf[:, None, :] * weights[:, :, None]).astype(mine.dtype)       # [n, k, H]
+    dys = spread.reshape(-1, spread.shape[-1])[order]
+    dw = jnp.einsum("nkh,nh->nk", mine.astype(jnp.float32), dyf).astype(weights.dtype)
+    return dys, dw, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+# megablox tiles (rows, contraction, columns), picked on the chip at OLMoE's per-chip
+# shapes (PERF.md, PR 26): 170 TFLOP/s forward where (512, 512, 1024) gives 155 and 136
+GMM_TILES = (512, 1024, 1024)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``out[m] = lhs[m] @ rhs[g(m)]`` for rows sorted by group: ``lhs [M, K]``,
+    ``rhs [G, K, N]``, ``group_sizes [G]`` summing to ``M``. On the TPU this is JAX's
+    megablox kernel (``jax.experimental.pallas.ops.tpu.megablox``; its backward is the
+    same kernel and its transposed sibling), elsewhere ``lax.ragged_dot``, which XLA's CPU
+    backend runs and whose TPU lowering reached 55 % of megablox's rate on the chip."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    tiles = tuple(min(t, d) for t, d in zip(GMM_TILES, (lhs.shape[0],) + rhs.shape[1:]))
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiles)
+
+
+class DroplessMoE:
+    """Top-k mixture of SiLU-gated experts that drops nothing.
+
+    ``apply(params, x [B, T, H]) -> (y, aux, stats)``. The router runs in float32 at
+    full precision; the ``k`` largest probabilities weigh their experts as they are
+    (``norm_topk_prob`` renormalises them). A chip sorts its own tokens' assignments by
+    expert and each expert's rows go through one grouped matmul (gate and up in one
+    product, then down): the buffer holds exactly ``n * k`` rows however the router
+    leans, so there is no capacity, no bound and nothing that could fail to fit. ``aux``
+    is the load-balancing term ``E * sum_e f_e * P_e`` over the global batch, with
+    ``f_e`` the share of assignments expert e received and ``P_e`` its mean probability.
+
+    Across chips the experts are STORED split and GATHERED for use, as ZeRO-3 does with a
+    parameter: where the context mesh's ``data`` axis (the engine traces the model under
+    its own) has several devices that divide the experts, each chip owns ``E / ep`` of
+    them, master copy and optimizer state with them; a layer all-gathers its bf16 expert
+    weights for the forward pass and again for the backward, every chip computes every
+    expert on its own tokens, and the expert gradients are summed and scattered back to
+    their owners, complete there and not averaged. Tokens never cross the chips: this is
+    not expert parallelism by a token all-to-all. That needs a static bound on what a
+    chip may receive, and a router at initialisation sends most tokens to the same few
+    experts (measured at OLMoE's widths, PERF.md PR 26: one chip had 2.19 times its even
+    share), so a bound that never drops is the worst case, ``ep`` times the buffers. The
+    price is wire traffic that grows with the parameters and not with the tokens
+    (PERF.md section 7: the token exchange is still to be measured on the chip).
+
+    ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
+    mean, over all chips).
+    """
+
+    def __init__(self, hidden, ffn_dim, num_experts, top_k, norm_topk_prob=False):
+        self.hidden, self.ffn_dim = hidden, ffn_dim
+        self.num_experts, self.top_k = num_experts, top_k
+        self.norm_topk_prob = norm_topk_prob
+
+    # ------------------------------------------------------------------ params
+    def init(self, rng, scale=0.02):
+        kr, k1, k2 = jax.random.split(rng, 3)
+        H, F, E = self.hidden, self.ffn_dim, self.num_experts
+        return {
+            "router_w": jax.random.normal(kr, (H, E), jnp.float32) * scale,
+            # experts stacked on a leading E axis, gate and up side by side: three leaves
+            "w_gate_up": jax.random.normal(k1, (E, H, 2 * F), jnp.float32) * scale,
+            "w_down": jax.random.normal(k2, (E, F, H), jnp.float32) * scale,
+        }
+
+    @staticmethod
+    def expert_specs(axis):
+        """PartitionSpecs of the leaves: experts over ``axis``, the router whole."""
+        return {"router_w": P(), "w_gate_up": P(axis), "w_down": P(axis)}
+
+    # ------------------------------------------------------------------- apply
+    def _expert_axis(self, batch):
+        """The mesh axis the experts are split over, or None: the context mesh's ``data``
+        axis where it is automatic, larger than one, and divides experts and batch."""
+        from .mesh import DATA_AXIS
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or DATA_AXIS not in mesh.auto_axes:
+            return None, None
+        ep = mesh.shape[DATA_AXIS]
+        if ep == 1 or self.num_experts % ep or batch % ep:
+            return None, None
+        return DATA_AXIS, mesh
+
+    def apply(self, params, x, details=False):
+        """``details`` adds to ``stats`` what a comparison with a reference reads (never
+        a step): ``experts``, each token's choices ``[B, T, k]`` in ascending order, and
+        ``router_logits`` ``[B, T, E]`` float32."""
+        axis, mesh = self._expert_axis(x.shape[0])
+        if axis is None:
+            return self._local(None, details, params["router_w"], params["w_gate_up"],
+                               params["w_down"], x)
+        specs = self.expert_specs(axis)
+        stats_specs = {"load_max_over_mean": P()}
+        if details:
+            stats_specs.update(experts=P(axis), router_logits=P(axis))
+        fn = jax.shard_map(
+            lambda r, gu, d, xl: self._local(axis, details, r, gu, d, xl),
+            in_specs=(specs["router_w"], specs["w_gate_up"], specs["w_down"], P(axis)),
+            out_specs=(P(axis), P(), stats_specs),
+            axis_names=frozenset(mesh.auto_axes), check_vma=False)
+        return fn(params["router_w"], params["w_gate_up"], params["w_down"], x)
+
+    def _local(self, axis, details, router_w, w_gate_up, w_down, x):
+        """One chip's part: ``x`` its tokens, the expert arrays the experts it owns."""
+        H, E, k, F = self.hidden, self.num_experts, self.top_k, self.ffn_dim
+        shape = x.shape
+        x2 = x.reshape(-1, H)
+        n = x2.shape[0]
+
+        with jax.named_scope("ds_moe_router"):
+            logits = jnp.dot(x2.astype(jnp.float32), router_w.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            probs = jax.nn.softmax(logits, axis=-1)                       # [n, E] f32
+            weights, experts = jax.lax.top_k(probs, k)                    # [n, k]
+            if self.norm_topk_prob:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            prob_sum = jnp.sum(probs, axis=0)                             # [E]
+
+        with jax.named_scope("ds_moe_dispatch"):
+            slots = jnp.arange(n * k, dtype=jnp.int32)
+            by_expert, order = jax.lax.sort((experts.reshape(-1).astype(jnp.int32), slots),
+                                            num_keys=1, is_stable=True)
+            starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
+            group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
+            inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
+            tok = order // k
+
+        def routed(x2, weights, w_gate_up, w_down):
+            dt = x2.dtype
+            w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
+            if axis is not None:
+                with jax.named_scope("ds_moe_exchange"):
+                    w_gate_up = jax.lax.all_gather(w_gate_up, axis, tiled=True)
+                    w_down = jax.lax.all_gather(w_down, axis, tiled=True)
+            with jax.named_scope("ds_moe_dispatch"):
+                xs = _take_rows(x2, tok, inverse)                         # [n * k, H]
+            with jax.named_scope("ds_moe_experts"):
+                gate_up = checkpoint_name(
+                    grouped_matmul(xs, w_gate_up, group_sizes), "ds_moe_gate_up")
+                hidden = (jax.nn.silu(gate_up[:, :F].astype(jnp.float32))
+                          * gate_up[:, F:].astype(jnp.float32)).astype(dt)
+                ys = grouped_matmul(hidden, w_down, group_sizes)
+            with jax.named_scope("ds_moe_combine"):
+                return _combine_rows(ys, weights, inverse, order)          # [n, H]
+
+        # The backward keeps the first product's output and each token's k expert outputs.
+        # It makes the gathered rows and the gated activation again (a gather and an
+        # elementwise pass) and gathers the experts' weights again: kept, the four
+        # layers' gathered weights would be 3.2 GB a chip.
+        y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
+            "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)
+
+        counts = group_sizes.astype(jnp.float32)
+        tokens = n
+        if axis is not None:
+            counts, prob_sum = jax.lax.psum((counts, prob_sum), axis)
+            tokens = n * jax.lax.axis_size(axis)
+        aux = E * jnp.sum(jax.lax.stop_gradient(counts) / (tokens * k) * prob_sum / tokens)
+        stats = {"load_max_over_mean": jnp.max(counts) / (tokens * k / E)}
+        if details:
+            stats["experts"] = jnp.sort(experts, axis=-1).reshape(shape[:-1] + (k,))
+            stats["router_logits"] = logits.reshape(shape[:-1] + (E,))
+        return y.reshape(shape), aux, stats

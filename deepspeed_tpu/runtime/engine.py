@@ -5,7 +5,8 @@ shape is preserved — ``forward``/``backward``/``step`` with gradient-accumulat
 semantics (engine.py:843-852), ``save_checkpoint``/``load_checkpoint``, progress reporting —
 but the mechanics are functional JAX:
 
-- the model is a pure function ``model_fn(params, *inputs) -> loss`` (or ``(loss, aux)``);
+- the model is a pure function ``model_fn(params, *inputs) -> loss`` (or ``(loss, aux)``;
+  of ``aux`` only the entries a model names in ``device_scalars`` leave the program);
   in a functional framework the objective must live inside the traced function, so the
   torch pattern "outputs = engine(x); loss = criterion(outputs); engine.backward(loss)"
   becomes "loss = engine(x, y); engine.backward(loss); engine.step()".
@@ -281,6 +282,13 @@ class DeepSpeedEngine:
             self.model_fn = sp_build(self.mesh, self.config.sequence_parallel_axis,
                                      schedule=self.config.sequence_parallel_schedule)
         self.model_fn = _traced_under(self.mesh, self.model_fn)
+        if param_shardings is None and hasattr(model, "engine_shardings"):
+            # the model's own layout over this mesh (experts that live split over the
+            # data axis); ZeRO claims what it leaves free, as for a caller's layout
+            param_shardings = model.engine_shardings(self.mesh)
+        # the names a model declares (``device_scalars``) of the per-step device scalars
+        # in the dict its apply returns beside the loss; nothing else of that dict is kept
+        self._device_scalar_names = tuple(getattr(model, "device_scalars", ()))
 
         # ---- precision policy ----
         if self.fp16_enabled():
@@ -1054,6 +1062,8 @@ class DeepSpeedEngine:
                           "bf16": jnp.bfloat16}[self.config.communication_data_type]
         self._grad_dtype = grad_dtype
 
+        scalar_names = self._device_scalar_names
+
         def local_loss_and_grad(params, scale, *batch):
             # named_scope is HLO metadata only (zero instructions — asserted by
             # tests/unit/test_telemetry.py), so the trace annotation is unconditional
@@ -1064,6 +1074,10 @@ class DeepSpeedEngine:
                     factor = scale / grad_acc_steps
                     if prescale:
                         factor = factor / predivide
+                    if scalar_names:     # they ride out beside the loss, on every grad path
+                        kept = {name: jax.lax.stop_gradient(out[1][name]) for name in scalar_names}
+                        assert all(v.ndim <= 1 for v in kept.values()), "scalars, or one a layer"
+                        return loss * factor, (loss, kept)
                     return loss * factor, loss
                 (_, loss), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(params)
                 grads = jax.tree_util.tree_map(lambda g: g.astype(grad_dtype), grads)
@@ -1986,7 +2000,6 @@ class DeepSpeedEngine:
                         "forward() (strict forward/backward/step rotation)")
                 loss, self._fused_pending = self._run_fused_step(batch)
                 self._pending_grads = _FUSED
-                self._pending_loss = loss
             elif (self._loss_and_grad_comm_fn is not None
                   and self.global_steps >= self.config.comm_compress_start_step):
                 # compressed phase of hierarchical_compressed: host-side step
@@ -1997,13 +2010,15 @@ class DeepSpeedEngine:
                     self._jit_loss_and_grad_comm, self.params,
                     self.scaler_state.cur_scale, self._comm_we, self._comm_se, *batch)
                 self._pending_grads = grads
-                self._pending_loss = loss
             else:
                 loss, grads = self._call_program(
                     "train.grad_program", "loss_and_grad", self._jit_loss_and_grad,
                     self.params, self.scaler_state.cur_scale, *batch)
                 self._pending_grads = grads
-                self._pending_loss = loss
+            if self._device_scalar_names:      # whichever program ran: (loss, the scalars)
+                loss, scalars = loss
+                self._spans.keep_device_scalars(self._span_engine, self.global_steps, scalars)
+            self._pending_loss = loss
         else:
             self._goodput_begin_eval()
             loss = self._jit_eval(self.params, *batch)

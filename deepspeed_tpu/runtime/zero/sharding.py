@@ -32,8 +32,8 @@ def zero_spec(shape, dp_size: int, min_size: int = 1024, existing_spec: P = P())
     candidates, and the existing placements are preserved.
     """
     spec = list(existing_spec) + [None] * (len(shape) - len(existing_spec))
-    if dp_size <= 1 or int(np.prod(shape)) < min_size:
-        return P(*spec)
+    if dp_size <= 1 or int(np.prod(shape)) < min_size or DATA_AXIS in spec:
+        return P(*spec)     # a leaf the layout already splits over 'data' (experts) stays
     best_axis = -1
     best_dim = 0
     for i, d in enumerate(shape):

@@ -35,6 +35,7 @@ import jax
 clock = time.perf_counter
 
 RING_SPANS = 8192                   # some five minutes of one-program-pair steps
+RING_DEVICE_SCALARS = 1024          # steps whose device scalars are kept, a few bytes each
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
@@ -84,6 +85,7 @@ class Recorder:
         self._open = threading.local()
         self._lock = threading.Lock()
         self._counters = {}          # (engine, name) -> int
+        self._device_scalars = collections.deque(maxlen=RING_DEVICE_SCALARS)
         self._programs = weakref.WeakValueDictionary()   # engine -> the Programs it holds
 
     # ------------------------------------------------------------------ spans
@@ -159,6 +161,18 @@ class Recorder:
     def counters(self, engine):
         with self._lock:
             return {name: v for (e, name), v in self._counters.items() if e == engine}
+
+    # --------------------------------------------------------- device scalars
+    def keep_device_scalars(self, engine, step, scalars):
+        """Keep a step's device scalars (a dict of arrays the step program returned beside
+        its loss) as they are, unfetched: nothing here waits for the device."""
+        self._device_scalars.append((engine, step, scalars))
+
+    def device_scalars(self, engine):
+        """``[(step, {name: device array})]`` of the steps still kept, oldest first, as
+        unfetched as they were kept: the caller fetches (``jax.device_get``), after a
+        measured window and never inside one."""
+        return [(step, scalars) for e, step, scalars in list(self._device_scalars) if e == engine]
 
     # ---------------------------------------------------------------- compiles
     def on_compile_event(self, event, seconds, **kwargs):

@@ -1,0 +1,215 @@
+"""OLMoE on the normal path against its plain float32 reference
+(``benchmarks/reference/olmoe_reference.py``) on seeded weights at a tiny size: one device
+and a four-device mesh with the experts split, top-2 and top-8; a router that sends every
+token to one expert; the grouped matmul against a per-expert loop."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import deepspeed_tpu
+from benchmarks.reference import olmoe_reference as ref
+from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
+from deepspeed_tpu.parallel.mesh import build_mesh
+from deepspeed_tpu.parallel.moe import DroplessMoE, grouped_matmul
+from deepspeed_tpu.utils import spans
+
+AUX = 0.01
+CASES = [(k, d) for k in (2, 8) for d in (1, 4)]
+IDS = [f"top{k}-{d}dev" for k, d in CASES]
+
+
+def published(top_k):
+    return dict(hidden_size=64, intermediate_size=32, num_hidden_layers=2,
+                num_attention_heads=4, num_key_value_heads=4, num_experts=8,
+                num_experts_per_tok=top_k, vocab_size=256, rms_norm_eps=1e-5, rope_theta=10000,
+                norm_topk_prob=False, max_position_embeddings=64)
+
+
+def build(top_k, **more):
+    keys = published(top_k)
+    more = dict(dict(compute_dtype=jnp.float32, initializer_range=0.2,
+                     router_aux_loss_coef=AUX), **more)
+    model = OlmoeModel(OlmoeConfig.from_published(keys, **more))
+    return keys, model, model.init(jax.random.PRNGKey(top_k))
+
+
+def batch(seed=1, rows=4, T=32):
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, 256, (rows, T)).astype(np.int32),
+            rng.integers(0, 256, (rows, T)).astype(np.int32))
+
+
+def on_mesh(model, params, tokens, labels, devices):
+    """Everything placed as the engine places it, and a wrapper that traces under the mesh."""
+    if devices == 1:
+        return params, tokens, labels, lambda fn: fn
+    mesh = build_mesh(data=devices, devices=jax.devices()[:devices])
+    rows = NamedSharding(mesh, P("data"))
+
+    def under(fn):
+        def traced(*args):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return fn(*args)
+        return traced
+    return (jax.device_put(params, model.engine_shardings(mesh)),
+            jax.device_put(tokens, rows), jax.device_put(labels, rows), under)
+
+
+@pytest.mark.parametrize("top_k, devices", CASES, ids=IDS)
+def test_loss_logits_and_expert_choices_match_the_reference(top_k, devices):
+    keys, model, params = build(top_k)
+    tokens, labels = batch()
+    want = jax.jit(lambda p: ref.forward(p, tokens, labels, keys, AUX, last=8))(params)
+    params, tok, lab, under = on_mesh(model, params, tokens, labels, devices)
+    got = jax.jit(under(lambda p, t, l: model.forward_details(p, t, l, 8)))(params, tok, lab)
+    for name in ("loss", "ce", "aux"):
+        assert float(got[name]) == pytest.approx(float(want[name]), rel=1e-5), name
+    np.testing.assert_allclose(got["logits"], want["logits"], atol=5e-5)
+    assert np.array_equal(got["experts"], want["experts"])
+    loss, stats = jax.jit(under(model.apply))(params, tok, lab)
+    assert float(loss) == pytest.approx(float(want["loss"]), rel=1e-5)
+    assert set(stats) == set(model.device_scalars) == {"moe_load_max_over_mean"}
+    assert stats["moe_load_max_over_mean"].shape == (2,) and np.all(stats["moe_load_max_over_mean"] >= 1)
+
+
+@pytest.mark.parametrize("top_k, devices", CASES, ids=IDS)
+def test_gradients_of_every_parameter_group_match_the_reference(top_k, devices):
+    keys, model, params = build(top_k)
+    tokens, labels = batch(seed=2)
+    want = jax.jit(jax.grad(lambda p: ref.loss(p, tokens, labels, keys, AUX)))(params)
+    params, tok, lab, under = on_mesh(model, params, tokens, labels, devices)
+    got = jax.jit(under(jax.grad(lambda p, t, l: model.apply(p, t, l)[0])))(params, tok, lab)
+    flat_got = dict(jax.tree_util.tree_flatten_with_path(got)[0])
+    for path, w in jax.tree_util.tree_flatten_with_path(want)[0]:
+        g = flat_got[path]
+        assert float(jnp.abs(g - w).max()) <= 2e-5 * float(jnp.abs(w).max()) + 1e-9, path
+    if devices > 1:      # an owner's gradient stays with it, split as its experts are
+        moe = got["layers"][0]["moe"]
+        assert moe["w_gate_up"].sharding.spec == P("data") and moe["w_down"].sharding.spec == P("data")
+
+
+@pytest.mark.parametrize("top_k, devices", CASES, ids=IDS)
+def test_every_token_to_the_same_experts_is_computed_whole(top_k, devices):
+    """The skewed router: every token is sent to the same ``k`` experts, so each of their
+    groups holds every token and the others nothing. Nothing is dropped (the output is
+    the reference's plain sum) and the load reads E / k."""
+    E, H, F = 8, 64, 32
+    layer = DroplessMoE(H, F, E, top_k)
+    params = layer.init(jax.random.PRNGKey(0), 0.2)
+    bias = jnp.where(jnp.arange(E) < top_k, 50.0, 0.0)       # experts 0..k-1, always
+    params["router_w"] = jnp.zeros((H, E)).at[0].set(bias)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, H)).at[..., 0].set(1.0)
+    keys = dict(num_experts=E, num_experts_per_tok=top_k, intermediate_size=F, norm_topk_prob=False)
+    want, chosen, _, _ = ref.expert_layer(x.reshape(-1, H), params, keys)
+    assert np.array_equal(np.sort(chosen, -1), np.tile(np.arange(top_k), (64, 1)))
+    if devices > 1:
+        mesh = build_mesh(data=devices, devices=jax.devices()[:devices])
+        specs = layer.expert_specs("data")
+        params = {k: jax.device_put(v, NamedSharding(mesh, specs[k])) for k, v in params.items()}
+        x = jax.device_put(x, NamedSharding(mesh, P("data")))
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            y, _, stats = jax.jit(layer.apply)(params, x)
+    else:
+        y, _, stats = jax.jit(layer.apply)(params, x)
+    assert float(stats["load_max_over_mean"]) == pytest.approx(E / top_k)
+    np.testing.assert_allclose(y.reshape(-1, H), want, atol=1e-4)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("sizes", [[16, 0, 40, 8], [64, 0, 0, 0], [16, 16, 16, 16]],
+                         ids=["uneven", "one-group", "even"])
+def test_grouped_matmul_matches_a_per_expert_loop(sizes, backward):
+    rng = np.random.default_rng(0)
+    lhs = jnp.asarray(rng.normal(size=(64, 24)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(4, 24, 12)), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    bounds = np.concatenate([[0], np.cumsum(sizes)])
+
+    def loop(lhs, rhs):
+        return jnp.concatenate([lhs[bounds[g]:bounds[g + 1]] @ rhs[g] for g in range(4)])
+
+    def grouped(lhs, rhs):
+        return grouped_matmul(lhs, rhs, group_sizes)
+
+    if not backward:
+        np.testing.assert_allclose(grouped(lhs, rhs), loop(lhs, rhs), rtol=1e-5, atol=1e-5)
+        return
+    cot = jnp.asarray(rng.normal(size=(64, 12)), jnp.float32)
+    for a, b in zip(jax.grad(lambda l, r: jnp.sum(grouped(l, r) * cot), (0, 1))(lhs, rhs),
+                    jax.grad(lambda l, r: jnp.sum(loop(l, r) * cot), (0, 1))(lhs, rhs)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fused_step", [False, True], ids=["two-programs", "fused_step"])
+@pytest.mark.parametrize("devices", [1, 4], ids=["1dev", "4dev"])
+def test_the_engine_trains_it_and_keeps_the_device_scalars(devices, fused_step):
+    _, model, params = build(2, compute_dtype=jnp.bfloat16, initializer_range=0.02)
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    mesh = build_mesh(data=devices, devices=jax.devices()[:devices])
+    engine = DeepSpeedEngine(model=model, model_parameters=params, mesh=mesh, config_params={
+        "train_batch_size": 4, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}, "steps_per_print": 10 ** 9,
+        "fused_step": fused_step})
+    assert (engine._run_fused_step is not None) == fused_step
+    moe = engine.master_params["layers"][0]["moe"]
+    split = P("data", None, None) if devices > 1 else P(None, None, None)
+    assert moe["w_gate_up"].sharding.spec == split and moe["w_down"].sharding.spec == split
+    assert engine.opt_state.exp_avg["layers"][1]["moe"]["w_down"].sharding.spec == split
+    assert engine.params["layers"][0]["moe"]["w_gate_up"].sharding.spec == (
+        P("data") if devices > 1 else P())
+    tokens, _ = batch(seed=4)
+    losses = []
+    for _ in range(4):
+        loss = engine(tokens, np.roll(tokens, -1, 1))
+        engine.backward(loss)
+        engine.step()
+        assert loss.shape == ()          # the scalars never reach the caller's loss
+        losses.append(float(loss))
+    assert losses[-1] < losses[0]
+    kept = spans.recorder().device_scalars(engine._span_engine)
+    assert all(isinstance(v, jax.Array) for _, s in kept for v in s.values())   # unfetched
+    kept = jax.device_get(kept)
+    assert [step for step, _ in kept] == [0, 1, 2, 3]
+    assert all(set(s) == {"moe_load_max_over_mean"} for _, s in kept)
+    assert kept[-1][1]["moe_load_max_over_mean"].shape == (2,)
+
+
+def test_a_dict_beside_the_loss_that_the_model_does_not_declare_is_dropped():
+    """``(loss, aux)`` from a model without ``device_scalars``: ``aux`` never leaves the grad
+    program (a model that returns its logits there must not have them kept a step)."""
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+
+    def model_fn(params, x, y):
+        out = x @ params["w"]
+        return jnp.mean((out - y) ** 2), {"logits": out}
+
+    engine = DeepSpeedEngine(
+        model=model_fn, model_parameters={"w": jnp.ones((8, 8), jnp.float32)},
+        mesh=build_mesh(data=1, devices=jax.devices()[:1]), config_params={
+            "train_batch_size": 4, "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+            "steps_per_print": 10 ** 9})
+    x = np.ones((4, 8), np.float32)
+    loss = engine(x, 2 * x)
+    engine.backward(loss)
+    engine.step()
+    assert loss.shape == () and spans.recorder().device_scalars(engine._span_engine) == []
+    outputs = jax.tree_util.tree_leaves(jax.eval_shape(
+        engine._loss_and_grad_fn, engine.params, engine.scaler_state.cur_scale, x, 2 * x))
+    assert sorted(o.shape for o in outputs) == [(), (8, 8)]
+
+
+def test_initialize_takes_the_model_as_it_takes_gpt2():
+    _, model, params = build(2, compute_dtype=jnp.bfloat16)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
+        "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}, "steps_per_print": 10 ** 9})
+    assert engine.dp_size == 8
+    assert engine.master_params["layers"][0]["moe"]["w_down"].sharding.spec == P("data", None, None)
+    tokens, _ = batch(seed=5, rows=8)
+    loss = engine(tokens, np.roll(tokens, -1, 1))
+    engine.backward(loss)
+    engine.step()
+    assert np.isfinite(float(loss))

@@ -53,8 +53,9 @@ def sumsq_grad(attn):
 FLASH_SHAPES = [(3, 25, 1024, 64),    # GPT-2 XL heads, chip_smoke phase A
                 (8, 16, 1024, 64),    # GPT-2 medium heads
                 (1, 16, 8192, 64),    # long sequence
-                (1, 4, 8192, 128)]    # the most the resident forward holds: the backward's
+                (1, 4, 8192, 128),    # the most the resident forward holds: the backward's
                                       # dQ accumulator takes it past the default 16 MB of VMEM
+                (2, 16, 4096, 128)]   # OLMoE's heads, one chip's rows of olmoe_d4_train_4chip
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
@@ -114,3 +115,28 @@ def test_flash_attention_compiles_under_a_four_chip_mesh(topo):
     assert "tpu_custom_call" in text
     with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
         compiled_text(sumsq_grad(attn), x, x, x)   # no mesh in context: XLA is asked
+
+
+def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
+    """OLMoE's expert layer at its published widths, one chip's 2 x 4096 tokens a chip:
+    the megablox grouped matmul inside the layer's own ``shard_map``, the experts' weights
+    gathered over ``data`` and their gradients scattered back to the owners."""
+    from deepspeed_tpu.parallel.moe import DroplessMoE
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1), ("pipe", "data", "model"))
+    layer = DroplessMoE(2048, 1024, 64, 8)
+    # the grouped matmul picks its kernel by the backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(shapes[k].shape, jnp.bfloat16,
+                                      sharding=NamedSharding(mesh, spec))
+              for k, spec in layer.expert_specs("data").items()}
+    x = jax.ShapeDtypeStruct((8, 4096, 2048), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(params, x):
+        y, aux, _ = layer.apply(params, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = compiled_text(jax.grad(loss), params, x)
+    assert "tpu_custom_call" in text and "all-gather" in text and "reduce-scatter" in text
