@@ -1,6 +1,7 @@
 """On-chip smoke test: the engine's main paths on one TPU chip, at published widths.
 
-    python chip_smoke.py            # one chip: phase A (train), flash parity, phase B (serve)
+    python chip_smoke.py            # one chip: phase A (train), flash parity, the experts in
+                                    # pieces, phase B (serve)
     python chip_smoke.py --chips 4  # one four-chip host: the data-parallel phase only
 
 One process, normal entry points, random weights from ``SEED``. Every phase prints
@@ -39,6 +40,8 @@ TRAIN_LAYERS = 20
 TRAIN = dict(model=dict(XL_WIDTHS, n_layer=TRAIN_LAYERS), batch=4, seq=1024)
 TRAIN_FULL = dict(model=dict(XL_WIDTHS, n_layer=XL_LAYERS), batch=4, seq=1024)
 PARITY_SHAPE = (3, 25, 1024, 64)
+# OLMoE's first expert product as one chip of four runs it: 8 x 8,192 assignments
+EXPERTS = dict(rows=65536, hidden=2048, columns=2048, experts=64, pieces=4)
 SERVE = dict(
     model=dict(vocab_size=50304, n_positions=1024, n_embd=1024, n_layer=24, n_head=16),
     serving=dict(max_seqs=8, block_size=16, num_blocks=513, max_model_len=1024,
@@ -273,6 +276,77 @@ def phase_flash_parity(shape, *, on_chip=True):
             "rel_err": {k: float(f"{v:.3e}") for k, v in errs.items()}}
 
 
+def expert_group_sizes(size, layout):
+    """Rows a group, summing to ``size['rows']``, no size a multiple of a row tile, so that
+    tiles span groups and pieces. ``spread``: every fifth group empty, the others uneven.
+    ``collapsed``: eight groups hold all but a few rows, as the router of
+    ``olmoe_d4_train_4chip`` leaves them (``moe_load_max_over_mean`` near 8)."""
+    rng = np.random.default_rng(SEED)
+    share = rng.random(size["experts"]) + 0.05
+    if layout == "collapsed":
+        share[rng.permutation(size["experts"])[8:]] *= 1e-3
+    share[::5] = 0.0
+    sizes = np.floor(share / share.sum() * size["rows"]).astype(np.int32)
+    sizes[np.flatnonzero(share)[-1]] += size["rows"] - sizes.sum()
+    return sizes
+
+
+def phase_experts_in_pieces(size, *, on_chip=True):
+    """``parallel/moe.experts_matmul`` given the experts in pieces out of order, as a layer
+    under a mesh gets them from the other chips (one kernel call a piece, chained through
+    one buffer that nothing zeroed), against one call over all of them: output, and the
+    cotangents of the rows and of every expert. Each row is the same product either way,
+    so the two agree bit for bit; ``PARITY_TOL`` is the limit, ``identical`` the reading."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.parallel.moe import GMM_TILES, experts_matmul
+
+    per = size["experts"] // size["pieces"]
+    order = [(1 - s) % size["pieces"] for s in range(size["pieces"])]      # chip 1's arrivals
+    keys = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    lhs = jax.random.normal(keys[0], (size["rows"], size["hidden"]), jnp.bfloat16)
+    rhs = jax.random.normal(keys[1], (size["experts"], size["hidden"], size["columns"]),
+                            jnp.bfloat16) * size["hidden"] ** -0.5
+    cot = jax.random.normal(keys[2], (size["rows"], size["columns"]), jnp.bfloat16)
+
+    def both_ways(lhs, rhs, cot, sizes, firsts):
+        whole, back = jax.vjp(lambda x, w: experts_matmul(x, (w,), (None,), sizes), lhs, rhs)
+        d_lhs, d_rhs = back(cot)
+        parts = tuple(rhs[o * per:(o + 1) * per] for o in order)
+        pieced, back = jax.vjp(lambda x, ws: experts_matmul(x, ws, tuple(firsts), sizes),
+                               lhs, parts)
+        p_lhs, d_parts = back(cot)
+        p_rhs = jnp.concatenate([d_parts[order.index(o)] for o in range(size["pieces"])])
+        return [(whole, pieced), (d_lhs, p_lhs), (d_rhs, p_rhs)]
+
+    firsts = jnp.asarray([o * per for o in order], jnp.int32)        # traced, as a chip's index is
+    program = jax.jit(both_ways)
+    if on_chip:
+        text = program.lower(lhs, rhs, cot, jnp.zeros(size["experts"], jnp.int32),
+                             firsts).compile().as_text()
+        require_kernel(text, "experts_matmul")
+    out = {"rows": size["rows"], "experts": size["experts"], "pieces": size["pieces"],
+           "tol": PARITY_TOL, "layouts": {}}
+    for layout in ("spread", "collapsed"):
+        sizes = expert_group_sizes(size, layout)
+        ends = np.cumsum(sizes)[per - 1::per][:-1]
+        pairs = jax.device_get(program(lhs, rhs, cot, jnp.asarray(sizes), firsts))
+        rel, same = {}, True
+        for name, (a, b) in zip(("out", "d_rows", "d_experts"), pairs):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            if not (np.isfinite(a).all() and np.isfinite(b).all()):
+                raise AssertionError(f"experts in pieces, {layout}: {name} is not finite")
+            rel[name] = float(np.abs(a - b).max() / np.abs(a).max())
+            same &= bool(np.array_equal(a, b))
+        if max(rel.values()) > PARITY_TOL:
+            raise AssertionError(f"experts in pieces, {layout}: {rel} against one call")
+        out["layouts"][layout] = {
+            "empty_groups": int((sizes == 0).sum()), "largest_group": int(sizes.max()),
+            "piece_ends_inside_a_tile": [int(e % GMM_TILES[0]) for e in ends],
+            "rel_err": {k: float(f"{v:.3e}") for k, v in rel.items()}, "identical": same}
+    return out
+
+
 # --------------------------------------------------------------------- serve
 def serving_programs(engine):
     """The serving engine's jitted programs by name, from its ``lint_programs`` hook."""
@@ -473,6 +547,7 @@ def main(argv=None):
         emit({"phase": "A train", "reduced": reduced_depth(TRAIN_LAYERS),
               **phase_train(TRAIN, log, mesh=mesh)})
         emit({"phase": "A flash parity", **phase_flash_parity(PARITY_SHAPE)})
+        emit({"phase": "A experts in pieces", **phase_experts_in_pieces(EXPERTS)})
         emit({"phase": "B serve", "reduced": {}, **phase_serve(SERVE, log)})
 
     emit({"cache_entries_after": cache_entries(cache_dir)})

@@ -31,6 +31,7 @@ GShard/Switch-Transformer recipe expressed TPU-first:
 it slots into ``PipelineModule`` stacks and the engine unchanged.
 """
 
+import functools
 import math
 from typing import Optional
 
@@ -378,17 +379,147 @@ _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 GMM_TILES = (512, 1024, 1024)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def _tiles(*rows_contraction_columns):
+    return tuple(min(t, d) for t, d in zip(GMM_TILES, rows_contraction_columns))
+
+
+def _ragged_sizes(rhs, group_sizes, first):
+    """``lax.ragged_dot``'s operands for the groups ``first .. first + len(rhs) - 1`` alone:
+    the rows before them become one leading group of zero weights, the rows after them
+    belong to no group and come out zero."""
+    if first is None:
+        return rhs, group_sizes
+    before = jnp.sum(jnp.where(jnp.arange(group_sizes.shape[0]) < first, group_sizes, 0))
+    mine = jax.lax.dynamic_slice(group_sizes, (first,), (rhs.shape[0],))
+    return (jnp.concatenate([jnp.zeros_like(rhs[:1]), rhs]),
+            jnp.concatenate([before[None].astype(group_sizes.dtype), mine]))
+
+
+def grouped_matmul(lhs, rhs, group_sizes, first=None, out=None, transpose_rhs=False):
     """``out[m] = lhs[m] @ rhs[g(m)]`` for rows sorted by group: ``lhs [M, K]``,
-    ``rhs [G, K, N]``, ``group_sizes [G]`` summing to ``M``. On the TPU this is JAX's
-    megablox kernel (``jax.experimental.pallas.ops.tpu.megablox``; its backward is the
-    same kernel and its transposed sibling), elsewhere ``lax.ragged_dot``, which XLA's CPU
-    backend runs and whose TPU lowering reached 55 % of megablox's rate on the chip."""
+    ``rhs [G, K, N]`` (``[G, N, K]`` with ``transpose_rhs``), ``group_sizes [G]`` summing
+    to ``M``. With ``first`` (an int32 scalar, traced or not) ``rhs`` holds only the groups
+    ``first .. first + len(rhs) - 1`` of a longer ``group_sizes``: their rows are computed,
+    every other row is ``out``'s (unspecified where ``out`` is None), so that a chain of
+    calls over pieces of the experts fills one buffer in place. On the TPU this is JAX's
+    megablox kernel (``jax.experimental.pallas.ops.tpu.megablox``), elsewhere
+    ``lax.ragged_dot``, which XLA's CPU backend runs and whose TPU lowering reached 55 % of
+    megablox's rate on the chip."""
     if jax.default_backend() != "tpu":
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-    tiles = tuple(min(t, d) for t, d in zip(GMM_TILES, (lhs.shape[0],) + rhs.shape[1:]))
-    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiles)
+        if transpose_rhs:
+            rhs = rhs.swapaxes(1, 2)
+        rhs, sizes = _ragged_sizes(rhs, group_sizes, first)
+        y = jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=lhs.dtype)
+        if out is None:
+            return y
+        rows = jnp.arange(lhs.shape[0])
+        return jnp.where(((rows >= sizes[0]) & (rows < jnp.sum(sizes)))[:, None], y, out)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    columns = rhs.shape[1 if transpose_rhs else 2]
+    if first is not None and out is None:
+        # the kernel writes the tiles it visits and the pieces' calls together visit all:
+        # the first starts from a buffer nothing has written (megablox alone would mask
+        # the whole output after every call)
+        out = jax.lax.empty((lhs.shape[0], columns), lhs.dtype)
+    tiles = _tiles(lhs.shape[0], lhs.shape[1], columns)
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiles, first, out, transpose_rhs=transpose_rhs)
+
+
+def grouped_matmul_weight_grad(lhs, grad, group_sizes, first, like):
+    """The cotangent of ``grouped_matmul``'s ``rhs`` (shaped and typed ``like`` it):
+    ``d_rhs[g] = lhs[rows of g].T @ grad[rows of g]`` for the groups ``first .. first +
+    len(like) - 1`` (all of them where ``first`` is None). megablox's ``tgmm`` on the TPU."""
+    if jax.default_backend() != "tpu":
+        padded, sizes = _ragged_sizes(like, group_sizes, first)
+        d_rhs, = jax.linear_transpose(
+            lambda r: jax.lax.ragged_dot(lhs, r, sizes, preferred_element_type=grad.dtype),
+            padded)(grad)
+        return d_rhs if first is None else d_rhs[1:]
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+    return tgmm(lhs.swapaxes(0, 1), grad, group_sizes, like.dtype,
+                _tiles(lhs.shape[0], *like.shape[1:]), first, like.shape[0])
+
+
+@jax.custom_vjp
+def experts_matmul(lhs, pieces, firsts, group_sizes):
+    """``grouped_matmul`` over experts that come in ``pieces`` (a tuple of ``[g, K, N]``;
+    ``firsts[i]`` the group piece i starts at, None for one whole piece): one kernel call a
+    piece, each writing its groups' rows into the same buffer, so that nothing copies the
+    pieces together and the first piece's product can start before the last has arrived.
+    Its cotangents are written the same way: one chained product for ``lhs``, one
+    ``[g, K, N]`` gradient a piece, in the pieces' order."""
+    out = None
+    for rhs, first in zip(pieces, firsts):
+        out = grouped_matmul(lhs, rhs, group_sizes, first, out)
+    return out
+
+
+def _experts_matmul_fwd(lhs, pieces, firsts, group_sizes):
+    return experts_matmul(lhs, pieces, firsts, group_sizes), (lhs, pieces, firsts, group_sizes)
+
+
+def _experts_matmul_bwd(res, grad):
+    lhs, pieces, firsts, group_sizes = res
+    d_lhs, d_pieces = None, []
+    for rhs, first in zip(pieces, firsts):
+        d_lhs = grouped_matmul(grad, rhs, group_sizes, first, d_lhs, transpose_rhs=True)
+        d_pieces.append(grouped_matmul_weight_grad(lhs, grad, group_sizes, first, rhs))
+    return d_lhs, tuple(d_pieces), tuple(None for _ in firsts), None
+
+
+experts_matmul.defvjp(_experts_matmul_fwd, _experts_matmul_bwd)
+
+
+# ------------------------------------------------------------- the experts' exchange
+def _send(x, axis, places):
+    """Every chip's ``x`` to the chip ``places`` further along ``axis`` (negative: back)."""
+    n = jax.lax.axis_size(axis)
+    return jax.lax.ppermute(x, axis, [(i, (i + places) % n) for i in range(n)])
+
+
+def _nearest_first(n):
+    """Where the chips of an axis of ``n`` sit from any one of them, itself first and then
+    by their distance along the axis: 0, 1, -1, 2, -2, ... places back. Along the axis, not
+    on the links: in ``jax.devices()`` order one place on a 2 x 2 host is a diagonal for
+    two of the four chips (PERF.md, PR 27: the chips as a ring measured 1.6-1.8 % faster)."""
+    return [0] + sorted((s if 2 * s <= n else s - n for s in range(1, n)),
+                        key=lambda s: (abs(s), -s))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def gather_pieces(w, axis):
+    """All-gather of ``w`` (this chip's experts, ``[E / n, ...]``) over ``axis`` as ``n - 1``
+    transfers chip to chip, none waiting for another: ``n`` pieces, this chip's own first,
+    then the others' in a fixed order (``piece_firsts`` has the expert each starts at). Each
+    transfer is a ``collective-permute`` that the TPU compiler starts early and ends late,
+    with the kernels that use the earlier pieces between; an ``all_gather`` of the same
+    bytes it runs synchronously, and a ring of neighbour hops makes every piece wait for
+    the hop before it (PERF.md, PR 27). The cotangent sends each piece's gradient straight
+    to the experts' owner, which adds what the ``n`` chips computed in float32 and rounds
+    once: the complete sum over the chips, not the mean."""
+    return tuple(w if back == 0 else _send(w, axis, back)
+                 for back in _nearest_first(jax.lax.axis_size(axis)))
+
+
+def _gather_pieces_fwd(w, axis):
+    return gather_pieces(w, axis), None
+
+
+def _gather_pieces_bwd(axis, _, grads):
+    with jax.named_scope("ds_moe_exchange"):
+        total = grads[0].astype(jnp.float32)
+        for back, grad in zip(_nearest_first(len(grads))[1:], grads[1:]):
+            total += _send(grad, axis, -back).astype(jnp.float32)
+        return (total.astype(grads[0].dtype),)
+
+
+gather_pieces.defvjp(_gather_pieces_fwd, _gather_pieces_bwd)
+
+
+def piece_firsts(axis, per_chip):
+    """The expert each piece of ``gather_pieces`` starts at, ``per_chip`` experts a chip."""
+    n, me = jax.lax.axis_size(axis), jax.lax.axis_index(axis)
+    return tuple(((me - back) % n * per_chip).astype(jnp.int32) for back in _nearest_first(n))
 
 
 class DroplessMoE:
@@ -406,16 +537,18 @@ class DroplessMoE:
     Across chips the experts are STORED split and GATHERED for use, as ZeRO-3 does with a
     parameter: where the context mesh's ``data`` axis (the engine traces the model under
     its own) has several devices that divide the experts, each chip owns ``E / ep`` of
-    them, master copy and optimizer state with them; a layer all-gathers its bf16 expert
-    weights for the forward pass and again for the backward, every chip computes every
-    expert on its own tokens, and the expert gradients are summed and scattered back to
-    their owners, complete there and not averaged. Tokens never cross the chips: this is
+    them, master copy and optimizer state with them; a layer fetches the other chips' bf16
+    expert weights for the forward pass and again for the backward (``gather_pieces``:
+    ``ep - 1`` chip-to-chip transfers the compiler runs under the kernels, each arrived
+    piece going straight into its own grouped-matmul call), every chip computes every
+    expert on its own tokens, and each piece's gradient goes straight back to its owner,
+    who sums them: complete there and not averaged. Tokens never cross the chips: this is
     not expert parallelism by a token all-to-all. That needs a static bound on what a
     chip may receive, and a router at initialisation sends most tokens to the same few
     experts (measured at OLMoE's widths, PERF.md PR 26: one chip had 2.19 times its even
     share), so a bound that never drops is the worst case, ``ep`` times the buffers. The
     price is wire traffic that grows with the parameters and not with the tokens
-    (PERF.md section 7: the token exchange is still to be measured on the chip).
+    (PERF.md section 7: the token exchange is still to be measured inside a step).
 
     ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
     mean, over all chips).
@@ -502,24 +635,27 @@ class DroplessMoE:
         def routed(x2, weights, w_gate_up, w_down):
             dt = x2.dtype
             w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
-            if axis is not None:
+            if axis is None:
+                gate_up_pieces, down_pieces, firsts = (w_gate_up,), (w_down,), (None,)
+            else:
                 with jax.named_scope("ds_moe_exchange"):
-                    w_gate_up = jax.lax.all_gather(w_gate_up, axis, tiled=True)
-                    w_down = jax.lax.all_gather(w_down, axis, tiled=True)
+                    gate_up_pieces = gather_pieces(w_gate_up, axis)
+                    down_pieces = gather_pieces(w_down, axis)
+                    firsts = piece_firsts(axis, w_down.shape[0])
             with jax.named_scope("ds_moe_dispatch"):
                 xs = _take_rows(x2, tok, inverse)                         # [n * k, H]
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
-                    grouped_matmul(xs, w_gate_up, group_sizes), "ds_moe_gate_up")
+                    experts_matmul(xs, gate_up_pieces, firsts, group_sizes), "ds_moe_gate_up")
                 hidden = (jax.nn.silu(gate_up[:, :F].astype(jnp.float32))
                           * gate_up[:, F:].astype(jnp.float32)).astype(dt)
-                ys = grouped_matmul(hidden, w_down, group_sizes)
+                ys = experts_matmul(hidden, down_pieces, firsts, group_sizes)
             with jax.named_scope("ds_moe_combine"):
                 return _combine_rows(ys, weights, inverse, order)          # [n, H]
 
         # The backward keeps the first product's output and each token's k expert outputs.
         # It makes the gathered rows and the gated activation again (a gather and an
-        # elementwise pass) and gathers the experts' weights again: kept, the four
+        # elementwise pass) and fetches the experts' weights again: kept, the four
         # layers' gathered weights would be 3.2 GB a chip.
         y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
             "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)

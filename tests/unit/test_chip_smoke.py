@@ -126,3 +126,16 @@ def test_compile_cache_helper(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_phase_experts_in_pieces_tiny():
+    """The control flow and the bookkeeping of the pieces (which cotangent is whose);
+    here both sides are ``lax.ragged_dot``, the kernel's chaining only the chip sees."""
+    size = dict(rows=256, hidden=32, columns=48, experts=8, pieces=4)
+    out = chip_smoke.phase_experts_in_pieces(size, on_chip=False)
+    assert set(out["layouts"]) == {"spread", "collapsed"}
+    for layout in out["layouts"].values():
+        assert layout["empty_groups"] >= 2
+        assert max(layout["rel_err"].values()) < chip_smoke.PARITY_TOL
+    for layout in ("spread", "collapsed"):
+        assert chip_smoke.expert_group_sizes(size, layout).sum() == size["rows"]

@@ -1,7 +1,8 @@
 """OLMoE on the normal path against its plain float32 reference
 (``benchmarks/reference/olmoe_reference.py``) on seeded weights at a tiny size: one device
-and a four-device mesh with the experts split, top-2 and top-8; a router that sends every
-token to one expert; the grouped matmul against a per-expert loop."""
+and meshes of two and four devices with the experts split, top-2 and top-8; a router that
+sends every token to one expert; the grouped matmul, whole and in pieces, against a
+per-expert loop; the experts' exchange against the collectives it stands for."""
 
 import jax
 import jax.numpy as jnp
@@ -13,11 +14,11 @@ import deepspeed_tpu
 from benchmarks.reference import olmoe_reference as ref
 from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
 from deepspeed_tpu.parallel.mesh import build_mesh
-from deepspeed_tpu.parallel.moe import DroplessMoE, grouped_matmul
+from deepspeed_tpu.parallel.moe import DroplessMoE, experts_matmul, gather_pieces, piece_firsts
 from deepspeed_tpu.utils import spans
 
 AUX = 0.01
-CASES = [(k, d) for k in (2, 8) for d in (1, 4)]
+CASES = [(k, d) for k in (2, 8) for d in (1, 2, 4)]
 IDS = [f"top{k}-{d}dev" for k, d in CASES]
 
 
@@ -119,20 +120,27 @@ def test_every_token_to_the_same_experts_is_computed_whole(top_k, devices):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
-@pytest.mark.parametrize("sizes", [[16, 0, 40, 8], [64, 0, 0, 0], [16, 16, 16, 16]],
-                         ids=["uneven", "one-group", "even"])
-def test_grouped_matmul_matches_a_per_expert_loop(sizes, backward):
+@pytest.mark.parametrize("pieces", [1, 2, 4], ids=["whole", "2-pieces", "4-pieces"])
+@pytest.mark.parametrize("sizes", [[16, 0, 40, 8], [64, 0, 0, 0], [0, 0, 0, 64], [16, 16, 16, 16]],
+                         ids=["uneven", "first-group", "last-group", "even"])
+def test_grouped_matmul_matches_a_per_expert_loop(sizes, pieces, backward):
+    """``pieces`` > 1: the experts handed over in pieces out of order, as the other chips'
+    arrive, each piece's rows written into the one output and its gradient returned alone."""
     rng = np.random.default_rng(0)
     lhs = jnp.asarray(rng.normal(size=(64, 24)), jnp.float32)
     rhs = jnp.asarray(rng.normal(size=(4, 24, 12)), jnp.float32)
     group_sizes = jnp.asarray(sizes, jnp.int32)
     bounds = np.concatenate([[0], np.cumsum(sizes)])
+    per = 4 // pieces
+    order = [(1 - s) % pieces for s in range(pieces)]           # chip 1's arrivals
+    firsts = (None,) if pieces == 1 else tuple(jnp.int32(o * per) for o in order)
 
     def loop(lhs, rhs):
         return jnp.concatenate([lhs[bounds[g]:bounds[g + 1]] @ rhs[g] for g in range(4)])
 
     def grouped(lhs, rhs):
-        return grouped_matmul(lhs, rhs, group_sizes)
+        return experts_matmul(lhs, tuple(rhs[o * per:(o + 1) * per] for o in order), firsts,
+                              group_sizes)
 
     if not backward:
         np.testing.assert_allclose(grouped(lhs, rhs), loop(lhs, rhs), rtol=1e-5, atol=1e-5)
@@ -141,6 +149,49 @@ def test_grouped_matmul_matches_a_per_expert_loop(sizes, backward):
     for a, b in zip(jax.grad(lambda l, r: jnp.sum(grouped(l, r) * cot), (0, 1))(lhs, rhs),
                     jax.grad(lambda l, r: jnp.sum(loop(l, r) * cot), (0, 1))(lhs, rhs)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("devices", [2, 4], ids=["2dev", "4dev"])
+def test_the_exchange_moves_what_the_collectives_moved(devices):
+    """``gather_pieces`` against ``all_gather`` and its cotangent against ``psum_scatter``, in
+    bf16 as the step runs them: the pieces, put where ``piece_firsts`` says, are the tiled
+    all-gather bit for bit; the owners end with the SUM over the chips of the gradients of
+    their experts, within bf16 roundings of the exact sum."""
+    per, tail = 3, (5, 8)
+    mesh = build_mesh(data=devices, devices=jax.devices()[:devices])
+    rng = np.random.default_rng(devices)
+    w = jnp.asarray(rng.normal(size=(devices * per,) + tail), jnp.bfloat16)
+    cots = jnp.asarray(rng.normal(size=(devices, devices, per) + tail), jnp.bfloat16)
+
+    def local(w, cots):                    # a chip: its experts; a cotangent a piece
+        firsts = piece_firsts("data", per)
+
+        def in_expert_order(pieces):
+            whole = jnp.zeros((devices * per,) + tail, pieces[0].dtype)
+            for piece, first in zip(pieces, firsts):
+                whole = jax.lax.dynamic_update_slice(whole, piece, (first, 0, 0))
+            return whole
+
+        pieces, back = jax.vjp(lambda w: gather_pieces(w, "data"), w)
+        cot = tuple(cots[0, s] for s in range(devices))
+        whole_cot = in_expert_order(cot)
+        return (in_expert_order(pieces), jax.lax.all_gather(w, "data", tiled=True),
+                back(cot)[0],
+                jax.lax.psum_scatter(whole_cot.astype(jnp.float32), "data", tiled=True),
+                jax.lax.psum_scatter(jnp.abs(whole_cot).astype(jnp.float32), "data", tiled=True),
+                jax.lax.psum_scatter(whole_cot, "data", tiled=True))
+
+    data = P("data")
+    pieces, gathered, summed, exact, size, collective = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(data, data), out_specs=(data,) * 6, check_vma=False))(w, cots)
+    assert pieces.dtype == jnp.bfloat16 and summed.dtype == jnp.bfloat16
+    assert np.array_equal(np.asarray(pieces, np.float32), np.asarray(gathered, np.float32))
+    assert np.array_equal(np.asarray(pieces, np.float32),
+                          np.tile(np.asarray(w, np.float32), (devices, 1, 1)))
+    rounding = (devices - 1) * 2.0 ** -8 * np.asarray(size)      # a rounding a hop at most
+    assert np.all(np.abs(np.asarray(summed, np.float32) - np.asarray(exact)) <= rounding)
+    assert np.all(np.abs(np.asarray(summed, np.float32) - np.asarray(collective, np.float32))
+                  <= 2 * rounding)
 
 
 @pytest.mark.parametrize("fused_step", [False, True], ids=["two-programs", "fused_step"])

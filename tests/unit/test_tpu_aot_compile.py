@@ -9,6 +9,7 @@ so these say nothing about results or times; ``chip_smoke.py`` does that on the 
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -117,10 +118,9 @@ def test_flash_attention_compiles_under_a_four_chip_mesh(topo):
         compiled_text(sumsq_grad(attn), x, x, x)   # no mesh in context: XLA is asked
 
 
-def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
-    """OLMoE's expert layer at its published widths, one chip's 2 x 4096 tokens a chip:
-    the megablox grouped matmul inside the layer's own ``shard_map``, the experts' weights
-    gathered over ``data`` and their gradients scattered back to the owners."""
+def expert_layer_on_four_chips(topo, monkeypatch):
+    """OLMoE's expert layer at its published widths with its arguments as shapes on the
+    described host: 2 x 4096 tokens a chip, the experts split over ``data``."""
     from deepspeed_tpu.parallel.moe import DroplessMoE
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1), ("pipe", "data", "model"))
     layer = DroplessMoE(2048, 1024, 64, 8)
@@ -132,6 +132,13 @@ def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
               for k, spec in layer.expert_specs("data").items()}
     x = jax.ShapeDtypeStruct((8, 4096, 2048), jnp.bfloat16,
                              sharding=NamedSharding(mesh, P("data")))
+    return mesh, layer, params, x
+
+
+def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
+    """The megablox grouped matmul inside the layer's own ``shard_map``, the experts'
+    weights fetched chip to chip over ``data`` and their gradients sent back to the owners."""
+    mesh, layer, params, x = expert_layer_on_four_chips(topo, monkeypatch)
 
     def loss(params, x):
         y, aux, _ = layer.apply(params, x)
@@ -139,4 +146,63 @@ def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
 
     with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         text = compiled_text(jax.grad(loss), params, x)
-    assert "tpu_custom_call" in text and "all-gather" in text and "reduce-scatter" in text
+    assert "tpu_custom_call" in text and "collective-permute-start" in text
+
+
+SHAPE_RE = re.compile(r"(pred|[a-z]+\d+)\[([\d,]*)\]")
+INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+
+
+def scheduled_entry(text):
+    """The entry computation of a compiled (scheduled) module, in the order it runs:
+    ``(name, opcode, the largest array among result and operands in bytes, the rest of the
+    line)`` an instruction."""
+    assert "is_scheduled=true" in text
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    out = []
+    for line in body.splitlines():
+        m = INSTRUCTION_RE.match(line)
+        if m is None:
+            continue
+        name, result, opcode, rest = m.groups()
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d], dtype=np.int64))
+                 * (1 if dtype == "pred" else int(re.sub(r"\D", "", dtype)) // 8)
+                 for dtype, dims in SHAPE_RE.findall(result + " " + rest.split("), ")[0])]
+        out.append((name, opcode, max(sizes, default=0), rest))
+    return out
+
+
+def test_the_experts_exchange_is_scheduled_under_the_kernels(topo, monkeypatch):
+    """The gradient of two stacked expert layers, as the chip's compiler orders it: the
+    experts' weights and gradients move as asynchronous chip-to-chip transfers with the
+    grouped-matmul kernels between a transfer's start and its end, forward and backward,
+    and no large collective is left that the chip only waits for. (An ``all_gather`` of the
+    same weights compiles to eight large synchronous collectives here: PERF.md, PR 27.)"""
+    mesh, layer, one, x = expert_layer_on_four_chips(topo, monkeypatch)
+    between = jax.ShapeDtypeStruct((2048, 2048), jnp.bfloat16, sharding=NamedSharding(mesh, P()))
+
+    def loss(params, between, x):
+        y, aux_a, _ = layer.apply(params["a"], x)
+        y, aux_b, _ = layer.apply(params["b"], jnp.dot(y, between))
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux_a + aux_b
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        entry = scheduled_entry(compiled_text(jax.grad(loss), {"a": one, "b": one}, between, x))
+    synchronous = [(name, size) for name, opcode, size, _ in entry
+                   if opcode in ("all-gather", "reduce-scatter", "all-reduce", "all-to-all",
+                                 "collective-permute") and size > 2 ** 20]
+    assert not synchronous, synchronous
+    started, covered = {}, {"forward": 0, "backward": 0}
+    kernels = 0
+    for name, opcode, size, rest in entry:
+        if opcode == "collective-permute-start":
+            started[name] = kernels
+        elif opcode == "collective-permute-done":
+            start = re.match(r"[^%]*%?([\w.\-]+)", rest).group(1)
+            assert start in started, f"{name} ends {start}, which has not started"
+            if kernels > started.pop(start) and size > 2 ** 20:
+                covered["backward" if "transpose(" in rest else "forward"] += 1
+        elif opcode == "custom-call" and "tpu_custom_call" in rest:
+            kernels += 1
+    assert not started, f"never ended: {sorted(started)}"
+    assert covered["forward"] and covered["backward"], covered
