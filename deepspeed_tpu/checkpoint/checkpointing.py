@@ -410,7 +410,6 @@ def snapshot_checkpoint(engine, tag: Optional[str] = None, client_state: Dict = 
     if writer:
         files[model_states_name() + ".npz"] = ("npz", params_flat)
     meta = {
-        "external_master": bool(getattr(engine, "_external_master", False)),
         "global_steps": engine.global_steps,
         "micro_steps": engine.micro_steps,
         "skipped_steps": engine.skipped_steps,
@@ -434,16 +433,10 @@ def snapshot_checkpoint(engine, tag: Optional[str] = None, client_state: Dict = 
 
     if offload is None:
         # --- optimizer + master states, one file per DP rank (elastic layout) ---
-        # external-master engines hold no master (it is byte-for-byte derivable as
-        # the fp32 upcast of the saved params — writing it would triple the
-        # checkpoint and materialize a full fp32 tree on device for nothing)
         from ..runtime.zero.sharding import elastic_split
         dp = engine.dp_size
-        if getattr(engine, "_external_master", False):
-            master_flat = {}
-        else:
-            master_flat = _flatten_with_paths(
-                engine._ckpt_export(engine.master_params, "master"), materialize=writer)
+        master_flat = _flatten_with_paths(
+            engine._ckpt_export(engine.master_params, "master"), materialize=writer)
         opt_flat = _flatten_with_paths(engine._ckpt_export(engine.opt_state, "opt"),
                                        materialize=writer)
         if writer:
@@ -605,6 +598,13 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None,
 
     with open(os.path.join(ckpt_dir, model_states_name() + ".json")) as f:
         meta = json.load(f)
+    if meta.get("external_master"):
+        # written by the removed external-master step path: no master was saved and
+        # the optimizer state is the client's own flat shard, which no engine holds
+        raise ValueError(
+            f"checkpoint {tag} in {load_dir} was saved by an external-master "
+            "optimizer (metadata external_master: true); that step path was "
+            "removed and this engine cannot hold its state")
 
     params = _load_tree_npz(os.path.join(ckpt_dir, model_states_name() + ".npz"),
                             engine._ckpt_export(engine.params, "params"))
@@ -646,11 +646,10 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None,
                                    _unflatten_like(t, eas_flat, numpy=True))
             else:
                 master_flat, ea_flat, eas_flat = _load_offload_regions(ckpt_dir)
-                if not getattr(engine, "_external_master", False):
-                    master = _unflatten_like(
-                        engine._ckpt_export(engine.master_params, "master"), master_flat)
-                    engine.master_params = engine._place_master(
-                        engine._ckpt_import(master, "master"))
+                master = _unflatten_like(
+                    engine._ckpt_export(engine.master_params, "master"), master_flat)
+                engine.master_params = jax.device_put(
+                    engine._ckpt_import(master, "master"), engine._master_shardings)
                 opt_flat = {f"exp_avg/{k}": v for k, v in ea_flat.items()}
                 opt_flat.update({f"exp_avg_sq/{k}": v for k, v in eas_flat.items()})
                 opt = _unflatten_like(engine._ckpt_export(engine.opt_state, "opt"), opt_flat)
@@ -677,21 +676,10 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None,
                                    _unflatten_like(t, ea, numpy=True),
                                    _unflatten_like(t, eas, numpy=True))
             else:
-                if getattr(engine, "_external_master", False):
-                    pass  # no master storage; the view re-derives from params
-                elif master_flat:
-                    master = _unflatten_like(
-                        engine._ckpt_export(engine.master_params, "master"), master_flat)
-                    engine.master_params = engine._place_master(
-                        engine._ckpt_import(master, "master"))
-                else:
-                    # an external-master checkpoint loaded into a standard engine:
-                    # the master is BY DEFINITION the fp32 upcast of the restored
-                    # params (that is why it was not written)
-                    engine.master_params = jax.device_put(
-                        jax.tree_util.tree_map(lambda p: jnp.asarray(p, jnp.float32),
-                                               engine.params),
-                        engine._master_shardings)
+                master = _unflatten_like(
+                    engine._ckpt_export(engine.master_params, "master"), master_flat)
+                engine.master_params = jax.device_put(
+                    engine._ckpt_import(master, "master"), engine._master_shardings)
                 opt = _unflatten_like(engine._ckpt_export(engine.opt_state, "opt"), opt_flat)
                 engine.opt_state = jax.device_put(
                     engine._ckpt_import(opt, "opt"), engine._opt_shardings)
@@ -700,8 +688,9 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None,
         if getattr(engine, "_offload", None) is not None:
             engine._offload.load_trees(master_tree=engine.params)
         else:
-            engine.master_params = engine._place_master(
-                jax.tree_util.tree_map(lambda p: jnp.asarray(p, jnp.float32), engine.params))
+            engine.master_params = jax.device_put(
+                jax.tree_util.tree_map(lambda p: jnp.asarray(p, jnp.float32), engine.params),
+                engine._master_shardings)
 
     if getattr(engine, "_comm_we", None) is not None:
         # engine-held compressed-comm error feedback: restore (with elastic

@@ -4,10 +4,9 @@ Each entry builds a real engine on the 8-virtual-device CPU mesh (the same
 mesh the tier-1 HLO tests pin collectives on) and captures every program on
 its active step path via ``engine.lint_programs`` — the engines themselves
 declare the expected-collective manifests. Entries cover the step-path matrix
-the bespoke tests grew one file at a time: standard two-jit ZeRO-2, the
-external-master fused single-jit (the pinned 1.5B bench structure), the
-unfused external-master accumulation window, ZeRO-Offload's host-tier split,
-and the instruction-executor pipeline's per-stage programs.
+the bespoke tests grew one file at a time: standard two-jit ZeRO-2, the comm
+modes, ZeRO-Offload's host-tier split, and the instruction-executor
+pipeline's per-stage programs.
 
 The lint model computes in the engine's compute dtype (params enter already
 cast; inputs are cast once at the boundary) — unlike the test-suite
@@ -48,29 +47,6 @@ class LintModel:
         return jnp.mean(jnp.square(out.astype(jnp.float32) - y))
 
 
-def _external_master_pair(n):
-    """Flat-shard external-master (init, apply) client pair — the 1.5B bench's
-    optimizer structure (bench.py) at test scale."""
-    def init(params):
-        flat = jnp.concatenate([p.reshape(-1).astype(jnp.float32)
-                                for p in jax.tree_util.tree_leaves(params)])
-        shard = flat[: flat.shape[0] // n]
-        return {"master": shard, "m1": jnp.zeros_like(shard),
-                "m2": jnp.zeros_like(shard)}
-
-    def apply(grads, opt_state, master, step, hyper):
-        g = jnp.concatenate([x.reshape(-1).astype(jnp.float32)
-                             for x in jax.tree_util.tree_leaves(grads)])
-        gs = g[: opt_state["master"].shape[0]]
-        m1 = 0.9 * opt_state["m1"] + 0.1 * gs
-        m2 = 0.999 * opt_state["m2"] + 0.001 * gs * gs
-        new_master = opt_state["master"] - hyper["lr"] * m1 / (jnp.sqrt(m2) + 1e-8)
-        return None, {"master": new_master, "m1": m1, "m2": m2}
-
-    apply.external_master = True
-    return init, apply
-
-
 def _config(batch=BATCH, **overrides):
     cfg = {"train_batch_size": batch, "steps_per_print": 1000,
            "optimizer": {"type": "Adam", "params": {"lr": 1e-2}}}
@@ -90,29 +66,6 @@ def _build_standard():
     eng, _, _, _ = deepspeed_tpu.initialize(
         model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
         config_params=_config(zero_optimization={"stage": 2}))
-    return eng, _sample_batch()
-
-
-def _build_external_master_fused():
-    import deepspeed_tpu
-    model = LintModel()
-    eng, _, _, _ = deepspeed_tpu.initialize(
-        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
-        optimizer=_external_master_pair(4),
-        config_params=_config(zero_optimization={"stage": 2},
-                              zero_allow_untested_optimizer=True))
-    return eng, _sample_batch()
-
-
-def _build_external_master_accum():
-    import deepspeed_tpu
-    model = LintModel()
-    eng, _, _, _ = deepspeed_tpu.initialize(
-        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
-        optimizer=_external_master_pair(4),
-        config_params=_config(batch=BATCH * 2, gradient_accumulation_steps=2,
-                              zero_optimization={"stage": 2},
-                              zero_allow_untested_optimizer=True))
     return eng, _sample_batch()
 
 
@@ -320,8 +273,6 @@ def _build_serving_sharded():
 
 BUILDERS = {
     "standard": _build_standard,
-    "external_master_fused": _build_external_master_fused,
-    "external_master_accum": _build_external_master_accum,
     "comm_hierarchical": _build_comm_hierarchical,
     "comm_compressed": _build_comm_compressed,
     "comm_overlap": _build_comm_overlap,

@@ -606,10 +606,10 @@ def _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k
     # name applied by the caller to the kernel's output cannot mark the
     # custom_vjp's own residual vars as saveable, so every remat policy would
     # re-run this forward kernel in backward just to regenerate (out, lse) —
-    # measured: tests/perf/remat_flash_probe.py showed fwd_replayed == n_layers
-    # for 'dots', 'attn' AND 'dots+attn' before this tag. Naming them here lets
+    # measured (earlier rig, not re-measured): fwd_replayed == n_layers for 'dots',
+    # 'attn' AND 'dots+attn' before this tag. Naming them here lets
     # save_only_these_names("attn_out", "attn_lse") keep the flash bwd kernels
-    # replay-free (fwd_replayed == 0, same probe).
+    # replay-free (fwd_replayed == 0).
     from jax.ad_checkpoint import checkpoint_name
     return out, (q, k, v, checkpoint_name(out, "attn_out"),
                  checkpoint_name(lse, "attn_lse"), bias, seed)

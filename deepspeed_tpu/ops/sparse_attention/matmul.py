@@ -13,7 +13,7 @@ Sparse operands/results use a flat block format: ``[batch, nnz, block, block]`` 
 ``nnz`` enumerates ``layout.nonzero()`` in row-major ``(head, row_block, col_block)``
 order (the same canonical order as ``block_sparse_attention.build_luts``).
 
-Performance (measured, tests/perf/sparse_ops_perf.py, BigBird block 128 at seq
+Performance (earlier rig, not re-measured; BigBird block 128 at seq
 4096/8192 bf16): the composed sdd→softmax→dsd attention runs at ~2.3–2.6× the fused
 ``block_sparse_attention`` Pallas kernel's time, and 6×/149× FASTER than dense
 unfused XLA attention — these ops are a usable building block for custom sparse

@@ -35,7 +35,7 @@ from ..utils import logger
 class AsyncCheckpointer:
     """Owns the background writer for one engine. ``last_stall_ms`` is the
     caller-visible cost of the most recent ``save()`` (snapshot + join of the
-    previous writer) — the number bench.py reports as ``checkpoint_stall_ms``."""
+    previous writer) — what a step pays for a checkpoint."""
 
     def __init__(self, engine, save_dir: str, save_latest: bool = True,
                  fence_delay_s: float = 0.0):

@@ -298,18 +298,12 @@ class DeepSpeedEngine:
         else:
             self.compute_dtype = jnp.float32
 
-        # ---- external-master client optimizers ----
-        # A client (init, apply) pair whose apply carries ``external_master = True``
-        # declares that it OWNS the parameter state it updates (e.g. the bench's
-        # emulated ZeRO-2 rank, whose fp32 shard lives in opt_state and whose param
-        # refresh would come from the missing ranks' all-gather): the engine then
-        # holds NO master storage — master_params becomes a derived fp32 view of
-        # the compute params (checkpoint save only) — and does not re-derive
-        # compute params after the update. At dp=1 this removes the 4-bytes/param
-        # master burden a real 1/dp rank never carries.
-        client_apply = (optimizer[1] if isinstance(optimizer, tuple)
-                        and len(optimizer) == 2 else None)
-        self._external_master = bool(getattr(client_apply, "external_master", False))
+        # input from outside the program: the standard path would hand an apply
+        # marked ``external_master`` a master it never asked for
+        if (isinstance(optimizer, tuple) and len(optimizer) == 2
+                and getattr(optimizer[1], "external_master", False)):
+            raise ValueError("the external-master step path was removed: a client apply "
+                             "marked external_master = True is no longer supported")
 
         # ---- shardings ----
         zero_stage = self.zero_optimization_stage()
@@ -434,13 +428,6 @@ class DeepSpeedEngine:
                 pipeline=zc.offload_pipeline,
                 pipeline_depth=zc.offload_pipeline_depth,
                 max_region_elements=zc.offload_max_region_elements)
-        elif self._external_master:
-            # no engine-held master at all: the optimizer owns parameter state, and
-            # the master_params property derives an fp32 VIEW of the compute params
-            # on access (checkpoint save). Keeping a real copy would either occupy
-            # 4 bytes/param of HBM (the exact dp=1 burden this mode removes) or
-            # require a full-model D2H at construction.
-            pass
         else:
             self.master_params = jax.device_put(master_fp32, self._master_shardings)
         self.params = jax.device_put(
@@ -743,19 +730,10 @@ class DeepSpeedEngine:
     def master_params(self):
         if getattr(self, "_offload", None) is not None:
             return self._offload.params_tree()
-        if getattr(self, "_external_master", False):
-            # The optimizer owns parameter state (its fp32 shard lives in
-            # opt_state, checkpointed with it); the engine-level master is a
-            # DERIVED fp32 view of the compute params, materialized on access for
-            # checkpoint save. There is no separate storage to restore into —
-            # the setter is a no-op (a loaded master equals this view upcast).
-            return jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), self.params)
         return self._master_params_store
 
     @master_params.setter
     def master_params(self, value):
-        if getattr(self, "_external_master", False):
-            return
         self._master_params_store = value
 
     @property
@@ -954,19 +932,8 @@ class DeepSpeedEngine:
             self.optimizer = OptimizerHandle(name, self.config.optimizer_params or {},
                                              group_specs=specs)
         init = self._opt_init
-        if self._external_master:
-            # the master is a derived view (see the master_params property) — never
-            # materialize it here. init sees an ABSTRACT fp32 master for shapes and
-            # a zero master for values: an external-master optimizer owns its own
-            # state, so by contract its init reads master SIZES, not values.
-            abstract_master = jax.tree_util.tree_map(
-                lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32), self.params)
-            opt_state_zero = jax.eval_shape(init, abstract_master)
-            params_treedef = jax.tree_util.tree_structure(abstract_master)
-        else:
-            abstract_master = None
-            opt_state_zero = jax.eval_shape(init, self.master_params)
-            params_treedef = jax.tree_util.tree_structure(self.master_params)
+        opt_state_zero = jax.eval_shape(init, self.master_params)
+        params_treedef = jax.tree_util.tree_structure(self.master_params)
         # optimizer states mirror the master-param tree (Adam moments, momentum buffers):
         # give each params-shaped field the master sharding so ZeRO/pipe layouts carry over
 
@@ -987,17 +954,7 @@ class DeepSpeedEngine:
             logger.warning("client optimizer state does not mirror the param tree; "
                            "optimizer state will be replicated")
             self._opt_shardings = replicated_sharding(self.mesh, opt_state_zero)
-        if self._external_master:
-            # init sees the REAL master values (master == params at construction):
-            # the fp32 upcast happens inside the jit, so leaves are freed as init
-            # consumes them (and fold away entirely for size-only inits) — no
-            # resident fp32 master tree is ever created.
-            self.opt_state = jax.jit(
-                lambda p: init(jax.tree_util.tree_map(
-                    lambda x: x.astype(jnp.float32), p)),
-                out_shardings=self._opt_shardings)(self.params)
-        else:
-            self.opt_state = jax.jit(init, out_shardings=self._opt_shardings)(self.master_params)
+        self.opt_state = jax.jit(init, out_shardings=self._opt_shardings)(self.master_params)
         log_dist(f"Using DeepSpeed Optimizer param name {self.optimizer.name}", ranks=[0])
 
     def _configure_lr_scheduler(self, client_lr_scheduler):
@@ -1370,7 +1327,7 @@ class DeepSpeedEngine:
             out_shardings=self._grad_shardings)))
 
         def prep_grads(acc_grads, scaler_state):
-            """Shared update prologue (standard + external-master paths): fp16
+            """The update's prologue (two-program and fused step alike): fp16
             overflow check and unscale, optional predivide, global norm, clip.
             With the numerics sentinel enabled, additionally returns per-subtree
             grad sumsq + nonfinite counts (the global norm and overflow bool are
@@ -1477,122 +1434,30 @@ class DeepSpeedEngine:
         scalar_shard = NamedSharding(self.mesh, P())
         scaler_shards = jax.tree_util.tree_map(lambda _: scalar_shard, self.scaler_state)
         # per-subtree sentinel vectors are tiny replicated arrays
-        grad_sent_shards = {"grad_sumsq": scalar_shard, "grad_nonfinite": scalar_shard}
-        full_sent_shards = dict(grad_sent_shards, weight_sumsq=scalar_shard,
-                                update_sumsq=scalar_shard)
-        if self._external_master:
-            # The optimizer owns its parameter state: the update touches only
-            # opt_state (there is no engine master, and compute params are not
-            # re-derived — a real ZeRO rank refreshes them from the all-gather of
-            # every rank's updated shard).
-            def apply_update_ext(opt_state, scaler_state, acc_grads, step, hyper):
-                grads, overflow, norm, sent = prep_grads(acc_grads, scaler_state)
-
-                def do_update(_):
-                    _, new_state = opt_apply(grads, opt_state, None, step, hyper)
-                    return new_state
-
-                with ds_named_scope("ds_apply_update"):
-                    new_opt = jax.lax.cond(overflow, lambda _: opt_state, do_update,
-                                           operand=None)
-                new_scaler = ls.update(scaler_state, overflow, dynamic=dynamic,
-                                       scale_window=scale_window, min_scale=min_scale,
-                                       hysteresis=hysteresis)
-                if sent is not None:
-                    # no engine-held master here: the sentinel carries grad stats
-                    # only (weight/update norms need master storage)
-                    return new_opt, new_scaler, overflow, norm, sent
-                return new_opt, new_scaler, overflow, norm
-
-            ext_out = (self._opt_shardings, scaler_shards, scalar_shard, scalar_shard)
-            if sentinel_index is not None:
-                ext_out = ext_out + (grad_sent_shards,)
-            self._jit_apply_update = self._watch("apply_update", jax.jit(
-                apply_update_ext,
-                out_shardings=ext_out,
-                # donate the grad buffer too (the standard path donates arg 3): at
-                # 1.5B the undonated fp32 grad tree would raise peak HBM through
-                # the update by a full param-tree
-                donate_argnums=(0, 2)))
-
-            # Fused single-jit train step (gas == 1): forward+backward+update in ONE
-            # program, so the full gradient tree never materializes as jit outputs —
-            # XLA frees each grad leaf as soon as the optimizer consumed it. The
-            # two-jit split must hold params + activations + the ENTIRE grad tree
-            # simultaneously, which is exactly the ~1 param-tree of HBM that keeps a
-            # 1.5B dp=1 run off the remat=dots policy (measured: dots@8 OOMs split,
-            # fits fused — the same structure as a hand-rolled one-jit rank step).
-            # Semantics: the update runs at forward() and is COMMITTED at step();
-            # forward/backward/step must rotate strictly (enforced in forward()).
-            if grad_acc_steps == 1 and fused_grad_ok:
-                def fused_step(opt_state, scaler_state, params, step, hyper, *batch):
-                    loss, grads = loss_and_grad(params, scaler_state.cur_scale,
-                                                *batch)
-                    grads, overflow, norm, sent = prep_grads(grads, scaler_state)
-
-                    def do_update(_):
-                        _, new_state = opt_apply(grads, opt_state, None, step, hyper)
-                        return new_state
-
-                    with ds_named_scope("ds_apply_update"):
-                        new_opt = jax.lax.cond(overflow, lambda _: opt_state, do_update,
-                                               operand=None)
-                    new_scaler = ls.update(scaler_state, overflow, dynamic=dynamic,
-                                           scale_window=scale_window,
-                                           min_scale=min_scale, hysteresis=hysteresis)
-                    if sent is not None:
-                        return loss, new_opt, new_scaler, overflow, norm, sent
-                    return loss, new_opt, new_scaler, overflow, norm
-
-                fused_out = (scalar_shard, self._opt_shardings, scaler_shards,
-                             scalar_shard, scalar_shard)
-                if sentinel_index is not None:
-                    fused_out = fused_out + (grad_sent_shards,)
-                jit_fused = self._watch("fused_step", jax.jit(
-                    fused_step,
-                    out_shardings=fused_out,
-                    donate_argnums=(0,)))
-                self._jit_fused = jit_fused  # exposed for flops_profile
-
-                def run_fused(batch):
-                    step_no = jnp.asarray(self.global_steps + 1 - self.skipped_steps,
-                                          jnp.int32)
-                    outs = self._call_program(
-                        "train.grad_program", "fused_step", jit_fused,
-                        self.opt_state, self.scaler_state, self.params, step_no,
-                        self.optimizer.current_hyper(), *batch)
-                    if sentinel_index is not None:
-                        loss, new_opt, new_scaler, overflow, norm, sent = outs
-                    else:
-                        (loss, new_opt, new_scaler, overflow, norm), sent = outs, None
-                    self.opt_state = new_opt
-                    self.scaler_state = new_scaler
-                    return loss, (overflow, norm, sent)
-
-                self._run_fused_step = run_fused
-            return
-
+        sent_shards = {"grad_sumsq": scalar_shard, "grad_nonfinite": scalar_shard,
+                       "weight_sumsq": scalar_shard, "update_sumsq": scalar_shard}
         std_out = (self._master_shardings, self._opt_shardings, scaler_shards,
                    self._param_shardings, scalar_shard, scalar_shard)
         if sentinel_index is not None:
-            std_out = std_out + (full_sent_shards,)
+            std_out = std_out + (sent_shards,)
         self._jit_apply_update = self._watch("apply_update", jax.jit(
             apply_update,
             out_shardings=std_out,
             donate_argnums=(0, 1, 3, 4)))
 
-        # Opt-in fused step for STANDARD engines ({"fused_step": true}, gas == 1):
-        # same single-program structure as the external-master fused step — the
-        # grad tree never materializes as jit outputs, buying ~1 param-tree of HBM
-        # headroom (the margin that decides the remat policy for large dp=1 runs).
-        # The update executes at forward() with master/opt/params adopted
-        # immediately (their buffers are donated); step() commits bookkeeping, and
-        # strict forward/backward/step rotation is enforced in forward().
+        # Opt-in fused step ({"fused_step": true}, gas == 1): forward, backward and
+        # update in ONE program, so the grad tree never materializes as jit outputs
+        # (XLA frees each grad leaf once the optimizer consumed it), buying ~1
+        # param-tree of HBM headroom (the margin that decides the remat policy for
+        # large dp=1 runs). The update executes at forward() with master/opt/params
+        # adopted immediately (their buffers are donated); step() commits
+        # bookkeeping, and strict forward/backward/step rotation is enforced in
+        # forward().
         if (self.config.fused_step and grad_acc_steps == 1
                 and fused_grad_ok
                 and not self._cpu_checkpointing_active()):
-            def fused_step_std(master, opt_state, scaler_state, params, step, hyper,
-                               *batch):
+            def fused_step(master, opt_state, scaler_state, params, step, hyper,
+                           *batch):
                 # the whole two-jit pipeline inlined: value_and_grad feeds the
                 # SAME apply_update body (overflow skip, scaler, param re-cast)
                 loss, grads = loss_and_grad(params, scaler_state.cur_scale,
@@ -1600,33 +1465,24 @@ class DeepSpeedEngine:
                 return (loss,) + apply_update(master, opt_state, scaler_state,
                                               grads, params, step, hyper)
 
-            fused_std_out = (scalar_shard,) + std_out
-            jit_fused_std = self._watch("fused_step", jax.jit(
-                fused_step_std,
-                out_shardings=fused_std_out,
+            jit_fused = self._watch("fused_step", jax.jit(
+                fused_step,
+                out_shardings=(scalar_shard,) + std_out,
                 donate_argnums=(0, 1, 3)))
-            self._jit_fused = jit_fused_std  # exposed for flops_profile
+            self._jit_fused = jit_fused  # exposed for flops_profile
 
-            def run_fused_std(batch):
+            def run_fused(batch):
                 step_no = jnp.asarray(self.global_steps + 1 - self.skipped_steps,
                                       jnp.int32)
                 outs = self._call_program(
-                    "train.grad_program", "fused_step", jit_fused_std,
+                    "train.grad_program", "fused_step", jit_fused,
                     self.master_params, self.opt_state, self.scaler_state,
                     self.params, step_no, self.optimizer.current_hyper(), *batch)
-                if sentinel_index is not None:
-                    (loss, new_master, new_opt, new_scaler, new_params, overflow,
-                     norm, sent) = outs
-                else:
-                    (loss, new_master, new_opt, new_scaler, new_params, overflow,
-                     norm), sent = outs, None
-                self.master_params = new_master
-                self.opt_state = new_opt
-                self.scaler_state = new_scaler
-                self.params = new_params
-                return loss, (overflow, norm, sent)
+                (loss, self.master_params, self.opt_state, self.scaler_state,
+                 self.params, overflow, norm, *sent) = outs
+                return loss, (overflow, norm, sent[0] if sent else None)
 
-            self._run_fused_step = run_fused_std
+            self._run_fused_step = run_fused
 
     # ------------------------------------------------------------------ lint hooks
     @staticmethod
@@ -1714,7 +1570,7 @@ class DeepSpeedEngine:
                                                                "dtypes": [compute]}})))
             return progs
 
-        scattered_master = (not self._external_master) and any(
+        scattered_master = any(
             not s.is_fully_replicated
             for s in jax.tree_util.tree_leaves(self._master_shardings))
 
@@ -1733,12 +1589,8 @@ class DeepSpeedEngine:
                         **{"all-gather": {"min": max(1, n_buckets),
                                           "dtypes": sorted({grad_dt, "f32",
                                                             compute})}})
-            if self._external_master:
-                args = (self.opt_state, self.scaler_state, self.params, step,
-                        hyper) + batch
-            else:
-                args = (self.master_params, self.opt_state, self.scaler_state,
-                        self.params, step, hyper) + batch
+            args = (self.master_params, self.opt_state, self.scaler_state,
+                    self.params, step, hyper) + batch
             progs.append(("fused_step", self._jit_fused, args, f_man))
             return progs
 
@@ -1782,18 +1634,8 @@ class DeepSpeedEngine:
             "donation": {"check_unusable": True},
             "strict": True,
         }
-        if self._external_master:
-            # the client update is opaque: it receives ZeRO-sharded grads and
-            # may legitimately gather them onto its own master layout (the
-            # SPMD partitioner emits that as all-gathers and/or scatter+
-            # all-reduce). Constrain the wire dtype, not the op counts.
-            client_dts = sorted({grad_dt, compute, "f32"})
-            au_man["collectives"] = {"all-gather": {"dtypes": client_dts}}
-            au_man["any_reduction"] = {"dtypes": client_dts}
-            args = (self.opt_state, self.scaler_state, acc_in, step, hyper)
-        else:
-            args = (self.master_params, self.opt_state, self.scaler_state,
-                    acc_in, self.params, step, hyper)
+        args = (self.master_params, self.opt_state, self.scaler_state,
+                acc_in, self.params, step, hyper)
         progs.append(("apply_update", self._jit_apply_update, args, au_man))
         return progs
 
@@ -1812,8 +1654,7 @@ class DeepSpeedEngine:
           the fused path, where the grad tree stays internal and XLA frees
           each leaf as the optimizer consumes it (PERF.md round 5)
         - ``master``/``optimizer``: engine-held fp32 master and moment state
-          (absent under ZeRO-Offload — host tier — and external-master, whose
-          client state rides in ``optimizer`` alone)
+          (absent under ZeRO-Offload — host tier)
         - ``comm_ef``: the compressed exchange's persistent error-feedback
           buffers, when configured
         """
@@ -1835,17 +1676,7 @@ class DeepSpeedEngine:
             grad_itemsize = jnp.dtype(self._acc_dtype).itemsize
         else:
             grad_itemsize = jnp.dtype(self._grad_dtype).itemsize
-        master_numel = 0
-        if offload:
-            pass                    # master + moments live in host DRAM
-        elif self._external_master:
-            classes["optimizer"] = self.opt_state
-            # the one client-declared quantity: an external master is an
-            # Adam-style fp32 triple (master, m1, m2) over the client's shard
-            master_numel = sum(
-                int(np.prod(l.shape)) if l.shape else 1
-                for l in jax.tree_util.tree_leaves(self.opt_state)) // 3
-        else:
+        if not offload:             # else master + moments live in host DRAM
             classes["master"] = self.master_params
             classes["optimizer"] = self.opt_state
         comm_ef_bytes = 0
@@ -1864,8 +1695,6 @@ class DeepSpeedEngine:
             "dp": int(self.dp_size),
             "zero_stage": int(self.zero_optimization_stage()),
             "zero_sharded_fraction": self._zero_sharded_fraction,
-            "external_master": bool(self._external_master),
-            "master_numel": int(master_numel),
             "offload": offload,
             "fused": fused,
             "gas": int(self.gradient_accumulation_steps()),
@@ -2072,7 +1901,7 @@ class DeepSpeedEngine:
 
     def zero_grad(self):
         self._grad_acc = None
-        # Fused-step window (external-master, gas==1): the optimizer update was
+        # Fused-step window (gas==1): the optimizer update was
         # already applied at forward() (its inputs were donated and cannot be
         # restored); zeroing mid-window abandons only the step bookkeeping.
         self._fused_pending = None
@@ -2113,28 +1942,14 @@ class DeepSpeedEngine:
             return
         hyper = self.optimizer.current_hyper()
         step = jnp.asarray(self.global_steps + 1 - self.skipped_steps, jnp.int32)
-        if self._external_master:
-            outs = self._call_program(
-                "train.update_program", "apply_update", self._jit_apply_update,
-                self.opt_state, self.scaler_state, self._grad_acc, step, hyper)
-            if self._sentinel_index is not None:
-                (self.opt_state, self.scaler_state, overflow,
-                 self._last_grad_norm, self._pending_sentinel) = outs
-            else:
-                (self.opt_state, self.scaler_state, overflow,
-                 self._last_grad_norm) = outs
-            self._finish_step(self._overflowed(overflow))
-            return
         outs = self._call_program(
             "train.update_program", "apply_update", self._jit_apply_update,
             self.master_params, self.opt_state, self.scaler_state, self._grad_acc,
             self.params, step, hyper)
-        if self._sentinel_index is not None:
-            (self.master_params, self.opt_state, self.scaler_state, self.params,
-             overflow, self._last_grad_norm, self._pending_sentinel) = outs
-        else:
-            (self.master_params, self.opt_state, self.scaler_state, self.params,
-             overflow, self._last_grad_norm) = outs
+        # the numerics sentinel, when on, is one more output
+        (self.master_params, self.opt_state, self.scaler_state, self.params,
+         overflow, self._last_grad_norm, *sent) = outs
+        self._pending_sentinel = sent[0] if sent else None
         self._finish_step(self._overflowed(overflow))
 
     def _overflowed(self, overflow) -> bool:
@@ -2494,15 +2309,6 @@ class DeepSpeedEngine:
         del kind
         return tree
 
-    def _place_master(self, tree):
-        """Put a restored master tree where this engine keeps it: device shards
-        normally; under an external-master optimizer there is no master storage
-        (the master_params setter is a no-op — the view re-derives from params),
-        so skip the device transfer entirely."""
-        if getattr(self, "_external_master", False):
-            return tree
-        return jax.device_put(tree, self._master_shardings)
-
     def flops_profile(self, *inputs, peak_tflops=None):
         """Cost analysis of THIS engine's compiled train step (fwd + bwd + update)
         from XLA's own numbers — see ``utils/flops_profiler.py``. ``inputs`` is one
@@ -2519,13 +2325,9 @@ class DeepSpeedEngine:
         step_no = jnp.asarray(1, jnp.int32)
         hyper = self.optimizer.current_hyper()
         if self._jit_fused is not None:
-            if self._external_master:
-                args = (self.opt_state, self.scaler_state, self.params, step_no,
-                        hyper) + batch
-            else:
-                args = (self.master_params, self.opt_state, self.scaler_state,
-                        self.params, step_no, hyper) + batch
-            report = _profile(self._jit_fused, *args, peak_tflops=peak_tflops)
+            report = _profile(self._jit_fused, self.master_params, self.opt_state,
+                              self.scaler_state, self.params, step_no, hyper,
+                              *batch, peak_tflops=peak_tflops)
             report["programs"] = ["fused_step"]
             report["program_flops"] = {"fused_step": report["flops"]}
         else:
@@ -2535,23 +2337,16 @@ class DeepSpeedEngine:
             report["programs"] = ["loss_and_grad"]
             report["program_flops"] = {"loss_and_grad": report["flops"]}
             if self._offload is None:
-                # shapes from self.params (identical tree), NOT the master_params
-                # property — under external-master that property materializes a
-                # full fp32 view on device, the exact HBM spike the mode avoids.
-                # 1-bit Adam stacked grads carry a leading per-worker dp axis.
+                # 1-bit Adam stacked grads carry a leading per-worker dp axis
                 lead = (self.dp_size,) if self._use_stacked_grads else ()
                 grads = jax.tree_util.tree_map(
                     lambda sh, l: jax.ShapeDtypeStruct(lead + l.shape,
                                                        self._acc_dtype,
                                                        sharding=sh),
                     self._grad_shardings, self.params)
-                if self._external_master:
-                    upd = _profile(self._jit_apply_update, self.opt_state,
-                                   self.scaler_state, grads, step_no, hyper)
-                else:
-                    upd = _profile(self._jit_apply_update, self.master_params,
-                                   self.opt_state, self.scaler_state, grads,
-                                   self.params, step_no, hyper)
+                upd = _profile(self._jit_apply_update, self.master_params,
+                               self.opt_state, self.scaler_state, grads,
+                               self.params, step_no, hyper)
                 for k in ("flops", "bytes_accessed"):
                     report[k] += upd[k]
                 report["program_flops"]["apply_update"] = upd["flops"]

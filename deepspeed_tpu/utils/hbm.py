@@ -229,13 +229,8 @@ def modeled_classes(geometry) -> Dict[str, int]:
         # XLA frees each leaf as the optimizer consumes it (PERF.md round 5)
         out["grads"] = int(round(psi * int(geometry["grad_itemsize"])
                                  * frac(2)))
-    if geometry.get("offload", False):
-        pass          # master + moments live in host DRAM: zero device bytes
-    elif geometry.get("external_master", False):
-        # client-owned flat shard: master + m1 + m2 fp32, replicated (client
-        # state does not mirror the param tree, so ZeRO cannot scatter it)
-        out["optimizer"] = int(3 * int(geometry["master_numel"]) * 4)
-    else:
+    if not geometry.get("offload", False):
+        # else master + moments live in host DRAM: zero device bytes
         out["master"] = int(round(4 * psi * frac(1)))
         out["optimizer"] = int(round(8 * psi * frac(1)))
     ef = int(geometry.get("comm_ef_bytes", 0))

@@ -71,6 +71,19 @@ def instruction_count(hlo_text):
     return len(_INSTR_RE.findall(hlo_text))
 
 
+_METADATA_RE = re.compile(r",?\s*metadata=\{[^{}]*\}")
+
+
+def instructions(hlo_text):
+    """Every instruction of the optimized program as written (name, shape, opcode,
+    operands, attributes), less its ``metadata={...}``: what "instruction-identical"
+    compares. The module's header tables (FileNames, FileLocations, StackFrames) and
+    the metadata's stack frame ids name the CALLER's source lines, so two engines
+    lowered from two lines of one file differ there and in nothing that runs."""
+    return [_METADATA_RE.sub("", line.strip()) for line in hlo_text.splitlines()
+            if _INSTR_RE.match(line)]
+
+
 def optimized_hlo(jitted, *args):
     """Optimized (post-SPMD-partitioner) HLO text of ``jitted`` on ``args``."""
     return jitted.lower(*args).compile().as_text()

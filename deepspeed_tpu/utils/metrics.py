@@ -6,9 +6,8 @@ Every scalar name any observatory emits (``Telemetry/*``, ``Numerics/*``,
 ``Profile/*``, ``Anatomy/*``, ``Train/*``, ``Alerts/*``) is declared ONCE
 here with its unit, direction (lower/higher-is-better/neutral), class and a
 one-line description. The catalog is the single source of truth for "which
-way is worse" — bench.py derives its regression directions from it (no
-private LOWER_IS_BETTER list survives anywhere else) and the alert plane
-(utils/alerts.py) uses it to orient ``delta`` regression rules.
+way is worse": the alert plane (utils/alerts.py) uses it to orient ``delta``
+regression rules.
 
 ``MetricStore`` is the router: attached to a ``SummaryMonitor`` (monitor.py)
 it sees every ``add_scalar`` on every rank, validates the name against the

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """BERT masked-LM pretraining on the REAL natural-text corpus — the MLM half of
-the real-data convergence gate (VERDICT r4 #9; the reference's analog workload is
+the real-data convergence gate (the reference's analog workload is
 the BingBertSquad/Megatron real-data suites, tests/model/BingBertSquad).
 
 Byte-level MLM over tests/model/data/corpus.txt: 15% of byte positions are

@@ -1,4 +1,4 @@
-"""Real-corpus convergence gate (VERDICT r4 #9).
+"""Real-corpus convergence gate.
 
 Every other model-suite workload trains on synthetic streams; this module pins
 that the framework trains models on NATURAL text to a quality threshold — the

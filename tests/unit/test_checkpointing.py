@@ -270,3 +270,25 @@ def test_checkpoint_pipe_topology_change(tmp_path):
         l1 = float(jax.device_get(engine.eval_batch(e1_it)))
         l2 = float(jax.device_get(engine2.eval_batch(e2_it)))
         np.testing.assert_allclose(l1, l2, rtol=1e-5)
+
+
+def test_external_master_checkpoint_is_refused(tmp_path):
+    """A checkpoint whose metadata says external_master: true was written by the
+    removed step path: it holds no master and a client's flat shard as optimizer
+    state. This engine cannot hold that, and says so instead of loading part of it."""
+    import glob
+    import json
+    import os
+    from deepspeed_tpu.checkpoint.checkpointing import MANIFEST_NAME, model_states_name
+
+    engine, loader = make_engine(simple_config())
+    train_steps(engine, loader, 1)
+    engine.save_checkpoint(str(tmp_path), tag="ext")
+    (meta_path,) = glob.glob(os.path.join(str(tmp_path), "ext", model_states_name() + ".json"))
+    meta = json.load(open(meta_path))
+    assert "external_master" not in meta          # this engine no longer writes the key
+    meta["external_master"] = True
+    json.dump(meta, open(meta_path, "w"))
+    os.remove(os.path.join(str(tmp_path), "ext", MANIFEST_NAME))   # or the edit reads as a torn checkpoint
+    with pytest.raises(ValueError, match="external-master"):
+        engine.load_checkpoint(str(tmp_path), tag="ext")

@@ -1,4 +1,4 @@
-"""Compiled-collective audit (VERDICT r3 #3).
+"""Compiled-collective audit.
 
 The design stance throughout the framework is "XLA emits the collective the reference
 called NCCL/MPI for" (zero/sharding.py vs stage2.py:682-745,1441-1472; pipeline_spmd /

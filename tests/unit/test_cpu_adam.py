@@ -366,7 +366,7 @@ def test_offload_checkpoint_cross_layout(tmp_path):
 
 def test_offload_push_bytes_proportional_to_partition():
     """H2D pushes after the host step must total the local PARTITION size, not
-    x n_devices (VERDICT r2 next #9): replicated leaves ride one PCIe push + an
+    x n_devices: replicated leaves ride one PCIe push + an
     on-device broadcast."""
     model = SimpleModel(hidden_dim=16)  # leaves too small to shard -> replicated on 8 devs
     eng = _make_engine(model, offload=True)
@@ -386,7 +386,7 @@ def test_offload_push_bytes_proportional_to_partition():
 def test_offload_grad_fetch_fallback_uses_addressable_shards():
     """A grad layout that doesn't tile the master regions must be assembled from
     addressable shards (never whole-leaf device_get, which breaks multi-host), and the
-    stepped result must match the matched-layout path (ADVICE r2 medium #2)."""
+    stepped result must match the matched-layout path."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     devs = jax.devices()
     if len(devs) < 2:
@@ -415,7 +415,7 @@ def test_offload_grad_fetch_fallback_uses_addressable_shards():
 
 def test_offload_grad_accumulation_fp32_accumulator():
     """With accumulation > 1 under offload, the accumulate buffer must be fp32 even
-    though per-microbatch grads stay in the compute dtype (ADVICE r2 medium #1)."""
+    though per-microbatch grads stay in the compute dtype."""
     model = SimpleModel(hidden_dim=16)
     params = model.init(jax.random.PRNGKey(0))
     cfg = simple_config(batch=16, gradient_accumulation_steps=2)

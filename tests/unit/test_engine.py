@@ -173,7 +173,7 @@ def test_zero_sharded_state_layout(eight_devices):
 
 
 def test_zero_sharded_fraction_reported(eight_devices):
-    """VERDICT r3 #9: the engine must account what fraction of master/optimizer bytes
+    """The engine must account what fraction of master/optimizer bytes
     actually sharded, and flagship-shaped configs must exceed 90% (GPT-2-like dims
     divisible by dp; a user should never silently run 'ZeRO-2' mostly replicated)."""
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
@@ -198,7 +198,7 @@ def test_zero_sharded_fraction_reported(eight_devices):
 
 
 def test_eval_forward_is_jitted_and_compiles_once():
-    """eval() forwards must go through one cached jit (VERDICT r2 weak #3): op-by-op
+    """eval() forwards must go through one cached jit: op-by-op
     dispatch of a large model would make eval pathologically slow."""
     model = SimpleModel(HIDDEN)
     params = model.init(jax.random.PRNGKey(0))
@@ -222,11 +222,9 @@ def test_eval_forward_is_jitted_and_compiles_once():
     assert abs(l1 - ref) < 1e-5
 
 
-def test_external_master_optimizer(tmp_path):
-    """A client (init, apply) pair marked external_master owns its parameter state:
-    the engine keeps the fp32 master as host numpy (zero HBM), the update touches
-    only opt_state, and compute params are NOT re-derived (VERDICT r3 #2 — this is
-    how the 1.5B bench emulates one ZeRO-2 rank without the dp=1 master burden)."""
+def _client_pair():
+    """A client (init, apply) pair on the engine's contract: apply(grads, state, master,
+    step, hyper) -> (new master, new state). State that does not mirror the param tree."""
     import jax.numpy as jnp
 
     def init(master):
@@ -235,27 +233,31 @@ def test_external_master_optimizer(tmp_path):
 
     def apply(grads, state, master, step, hyper):
         g = jnp.concatenate([x.reshape(-1) for x in jax.tree_util.tree_leaves(grads)])
-        return master, {"shard": state["shard"] - hyper["lr"] * g[: state["shard"].size]}
+        new_master = jax.tree_util.tree_map(lambda m, x: m - hyper["lr"] * x, master, grads)
+        return new_master, {"shard": state["shard"] - hyper["lr"] * g[: state["shard"].size]}
 
-    apply.external_master = True
+    return init, apply
 
+
+def test_external_master_optimizer(tmp_path):
+    """A client (init, apply) pair (the one that used to carry the external_master
+    mark, now without it) trains through the standard two-program step: the engine
+    holds the fp32 master the pair updates, re-derives the compute params from it,
+    and round-trips the pair's own state through a checkpoint."""
     model = SimpleModel(HIDDEN)
     params = model.init(jax.random.PRNGKey(0))
     engine, _, _, _ = deepspeed_tpu.initialize(
-        model=model, model_parameters=params, optimizer=(init, apply),
+        model=model, model_parameters=params, optimizer=_client_pair(),
         config_params=simple_config(zero_optimization={"stage": 2},
                                     zero_allow_untested_optimizer=True))
-    assert engine._external_master
-    # no separate master storage exists: master_params is a derived fp32 view of
-    # the compute params (zero extra HBM — the whole point at dp=1/1.5B)
-    assert not hasattr(engine, "_master_params_store")
-    jax.tree_util.tree_map(
-        lambda m, p: np.testing.assert_allclose(np.asarray(jax.device_get(m)),
-                                                np.asarray(jax.device_get(p), np.float32),
-                                                rtol=1e-6),
-        engine.master_params, engine.params)
+    def params_are_the_master_recast():
+        jax.tree_util.tree_map(
+            lambda m, p: np.testing.assert_array_equal(np.asarray(m.astype(p.dtype)),
+                                                       np.asarray(p)),
+            engine.master_params, engine.params)
+
+    params_are_the_master_recast()
     before_master = jax.device_get(engine.master_params)
-    before_params = jax.device_get(engine.params)
     shard0 = np.asarray(jax.device_get(engine.opt_state["shard"]))
 
     x = np.random.default_rng(0).normal(size=(8, HIDDEN)).astype(np.float32)
@@ -264,64 +266,40 @@ def test_external_master_optimizer(tmp_path):
         engine.backward(loss)
         engine.step()
     assert engine.global_steps == 2
-    # opt state moved; master view and compute params did not (the optimizer owns them)
+    # the pair's state moved, and so did the master it was handed; the compute
+    # params are the updated master, re-cast
     assert np.abs(np.asarray(jax.device_get(engine.opt_state["shard"])) - shard0).max() > 0
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+    moved = jax.tree_util.tree_map(
+        lambda a, b: float(np.abs(np.asarray(a) - np.asarray(b)).max()),
         jax.device_get(engine.master_params), before_master)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
-        jax.device_get(engine.params), before_params)
+    assert min(jax.tree_util.tree_leaves(moved)) > 0
+    params_are_the_master_recast()
 
-    # checkpoint roundtrip: the optimizer-owned shard survives; no master storage
+    # checkpoint roundtrip: the pair's shard and the engine's master both survive
     shard_now = np.asarray(jax.device_get(engine.opt_state["shard"]))
+    master_now = jax.device_get(engine.master_params)
     engine.save_checkpoint(str(tmp_path))
+    for _ in range(2):
+        engine.backward(engine(x, np.tanh(x)))
+        engine.step()
     engine.load_checkpoint(str(tmp_path))
     np.testing.assert_allclose(np.asarray(jax.device_get(engine.opt_state["shard"])),
                                shard_now, rtol=1e-6)
-    assert not hasattr(engine, "_master_params_store")
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6),
+        jax.device_get(engine.master_params), master_now)
 
 
-def test_external_master_unfused_accumulation_and_rotation_contract():
-    """gas>1 external-master engines use the two-jit path (accumulated grads ->
-    apply_update_ext); at gas==1 the fused step enforces strict
-    forward/backward/step rotation."""
-    import jax.numpy as jnp
-
-    def init(master):
-        n = sum(l.size for l in jax.tree_util.tree_leaves(master))
-        return {"shard": jnp.zeros((n // 4,), jnp.float32)}
-
-    def apply(grads, state, master, step, hyper):
-        g = jnp.concatenate([x.reshape(-1) for x in jax.tree_util.tree_leaves(grads)])
-        return master, {"shard": state["shard"] - hyper["lr"] * g[: state["shard"].size]}
-
+def test_external_master_mark_is_refused():
+    """An apply marked external_master asked for a step path that is gone: the engine
+    says so at construction instead of handing it a master it never asked for."""
+    init, apply = _client_pair()
     apply.external_master = True
     model = SimpleModel(HIDDEN)
-    x = np.random.default_rng(1).normal(size=(8, HIDDEN)).astype(np.float32)
-
-    # gas = 2: unfused (grad accumulation needs materialized grads)
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
-        optimizer=(init, apply),
-        config_params=simple_config(batch=16, gradient_accumulation_steps=2))
-    assert engine._run_fused_step is None
-    shard0 = np.asarray(jax.device_get(engine.opt_state["shard"]))
-    for _ in range(2):
-        loss = engine(x, np.tanh(x))
-        engine.backward(loss)
-        engine.step()
-    assert engine.global_steps == 1
-    assert np.abs(np.asarray(jax.device_get(engine.opt_state["shard"])) - shard0).max() > 0
-
-    # gas = 1: fused; a second forward before step() must fail loudly
-    engine2, _, _, _ = deepspeed_tpu.initialize(
-        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
-        optimizer=(init, apply), config_params=simple_config())
-    assert engine2._run_fused_step is not None
-    engine2(x, np.tanh(x))
-    with pytest.raises(RuntimeError, match="rotation"):
-        engine2(x, np.tanh(x))
+    with pytest.raises(ValueError, match="external-master step path was removed"):
+        deepspeed_tpu.initialize(
+            model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
+            optimizer=(init, apply), config_params=simple_config())
 
 
 def test_fused_step_config_matches_two_jit_path():
@@ -347,6 +325,10 @@ def test_fused_step_config_matches_two_jit_path():
             engine.step()
             losses.append(float(jax.device_get(loss)))
         results[fused] = (losses, jax.device_get(engine.master_params))
+    # the last engine is the fused one: a second forward before step() fails loudly
+    engine(x, y)
+    with pytest.raises(RuntimeError, match="rotation"):
+        engine(x, y)
     np.testing.assert_allclose(results[True][0], results[False][0], rtol=1e-6)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7),

@@ -300,7 +300,7 @@ def test_chunked_long_context_matches_dense(causal):
 @pytest.mark.parametrize("causal", [False, True])
 def test_chunked_dropout_matches_global_oracle(causal):
     """Chunked tiles hash GLOBAL coordinates: dropout through the chunked path must
-    equal dense attention with the whole-sequence oracle mask (VERDICT r3 #4 — the
+    equal dense attention with the whole-sequence oracle mask (the
     long-context path previously ran without attention dropout)."""
     from deepspeed_tpu.ops.pallas.flash_attention import (_flash_attention_chunked,
                                                           dropout_keep_reference)
@@ -329,7 +329,7 @@ def test_chunked_dropout_matches_global_oracle(causal):
 def test_long_context_dispatch_raises_when_chunk_ineligible(monkeypatch):
     """Past the resident VMEM ceiling, an ineligible chunked path must raise a
     descriptive error instead of compiling the resident kernel into a Mosaic
-    failure (ADVICE r3)."""
+    failure."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     D = 32
     k = jnp.zeros((1, 1, 16384, D), jnp.bfloat16)

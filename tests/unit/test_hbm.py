@@ -170,7 +170,7 @@ def test_modeled_classes_zero2_sharding_fraction():
     psi, zsf, dp = 1000, 0.97, 8
     geo = {"kind": "training", "psi": psi, "param_itemsize": 4,
            "grad_itemsize": 4, "dp": dp, "zero_stage": 2,
-           "zero_sharded_fraction": zsf, "external_master": False,
+           "zero_sharded_fraction": zsf,
            "offload": False, "fused": False, "comm_ef_bytes": 0}
     classes = hbm.modeled_classes(geo)
     frac = 1.0 - zsf + zsf / dp

@@ -328,7 +328,7 @@ def test_mpi_identity_without_coordinator(tmp_path):
 
 @pytest.mark.slow
 def test_two_process_offload_elastic_world_change(tmp_path):
-    """The sharded-state LIFECYCLE across a world-size change (VERDICT r4 #6):
+    """The sharded-state LIFECYCLE across a world-size change:
     2 real jax.distributed processes train ZeRO-2+offload and save per-process
     region files; a FRESH single-process engine (2 virtual devices — same global
     math) elastically reloads the 2-process checkpoint (merge + re-scatter) and

@@ -1,16 +1,11 @@
 """Metric catalog tests (docs/metrics.md).
 
 The catalog (utils/metrics.py) is the single declaration point for every
-scalar name any observatory emits: unit, direction, class, description. Two
-contracts ride on it:
-
-  1. ROUTING — SummaryMonitor.add_scalar feeds the per-host metric ring
-     through the catalog on EVERY rank (before the rank-0 early return), so
-     undeclared names warn exactly once (or raise in strict mode) and every
-     host's flight-recorder dump carries a mergeable ring.
-  2. DIRECTION — bench.py derives its lower-is-better regression set from
-     the catalog instead of a private frozenset, so a new bench key without
-     a declared metric is a test failure, not a silently-unflagged number.
+scalar name any observatory emits: unit, direction, class, description. The
+contract that rides on it is ROUTING: SummaryMonitor.add_scalar feeds the
+per-host metric ring through the catalog on EVERY rank (before the rank-0
+early return), so undeclared names warn exactly once (or raise in strict mode)
+and every host's flight-recorder dump carries a mergeable ring.
 
 The drift guard at the bottom runs a REAL engine with a strict-mode store
 attached, so any emitter that grows an undeclared scalar name fails here
@@ -20,7 +15,6 @@ before it ships.
 import json
 import logging
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -35,8 +29,6 @@ from deepspeed_tpu.utils.metrics import (DEFAULT_RING_LEN, MetricCatalog,
 from simple_model import SimpleModel, random_dataset, simple_config
 
 HIDDEN = 16
-ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
 
 
 # ------------------------------------------------------------- resolution
@@ -221,64 +213,6 @@ def test_openmetrics_export_latest_only(tmp_path):
     assert text.endswith("# EOF\n")
     path = export_store(store, str(tmp_path / "om" / "metrics.txt"))
     assert open(path).read() == text
-
-
-# -------------------------------------------------------- bench directions
-
-
-def _bench():
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    import bench
-    return bench
-
-
-def test_every_regression_key_has_a_declared_metric():
-    """Satellite contract: bench keeps NO private direction list — every
-    regression key maps to a catalog metric with a real (non-neutral)
-    direction, so 'which way is worse' has exactly one source of truth."""
-    bench = _bench()
-    cat = default_catalog()
-    assert set(bench.REGRESSION_KEYS) == set(bench.REGRESSION_KEY_METRICS), \
-        "regression keys and their catalog mapping drifted apart"
-    for key, metric in bench.REGRESSION_KEY_METRICS.items():
-        spec = cat.resolve(metric)
-        assert spec is not None, f"{key} -> {metric}: undeclared metric"
-        assert spec.direction != "neutral", \
-            f"{key} -> {metric}: neutral direction can't drive a regression flag"
-
-
-def test_private_direction_list_is_retired():
-    bench = _bench()
-    assert not hasattr(bench, "LOWER_IS_BETTER_KEYS"), \
-        "bench grew its private direction list back"
-
-
-def test_catalog_reproduces_the_retired_membership():
-    """The catalog-derived set must equal the frozenset bench shipped before
-    this PR — retiring the list must not silently flip any key's direction."""
-    bench = _bench()
-    retired = frozenset(
-        k for k in bench.REGRESSION_KEYS
-        if k.endswith("_ms_p50") or k.endswith("_ms_p95")) | frozenset({
-            "extra.resilience.checkpoint_stall_ms",
-            "extra.resilience.restore_warm_vs_cold_ttft",
-            "extra.goodput.badput_checkpoint_pct",
-            "extra.serving_speculative.target_steps_per_token",
-            "extra.serving_1p5b_spec.target_steps_per_token",
-            "extra.serving_fleet.fleet_p99_ttft_ms",
-            "extra.serving_fleet.shed_rate",
-            "extra.serving_fleet.shed_rate_2x_saturation",
-            "extra.hbm.peak_by_class.params",
-            "extra.hbm.peak_by_class.grads",
-            "extra.hbm.peak_by_class.master",
-            "extra.hbm.peak_by_class.optimizer",
-            "extra.hbm.peak_by_class.compiled_temp_peak",
-            "extra.profile.exposed_ici_ms",
-            "extra.profile.exposed_dcn_ms",
-            "extra.profile.host_gap_ms",
-        })
-    assert bench.lower_is_better_keys() == retired
 
 
 # --------------------------------------------------------- catalog drift guard

@@ -154,7 +154,7 @@ def test_pipelined_wall_clock_overlaps_simulated_latency():
 
 
 def test_pipelined_timing_schema_and_overlap_fields():
-    """step_regions must publish the lane-busy/overlap schema bench.py consumes."""
+    """step_regions must publish the lane-busy/overlap schema docs/zero-offload.md describes."""
     rng = np.random.default_rng(2)
     params = _params(rng, n_leaves=4, size=900)
     opt = DeepSpeedCPUAdam(params, max_region_elements=256)

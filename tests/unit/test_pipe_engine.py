@@ -104,7 +104,7 @@ def test_pipe_matches_sequential():
 
 
 def test_spmd_loss_matches_instruction_executor_fp32():
-    """VERDICT r3 #1 acceptance: under the SAME public API and config, the SPMD
+    """Under the SAME public API and config, the SPMD
     executor's per-step losses equal the instruction executor's at fp32."""
     losses = {}
     for mode in ["spmd", "instruction"]:
@@ -279,8 +279,8 @@ def test_pipe_wall_clock_breakdown_timers():
 
 
 def test_instruction_path_buffer_bound_m_much_greater_than_s():
-    """The reference's num_pipe_buffers memory contract as a tested invariant
-    (VERDICT r2 next #10): with M >> S the channel dicts must never hold more
+    """The reference's num_pipe_buffers memory contract as a tested invariant:
+    with M >> S the channel dicts must never hold more
     in-flight payloads than the receiver's ring size — the engine asserts this on
     every Send, so a clean train_batch at M = 8S IS the proof."""
     S, M = 2, 16

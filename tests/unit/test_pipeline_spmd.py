@@ -83,7 +83,7 @@ def test_ambiguous_last_stage_args_refused_without_specs(mesh, toy):
     """A last_stage_args leaf whose leading dim == M is ambiguous (micro-batched
     labels vs a weight that coincidentally matches); the default streamed path must
     refuse and name the leaf — same contract as the drain-per-flush schedule —
-    instead of silently guessing data-sharded (ADVICE r5 medium)."""
+    instead of silently guessing data-sharded."""
     stacked, x_mb, labels_mb = toy
 
     def last_fn(y, labels_all, mb):
@@ -225,7 +225,7 @@ def test_gpt2_pipe_odd_vocab_matches_dense():
 @pytest.mark.parametrize("tp", [1, 2])
 def test_gpt2_pipe_to_dense_roundtrip(tp):
     """to_dense must invert _stack exactly — vocab padding stripped, qkv permutation
-    undone — so checkpoints can move across (num_stages, tp) topologies (ADVICE r2)."""
+    undone — so checkpoints can move across (num_stages, tp) topologies."""
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
     from deepspeed_tpu.models.gpt2_pipe import GPT2Pipe
 
@@ -248,7 +248,7 @@ def test_gpt2_pipe_to_dense_roundtrip(tp):
 
 @pytest.mark.parametrize("streamed", [True, False])
 def test_auto_flush_split_matches_single_flush(mesh, streamed):
-    """M = 8S must auto-split into rematerialized segments (VERDICT r2 next #5) with
+    """M = 8S must auto-split into rematerialized segments with
     bit-comparable loss AND grads vs the unsplit pipeline — in BOTH the streamed
     (single-fill, default) and the legacy drain-per-flush schedule. The grad check
     covers every segment-boundary micro-batch (the streamed carry's hard case)."""

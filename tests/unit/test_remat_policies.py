@@ -1,7 +1,7 @@
 """Remat-policy classification (CPU guard for the TPU-side replay probe).
 
-`tests/perf/remat_flash_probe.py` proves on the real chip that the attention
-policies compile replay-free; this suite pins the POLICY CALLABLES' decisions
+On the chip the attention policies compiled replay-free (earlier rig, not
+re-measured); this suite pins the POLICY CALLABLES' decisions
 per-equation in CI (the width-signature logic that distinguishes the fused-qkv
 and square projections must not drift)."""
 
@@ -72,7 +72,7 @@ def test_flash_policy_refuses_colliding_qkv_widths():
 def test_flash_policy_refuses_foreign_square_projection():
     """A square dot whose width disagrees with the qkv-implied embed width is
     NOT the attention output projection (e.g. an MoE/router square) and must
-    not be silently excluded (ADVICE low finding)."""
+    not be silently excluded."""
     pol = _flash_policy(exclude="square", keep_qkv=True)
     assert _decide(pol, _dot_eqn(E, 3 * E))  # establishes embed width E
     with pytest.raises(ValueError, match="MoE/router square"):

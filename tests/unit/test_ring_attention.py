@@ -178,7 +178,7 @@ def test_gpt2_sequence_parallel_trains_through_engine(mesh):
 def test_ring_dropout_matches_global_oracle(mesh, causal):
     """Attention dropout under the ring: every rank hashes GLOBAL coordinates, so
     the 8-shard ring must equal dense attention with the whole-sequence oracle
-    mask — fwd and grads (VERDICT r3 #4)."""
+    mask — fwd and grads."""
     from deepspeed_tpu.ops.pallas.flash_attention import dropout_keep_reference
     rate, seed = 0.2, 1234
     q, k, v = qkv(5)

@@ -202,7 +202,7 @@ def test_extend_position_embedding():
 @pytest.mark.parametrize("group", [1, 2, 4])
 @pytest.mark.parametrize("causal", [False, True])
 def test_grouped_kernel_parity(group, causal):
-    """Row-group union LUT + membership masks (VERDICT r2 next #2) must be
+    """Row-group union LUT + membership masks must be
     numerically identical to the ungrouped kernel and the dense oracle — fwd AND
     grads, causal included."""
     cfg = BigBirdSparsityConfig(num_heads=H, block=BLOCK)

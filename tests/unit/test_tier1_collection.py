@@ -36,14 +36,14 @@ def test_perf_scripts_never_collected_by_tier1():
         f"rename them (the perf drivers are invoked directly, not collected)")
 
 
-def test_serving_perf_driver_stays_out_of_tier1():
-    """The serving benchmark (TPU-only, minutes of wall clock) must exist as
-    a direct-invocation driver and never under a collectable name."""
+def test_chip_drivers_stay_out_of_tier1():
+    """The chip-only probes PERF.md cites (minutes of chip time each) exist as
+    direct-invocation drivers and never under a collectable name."""
     perf = REPO / "tests" / "perf"
-    assert (perf / "serving_perf.py").exists()
-    assert not (perf / "test_serving_perf.py").exists(), (
-        "serving perf driver must not be collectable — tier-1 would sys.exit "
-        "on the CPU mesh")
+    for name in ("flash_sweep.py", "moe_exchange_probe.py", "olmoe_precision_probe.py"):
+        assert (perf / name).exists()
+        assert not (perf / f"test_{name}").exists(), (
+            f"{name} must not be collectable: tier-1 would sys.exit on the CPU mesh")
 
 
 def test_request_trace_suite_is_collectable_and_golden_pinned():
