@@ -20,7 +20,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import chunked_cross_entropy, loss_chunk_for
+from .layers import chunked_cross_entropy
 
 
 @dataclass
@@ -47,7 +47,10 @@ class GPT2Config:
     fused_block: bool = False
     remat: bool = False            # activation checkpointing over blocks
     remat_policy: Any = None       # None=full recompute; "dots"=save matmul outputs
-    loss_chunk: int = 128          # seq-chunked fused CE (0 = materialize full logits)
+    # 0 = materialize the full [B, T, vocab] logits; any other value = do not (the fused
+    # head + cross-entropy of layers.chunked_cross_entropy, which picks its own tile from
+    # the shapes: the number sets nothing)
+    loss_chunk: int = 128
     compute_dtype: Any = jnp.bfloat16
     # Mixture-of-Experts (parallel/moe.py): 0 = dense FFN everywhere. When > 0,
     # every ``moe_every``-th block replaces its MLP with a switch-style MoE FFN;
@@ -508,12 +511,8 @@ class GPT2Model:
         aux = (c.moe_aux_weight * aux if self._moe is not None
                else jnp.zeros((), jnp.float32))
         with jax.named_scope("ds_loss"):
-            T = x.shape[1]
             if c.loss_chunk:
-                # largest divisor of T not exceeding loss_chunk (static shapes for XLA)
-                chunk = loss_chunk_for(T, c.loss_chunk)
-                if chunk < T:
-                    return chunked_cross_entropy(x, params["wte"], labels, chunk), aux
+                return chunked_cross_entropy(x, params["wte"], labels), aux
             logits = jnp.einsum("bth,vh->btv", x, params["wte"].astype(x.dtype),
                                 preferred_element_type=jnp.float32)
             logp = jax.nn.log_softmax(logits, axis=-1)

@@ -5,43 +5,114 @@ import jax
 import jax.numpy as jnp
 
 
-def chunked_cross_entropy(x, head, labels, chunk):
-    """Fused LM-head + softmax cross-entropy, scanned over sequence chunks so the
-    (B, T, vocab) fp32 logits tensor never materializes — at GPT-2 vocab (50k) full
-    logits for a 16×1024 batch are 3.3 GB and dominate HBM. The rematted scan body
-    recomputes each chunk's logits in backward from the (tiny) hidden states.
+# Bytes of float32 logits the head's cross-entropy makes at once on a chip: what the TPU
+# compiler keeps in a v5e's 128 MiB of VMEM from the product to the last read of it (4 x 128
+# positions of a 50,304-word vocabulary are 98 MiB). A tile four times as large goes through
+# HBM and the whole function takes a tenth to a sixth longer (PERF.md, PR 29).
+LOGITS_TILE_BYTES = 100 << 20
 
-    ``head`` is the ``[V, H]`` table (GPT-2's tied ``wte``, OLMoE's untied head);
-    negative labels are ignored. Returns the mean over the valid positions."""
-    B, T, H = x.shape
-    n = T // chunk
-    xs = x.reshape(B, n, chunk, H).swapaxes(0, 1)     # (n, B, C, H)
-    ls = labels.reshape(B, n, chunk).swapaxes(0, 1)   # (n, B, C)
-    w = head.astype(x.dtype)                          # (V, H)
 
-    def body(tot, xc_lc):
+def _tiling(B, T, V):
+    """``(shards, positions, tiles)``: the ways the batch is split over chips, and as many
+    positions of all of a chip's rows as keep its float32 logits at or under
+    ``LOGITS_TILE_BYTES``, the tiles of one length. The batch is split over the context
+    mesh's ``data`` axis (the engine traces its programs under its mesh and splits the
+    batch over that axis); inside a ``shard_map`` and without a mesh it is whole."""
+    from ..parallel.mesh import DATA_AXIS
+    mesh = jax.sharding.get_abstract_mesh()
+    shards = 1 if mesh.empty or DATA_AXIS not in mesh.auto_axes else mesh.shape[DATA_AXIS]
+    if B % shards:
+        shards = 1
+    tiles = -(-T // max(1, LOGITS_TILE_BYTES // (B // shards * V * 4)))
+    return shards, -(-T // tiles), tiles
+
+
+def _in_tiles(a, shards, positions, tiles, fill=0):
+    """``[B, T, ...]`` as ``[tiles, shards, rows, positions, ...]``, ``T`` filled up to the
+    tiles' end: the batch stays apart from the positions and each chip's rows from the
+    others', so that under a mesh a tile is every chip's own rows and nothing else."""
+    B, T = a.shape[:2]
+    pad = [(0, 0), (0, tiles * positions - T)] + [(0, 0)] * (a.ndim - 2)
+    a = jnp.pad(a, pad, constant_values=fill) if pad[1][1] else a
+    return jnp.moveaxis(a.reshape(shards, B // shards, tiles, positions, *a.shape[2:]), 2, 0)
+
+
+def _cross_entropy_tiles(x, head, labels, keep):
+    """The sum of the valid positions' losses, a tile of positions at a time; with ``keep``
+    also every tile's ``softmax - onehot`` ``[tiles, shards, rows, positions, V]``, taken
+    while the tile's logits exist and rounded to the products' dtype as it stands, within
+    [-1, 1], so that no scale can make it underflow."""
+    V = head.shape[0]
+    shards, positions, tiles = _tiling(*labels.shape, V)
+    xs = _in_tiles(x, shards, positions, tiles)
+    ls = _in_tiles(labels, shards, positions, tiles, fill=-1)    # a filled position: ignored
+    w = head.astype(x.dtype)
+
+    def tile(total, xc_lc):
         xc, lc = xc_lc
-        # contract against the UNtransposed table (dot_general picks the dim):
-        # a materialized wte.T costs a 153 MB HBM temp at GPT-2 1.5B — measured
-        # as an AllocateBuffer in the fused-step OOM breakdown
-        logits = jnp.einsum("bch,vh->bcv", xc, w,
-                            preferred_element_type=jnp.float32)  # (B, C, V)
+        # contract against the UNtransposed table (dot_general picks the dim): a
+        # materialized wte.T costs a 153 MB HBM temp at GPT-2 1.5B
+        logits = jnp.einsum("srch,vh->srcv", xc, w, preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        valid = (lc >= 0).astype(jnp.float32)  # < 0 = ignored (BERT's -100)
-        gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[..., None],
-                                   axis=-1)[..., 0]
-        return (tot[0] + jnp.sum((lse - gold) * valid),
-                tot[1] + jnp.sum(valid)), None
+        valid = lc >= 0                                # < 0 = ignored (BERT's -100)
+        gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
+        # a chip's own sum, one a shard: the sums cross the chips once, after the scan
+        total = total + jnp.sum(jnp.where(valid, lse - gold, 0.0), axis=(1, 2))
+        if not keep:
+            return total, None
+        onehot = jnp.arange(V, dtype=lc.dtype) == lc[..., None]
+        g = jnp.where(valid[..., None], jnp.exp(logits - lse[..., None]) - onehot, 0.0)
+        return total, g.astype(w.dtype)
 
-    (total, n_valid), _ = jax.lax.scan(
-        jax.checkpoint(body),
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), (xs, ls))
-    return total / jnp.maximum(n_valid, 1.0)
+    total, gs = jax.lax.scan(tile, jnp.zeros((shards,), jnp.float32), (xs, ls))
+    return jnp.sum(total), gs
 
 
-def loss_chunk_for(T, loss_chunk):
-    """The largest divisor of ``T`` not above ``loss_chunk`` (static shapes for XLA)."""
-    return next(cc for cc in range(min(loss_chunk, T), 0, -1) if T % cc == 0)
+def _valid_count(labels):
+    return jnp.maximum(jnp.sum(labels >= 0).astype(jnp.float32), 1.0)
+
+
+@jax.custom_vjp
+def chunked_cross_entropy(x, head, labels):
+    """Fused LM-head + softmax cross-entropy, a tile of sequence positions at a time, so
+    that the (B, T, vocab) float32 logits never exist: at GPT-2's vocabulary a 16 x 1024
+    batch of them is 3.3 GB. ``head`` is the ``[V, H]`` table (GPT-2's tied ``wte``,
+    OLMoE's untied head); negative labels are ignored. Returns the mean over the valid
+    positions.
+
+    Its backward is its own. When a gradient is asked for, the forward makes each tile's
+    logits ONCE and keeps their gradient in the products' dtype (``[B, T, vocab]``, half
+    the logits' bytes; JAX's derivative of a rematted scan made every tile twice and
+    rewrote the table's gradient once a chunk, under a mesh reducing it over the chips
+    each time). The backward rule is two whole products, each accumulated in float32,
+    scaled there by the incoming cotangent over the count of valid labels, and rounded
+    once; under a mesh the table's gradient crosses the chips once."""
+    with jax.named_scope("ds_loss"):
+        return _cross_entropy_tiles(x, head, labels, False)[0] / _valid_count(labels)
+
+
+def _chunked_cross_entropy_fwd(x, head, labels):
+    with jax.named_scope("ds_loss"):
+        count = _valid_count(labels)
+        total, gs = _cross_entropy_tiles(x, head, labels, True)
+        return total / count, (gs, x, head, count)
+
+
+def _chunked_cross_entropy_bwd(res, ct):
+    gs, x, head, count = res
+    with jax.named_scope("ds_loss"):
+        tiles, shards, _, positions, _ = gs.shape
+        scale = ct.astype(jnp.float32) / count
+        xs = _in_tiles(x, shards, positions, tiles)
+        dxs = scale * jnp.einsum("nsrcv,vh->nsrch", gs, head.astype(x.dtype),
+                                 preferred_element_type=jnp.float32)
+        d_head = scale * jnp.einsum("nsrcv,nsrch->vh", gs, xs,
+                                    preferred_element_type=jnp.float32)
+        dx = jnp.moveaxis(dxs, 0, 2).reshape(x.shape[0], -1, x.shape[2])[:, :x.shape[1]]
+        return dx.astype(x.dtype), d_head.astype(head.dtype), None
+
+
+chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd, _chunked_cross_entropy_bwd)
 
 
 def rms_norm(x, scale, eps):

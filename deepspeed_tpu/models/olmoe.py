@@ -22,13 +22,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .layers import chunked_cross_entropy, loss_chunk_for, rms_norm, rope
-
-# Sequence positions a step of the chunked cross-entropy takes. Every chunk's backward
-# rewrites the float32 gradient of the [vocab, hidden] head (412 MB here), so the chunk is
-# as long as the logits it makes at once allow: at 2 x 4096 tokens a chip, GPT-2's 128 cost
-# 203 ms a step in ds_loss backward, 1024 (412 MB of logits a chip) 50 ms (PERF.md, PR 26).
-LOSS_CHUNK = 1024
+from .layers import chunked_cross_entropy, rms_norm, rope
 
 
 @dataclass
@@ -167,16 +161,12 @@ class OlmoeModel:
             return jnp.einsum("bth,vh->btv", x, params["head"].astype(x.dtype),
                               preferred_element_type=jnp.float32)
 
-    def _cross_entropy(self, params, x, labels):
-        chunk = loss_chunk_for(x.shape[1], LOSS_CHUNK)
-        return chunked_cross_entropy(x, params["head"], labels, chunk)
-
     def forward_details(self, params, tokens, labels, last):
         """What a comparison with the plain reference reads: the loss and its parts, the
         logits of the ``last`` positions, and every layer's expert choices."""
         x, aux, stats = self._backbone(params, tokens, details=True)
         with jax.named_scope("ds_loss"):
-            ce = self._cross_entropy(params, x, labels)
+            ce = chunked_cross_entropy(x, params["head"], labels)
             logits = jnp.einsum("bth,vh->btv", x[:, -last:], params["head"].astype(x.dtype),
                                 preferred_element_type=jnp.float32)
         return {"loss": ce + self.config.router_aux_loss_coef * aux, "ce": ce, "aux": aux,
@@ -191,6 +181,6 @@ class OlmoeModel:
             return self.logits(params, tokens)
         x, aux, stats = self._backbone(params, tokens)
         with jax.named_scope("ds_loss"):
-            ce = self._cross_entropy(params, x, labels)
+            ce = chunked_cross_entropy(x, params["head"], labels)
         return (ce + self.config.router_aux_loss_coef * aux,
                 {"moe_load_max_over_mean": stats["load_max_over_mean"]})
