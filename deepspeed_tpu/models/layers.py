@@ -15,7 +15,8 @@ LOGITS_TILE_BYTES = 100 << 20
 def _tiling(B, T, V):
     """``(shards, positions, tiles)``: the ways the batch is split over chips, and as many
     positions of all of a chip's rows as keep its float32 logits at or under
-    ``LOGITS_TILE_BYTES``, the tiles of one length. The batch is split over the context
+    ``LOGITS_TILE_BYTES``, the tiles of one length (and dividing ``T`` where that takes at
+    most twice the tiles). The batch is split over the context
     mesh's ``data`` axis (the engine traces its programs under its mesh and splits the
     batch over that axis); inside a ``shard_map`` and without a mesh it is whole."""
     from ..parallel.mesh import DATA_AXIS
@@ -24,6 +25,10 @@ def _tiling(B, T, V):
     if B % shards:
         shards = 1
     tiles = -(-T // max(1, LOGITS_TILE_BYTES // (B // shards * V * 4)))
+    # a count that divides T, where one lies within twice the least, has no filled tail: at
+    # T = 8192 over 18,992 words six tiles of 1,366 (8,196 positions) took the TPU compiler
+    # 48 s and the step 5 ms of copies that eight of 1,024 do not (PERF.md, PR 31)
+    tiles = next((n for n in range(tiles, 2 * tiles + 1) if T % n == 0), tiles)
     return shards, -(-T // tiles), tiles
 
 
@@ -115,17 +120,24 @@ def _chunked_cross_entropy_bwd(res, ct):
 chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd, _chunked_cross_entropy_bwd)
 
 
-def rms_norm(x, scale, eps):
-    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32."""
+def rms_norm(x, scale, eps, zero_centred=False):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32; with
+    ``zero_centred`` the stored weight is the scale's distance from one (``* (1 + scale)``,
+    Qwen3-Next's norms, whose weights start at zero)."""
     xf = x.astype(jnp.float32)
     out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (out * scale.astype(jnp.float32)).astype(x.dtype)
+    scale = scale.astype(jnp.float32)
+    return (out * (1.0 + scale if zero_centred else scale)).astype(x.dtype)
 
 
-def rope(x, positions, theta):
+def rope(x, positions, theta, width=None):
     """Rotary embedding in the half-split convention (``rotate_half``): ``x`` is
     ``[B, H, T, D]``, ``positions`` ``[T]``; pair ``i`` of the first and second half
-    of ``D`` turns by ``pos * theta^(-2i/D)``. Angles and the rotation in float32."""
+    of the first ``width`` features (all ``D`` where None) turns by
+    ``pos * theta^(-2i/width)``, the features past ``width`` pass unchanged. Angles and
+    the rotation in float32."""
+    if width is not None and width < x.shape[-1]:
+        return jnp.concatenate([rope(x[..., :width], positions, theta), x[..., width:]], axis=-1)
     D = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]      # [T, D/2]

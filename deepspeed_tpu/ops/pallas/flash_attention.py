@@ -355,13 +355,22 @@ def _flash_fwd(q, k, v, seed, bias, sm_scale, causal, rate, block_q, block_k, in
         (q, k, v), seed, bias, rate)
 
 
+def _kv_head(group):
+    """The forward's index map of K and V: query row ``b`` of ``[B * H]`` reads the
+    key/value head of its group (itself where every head has its own)."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
+
+
 def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, block_k,
                      interpret):
     B, H, T, D = q.shape
+    group = H // k.shape[1]          # query heads a key/value head serves, side by side
     grid = (B * H, pl.cdiv(T, block_q))
     q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H, T, D)
-    v3 = v.reshape(B * H, T, D)
+    k3 = k.reshape(B * H // group, T, D)
+    v3 = v.reshape(B * H // group, T, D)
 
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_k=block_k, seq_len=T, has_bias=bias is not None,
@@ -373,8 +382,8 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
         grid=grid,
         in_specs=aux_specs + [
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, T, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, T, D), _kv_head(group)),
+            pl.BlockSpec((None, T, D), _kv_head(group)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -513,9 +522,10 @@ def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, int
 def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, rate,
                      block_q, block_k, interpret):
     B, H, T, D = q.shape
+    group = H // k.shape[1]
     q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H, T, D)
-    v3 = v.reshape(B * H, T, D)
+    k3 = k.reshape(B * H // group, T, D)
+    v3 = v.reshape(B * H // group, T, D)
     do3 = do.reshape(B * H, T, D)
     lse3 = lse.reshape(B * H, 1, T)
     delta3 = delta.reshape(B * H, 1, T)
@@ -527,13 +537,15 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     whole = pl.BlockSpec((None, T, D), lambda b, j: (b, 0, 0))
     row = pl.BlockSpec((None, 1, T), lambda b, j: (b, 0, 0))
     tile = pl.BlockSpec((None, block_k, D), lambda b, j: (b, j, 0))
+    kv_tile = tile if group == 1 else pl.BlockSpec((None, block_k, D),
+                                                   lambda b, j: (b // group, j, 0))
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, seq_len=T, has_bias=bias is not None,
                           rate=rate, threshold=_keep_threshold(rate),
                           has_seed=seed is not None, seg=_is_segmented(seed)),
         grid=(B * H, T // block_k),
-        in_specs=aux_specs + [whole, tile, tile, whole, row, row],
+        in_specs=aux_specs + [whole, kv_tile, kv_tile, whole, row, row],
         out_specs=[whole, tile, tile],
         out_shape=[jax.ShapeDtypeStruct((B * H, T, D), q.dtype)] * 3,
         scratch_shapes=[pltpu.VMEM((D, T), jnp.float32)],
@@ -546,7 +558,10 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     )
     with jax.named_scope("ds_flash_bwd_dkv"):
         dq, dk, dv = call(*aux, q3, k3, v3, do3, lse3, delta3)
-    return dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D)
+        if group > 1:      # a query head's dK and dV each: summed over the group in float32
+            dk, dv = (jnp.sum(a.reshape(B, H // group, group, T, D).astype(jnp.float32),
+                              axis=2).astype(k.dtype) for a in (dk, dv))
+    return dq.reshape(B, H, T, D), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,8 @@ device, or inside a ``shard_map`` that already made every axis manual, the call
 is direct.
 """
 
+import math
+
 import jax
 from jax.sharding import PartitionSpec as P
 
@@ -36,7 +38,10 @@ def shard_over_mesh(fn, operands, dims):
         ok = name in mesh.auto_axes and mesh.shape[name] > 1 and dim % mesh.shape[name] == 0
         return name if ok else None
 
-    batch, heads = operands[0].shape[:2]
+    # K and V may have fewer heads than the queries they serve (a group of query heads a
+    # key/value head): the heads are split only as finely as every such operand allows
+    batch = operands[0].shape[0]
+    heads = math.gcd(*(o.shape[1] for o, d in zip(operands, dims) if d == "bh"))
     b_axis, h_axis = axis_for(DATA_AXIS, batch), axis_for(MODEL_AXIS, heads)
     spec = {"bh": P(b_axis, h_axis), "b": P(b_axis), "": P()}
 

@@ -522,6 +522,74 @@ def piece_firsts(axis, per_chip):
     return tuple(((me - back) % n * per_chip).astype(jnp.int32) for back in _nearest_first(n))
 
 
+# ------------------------------------------------------------ a held range's rows
+def _held_pass(c, x2, weights, w_gate_up, w_down, sort):
+    """The held-range form's pass ``c``: the sorted rows ``lo + c n .. lo + (c + 1) n - 1``,
+    those of them that are held, gathered, multiplied and added into their tokens
+    ``[n, H]`` float32. ``sort = (tok, order, lo, rows_here, ends)``: each sorted row's
+    token and flat ``n * k + j``, the first held row, their count, and the held rows up to
+    each held expert."""
+    tok, order, lo, rows_here, ends = sort
+    n, F = x2.shape[0], w_down.shape[1]
+    with jax.named_scope("ds_moe_dispatch"):
+        at = c * n + jnp.arange(n, dtype=jnp.int32)
+        live = (at < rows_here)[:, None]
+        at = jnp.minimum(lo + at, tok.shape[0] - 1)
+        mine, slot = tok[at], order[at]
+        sizes = jnp.diff(jnp.clip(ends - c * n, 0, n), prepend=0).astype(jnp.int32)
+        xs = jnp.where(live, x2[mine], 0)     # a row past the held ones: nothing in, nothing back
+    with jax.named_scope("ds_moe_experts"):
+        gate_up = experts_matmul(xs, (w_gate_up,), (None,), sizes)
+        hidden = (jax.nn.silu(gate_up[:, :F].astype(jnp.float32))
+                  * gate_up[:, F:].astype(jnp.float32)).astype(xs.dtype)
+        ys = experts_matmul(hidden, (w_down,), (None,), sizes)
+    with jax.named_scope("ds_moe_combine"):
+        # the products leave rows past their groups unwritten: taken as zero
+        ys = jnp.where(live, ys.astype(jnp.float32), 0.0) * weights.reshape(-1)[slot][:, None]
+        return jnp.zeros(x2.shape, jnp.float32).at[mine].add(ys)
+
+
+def _passes(k, rows_here, n, one_pass, zero):
+    """``sum_c one_pass(c)`` over the passes that hold a held row, of at most ``k``: a pass
+    past the held rows costs a comparison, and leaves the sum where it is."""
+    def add(total, c):
+        more = lambda t: jax.tree_util.tree_map(jnp.add, t, one_pass(c))     # noqa: E731
+        return jax.lax.cond(c * n < rows_here, more, lambda t: t, total), None
+    return jax.lax.scan(add, zero, jnp.arange(k, dtype=jnp.int32))[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows(k, x2, weights, w_gate_up, w_down, sort):
+    """The held experts' part of the layer's result, ``[n, H]``: ``_held_pass`` over as
+    many passes as the held rows fill. The backward keeps the layer's input, the router's
+    weights and the sort, and makes each pass again as it takes its cotangents (a gather
+    and two small products): a row sent to an absent expert costs no buffer either way."""
+    n = x2.shape[0]
+    y = _passes(k, sort[3], n, lambda c: _held_pass(c, x2, weights, w_gate_up, w_down, sort),
+                jnp.zeros(x2.shape, jnp.float32))
+    return y.astype(x2.dtype)
+
+
+def _held_rows_fwd(k, x2, weights, w_gate_up, w_down, sort):
+    return _held_rows(k, x2, weights, w_gate_up, w_down, sort), (x2, weights, w_gate_up, w_down, sort)
+
+
+def _held_rows_bwd(k, res, dy):
+    *inputs, sort = res
+    dy = dy.astype(jnp.float32)
+
+    def one_pass(c):
+        _, back = jax.vjp(lambda *a: _held_pass(c, *a, sort), *inputs)
+        return tuple(g.astype(jnp.float32) for g in back(dy))
+
+    zero = tuple(jnp.zeros(a.shape, jnp.float32) for a in inputs)
+    grads = _passes(k, sort[3], inputs[0].shape[0], one_pass, zero)
+    return tuple(g.astype(a.dtype) for g, a in zip(grads, inputs)) + (None,)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 class DroplessMoE:
     """Top-k mixture of SiLU-gated experts that drops nothing.
 
@@ -550,24 +618,42 @@ class DroplessMoE:
     price is wire traffic that grows with the parameters and not with the tokens
     (PERF.md section 7: the token exchange is still to be measured inside a step).
 
+    The HELD-RANGE form (``held=(first, count)``) is one chip's share of a layer whose
+    experts are divided over more chips than are here (Qwen3-Next's 512 over 16): the
+    router keeps its ``num_experts`` outputs and its ``top_k``, every assignment is sorted
+    as above, and the layer computes the part of the result that the experts ``first ..
+    first + count - 1`` give: the rows sent to them, ``n`` sorted rows a pass (one pass
+    unless more than ``n`` of the ``n * k`` assignments landed here), gathered, multiplied
+    and added into their tokens. What the absent experts would add is left out, and a row
+    sent to one costs no product, no gather and no buffer; nothing of the row path is kept
+    for the backward, which makes it again. The expert arrays hold ``count`` experts. No
+    token crosses a chip: this is the layer WITHOUT its exchange, and nothing stands in for
+    the absent chips. Under a mesh every chip is a replica of the same share. With the
+    whole range held (``held=None`` or ``(0, num_experts)``) it is the layer above.
+
     ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
-    mean, over all chips).
+    mean, over all chips and all ``num_experts``); in the held-range form also
+    ``rows_here`` (float32: the assignments that landed on held experts).
     """
 
-    def __init__(self, hidden, ffn_dim, num_experts, top_k, norm_topk_prob=False):
+    def __init__(self, hidden, ffn_dim, num_experts, top_k, norm_topk_prob=False, held=None):
         self.hidden, self.ffn_dim = hidden, ffn_dim
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = norm_topk_prob
+        first, count = held or (0, num_experts)
+        assert 0 <= first and count >= 1 and first + count <= num_experts, held
+        self.held = None if count == num_experts else (first, count)
 
     # ------------------------------------------------------------------ params
     def init(self, rng, scale=0.02):
         kr, k1, k2 = jax.random.split(rng, 3)
         H, F, E = self.hidden, self.ffn_dim, self.num_experts
+        held = E if self.held is None else self.held[1]
         return {
             "router_w": jax.random.normal(kr, (H, E), jnp.float32) * scale,
-            # experts stacked on a leading E axis, gate and up side by side: three leaves
-            "w_gate_up": jax.random.normal(k1, (E, H, 2 * F), jnp.float32) * scale,
-            "w_down": jax.random.normal(k2, (E, F, H), jnp.float32) * scale,
+            # experts stacked on a leading axis, gate and up side by side: three leaves
+            "w_gate_up": jax.random.normal(k1, (held, H, 2 * F), jnp.float32) * scale,
+            "w_down": jax.random.normal(k2, (held, F, H), jnp.float32) * scale,
         }
 
     @staticmethod
@@ -581,7 +667,7 @@ class DroplessMoE:
         axis where it is automatic, larger than one, and divides experts and batch."""
         from .mesh import DATA_AXIS
         mesh = jax.sharding.get_abstract_mesh()
-        if mesh.empty or DATA_AXIS not in mesh.auto_axes:
+        if self.held is not None or mesh.empty or DATA_AXIS not in mesh.auto_axes:
             return None, None
         ep = mesh.shape[DATA_AXIS]
         if ep == 1 or self.num_experts % ep or batch % ep:
@@ -629,9 +715,9 @@ class DroplessMoE:
                                             num_keys=1, is_stable=True)
             starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
             group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
-            inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
+            if self.held is None:
+                inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
             tok = order // k
-
         def routed(x2, weights, w_gate_up, w_down):
             dt = x2.dtype
             w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
@@ -657,8 +743,16 @@ class DroplessMoE:
         # It makes the gathered rows and the gated activation again (a gather and an
         # elementwise pass) and fetches the experts' weights again: kept, the four
         # layers' gathered weights would be 3.2 GB a chip.
-        y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
-            "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)
+        if self.held is None:
+            y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
+                "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)
+        else:
+            # the held range's rows are one run of the sorted order: its start, its length,
+            # and the held rows up to each held expert
+            first, count = self.held
+            lo, rows_here = starts[first], starts[first + count] - starts[first]
+            sort = (tok, order, lo, rows_here, jnp.cumsum(group_sizes[first:first + count]))
+            y = _held_rows(k, x2, weights, w_gate_up.astype(x2.dtype), w_down.astype(x2.dtype), sort)
 
         counts = group_sizes.astype(jnp.float32)
         tokens = n
@@ -667,6 +761,8 @@ class DroplessMoE:
             tokens = n * jax.lax.axis_size(axis)
         aux = E * jnp.sum(jax.lax.stop_gradient(counts) / (tokens * k) * prob_sum / tokens)
         stats = {"load_max_over_mean": jnp.max(counts) / (tokens * k / E)}
+        if self.held is not None:
+            stats["rows_here"] = rows_here.astype(jnp.float32)
         if details:
             stats["experts"] = jnp.sort(experts, axis=-1).reshape(shape[:-1] + (k,))
             stats["router_logits"] = logits.reshape(shape[:-1] + (E,))

@@ -71,6 +71,42 @@ def test_flash_attention_compiles_for_v5e(chip, shape, backward):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+def test_grouped_query_flash_attention_compiles_for_v5e(chip, backward):
+    """Qwen3-Next's full-attention layer as ``qwen3next_ep16_train_1chip`` calls it: 16 query
+    heads of 256 over 2 key/value heads at 8,192 positions, K and V tiles indexed by group."""
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, interpret=False)
+
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 256), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, 256), jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn) if backward else attn, q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
+    """Qwen3-Next's expert layer at its published widths as one chip of sixteen holds it: a
+    router over 512, 32 experts of 512 held, 8,192 tokens; the megablox grouped matmul inside
+    the passes' ``cond``, forward and the hand-written backward."""
+    from deepspeed_tpu.parallel.moe import DroplessMoE
+    layer = DroplessMoE(2048, 512, 512, 10, norm_topk_prob=True, held=(0, 32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the grouped matmul's kernel
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16, sharding=chip) for k, v in shapes.items()}
+    assert params["w_gate_up"].shape == (32, 2048, 1024) and params["router_w"].shape == (2048, 512)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=chip)
+
+    def loss(params, x):
+        y, aux, _ = layer.apply(params, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no buffer of n * k rows: the temporaries stay under the weights' gradients in float32
+    # (0.4 GB) and a few passes of 8,192 rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
 def test_block_sparse_attention_compiles_for_v5e(chip, backward):
     heads, seq, block = 16, 8192, 128
     layout = np.asarray(BSLongformerSparsityConfig(
