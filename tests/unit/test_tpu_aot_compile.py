@@ -83,6 +83,25 @@ def test_grouped_query_flash_attention_compiles_for_v5e(chip, backward):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("T", [8192, 1024])
+def test_the_delta_rule_kernels_compile_for_v5e(chip, T, dtype):
+    """The gated delta rule as ``qwen3next_ep16_train_1chip`` calls it: 16 key heads serving 32
+    value heads of 128, forward and the hand-written backward, in bfloat16 (the step) and on
+    float32 arrays (the set-up's check: 8,192 positions for o, 1,024 for the gradients)."""
+    from deepspeed_tpu.ops.delta_rule import gated_delta_rule
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    args = (shape(1, T, 16, 128), shape(1, T, 16, 128), shape(1, T, 32, 128),
+            shape(1, T, 32, dt=jnp.float32), shape(1, T, 32, dt=jnp.float32))
+    loss = lambda *a: jnp.sum(gated_delta_rule(*a, interpret=False).astype(jnp.float32) ** 2)  # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ds_delta_rule_fwd" in text and "ds_delta_rule_bwd" in text
+    # what the backward is handed: a block's states, a chunk's inverses and updates (0.24 GB
+    # at 8,192 positions), never a state a chunk (1.07 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9 * T / 8192
+
+
 def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
     """Qwen3-Next's expert layer at its published widths as one chip of sixteen holds it: a
     router over 512, 32 experts of 512 held, 8,192 tokens; the megablox grouped matmul inside
