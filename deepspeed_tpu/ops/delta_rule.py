@@ -28,16 +28,19 @@ BLOCK_CHUNKS = 8           # chunks a grid step: the spacing of the states the b
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2,))
-def causal_conv(x, w, silu=False):
-    """Depthwise causal convolution over time, no bias: ``x [B, T, C]``, ``w [W, C]``,
-    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the first token, then SiLU
-    where ``silu``. Summed in float32, returned in ``x``'s dtype; the backward makes the
+def causal_conv(x, w, silu=False, bias=None):
+    """Depthwise causal convolution over time: ``x [B, T, C]``, ``w [W, C]``,
+    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the first token, plus
+    ``bias [C]`` where one is given (a Mamba-2 mixer's; the delta-rule mixer's has none), then
+    SiLU where ``silu``. Summed in float32, returned in ``x``'s dtype; the backward makes the
     sum again from ``x`` and ``w`` (elementwise passes), so that no float32 copy of the
     ``W`` shifted inputs is kept."""
     W, T = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
     y = sum(xp[:, j:j + T].astype(jnp.float32) * wf[j] for j in range(W))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return (jax.nn.silu(y) if silu else y).astype(x.dtype)
 
 

@@ -102,6 +102,55 @@ def test_the_delta_rule_kernels_compile_for_v5e(chip, T, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9 * T / 8192
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+def test_grouped_query_flash_attention_at_width_64_compiles_for_v5e(chip, backward):
+    """Granite 4.0-H's attention layer as ``granite4h_d10_train_1chip`` calls it: 32 query heads
+    of 64 over 8 key/value heads at 8,192 positions, the published scale 1/64, no positions."""
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, sm_scale=1 / 64, interpret=False)
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn) if backward else attn, q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_the_state_space_scan_compiles_for_v5e(chip, dtype):
+    """The chunked scan as ``granite4h_d10_train_1chip`` calls it: 64 heads of 64 with a state
+    of 128 at 8,192 positions, forward and JAX's backward, in bfloat16 (the step) and on
+    float32 arrays (the set-up's check). The decay matrices of a few heads exist together:
+    1.35 GB of temporaries in bfloat16, where all 64 heads' at once took 2.6."""
+    from deepspeed_tpu.ops.ssd import ssd_scan
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    f32 = jnp.float32
+    args = (shape(1, 8192, 64, 64), shape(1, 8192, 64, dt=f32), shape(64, dt=f32),
+            shape(1, 8192, 128), shape(1, 8192, 128), shape(64, dt=f32))
+    loss = lambda *a: jnp.sum(ssd_scan(*a).astype(jnp.float32) ** 2)  # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1.6e9 if dtype == jnp.bfloat16 else 2.2e9)
+
+
+def test_a_recomputed_state_space_block_and_the_tied_head_compile_for_v5e(chip, monkeypatch):
+    """The gradient program of ``granite4h_d10_train_1chip`` at its widths and 8,192 positions,
+    cut to one Mamba-2 block and the attention block (whole blocks recomputed, the tied head's
+    cross-entropy over 12,544 words): the flash kernel is in it, and what it needs beside its
+    parameters and their gradients is a block's internals (2.43 GB here; the cell's ten layers
+    compile to 2.38 GB of temporaries; 12.35 GB of training state leave 3.6)."""
+    from deepspeed_tpu.models.granite_hybrid import GraniteHybridConfig, GraniteHybridModel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the flash kernel, not its interpreter
+    model = GraniteHybridModel(GraniteHybridConfig(
+        vocab_size=12544, num_hidden_layers=2, layer_types=("mamba", "attention"), remat=True))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 76_182_976 + 60_821_504 + 25_692_160
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(model.apply)).lower(params, tokens, tokens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
+
+
 def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
     """Qwen3-Next's expert layer at its published widths as one chip of sixteen holds it: a
     router over 512, 32 experts of 512 held, 8,192 tokens; the megablox grouped matmul inside
