@@ -57,8 +57,9 @@ def inputs(T, dtype, seed=0):
     return (q, k, v, jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32)), do
 
 
-def device_ms(fn, args, calls=4):
-    """``(ms a call of every device operation, {kernel name: ms a call})`` of ``fn(*args)``."""
+def device_ms(fn, args, calls=4, kernels_named="ds_delta_rule_"):
+    """``(ms a call of every device operation, {kernel name: ms a call})`` of ``fn(*args)``,
+    the kernels those whose name starts with ``kernels_named``."""
     from jax.profiler import ProfileData
     f = jax.jit(fn)
     jax.block_until_ready(f(*args))
@@ -80,7 +81,7 @@ def device_ms(fn, args, calls=4):
                     continue
                 for e in line.events:
                     busy.append((e.start_ns, e.start_ns + e.duration_ns))
-                    m = re.search(r"ds_delta_rule_\w+?(?=\.\d+|$|[^\w])", e.name)
+                    m = re.search(kernels_named + r"\w+?(?=\.\d+|$|[^\w])", e.name)
                     if m:
                         kernels[m.group(0)] += e.duration_ns * 1e-6
         # the union of the operations' intervals: a loop's own event spans its body's

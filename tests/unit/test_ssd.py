@@ -9,7 +9,8 @@ import pytest
 
 from benchmarks.reference import granite_hybrid_reference as ref
 from deepspeed_tpu.ops.delta_rule import causal_conv
-from deepspeed_tpu.ops.ssd import CHUNK, segment_sums, ssd_scan
+from deepspeed_tpu.ops.pallas import ssd as kernels
+from deepspeed_tpu.ops.ssd import CHUNK, ssd_scan
 
 NAMES = ("x", "dt", "A", "B", "C", "D")
 
@@ -65,21 +66,55 @@ def test_a_slow_and_a_fast_head_alone(head, rate, highest):
     assert through > 1e-3
 
 
+def segment_sums(a):
+    """What the kernels make a tile's decays from: one head's ``sum_{j < m <= i} a_m [Q, Q]``
+    out of the prefix sums' pairs, run as the kernels run it."""
+    from jax.experimental import pallas as pl
+    Q = a.shape[0]
+
+    def kernel(a_ref, out_ref):
+        tile = kernels._Tile(Q, 1)
+        dec = kernels._decays(a_ref[...], jnp.ones((1, 1), jnp.float32), tile)
+        out_ref[...] = kernels._segment_sums(dec, 0)
+
+    return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct((Q, Q), jnp.float32), interpret=True)(a[None])
+
+
 def test_segment_sums_start_under_the_diagonal():
     a = -jnp.asarray([0.5, 1.0, 2.0, 4.0])
     s = segment_sums(a)
     assert np.array_equal(np.diag(s), np.zeros(4))
     assert float(s[3, 0]) == -7.0 and float(s[2, 1]) == -2.0 and float(s[1, 0]) == -1.0
-    assert np.all(np.isneginf(np.asarray(s)[np.triu_indices(4, 1)]))
-    # a long fast chunk: a difference of two cumulative sums would round at the chunk's
-    # whole decay (1,600); the segment's own sum is exact to its own size
+    # a long fast tile: a difference of two float32 cumulative sums would round at the tile's
+    # whole decay (1,600); the pairs' difference is exact to the segment's own size
     a = jnp.full((256,), -6.4).at[255].set(-1e-3)
-    assert float(segment_sums(a)[255, 254]) == pytest.approx(-1e-3, rel=1e-6)
+    s = segment_sums(a)
+    assert float(s[255, 254]) == pytest.approx(-1e-3, rel=1e-6)
+    assert float(s[254, 253]) == pytest.approx(-6.4, rel=1e-6)
+    assert float(s[255, 0]) == pytest.approx(-6.4 * 254 - 1e-3, rel=1e-6)
+    # and the decays are masked above the diagonal before the exponential
+    tile = kernels._Tile(4, 1)
+    assert np.array_equal(np.asarray(tile.lower), np.tril(np.ones((4, 4), bool)))
+
+
+def pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs its equations hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += pallas_calls(sub)
+    return found
 
 
 def test_the_scan_keeps_its_inputs_dtype_and_a_float32_state():
-    """bfloat16 operands give a bfloat16 output near the float32 one, and the state handed
-    from chunk to chunk is float32 whatever the operands."""
+    """bfloat16 operands give a bfloat16 output near the float32 one, and the state's scratch
+    and the states the backward is kept are float32 whatever the operands."""
     args32 = operands(64, seed=3)
     args16 = tuple(a.astype(jnp.bfloat16) if i in (0, 3, 4) else a for i, a in enumerate(args32))
     got = jax.jit(lambda *a: ssd_scan(*a, 16))(*args16)
@@ -87,9 +122,14 @@ def test_the_scan_keeps_its_inputs_dtype_and_a_float32_state():
     rounded = tuple(a.astype(jnp.float32) for a in args16)
     want = jax.jit(ref.ssm_recurrent)(*rounded)
     assert np.linalg.norm(got.astype(jnp.float32) - want) <= 2e-2 * np.linalg.norm(want)
-    carried = [e for e in jax.make_jaxpr(lambda *a: ssd_scan(*a, 16))(*args16).eqns
-               if e.primitive.name == "scan"]
-    assert len(carried) == 1 and all(v.aval.dtype == jnp.float32 for v in carried[0].outvars)
+    call, = pallas_calls(jax.make_jaxpr(lambda *a: ssd_scan(*a, 16))(*args16).jaxpr)
+    assert call.params["name"] == "ds_ssd_scan_fwd"
+    y, kept = call.outvars
+    assert y.aval.dtype == jnp.bfloat16 and kept.aval.dtype == jnp.float32
+    # the state's scratch (and the tile's C B^T) beside the operands
+    scratch = call.params["jaxpr"].invars[-2:]
+    assert [v.aval.dtype for v in scratch] == [jnp.float32] * 2
+    assert scratch[0].aval.shape[1:] == kept.aval.shape[-2:] == (16, 4 * 8)
     assert CHUNK == 256
 
 
