@@ -20,6 +20,7 @@ but the mechanics are functional JAX:
   (engine.py:1016-1089, stage2.py:682-745, 1441-1472).
 """
 
+import collections
 import functools
 import os
 import time
@@ -37,6 +38,7 @@ from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_from_mpu
 from ..utils import SynchronizedWallClockTimer, log_dist, logger, spans
 from ..utils.cluster import named_scope as ds_named_scope
 from ..utils.compile_cache import configure_compile_cache
+from ..utils.hbm import device_memory_stats
 from .config import DeepSpeedConfig
 from .constants import (ADAM_OPTIMIZER, ADAMW_OPTIMIZER, LAMB_OPTIMIZER, ONEBIT_ADAM_OPTIMIZER,
                         SGD_OPTIMIZER, ROUTE_TRAIN,
@@ -470,6 +472,9 @@ class DeepSpeedEngine:
         self._step_programs = spans.Programs()      # held here, so it goes with the engine
         self._span_engine = self._spans.new_engine(self._step_programs)
         self._step_span = None
+        # the last steps' losses, unfetched: how many the device has not made yet when a
+        # step begins is the host's lead over it (``in_flight`` of ``train.step``)
+        self._step_losses = collections.deque(maxlen=8)
 
         # module-level activation-checkpointing config (reference engine.py:385-400).
         # Only push settings into the process-global module when THIS config carries
@@ -1801,7 +1806,8 @@ class DeepSpeedEngine:
             if self._step_span is not None:      # a window that never reached step()
                 self._spans.end(self._step_span)
             self._step_span = self._spans.begin(
-                "train.step", engine=self._span_engine, step=self.global_steps, root=True)
+                "train.step", engine=self._span_engine, step=self.global_steps, root=True,
+                in_flight=sum(not loss.is_ready() for loss in self._step_losses))
         with self._spans.span("train.put_batch", engine=self._span_engine):
             batch = tuple(self.shard_batch(x) if not isinstance(x, jax.Array) else x
                           for x in inputs)
@@ -2032,7 +2038,20 @@ class DeepSpeedEngine:
             }
         return overflow
 
+    def _note_memory_in_use(self):
+        """``bytes_in_use`` of the open ``train.step``: the most any of this process's
+        devices holds now that the step's programs are enqueued (the runtime allocates a
+        program's buffers when it is enqueued, not when it runs), and ``bytes_limit`` on
+        the engine's first step. Nothing where the backend reports nothing (the CPU)."""
+        stats = [s for s in map(device_memory_stats, self.mesh.local_devices) if s]
+        if not stats or self._step_span is None:
+            return
+        self._step_span.attrs["bytes_in_use"] = max(s.get("bytes_in_use", 0) for s in stats)
+        if not self._step_losses and all("bytes_limit" in s for s in stats):   # no step before
+            self._step_span.attrs["bytes_limit"] = min(s["bytes_limit"] for s in stats)
+
     def _finish_step(self, overflowed: bool):
+        self._note_memory_in_use()
         self._grad_acc = None
         if overflowed:
             self.skipped_steps += 1
@@ -2082,6 +2101,8 @@ class DeepSpeedEngine:
             # disarm the watchdog and allgather this step's heartbeat on the
             # host CPU world; host 0 derives and emits the Cluster/* scalars
             self._cluster.on_step_end(self.global_steps)
+        if self._window_losses:
+            self._step_losses.append(self._window_losses[-1])
         self._window_losses = []
         interval = self.config.resilience_save_interval
         if (self._resilience is not None and interval > 0

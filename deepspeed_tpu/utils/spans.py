@@ -15,11 +15,15 @@ fetches from it or adds to a compiled program.
         ...
     rec.spans()        # the ring, oldest first
     rec.counters(eid)  # {"program.builds[loss_and_grad]": 2, ...}
-    rec.programs(eid)  # lazy: {program: {"module": ..., "ops": {instruction: op_name}}}
+    rec.programs(eid)  # lazy: {program: {"module": ..., "ops": {instruction: op_name},
+                       #                  "memory": {"argument": bytes, ..., "code": bytes}}}
 
 A span's parent is the innermost span of its engine that was open on the same thread
 when it began (two engines may take turns on one thread), or of any engine if it names
-none. The four ``compile.*`` spans come from ``jax.monitoring``'s duration events, which
+none. Beside its wall seconds a span keeps the CPU seconds of its thread (``cpu_s``,
+``time.thread_time`` at ``begin`` and ``end``, children included): wall less CPU is the
+time the thread was held, by the runtime inside a program call or by the operating system
+anywhere. The synthetic ``compile.*`` spans carry none. The four ``compile.*`` spans come from ``jax.monitoring``'s duration events, which
 arrive when the work is over: each is put down as ``[now - seconds, now]`` under whichever
 span is open, so a build lands in the step and the program call that caused it.
 """
@@ -33,6 +37,7 @@ import weakref
 import jax
 
 clock = time.perf_counter
+cpu_clock = time.thread_time          # the calling thread's CPU seconds, user and system
 
 RING_SPANS = 8192                   # some five minutes of one-program-pair steps
 RING_DEVICE_SCALARS = 1024          # steps whose device scalars are kept, a few bytes each
@@ -43,22 +48,26 @@ COMPILE_EVENTS = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
 }
 BUILD_SPAN = "compile.backend"      # one a built or loaded executable
+MEMORY_SIZES = {"argument": "argument_size_in_bytes", "output": "output_size_in_bytes",
+                "alias": "alias_size_in_bytes", "temp": "temp_size_in_bytes",
+                "code": "generated_code_size_in_bytes"}      # of ``memory_analysis()``
 MIN_COMPILE_SPAN_S = 1e-3           # shorter ones are counted and not kept: the inner jits
                                     # of one trace come by the thousand and would empty the ring
 
 
 class Span:
     __slots__ = ("id", "parent", "engine", "name", "start", "end", "step", "attrs",
-                 "_annotation")
+                 "cpu_s", "_cpu_start", "_annotation")
 
     def __init__(self, id, parent, engine, name, start, step, attrs):
         self.id, self.parent, self.engine, self.name = id, parent, engine, name
         self.start, self.end, self.step, self.attrs = start, None, step, attrs
-        self._annotation = None
+        self.cpu_s = self._cpu_start = self._annotation = None
 
     def as_dict(self):
         return {"id": self.id, "parent": self.parent, "engine": self.engine,
                 "name": self.name, "start": self.start, "end": self.end,
+                "cpu_s": self.cpu_s,
                 "step": self.step, "attrs": dict(self.attrs) if self.attrs else {}}
 
 
@@ -123,6 +132,7 @@ class Recorder:
         span._annotation = jax.profiler.TraceAnnotation(name)
         span._annotation.__enter__()
         stack.append(span)
+        span._cpu_start = cpu_clock()
         return span
 
     def end(self, span):
@@ -141,6 +151,7 @@ class Recorder:
         closing = [s for s in stack[at:] if s.id in inside]
         stack[at:] = others
         for s in reversed(closing):
+            s.cpu_s = cpu_clock() - s._cpu_start
             s._annotation.__exit__(None, None, None)
             s._annotation = None
             s.end = clock()
@@ -226,19 +237,31 @@ class Programs:
         self._catalog.pop(program, None)
 
     def catalog(self):
-        """``{program: {"module": HloModule name, "ops": {instruction: op_name}}}`` for the
-        step programs the engine has run: every instruction of the optimized program
-        with the scope path JAX gave it ("" where the compiler made it up). Computed on
-        request and kept: it compiles (or loads from the persistent cache), so nobody
-        asks inside a measured window."""
+        """``{program: {"module": HloModule name, "ops": {instruction: op_name}, "memory":
+        {"argument", "output", "alias", "temp", "code": bytes}}}`` for the step programs the
+        engine has run: every instruction of the optimized program with the scope path JAX
+        gave it ("" where the compiler made it up), and the program's own need of device
+        memory as the compiler states it (``memory_analysis()``; None where the backend
+        states none). Computed on request and kept: it compiles (or loads from the
+        persistent cache), so nobody asks inside a measured window."""
         from . import hlo
         for program, (jitted, args) in self._kept.items():
             if program not in self._catalog:
-                text = jitted.lower(*args).compile().as_text()
+                compiled = jitted.lower(*args).compile()
+                text = compiled.as_text()
                 ops = dict.fromkeys(hlo.instruction_names(text), "")
                 ops.update(hlo.instruction_op_names(text))
-                self._catalog[program] = {"module": hlo.module_name(text), "ops": ops}
+                self._catalog[program] = {"module": hlo.module_name(text), "ops": ops,
+                                          "memory": _memory_sizes(compiled)}
         return dict(self._catalog)
+
+
+def _memory_sizes(compiled):
+    """``compiled.memory_analysis()`` as a dict of bytes a device; None where it has none."""
+    stated = compiled.memory_analysis()
+    if stated is None:
+        return None
+    return {name: int(getattr(stated, field)) for name, field in MEMORY_SIZES.items()}
 
 
 _RECORDER = None

@@ -251,3 +251,168 @@ def test_scopes_and_spans_add_no_instruction_to_either_program(monkeypatch):
     assert "ds_mlp" not in bare[0] and "ds_fwd_bwd" not in bare[0]
     for a, b in zip(with_scopes, bare):
         assert instruction_count(a) == instruction_count(b) > 0
+
+
+# ------------------------------------------- CPU beside wall, the lead, the memory
+def _spin(seconds):
+    """Burn ``seconds`` of this thread's CPU."""
+    until = spans.cpu_clock() + seconds
+    while spans.cpu_clock() < until:
+        pass
+
+
+def _wall(span):
+    return span["end"] - span["start"]
+
+
+@pytest.mark.parametrize("work", ["sleeps", "spins"])
+def test_a_spans_cpu_seconds_tell_work_from_being_held(work):
+    import time
+    rec = spans.Recorder()
+    with rec.span("probe"):
+        time.sleep(0.05) if work == "sleeps" else _spin(0.05)
+    got = rec.spans()[0]
+    held = _wall(got) - got["cpu_s"]
+    assert 0 <= got["cpu_s"] <= _wall(got)          # the CPU clock is read inside the wall clock's
+    if work == "sleeps":                            # by an order of magnitude, not by a margin
+        assert got["cpu_s"] < _wall(got) / 10 and held > 0.9 * _wall(got)
+    else:
+        assert got["cpu_s"] >= 0.05 and got["cpu_s"] > _wall(got) / 10
+
+
+def test_a_childs_cpu_is_inside_its_parents_and_a_compile_span_has_none():
+    rec = spans.Recorder()
+    with rec.span("train.step", engine=1) as step:
+        with rec.span("train.grad_program", program="p") as call:
+            _spin(0.02)
+            rec.on_compile_event(BACKEND, 0.01)
+        _spin(0.01)
+    assert 0.02 <= call.cpu_s <= step.cpu_s - 0.01
+    by_name = {s["name"]: s for s in rec.spans()}
+    assert by_name["compile.backend"]["cpu_s"] is None
+    assert by_name["train.step"]["cpu_s"] == step.cpu_s and "cpu_s" in by_name["train.grad_program"]
+
+
+class _Loss:
+    """A step's loss as the engine keeps it: asked whether it is ready, and nothing else."""
+
+    def __init__(self, ready):
+        self.ready, self.asked = ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+    def __getattr__(self, name):          # block_until_ready, __array__, __float__, ...
+        raise AssertionError(f"the engine touched a kept loss: {name}")
+
+
+def _simple_engine(**config):
+    model = SimpleModel(16)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=model.init(jax.random.PRNGKey(0)),
+        config_params=simple_config(bf16={"enabled": True}, **config))
+    data = random_dataset(8, 16)
+    xs = np.stack([d[0] for d in data]).astype(jnp.bfloat16)
+    ys = np.stack([d[1] for d in data]).astype(jnp.bfloat16)
+    return engine, xs, ys
+
+
+def _steps_of(engine):
+    return [s for s in spans.recorder().spans(engine=engine._span_engine)
+            if s["name"] == "train.step"]
+
+
+def _step(engine, xs, ys):
+    loss = engine(xs, ys)
+    engine.backward(loss)
+    engine.step()
+    return loss
+
+
+def test_in_flight_counts_the_losses_that_are_not_ready_and_waits_for_none():
+    engine, xs, ys = _simple_engine()
+    _step(engine, xs, ys)
+    kept = [_Loss(True), _Loss(False), _Loss(False), _Loss(True), _Loss(False)]
+    engine._step_losses.extend(kept)
+    _step(engine, xs, ys)
+    first, second = _steps_of(engine)
+    assert first["attrs"]["in_flight"] == 0          # no step before it
+    # the five stand-ins, and the first step's own loss, which may or may not be made yet
+    assert second["attrs"]["in_flight"] in (3, 4)
+    assert all(k.asked == 1 for k in kept)
+
+
+def test_the_engine_keeps_its_last_eight_losses_and_no_more():
+    engine, xs, ys = _simple_engine()
+    losses = [_step(engine, xs, ys) for _ in range(10)]
+    assert len(engine._step_losses) == 8
+    assert all(a is b for a, b in zip(engine._step_losses, losses[2:]))
+    jax.block_until_ready(losses)
+    _step(engine, xs, ys)
+    flights = [s["attrs"]["in_flight"] for s in _steps_of(engine)]
+    assert len(flights) == 11 and flights[0] == 0 and flights[-1] == 0     # a drained device
+    assert all(0 <= f <= min(i, 8) for i, f in enumerate(flights))
+
+
+def test_in_flight_is_kept_on_the_fused_step_path():
+    engine, xs, ys = _simple_engine(fused_step=True)
+    assert engine._run_fused_step is not None
+    _step(engine, xs, ys)
+    engine._step_losses.extend([_Loss(False), _Loss(False)])
+    _step(engine, xs, ys)
+    first, second = _steps_of(engine)
+    assert first["attrs"]["in_flight"] == 0 and second["attrs"]["in_flight"] in (2, 3)
+    assert len(engine._step_losses) == 4
+
+
+def test_bytes_in_use_is_absent_and_harmless_where_the_backend_reports_nothing():
+    engine, xs, ys = _simple_engine()
+    assert all(d.memory_stats() is None for d in engine.mesh.local_devices)    # the CPU
+    _step(engine, xs, ys)
+    (step,) = _steps_of(engine)
+    assert "bytes_in_use" not in step["attrs"] and "bytes_limit" not in step["attrs"]
+    assert set(step["attrs"]) == {"in_flight"}
+
+
+def test_bytes_in_use_is_the_most_over_the_devices_and_the_limit_is_noted_once(monkeypatch):
+    from deepspeed_tpu.runtime import engine as engine_module
+    engine, xs, ys = _simple_engine()
+    devices = list(engine.mesh.local_devices)
+    reads = []
+
+    def stats(device):
+        reads.append(device)
+        n = devices.index(device)
+        return {"bytes_in_use": 1000 * len(reads) + n, "peak_bytes_in_use": 10 ** 6,
+                "bytes_limit": 5000 + n}
+
+    monkeypatch.setattr(engine_module, "device_memory_stats", stats)
+    for _ in range(3):
+        _step(engine, xs, ys)
+    first, second, third = _steps_of(engine)
+    n = len(devices)
+    assert reads == devices * 3                         # one read a device a step
+    assert first["attrs"]["bytes_in_use"] == 1000 * n + n - 1
+    assert third["attrs"]["bytes_in_use"] == 1000 * 3 * n + n - 1
+    assert first["attrs"]["bytes_limit"] == 5000        # the least over the devices
+    assert "bytes_limit" not in second["attrs"] and "bytes_limit" not in third["attrs"]
+    # a device that reports nothing beside one that reports: the one that reports counts
+    monkeypatch.setattr(engine_module, "device_memory_stats",
+                        lambda d: {"bytes_in_use": 7} if d is devices[0] else None)
+    _step(engine, xs, ys)
+    assert _steps_of(engine)[-1]["attrs"]["bytes_in_use"] == 7
+
+
+def test_the_catalog_gives_each_step_programs_memory_as_the_compiler_states_it():
+    engine, xs, ys = _simple_engine()
+    _step(engine, xs, ys)
+    catalog = spans.recorder().programs(engine._span_engine)
+    assert set(catalog) == {"loss_and_grad", "apply_update"}
+    for program in catalog.values():
+        assert set(program["memory"]) == {"argument", "output", "alias", "temp", "code"}
+        assert all(isinstance(v, int) and v >= 0 for v in program["memory"].values())
+        assert program["memory"]["argument"] > 0 and program["memory"]["output"] > 0
+    # the update program writes its state over what it was given
+    assert catalog["apply_update"]["memory"]["alias"] > 0
+    assert spans._memory_sizes(type("C", (), {"memory_analysis": lambda self: None})()) is None
