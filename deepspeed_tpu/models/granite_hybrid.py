@@ -166,10 +166,12 @@ class GraniteHybridModel:
         inner, N = c.mamba_inner, c.mamba_d_state
         x = checkpoint_name(x, "ds_dot:qkv")      # the remat policies classify dots by tag
         proj = _dot(x, mp["w_in"])                                            # float32
-        z, xBC = (proj[..., :inner].astype(x.dtype),
-                  proj[..., inner:2 * inner + 2 * N].astype(x.dtype))
         dt = jax.nn.softplus(proj[..., 2 * inner + 2 * N:] + mp["dt_bias"])
-        xBC = causal_conv(xBC, mp["conv_w"], True, mp["conv_b"])
+        # the gate and the convolution's input in the compute dtype, where the projection
+        # leaves them: the convolution reads its columns in place
+        proj = proj.astype(x.dtype)
+        z = proj[..., :inner]
+        xBC = causal_conv(proj, mp["conv_w"], True, mp["conv_b"], columns=(inner, 2 * inner + 2 * N))
         xs, Bm, Cm = jnp.split(xBC, [inner, inner + N], axis=-1)
         return xs.reshape(B, T, c.mamba_n_heads, c.mamba_d_head), dt, Bm, Cm, z
 

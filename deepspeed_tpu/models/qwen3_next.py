@@ -161,8 +161,9 @@ class Qwen3NextModel:
             qkvz = _dot(x, mp["w_qkvz"]).astype(x.dtype)
             x = checkpoint_name(x, "ds_dot:qkv")
             b, a = jnp.split(_dot(x, mp["w_ba"]), 2, axis=-1)                 # float32
-            mixed, z = qkvz[..., :2 * Hk * Dk + Hv * Dv], qkvz[..., 2 * Hk * Dk + Hv * Dv:]
-            mixed = causal_conv(mixed, mp["conv_w"], True)
+            z = qkvz[..., 2 * Hk * Dk + Hv * Dv:]
+            # the convolution reads q, k and v where the projection leaves them
+            mixed = causal_conv(qkvz, mp["conv_w"], True, columns=(0, 2 * Hk * Dk + Hv * Dv))
             q, k, v = jnp.split(mixed, [Hk * Dk, 2 * Hk * Dk], axis=-1)
             beta = jax.nn.sigmoid(b)
             g = -jnp.exp(mp["A_log"]) * jax.nn.softplus(a + mp["dt_bias"])

@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .pallas import causal_conv as conv_kernels
 from .pallas import delta_rule as kernels
 from .pallas.delta_rule import CHUNK
 
@@ -28,13 +29,10 @@ BLOCK_CHUNKS = 8           # chunks a grid step: the spacing of the states the b
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2,))
-def causal_conv(x, w, silu=False, bias=None):
-    """Depthwise causal convolution over time: ``x [B, T, C]``, ``w [W, C]``,
-    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the first token, plus
-    ``bias [C]`` where one is given (a Mamba-2 mixer's; the delta-rule mixer's has none), then
-    SiLU where ``silu``. Summed in float32, returned in ``x``'s dtype; the backward makes the
-    sum again from ``x`` and ``w`` (elementwise passes), so that no float32 copy of the
-    ``W`` shifted inputs is kept."""
+def plain_causal_conv(x, w, silu=False, bias=None):
+    """``causal_conv`` as plain ``jnp``: what the kernels are compared with, and what runs
+    where the channels do not fill whole registers of 128 lanes. Summed in float32, returned
+    in ``x``'s dtype; the backward (JAX's own) makes the sum again from ``x`` and ``w``."""
     W, T = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
@@ -42,6 +40,53 @@ def causal_conv(x, w, silu=False, bias=None):
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     return (jax.nn.silu(y) if silu else y).astype(x.dtype)
+
+
+# ``how``: the kernels' static arguments, ``(start, C, rows, lanes, silu, interpret)``
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv(x, w, bias, how):
+    return conv_kernels.causal_conv_fwd(x, w, bias, *how)
+
+
+def _conv_fwd(x, w, bias, how):
+    return conv_kernels.causal_conv_fwd(x, w, bias, *how), (x, w, bias)
+
+
+def _conv_bwd(how, res, dy):
+    # traced under the scopes of the call (a caller's ``ds_ssm`` or ``ds_lin_attn``, and
+    # ``ds_conv`` below), as the forward is; nothing is kept of the forward but its operands
+    x, w, bias = res
+    start, C = how[:2]
+    dx, dw, dbias = conv_kernels.causal_conv_bwd(x, w, bias, dy, *how)
+    # the window's cotangent in its place in the operand's, as a slice's transpose puts it
+    dx = jnp.pad(dx, ((0, 0), (0, 0), (start, x.shape[2] - start - C)))
+    return dx, dw.astype(w.dtype), None if bias is None else dbias.astype(bias.dtype)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def causal_conv(x, w, silu=False, bias=None, columns=None, interpret=None):
+    """Depthwise causal convolution over time: ``x [B, T, C]``, ``w [W, C]``,
+    ``y_t = sum_j w[j] x_{t - (W - 1) + j}`` with zeros before the first token, plus
+    ``bias [C]`` where one is given (a Mamba-2 mixer's; the delta-rule mixer's has none), then
+    SiLU where ``silu``. Summed in float32 in that order, returned in ``x``'s dtype.
+    ``columns = (start, stop)``: ``x`` is wider, a projection's whole output, and its channels
+    ``[start, stop)`` are what is meant; the kernels read them where they lie, no copy is made.
+
+    Two Pallas kernels under a ``jax.custom_vjp`` (``ops/pallas/causal_conv.py``) where the
+    channels, and the window's start, fill whole registers of 128 lanes: one pass over the rows
+    forward, one backward that keeps nothing but ``x``, ``w`` and ``bias`` and makes the sum
+    again. Off the TPU they run interpreted (``interpret`` None), as the other kernels do. Any
+    other width takes ``plain_causal_conv``, the same sum in plain ``jnp``."""
+    start, stop = columns or (0, x.shape[2])
+    with jax.named_scope("ds_conv"):
+        if (stop - start) % 128 or start % 128:
+            return plain_causal_conv(x[..., start:stop], w, silu, bias)
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        C = stop - start
+        return _conv(x, w, bias, (start, C, *conv_kernels.sizes(x.shape[1], C, start), silu, interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

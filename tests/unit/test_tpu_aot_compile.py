@@ -135,6 +135,29 @@ def test_the_state_space_scan_compiles_for_v5e(chip, dtype, T):
     assert compiled.memory_analysis().temp_size_in_bytes < (0.3e9 if dtype == jnp.bfloat16 else 0.5e9) * T / 8192
 
 
+@pytest.mark.parametrize("wide, columns, bias, dtype, T", [
+    (8512, (4096, 8448), True, jnp.bfloat16, 8192),       # granite4h_d10_train_1chip's step
+    (12288, (0, 8192), False, jnp.bfloat16, 8192),        # qwen3next_ep16_train_1chip's step
+    (8512, (4096, 8448), True, jnp.float32, 1024),        # the set-up's check of a mixer's gradients
+    (8512, (4096, 8448), True, jnp.bfloat16, 1000),       # a last block the sequence does not fill
+], ids=["granite", "qwen3next", "granite-f32-check", "a-short-last-block"])
+def test_the_causal_convolution_compiles_for_v5e(chip, wide, columns, bias, dtype, T):
+    """The mixers' short convolution as the two hybrid cells call it: a window of the
+    projection's output read where it lies, forward and the hand-written backward. Beside the
+    operands the program holds the result and the window's cotangent, never a float32 copy of
+    the shifted inputs (0.57 GB for Granite's four taps)."""
+    from deepspeed_tpu.ops.delta_rule import causal_conv
+    C = columns[1] - columns[0]
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    args = (shape(1, T, wide), shape(4, C)) + ((shape(C),) if bias else ())
+    conv = lambda x, w, b=None: causal_conv(x, w, True, b, columns, interpret=False)      # noqa: E731
+    loss = lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2)      # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ds_causal_conv_fwd" in text and "ds_causal_conv_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * T * wide * jnp.dtype(dtype).itemsize
+
+
 def test_a_recomputed_state_space_block_and_the_tied_head_compile_for_v5e(chip, monkeypatch):
     """The gradient program of ``granite4h_d10_train_1chip`` at its widths and 8,192 positions,
     cut to one Mamba-2 block and the attention block (whole blocks recomputed, the tied head's
