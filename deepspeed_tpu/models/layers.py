@@ -42,11 +42,13 @@ def _in_tiles(a, shards, positions, tiles, fill=0):
     return jnp.moveaxis(a.reshape(shards, B // shards, tiles, positions, *a.shape[2:]), 2, 0)
 
 
-def _cross_entropy_tiles(x, head, labels, keep):
+def _cross_entropy_tiles(x, head, labels, keep, a_position=False):
     """The sum of the valid positions' losses, a tile of positions at a time; with ``keep``
     also every tile's ``softmax - onehot`` ``[tiles, shards, rows, positions, V]``, taken
     while the tile's logits exist and rounded to the products' dtype as it stands, within
-    [-1, 1], so that no scale can make it underflow."""
+    [-1, 1], so that no scale can make it underflow. With ``a_position`` the losses
+    themselves ``[tiles, shards, rows, positions]`` (0 where the label is negative or the
+    position filled) in the sum's place."""
     V = head.shape[0]
     shards, positions, tiles = _tiling(*labels.shape, V)
     xs = _in_tiles(x, shards, positions, tiles)
@@ -61,16 +63,17 @@ def _cross_entropy_tiles(x, head, labels, keep):
         lse = jax.nn.logsumexp(logits, axis=-1)
         valid = lc >= 0                                # < 0 = ignored (BERT's -100)
         gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
+        losses = jnp.where(valid, lse - gold, 0.0)
         # a chip's own sum, one a shard: the sums cross the chips once, after the scan
-        total = total + jnp.sum(jnp.where(valid, lse - gold, 0.0), axis=(1, 2))
+        total = total + jnp.sum(losses, axis=(1, 2))
         if not keep:
-            return total, None
+            return total, (losses if a_position else None, None)
         onehot = jnp.arange(V, dtype=lc.dtype) == lc[..., None]
         g = jnp.where(valid[..., None], jnp.exp(logits - lse[..., None]) - onehot, 0.0)
-        return total, g.astype(w.dtype)
+        return total, (losses if a_position else None, g.astype(w.dtype))
 
-    total, gs = jax.lax.scan(tile, jnp.zeros((shards,), jnp.float32), (xs, ls))
-    return jnp.sum(total), gs
+    total, (losses, gs) = jax.lax.scan(tile, jnp.zeros((shards,), jnp.float32), (xs, ls))
+    return (losses if a_position else jnp.sum(total)), gs
 
 
 def _valid_count(labels):
@@ -118,6 +121,52 @@ def _chunked_cross_entropy_bwd(res, ct):
 
 
 chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd, _chunked_cross_entropy_bwd)
+
+
+def _out_of_tiles(a, B, T):
+    """``_in_tiles`` undone: ``[tiles, shards, rows, positions, ...]`` as ``[B, T, ...]``."""
+    return jnp.moveaxis(a, 0, 2).reshape(B, -1, *a.shape[4:])[:, :T]
+
+
+@jax.custom_vjp
+def chunked_cross_entropy_a_position(x, head, labels):
+    """``chunked_cross_entropy`` before its mean: the float32 loss of EVERY position
+    ``[B, T]`` (0 where the label is negative), on the same tiles and under the same scope,
+    for a loss that weighs positions itself (a looped model's exits, each position of each
+    exit by its own differentiated weight: ``models/ouro.py``).
+
+    Its backward is its own too, and takes a cotangent a position: the forward keeps each
+    tile's ``softmax - onehot`` as above; the rule is the same two whole products, a
+    position's row of the input's gradient scaled by its cotangent AFTER the product, in
+    float32, and the input's rows scaled by it BEFORE the table's (in the products' dtype,
+    whose exponent is float32's): the kept ``[B, T, vocab]`` is read twice and never
+    written again."""
+    with jax.named_scope("ds_loss"):
+        losses, _ = _cross_entropy_tiles(x, head, labels, False, a_position=True)
+        return _out_of_tiles(losses, *labels.shape)
+
+
+def _chunked_cross_entropy_a_position_fwd(x, head, labels):
+    with jax.named_scope("ds_loss"):
+        losses, gs = _cross_entropy_tiles(x, head, labels, True, a_position=True)
+        return _out_of_tiles(losses, *labels.shape), (gs, x, head)
+
+
+def _chunked_cross_entropy_a_position_bwd(res, ct):
+    gs, x, head = res
+    with jax.named_scope("ds_loss"):
+        tiles, shards, _, positions, _ = gs.shape
+        cts = _in_tiles(ct.astype(jnp.float32), shards, positions, tiles)[..., None]
+        xs = _in_tiles(x, shards, positions, tiles)
+        dxs = cts * jnp.einsum("nsrcv,vh->nsrch", gs, head.astype(x.dtype),
+                               preferred_element_type=jnp.float32)
+        d_head = jnp.einsum("nsrcv,nsrch->vh", gs, (cts * xs).astype(x.dtype),
+                            preferred_element_type=jnp.float32)
+        return _out_of_tiles(dxs, *x.shape[:2]).astype(x.dtype), d_head.astype(head.dtype), None
+
+
+chunked_cross_entropy_a_position.defvjp(_chunked_cross_entropy_a_position_fwd,
+                                        _chunked_cross_entropy_a_position_bwd)
 
 
 def rms_norm(x, scale, eps, zero_centred=False):

@@ -178,6 +178,27 @@ def test_a_recomputed_state_space_block_and_the_tied_head_compile_for_v5e(chip, 
     assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
 
 
+def test_a_looped_models_recomputed_passes_and_its_exits_compile_for_v5e(chip, monkeypatch):
+    """The gradient program of ``ouro_d6_train_1chip`` at its widths and 2 x 4,096 positions, cut
+    to ONE layer run TWICE on its leaves (whole blocks recomputed; the two exits' cross-entropy a
+    position over the whole vocabulary of 49,152 as one call; the exit gate): the flash kernel is
+    in it at OLMoE's shape, a layer's leaves are arguments once, and what it needs beside its
+    parameters and their gradients is the exits' kept ``softmax - onehot`` (2 x 8192 x 49,152
+    bf16 = 1.61 GB), the table's float32 gradient and a block's internals (4.22 GB here; the cell's
+    six layers and four passes compile to 5.11 GB of temporaries; 8.15 GB of state leave 8.7)."""
+    from deepspeed_tpu.models.ouro import OuroConfig, OuroModel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the flash kernel, not its interpreter
+    model = OuroModel(OuroConfig(num_hidden_layers=1, total_ut_steps=2, remat=True))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 51_388_416 + 201_326_592 + 2048 + 2049
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
 def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
     """Qwen3-Next's expert layer at its published widths as one chip of sixteen holds it: a
     router over 512, 32 experts of 512 held, 8,192 tokens; the megablox grouped matmul inside
