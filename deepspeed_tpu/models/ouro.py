@@ -41,6 +41,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..runtime.activation_checkpointing.checkpointing import checkpoint_wrapper
 from .layers import chunked_cross_entropy_a_position, rms_norm, rope
@@ -59,7 +60,7 @@ class OuroConfig:
     rope_theta: float = 1000000.0
     exit_entropy_coef: float = 0.05     # beta: the entropy of the exit distribution, rewarded
     initializer_range: float = 0.02
-    remat: bool = False            # whole blocks made again in the backward: only a block's input is kept
+    remat: bool = False            # whole blocks made again in the backward: a block pass keeps KEPT_BY_A_BLOCK_PASS
     compute_dtype: Any = jnp.bfloat16
 
     @classmethod
@@ -77,6 +78,13 @@ class OuroConfig:
         kinds = set(keys.get("layer_types", ())[:keys.get("num_hidden_layers")])
         assert kinds <= {"full_attention"}, f"unknown layer_types {sorted(kinds)}"
         return cls(**{k: v for k, v in keys.items() if k in cls.__dataclass_fields__}, **more)
+
+
+# What a recomputed block pass keeps beside its input, by name: the flash kernel's output
+# and row sums (named in its forward rule) and ``w_down``'s output, which only ``norm_4``'s
+# backward reads. A kept [B, T, H] takes a 5632-deep product or the kernel out of the second
+# forward, 29-40 ms a GB on a v5e; every other tensor of a block buys 10 (PERF.md, PR 38).
+KEPT_BY_A_BLOCK_PASS = jax.checkpoint_policies.save_only_these_names("attn_out", "attn_lse", "mlp_out")
 
 
 def _dot(x, w):
@@ -145,7 +153,7 @@ class OuroModel:
 
     def mlp(self, x, lp):
         hidden = jax.nn.silu(_dot(x, lp["w_gate"]).astype(jnp.float32)) * _dot(x, lp["w_up"])
-        return _dot(hidden.astype(x.dtype), lp["w_down"])
+        return checkpoint_name(_dot(hidden.astype(x.dtype), lp["w_down"]), "mlp_out")
 
     def _block(self, x, lp, positions):
         """One layer in a pass: a norm before and a norm after each branch. The passes' scope
@@ -162,7 +170,7 @@ class OuroModel:
         positions = jnp.arange(x.shape[1])
         block = self._block
         if c.remat:
-            block = checkpoint_wrapper(block)
+            block = checkpoint_wrapper(block, KEPT_BY_A_BLOCK_PASS)
         for lp in params["layers"]:
             x = block(x, lp, positions)
         with jax.named_scope("ds_loss"):      # the last norm feeds the head, the gate and the next pass

@@ -617,17 +617,17 @@ def _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k
     assert q.shape[2] % bq == 0 and q.shape[2] % bk == 0, \
         f"seq_len {q.shape[2]} must be divisible by block sizes ({bq}, {bk})"
     out, lse = _flash_fwd(q, k, v, seed, bias, sm_scale_, causal, rate, bq, bk, interp)
-    # Tag the RESIDUALS (not just downstream values): under jax.checkpoint a
-    # name applied by the caller to the kernel's output cannot mark the
-    # custom_vjp's own residual vars as saveable, so every remat policy would
-    # re-run this forward kernel in backward just to regenerate (out, lse) —
-    # measured (earlier rig, not re-measured): fwd_replayed == n_layers for 'dots',
-    # 'attn' AND 'dots+attn' before this tag. Naming them here lets
-    # save_only_these_names("attn_out", "attn_lse") keep the flash bwd kernels
-    # replay-free (fwd_replayed == 0).
+    # Name what a jax.checkpoint round the call may keep, and hand the NAMED ``out`` on as
+    # the primal too, so that the residual and the value the caller goes on with are one
+    # variable. A name on the residual alone keeps the backward kernels' operand and still
+    # re-runs this kernel for the primal (Ouro's gradient program compiled for a v5e, PR 38:
+    # 12 forward calls, 6 of them rematted, with or without it); with a second name at the
+    # caller the tensor is kept twice. So: save_only_these_names("attn_out", "attn_lse")
+    # keeps ``out`` once and replays no forward kernel. (The name lowers to nothing: a
+    # program with no jax.checkpoint round the call is the same program, byte for byte.)
     from jax.ad_checkpoint import checkpoint_name
-    return out, (q, k, v, checkpoint_name(out, "attn_out"),
-                 checkpoint_name(lse, "attn_lse"), bias, seed)
+    out = checkpoint_name(out, "attn_out")
+    return out, (q, k, v, out, checkpoint_name(lse, "attn_lse"), bias, seed)
 
 
 def _core_bwd_rule(causal, sm_scale, rate, block_q, block_k, interpret, res, g):

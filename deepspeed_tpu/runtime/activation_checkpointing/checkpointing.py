@@ -274,17 +274,17 @@ def checkpoint_wrapper(fn, policy=None):
         elif policy == "dots":
             eff_policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         elif policy == "attn":
-            # save only attention OUTPUTS (tagged "attn_out"/"attn_lse" by the
-            # models): backward skips replaying the flash kernel — the priciest
-            # recompute — for one [B, T, E] + one [B, H, T] residual per layer
+            # save only attention OUTPUTS ("attn_out"/"attn_lse": the flash kernel's forward rule names them, most models
+            # again at the call): backward skips replaying the flash kernel — the priciest recompute — for one [B, H, T]
+            # and one [B, T, E] residual per layer and NAME (two with a model's own tag: compiled for a v5e, PR 38)
             eff_policy = jax.checkpoint_policies.save_only_these_names(
                 "attn_out", "attn_lse")
         elif policy == "dots+attn":
             # dots AND the flash kernel's (out, lse): backward replays ONLY cheap
             # elementwise ops (layernorm/gelu/adds) — the kernel's own residuals
             # (q,k,v) are saved dots, out/lse are the named saves, so the flash
-            # bwd kernels run with zero fwd-kernel replay. The extra HBM over
-            # 'dots' is one [B,T,E] + one [B,H,T] per layer (~3% of the dots set).
+            # bwd kernels run with zero fwd-kernel replay. The extra HBM over 'dots' is one [B,H,T] per layer and one
+            # [B,T,E] for each name on the kernel's output: the forward rule's and, in every model but Ouro, the caller's.
             eff_policy = jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                 jax.checkpoint_policies.save_only_these_names("attn_out", "attn_lse"))

@@ -3,7 +3,11 @@
 through ``deepspeed_tpu.initialize`` (loss, every exit's logits, the exit distribution, the
 gradient of every leaf, with whole blocks recomputed and without), a shared leaf's gradient as
 the sum over untied copies, one pass as a plain decoder, the exit distribution, the head a
-position, and the scopes the benchmark's readers find a pass by."""
+position, the scopes the benchmark's readers find a pass by, and what a recomputed block pass
+keeps beside its input."""
+
+import collections
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +16,7 @@ import pytest
 
 import deepspeed_tpu
 from benchmarks.reference import ouro_reference as ref
-from deepspeed_tpu.models import layers
+from deepspeed_tpu.models import layers, ouro
 from deepspeed_tpu.models.ouro import OuroConfig, OuroModel, exit_distribution
 from deepspeed_tpu.utils import spans
 
@@ -176,6 +180,86 @@ def test_recomputed_blocks_give_the_same_loss_gradients_and_the_passes_scopes():
     assert text.count("stablehlo.while") >= 2
     assert "rematted_computation/ds_loop" not in jax.jit(jax.grad(loss_of(kept))).lower(
         params, tokens, labels).as_text(debug_info=True)
+
+
+# ------------------------------------------------------------------ what a recomputed block pass keeps
+@pytest.mark.parametrize("other", ["blocks-kept", "only-the-input-kept"])
+def test_what_a_block_pass_keeps_changes_no_bit_of_a_gradient(other, monkeypatch):
+    """The kept tensors are the values the second forward would have made again: in float32
+    on the CPU every leaf's gradient is the same bits with nothing recomputed and with
+    everything but a block's input recomputed."""
+    _, model, params = build(remat=True)
+    tokens, labels = batch(seed=6, rows=2)
+    grad_of = lambda m: jax.jit(jax.value_and_grad(lambda p: m.apply(p, tokens, labels)[0]))(params)   # noqa: E731
+    loss, got = grad_of(model)
+    if other == "blocks-kept":
+        want_loss, want = grad_of(build(remat=False)[1])
+    else:
+        monkeypatch.setattr(ouro, "KEPT_BY_A_BLOCK_PASS", None)
+        want_loss, want = grad_of(model)
+    assert float(loss) == float(want_loss)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
+
+
+def primitives_by_path(jaxpr, path=(), found=None):
+    """``{(enclosing primitives, primitive): count}`` over a jaxpr and what its equations hold."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        found[path, eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    primitives_by_path(inner, path + (eqn.primitive.name,), found)
+    return found
+
+
+def test_the_second_forward_runs_no_flash_kernel_and_one_product_fewer_a_layer(monkeypatch):
+    """What the backward makes again of a block: six products where policy None makes
+    seven (``w_down``'s is kept), and no flash forward kernel; the two scopes the benchmark's
+    readers find the second forward by are still there."""
+    _, model, params = build(remat=True)
+    tokens, labels = batch(seed=5, rows=2)
+
+    def read():          # a new function each time: a trace is cached by its function, not by what the model keeps
+        grad = jax.grad(lambda p: model.apply(p, tokens, labels)[0])
+        found = primitives_by_path(jax.make_jaxpr(grad)(params).jaxpr)
+        # in the blocks' backward: the second forward and the backward proper
+        again = {what: sum(n for (path, name), n in found.items() if name == what and path == ("scan", "remat2"))
+                 for what in ("dot_general", "pallas_call")}
+        compiled = jax.jit(grad).lower(params).compile().as_text()
+        return again, compiled, [line for line in compiled.splitlines() if "rematted_computation" in line]
+    layers_ = len(params["layers"])
+    again, compiled, remade = read()
+    assert again == {"dot_general": layers_ * (6 + 14), "pallas_call": layers_}        # the backward kernel alone
+    assert not any("ds_flash_fwd" in line for line in remade) and "ds_flash_fwd" in compiled
+    assert any("rematted_computation/ds_loop/ds_attn" in line for line in remade)
+    assert any("rematted_computation/ds_loop/ds_mlp" in line for line in remade)
+    monkeypatch.setattr(ouro, "KEPT_BY_A_BLOCK_PASS", None)
+    again, _, remade = read()
+    assert again == {"dot_general": layers_ * (7 + 14), "pallas_call": 2 * layers_}
+    assert any("ds_flash_fwd" in line for line in remade)
+
+
+@pytest.mark.parametrize("shape, a_layer, more", [((2, 4, 40, 8), 1, 0), ((2, 4, 40), 1, 0), ((2, 40, 32), 2, 1)],
+                         ids=["attn_out", "attn_lse", "input-and-mlp_out"])
+def test_a_block_pass_keeps_each_named_tensor_once(shape, a_layer, more, monkeypatch, capsys):
+    """The residuals of one pass through two recomputed blocks, by shape: a layer keeps ONE
+    kernel output, ONE set of row sums, its input and ``w_down``'s output, none of them
+    twice; the last block's output is ``norm_f``'s to keep (``more``)."""
+    _, model, params = build(remat=True)
+
+    def kept():       # the activations a row of the batch: no leaf, no constant, not what norm_f keeps of its own
+        jax.ad_checkpoint.print_saved_residuals(model.one_pass, params, jnp.ones((2, 40, 32)))
+        shapes = (re.match(r"\w+\[([\d,]+)\] (?!from the argument params|from a constant)", line)
+                  for line in capsys.readouterr().out.splitlines() if "(rms_norm)" not in line)
+        return collections.Counter(s for s in (tuple(map(int, m.group(1).split(","))) for m in shapes if m) if s[0] == 2)
+    layers_ = len(params["layers"])
+    found = kept()
+    assert found[shape] == a_layer * layers_ + more and sum(found.values()) == 4 * layers_ + 1, found
+    monkeypatch.setattr(ouro, "KEPT_BY_A_BLOCK_PASS", None)
+    assert kept() == {(2, 40, 32): layers_ + 1}
 
 
 def test_it_trains_in_bfloat16_through_initialize_with_blocks_recomputed():

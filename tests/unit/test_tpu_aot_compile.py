@@ -178,6 +178,19 @@ def test_a_recomputed_state_space_block_and_the_tied_head_compile_for_v5e(chip, 
     assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
 
 
+def looped_gradient_program(chip, monkeypatch, layers, passes):
+    """Ouro's gradient program at its published widths and 2 x 4,096 positions, whole blocks
+    recomputed, compiled for the described chip; and the shapes of its leaves."""
+    from deepspeed_tpu.models.ouro import OuroConfig, OuroModel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the flash kernel, not its interpreter
+    model = OuroModel(OuroConfig(num_hidden_layers=layers, total_ut_steps=passes, remat=True))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=chip)
+    return jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile(), shapes
+
+
 def test_a_looped_models_recomputed_passes_and_its_exits_compile_for_v5e(chip, monkeypatch):
     """The gradient program of ``ouro_d6_train_1chip`` at its widths and 2 x 4,096 positions, cut
     to ONE layer run TWICE on its leaves (whole blocks recomputed; the two exits' cross-entropy a
@@ -186,17 +199,30 @@ def test_a_looped_models_recomputed_passes_and_its_exits_compile_for_v5e(chip, m
     parameters and their gradients is the exits' kept ``softmax - onehot`` (2 x 8192 x 49,152
     bf16 = 1.61 GB), the table's float32 gradient and a block's internals (4.22 GB here; the cell's
     six layers and four passes compile to 5.11 GB of temporaries; 8.15 GB of state leave 8.7)."""
-    from deepspeed_tpu.models.ouro import OuroConfig, OuroModel
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the flash kernel, not its interpreter
-    model = OuroModel(OuroConfig(num_hidden_layers=1, total_ut_steps=2, remat=True))
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    compiled, shapes = looped_gradient_program(chip, monkeypatch, layers=1, passes=2)
     assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 51_388_416 + 201_326_592 + 2048 + 2049
-    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=chip)
-    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
+def test_a_looped_models_block_passes_keep_each_named_tensor_once_on_a_v5e(chip, monkeypatch):
+    """The gradient program of ``ouro_d6_train_1chip`` WHOLE (its widths, six layers, four passes,
+    2 x 4,096 positions): a block pass keeps the flash kernel's output and ``w_down``'s beside its
+    input, so the program holds six flash forward calls (twelve while the second forward ran the
+    kernel again), the forward loop carries six stacked kernel outputs and not twelve, and a kept
+    tensor costs what it weighs: 24 block passes x (2 x 33.5 MB + 0.5 MB) = 1.62 GB over the
+    5.68 GB that policy None compiles to, in the compiler's own buffer assignment. What
+    ``memory_analysis()`` calls ``temp`` counts every array a loop stacks a second time (7.05 GB
+    under policy None, 10.35 here, PERF.md, PR 38): the pin is on that reading plus 5 %."""
+    compiled, _ = looped_gradient_program(chip, monkeypatch, layers=6, passes=4)
+    lines = compiled.as_text().splitlines()
+    forward_calls = [line for line in lines if "tpu_custom_call" in line and "ds_flash_fwd" in line]
+    assert len(forward_calls) == 6 and not any("rematted_computation" in line for line in forward_calls)
+    forward_loop = next(line for line in lines if re.search(r"= \(.*\) while\(", line)
+                        and "jvp()/while" in line and "transpose" not in line)
+    assert forward_loop.count("bf16[4,2,16,4096,128]") == 6 and forward_loop.count("f32[4,2,16,4096]") == 6
+    assert forward_loop.count("bf16[4,2,4096,2048]") == 6 * 2 + 1          # a block's input, w_down's output; the exits
+    assert compiled.memory_analysis().temp_size_in_bytes < 10.354e9 * 1.05
 
 
 def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
