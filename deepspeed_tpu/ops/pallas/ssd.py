@@ -4,7 +4,11 @@ recurrence and the entry point), forward and backward.
 A grid step is one batch row, one tile of ``Q`` tokens and one group of ``Hg`` heads; the
 tiles of a row and, inside a tile, its groups of heads are the sequential axes, so that ``B``
 and ``C`` (one for all heads) are fetched once a tile, ``G = C B^T`` is made once a tile
-(a scratch), and the backward adds the heads' cotangents of ``B`` and ``C`` up in VMEM. The
+(a scratch), and the backward adds the heads' cotangents of ``B`` and ``C`` up in VMEM. Where
+the heads come in ``groups`` that each have a ``B`` and ``C`` of their own (``b``, ``c``
+``[B, T, groups * N]``, a group's ``N`` side by side), a grid step is the heads of one such
+group or a divisor of them: the ``b``/``c`` block follows the step's group, ``G`` is made
+again when the group changes, and the cotangents are summed into the group's own block. The
 heads' states live across the tiles in a VMEM scratch ``[H / Hg, N, Hg * P]`` float32 (a
 head's ``S^T [N, P]`` side by side in the lanes, zero at the first tile). Inside a tile, with
 ``cs_i = sum_{m <= i} dt_m A`` a head's cumulative log decay:
@@ -174,23 +178,49 @@ def _whole(dec, Hg, k, P):
                    for p in range(Hg // k)], 1)
 
 
-def _begin(state_ref, G_ref, b_ref, c_ref):
-    """A row's first tile starts from zero; a tile's first group of heads makes ``C B^T``."""
+def _first_of_group(g, steps):
+    """Whether grid step ``g`` is the first of the ``steps`` that share a ``B`` and ``C``:
+    a traced flag, or True where every step is (``steps`` 1). ``steps`` None: one ``B`` and
+    ``C`` for all heads, the first step of a tile."""
+    if steps is None:
+        return g == 0
+    return True if steps == 1 else g % steps == 0
+
+
+def _later_in_group(g, steps):
+    """The complement of ``_first_of_group``: a traced flag, or False where no step is."""
+    if steps is None:
+        return g > 0
+    return False if steps == 1 else g % steps != 0
+
+
+def _when(flag, fn):
+    """``fn()`` where ``flag`` holds; a flag known at trace time costs no branch."""
+    if flag is True:
+        fn()
+    elif flag is not False:
+        pl.when(flag)(fn)
+
+
+def _begin(state_ref, G_ref, b_ref, c_ref, steps):
+    """A row's first tile starts from zero; the first step of the heads that share a ``B``
+    and ``C`` makes ``C B^T``. Returns the step."""
     t, g = pl.program_id(1), pl.program_id(2)
 
     @pl.when(t == 0)
     def _():
         state_ref[g] = jnp.zeros(state_ref.shape[1:], _F32)
 
-    @pl.when(g == 0)
-    def _():
+    def make():
         G_ref[...] = _mm(_NT, (_parts(c_ref[...]), _parts(b_ref[...])))
 
+    _when(_first_of_group(g, steps), make)
     return g
 
 
-def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, start_ref, S_ref, G_ref, *, P, k):
-    g = _begin(S_ref, G_ref, b_ref, c_ref)
+def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, start_ref, S_ref, G_ref, *, P, k,
+                steps):
+    g = _begin(S_ref, G_ref, b_ref, c_ref, steps)
     Q, Hg = x_ref.shape[0], dt_ref.shape[0]
     W, dtype = k * P, x_ref.dtype
     S = S_ref[g]
@@ -217,8 +247,8 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, start_ref, S_r
 
 
 def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, start_ref, dy_ref,
-                dx_ref, da_ref, ddt_ref, db_ref, dc_ref, dd_ref, dS_ref, G_ref, *, P, k):
-    g = _begin(dS_ref, G_ref, b_ref, c_ref)
+                dx_ref, da_ref, ddt_ref, db_ref, dc_ref, dd_ref, dS_ref, G_ref, *, P, k, steps):
+    g = _begin(dS_ref, G_ref, b_ref, c_ref, steps)
     Q, Hg = x_ref.shape[0], dt_ref.shape[0]
     W, dtype = k * P, x_ref.dtype
     S, dS = start_ref[...], dS_ref[g]
@@ -274,15 +304,17 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, start_ref, dy_ref,
     db = _mm(_TN, (dGp, cp)) + _mm(_NT, (leaves, dSp))
     dc = _mm(_NN, (dGp, bp)) + _mm(_NT, (into_read, Sp))
 
-    @pl.when(g == 0)
-    def _():
+    def write():
         db_ref[...] = db
         dc_ref[...] = dc
 
-    @pl.when(g > 0)
-    def _():
+    def add():
         db_ref[...] += db
         dc_ref[...] += dc
+
+    # the first of the steps that share a B and C starts their sum, the others add to it
+    _when(_first_of_group(g, steps), write)
+    _when(_later_in_group(g, steps), add)
 
     # every head's cs together, a head a lane: start = exp(cs), end = exp(cs_last - cs), and
     # the last token's also takes whole = exp(cs_last) = its start
@@ -298,13 +330,15 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, start_ref, dy_ref,
     ddt_ref[...] = tile.rows_of(d_leaf * cols["end"], False) + d_dt_rows[:Hg]
 
 
-def _specs(T, H, P, N, Q, Hg, reverse):
-    """Block specs of the operands by kind, for a grid ``(B, T / Q, H / Hg)``."""
+def _specs(T, H, P, N, Q, Hg, reverse, steps):
+    """Block specs of the operands by kind, for a grid ``(B, T / Q, H / Hg)``; ``steps`` grid
+    steps share a block of ``b`` and ``c`` (None: all of them, one block)."""
     tiles = T // Q
     at = (lambda t: tiles - 1 - t) if reverse else (lambda t: t)
+    group = (lambda g: 0) if steps is None else (lambda g: g // steps)
     return dict(
         x=pl.BlockSpec((None, Q, Hg * P), lambda b, t, g: (b, at(t), g)),
-        bc=pl.BlockSpec((None, Q, N), lambda b, t, g: (b, at(t), 0)),
+        bc=pl.BlockSpec((None, Q, N), lambda b, t, g: (b, at(t), group(g))),
         dt=pl.BlockSpec((None, None, Hg, Q), lambda b, t, g: (b, at(t), g, 0)),
         a=pl.BlockSpec((Hg, 1), lambda b, t, g: (g, 0)),
         d=pl.BlockSpec((1, Hg * P), lambda b, t, g: (0, g)),
@@ -318,35 +352,39 @@ def _compiler_params():
                                 vmem_limit_bytes=64 * 2 ** 20)
 
 
-def _sizes(x, dt, b, heads, interpret):
+def _sizes(x, dt, b, heads, groups, interpret):
+    """The sizes, and the grid steps that share a ``b``/``c`` block (None with one group)."""
     B, T = x.shape[:2]
     H, Q = dt.shape[2:]
-    P, N = x.shape[2] // H, b.shape[2]
+    P, N = x.shape[2] // H, b.shape[2] // groups
     k = heads_together(heads, P)
+    assert H % groups == 0 and (groups == 1 or (H // groups) % heads == 0), \
+        f"a grid step's {heads} heads are one of the {groups} groups' {H // groups} or a divisor"
     assert interpret or ((k * P) % 128 == 0 and N % 128 == 0 and Q % 64 == 0
                          and (heads % 8 == 0 or heads == H)), \
         f"the scan's kernels take heads that fill whole registers of 128 lanes, a state of " \
         f"128s and a tile of 64s, not {heads} heads of {P}, a state of {N}, a tile of {Q}"
     assert H % heads == 0 and T % Q == 0
-    return B, T, H, P, N, Q, k
+    return B, T, H, P, N, Q, k, (None if groups == 1 else H // groups // heads)
 
 
 # jitted and inlined: the jaxpr of a kernel's body (sixty-four heads unrolled) is made once
 # for a shape, not once for every call of a program (twenty-seven in a step of nine layers
 # with their blocks recomputed), and each call still carries the scopes it was made under
-_inlined = functools.partial(jax.jit, static_argnames=("heads", "interpret"), inline=True)
+_inlined = functools.partial(jax.jit, static_argnames=("heads", "groups", "interpret"), inline=True)
 
 
 @_inlined
-def ssd_scan_fwd(x, dt, A, b, c, D, heads, interpret):
+def ssd_scan_fwd(x, dt, A, b, c, D, heads, interpret, groups=1):
     """``x [B, T, H * P]``, ``dt`` float32 ``[B, T / Q, H, Q]`` (a tile's tokens in the lanes),
-    ``A`` float32 ``[H, 1]``, ``b``, ``c`` ``[B, T, N]``, ``D`` float32 ``[1, H * P]`` (a head's
-    over its lanes), ``heads`` a grid step: ``(y`` as ``x``, the float32 states every tile
-    starts from ``[B, T / Q, H / heads, N, heads * P])``."""
-    B, T, H, P, N, Q, k = _sizes(x, dt, b, heads, interpret)
-    spec = _specs(T, H, P, N, Q, heads, False)
+    ``A`` float32 ``[H, 1]``, ``b``, ``c`` ``[B, T, groups * N]`` (head ``h`` reads group
+    ``h // (H / groups)``), ``D`` float32 ``[1, H * P]`` (a head's over its lanes), ``heads`` a
+    grid step: ``(y`` as ``x``, the float32 states every tile starts from
+    ``[B, T / Q, H / heads, N, heads * P])``."""
+    B, T, H, P, N, Q, k, steps = _sizes(x, dt, b, heads, groups, interpret)
+    spec = _specs(T, H, P, N, Q, heads, False, steps)
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, P=P, k=k),
+        functools.partial(_fwd_kernel, P=P, k=k, steps=steps),
         grid=(B, T // Q, H // heads),
         in_specs=[spec["x"], spec["dt"], spec["a"], spec["bc"], spec["bc"], spec["d"]],
         out_specs=[spec["x"], spec["start"]],
@@ -362,15 +400,15 @@ def ssd_scan_fwd(x, dt, A, b, c, D, heads, interpret):
 
 
 @_inlined
-def ssd_scan_bwd(x, dt, A, b, c, D, start, dy, heads, interpret):
+def ssd_scan_bwd(x, dt, A, b, c, D, start, dy, heads, interpret, groups=1):
     """From y's cotangent and the states the forward kept: the cotangents of ``x`` (as
     ``x``), of the log decays ``dt A`` and of ``dt`` where it is a factor (each as ``dt``),
-    of ``b`` and ``c`` (float32, the heads' summed), and ``sum_t x dy`` a tile
+    of ``b`` and ``c`` (float32, a group's heads' summed), and ``sum_t x dy`` a tile
     ``[B, T / Q, 1, H * P]`` (``D``'s, to be summed)."""
-    B, T, H, P, N, Q, k = _sizes(x, dt, b, heads, interpret)
-    spec = _specs(T, H, P, N, Q, heads, True)
+    B, T, H, P, N, Q, k, steps = _sizes(x, dt, b, heads, groups, interpret)
+    spec = _specs(T, H, P, N, Q, heads, True, steps)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, P=P, k=k),
+        functools.partial(_bwd_kernel, P=P, k=k, steps=steps),
         grid=(B, T // Q, H // heads),
         in_specs=[spec["x"], spec["dt"], spec["a"], spec["bc"], spec["bc"], spec["d"],
                   spec["start"], spec["x"]],

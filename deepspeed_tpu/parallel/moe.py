@@ -522,15 +522,29 @@ def piece_firsts(axis, per_chip):
     return tuple(((me - back) % n * per_chip).astype(jnp.int32) for back in _nearest_first(n))
 
 
+# ------------------------------------------------------------------ the experts' form
+SILU_GATED, RELU2 = "silu_gated", "relu2"
+
+
+def _activate(form, up, dt):
+    """What lies between an expert's products, from the first one's output ``up``: the gated
+    SiLU of its two halves ``silu(gate) * up`` (``w_gate_up [.., H, 2F]``), or the squared
+    ReLU of the whole (``w_up [.., H, F]``: an expert of two matrices); in float32."""
+    if form == RELU2:
+        return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
+    F = up.shape[1] // 2
+    return (jax.nn.silu(up[:, :F].astype(jnp.float32)) * up[:, F:].astype(jnp.float32)).astype(dt)
+
+
 # ------------------------------------------------------------ a held range's rows
-def _held_pass(c, x2, weights, w_gate_up, w_down, sort):
+def _held_pass(form, c, x2, weights, w_gate_up, w_down, sort):
     """The held-range form's pass ``c``: the sorted rows ``lo + c n .. lo + (c + 1) n - 1``,
     those of them that are held, gathered, multiplied and added into their tokens
     ``[n, H]`` float32. ``sort = (tok, order, lo, rows_here, ends)``: each sorted row's
     token and flat ``n * k + j``, the first held row, their count, and the held rows up to
     each held expert."""
     tok, order, lo, rows_here, ends = sort
-    n, F = x2.shape[0], w_down.shape[1]
+    n = x2.shape[0]
     with jax.named_scope("ds_moe_dispatch"):
         at = c * n + jnp.arange(n, dtype=jnp.int32)
         live = (at < rows_here)[:, None]
@@ -540,9 +554,7 @@ def _held_pass(c, x2, weights, w_gate_up, w_down, sort):
         xs = jnp.where(live, x2[mine], 0)     # a row past the held ones: nothing in, nothing back
     with jax.named_scope("ds_moe_experts"):
         gate_up = experts_matmul(xs, (w_gate_up,), (None,), sizes)
-        hidden = (jax.nn.silu(gate_up[:, :F].astype(jnp.float32))
-                  * gate_up[:, F:].astype(jnp.float32)).astype(xs.dtype)
-        ys = experts_matmul(hidden, (w_down,), (None,), sizes)
+        ys = experts_matmul(_activate(form, gate_up, xs.dtype), (w_down,), (None,), sizes)
     with jax.named_scope("ds_moe_combine"):
         # the products leave rows past their groups unwritten: taken as zero
         ys = jnp.where(live, ys.astype(jnp.float32), 0.0) * weights.reshape(-1)[slot][:, None]
@@ -559,27 +571,30 @@ def _passes(k, rows_here, n, one_pass, zero):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_rows(k, x2, weights, w_gate_up, w_down, sort):
+def _held_rows(static, x2, weights, w_gate_up, w_down, sort):
     """The held experts' part of the layer's result, ``[n, H]``: ``_held_pass`` over as
-    many passes as the held rows fill. The backward keeps the layer's input, the router's
-    weights and the sort, and makes each pass again as it takes its cotangents (a gather
-    and two small products): a row sent to an absent expert costs no buffer either way."""
+    many passes as the held rows fill (``static = (k, the experts' form)``). The backward
+    keeps the layer's input, the router's weights and the sort, and makes each pass again as
+    it takes its cotangents (a gather and two small products): a row sent to an absent expert
+    costs no buffer either way."""
+    k, form = static
     n = x2.shape[0]
-    y = _passes(k, sort[3], n, lambda c: _held_pass(c, x2, weights, w_gate_up, w_down, sort),
+    y = _passes(k, sort[3], n, lambda c: _held_pass(form, c, x2, weights, w_gate_up, w_down, sort),
                 jnp.zeros(x2.shape, jnp.float32))
     return y.astype(x2.dtype)
 
 
-def _held_rows_fwd(k, x2, weights, w_gate_up, w_down, sort):
-    return _held_rows(k, x2, weights, w_gate_up, w_down, sort), (x2, weights, w_gate_up, w_down, sort)
+def _held_rows_fwd(static, x2, weights, w_gate_up, w_down, sort):
+    return _held_rows(static, x2, weights, w_gate_up, w_down, sort), (x2, weights, w_gate_up, w_down, sort)
 
 
-def _held_rows_bwd(k, res, dy):
+def _held_rows_bwd(static, res, dy):
+    k, form = static
     *inputs, sort = res
     dy = dy.astype(jnp.float32)
 
     def one_pass(c):
-        _, back = jax.vjp(lambda *a: _held_pass(c, *a, sort), *inputs)
+        _, back = jax.vjp(lambda *a: _held_pass(form, c, *a, sort), *inputs)
         return tuple(g.astype(jnp.float32) for g in back(dy))
 
     zero = tuple(jnp.zeros(a.shape, jnp.float32) for a in inputs)
@@ -591,11 +606,19 @@ _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 class DroplessMoE:
-    """Top-k mixture of SiLU-gated experts that drops nothing.
+    """Top-k mixture of experts that drops nothing.
 
     ``apply(params, x [B, T, H]) -> (y, aux, stats)``. The router runs in float32 at
     full precision; the ``k`` largest probabilities weigh their experts as they are
-    (``norm_topk_prob`` renormalises them). A chip sorts its own tokens' assignments by
+    (``norm_topk_prob`` renormalises them). Two things follow from a model's published keys
+    and nothing else. ``router``: ``"softmax"`` of the logits, or ``("sigmoid_bias", factor)``:
+    ``s = sigmoid(logits)``, the ``k`` largest of ``s + b`` chosen (``router_bias [E]``, a
+    leaf no gradient reaches: a model moves it by a rule of its own, from ``stats["counts"]``),
+    each weighted by its OWN ``s``, renormalised over the chosen where ``norm_topk_prob``,
+    times ``factor``; ``aux`` is then zero (such a router is balanced by its bias).
+    ``experts``: ``"silu_gated"``, ``W_down(silu(W_gate x) * W_up x)`` with gate and up side by
+    side in ``w_gate_up [.., H, 2F]``, or ``"relu2"``, ``W_down relu(W_up x)^2`` over
+    ``w_up [.., H, F]``. Everything below is shared by both. A chip sorts its own tokens' assignments by
     expert and each expert's rows go through one grouped matmul (gate and up in one
     product, then down): the buffer holds exactly ``n * k`` rows however the router
     leans, so there is no capacity, no bound and nothing that could fail to fit. ``aux``
@@ -631,35 +654,59 @@ class DroplessMoE:
     the absent chips. Under a mesh every chip is a replica of the same share. With the
     whole range held (``held=None`` or ``(0, num_experts)``) it is the layer above.
 
+    ``stand_in=True`` (held-range form only) lets the held experts stand in for the absent
+    ones: expert ``e``'s rows go through the weights of held expert ``first + (e - first) %
+    count``, as if this chip were each of the ``num_experts / count`` chips in turn. All
+    ``n * k`` assignments are then computed here, ``k`` full passes a layer whatever the
+    router does: the rows a deployment's exchange brings a chip at an even router, and the
+    same work on every step, where the plain held range's rows follow the router's lean
+    (a router that sends every token to one expert gives this chip ``n`` rows more or none,
+    as that expert is held or not). The router, its choice, its weights and ``counts``
+    stay those of all ``num_experts``; ``rows_here`` is ``n * k``.
+
     ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
     mean, over all chips and all ``num_experts``); in the held-range form also
-    ``rows_here`` (float32: the assignments that landed on held experts).
+    ``rows_here`` (float32: the assignments that landed on held experts); with a
+    ``sigmoid_bias`` router also ``counts`` (float32 ``[num_experts]``: the step's assignments
+    to every expert, over all chips).
     """
 
-    def __init__(self, hidden, ffn_dim, num_experts, top_k, norm_topk_prob=False, held=None):
+    def __init__(self, hidden, ffn_dim, num_experts, top_k, norm_topk_prob=False, held=None,
+                 router="softmax", experts=SILU_GATED, stand_in=False):
         self.hidden, self.ffn_dim = hidden, ffn_dim
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = norm_topk_prob
+        assert router == "softmax" or (len(router) == 2 and router[0] == "sigmoid_bias"), router
+        assert experts in (SILU_GATED, RELU2), experts
+        self.scaling = None if router == "softmax" else float(router[1])
+        self.form = experts
+        self.w_in = "w_up" if experts == RELU2 else "w_gate_up"
         first, count = held or (0, num_experts)
         assert 0 <= first and count >= 1 and first + count <= num_experts, held
         self.held = None if count == num_experts else (first, count)
+        assert not stand_in or (self.held is not None and num_experts % count == 0), (held, stand_in)
+        self.stand_in = stand_in
 
     # ------------------------------------------------------------------ params
     def init(self, rng, scale=0.02):
         kr, k1, k2 = jax.random.split(rng, 3)
         H, F, E = self.hidden, self.ffn_dim, self.num_experts
         held = E if self.held is None else self.held[1]
-        return {
+        wide = F if self.form == RELU2 else 2 * F
+        params = {
             "router_w": jax.random.normal(kr, (H, E), jnp.float32) * scale,
             # experts stacked on a leading axis, gate and up side by side: three leaves
-            "w_gate_up": jax.random.normal(k1, (held, H, 2 * F), jnp.float32) * scale,
+            self.w_in: jax.random.normal(k1, (held, H, wide), jnp.float32) * scale,
             "w_down": jax.random.normal(k2, (held, F, H), jnp.float32) * scale,
         }
+        if self.scaling is not None:
+            params["router_bias"] = jnp.zeros((E,), jnp.float32)
+        return params
 
-    @staticmethod
-    def expert_specs(axis):
+    def expert_specs(self, axis):
         """PartitionSpecs of the leaves: experts over ``axis``, the router whole."""
-        return {"router_w": P(), "w_gate_up": P(axis), "w_down": P(axis)}
+        specs = {"router_w": P(), self.w_in: P(axis), "w_down": P(axis)}
+        return specs if self.scaling is None else dict(specs, router_bias=P())
 
     # ------------------------------------------------------------------- apply
     def _expert_axis(self, batch):
@@ -679,23 +726,49 @@ class DroplessMoE:
         a step): ``experts``, each token's choices ``[B, T, k]`` in ascending order, and
         ``router_logits`` ``[B, T, E]`` float32."""
         axis, mesh = self._expert_axis(x.shape[0])
+        # the leaves in a fixed order; the selection bias, where the router has one, last
+        leaves = [params["router_w"], params[self.w_in], params["w_down"]]
+        if self.scaling is not None:
+            leaves.append(params["router_bias"])
         if axis is None:
-            return self._local(None, details, params["router_w"], params["w_gate_up"],
-                               params["w_down"], x)
+            return self._local(None, details, *leaves[:3], x, *leaves[3:])
         specs = self.expert_specs(axis)
         stats_specs = {"load_max_over_mean": P()}
+        if self.scaling is not None:
+            stats_specs["counts"] = P()
         if details:
             stats_specs.update(experts=P(axis), router_logits=P(axis))
         fn = jax.shard_map(
-            lambda r, gu, d, xl: self._local(axis, details, r, gu, d, xl),
-            in_specs=(specs["router_w"], specs["w_gate_up"], specs["w_down"], P(axis)),
+            lambda r, gu, d, xl, *b: self._local(axis, details, r, gu, d, xl, *b),
+            in_specs=(specs["router_w"], specs[self.w_in], specs["w_down"], P(axis))
+            + (P(),) * (len(leaves) - 3),
             out_specs=(P(axis), P(), stats_specs),
             axis_names=frozenset(mesh.auto_axes), check_vma=False)
-        return fn(params["router_w"], params["w_gate_up"], params["w_down"], x)
+        return fn(*leaves[:3], x, *leaves[3:])
 
-    def _local(self, axis, details, router_w, w_gate_up, w_down, x):
-        """One chip's part: ``x`` its tokens, the expert arrays the experts it owns."""
-        H, E, k, F = self.hidden, self.num_experts, self.top_k, self.ffn_dim
+    def _choose(self, logits, bias):
+        """``(weights [n, k], experts [n, k], what ``aux`` sums over the tokens [E])`` from
+        the router's float32 logits ``[n, E]``."""
+        k = self.top_k
+        if self.scaling is None:
+            probs = jax.nn.softmax(logits, axis=-1)                       # [n, E] f32
+            weights, experts = jax.lax.top_k(probs, k)                    # [n, k]
+            if self.norm_topk_prob:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            return weights, experts, jnp.sum(probs, axis=0)
+        scores = jax.nn.sigmoid(logits)
+        # the bias chooses and never weighs; no gradient reaches it
+        _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if self.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return weights * self.scaling, experts, jnp.zeros(logits.shape[1:], jnp.float32)
+
+    def _local(self, axis, details, router_w, w_gate_up, w_down, x, bias=None):
+        """One chip's part: ``x`` its tokens, the expert arrays the experts it owns
+        (``w_gate_up``: the first product's, whatever the experts' form)."""
+        H, E, k = self.hidden, self.num_experts, self.top_k
+        form = self.form
         shape = x.shape
         x2 = x.reshape(-1, H)
         n = x2.shape[0]
@@ -703,16 +776,17 @@ class DroplessMoE:
         with jax.named_scope("ds_moe_router"):
             logits = jnp.dot(x2.astype(jnp.float32), router_w.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            probs = jax.nn.softmax(logits, axis=-1)                       # [n, E] f32
-            weights, experts = jax.lax.top_k(probs, k)                    # [n, k]
-            if self.norm_topk_prob:
-                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-            prob_sum = jnp.sum(probs, axis=0)                             # [E]
+            weights, experts, prob_sum = self._choose(logits, bias)
 
         with jax.named_scope("ds_moe_dispatch"):
             slots = jnp.arange(n * k, dtype=jnp.int32)
-            by_expert, order = jax.lax.sort((experts.reshape(-1).astype(jnp.int32), slots),
-                                            num_keys=1, is_stable=True)
+            sent_to = experts.reshape(-1).astype(jnp.int32)
+            if self.stand_in:
+                # every row to the held expert that stands in for its own: sorted by that one
+                first, count = self.held
+                every = jnp.sum(sent_to[:, None] == jnp.arange(E, dtype=jnp.int32), axis=0)
+                sent_to = first + (sent_to - first) % count
+            by_expert, order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)
             starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
             group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
             if self.held is None:
@@ -733,9 +807,7 @@ class DroplessMoE:
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
                     experts_matmul(xs, gate_up_pieces, firsts, group_sizes), "ds_moe_gate_up")
-                hidden = (jax.nn.silu(gate_up[:, :F].astype(jnp.float32))
-                          * gate_up[:, F:].astype(jnp.float32)).astype(dt)
-                ys = experts_matmul(hidden, down_pieces, firsts, group_sizes)
+                ys = experts_matmul(_activate(form, gate_up, dt), down_pieces, firsts, group_sizes)
             with jax.named_scope("ds_moe_combine"):
                 return _combine_rows(ys, weights, inverse, order)          # [n, H]
 
@@ -752,15 +824,18 @@ class DroplessMoE:
             first, count = self.held
             lo, rows_here = starts[first], starts[first + count] - starts[first]
             sort = (tok, order, lo, rows_here, jnp.cumsum(group_sizes[first:first + count]))
-            y = _held_rows(k, x2, weights, w_gate_up.astype(x2.dtype), w_down.astype(x2.dtype), sort)
+            y = _held_rows((k, form), x2, weights, w_gate_up.astype(x2.dtype),
+                           w_down.astype(x2.dtype), sort)
 
-        counts = group_sizes.astype(jnp.float32)
+        counts = (every if self.stand_in else group_sizes).astype(jnp.float32)
         tokens = n
         if axis is not None:
             counts, prob_sum = jax.lax.psum((counts, prob_sum), axis)
             tokens = n * jax.lax.axis_size(axis)
         aux = E * jnp.sum(jax.lax.stop_gradient(counts) / (tokens * k) * prob_sum / tokens)
         stats = {"load_max_over_mean": jnp.max(counts) / (tokens * k / E)}
+        if self.scaling is not None:
+            stats["counts"] = counts
         if self.held is not None:
             stats["rows_here"] = rows_here.astype(jnp.float32)
         if details:

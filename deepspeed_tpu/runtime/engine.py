@@ -6,7 +6,10 @@ semantics (engine.py:843-852), ``save_checkpoint``/``load_checkpoint``, progress
 but the mechanics are functional JAX:
 
 - the model is a pure function ``model_fn(params, *inputs) -> loss`` (or ``(loss, aux)``;
-  of ``aux`` only the entries a model names in ``device_scalars`` leave the program);
+  of ``aux`` only the entries a model names in ``device_scalars`` leave the program, and
+  those it names in ``rule_sums``, which the engine sums over a step for the rule by which
+  the model itself updates the leaves it names in ``rule_updated_leaves``: they get no
+  optimizer update, no weight decay, no clipping share and no schedule);
   in a functional framework the objective must live inside the traced function, so the
   torch pattern "outputs = engine(x); loss = criterion(outputs); engine.backward(loss)"
   becomes "loss = engine(x, y); engine.backward(loss); engine.step()".
@@ -291,6 +294,18 @@ class DeepSpeedEngine:
         # the names a model declares (``device_scalars``) of the per-step device scalars
         # in the dict its apply returns beside the loss; nothing else of that dict is kept
         self._device_scalar_names = tuple(getattr(model, "device_scalars", ()))
+        # leaves that the model updates by a rule of its own: ``rule_updated_leaves`` names
+        # them (patterns over leaf paths, as ``param_group_patterns``), ``rule_sums`` names the
+        # entries of that dict the rule reads, summed over a step's micro-batches (and, the
+        # batch being global, over the data axis), and ``apply_rule(leaves, sums)`` is the
+        # rule: called once a step inside the update program on the float32 master's named
+        # leaves (the tree with every other leaf None), its result written into the master
+        # and into the compute copy, which holds such a leaf as the master does (it is no
+        # weight: the forward reads what the rule wrote, not a rounding of it)
+        self._rule_patterns = tuple(getattr(model, "rule_updated_leaves", ()))
+        self._rule_sum_names = tuple(getattr(model, "rule_sums", ())) if self._rule_patterns else ()
+        self._rule_fn = getattr(model, "apply_rule", None) if self._rule_patterns else None
+        self._rule_sums = self._pending_rule_sums = None     # the window's sums so far
 
         # ---- precision policy ----
         if self.fp16_enabled():
@@ -432,9 +447,8 @@ class DeepSpeedEngine:
                 max_region_elements=zc.offload_max_region_elements)
         else:
             self.master_params = jax.device_put(master_fp32, self._master_shardings)
-        self.params = jax.device_put(
-            jax.tree_util.tree_map(lambda p: p.astype(self.compute_dtype), master_fp32),
-            self._param_shardings)
+        self._rule_mask = self._build_rule_mask(master_fp32)
+        self.params = jax.device_put(self._compute_copy(master_fp32), self._param_shardings)
 
         # ---- optimizer ----
         self._configure_optimizer(optimizer)
@@ -852,12 +866,9 @@ class DeepSpeedEngine:
         ``[{"pattern": "bias|LayerNorm|ln_", "weight_decay": 0.0}]``."""
         import re
         treedef = jax.tree_util.tree_structure(self.params)
-        paths = jax.tree_util.tree_flatten_with_path(self.params)[0]
         compiled = [re.compile(s["pattern"]) for s in specs]
         ids, counts = [], [0] * (len(specs) + 1)
-        for path, _ in paths:
-            pstr = "/".join(str(getattr(p, "key", getattr(p, "idx", getattr(p, "name", p))))
-                            for p in path)
+        for pstr in self._leaf_paths(self.params):
             gi = 0
             for i, rx in enumerate(compiled):
                 if rx.search(pstr):
@@ -868,6 +879,38 @@ class DeepSpeedEngine:
         log_dist(f"optimizer param groups: {counts[0]} base leaves + "
                  f"{counts[1:]} per pattern group", ranks=[0])
         return jax.tree_util.tree_unflatten(treedef, ids)
+
+    @staticmethod
+    def _leaf_paths(tree):
+        """Every leaf of a parameter tree as ``a/0/b``, in the tree's order: what a
+        pattern over leaf paths is matched against."""
+        return ["/".join(str(getattr(p, "key", getattr(p, "idx", getattr(p, "name", p))))
+                         for p in path)
+                for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+    def _build_rule_mask(self, params):
+        """``params``' tree with True at the leaves the model updates by its own rule
+        (``rule_updated_leaves``), or None where it names none."""
+        if not self._rule_patterns:
+            return None
+        import re
+        assert callable(self._rule_fn), "a model that names rule_updated_leaves gives apply_rule"
+        compiled = [re.compile(pattern) for pattern in self._rule_patterns]
+        mask = [any(rx.search(pstr) for rx in compiled) for pstr in self._leaf_paths(params)]
+        assert any(mask), f"no leaf matches rule_updated_leaves {self._rule_patterns}"
+        log_dist(f"{sum(mask)} leaves are updated by the model's own rule", ranks=[0])
+        return jax.tree_util.tree_unflatten(jax.tree_util.tree_structure(params), mask)
+
+    def _compute_copy(self, master):
+        """``master`` as a step's programs read the parameters: every weight in the compute
+        dtype; a leaf the model updates by its own rule as the master holds it."""
+        def cast(p):
+            return p.astype(self.compute_dtype)
+        if self._rule_mask is None:
+            return jax.tree_util.tree_map(cast, master)
+        # a copy: master and compute copy are donated side by side, never one buffer twice
+        return jax.tree_util.tree_map(lambda m, p: jnp.copy(p) if m else cast(p),
+                                      self._rule_mask, master)
 
     def _configure_optimizer(self, client_optimizer):
         # per-group hyperparameters: JSON config wins, else an optional model hook
@@ -1025,6 +1068,8 @@ class DeepSpeedEngine:
         self._grad_dtype = grad_dtype
 
         scalar_names = self._device_scalar_names
+        sum_names = self._rule_sum_names
+        rule_mask, rule_fn = self._rule_mask, self._rule_fn
 
         def local_loss_and_grad(params, scale, *batch):
             # named_scope is HLO metadata only (zero instructions — asserted by
@@ -1036,11 +1081,15 @@ class DeepSpeedEngine:
                     factor = scale / grad_acc_steps
                     if prescale:
                         factor = factor / predivide
-                    if scalar_names:     # they ride out beside the loss, on every grad path
-                        kept = {name: jax.lax.stop_gradient(out[1][name]) for name in scalar_names}
-                        assert all(v.ndim <= 1 for v in kept.values()), "scalars, or one a layer"
-                        return loss * factor, (loss, kept)
-                    return loss * factor, loss
+                    if not (scalar_names or sum_names):
+                        return loss * factor, loss
+                    # what a model names rides out beside the loss, on every grad path, as
+                    # (loss, its device scalars, the sums its own rule reads); either may be empty
+                    kept = {name: jax.lax.stop_gradient(out[1][name]) for name in scalar_names}
+                    assert all(v.ndim <= 1 for v in kept.values()), "scalars, or one a layer"
+                    sums = {name: jax.lax.stop_gradient(out[1][name]).astype(jnp.float32)
+                            for name in sum_names}
+                    return loss * factor, (loss, kept, sums)
                 (_, loss), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(params)
                 grads = jax.tree_util.tree_map(lambda g: g.astype(grad_dtype), grads)
             return loss, grads
@@ -1197,6 +1246,14 @@ class DeepSpeedEngine:
         fused_grad_ok = (loss_and_grad is local_loss_and_grad
                          or (overlap_active
                              and self._comm_mode != COMM_MODE_COMPRESSED))
+        # a rule-updated leaf lives on the plain path alone (the default two-program step and
+        # the fused step): elsewhere nothing would sum its sums or call its rule
+        assert rule_mask is None or (
+            loss_and_grad is local_loss_and_grad and self._offload is None
+            and self._comm_mode != COMM_MODE_COMPRESSED), (
+            "a model with rule_updated_leaves trains on the plain gradient path: not under "
+            "ZeRO-Offload, 1-bit Adam, sparse gradients, or a hierarchical, compressed or "
+            "bucketed gradient exchange")
         if self.config.fused_step and not (
                 grad_acc_steps == 1 and fused_grad_ok
                 and self._offload is None and not self._cpu_checkpointing_active()):
@@ -1377,11 +1434,39 @@ class DeepSpeedEngine:
                 grads = clip_grads_by_global_norm(grads, clip, norm=norm)
             return grads, overflow, norm, sent
 
-        def apply_update(master, opt_state, scaler_state, acc_grads, params, step, hyper):
+        def by_rule(master, opt_state, new_master, new_opt, sums):
+            """The optimizer's result with the rule-updated leaves taken from the model's own
+            rule instead (called once, here), and their optimizer state left as it was."""
+            flat_mask = jax.tree_util.tree_leaves(rule_mask)
+            named = jax.tree_util.tree_map(lambda m, x: x if m else None, rule_mask, master)
+            moved = iter(jax.tree_util.tree_leaves(rule_fn(named, sums)))
+            new_flat, treedef = jax.tree_util.tree_flatten(new_master)
+            new_master = jax.tree_util.tree_unflatten(treedef, [
+                next(moved).astype(new.dtype) if m else new for m, new in zip(flat_mask, new_flat)])
+
+            def untouched(new_field, old_field):
+                if jax.tree_util.tree_structure(new_field) != treedef:
+                    return new_field
+                return jax.tree_util.tree_map(lambda m, new, old: old if m else new,
+                                              rule_mask, new_field, old_field)
+            if hasattr(new_opt, "_fields"):
+                new_opt = type(new_opt)(*[untouched(n, o) for n, o in zip(new_opt, opt_state)])
+            else:
+                new_opt = untouched(new_opt, opt_state)
+            return new_master, new_opt
+
+        def apply_update(master, opt_state, scaler_state, acc_grads, params, step, hyper,
+                         rule_sums=None):
+            if rule_mask is not None:      # no share of the norm or of the clipping either
+                acc_grads = jax.tree_util.tree_map(
+                    lambda m, g: jnp.zeros_like(g) if m else g, rule_mask, acc_grads)
             grads, overflow, norm, sent = prep_grads(acc_grads, scaler_state)
 
             def do_update(_):
-                return opt_apply(grads, opt_state, master, step, hyper)
+                new_master, new_opt = opt_apply(grads, opt_state, master, step, hyper)
+                if rule_mask is None:
+                    return new_master, new_opt
+                return by_rule(master, opt_state, new_master, new_opt, rule_sums)
 
             def skip_update(_):
                 return master, opt_state
@@ -1392,7 +1477,7 @@ class DeepSpeedEngine:
                                    min_scale=min_scale, hysteresis=hysteresis)
             # params enter only to donate their buffer to the re-cast output
             del params
-            new_params = jax.tree_util.tree_map(lambda p: p.astype(compute_dtype), new_master)
+            new_params = self._compute_copy(new_master)
             if sent is not None:
                 # weight norm + update magnitude per subtree (update is exactly
                 # zero on a skipped step — the cond selected the old master)
@@ -1468,7 +1553,8 @@ class DeepSpeedEngine:
                 loss, grads = loss_and_grad(params, scaler_state.cur_scale,
                                             *batch)
                 return (loss,) + apply_update(master, opt_state, scaler_state,
-                                              grads, params, step, hyper)
+                                              grads, params, step, hyper,
+                                              self._loss_scalars_sums(loss)[2])
 
             jit_fused = self._watch("fused_step", jax.jit(
                 fused_step,
@@ -1640,9 +1726,22 @@ class DeepSpeedEngine:
             "strict": True,
         }
         args = (self.master_params, self.opt_state, self.scaler_state,
-                acc_in, self.params, step, hyper)
+                acc_in, self.params, step, hyper, self._rule_sum_shapes(batch))
         progs.append(("apply_update", self._jit_apply_update, args, au_man))
         return progs
+
+    def _loss_scalars_sums(self, out):
+        """``(loss, device scalars, rule sums)`` from what a gradient program returns in its
+        loss's place: the three where the model names scalars or sums, else the bare loss."""
+        return out if self._device_scalar_names or self._rule_sum_names else (out, {}, {})
+
+    def _rule_sum_shapes(self, batch):
+        """The update program's last operand, as shapes: the sums the model's rule reads
+        (an empty dict without rule-updated leaves)."""
+        if not self._rule_sum_names:
+            return {}
+        out = jax.eval_shape(self._loss_and_grad_fn, self.params, self.scaler_state.cur_scale, *batch)
+        return self._loss_scalars_sums(out[0])[2]
 
     def memory_manifest(self):
         """The memory analogue of ``lint_programs``: every persistent
@@ -1850,9 +1949,11 @@ class DeepSpeedEngine:
                     "train.grad_program", "loss_and_grad", self._jit_loss_and_grad,
                     self.params, self.scaler_state.cur_scale, *batch)
                 self._pending_grads = grads
-            if self._device_scalar_names:      # whichever program ran: (loss, the scalars)
-                loss, scalars = loss
+            loss, scalars, sums = self._loss_scalars_sums(loss)     # whichever program ran
+            if scalars:
                 self._spans.keep_device_scalars(self._span_engine, self.global_steps, scalars)
+            # the fused step has called the rule already
+            self._pending_rule_sums = sums if sums and not use_fused else None
             self._pending_loss = loss
         else:
             self._goodput_begin_eval()
@@ -1880,6 +1981,10 @@ class DeepSpeedEngine:
             if self.wall_clock_breakdown():
                 self.timers("backward_microstep").stop()
             return loss
+        if self._pending_rule_sums is not None:      # summed over the window like the gradients
+            self._rule_sums = self._pending_rule_sums if self._rule_sums is None else \
+                jax.tree_util.tree_map(jnp.add, self._rule_sums, self._pending_rule_sums)
+            self._pending_rule_sums = None
         with self._spans.span("train.accumulate", engine=self._span_engine):
             if self._grad_acc is None:
                 # First micro-batch of the window: adopt the grads directly (they already
@@ -1907,6 +2012,7 @@ class DeepSpeedEngine:
 
     def zero_grad(self):
         self._grad_acc = None
+        self._rule_sums = None
         # Fused-step window (gas==1): the optimizer update was
         # already applied at forward() (its inputs were donated and cannot be
         # restored); zeroing mid-window abandons only the step bookkeeping.
@@ -1948,10 +2054,11 @@ class DeepSpeedEngine:
             return
         hyper = self.optimizer.current_hyper()
         step = jnp.asarray(self.global_steps + 1 - self.skipped_steps, jnp.int32)
+        rule_sums, self._rule_sums = self._rule_sums, None
         outs = self._call_program(
             "train.update_program", "apply_update", self._jit_apply_update,
             self.master_params, self.opt_state, self.scaler_state, self._grad_acc,
-            self.params, step, hyper)
+            self.params, step, hyper, rule_sums or {})
         # the numerics sentinel, when on, is one more output
         (self.master_params, self.opt_state, self.scaler_state, self.params,
          overflow, self._last_grad_norm, *sent) = outs
@@ -2367,7 +2474,7 @@ class DeepSpeedEngine:
                     self._grad_shardings, self.params)
                 upd = _profile(self._jit_apply_update, self.master_params,
                                self.opt_state, self.scaler_state, grads,
-                               self.params, step_no, hyper)
+                               self.params, step_no, hyper, self._rule_sum_shapes(batch))
                 for k in ("flops", "bytes_accessed"):
                     report[k] += upd[k]
                 report["program_flops"]["apply_update"] = upd["flops"]

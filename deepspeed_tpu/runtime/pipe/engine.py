@@ -126,6 +126,8 @@ class PipelineEngine(DeepSpeedEngine):
                  training_data=None, lr_scheduler=None, mpu=None, dist_init_required=None,
                  collate_fn=None, config_params=None, mesh=None):
         assert isinstance(model, PipelineModule), "model must be a PipelineModule"
+        assert not getattr(model, "rule_updated_leaves", ()), \
+            "the pipeline engine has no step for leaves a model updates by a rule of its own"
         self.pipe_module = model
         self.num_stages = model.num_stages
 

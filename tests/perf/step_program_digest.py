@@ -1,0 +1,44 @@
+"""sha256 of the ENGINE's lowered step programs (gradient and update program, and the fused step)
+for the accepted hybrid and expert models at the tests' small sizes, source locations taken out:
+run it on two trees and compare the lines (a PR that says "the programs of the accepted cells are
+unchanged" shows it so): python tests/perf/step_program_digest.py <root of a checkout>"""
+import hashlib
+import os
+import re
+import sys
+
+root = os.path.abspath(sys.argv[1])
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, root)
+sys.path.insert(0, os.path.join(root, "tests/unit"))
+os.chdir(root)
+
+import numpy as np  # noqa: E402
+
+import deepspeed_tpu  # noqa: E402
+import test_granite_hybrid as granite, test_olmoe as olmoe, test_ouro as ouro, test_qwen3_next as qwen  # noqa: E401,E402
+
+
+def digest(jitted, *args):
+    text = re.sub(r"#loc.*", "", re.sub(r"loc\(.*?\)", "", jitted.lower(*args).as_text()))
+    return hashlib.sha256(text.encode()).hexdigest()[:16], len(text)
+
+
+def models():
+    for name, mod, kw in (("granite_remat", granite, {"remat": True}), ("qwen3next", qwen, {}), ("ouro", ouro, {})):
+        _, model, params = mod.build(**kw)
+        yield name, model, params, mod.batch()
+    _, model, params = olmoe.build(2)
+    yield "olmoe", model, params, olmoe.batch()
+
+
+for fused in (False, True):
+    for name, model, params, batch in models():
+        tokens, labels = (np.concatenate([np.asarray(a)] * 8)[:8] for a in batch)
+        engine, *_ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
+            "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-5}}, "steps_per_print": 10 ** 9,
+            "fused_step": fused})
+        for program, jitted, args, _ in engine.lint_programs((tokens, labels)):
+            print(name, "fused" if fused else "two-program", program, *digest(jitted, *args), flush=True)

@@ -119,6 +119,75 @@ def test_every_token_to_the_same_experts_is_computed_whole(top_k, devices):
     np.testing.assert_allclose(y.reshape(-1, H), want, atol=1e-4)
 
 
+@pytest.mark.parametrize("devices", [1, 4], ids=["1dev", "4dev"])
+@pytest.mark.parametrize("router, experts", [("softmax", "relu2"), (("sigmoid_bias", 2.5), "silu_gated"),
+                                             (("sigmoid_bias", 2.5), "relu2")],
+                         ids=["softmax-relu2", "sigmoid_bias-silu_gated", "sigmoid_bias-relu2"])
+def test_the_routers_rule_and_the_experts_form_follow_the_constructor(router, experts, devices):
+    """The two things a model's published keys decide, against a plain loop over the experts:
+    a sigmoid router chooses by ``s + b`` and weighs by ``s`` alone (experts 6 and 7 carry a
+    bias that gets them chosen whatever they score), renormalised and scaled; no gradient
+    reaches ``b``; ``relu2`` experts are two matrices. The counts are every chip's, summed."""
+    E, H, F, k = 8, 64, 32, 3
+    layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, router=router, experts=experts)
+    params = layer.init(jax.random.PRNGKey(0), 0.2)
+    w_in = "w_up" if experts == "relu2" else "w_gate_up"
+    assert params[w_in].shape == (E, H, F if experts == "relu2" else 2 * F)
+    assert ("router_bias" in params) == (router != "softmax") and set(layer.expert_specs("data")) == set(params)
+    if router != "softmax":
+        params["router_bias"] = jnp.where(jnp.arange(E) >= 6, 5.0, 0.0) + 0.05 * jnp.arange(E)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, H))
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def plain(params, x):
+        x2 = x.reshape(-1, H)
+        logits = jnp.dot(x2, params["router_w"], precision="highest")
+        if router == "softmax":
+            top, chosen = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+            top = top / jnp.sum(top, -1, keepdims=True)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(scores + params["router_bias"], k)
+            top = jnp.take_along_axis(scores, chosen, -1)
+            top = router[1] * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+        weight = jnp.sum(jax.nn.one_hot(chosen, E) * top[..., None], axis=1)              # [n, E]
+        y = 0.0
+        for e in range(E):
+            up = jnp.dot(x2, params[w_in][e], precision="highest")
+            hidden = jnp.square(jax.nn.relu(up)) if experts == "relu2" else jax.nn.silu(up[:, :F]) * up[:, F:]
+            y = y + weight[:, e:e + 1] * jnp.dot(hidden, params["w_down"][e], precision="highest")
+        return y.reshape(x.shape), chosen
+
+    want, chosen = plain(params, x)
+    want_grads = jax.grad(lambda p, x: jnp.sum(plain(p, x)[0] * cot), argnums=(0, 1))(params, x)
+    if router != "softmax":         # the biased experts are chosen every time, and weigh what they score
+        assert np.all(np.sum(np.asarray(chosen) >= 6, axis=-1) == 2)
+
+    def run(params, x):
+        y, aux, stats = layer.apply(params, x)
+        return jnp.sum(y.astype(jnp.float32) * cot), (y, aux, stats)
+
+    if devices > 1:
+        mesh = build_mesh(data=devices, devices=jax.devices()[:devices])
+        specs = layer.expert_specs("data")
+        params = {name: jax.device_put(v, NamedSharding(mesh, specs[name])) for name, v in params.items()}
+        x = jax.device_put(x, NamedSharding(mesh, P("data")))
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            (_, (y, aux, stats)), grads = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))(params, x)
+    else:
+        (_, (y, aux, stats)), grads = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))(params, x)
+    np.testing.assert_allclose(y, want, atol=2e-4)
+    for name in params:
+        np.testing.assert_allclose(grads[0][name], want_grads[0][name], atol=3e-4, err_msg=name)
+    np.testing.assert_allclose(grads[1], want_grads[1], atol=3e-4)
+    if router == "softmax":
+        assert "counts" not in stats and float(aux) > 0
+    else:
+        assert not np.any(grads[0]["router_bias"]) and float(aux) == 0.0
+        assert np.array_equal(stats["counts"], np.bincount(np.asarray(chosen).reshape(-1), minlength=E))
+        assert float(stats["load_max_over_mean"]) == pytest.approx(64 / (64 * k / E))
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
 @pytest.mark.parametrize("pieces", [1, 2, 4], ids=["whole", "2-pieces", "4-pieces"])
 @pytest.mark.parametrize("sizes", [[16, 0, 40, 8], [64, 0, 0, 0], [0, 0, 0, 64], [16, 16, 16, 16]],
@@ -264,3 +333,54 @@ def test_initialize_takes_the_model_as_it_takes_gpt2():
     engine.backward(loss)
     engine.step()
     assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("stand_in", [False, True], ids=["absent-left-out", "held-stand-in"])
+def test_a_held_range_leaves_the_absent_experts_out_or_stands_in_for_them(stand_in):
+    """Experts 4..7 of 16 held, a sigmoid router over all 16 with three a token, against a plain
+    loop. Left out: only the rows sent to 4..7 add their part, and ``rows_here`` counts them.
+    Standing in: expert ``e``'s rows go through held expert ``4 + (e - 4) % 4``, every
+    assignment is computed (``rows_here = n k`` whatever the router does), and choice, weights
+    and ``counts`` stay those of all 16."""
+    E, H, F, k, first, count = 16, 64, 32, 3, 4, 4
+    layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, count),
+                        router=("sigmoid_bias", 2.5), experts="relu2", stand_in=stand_in)
+    params = layer.init(jax.random.PRNGKey(0), 0.2)
+    assert params["w_up"].shape == (count, H, F) and params["router_w"].shape == (H, E)
+    params["router_bias"] = 0.05 * jnp.arange(E, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, H))
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def plain(params, x):
+        x2 = x.reshape(-1, H)
+        scores = jax.nn.sigmoid(jnp.dot(x2, params["router_w"], precision="highest"))
+        _, chosen = jax.lax.top_k(scores + params["router_bias"], k)
+        top = jnp.take_along_axis(scores, chosen, -1)
+        top = 2.5 * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+        weight = jnp.sum(jax.nn.one_hot(chosen, E) * top[..., None], axis=1)              # [n, E]
+        y = 0.0
+        for e in range(E) if stand_in else range(first, first + count):
+            held = (e - first) % count
+            hidden = jnp.square(jax.nn.relu(jnp.dot(x2, params["w_up"][held], precision="highest")))
+            y = y + weight[:, e:e + 1] * jnp.dot(hidden, params["w_down"][held], precision="highest")
+        return y.reshape(x.shape), chosen
+
+    want, chosen = plain(params, x)
+    want_grads = jax.grad(lambda p, x: jnp.sum(plain(p, x)[0] * cot), argnums=(0, 1))(params, x)
+
+    def run(params, x):
+        y, aux, stats = layer.apply(params, x)
+        return jnp.sum(y.astype(jnp.float32) * cot), (y, stats)
+
+    (_, (y, stats)), grads = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))(params, x)
+    np.testing.assert_allclose(y, want, atol=2e-4)
+    for name in params:
+        np.testing.assert_allclose(grads[0][name], want_grads[0][name], atol=3e-4, err_msg=name)
+    np.testing.assert_allclose(grads[1], want_grads[1], atol=3e-4)
+    assert not np.any(grads[0]["router_bias"])
+    chosen = np.asarray(chosen)
+    assert np.array_equal(stats["counts"], np.bincount(chosen.reshape(-1), minlength=E))
+    here = chosen.size if stand_in else np.sum((chosen >= first) & (chosen < first + count))
+    assert float(stats["rows_here"]) == here and (stand_in or here < chosen.size)
+    with pytest.raises(AssertionError):      # nothing to stand in for where every expert is held
+        DroplessMoE(H, F, E, k, router=("sigmoid_bias", 2.5), experts="relu2", stand_in=True)

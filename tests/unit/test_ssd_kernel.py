@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import granite_hybrid_reference as ref
+from benchmarks.reference import nemotron_h_reference as grouped_ref
 from deepspeed_tpu.ops import ssd
 from deepspeed_tpu.ops.pallas import ssd as kernels
 from deepspeed_tpu.ops.ssd import ssd_scan
@@ -19,14 +20,16 @@ ARGNUMS = tuple(range(6))
 NAMES = ("x", "dt", "A", "B", "C", "D")
 
 
-def inputs(T, H=4, P=64, N=128, seed=0, rates=None, dtype=jnp.float32):
+def inputs(T, H=4, P=64, N=128, seed=0, rates=None, dtype=jnp.float32, groups=None):
     """x, B, C holding bfloat16 values (as the convolution leaves them), dt log-uniform in
-    [0.001, 0.1], A from slow to fast, D, and a cotangent of bfloat16 values."""
+    [0.001, 0.1], A from slow to fast, D, and a cotangent of bfloat16 values. ``groups``: B and
+    C ``[1, T, groups, N]``, one for each group of heads."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     low = lambda key, *shape: jax.nn.silu(jax.random.normal(key, shape)).astype(jnp.bfloat16).astype(dtype)  # noqa: E731
     dt = jnp.exp(jax.random.uniform(ks[1], (1, T, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
     A = -jnp.asarray(rates if rates is not None else np.geomspace(1.0, 64.0, H), jnp.float32)
-    return ((low(ks[0], 1, T, H, P), dt, A, low(ks[2], 1, T, N), low(ks[3], 1, T, N),
+    bc = (1, T, N) if groups is None else (1, T, groups, N)
+    return ((low(ks[0], 1, T, H, P), dt, A, low(ks[2], *bc), low(ks[3], *bc),
              1.0 + 0.1 * jax.random.normal(ks[4], (H,))), low(ks[5], 1, T, H, P))
 
 
@@ -35,20 +38,34 @@ def grads(fn, args, cot):
                     argnums=ARGNUMS)(*args)
 
 
-@pytest.mark.parametrize("T, heads", [(100, 8), (300, 2)], ids=["under-a-tile", "over-a-tile-two-groups"])
-def test_the_kernels_at_the_cells_head_widths_are_the_recurrence(T, heads, monkeypatch):
+@pytest.mark.parametrize("T, heads, H, groups", [(100, 8, 4, None), (300, 2, 4, None), (300, 8, 4, 2),
+                                                 (150, 2, 16, 8), (140, 1, 4, 2)],
+                         ids=["under-a-tile", "over-a-tile-two-groups", "two-B-C-groups",
+                              "eight-B-C-groups", "two-steps-a-B-C-group"])
+def test_the_kernels_at_the_cells_head_widths_are_the_recurrence(T, heads, H, groups, monkeypatch):
     """Heads of 64 with a state of 128, two sharing a register's lanes; one group of four heads
-    a grid step, then two groups of two over three tiles: forward and all six gradients."""
+    a grid step, then two groups of two over three tiles: forward and all six gradients. Then
+    heads that come in groups with a B and C each (Nemotron-H's eight): a grid step one
+    group's heads, and two grid steps a group (``HEADS`` 1 divides a group's two)."""
     monkeypatch.setattr(ssd, "HEADS", heads)
-    args, cot = inputs(T, seed=T)
+    args, cot = inputs(T, H=H, seed=T, groups=groups)
+    recurrence = ref.ssm_recurrent if groups is None else grouped_ref.ssm_recurrent
     with jax.default_matmul_precision("highest"):
-        want, want_grads = ref.ssm_recurrent(*args), grads(ref.ssm_recurrent, args, cot)
+        want, want_grads = recurrence(*args), grads(recurrence, args, cot)
     got = ssd_scan(*args)
     assert got.shape == want.shape and got.dtype == jnp.float32
     assert rel(got, want) < 2e-6
     for name, g, w in zip(NAMES, grads(ssd_scan, args, cot), want_grads):
         assert g.shape == w.shape and g.dtype == w.dtype
         assert rel(g, w) < 1e-5, name
+
+
+def test_one_group_of_b_and_c_is_the_ungrouped_call_bit_for_bit():
+    args, cot = inputs(200, seed=9)
+    one = tuple(a[:, :, None] if i in (3, 4) else a for i, a in enumerate(args))
+    assert np.array_equal(ssd_scan(*args), ssd_scan(*one))
+    for name, g, w in zip(NAMES, grads(ssd_scan, one, cot), grads(ssd_scan, args, cot)):
+        assert np.array_equal(np.asarray(g).reshape(w.shape), w), name
 
 
 def test_the_bfloat16_call_is_the_float32_call_on_the_same_values():
@@ -68,20 +85,27 @@ def test_the_bfloat16_call_is_the_float32_call_on_the_same_values():
 
 
 def recurrence64(x, dt, A, B, C, D):
-    """The recurrence a token at a time in float64, one row."""
+    """The recurrence a token at a time in float64, one row; ``B``, ``C`` ``[1, T, N]`` or, a
+    group of heads each, ``[1, T, G, N]``."""
     x, dt, A, B, C, D = (np.asarray(a, np.float64) for a in (x, dt, A, B, C, D))
+    if B.ndim == 4:          # every head its group's
+        B, C = (np.repeat(a, x.shape[2] // a.shape[2], axis=2) for a in (B, C))
+    else:
+        B, C = (np.repeat(a[:, :, None], x.shape[2], axis=2) for a in (B, C))
     S = np.zeros((x.shape[2], x.shape[3], B.shape[-1]))
     y = np.zeros(x.shape[1:])
     for t in range(x.shape[1]):
-        S = np.exp(dt[0, t] * A)[:, None, None] * S + (dt[0, t, :, None] * x[0, t])[:, :, None] * B[0, t]
-        y[t] = S @ C[0, t] + D[:, None] * x[0, t]
+        S = np.exp(dt[0, t] * A)[:, None, None] * S + (dt[0, t, :, None] * x[0, t])[:, :, None] * B[0, t][:, None, :]
+        y[t] = np.einsum("hpn,hn->hp", S, C[0, t]) + D[:, None] * x[0, t]
     return y
 
 
-def test_a_head_that_forgets_slowly_keeps_its_state_over_two_thousand_tokens():
+@pytest.mark.parametrize("groups", [None, 2], ids=["one-B-C", "a-B-C-a-head"])
+def test_a_head_that_forgets_slowly_keeps_its_state_over_two_thousand_tokens(groups):
     """``A dt`` = 1e-3 a token: the state carries a thousand tokens of memory through sixteen
-    tiles nearly whole. Against the recurrence in float64."""
-    (x, dt, A, B, C, D), _ = inputs(2048, H=2, P=8, N=16, rates=[1.0, 40.0])
+    tiles nearly whole. Against the recurrence in float64 (with groups too: the grouped scan's
+    limit in the benchmark is under 1e-4, so it is checked against float64 once, here)."""
+    (x, dt, A, B, C, D), _ = inputs(2048, H=2, P=8, N=16, rates=[1.0, 40.0], groups=groups)
     dt = dt.at[:, :, 0].set(1e-3)
     got = np.asarray(ssd_scan(x, dt, A, B, C, D), np.float64)[0]
     want = recurrence64(x, dt, A, B, C, D)

@@ -178,6 +178,47 @@ def test_a_recomputed_state_space_block_and_the_tied_head_compile_for_v5e(chip, 
     assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
 
 
+def test_the_grouped_state_space_scan_compiles_for_v5e(chip):
+    """The scan as ``nemotronh_ep16_d9_train_1chip`` calls it: 64 heads of 64 in EIGHT groups
+    with a B and C of 128 each, a grid step one group's eight heads, tiles of 128 tokens."""
+    from deepspeed_tpu.ops.ssd import ssd_scan
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    f32 = jnp.float32
+    args = (shape(1, 8192, 64, 64), shape(1, 8192, 64, dt=f32), shape(64, dt=f32),
+            shape(1, 8192, 8, 128), shape(1, 8192, 8, 128), shape(64, dt=f32))
+    loss = lambda *a: jnp.sum(ssd_scan(*a, 128, interpret=False).astype(jnp.float32) ** 2)  # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ds_ssd_scan_fwd" in text and "ds_ssd_scan_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, monkeypatch):
+    """The gradient program of ``nemotronh_ep16_d9_train_1chip`` WHOLE: the published widths, the
+    nine layers MEMEM*EME with 8 of 128 experts held, 1 x 8,192 positions, whole layers
+    recomputed, the untied head's cross-entropy over 16,384 words. The grouped scan, the
+    convolution over 6,144 channels, the flash kernel at sixteen query heads a key/value head
+    and the megablox products over experts 1,856 wide (no multiple of 128) are all in it, and
+    what it needs beside its parameters and their gradients stays under the 5.3 GB that 10.67 GB
+    of training state leave on the chip."""
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners.train_ssm_moe import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels, not their interpreters
+    model = build_model(Manifest().config("nemotron-twotower-30b-a3b-ep16-d9"))
+    assert model.config.kinds == "MEMEM*EME" and model.config.remat
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 666_963_456
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
+    text = compiled.as_text()
+    for kernel in ("ds_ssd_scan_fwd", "ds_ssd_scan_bwd", "ds_causal_conv_fwd", "ds_flash_fwd", "gmm"):
+        assert kernel in text, kernel
+    # 1.39 GB as compiled here (PR 40)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.7e9
+
+
 def looped_gradient_program(chip, monkeypatch, layers, passes):
     """Ouro's gradient program at its published widths and 2 x 4,096 positions, whole blocks
     recomputed, compiled for the described chip; and the shapes of its leaves."""
