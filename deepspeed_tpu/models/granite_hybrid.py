@@ -69,8 +69,7 @@ class GraniteHybridConfig:
     logits_scaling: float = 8.0
     rms_norm_eps: float = 1e-5
     initializer_range: float = 0.02
-    remat: bool = False            # whole blocks made again in the backward
-    remat_policy: Any = None       # None: only a block's input is kept
+    remat: bool = False            # whole blocks made again in the backward: a block keeps KEPT_BY_A_BLOCK
     compute_dtype: Any = jnp.bfloat16
 
     @classmethod
@@ -103,6 +102,16 @@ class GraniteHybridConfig:
     @property
     def mamba_inner(self):
         return self.mamba_n_heads * self.mamba_d_head
+
+
+# What a recomputed block keeps beside its input, by name: the flash kernel's output and row
+# sums (named in its forward rule), the mixer's last product's output (``norm_2`` and the MLP
+# read ``x + r * mixed`` inside the same block, so that product is NOT dead in the second
+# forward, where the MLP's last one is: nothing behind it reads its output), and the Mamba-2
+# mixers' first product's output as the forward leaves it: the compute dtype's copy and the
+# float32 ``dt`` columns. Bytes and what each buys on a v5e: docs/granite-hybrid.md, PERF.md (PR 41).
+KEPT_BY_A_BLOCK = jax.checkpoint_policies.save_only_these_names(
+    "attn_out", "attn_lse", "mixer_out", "ssm_in", "ssm_dt")
 
 
 def _dot(x, w):
@@ -166,10 +175,12 @@ class GraniteHybridModel:
         inner, N = c.mamba_inner, c.mamba_d_state
         x = checkpoint_name(x, "ds_dot:qkv")      # the remat policies classify dots by tag
         proj = _dot(x, mp["w_in"])                                            # float32
-        dt = jax.nn.softplus(proj[..., 2 * inner + 2 * N:] + mp["dt_bias"])
+        # dt is read off the float32 product; both of the product's readers are named, so a
+        # block that keeps them runs no second product
+        dt = jax.nn.softplus(checkpoint_name(proj[..., 2 * inner + 2 * N:], "ssm_dt") + mp["dt_bias"])
         # the gate and the convolution's input in the compute dtype, where the projection
         # leaves them: the convolution reads its columns in place
-        proj = proj.astype(x.dtype)
+        proj = checkpoint_name(proj.astype(x.dtype), "ssm_in")
         z = proj[..., :inner]
         xBC = causal_conv(proj, mp["conv_w"], True, mp["conv_b"], columns=(inner, 2 * inner + 2 * N))
         xs, Bm, Cm = jnp.split(xBC, [inner, inner + N], axis=-1)
@@ -209,8 +220,7 @@ class GraniteHybridModel:
         x = checkpoint_name(x, "ds_dot:qkv")
         k, v = jnp.split(_dot(x, mp["wkv"]).astype(x.dtype).reshape(B, T, 2 * nkv, D), 2, axis=2)
         y = flash_attention(heads(q), heads(k), heads(v), True, sm_scale=c.attention_multiplier)
-        y = checkpoint_name(heads(checkpoint_name(y, "attn_out")).reshape(B, T, nq * D),
-                            "ds_dot:proj")
+        y = checkpoint_name(heads(y).reshape(B, T, nq * D), "ds_dot:proj")
         return _dot(y, mp["wo"]).astype(x.dtype)
 
     def mlp(self, x, mp):
@@ -225,7 +235,7 @@ class GraniteHybridModel:
             n = self._norm(x, lp["norm_1"])
             mixed = self.attention(n, lp["mixer"]) if kind == ATTENTION \
                 else self.mamba_mixer(n, lp["mixer"])
-            x = x + r * mixed
+            x = x + r * checkpoint_name(mixed, "mixer_out")
         with jax.named_scope("ds_mlp"):
             x = x + r * self.mlp(self._norm(x, lp["norm_2"]), lp["mlp"])
         return (x, n) if details else x
@@ -240,7 +250,7 @@ class GraniteHybridModel:
         for l, lp in enumerate(params["layers"]):
             block = functools.partial(self._block, kind=c.kind(l), details=details)
             if c.remat and not details:     # config-aware remat, as ``models/gpt2.py``'s blocks
-                block = checkpoint_wrapper(block, policy=c.remat_policy)
+                block = checkpoint_wrapper(block, policy=KEPT_BY_A_BLOCK)
             x = block(x, lp)
             if details:
                 x, n = x

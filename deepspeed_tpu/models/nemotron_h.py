@@ -90,7 +90,7 @@ class NemotronHConfig:
     bias_update_rate: float = 1e-3           # u of the rule; no published key
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
-    remat: bool = False            # whole layers made again in the backward: only a layer's input is kept
+    remat: bool = False            # whole layers made again in the backward: a layer keeps KEPT_BY_A_LAYER
     compute_dtype: Any = jnp.bfloat16
 
     @classmethod
@@ -123,6 +123,16 @@ class NemotronHConfig:
     @property
     def mamba_inner(self):
         return self.mamba_num_heads * self.mamba_head_dim
+
+
+# What a recomputed layer keeps beside its input, by name: the flash kernel's output and row
+# sums (named in its forward rule), the Mamba-2 mixers' first product's output as the forward
+# leaves it (the compute dtype's copy and the float32 ``dt`` columns), and the shared expert's
+# first product's output before its activation. Every layer ends ``x + f(norm(x))``: nothing in
+# a layer's backward reads its LAST product's output, so the second forward never ran one.
+# Bytes and what each buys on a v5e: docs/nemotron-h.md, PERF.md (PR 41).
+KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
+    "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up")
 
 
 def _dot(x, w):
@@ -206,10 +216,12 @@ class NemotronHModel:
         inner, G, N = c.mamba_inner, c.n_groups, c.ssm_state_size
         x = checkpoint_name(x, "ds_dot:qkv")      # the remat policies classify dots by tag
         proj = _dot(x, mp["w_in"])                                            # float32
-        dt = jax.nn.softplus(proj[..., 2 * inner + 2 * G * N:] + mp["dt_bias"])
+        # dt is read off the float32 product; both of the product's readers are named, so a
+        # layer that keeps them runs no second product
+        dt = jax.nn.softplus(checkpoint_name(proj[..., 2 * inner + 2 * G * N:], "ssm_dt") + mp["dt_bias"])
         # the gate and the convolution's input in the compute dtype, where the projection
         # leaves them: the convolution reads its columns in place
-        proj = proj.astype(x.dtype)
+        proj = checkpoint_name(proj.astype(x.dtype), "ssm_in")
         z = proj[..., :inner]
         xBC = causal_conv(proj, mp["conv_w"], True, mp["conv_b"],
                           columns=(inner, 2 * inner + 2 * G * N))
@@ -255,8 +267,7 @@ class NemotronHModel:
         x = checkpoint_name(x, "ds_dot:qkv")
         k, v = jnp.split(_dot(x, mp["wkv"]).astype(x.dtype).reshape(B, T, 2 * nkv, D), 2, axis=2)
         y = flash_attention(heads(q), heads(k), heads(v), True)
-        y = checkpoint_name(heads(checkpoint_name(y, "attn_out")).reshape(B, T, nq * D),
-                            "ds_dot:proj")
+        y = checkpoint_name(heads(y).reshape(B, T, nq * D), "ds_dot:proj")
         return _dot(y, mp["wo"]).astype(x.dtype)
 
     def expert_layer(self, x, lp, details=False):
@@ -264,7 +275,7 @@ class NemotronHModel:
         y, _, stats = self.moe.apply(lp["moe"], x, details)
         with jax.named_scope("ds_moe_shared"):
             sp = lp["shared"]
-            hidden = _relu2(_dot(x, sp["w_up"])).astype(x.dtype)
+            hidden = _relu2(checkpoint_name(_dot(x, sp["w_up"]), "shared_up")).astype(x.dtype)
             shared = _dot(hidden, sp["w_down"])
         stats["bias_abs_max"] = jnp.max(jnp.abs(jax.lax.stop_gradient(
             lp["moe"]["router_bias"]).astype(jnp.float32)))
@@ -294,7 +305,7 @@ class NemotronHModel:
         for kind, lp in zip(c.kinds, params["layers"]):
             layer = functools.partial(self._layer, kind=kind, details=details)
             if c.remat and not details:     # config-aware remat, as ``models/gpt2.py``'s blocks
-                layer = checkpoint_wrapper(layer)
+                layer = checkpoint_wrapper(layer, policy=KEPT_BY_A_LAYER)
             x, s = layer(x, lp)
             if details:
                 seen.append(s.pop("layer_in"))

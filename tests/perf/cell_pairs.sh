@@ -2,11 +2,12 @@
 # Parent against change in cells the benchmark already has, in one call to the chip, every pair on a seed
 # of its own: usage (from the repo's root, the parent unpacked into _parent with `git archive`):
 #   bash tests/perf/cell_pairs.sh <out> <cell> <seed> <side> [<side> ...]
-# a side is "parent" or "change"; every two runs share a seed (parent change change parent: two pairs).
+# a side is "parent", "change" (the working tree) or "archive" (the committed files alone, unpacked into
+# _archive_check with `git archive $(git write-tree)`); every two runs share a seed (parent change change parent: two pairs).
 out=/root/repo/chiprun_out/$1; mkdir -p $out; cell=$2; seed=$3; shift 3
 i=0
 for side in "$@"; do
-  if [ "$side" = parent ]; then dir=/root/repo/_parent; else dir=/root/repo; fi
+  case $side in parent) dir=/root/repo/_parent;; archive) dir=/root/repo/_archive_check;; *) dir=/root/repo;; esac
   t0=$(date +%s)
   (cd $dir && timeout 1500 python3 benchmarks/run.py --workload $cell --seed $seed --seconds 40 --trace 0 \
      > $out/$cell.$seed.$side.out 2> $out/$cell.$seed.$side.err)

@@ -1,8 +1,14 @@
 """Granite 4.0-H on the normal path against its plain float32 reference
 (``benchmarks/reference/granite_hybrid_reference.py``) on seeded weights at a tiny size: the
 whole model through ``deepspeed_tpu.initialize`` (loss, logits, the gradient of every leaf,
-with whole blocks recomputed and without), the gate-then-norm of the Mamba-2 mixer, the
-attention's published scale and its lack of positions, the four multipliers, the tied table."""
+with whole blocks recomputed and without), what a recomputed block keeps by name, the
+gate-then-norm of the Mamba-2 mixer, the attention's published scale and its lack of
+positions, the four multipliers, the tied table."""
+
+import collections
+import contextlib
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -11,8 +17,10 @@ import pytest
 
 import deepspeed_tpu
 from benchmarks.reference import granite_hybrid_reference as ref
+from deepspeed_tpu.models import granite_hybrid
 from deepspeed_tpu.models.granite_hybrid import GraniteHybridConfig, GraniteHybridModel
 from deepspeed_tpu.models.layers import rope
+from test_ouro import kernels_in_the_backward, primitives_by_path, residuals_by_shape
 
 KINDS = ["mamba", "mamba", "attention", "mamba"]
 
@@ -105,6 +113,112 @@ def test_recomputed_blocks_give_the_same_loss_and_gradients():
     assert "rematted_computation/ds_attn/ds_ssm/ds_ssd_scan" in text and "rematted_computation/ds_mlp" in text
     assert "rematted_computation/ds_attn" not in jax.jit(jax.grad(kept.apply)).lower(
         params, tokens, labels).as_text(debug_info=True)
+
+
+# ------------------------------------------------------------------ what a recomputed block keeps
+MAMBA_BLOCKS, ATTENTION_BLOCKS = KINDS.count("mamba"), KINDS.count("attention")
+ONLY_THE_INPUT = "only-the-input-kept"
+
+
+@contextlib.contextmanager
+def keeping(what):
+    """The model's kept set, or ``policy=None`` in its place (``ONLY_THE_INPUT``)."""
+    with pytest.MonkeyPatch.context() as patch:
+        if what == ONLY_THE_INPUT:
+            patch.setattr(granite_hybrid, "KEPT_BY_A_BLOCK", None)
+        yield
+
+
+@functools.lru_cache(maxsize=None)
+def loss_and_gradients(dtype, what):
+    """The loss and every leaf's gradient, with whole blocks recomputed under the kept set or
+    under ``policy=None``, or with nothing recomputed (``"blocks-kept"``), compiled so that a
+    value is the same bits wherever it is made: no rounding to bfloat16 dropped between two
+    operations that happen to be fused (``xla_allow_excess_precision``), and multipliers that
+    are powers of two (the CPU backend contracts ``12 e + r m`` into one rounding where both
+    are made in one fusion and not where ``12 e`` is read back; the toy's ``r`` is 0.5)."""
+    _, model, params = build(published(embedding_multiplier=8.0), remat=what != "blocks-kept",
+                             compute_dtype=getattr(jnp, dtype))
+    tokens, labels = batch(seed=6, rows=2)
+    with keeping(what):
+        compiled = jax.jit(jax.value_and_grad(lambda p: model.apply(p, tokens, labels))).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+    return jax.device_get(compiled(params))
+
+
+@pytest.mark.parametrize("dtype, other", [("float32", ONLY_THE_INPUT), ("bfloat16", ONLY_THE_INPUT),
+                                          ("float32", "blocks-kept")])
+def test_what_a_block_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, other):
+    """The kept tensors are the values the second forward would have made again, in the dtype
+    the forward made them in: the loss and every leaf's gradient are the same bits as under
+    ``policy=None``, in float32 and in bfloat16, and as with nothing recomputed."""
+    (loss, got), (want_loss, want) = loss_and_gradients(dtype, "the-kept-set"), loss_and_gradients(dtype, other)
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
+        assert np.any(np.asarray(a, np.float32)), jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("what", ["the-kept-set", ONLY_THE_INPUT])
+def test_the_second_forward_runs_no_flash_kernel_and_three_products_fewer_a_period(what):
+    """What the backward makes again, by block: a Mamba-2 block's second forward runs ONE
+    product (the MLP's first) where ``policy=None`` runs three (the mixer's two besides), the
+    attention block's three (``wq``, ``wkv``, the MLP's first) where it runs four (``wo``
+    besides) and no flash forward kernel. The MLP's LAST product is in neither: nothing in a
+    block's backward reads its output (PERF.md, PR 41). A product's backward is two products."""
+    _, model, params = build(remat=True)
+    tokens, labels = batch(seed=5, rows=2)
+    with keeping(what):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(p, tokens, labels)))(params).jaxpr
+    found, kernels = primitives_by_path(jaxpr), kernels_in_the_backward(jaxpr)
+    again = {"the-kept-set": (1, 3, 0), ONLY_THE_INPUT: (3, 4, 1)}[what]       # mamba, attention, flash
+    assert found[("remat2",), "dot_general"] == MAMBA_BLOCKS * (again[0] + 2 * 4) + ATTENTION_BLOCKS * (again[1] + 2 * 5)
+    assert +kernels == +collections.Counter({
+        "ds_flash_fwd": ATTENTION_BLOCKS * again[2], "ds_flash_bwd_dkv": ATTENTION_BLOCKS,
+        "ds_ssd_scan_fwd": MAMBA_BLOCKS, "ds_ssd_scan_bwd": MAMBA_BLOCKS})
+    # the first forward is the same either way: every kernel and every product once
+    assert found[(), "pallas_call"] == MAMBA_BLOCKS + ATTENTION_BLOCKS
+    assert found[(), "dot_general"] >= MAMBA_BLOCKS * 4 + ATTENTION_BLOCKS * 5
+
+
+@functools.lru_cache(maxsize=None)
+def kept_by_the_blocks(what):
+    """``{shape: count}`` of the activations that the blocks of two sequences keep for their backward."""
+    _, model, params = build(remat=True)
+    tokens, _ = batch(seed=5, rows=2)
+    with keeping(what):
+        return residuals_by_shape(lambda p: model._backbone(p, tokens), params)
+
+
+@pytest.mark.parametrize("shape, count", [
+    ((2, 4, 40, 8), ATTENTION_BLOCKS), ((2, 4, 40), ATTENTION_BLOCKS),
+    ((2, 40, 2 * 64 + 2 * 16 + 8), MAMBA_BLOCKS), ((2, 40, 8), MAMBA_BLOCKS),
+    ((2, 40, 32), 2 * (MAMBA_BLOCKS + ATTENTION_BLOCKS) + 1)],
+    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "input-and-mixer_out"])
+def test_a_block_keeps_each_named_tensor_once(shape, count):
+    """The residuals of the blocks by shape: an attention block keeps ONE kernel output (no
+    second ``attn_out`` at the call) and ONE set of row sums, a Mamba-2 block its first
+    product's output once in the compute dtype and the ``dt`` columns once, every block its
+    input and its mixer's output, and nothing else (the last block's output is ``norm_f``'s to
+    keep); under ``policy=None`` the inputs alone."""
+    found = kept_by_the_blocks("the-kept-set")
+    assert found[shape] == count, found
+    assert sum(found.values()) == 4 * MAMBA_BLOCKS + 4 * ATTENTION_BLOCKS + 1, found
+    assert kept_by_the_blocks(ONLY_THE_INPUT) == {(2, 40, 32): MAMBA_BLOCKS + ATTENTION_BLOCKS + 1}
+
+
+def test_the_names_are_nothing_where_no_block_is_recomputed(monkeypatch):
+    """With ``remat=False`` (the reference comparison's path) a name lowers to nothing: the
+    gradient program is the same text with every name of this file taken out (but for the
+    numbers JAX gives its private functions, which count the traces before)."""
+    _, model, params = build(compute_dtype=jnp.bfloat16)
+    tokens, labels = batch(seed=5, rows=2)
+    lowered = lambda: re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(jax.value_and_grad(      # noqa: E731
+        lambda p: model.apply(p, tokens, labels))).lower(params).as_text())
+    named = lowered()
+    monkeypatch.setattr(granite_hybrid, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named and "stablehlo.dot_general" in named
+    assert not hasattr(model.config, "remat_policy")        # the policy is the model's, no key
 
 
 def test_it_trains_in_bfloat16_through_initialize_with_blocks_recomputed():

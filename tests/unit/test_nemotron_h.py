@@ -3,7 +3,13 @@
 expert choices and counts; every leaf's gradient and the rule's update through
 ``deepspeed_tpu.initialize``; the layers' pieces (the grouped norm, the position-free attention
 at head_dim x heads wider than the model); the sixteen held ranges' parts adding up to the
-uncut layer; the scopes the benchmark reads, pinned in the compiled programs."""
+uncut layer; what a recomputed layer keeps by name; the scopes the benchmark reads, pinned in
+the compiled programs."""
+
+import collections
+import contextlib
+import functools
+import re
 
 import numpy as np
 import pytest
@@ -13,9 +19,11 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from benchmarks.reference import nemotron_h_reference as ref
+from deepspeed_tpu.models import nemotron_h
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
 from deepspeed_tpu.parallel.moe import RELU2, DroplessMoE
 from deepspeed_tpu.utils import spans
+from test_ouro import kernels_in_the_backward, primitives_by_path, residuals_by_shape
 
 PATTERN = "MEM*EMEM*E"          # the first seven run: three mixers, three expert layers, an attention
 
@@ -168,6 +176,109 @@ def test_recomputed_layers_give_the_same_loss_and_gradients():
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
+# ------------------------------------------------------------------ what a recomputed layer keeps
+MIXERS, EXPERT_LAYERS, ATTENTIONS = (PATTERN[:7].count(kind) for kind in "ME*")
+ONLY_THE_INPUT = "only-the-input-kept"
+
+
+@contextlib.contextmanager
+def keeping(what):
+    """The model's kept set, or ``policy=None`` in its place (``ONLY_THE_INPUT``)."""
+    with pytest.MonkeyPatch.context() as patch:
+        if what == ONLY_THE_INPUT:
+            patch.setattr(nemotron_h, "KEPT_BY_A_LAYER", None)
+        yield
+
+
+@functools.lru_cache(maxsize=None)
+def loss_and_gradients(dtype, what):
+    """The loss and every leaf's gradient, with whole layers recomputed under the kept set or
+    under ``policy=None``, or with nothing recomputed (``"layers-kept"``), compiled so that a
+    value is the same bits wherever it is made: no rounding to bfloat16 dropped between two
+    operations that happen to be fused (``xla_allow_excess_precision``)."""
+    _, model, params = build(remat=what != "layers-kept", compute_dtype=getattr(jnp, dtype))
+    tokens, labels = batch(seed=6, rows=2)
+    with keeping(what):
+        compiled = jax.jit(jax.value_and_grad(lambda p: model.apply(p, tokens, labels)[0])).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+    return jax.device_get(compiled(params))
+
+
+@pytest.mark.parametrize("dtype, other", [("float32", ONLY_THE_INPUT), ("bfloat16", ONLY_THE_INPUT),
+                                          ("float32", "layers-kept")])
+def test_what_a_layer_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, other):
+    """The kept tensors are the values the second forward would have made again, in the dtype
+    the forward made them in (the shared expert's first product in float32, before its
+    activation): the loss and every leaf's gradient are the same bits as under ``policy=None``,
+    in float32 and in bfloat16, and as with nothing recomputed; the selection bias gets none."""
+    (loss, got), (want_loss, want) = loss_and_gradients(dtype, "the-kept-set"), loss_and_gradients(dtype, other)
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
+        assert np.any(np.asarray(a, np.float32)) != ("router_bias" in jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("what", ["the-kept-set", ONLY_THE_INPUT])
+def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and_an_expert_layer(what):
+    """What the backward makes again, by layer: a mixer's second forward runs NO product where
+    ``policy=None`` runs one (``w_in``), an expert layer's the router's alone where it runs two
+    (the shared expert's first besides), the attention's two (``wq``, ``wkv``) either way and no
+    flash forward kernel. A layer's LAST product (``w_out``, ``wo``, the shared ``w_down``) is in
+    neither: every layer ends ``x + f(norm(x))`` and nothing in its backward reads that output
+    (PERF.md, PR 41). A product's backward is two products; the held experts' are their own."""
+    _, model, params = build(remat=True)
+    tokens, labels = batch(seed=5, rows=2)
+    with keeping(what):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(p, tokens, labels)[0]))(params).jaxpr
+    found, kernels = primitives_by_path(jaxpr), kernels_in_the_backward(jaxpr)
+    again = {"the-kept-set": (0, 1, 0), ONLY_THE_INPUT: (1, 2, 1)}[what]       # mixer, expert layer, flash
+    assert found[("remat2",), "dot_general"] == (MIXERS * (again[0] + 2 * 2) + EXPERT_LAYERS * (again[1] + 2 * 3)
+                                                 + ATTENTIONS * (2 + 2 * 3))
+    assert +kernels == +collections.Counter({
+        "ds_flash_fwd": ATTENTIONS * again[2], "ds_flash_bwd_dkv": ATTENTIONS,
+        "ds_ssd_scan_fwd": MIXERS, "ds_ssd_scan_bwd": MIXERS})
+    # the first forward is the same either way: every kernel once
+    assert found[(), "pallas_call"] == MIXERS + ATTENTIONS
+
+
+@functools.lru_cache(maxsize=None)
+def kept_by_the_layers(what):
+    """``{shape: count}`` of the activations that the layers of two sequences keep for their backward."""
+    _, model, params = build(remat=True)
+    tokens, _ = batch(seed=5, rows=2)
+    with keeping(what):
+        return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params)
+
+
+@pytest.mark.parametrize("shape, count", [
+    ((2, 4, 40, 16), ATTENTIONS), ((2, 4, 40), ATTENTIONS), ((2, 40, 2 * 32 + 2 * 2 * 16 + 4), MIXERS),
+    ((2, 40, 4), MIXERS), ((2, 40, 40), EXPERT_LAYERS), ((2, 40, 32), MIXERS + EXPERT_LAYERS + ATTENTIONS + 1)],
+    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input"])
+def test_a_layer_keeps_each_named_tensor_once(shape, count):
+    """The residuals of the layers by shape: the attention keeps ONE kernel output (no second
+    ``attn_out`` at the call) and ONE set of row sums, a mixer its first product's output once
+    in the compute dtype and the ``dt`` columns once, an expert layer the shared expert's first
+    product's output once, every layer its input, and nothing else (the last layer's output is
+    ``norm_f``'s to keep); under ``policy=None`` the inputs alone."""
+    found = kept_by_the_layers("the-kept-set")
+    assert found[shape] == count, found
+    assert sum(found.values()) == 3 * MIXERS + 2 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, found
+    assert kept_by_the_layers(ONLY_THE_INPUT) == {(2, 40, 32): MIXERS + EXPERT_LAYERS + ATTENTIONS + 1}
+
+
+def test_the_names_are_nothing_where_no_layer_is_recomputed(monkeypatch):
+    """With ``remat=False`` (the reference comparison's path) a name lowers to nothing: the
+    gradient program is the same text with every name of this file taken out (but for the
+    numbers JAX gives its private functions, which count the traces before)."""
+    _, model, params = build(compute_dtype=jnp.bfloat16)
+    tokens, labels = batch(seed=5, rows=2)
+    lowered = lambda: re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(jax.value_and_grad(      # noqa: E731
+        lambda p: model.apply(p, tokens, labels)[0])).lower(params).as_text())
+    named = lowered()
+    monkeypatch.setattr(nemotron_h, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named and "stablehlo.dot_general" in named
+
+
 def test_from_published_parses_the_pattern_and_refuses_what_the_model_cannot_do():
     c = NemotronHConfig.from_published(published())
     assert c.kinds == "MEM*EME" and c.hybrid_override_pattern == PATTERN
@@ -260,7 +371,6 @@ def test_the_sixteen_held_ranges_add_up_to_the_uncut_layer(highest):
 def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
     _, model, params = build(remat=True)
     tokens, labels = batch(seed=6, rows=2)
-    import re
     text = jax.jit(jax.grad(lambda p, t, l: model.apply(p, t, l)[0])).lower(
         params, tokens, labels).compile().as_text()
     # a mixer is its layer's attention part, an expert layer its MLP part (the compiled

@@ -7,6 +7,8 @@ position, the scopes the benchmark's readers find a pass by, and what a recomput
 keeps beside its input."""
 
 import collections
+import contextlib
+import io
 import re
 
 import jax
@@ -215,6 +217,26 @@ def primitives_by_path(jaxpr, path=(), found=None):
     return found
 
 
+def kernels_in_the_backward(jaxpr):
+    """``{kernel's name: count}`` over the Pallas calls inside a gradient's top-level
+    ``remat2`` equations: the recomputed layers' second forward and their backward proper."""
+    return collections.Counter(
+        eqn.params["name"] for outer in jaxpr.eqns if outer.primitive.name == "remat2"
+        for eqn in outer.params["jaxpr"].eqns if eqn.primitive.name == "pallas_call")
+
+
+def residuals_by_shape(function, params, *rest):
+    """``{shape: count}`` of the activations of two sequences that ``function(params, *rest)``
+    keeps for its backward: no leaf, no constant, not what ``norm_f`` keeps of its own."""
+    printed = io.StringIO()
+    with contextlib.redirect_stdout(printed):
+        jax.ad_checkpoint.print_saved_residuals(lambda params, *rest: function(params, *rest), params, *rest)
+    shapes = (re.match(r"\w+\[([\d,]+)\] (?!from the argument params|from a constant)", line)
+              for line in printed.getvalue().splitlines() if "(rms_norm)" not in line)
+    return collections.Counter(s for s in (tuple(map(int, m.group(1).split(","))) for m in shapes if m)
+                               if s[0] == 2 and len(s) > 2 and s[-1] > 1)
+
+
 def test_the_second_forward_runs_no_flash_kernel_and_one_product_fewer_a_layer(monkeypatch):
     """What the backward makes again of a block: six products where policy None makes
     seven (``w_down``'s is kept), and no flash forward kernel; the two scopes the benchmark's
@@ -244,17 +266,13 @@ def test_the_second_forward_runs_no_flash_kernel_and_one_product_fewer_a_layer(m
 
 @pytest.mark.parametrize("shape, a_layer, more", [((2, 4, 40, 8), 1, 0), ((2, 4, 40), 1, 0), ((2, 40, 32), 2, 1)],
                          ids=["attn_out", "attn_lse", "input-and-mlp_out"])
-def test_a_block_pass_keeps_each_named_tensor_once(shape, a_layer, more, monkeypatch, capsys):
+def test_a_block_pass_keeps_each_named_tensor_once(shape, a_layer, more, monkeypatch):
     """The residuals of one pass through two recomputed blocks, by shape: a layer keeps ONE
     kernel output, ONE set of row sums, its input and ``w_down``'s output, none of them
     twice; the last block's output is ``norm_f``'s to keep (``more``)."""
     _, model, params = build(remat=True)
 
-    def kept():       # the activations a row of the batch: no leaf, no constant, not what norm_f keeps of its own
-        jax.ad_checkpoint.print_saved_residuals(model.one_pass, params, jnp.ones((2, 40, 32)))
-        shapes = (re.match(r"\w+\[([\d,]+)\] (?!from the argument params|from a constant)", line)
-                  for line in capsys.readouterr().out.splitlines() if "(rms_norm)" not in line)
-        return collections.Counter(s for s in (tuple(map(int, m.group(1).split(","))) for m in shapes if m) if s[0] == 2)
+    kept = lambda: residuals_by_shape(model.one_pass, params, jnp.ones((2, 40, 32)))      # noqa: E731
     layers_ = len(params["layers"])
     found = kept()
     assert found[shape] == a_layer * layers_ + more and sum(found.values()) == 4 * layers_ + 1, found
