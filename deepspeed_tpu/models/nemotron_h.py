@@ -127,12 +127,15 @@ class NemotronHConfig:
 
 # What a recomputed layer keeps beside its input, by name: the flash kernel's output and row
 # sums (named in its forward rule), the Mamba-2 mixers' first product's output as the forward
-# leaves it (the compute dtype's copy and the float32 ``dt`` columns), and the shared expert's
-# first product's output before its activation. Every layer ends ``x + f(norm(x))``: nothing in
-# a layer's backward reads its LAST product's output, so the second forward never ran one.
-# Bytes and what each buys on a v5e: docs/nemotron-h.md, PERF.md (PR 41).
+# leaves it (the compute dtype's copy and the float32 ``dt`` columns), the shared expert's
+# first product's output before its activation, and the held experts' first grouped product's
+# output where they stand in for the absent ones (named in ``parallel/moe.py``, whose own
+# checkpoint keeps it for the rows' backward: kept here, the second forward gathers no row for
+# it and runs no ``w_up``). Every layer ends ``x + f(norm(x))``: nothing in a layer's backward
+# reads its LAST product's output, so the second forward never ran one.
+# Bytes and what each buys on a v5e: docs/nemotron-h.md, PERF.md (PR 41, PR 42).
 KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
-    "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up")
+    "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "ds_moe_gate_up")
 
 
 def _dot(x, w):

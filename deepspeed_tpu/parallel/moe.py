@@ -657,12 +657,19 @@ class DroplessMoE:
     ``stand_in=True`` (held-range form only) lets the held experts stand in for the absent
     ones: expert ``e``'s rows go through the weights of held expert ``first + (e - first) %
     count``, as if this chip were each of the ``num_experts / count`` chips in turn. All
-    ``n * k`` assignments are then computed here, ``k`` full passes a layer whatever the
-    router does: the rows a deployment's exchange brings a chip at an even router, and the
-    same work on every step, where the plain held range's rows follow the router's lean
-    (a router that sends every token to one expert gives this chip ``n`` rows more or none,
-    as that expert is held or not). The router, its choice, its weights and ``counts``
-    stay those of all ``num_experts``; ``rows_here`` is ``n * k``.
+    ``n * k`` assignments are then computed here whatever the router does: the rows a
+    deployment's exchange brings a chip at an even router, and the same work on every step,
+    where the plain held range's rows follow the router's lean (a router that sends every
+    token to one expert gives this chip ``n`` rows more or none, as that expert is held or
+    not). The router, its choice, its weights and ``counts`` stay those of all
+    ``num_experts``; ``rows_here`` is ``n * k``. Since the rows are ``n * k`` before anything
+    is traced, the layer does what a chip does after its exchange and what the whole range
+    does above: ONE sort by stand-in expert, one gather of the rows, one grouped matmul a
+    product over the ``count`` groups, one gather back, the first product's output and each
+    token's ``k`` expert outputs kept for the backward (``ds_moe_gate_up``, ``ds_moe_out``:
+    names a recomputed layer around this one may keep too) and cotangents that are gathers.
+    No passes, no branch, no scatter; the buffers are ``n * k`` rows, which the passes avoid
+    for a held range that may see a sixteenth of them.
 
     ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
     mean, over all chips and all ``num_experts``); in the held-range form also
@@ -686,6 +693,9 @@ class DroplessMoE:
         self.held = None if count == num_experts else (first, count)
         assert not stand_in or (self.held is not None and num_experts % count == 0), (held, stand_in)
         self.stand_in = stand_in
+        # every assignment is computed here, ``n * k`` rows known before tracing: the layer
+        # sorts once and multiplies once; else the held rows' count follows the router
+        self.every_row_here = self.held is None or stand_in
 
     # ------------------------------------------------------------------ params
     def init(self, rng, scale=0.02):
@@ -772,6 +782,7 @@ class DroplessMoE:
         shape = x.shape
         x2 = x.reshape(-1, H)
         n = x2.shape[0]
+        first, count = self.held or (0, E)
 
         with jax.named_scope("ds_moe_router"):
             logits = jnp.dot(x2.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -783,15 +794,16 @@ class DroplessMoE:
             sent_to = experts.reshape(-1).astype(jnp.int32)
             if self.stand_in:
                 # every row to the held expert that stands in for its own: sorted by that one
-                first, count = self.held
                 every = jnp.sum(sent_to[:, None] == jnp.arange(E, dtype=jnp.int32), axis=0)
                 sent_to = first + (sent_to - first) % count
             by_expert, order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)
             starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
             group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
-            if self.held is None:
+            if self.every_row_here:
                 inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
             tok = order // k
+        # the groups the whole range's products run over: standing in, the held ones hold every row
+        sizes = group_sizes[first:first + count] if self.stand_in else group_sizes
         def routed(x2, weights, w_gate_up, w_down):
             dt = x2.dtype
             w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
@@ -806,23 +818,23 @@ class DroplessMoE:
                 xs = _take_rows(x2, tok, inverse)                         # [n * k, H]
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
-                    experts_matmul(xs, gate_up_pieces, firsts, group_sizes), "ds_moe_gate_up")
-                ys = experts_matmul(_activate(form, gate_up, dt), down_pieces, firsts, group_sizes)
+                    experts_matmul(xs, gate_up_pieces, firsts, sizes), "ds_moe_gate_up")
+                ys = experts_matmul(_activate(form, gate_up, dt), down_pieces, firsts, sizes)
             with jax.named_scope("ds_moe_combine"):
                 return _combine_rows(ys, weights, inverse, order)          # [n, H]
 
+        if self.held is not None:
+            # the held range's rows are one run of the sorted order: its start and its length
+            lo, rows_here = starts[first], starts[first + count] - starts[first]
         # The backward keeps the first product's output and each token's k expert outputs.
         # It makes the gathered rows and the gated activation again (a gather and an
         # elementwise pass) and fetches the experts' weights again: kept, the four
         # layers' gathered weights would be 3.2 GB a chip.
-        if self.held is None:
+        if self.every_row_here:
             y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
                 "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)
         else:
-            # the held range's rows are one run of the sorted order: its start, its length,
-            # and the held rows up to each held expert
-            first, count = self.held
-            lo, rows_here = starts[first], starts[first + count] - starts[first]
+            # as many rows as the router sends: in passes, with the held rows up to each held expert
             sort = (tok, order, lo, rows_here, jnp.cumsum(group_sizes[first:first + count]))
             y = _held_rows((k, form), x2, weights, w_gate_up.astype(x2.dtype),
                            w_down.astype(x2.dtype), sort)

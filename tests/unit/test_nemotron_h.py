@@ -179,24 +179,35 @@ def test_recomputed_layers_give_the_same_loss_and_gradients():
 # ------------------------------------------------------------------ what a recomputed layer keeps
 MIXERS, EXPERT_LAYERS, ATTENTIONS = (PATTERN[:7].count(kind) for kind in "ME*")
 ONLY_THE_INPUT = "only-the-input-kept"
+ROWS_MADE_AGAIN = "the-experts-rows-made-again"      # the kept set less ``parallel/moe.py``'s name (PR 41's)
+ALL_THE_ROWS_KEPT = "each-tokens-expert-outputs-kept-too"  # and with the other (``ds_moe_out``): PERF.md, PR 42
+STANDING_IN = {False: "absent-left-out", True: "held-stand-in"}
 
 
 @contextlib.contextmanager
 def keeping(what):
-    """The model's kept set, or ``policy=None`` in its place (``ONLY_THE_INPUT``)."""
+    """The model's kept set, ``policy=None`` in its place (``ONLY_THE_INPUT``), or the set without
+    the name of an expert layer's row path (``ROWS_MADE_AGAIN``) or with both (``ALL_THE_ROWS_KEPT``)."""
     with pytest.MonkeyPatch.context() as patch:
         if what == ONLY_THE_INPUT:
             patch.setattr(nemotron_h, "KEPT_BY_A_LAYER", None)
+        elif what in (ROWS_MADE_AGAIN, ALL_THE_ROWS_KEPT):
+            rows = ("ds_moe_gate_up", "ds_moe_out") if what == ALL_THE_ROWS_KEPT else ()
+            patch.setattr(nemotron_h, "KEPT_BY_A_LAYER", jax.checkpoint_policies.save_only_these_names(
+                "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", *rows))
         yield
 
 
 @functools.lru_cache(maxsize=None)
-def loss_and_gradients(dtype, what):
+def loss_and_gradients(dtype, what, stand_in=False):
     """The loss and every leaf's gradient, with whole layers recomputed under the kept set or
     under ``policy=None``, or with nothing recomputed (``"layers-kept"``), compiled so that a
     value is the same bits wherever it is made: no rounding to bfloat16 dropped between two
-    operations that happen to be fused (``xla_allow_excess_precision``)."""
-    _, model, params = build(remat=what != "layers-kept", compute_dtype=getattr(jnp, dtype))
+    operations that happen to be fused (``xla_allow_excess_precision``). ``stand_in``: every
+    assignment computed by the held experts, as in the cell, where an expert layer's rows go
+    through the whole range's form and the kept set names two of its tensors."""
+    _, model, params = build(published(stand_in=stand_in), remat=what != "layers-kept",
+                             compute_dtype=getattr(jnp, dtype))
     tokens, labels = batch(seed=6, rows=2)
     with keeping(what):
         compiled = jax.jit(jax.value_and_grad(lambda p: model.apply(p, tokens, labels)[0])).lower(params).compile(
@@ -204,66 +215,116 @@ def loss_and_gradients(dtype, what):
     return jax.device_get(compiled(params))
 
 
+@pytest.mark.parametrize("stand_in", [False, True], ids=STANDING_IN.values())
 @pytest.mark.parametrize("dtype, other", [("float32", ONLY_THE_INPUT), ("bfloat16", ONLY_THE_INPUT),
                                           ("float32", "layers-kept")])
-def test_what_a_layer_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, other):
+def test_what_a_layer_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, other, stand_in):
     """The kept tensors are the values the second forward would have made again, in the dtype
     the forward made them in (the shared expert's first product in float32, before its
-    activation): the loss and every leaf's gradient are the same bits as under ``policy=None``,
-    in float32 and in bfloat16, and as with nothing recomputed; the selection bias gets none."""
-    (loss, got), (want_loss, want) = loss_and_gradients(dtype, "the-kept-set"), loss_and_gradients(dtype, other)
+    activation; where the held experts stand in, the first grouped product's output and each
+    token's expert outputs in the compute dtype): the loss and every leaf's gradient are the
+    same bits as under ``policy=None``, in float32 and in bfloat16, and as with nothing
+    recomputed; the selection bias gets none."""
+    (loss, got), (want_loss, want) = (loss_and_gradients(dtype, "the-kept-set", stand_in),
+                                      loss_and_gradients(dtype, other, stand_in))
     assert float(loss) == float(want_loss) and np.isfinite(float(loss))
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
         assert np.any(np.asarray(a, np.float32)) != ("router_bias" in jax.tree_util.keystr(path))
 
 
-@pytest.mark.parametrize("what", ["the-kept-set", ONLY_THE_INPUT])
-def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and_an_expert_layer(what):
+def rows_in_the_backward(jaxpr):
+    """``({primitive: count} of the second forward, {primitive: count} of the layers' own backward)``
+    over the grouped products and the gathers of the recomputed expert layers: what the gradient's
+    top-level ``remat2`` equations hold directly, and what the ``jax.checkpoint`` of the whole
+    range's form holds inside them (its remake of the gathered rows, and the cotangents)."""
+    second, own = collections.Counter(), collections.Counter()
+    for outer in jaxpr.eqns:
+        if outer.primitive.name == "remat2" and outer.params["differentiated"]:
+            for (path, name), n in primitives_by_path(outer.params["jaxpr"]).items():
+                if name in ("ragged_dot_general", "gather") and "jit" not in path:
+                    (own if "remat2" in path else second)[name] += n
+    return second, own
+
+
+@pytest.mark.parametrize("what, stand_in", [
+    ("the-kept-set", False), (ONLY_THE_INPUT, False),
+    ("the-kept-set", True), (ROWS_MADE_AGAIN, True), (ALL_THE_ROWS_KEPT, True), (ONLY_THE_INPUT, True)],
+    ids=lambda v: STANDING_IN.get(v, v))
+def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and_an_expert_layer(what, stand_in):
     """What the backward makes again, by layer: a mixer's second forward runs NO product where
     ``policy=None`` runs one (``w_in``), an expert layer's the router's alone where it runs two
     (the shared expert's first besides), the attention's two (``wq``, ``wkv``) either way and no
     flash forward kernel. A layer's LAST product (``w_out``, ``wo``, the shared ``w_down``) is in
     neither: every layer ends ``x + f(norm(x))`` and nothing in its backward reads that output
-    (PERF.md, PR 41). A product's backward is two products; the held experts' are their own."""
-    _, model, params = build(remat=True)
+    (PERF.md, PR 41). A product's backward is two products; the held experts' are their own.
+    Where the held experts stand in (the cell), an expert layer's rows go through the whole
+    range's form under its own ``jax.checkpoint``, which keeps two tensors: the layer's second
+    forward makes both again (a gather, two grouped products, a gather) unless the LAYER keeps
+    them by name too. The kept set holds the first product's output (PERF.md, PR 42): the second
+    forward gathers no row for it and runs ``w_down``'s product and the gather back alone; with
+    each token's expert outputs kept too it would run none of the row path. The form's own
+    backward is the remake of the gathered rows, two gathers of cotangents and each product's
+    two cotangents, whatever the layer keeps."""
+    _, model, params = build(published(stand_in=stand_in), remat=True)
     tokens, labels = batch(seed=5, rows=2)
     with keeping(what):
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(p, tokens, labels)[0]))(params).jaxpr
     found, kernels = primitives_by_path(jaxpr), kernels_in_the_backward(jaxpr)
-    again = {"the-kept-set": (0, 1, 0), ONLY_THE_INPUT: (1, 2, 1)}[what]       # mixer, expert layer, flash
-    assert found[("remat2",), "dot_general"] == (MIXERS * (again[0] + 2 * 2) + EXPERT_LAYERS * (again[1] + 2 * 3)
-                                                 + ATTENTIONS * (2 + 2 * 3))
+    # mixer, expert layer, flash; grouped products, row gathers of an expert layer's second forward
+    again = {"the-kept-set": (0, 1, 0, 1, 1), ROWS_MADE_AGAIN: (0, 1, 0, 2, 2), ALL_THE_ROWS_KEPT: (0, 1, 0, 0, 0),
+             ONLY_THE_INPUT: (1, 2, 1, 2, 2)}[what]
+    # standing in, the FIRST forward's combine is a product inside the form's own checkpoint
+    assert found[("remat2",), "dot_general"] == (MIXERS * (again[0] + 2 * 2) + ATTENTIONS * (2 + 2 * 3)
+                                                 + EXPERT_LAYERS * (again[1] + 2 * 3 + stand_in))
     assert +kernels == +collections.Counter({
         "ds_flash_fwd": ATTENTIONS * again[2], "ds_flash_bwd_dkv": ATTENTIONS,
         "ds_ssd_scan_fwd": MIXERS, "ds_ssd_scan_bwd": MIXERS})
     # the first forward is the same either way: every kernel once
     assert found[(), "pallas_call"] == MIXERS + ATTENTIONS
+    if stand_in:
+        second, own = rows_in_the_backward(jaxpr)
+        assert +second == +collections.Counter({"ragged_dot_general": EXPERT_LAYERS * again[3],
+                                                "gather": EXPERT_LAYERS * again[4]})
+        assert own == {"ragged_dot_general": EXPERT_LAYERS * 4, "gather": EXPERT_LAYERS * 3}
+        assert not any(name in ("scan", "cond", "while") for path, name in found
+                       if "remat2" in path and "jit" not in path and "pallas_call" not in path)
 
 
 @functools.lru_cache(maxsize=None)
-def kept_by_the_layers(what):
-    """``{shape: count}`` of the activations that the layers of two sequences keep for their backward."""
-    _, model, params = build(remat=True)
+def kept_by_the_layers(what, stand_in=False):
+    """``{shape: count}`` of the activations that the layers of two sequences keep for their
+    backward, an expert layer's flat rows among them (80 tokens, 240 assignments)."""
+    _, model, params = build(published(stand_in=stand_in), remat=True)
     tokens, _ = batch(seed=5, rows=2)
     with keeping(what):
-        return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params)
+        return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params, rows=(80, 240))
 
 
 @pytest.mark.parametrize("shape, count", [
     ((2, 4, 40, 16), ATTENTIONS), ((2, 4, 40), ATTENTIONS), ((2, 40, 2 * 32 + 2 * 2 * 16 + 4), MIXERS),
-    ((2, 40, 4), MIXERS), ((2, 40, 40), EXPERT_LAYERS), ((2, 40, 32), MIXERS + EXPERT_LAYERS + ATTENTIONS + 1)],
-    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input"])
+    ((2, 40, 4), MIXERS), ((2, 40, 40), EXPERT_LAYERS), ((2, 40, 32), MIXERS + EXPERT_LAYERS + ATTENTIONS + 1),
+    ((240, 24), EXPERT_LAYERS), ((80, 3, 32), 0)],
+    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input", "ds_moe_gate_up", "ds_moe_out"])
 def test_a_layer_keeps_each_named_tensor_once(shape, count):
     """The residuals of the layers by shape: the attention keeps ONE kernel output (no second
     ``attn_out`` at the call) and ONE set of row sums, a mixer its first product's output once
     in the compute dtype and the ``dt`` columns once, an expert layer the shared expert's first
     product's output once, every layer its input, and nothing else (the last layer's output is
-    ``norm_f``'s to keep); under ``policy=None`` the inputs alone."""
-    found = kept_by_the_layers("the-kept-set")
+    ``norm_f``'s to keep); under ``policy=None`` the inputs alone. Where the held experts stand
+    in, an expert layer keeps the first grouped product's output ``[n k, F]`` besides, once, and
+    not each token's ``k`` expert outputs ``[n, k, H]`` (a set that names them too keeps them once);
+    where they do not, the passes name nothing and keep nothing."""
+    rows = shape in ((240, 24), (80, 3, 32))
+    found = kept_by_the_layers("the-kept-set", True)
     assert found[shape] == count, found
-    assert sum(found.values()) == 3 * MIXERS + 2 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, found
-    assert kept_by_the_layers(ONLY_THE_INPUT) == {(2, 40, 32): MIXERS + EXPERT_LAYERS + ATTENTIONS + 1}
+    assert sum(found.values()) == 3 * MIXERS + 3 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, found
+    assert kept_by_the_layers(ALL_THE_ROWS_KEPT, True) == found + collections.Counter({(80, 3, 32): EXPERT_LAYERS})
+    left_out = kept_by_the_layers("the-kept-set")
+    assert left_out[shape] == (0 if rows else count), left_out
+    assert sum(left_out.values()) == 3 * MIXERS + 2 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, left_out
+    assert kept_by_the_layers(ROWS_MADE_AGAIN, True) == left_out
+    assert kept_by_the_layers(ONLY_THE_INPUT, stand_in=rows) == {(2, 40, 32): MIXERS + EXPERT_LAYERS + ATTENTIONS + 1}
 
 
 def test_the_names_are_nothing_where_no_layer_is_recomputed(monkeypatch):
@@ -384,6 +445,21 @@ def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
     # the held experts' passes are made again by their own backward, never by the layer's
     # second forward: nothing needs that forward's result
     assert not re.search(r"rematted_computation/ds_mlp/\S*ds_moe_experts", text)
+    # standing in (the cell), the rows go through the whole range's form under its own checkpoint:
+    # the same scopes; its backward gathers the rows again and makes the activation again under
+    # ITS ``rematted_computation``, which ``recompute_time_share`` reads; the layer's second
+    # forward runs the router, the sort, ``w_down``'s product and the gather back, and gathers no
+    # row for ``w_up`` (the layer keeps that product's output by name)
+    _, stands_in, its_params = build(published(stand_in=True), remat=True)
+    text = jax.jit(jax.grad(lambda p, t, l: stands_in.apply(p, t, l)[0])).lower(
+        its_params, tokens, labels).compile().as_text()
+    for path in (r"ds_mlp/ds_moe_router", r"ds_mlp/ds_moe_dispatch", r"ds_mlp/checkpoint/ds_moe_dispatch",
+                 r"ds_mlp/checkpoint/ds_moe_experts", r"ds_mlp/checkpoint/ds_moe_combine",
+                 r"ds_mlp/checkpoint/rematted_computation/ds_moe_dispatch",
+                 r"ds_mlp/checkpoint/rematted_computation/ds_moe_experts",
+                 r"rematted_computation/ds_mlp/ds_moe_router", r"rematted_computation/ds_mlp/ds_moe_dispatch",
+                 r"rematted_computation/ds_mlp/ds_moe_experts", r"rematted_computation/ds_mlp/ds_moe_combine"):
+        assert re.search(path, text), path
     # the rule runs inside the update program, under the optimizer's scope and its own
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
         "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},

@@ -16,6 +16,7 @@ from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
 from deepspeed_tpu.parallel.mesh import build_mesh
 from deepspeed_tpu.parallel.moe import DroplessMoE, experts_matmul, gather_pieces, piece_firsts
 from deepspeed_tpu.utils import spans
+from test_ouro import equations_by_path
 
 AUX = 0.01
 CASES = [(k, d) for k in (2, 8) for d in (1, 2, 4)]
@@ -335,19 +336,19 @@ def test_initialize_takes_the_model_as_it_takes_gpt2():
     assert np.isfinite(float(loss))
 
 
-@pytest.mark.parametrize("stand_in", [False, True], ids=["absent-left-out", "held-stand-in"])
-def test_a_held_range_leaves_the_absent_experts_out_or_stands_in_for_them(stand_in):
-    """Experts 4..7 of 16 held, a sigmoid router over all 16 with three a token, against a plain
-    loop. Left out: only the rows sent to 4..7 add their part, and ``rows_here`` counts them.
-    Standing in: expert ``e``'s rows go through held expert ``4 + (e - 4) % 4``, every
-    assignment is computed (``rows_here = n k`` whatever the router does), and choice, weights
-    and ``counts`` stay those of all 16."""
-    E, H, F, k, first, count = 16, 64, 32, 3, 4, 4
+HELD = dict(E=16, H=64, F=32, k=3, first=4, count=4)
+
+
+def held_range_against_a_plain_loop(stand_in, router_bias):
+    """Experts 4..7 of 16 held, a sigmoid router over all 16 with three a token, the layer under
+    ``jit`` against a plain loop over the experts: ``(the chosen experts [n, k], stats)`` once
+    the result and every gradient have been compared at the test's limits."""
+    E, H, F, k, first, count = (HELD[name] for name in ("E", "H", "F", "k", "first", "count"))
     layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, count),
                         router=("sigmoid_bias", 2.5), experts="relu2", stand_in=stand_in)
     params = layer.init(jax.random.PRNGKey(0), 0.2)
     assert params["w_up"].shape == (count, H, F) and params["router_w"].shape == (H, E)
-    params["router_bias"] = 0.05 * jnp.arange(E, dtype=jnp.float32)
+    params["router_bias"] = jnp.asarray(router_bias, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, H))
     cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
 
@@ -380,7 +381,77 @@ def test_a_held_range_leaves_the_absent_experts_out_or_stands_in_for_them(stand_
     assert not np.any(grads[0]["router_bias"])
     chosen = np.asarray(chosen)
     assert np.array_equal(stats["counts"], np.bincount(chosen.reshape(-1), minlength=E))
+    return chosen, stats
+
+
+@pytest.mark.parametrize("stand_in", [False, True], ids=["absent-left-out", "held-stand-in"])
+def test_a_held_range_leaves_the_absent_experts_out_or_stands_in_for_them(stand_in):
+    """Experts 4..7 of 16 held, a sigmoid router over all 16 with three a token, against a plain
+    loop. Left out: only the rows sent to 4..7 add their part, and ``rows_here`` counts them.
+    Standing in: expert ``e``'s rows go through held expert ``4 + (e - 4) % 4``, every
+    assignment is computed (``rows_here = n k`` whatever the router does), and choice, weights
+    and ``counts`` stay those of all 16."""
+    E, H, F, k, first, count = (HELD[name] for name in ("E", "H", "F", "k", "first", "count"))
+    chosen, stats = held_range_against_a_plain_loop(stand_in, 0.05 * np.arange(E))
     here = chosen.size if stand_in else np.sum((chosen >= first) & (chosen < first + count))
     assert float(stats["rows_here"]) == here and (stand_in or here < chosen.size)
     with pytest.raises(AssertionError):      # nothing to stand in for where every expert is held
         DroplessMoE(H, F, E, k, router=("sigmoid_bias", 2.5), experts="relu2", stand_in=True)
+
+
+@pytest.mark.parametrize("stand_in", [False, True], ids=["absent-left-out", "held-stand-in"])
+def test_a_router_that_sends_every_token_to_one_held_expert_changes_no_shape(stand_in):
+    """A selection bias that gives every token the experts 5, 9 and 13. Standing in, all three
+    are held expert 5's rows: ONE group of ``n k`` rows and three empty ones, the same static
+    buffers as at an even router, the plain loop's result and gradients. Left out, expert 5's
+    ``n`` rows alone are here, one full pass."""
+    E, first, count = HELD["E"], HELD["first"], HELD["count"]
+    lean = np.where(np.isin(np.arange(E), (5, 9, 13)), 10.0, 0.0)
+    chosen, stats = held_range_against_a_plain_loop(stand_in, lean)
+    assert np.array_equal(np.sort(chosen, axis=-1), np.broadcast_to([5, 9, 13], chosen.shape))
+    assert set(first + (chosen.reshape(-1) - first) % count) == {5}
+    assert float(stats["rows_here"]) == (chosen.size if stand_in else chosen.shape[0])
+    assert float(stats["load_max_over_mean"]) == pytest.approx(E / 3)
+
+
+def shapes_of(jaxpr, primitive):
+    """``[(enclosing primitives, the first output's shape)]`` of every ``primitive`` of a jaxpr."""
+    return [(path, eqn.outvars[0].aval.shape) for path, eqn in equations_by_path(jaxpr)
+            if eqn.primitive.name == primitive]
+
+
+@pytest.mark.parametrize("stand_in", [False, True], ids=["absent-left-out", "held-stand-in"])
+def test_where_every_row_is_computed_here_the_layer_sorts_once_and_multiplies_once(stand_in):
+    """The gradient's jaxpr of a held range. Standing in, the rows are ``n k`` whatever the
+    router does, and the layer is the whole range's: no loop, no branch and no scatter (the one
+    ``scan`` is ``searchsorted``'s, the one ``scatter-add`` the cotangent of the chosen scores,
+    both the router's), each product once forward, and in the backward, under the layer's own
+    ``checkpoint``, each product's two cotangents once, ``[n k, .]`` for the rows and
+    ``[count, ., .]`` for the weights. A plain held range's rows follow the router: it stays in
+    passes of ``n`` rows, a loop and a branch forward and backward, and the backward makes each
+    pass again before its cotangents."""
+    E, H, F, k, first, count = (HELD[name] for name in ("E", "H", "F", "k", "first", "count"))
+    layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, count),
+                        router=("sigmoid_bias", 2.5), experts="relu2", stand_in=stand_in)
+    params = layer.init(jax.random.PRNGKey(0), 0.2)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, H))
+    n = 2 * 48
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(layer.apply(p, x)[0]), argnums=(0, 1)))(params, x).jaxpr
+    count_of = lambda name: len(shapes_of(jaxpr, name))      # noqa: E731
+    products = shapes_of(jaxpr, "ragged_dot_general")
+    if stand_in:
+        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (1, 0, 0, 1)
+        assert sorted(products) == sorted([
+            (("custom_vjp_call",), (n * k, F)), (("custom_vjp_call",), (n * k, H)),
+            (("remat2",), (n * k, F)), (("remat2",), (n * k, H)),
+            (("remat2",), (count, H, F)), (("remat2",), (count, F, H))])
+        assert count_of("remat2") == 1 and count_of("sort") == 2
+    else:
+        # searchsorted's, the forward's passes, the backward's; a branch a loop
+        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (3, 0, 2, 5)
+        in_passes = [(path, shape) for path, shape in products if path[:2] in (("scan", "cond"), ("custom_vjp_call", "scan"))]
+        assert len(products) == len(in_passes) == 8
+        # a pass of n rows: two products forward, the two made again, and four cotangents
+        assert sorted(shape for _, shape in products) == sorted(
+            [(n, F), (n, H)] * 2 + [(n, F), (n, H), (count, H, F), (count, F, H)])
+        assert count_of("remat2") == 0 and count_of("sort") == 1

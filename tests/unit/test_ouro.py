@@ -204,17 +204,20 @@ def test_what_a_block_pass_keeps_changes_no_bit_of_a_gradient(other, monkeypatch
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
 
 
-def primitives_by_path(jaxpr, path=(), found=None):
-    """``{(enclosing primitives, primitive): count}`` over a jaxpr and what its equations hold."""
-    found = collections.Counter() if found is None else found
+def equations_by_path(jaxpr, path=()):
+    """``(enclosing primitives, equation)`` over a jaxpr and what its equations hold."""
     for eqn in jaxpr.eqns:
-        found[path, eqn.primitive.name] += 1
+        yield path, eqn
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    primitives_by_path(inner, path + (eqn.primitive.name,), found)
-    return found
+                    yield from equations_by_path(inner, path + (eqn.primitive.name,))
+
+
+def primitives_by_path(jaxpr):
+    """``{(enclosing primitives, primitive): count}`` over a jaxpr and what its equations hold."""
+    return collections.Counter((path, eqn.primitive.name) for path, eqn in equations_by_path(jaxpr))
 
 
 def kernels_in_the_backward(jaxpr):
@@ -225,16 +228,17 @@ def kernels_in_the_backward(jaxpr):
         for eqn in outer.params["jaxpr"].eqns if eqn.primitive.name == "pallas_call")
 
 
-def residuals_by_shape(function, params, *rest):
+def residuals_by_shape(function, params, *rest, rows=()):
     """``{shape: count}`` of the activations of two sequences that ``function(params, *rest)``
-    keeps for its backward: no leaf, no constant, not what ``norm_f`` keeps of its own."""
+    keeps for its backward: no leaf, no constant, not what ``norm_f`` keeps of its own. ``rows``:
+    leading sizes that count beside the two sequences' (an expert layer's flat rows)."""
     printed = io.StringIO()
     with contextlib.redirect_stdout(printed):
         jax.ad_checkpoint.print_saved_residuals(lambda params, *rest: function(params, *rest), params, *rest)
     shapes = (re.match(r"\w+\[([\d,]+)\] (?!from the argument params|from a constant)", line)
               for line in printed.getvalue().splitlines() if "(rms_norm)" not in line)
     return collections.Counter(s for s in (tuple(map(int, m.group(1).split(","))) for m in shapes if m)
-                               if s[0] == 2 and len(s) > 2 and s[-1] > 1)
+                               if (s[0] == 2 and len(s) > 2 or s[0] in rows and len(s) > 1) and s[-1] > 1)
 
 
 def test_the_second_forward_runs_no_flash_kernel_and_one_product_fewer_a_layer(monkeypatch):
