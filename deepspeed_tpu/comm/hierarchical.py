@@ -50,10 +50,9 @@ __all__ = [
 ]
 
 # named_scope prefix stamped on every bucketed exchange: it survives into the
-# optimized HLO as instruction metadata (op_name), which is how the anatomy
-# pass recognizes an eagerly-issued bucket collective and prices its real
-# issue-to-use window instead of treating the sync instruction as fully
-# exposed (utils/anatomy.py, docs/overlap.md)
+# optimized HLO as instruction metadata (op_name), which is how a reader of
+# the program or of a trace tells an eagerly-issued bucket collective from
+# the monolithic exchange (docs/overlap.md)
 GRAD_BUCKET_SCOPE = "ds_grad_bucket"
 
 

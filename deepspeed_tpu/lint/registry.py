@@ -100,8 +100,8 @@ def _build_comm_overlap():
     # buckets split the LintModel into three EQUAL padded buckets
     # ((b1, b2) / (w1) / (w2), 1024 elements each), so the backward issues
     # three independent reduce-scatter/psum/all-gather chains and every
-    # bucket's ICI phases fit under the other buckets' in-flight DCN wire —
-    # the exposed-ICI == 0 shape the anatomy golden pins (docs/overlap.md)
+    # bucket's ICI phases fit under the other buckets' in-flight DCN wire
+    # (docs/overlap.md)
     import deepspeed_tpu
     model = LintModel()
     eng, _, _, _ = deepspeed_tpu.initialize(

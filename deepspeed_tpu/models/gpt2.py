@@ -37,9 +37,9 @@ class GPT2Config:
     # Fused Pallas transformer-block kernel (ops/pallas/fused_block.py): the
     # whole attention half — LN + fused qkv + causal attention + output
     # projection + residual — runs as ONE kernel, so none of the block's
-    # intermediate [B, T, E] tensors round-trip through HBM (the path the
-    # anatomy roofline flags as HBM-bound). Takes precedence over
-    # use_flash_attention when eligible; requires dropout == 0 and no
+    # intermediate [B, T, E] tensors round-trip through HBM (an HBM-bound
+    # path). Takes precedence over use_flash_attention when eligible;
+    # requires dropout == 0 and no
     # sparse_attention, and falls back to the unfused path under manual TP /
     # sequence parallelism (the kernel is single-chip, whole-row K/V). Interpreter
     # only so far: the chip's compiler refuses it at model widths (VMEM, see the

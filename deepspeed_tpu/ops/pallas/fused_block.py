@@ -4,10 +4,9 @@ Pallas counterpart of the reference's fused CUDA transformer op
 (``csrc/transformer/transform_kernels.cu`` + the fused softmax path): one kernel
 computes ``x + proj(attn(qkv(layernorm(x))))`` per q-tile, so the normalized
 hidden states, the qkv activations, the [T, T] score matrix and the pre-residual
-attention output never round-trip through HBM. The roofline ledger
-(``ds-tpu anatomy``) prices exactly this path as HBM-bound: at GPT-2 shapes the
-unfused forward writes ~7 intermediate [B, T, E]-class tensors per block; the
-fused kernel writes one.
+attention output never round-trip through HBM. This path is HBM-bound: at
+GPT-2 shapes the unfused forward writes ~7 intermediate [B, T, E]-class tensors
+per block; the fused kernel writes one.
 
 Design:
 - grid ``(B, T // block_q)``; the second dimension is sequential, so the kernel

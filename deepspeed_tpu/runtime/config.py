@@ -270,27 +270,6 @@ class DeepSpeedConfig:
                 f"int >= 1, got {cap!r}")
         self.pipeline_trace_dump_dir = get_scalar_param(pt_dict, PIPELINE_TRACE_DUMP_DIR,
                                                         PIPELINE_TRACE_DUMP_DIR_DEFAULT)
-        an_dict = tel_dict.get(TELEMETRY_ANATOMY, {}) or {}
-        self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_ANATOMY}",
-                                  an_dict, ANATOMY_CONFIG_KEYS)
-        self.telemetry_anatomy_enabled = get_scalar_param(an_dict, ANATOMY_ENABLED,
-                                                          ANATOMY_ENABLED_DEFAULT)
-        self.telemetry_anatomy_chip = get_scalar_param(an_dict, ANATOMY_CHIP, ANATOMY_CHIP_DEFAULT)
-        for attr, key, default in (("telemetry_anatomy_peak_tflops", ANATOMY_PEAK_TFLOPS,
-                                    ANATOMY_PEAK_TFLOPS_DEFAULT),
-                                   ("telemetry_anatomy_hbm_gbps", ANATOMY_HBM_GBPS,
-                                    ANATOMY_HBM_GBPS_DEFAULT),
-                                   ("telemetry_anatomy_ici_gbps", ANATOMY_ICI_GBPS,
-                                    ANATOMY_ICI_GBPS_DEFAULT),
-                                   ("telemetry_anatomy_dcn_gbps", ANATOMY_DCN_GBPS,
-                                    ANATOMY_DCN_GBPS_DEFAULT)):
-            val = get_scalar_param(an_dict, key, default)
-            if isinstance(val, bool) or not isinstance(val, (int, float)) or val < 0:
-                raise ValueError(
-                    f"DeepSpeedConfig: telemetry.anatomy.{key} must be a "
-                    f"number >= 0 (0 = use the chip table value), got {val!r}")
-            setattr(self, attr, float(val))
-
         cl_dict = tel_dict.get(TELEMETRY_CLUSTER, {}) or {}
         self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_CLUSTER}",
                                   cl_dict, CLUSTER_CONFIG_KEYS)
@@ -382,37 +361,6 @@ class DeepSpeedConfig:
             raise ValueError(
                 "DeepSpeedConfig: telemetry.hbm.enabled must be a bool, got "
                 f"{self.telemetry_hbm_enabled!r}")
-
-        prof_dict = tel_dict.get(TELEMETRY_PROFILE, {}) or {}
-        self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_PROFILE}",
-                                  prof_dict, PROFILE_CONFIG_KEYS)
-        self.telemetry_profile_enabled = get_scalar_param(
-            prof_dict, PROFILE_ENABLED, PROFILE_ENABLED_DEFAULT)
-        if not isinstance(self.telemetry_profile_enabled, bool):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.profile.enabled must be a bool, "
-                f"got {self.telemetry_profile_enabled!r}")
-        if self.telemetry_profile_enabled and not self.telemetry_enabled:
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.profile.enabled requires "
-                "telemetry.enabled — the observatory ingests the trace window "
-                "the telemetry session writes")
-        self.telemetry_profile_reconcile_tolerance = get_scalar_param(
-            prof_dict, PROFILE_RECONCILE_TOLERANCE,
-            PROFILE_RECONCILE_TOLERANCE_DEFAULT)
-        tol = self.telemetry_profile_reconcile_tolerance
-        if isinstance(tol, bool) or not isinstance(tol, (int, float)) \
-                or tol <= 0:
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.profile.reconcile_tolerance must "
-                f"be a number > 0, got {tol!r}")
-        self.telemetry_profile_reconcile_tolerance = float(tol)
-        self.telemetry_profile_emit_scalars = get_scalar_param(
-            prof_dict, PROFILE_EMIT_SCALARS, PROFILE_EMIT_SCALARS_DEFAULT)
-        if not isinstance(self.telemetry_profile_emit_scalars, bool):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.profile.emit_scalars must be a "
-                f"bool, got {self.telemetry_profile_emit_scalars!r}")
 
         met_dict = tel_dict.get(TELEMETRY_METRICS, {}) or {}
         self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_METRICS}",

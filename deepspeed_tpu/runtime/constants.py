@@ -181,25 +181,6 @@ TELEMETRY_OUTPUT_PATH_DEFAULT = ""
 TELEMETRY_JOB_NAME = "job_name"
 TELEMETRY_JOB_NAME_DEFAULT = "DeepSpeedTelemetry"
 
-# telemetry.anatomy sub-block: the step-time anatomy — per-program roofline
-# ledger + async-overlap analysis over the watchdog's AOT artifacts, emitted
-# as Anatomy/* scalars (docs/anatomy.md). chip "" auto-detects; the rate
-# overrides (0 = keep the chip table value) let one machine be priced as
-# another.
-TELEMETRY_ANATOMY = "anatomy"
-ANATOMY_ENABLED = "enabled"
-ANATOMY_ENABLED_DEFAULT = False
-ANATOMY_CHIP = "chip"
-ANATOMY_CHIP_DEFAULT = ""
-ANATOMY_PEAK_TFLOPS = "peak_tflops"
-ANATOMY_PEAK_TFLOPS_DEFAULT = 0.0
-ANATOMY_HBM_GBPS = "hbm_gbps"
-ANATOMY_HBM_GBPS_DEFAULT = 0.0
-ANATOMY_ICI_GBPS = "ici_gbps"
-ANATOMY_ICI_GBPS_DEFAULT = 0.0
-ANATOMY_DCN_GBPS = "dcn_gbps"
-ANATOMY_DCN_GBPS_DEFAULT = 0.0
-
 # telemetry.pipeline_trace sub-block: per-instruction span timeline for the
 # pipeline instruction executor (docs/pipeline-trace.md)
 TELEMETRY_PIPELINE_TRACE = "pipeline_trace"
@@ -259,23 +240,6 @@ GOODPUT_EVAL_TAG_DEFAULT = "eval"
 TELEMETRY_HBM = "hbm"
 HBM_ENABLED = "enabled"
 HBM_ENABLED_DEFAULT = False
-
-# telemetry.profile sub-block: measured-time profile observatory — reads the
-# trace window's profiler JSON back after it closes, classifies the device
-# timeline per named scope, and reconciles measured vs predicted (anatomy) vs
-# derived (step counters) step time (docs/profile.md). Host-side file parsing
-# only; the lowered step program is HLO-instruction-identical with the block
-# on or off. Requires telemetry.enabled (and a trace window to have anything
-# to ingest).
-TELEMETRY_PROFILE = "profile"
-PROFILE_ENABLED = "enabled"
-PROFILE_ENABLED_DEFAULT = False
-# relative tolerance of the ds-tpu profile --reconcile verdicts (the
-# machine-independent pairs: flops, collective counts, wire bytes)
-PROFILE_RECONCILE_TOLERANCE = "reconcile_tolerance"
-PROFILE_RECONCILE_TOLERANCE_DEFAULT = 0.05
-PROFILE_EMIT_SCALARS = "emit_scalars"
-PROFILE_EMIT_SCALARS_DEFAULT = True
 
 # telemetry.metrics sub-block: unified metric catalog + per-host time-series
 # ring — every scalar any observatory emits is resolved against the declared
@@ -643,22 +607,11 @@ TELEMETRY_CONFIG_KEYS = frozenset({
     TELEMETRY_OUTPUT_PATH,
     TELEMETRY_JOB_NAME,
     TELEMETRY_PIPELINE_TRACE,
-    TELEMETRY_ANATOMY,
     TELEMETRY_CLUSTER,
     TELEMETRY_GOODPUT,
     TELEMETRY_HBM,
-    TELEMETRY_PROFILE,
     TELEMETRY_METRICS,
     TELEMETRY_ALERTS,
-})
-
-ANATOMY_CONFIG_KEYS = frozenset({
-    ANATOMY_ENABLED,
-    ANATOMY_CHIP,
-    ANATOMY_PEAK_TFLOPS,
-    ANATOMY_HBM_GBPS,
-    ANATOMY_ICI_GBPS,
-    ANATOMY_DCN_GBPS,
 })
 
 PIPELINE_TRACE_CONFIG_KEYS = frozenset({
@@ -686,12 +639,6 @@ GOODPUT_CONFIG_KEYS = frozenset({
 
 HBM_CONFIG_KEYS = frozenset({
     HBM_ENABLED,
-})
-
-PROFILE_CONFIG_KEYS = frozenset({
-    PROFILE_ENABLED,
-    PROFILE_RECONCILE_TOLERANCE,
-    PROFILE_EMIT_SCALARS,
 })
 
 METRICS_CONFIG_KEYS = frozenset({

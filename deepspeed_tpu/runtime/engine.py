@@ -512,46 +512,15 @@ class DeepSpeedEngine:
         self.telemetry = None
         if self.config.telemetry_enabled:
             from ..utils.telemetry import TelemetrySession
-            anatomy_spec = None
-            if self.config.telemetry_anatomy_enabled:
-                # step-anatomy roofline spec (docs/anatomy.md): resolved once
-                # here so every program the watchdog captures is priced
-                # against the same chip model
-                from ..utils.roofline import resolve_spec
-                anatomy_spec = resolve_spec(
-                    self.config.telemetry_anatomy_chip,
-                    self.config.telemetry_anatomy_peak_tflops,
-                    self.config.telemetry_anatomy_hbm_gbps,
-                    self.config.telemetry_anatomy_ici_gbps,
-                    self.config.telemetry_anatomy_dcn_gbps)
-            # with anatomy on and no explicit MFU peak, price measured MFU off
-            # the same chip spec as the ceiling — the two are only comparable
-            # against one denominator
-            peak_tflops = (self.config.telemetry_peak_tflops
-                           or (anatomy_spec.peak_tflops if anatomy_spec
-                               else 0.0))
             self.telemetry = TelemetrySession(
                 monitor=self.monitor,
-                peak_tflops=peak_tflops or None,
+                peak_tflops=self.config.telemetry_peak_tflops or None,
                 trace_dir=self.config.telemetry_trace_dir or None,
                 trace_steps=self.config.telemetry_trace_steps,
                 mfu_window=self.config.telemetry_mfu_window,
                 recompile_warn=self.config.telemetry_recompile_warn,
                 output_path=self.config.telemetry_output_path or None,
-                job_name=self.config.telemetry_job_name,
-                anatomy_spec=anatomy_spec)
-            # measured-time profile observatory (docs/profile.md): configured
-            # BEFORE _compile_steps so every step program's compile also
-            # records the scope/collective identity catalog the trace
-            # ingester joins on — host-side text analysis only, the compiled
-            # step is HLO-instruction-identical on or off (pinned in tests)
-            if self.config.telemetry_profile_enabled:
-                self.telemetry.configure_profile(
-                    True,
-                    reconcile_tolerance=(
-                        self.config.telemetry_profile_reconcile_tolerance),
-                    emit_scalars=(
-                        self.config.telemetry_profile_emit_scalars))
+                job_name=self.config.telemetry_job_name)
             if self._comm_topo.is_hierarchical:
                 # per-axis wire ledger: split every program's collective bytes
                 # into ICI (intra-slice) vs DCN (cross-slice) — installed before

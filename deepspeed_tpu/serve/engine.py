@@ -126,7 +126,7 @@ class InferenceEngine:
             self._mesh = build_mesh(data=1, model=tp, pipe=1,
                                     devices=jax.devices()[:tp])
             if self.telemetry is not None:
-                # classify the decode/prefill psums' links for the anatomy
+                # classify the decode/prefill psums' links for the wire-byte
                 # ledger: the model axis of one serving replica rides a
                 # single slice, so its collectives are all-ICI wire
                 topo = CommTopology(tp, 1)

@@ -2,7 +2,7 @@
 merged fleet timelines.
 
 Every observatory before this one (telemetry scalars, the numerics flight
-recorder, step-time anatomy, the serving request ledger) is strictly
+recorder, the serving request ledger) is strictly
 per-host. This module is the cross-host plane that rides the gloo CPU world
 `runtime/dist.py` already initialises (docs/cluster.md):
 

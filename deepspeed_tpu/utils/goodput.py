@@ -1,7 +1,7 @@
 """Run-lifecycle goodput observatory: the badput ledger.
 
-Every other observatory in the repo accounts for one subsystem — step anatomy
-(Anatomy/*), pipeline bubbles (Pipeline/Goodput/*), serving requests
+Every other observatory in the repo accounts for one subsystem — pipeline
+bubbles (Pipeline/Goodput/*), serving requests
 (Serving/*), cluster hangs/stragglers (Cluster/*), resilience events. None of
 them answers the run-level question: of the wall-clock between engine
 construction and exit, what fraction was productive training, and where did

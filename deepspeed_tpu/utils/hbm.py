@@ -640,7 +640,7 @@ def hbm_main(argv=None):
                              "byte growth beyond tolerance")
     args = parser.parse_args(argv)
 
-    # stdout belongs to the report (same contract as ds-tpu lint/anatomy)
+    # stdout belongs to the report (same contract as ds-tpu lint)
     import logging
     for h in logging.getLogger("DeepSpeedTPU").handlers:
         if isinstance(h, logging.StreamHandler) and h.stream is sys.stdout:

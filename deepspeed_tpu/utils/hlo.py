@@ -247,9 +247,8 @@ def collective_axis_breakdown(hlo_text, slice_sets):
 # ------------------------------------------------------- async start/done pairs
 # Post-scheduling HLO splits an overlappable collective into a `-start` that
 # launches the transfer and a `-done` that blocks on it; every instruction the
-# scheduler placed between the two runs concurrently with the wire. The step-
-# anatomy analyzer (utils/anatomy.py) prices that window to split each
-# collective into overlapped vs exposed time. Two syntactic forms exist:
+# scheduler placed between the two runs concurrently with the wire, so that
+# window is what a collective has to hide under. Two syntactic forms exist:
 # dedicated start/done ops (`all-reduce-start` / `all-reduce-done`) and the
 # generic wrapper (`async-start(...), calls=%comp` holding the collective
 # inside the called computation, optionally chained through `async-update`).
@@ -366,26 +365,6 @@ def parse_async_pairs(hlo_text):
             pair["done_line"] = i
             pairs.append(pair)
     return pairs
-
-
-def collective_lines(hlo_text):
-    """[(line index, instruction name, base op, is_start, produced bytes,
-    groups-or-None)] per collective instruction, in program order — the
-    line-indexed refinement of ``collective_instructions`` the anatomy
-    analyzer needs to tell paired async starts from synchronous collectives."""
-    out = []
-    for i, line in enumerate(hlo_text.splitlines()):
-        m = _OP_RE.search(line)
-        if not m:
-            continue
-        ty, op, start = m.groups()
-        name_m = _DEF_NAME_RE.match(line)
-        b = sum(_elements(dims) * _DTYPE_BYTES[dt]
-                for dt, dims in _result_shapes(ty, op, bool(start))
-                if dt in _DTYPE_BYTES)
-        out.append((i, name_m.group(1) if name_m else "", op, bool(start), b,
-                    parse_replica_groups(line)))
-    return out
 
 
 # ------------------------------------------------------- metadata / identity

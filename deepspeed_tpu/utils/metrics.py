@@ -2,8 +2,8 @@
 
 Every scalar name any observatory emits (``Telemetry/*``, ``Numerics/*``,
 ``Pipeline/*``, ``Serving/*`` including ``Serving/Fleet/*`` and
-``Serving/Spec/*``, ``Cluster/*``, ``Run/Goodput/*``, ``Memory/*``,
-``Profile/*``, ``Anatomy/*``, ``Train/*``, ``Alerts/*``) is declared ONCE
+``Serving/Spec/*``, ``Cluster/*``, ``Run/Goodput/*``, ``Memory/*``, ``Train/*``,
+``Alerts/*``) is declared ONCE
 here with its unit, direction (lower/higher-is-better/neutral), class and a
 one-line description. The catalog is the single source of truth for "which
 way is worse": the alert plane (utils/alerts.py) uses it to orient ``delta``
@@ -119,21 +119,6 @@ _DECLARATIONS = (
     # -- HBM observatory (docs/hbm.md): per-class resident bytes -----------
     _spec("Memory/*", "bytes", LOWER, "bytes",
           "per-class resident HBM attribution from the engine manifest"),
-    # -- step anatomy (docs/anatomy.md): roofline attribution --------------
-    _spec("Anatomy/compute_ms", "ms", NEUTRAL, "time",
-          "roofline compute floor of the measured step"),
-    _spec("Anatomy/hbm_bound_ms", "ms", NEUTRAL, "time",
-          "roofline HBM-bandwidth floor of the measured step"),
-    _spec("Anatomy/exposed_ici_ms", "ms", LOWER, "time",
-          "un-overlapped ICI collective time attributed to the step"),
-    _spec("Anatomy/exposed_dcn_ms", "ms", LOWER, "time",
-          "un-overlapped DCN collective time attributed to the step"),
-    _spec("Anatomy/host_gap_ms", "ms", LOWER, "time",
-          "measured wall minus every device-side floor (host stall)"),
-    _spec("Anatomy/predicted_floor_ms", "ms", NEUTRAL, "time",
-          "max of the roofline floors — the step's predicted best case"),
-    _spec("Anatomy/mfu_ceiling", "fraction", NEUTRAL, "fraction",
-          "MFU the roofline model admits for this step shape"),
     # -- pipeline schedule goodput (docs/pipeline-trace.md) ----------------
     _spec("Pipeline/Goodput/bubble_seconds", "s", LOWER, "time",
           "schedule bubble (idle) seconds within one pipeline step"),
@@ -235,19 +220,6 @@ _DECLARATIONS = (
           "worst host HBM peak this heartbeat"),
     _spec("Cluster/straggler_host", "host", NEUTRAL, "gauge",
           "host id named straggler (-1 = none)"),
-    # -- measured-time profile observatory (docs/profile.md) ---------------
-    _spec("Profile/exposed_ici_ms", "ms", LOWER, "time",
-          "measured un-overlapped ICI time per step"),
-    _spec("Profile/exposed_dcn_ms", "ms", LOWER, "time",
-          "measured un-overlapped DCN time per step"),
-    _spec("Profile/host_gap_ms", "ms", LOWER, "time",
-          "measured device-idle host gap per step"),
-    _spec("Profile/step_wall_ms", "ms", LOWER, "time",
-          "measured step wall from the trace window"),
-    _spec("Profile/mfu", "fraction", HIGHER, "fraction",
-          "measured-window MFU"),
-    _spec("Profile/*", "ms", NEUTRAL, "time",
-          "measured per-class busy time per step"),
     # -- numerics observatory (docs/numerics.md): per-subtree stats --------
     _spec("Numerics/grad_norm/*", "1", NEUTRAL, "gauge",
           "per-subtree gradient norm from the in-graph sentinel"),

@@ -596,8 +596,8 @@ def to_trace_events(bundle):
                           trace_version=bundle.get("version"))
 
 
-# serialize_trace lives in utils/trace_event.py (shared with the serve and
-# anatomy exporters) and stays re-exported here for its historical importers.
+# serialize_trace lives in utils/trace_event.py (shared with the serve
+# exporter) and stays re-exported here for its historical importers.
 
 
 # --------------------------------------------------------------------- the CLI

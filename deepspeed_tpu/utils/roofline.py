@@ -1,19 +1,17 @@
-"""Roofline chip-spec model: peak-rate floors for the step-time anatomy.
+"""Roofline chip-spec model: peak-rate floors of a step.
 
 The roofline method (Williams et al., 2009) bounds a program's runtime from
 below by each hardware resource it must saturate: executed flops can go no
 faster than peak matrix throughput, touched bytes no faster than HBM
 bandwidth, and collective bytes no faster than the link level they ride
-(ICI within a slice, DCN across slices). utils/anatomy.py combines these
-floors with the async-overlap analysis into a predicted step floor and an
-MFU ceiling; this module owns the per-chip peak-rate table and the floor
-arithmetic, so the numbers live in exactly one place.
+(ICI within a slice, DCN across slices). This module owns the per-chip
+peak-rate table and the floor arithmetic, so the numbers live in exactly one
+place.
 
 The table entries are approximate public figures on a deliberately simple
 convention — dense bf16 peak per chip, aggregate HBM bandwidth per chip, and
 an effective per-chip collective bandwidth per link level (not per-link
-signaling rates). Every number is overridable through the
-``telemetry.anatomy`` config block or the ``ds-tpu anatomy`` CLI; the
+signaling rates). Every number is overridable through ``resolve_spec``; the
 ``cpu-test`` spec is a generous upper bound for the 8-virtual-device CI mesh,
 chosen so predicted floors always sit below measured CPU step times (the
 sanity invariant tests pin).

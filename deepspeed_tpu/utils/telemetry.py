@@ -58,31 +58,22 @@ def _abstract_signature(args) -> tuple:
 _mem_unavailable_warned = set()   # backends already named in a warning
 
 
-def _analyze_compiled(compiled, slice_sets=None, anatomy_spec=None,
-                      profile_scopes=False):
+def _analyze_compiled(compiled, slice_sets=None):
     """(flops, argument/output/temp bytes, collective wire bytes, wire bytes
-    split (ici, dcn), HBM bytes accessed, anatomy report, profile_info,
-    mem_unavailable) of a compiled executable, each 0/None when the backend
-    doesn't report it. With no slice factorization every wire byte accounts
-    as ICI. The anatomy report (utils/anatomy.analyze_program) is computed
-    only when ``anatomy_spec`` names a chip spec — pure host-side text
-    analysis of the same artifact. ``profile_scopes`` additionally parses the
-    program's scope/collective identity catalog
-    (utils/profile_ingest.program_profile_info) so a measured trace window
-    can be joined back to this compile. ``mem_unavailable`` is True when
+    split (ici, dcn), mem_unavailable) of a compiled executable, each 0 when
+    the backend doesn't report it. With no slice factorization every wire
+    byte accounts as ICI. ``mem_unavailable`` is True when
     ``memory_analysis()`` raised or returned nothing — recorded so its zeros
     are distinguishable from a genuinely zero-byte program, with one warning
     per backend per session instead of a silent pass."""
-    flops = hbm_b = 0.0
+    flops = 0.0
     arg_b = out_b = tmp_b = wire = wire_ici = wire_dcn = 0
-    anatomy = profile_info = None
     mem_unavailable = False
     try:
         ca = compiled.cost_analysis()
         if not isinstance(ca, dict):  # older jax returned [dict]
             ca = ca[0] if ca else {}
         flops = max(float(ca.get("flops", 0.0)), 0.0)
-        hbm_b = max(float(ca.get("bytes accessed", 0.0)), 0.0)
     except Exception:
         pass
     try:
@@ -114,17 +105,10 @@ def _analyze_compiled(compiled, slice_sets=None, anatomy_spec=None,
         if slice_sets and len(slice_sets) > 1:
             split = collective_axis_bytes(text, slice_sets)
             wire_ici, wire_dcn = split["ici"], split["dcn"]
-        if anatomy_spec is not None:
-            from .anatomy import analyze_program
-            anatomy = analyze_program(text, flops, hbm_b, anatomy_spec,
-                                      slice_sets=slice_sets)
-        if profile_scopes:
-            from .profile_ingest import program_profile_info
-            profile_info = program_profile_info(text, slice_sets=slice_sets)
     except Exception:
         pass
-    return (flops, arg_b, out_b, tmp_b, wire, wire_ici, wire_dcn, hbm_b,
-            anatomy, profile_info, mem_unavailable)
+    return (flops, arg_b, out_b, tmp_b, wire, wire_ici, wire_dcn,
+            mem_unavailable)
 
 
 class CompileRecord:
@@ -132,13 +116,11 @@ class CompileRecord:
 
     __slots__ = ("signature", "compile_seconds", "flops", "argument_bytes",
                  "output_bytes", "temp_bytes", "wire_bytes", "wire_bytes_ici",
-                 "wire_bytes_dcn", "hbm_bytes", "anatomy", "profile_info",
-                 "mem_unavailable", "count")
+                 "wire_bytes_dcn", "mem_unavailable", "count")
 
     def __init__(self, signature, compile_seconds, flops=0.0, argument_bytes=0,
                  output_bytes=0, temp_bytes=0, wire_bytes=0, wire_bytes_ici=0,
-                 wire_bytes_dcn=0, hbm_bytes=0.0, anatomy=None,
-                 profile_info=None, mem_unavailable=False):
+                 wire_bytes_dcn=0, mem_unavailable=False):
         self.signature = signature
         self.compile_seconds = compile_seconds
         self.flops = flops
@@ -148,9 +130,6 @@ class CompileRecord:
         self.wire_bytes = wire_bytes
         self.wire_bytes_ici = wire_bytes_ici
         self.wire_bytes_dcn = wire_bytes_dcn
-        self.hbm_bytes = hbm_bytes          # cost_analysis "bytes accessed"
-        self.anatomy = anatomy              # utils/anatomy report or None
-        self.profile_info = profile_info    # utils/profile_ingest catalog row
         self.mem_unavailable = mem_unavailable  # memory_analysis absent: the
         # zero arg/out/temp bytes above mean "not reported", not "zero bytes"
         self.count = 1
@@ -169,12 +148,6 @@ class CompileWatchdog:
         # slice factorization for the per-axis (ICI vs DCN) wire-byte split;
         # None means single-slice — every collective byte accounts as ICI
         self.slice_sets = None
-        # roofline ChipSpec: when set, every analyzed compile also gets the
-        # step-anatomy report (utils/anatomy) — still pure host text analysis
-        self.anatomy_spec = None
-        # profile observatory: when True, every analyzed compile also parses
-        # the scope/collective identity catalog the trace ingester joins on
-        self.profile_scopes = False
 
     def record(self, name: str, sig, seconds: float, compiled=None) -> CompileRecord:
         per = self.records.setdefault(name, {})
@@ -185,16 +158,12 @@ class CompileWatchdog:
         else:
             if compiled is not None:
                 (flops, arg_b, out_b, tmp_b, wire, wire_ici, wire_dcn,
-                 hbm_b, anatomy, profile_info, mem_unavail) = \
-                    _analyze_compiled(compiled, self.slice_sets,
-                                      self.anatomy_spec, self.profile_scopes)
+                 mem_unavail) = _analyze_compiled(compiled, self.slice_sets)
             else:
                 flops = arg_b = out_b = tmp_b = wire = wire_ici = wire_dcn = 0
-                hbm_b, anatomy, profile_info, mem_unavail = 0.0, None, None, \
-                    False
+                mem_unavail = False
             rec = per[sig] = CompileRecord(sig, seconds, flops, arg_b, out_b,
                                            tmp_b, wire, wire_ici, wire_dcn,
-                                           hbm_b, anatomy, profile_info,
                                            mem_unavail)
         n = sum(r.count for r in per.values())
         if len(per) >= self.recompile_warn and name not in self._storm_warned:
@@ -273,15 +242,9 @@ class _WatchedJit:
                 return self._call_fallback(sig, *args)
             rec = self._session.watchdog.record(
                 self._name, sig, time.perf_counter() - t0, compiled)
-            anat = rec.anatomy or {}
-            exposed = anat.get("exposed_s", {})
             entry = self._cache[sig] = (compiled, rec.flops, rec.wire_bytes,
-                                        rec.wire_bytes_ici, rec.wire_bytes_dcn,
-                                        rec.hbm_bytes,
-                                        exposed.get("ici", 0.0),
-                                        exposed.get("dcn", 0.0))
-        (compiled, flops, wire, wire_ici, wire_dcn, hbm_b, exp_ici,
-         exp_dcn) = entry
+                                        rec.wire_bytes_ici, rec.wire_bytes_dcn)
+        compiled, flops, wire, wire_ici, wire_dcn = entry
         try:
             out = compiled(*args)
         except Exception as e:
@@ -291,9 +254,7 @@ class _WatchedJit:
                            f"program {self._name!r} ({e!r}); falling back to the "
                            "raw jit (signature tracking only)")
             return self._jit(*args)
-        self._session.note_execution(flops, wire, wire_ici, wire_dcn,
-                                     hbm_bytes=hbm_b, exposed_ici_s=exp_ici,
-                                     exposed_dcn_s=exp_dcn)
+        self._session.note_execution(flops, wire, wire_ici, wire_dcn)
         return out
 
 
@@ -318,15 +279,9 @@ class TelemetrySession:
                  trace_dir: Optional[str] = None, trace_steps=None,
                  mfu_window: int = 20, recompile_warn: int = 3,
                  output_path: Optional[str] = None, job_name: Optional[str] = None,
-                 anatomy_spec=None, run_id: Optional[str] = None,
+                 run_id: Optional[str] = None,
                  host_id: Optional[int] = None):
         self.watchdog = CompileWatchdog(recompile_warn=recompile_warn)
-        # step-anatomy: a roofline ChipSpec (utils/roofline.resolve_spec)
-        # switches on the per-compile overlap/roofline analysis and the
-        # Anatomy/* end_step scalars; None keeps the analyzer fully off
-        self.watchdog.anatomy_spec = anatomy_spec
-        self.anatomy_spec = anatomy_spec
-        self.last_anatomy = None
         self.peak_tflops = float(peak_tflops) if peak_tflops else None
         self.trace_dir = trace_dir or "deepspeed_telemetry_trace"
         # namespaced trace output (mirrors the flight-recorder dump naming):
@@ -349,11 +304,6 @@ class TelemetrySession:
                          f"trace_{self.run_id}_host{self.host_id}")
             if self.run_id else self.trace_dir)
         self.trace_steps = tuple(trace_steps) if trace_steps is not None else None
-        # profile observatory (docs/profile.md): off until configure_profile
-        self.profile_enabled = False
-        self.profile_rel_tol = None
-        self.profile_emit_scalars = True
-        self.last_profile = None
         # metric catalog + alert plane (docs/metrics.md, docs/alerts.md):
         # off until configure_metrics / configure_alerts
         self.metric_store = None
@@ -372,9 +322,6 @@ class TelemetrySession:
         self.wire_bytes_executed = 0
         self.wire_ici_executed = 0
         self.wire_dcn_executed = 0
-        self.hbm_bytes_executed = 0.0
-        self.exposed_ici_executed = 0.0
-        self.exposed_dcn_executed = 0.0
         self.steps_recorded = 0
         self.last_mfu = None
         self.last_step_ms = None
@@ -390,9 +337,6 @@ class TelemetrySession:
         self._last_wire = 0
         self._last_wire_ici = 0
         self._last_wire_dcn = 0
-        self._last_hbm = 0.0
-        self._last_exp_ici = 0.0
-        self._last_exp_dcn = 0.0
         self._last_compiles = 0
 
         # HBM observatory (docs/hbm.md): per-class resident bytes from the
@@ -418,16 +362,11 @@ class TelemetrySession:
         return _WatchedJit(name, jitted, self)
 
     def note_execution(self, flops: float, wire_bytes: int,
-                       wire_ici: int = 0, wire_dcn: int = 0,
-                       hbm_bytes: float = 0.0, exposed_ici_s: float = 0.0,
-                       exposed_dcn_s: float = 0.0):
+                       wire_ici: int = 0, wire_dcn: int = 0):
         self.flops_executed += flops
         self.wire_bytes_executed += wire_bytes
         self.wire_ici_executed += wire_ici
         self.wire_dcn_executed += wire_dcn
-        self.hbm_bytes_executed += hbm_bytes
-        self.exposed_ici_executed += exposed_ici_s
-        self.exposed_dcn_executed += exposed_dcn_s
 
     def set_memory_manifest(self, class_bytes, geometry=None,
                             forecast_config=None):
@@ -455,34 +394,6 @@ class TelemetrySession:
             "measured": hbm_stats(),
             "temp_peak_bytes": self.watchdog.peak_temp_bytes(),
             "forecast_config": self._forecast_config,
-        }
-
-    def configure_profile(self, enabled: bool, reconcile_tolerance=None,
-                          emit_scalars: bool = True):
-        """Switch the measured-time profile observatory on for this session:
-        every subsequently compiled program also records its scope/collective
-        identity catalog (utils/profile_ingest.program_profile_info — pure
-        host text analysis, the compiled step is untouched), and when a trace
-        window closes end_step ingests the written trace into ``Profile/*``
-        scalars and ``last_profile``. Call before the step programs compile,
-        like set_comm_topology."""
-        self.profile_enabled = bool(enabled)
-        self.profile_rel_tol = reconcile_tolerance
-        self.profile_emit_scalars = bool(emit_scalars)
-        if self.profile_enabled:
-            self.watchdog.profile_scopes = True
-
-    def profile_snapshot(self) -> Optional[Dict[str, Any]]:
-        """Flight-recorder embedding: the last closed trace window's measured
-        profile report (utils/profile_ingest.summarize_slices) plus the
-        window disposition. None when no window was ever ingested AND the
-        trace never failed — i.e. when there is nothing worth embedding."""
-        if self.last_profile is None and not self._trace_failed:
-            return None
-        return {
-            "trace_dir": self.trace_output_dir,
-            "trace_failed": self._trace_failed,
-            "report": self.last_profile,
         }
 
     def configure_metrics(self, enabled: bool = True, ring_len: int = 512,
@@ -578,29 +489,6 @@ class TelemetrySession:
         self._trace_active = False
         self._trace_done = True
 
-    def _ingest_profile(self):
-        """Read the just-closed trace window back into the measured profile
-        report (utils/profile_ingest) — pure host file parsing after
-        stop_trace flushed, no device work. Failures warn once and leave
-        ``last_profile`` None; the training loop is never at risk from a
-        malformed trace."""
-        from .profile_ingest import (ProfileParseError, catalog_from_watchdog,
-                                     device_slices, load_trace_dir,
-                                     summarize_slices)
-        a, b = self.trace_steps
-        try:
-            events, _files = load_trace_dir(self.trace_output_dir)
-            self.last_profile = summarize_slices(
-                device_slices(events),
-                catalog=catalog_from_watchdog(self.watchdog),
-                devices=jax.device_count(), steps=max(b - a, 1),
-                peak_tflops=self.peak_tflops)
-        except (ProfileParseError, OSError) as e:
-            logger.warning(f"[deepspeed_tpu] telemetry: profile ingest of "
-                           f"{self.trace_output_dir} failed ({e}); Profile/* "
-                           "scalars skipped")
-        return self.last_profile
-
     # ------------------------------------------------------------- step metrics
     def mark_step_dispatched(self):
         """Host-local step boundary: the engine calls this when every
@@ -678,18 +566,12 @@ class TelemetrySession:
         wire_d = self.wire_bytes_executed - self._last_wire
         wire_ici_d = self.wire_ici_executed - self._last_wire_ici
         wire_dcn_d = self.wire_dcn_executed - self._last_wire_dcn
-        hbm_d = self.hbm_bytes_executed - self._last_hbm
-        exp_ici_d = self.exposed_ici_executed - self._last_exp_ici
-        exp_dcn_d = self.exposed_dcn_executed - self._last_exp_dcn
         had_compile = compiles != self._last_compiles
         self._last_end = now
         self._last_flops = self.flops_executed
         self._last_wire = self.wire_bytes_executed
         self._last_wire_ici = self.wire_ici_executed
         self._last_wire_dcn = self.wire_dcn_executed
-        self._last_hbm = self.hbm_bytes_executed
-        self._last_exp_ici = self.exposed_ici_executed
-        self._last_exp_dcn = self.exposed_dcn_executed
         self._last_compiles = compiles
 
         samples = global_step * samples_per_step
@@ -733,28 +615,6 @@ class TelemetrySession:
                 mon.add_scalar(f"Memory/{cls}_bytes", nbytes, samples)
             mon.add_scalar("Memory/compiled_temp_peak_bytes",
                            self.watchdog.peak_temp_bytes(), samples)
-        # step anatomy: the roofline attribution of this step's measured wall
-        # time. Pure arithmetic over counters the proxies already fed — the
-        # scalars appear or disappear with telemetry.anatomy, nothing else
-        # about the step path changes (asserted HLO-identical in tests).
-        if self.anatomy_spec is not None and dt > 0 and not had_compile:
-            from .roofline import roofline
-            rf = roofline(flops_d, hbm_d, exp_ici_d, exp_dcn_d,
-                          self.anatomy_spec, measured_seconds=dt)
-            self.last_anatomy = rf
-            mon.add_scalar("Anatomy/compute_ms",
-                           rf["compute_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/hbm_bound_ms",
-                           rf["hbm_bound_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/exposed_ici_ms",
-                           rf["exposed_ici_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/exposed_dcn_ms",
-                           rf["exposed_dcn_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/host_gap_ms",
-                           rf["host_gap_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/predicted_floor_ms",
-                           rf["predicted_floor_s"] * 1000.0, samples)
-            mon.add_scalar("Anatomy/mfu_ceiling", rf["mfu_ceiling"], samples)
         if schedule_goodput:
             for key in ("fwd_seconds", "bwd_seconds", "p2p_seconds", "load_seconds",
                         "reduce_seconds", "opt_seconds", "bubble_seconds",
@@ -775,39 +635,6 @@ class TelemetrySession:
         if self._trace_active and self.trace_steps is not None \
                 and global_step >= self.trace_steps[1]:
             self._stop_trace()
-            # measured-time observatory: the window just flushed to disk —
-            # read it back (host-side file parsing only; the step programs
-            # are untouched and HLO-instruction-identical, pinned in tests)
-            if self.profile_enabled and not self._trace_failed \
-                    and self._ingest_profile() is not None \
-                    and self.profile_emit_scalars:
-                prof = self.last_profile
-                steps = max(prof["steps"], 1)
-                cls = prof["classes"]
-                mon.add_scalar("Profile/compute_ms",
-                               cls["compute"]["busy_us"] / steps / 1e3,
-                               samples)
-                mon.add_scalar("Profile/collective_ici_ms",
-                               cls["collective_ici"]["busy_us"] / steps / 1e3,
-                               samples)
-                mon.add_scalar("Profile/collective_dcn_ms",
-                               cls["collective_dcn"]["busy_us"] / steps / 1e3,
-                               samples)
-                mon.add_scalar("Profile/exposed_ici_ms",
-                               cls["collective_ici"]["exposed_us"] / steps
-                               / 1e3, samples)
-                mon.add_scalar("Profile/exposed_dcn_ms",
-                               cls["collective_dcn"]["exposed_us"] / steps
-                               / 1e3, samples)
-                mon.add_scalar("Profile/host_gap_ms",
-                               cls["host_gap"]["gap_us"] / steps / 1e3,
-                               samples)
-                mon.add_scalar("Profile/step_wall_ms",
-                               prof["step_wall_us"] / 1e3, samples)
-                if prof.get("measured_mfu") is not None:
-                    mon.add_scalar("Profile/mfu", prof["measured_mfu"],
-                                   samples)
-                mon.flush()
         if self.alert_engine is not None:
             # alert rules run on the end_step boundary, on the same axis the
             # scalars above were recorded at — pure reads of the host-side
@@ -840,40 +667,6 @@ class TelemetrySession:
         """One-shot digest for benches/reports: rolling MFU, HBM watermarks,
         wire bytes of the last step, and the watchdog's compile accounting."""
         stats = hbm_stats() or {}
-        anatomy = None
-        if self.last_anatomy is not None:
-            rf = self.last_anatomy
-            anatomy = {
-                "predicted_floor_ms": round(rf["predicted_floor_s"] * 1e3, 6),
-                "compute_ms": round(rf["compute_s"] * 1e3, 6),
-                "hbm_bound_ms": round(rf["hbm_bound_s"] * 1e3, 6),
-                "exposed_ici_ms": round(rf["exposed_ici_s"] * 1e3, 6),
-                "exposed_dcn_ms": round(rf["exposed_dcn_s"] * 1e3, 6),
-                "host_gap_ms": round(rf["host_gap_s"] * 1e3, 6),
-                "mfu_ceiling": round(rf["mfu_ceiling"], 4),
-            }
-        profile = None
-        if self.last_profile is not None:
-            prof = self.last_profile
-            steps = max(prof["steps"], 1)
-            cls = prof["classes"]
-            profile = {
-                "compute_ms": round(cls["compute"]["busy_us"] / steps / 1e3, 6),
-                "collective_ici_ms": round(
-                    cls["collective_ici"]["busy_us"] / steps / 1e3, 6),
-                "collective_dcn_ms": round(
-                    cls["collective_dcn"]["busy_us"] / steps / 1e3, 6),
-                "exposed_ici_ms": round(
-                    cls["collective_ici"]["exposed_us"] / steps / 1e3, 6),
-                "exposed_dcn_ms": round(
-                    cls["collective_dcn"]["exposed_us"] / steps / 1e3, 6),
-                "host_gap_ms": round(
-                    cls["host_gap"]["gap_us"] / steps / 1e3, 6),
-                "step_wall_ms": round(prof["step_wall_us"] / 1e3, 6),
-                "measured_mfu": prof.get("measured_mfu"),
-                "scopes": sorted(prof.get("scopes", {})),
-                "steps": prof["steps"],
-            }
         # trace-window disposition, with the _trace_failed latch surfaced so
         # a "profiler unavailable" run is visible in every bench/report
         # digest instead of only in one early warning line
@@ -890,9 +683,7 @@ class TelemetrySession:
             "mfu": self.last_mfu,
             "step_time_ms": self.last_step_ms,
             "steps_recorded": self.steps_recorded,
-            "anatomy": anatomy,
             "trace": trace,
-            "profile": profile,
             "wire_bytes_per_step": self.last_wire_bytes,
             "wire_bytes_per_step_ici": self.last_wire_bytes_ici,
             "wire_bytes_per_step_dcn": self.last_wire_bytes_dcn,

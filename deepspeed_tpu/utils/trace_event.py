@@ -1,11 +1,10 @@
 """Shared Chrome/Perfetto ``trace_event`` writer.
 
-Three CLIs export timelines in the Chrome trace_event JSON format —
+The CLIs that export timelines in the Chrome trace_event JSON format —
 ``ds-tpu timeline`` (pipeline instruction spans, utils/pipeline_trace.py),
-``ds-tpu serve-timeline`` (serving request lifecycles, serve/request_trace.py)
-and ``ds-tpu anatomy`` (predicted roofline schedules, utils/anatomy.py). They
-grew three private copies of the same event constructors and the byte-stable
-serializer; this module is the single copy all of them build on.
+``ds-tpu serve-timeline`` (serving request lifecycles, serve/request_trace.py),
+``ds-tpu goodput`` and ``ds-tpu cluster-dump`` — build on this one copy of the
+event constructors and the byte-stable serializer.
 
 The golden-file contract lives in :func:`serialize_trace`: sorted keys, no
 whitespace, so the emitted bytes are a pure function of the event dicts'
@@ -18,9 +17,8 @@ byte-identical across the refactor.
 import json
 
 __all__ = ["serialize_trace", "trace_envelope", "load_bundle",
-           "process_name_event", "process_sort_index_event",
-           "thread_meta_events", "complete_slice", "counter_event",
-           "instant_event"]
+           "process_name_event", "thread_meta_events", "complete_slice",
+           "counter_event", "instant_event"]
 
 
 def serialize_trace(trace):
@@ -55,14 +53,6 @@ def load_bundle(path, kind):
 def process_name_event(pid, name, tid=0):
     return {"ph": "M", "pid": pid, "tid": tid, "name": "process_name",
             "args": {"name": name}}
-
-
-def process_sort_index_event(pid, sort_index, tid=0):
-    """Pin a process track's vertical position in the Perfetto UI — the merged
-    measured-vs-predicted profile timeline uses it to keep the predicted
-    schedule above the measured one regardless of pid numbering."""
-    return {"ph": "M", "pid": pid, "tid": tid, "name": "process_sort_index",
-            "args": {"sort_index": sort_index}}
 
 
 def thread_meta_events(pid, tid, name, sort_index=None):

@@ -69,24 +69,6 @@ timeout -k 10 300 "$REPO/bin/ds-tpu" serve-sim --sharding 2 \
     --verify-unsharded --json /tmp/_serve_sharded.json \
     --output /tmp/_serve_sharded_telemetry
 shard_rc=$?
-# anatomy: roofline ledger + overlap analysis over the comm-mode registry
-# entries, with the flat-vs-hierarchical-vs-overlap exposed-DCN comparison
-# byte-compared against the committed golden — any pricing or exchange drift
-# fails CI. (`ds-tpu anatomy` itself exits nonzero when the two-level modes
-# stop strictly beating flat, when bucketed overlap stops strictly beating
-# the monolithic hierarchical exchange or its grad-ICI exposure leaves zero,
-# or when any overlap-enabled entry reports a zero-overlap bucketed grad
-# collective — the overlap gate.) Full report in /tmp/_anatomy.json
-# (deterministic bytes); /tmp/_anatomy.trace.json is the predicted-schedule
-# Perfetto view.
-timeout -k 10 300 "$REPO/bin/ds-tpu" anatomy --json --out /tmp/_anatomy.json \
-    --entry standard --entry comm_hierarchical --entry comm_compressed \
-    --entry comm_overlap --entry comm_overlap_compressed \
-    --timeline /tmp/_anatomy.trace.json \
-    --comm-compare-out /tmp/_anatomy_comm.json \
-&& cmp "$REPO/tests/unit/golden/anatomy_comm_compare.json" \
-       /tmp/_anatomy_comm.json
-anatomy_rc=$?
 # hbm: memory-observatory gate — per-buffer attribution parsed from every
 # lint-registry program's entry layout, reconciled against the analytic ZeRO
 # memory model within the pinned tolerance ON EVERY ENTRY (`ds-tpu hbm`
@@ -134,18 +116,6 @@ timeout -k 10 120 "$REPO/bin/ds-tpu" hang-sim --json /tmp/_hang_sim.json \
 && cmp "$REPO/tests/unit/golden/cluster_timeline_2host.trace.json" \
        /tmp/_cluster_timeline.trace.json
 hang_rc=$?
-# profile: measured-time observatory gate — run a traced CPU-mesh window
-# through the comm_overlap lint entry and reconcile measured (trace) vs
-# predicted (compile-time catalog) vs derived (step counters) per class
-# (`ds-tpu profile --reconcile` exits 1 on any drift verdict). The stable
-# projection (verdicts, collective execution counts, wire bytes, flops,
-# scope/bucket coverage — no wall-clock fields) is byte-compared against the
-# committed golden so any attribution or schedule drift fails CI.
-timeout -k 10 300 "$REPO/bin/ds-tpu" profile --reconcile --json \
-    --out /tmp/_profile.json --golden-out /tmp/_profile_golden.json \
-&& cmp "$REPO/tests/unit/golden/profile_reconcile.json" \
-       /tmp/_profile_golden.json
-profile_rc=$?
 # alert-sim: alert attribution harness — four injected ground-truth
 # regressions (MFU drop via step-wall inflation, fleet shed spike via
 # Poisson arrivals at 2x capacity, loss-scale stuck streak via forced
@@ -183,11 +153,9 @@ fleet_rc=$?
 [ "$cache_rc" -ne 0 ] && exit "$cache_rc"
 [ "$spec_rc" -ne 0 ] && exit "$spec_rc"
 [ "$shard_rc" -ne 0 ] && exit "$shard_rc"
-[ "$anatomy_rc" -ne 0 ] && exit "$anatomy_rc"
 [ "$hbm_rc" -ne 0 ] && exit "$hbm_rc"
 [ "$crash_rc" -ne 0 ] && exit "$crash_rc"
 [ "$goodput_rc" -ne 0 ] && exit "$goodput_rc"
 [ "$hang_rc" -ne 0 ] && exit "$hang_rc"
-[ "$profile_rc" -ne 0 ] && exit "$profile_rc"
 [ "$alert_rc" -ne 0 ] && exit "$alert_rc"
 exit "$fleet_rc"

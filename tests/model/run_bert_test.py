@@ -23,8 +23,7 @@ def _run_bert(config_name, tmp_path):
 
 @pytest.mark.parametrize("config_name", [
     "ds_config_func_bs8_zero2.json",
-    pytest.param("ds_config_func_bs8_fp16.json",
-                 marks=pytest.mark.slow)])  # ~16s subprocess; tier-1 cap
+    "ds_config_func_bs8_fp16.json"])
 def test_bert_qa_finetune_converges(config_name, tmp_path):
     records, stdout = _run_bert(config_name, tmp_path)
     assert len(records) == STEPS, stdout

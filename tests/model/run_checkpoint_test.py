@@ -15,9 +15,7 @@ SAVE_AT = 4
 
 @pytest.mark.parametrize("config_name", [
     "ds_config_func_bs8_zero2.json",
-    # scheduler resume coverage rides the zero2 variant in tier-1; the second
-    # ~35s subprocess pair is `slow` (tier-1 870s cap)
-    pytest.param("ds_config_func_scheduler.json", marks=pytest.mark.slow)])
+    "ds_config_func_scheduler.json"])
 def test_resume_matches_straight_run(config_name, tmp_path, tmp_path_factory):
     cfg = load_config(config_name)
     ckpt = tmp_path / "ckpt"

@@ -153,7 +153,6 @@ def test_gpt2_remat_uses_config(xw):
     np.testing.assert_allclose(float(loss_remat), float(loss_plain), rtol=1e-5)
 
 
-@pytest.mark.slow  # engine+offload-remat compile (~11s); tier-1 870s cap
 def test_engine_composes_with_cpu_checkpointing():
     """regression: offload-remat custom-calls must not collide with the engine's
     out_shardings (XLA SPMD 'side-effect ops cannot be replicated')."""

@@ -423,7 +423,6 @@ def _run_hang_sim(tmp_path, tag):
     return out, dumps
 
 
-@pytest.mark.slow
 def test_hang_sim_deterministic_and_cli_roundtrip(tmp_path, capsys):
     """Two hang-sim runs produce byte-identical transcripts (the property the
     lint gate's golden compare rests on), and cluster-dump over the produced

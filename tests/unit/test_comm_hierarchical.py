@@ -295,7 +295,6 @@ def test_parse_replica_groups_forms():
 
 
 # --------------------------------------------------------------- comm-sim
-@pytest.mark.slow
 def test_comm_sim_report_passes_manifest():
     """The comm-sim gate (scripts/lint.sh) holds on the shipped schedule and
     its JSON rendering is deterministic and parseable."""

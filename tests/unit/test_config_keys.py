@@ -97,20 +97,6 @@ SWEEP = {
         ({"pipeline_trace": {"enabled": True, "dump_dir": "/tmp/pt"}},
          ("attr", "pipeline_trace_dump_dir", "/tmp/pt")),
         ({"pipeline_trace": {"enabled": True, "capacity": 0}}, ("raise", ValueError)),
-        ({"anatomy": {"enabled": True}},
-         ("attr", "telemetry_anatomy_enabled", True)),
-        ({"anatomy": {"enabled": True, "chip": "tpu-v5e"}},
-         ("attr", "telemetry_anatomy_chip", "tpu-v5e")),
-        ({"anatomy": {"enabled": True, "peak_tflops": 275}},
-         ("attr", "telemetry_anatomy_peak_tflops", 275.0)),
-        ({"anatomy": {"enabled": True, "hbm_gbps": 819}},
-         ("attr", "telemetry_anatomy_hbm_gbps", 819.0)),
-        ({"anatomy": {"enabled": True, "ici_gbps": 200}},
-         ("attr", "telemetry_anatomy_ici_gbps", 200.0)),
-        ({"anatomy": {"enabled": True, "dcn_gbps": 25}},
-         ("attr", "telemetry_anatomy_dcn_gbps", 25.0)),
-        ({"anatomy": {"enabled": True, "peak_tflops": -1}}, ("raise", ValueError)),
-        ({"anatomy": {"enabled": True, "hbm_gbps": True}}, ("raise", ValueError)),
         ({"enabled": True, "cluster": {"enabled": True}},
          ("attr", "telemetry_cluster_enabled", True)),
         ({"enabled": True, "cluster": {"enabled": True, "heartbeat_interval": 5}},
@@ -142,22 +128,6 @@ SWEEP = {
          ("raise", ValueError)),
         ({"enabled": True, "goodput": {"enabled": True, "ledger_dir": 5}},
          ("raise", ValueError)),
-        ({"enabled": True, "profile": {"enabled": True}},
-         ("attr", "telemetry_profile_enabled", True)),
-        ({"enabled": True, "profile": {"enabled": True,
-                                       "reconcile_tolerance": 0.1}},
-         ("attr", "telemetry_profile_reconcile_tolerance", 0.1)),
-        ({"enabled": True, "profile": {"enabled": True, "emit_scalars": False}},
-         ("attr", "telemetry_profile_emit_scalars", False)),
-        # the observatory ingests the trace window the telemetry session
-        # writes — no telemetry, no profile
-        ({"profile": {"enabled": True}}, ("raise", ValueError)),
-        ({"enabled": True, "profile": {"enabled": True,
-                                       "reconcile_tolerance": 0}},
-         ("raise", ValueError)),
-        ({"enabled": True, "profile": {"enabled": True, "emit_scalars": 1}},
-         ("raise", ValueError)),
-        ({"enabled": True, "profile": {"enabled": 1}}, ("raise", ValueError)),
         # the heartbeat rides the telemetry end_step record — no telemetry, no cluster
         ({"cluster": {"enabled": True}}, ("raise", ValueError)),
         ({"enabled": True, "cluster": {"enabled": True, "heartbeat_interval": 0}},
@@ -389,20 +359,25 @@ def test_unknown_pipeline_trace_key_warns(capture):
     assert "capactiy" in capture.text
 
 
-def test_unknown_anatomy_key_warns(capture):
-    _cfg(telemetry={"anatomy": {"enabled": True, "chipp": "tpu-v4"}})
-    assert "unknown telemetry.anatomy config key" in capture.text
-    assert "chipp" in capture.text
-    assert "chip" in capture.text    # the known-keys hint points at the fix
+def _state(cfg):
+    """Everything a DeepSpeedConfig parsed, the raw dict left out and its
+    nested config objects opened."""
+    return {k: (v if isinstance(v, (type(None), bool, int, float, str, tuple,
+                                    list, dict)) else vars(v))
+            for k, v in vars(cfg).items() if k != "_param_dict"}
 
 
-def test_unknown_profile_key_warns(capture):
-    _cfg(telemetry={"enabled": True,
-                    "profile": {"enabled": True, "tolernce": 0.1}})
-    assert "unknown telemetry.profile config key" in capture.text
-    assert "tolernce" in capture.text
-    # the known-keys hint points at the fix
-    assert "reconcile_tolerance" in capture.text
+@pytest.mark.parametrize("block", ["anatomy", "profile"])
+def test_a_removed_telemetry_block_warns_and_changes_nothing(capture, block):
+    """``telemetry.anatomy`` and ``telemetry.profile`` named modules that are
+    gone: a config that still carries one is told so like any unknown key, and
+    parses to what it would without it."""
+    with_block = _cfg(telemetry={"enabled": True, "trace_steps": [2, 5],
+                                 block: {"enabled": True}})
+    assert "unknown telemetry config key" in capture.text
+    assert f"['{block}']" in capture.text
+    without = _cfg(telemetry={"enabled": True, "trace_steps": [2, 5]})
+    assert _state(with_block) == _state(without)
 
 
 def test_unknown_metrics_key_warns(capture):
@@ -500,12 +475,8 @@ def test_unknown_numerics_key_warns(capture):
 def test_known_nested_keys_do_not_warn(capture):
     _cfg(telemetry={"enabled": True, "trace_steps": [2, 5],
                     "pipeline_trace": {"enabled": True, "capacity": 7},
-                    "anatomy": {"enabled": True, "chip": "tpu-v4",
-                                "dcn_gbps": 25.0},
                     "goodput": {"enabled": True, "ledger_dir": "/tmp/gp",
                                 "emit_scalars": True, "eval_tag": "eval"},
-                    "profile": {"enabled": True, "reconcile_tolerance": 0.05,
-                                "emit_scalars": True},
                     "metrics": {"enabled": True, "ring_len": 128,
                                 "strict_catalog": True,
                                 "export_path": "/tmp/om.txt"},

@@ -32,8 +32,7 @@ def _run_example(script, *args, timeout=600):
 
 
 @pytest.mark.parametrize("extra", [
-    (), pytest.param(("--zero", "3", "--sparse", "--seq", "128"),
-                     marks=pytest.mark.slow)])  # ~26s subprocess; tier-1 cap
+    (), ("--zero", "3", "--sparse", "--seq", "128")])
 def test_train_gpt2_example(extra):
     out = _run_example("train_gpt2.py", "--steps", "3", "--layers", "2",
                        "--width", "64", "--vocab", "512", *extra)

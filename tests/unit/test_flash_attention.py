@@ -271,8 +271,7 @@ def test_transformer_layer_masked_dropout_uses_flash(monkeypatch):
     assert np.isfinite(np.asarray(out)).all()
 
 
-@pytest.mark.parametrize(
-    "causal", [pytest.param(False, marks=pytest.mark.slow), True])
+@pytest.mark.parametrize("causal", [False, True])
 def test_chunked_long_context_matches_dense(causal):
     """The k-chunked long-context path (used past the resident kernel's VMEM cap)
     must match dense attention exactly — fwd and grads, causal decomposition
@@ -296,7 +295,6 @@ def test_chunked_long_context_matches_dense(causal):
                                    err_msg=f"d{n} (causal={causal})")
 
 
-@pytest.mark.slow  # whole-sequence oracle mask, compile-bound (~33s for the pair)
 @pytest.mark.parametrize("causal", [False, True])
 def test_chunked_dropout_matches_global_oracle(causal):
     """Chunked tiles hash GLOBAL coordinates: dropout through the chunked path must

@@ -255,7 +255,6 @@ import numpy as np
 from launcher_worker import clean_spawn_env as _clean_env, free_port as _free_port  # noqa: E402
 
 
-@pytest.mark.slow
 def test_two_process_launcher_loss_parity(tmp_path):
     worker = os.path.join(os.path.dirname(__file__), "launcher_worker.py")
 
@@ -326,7 +325,6 @@ def test_mpi_identity_without_coordinator(tmp_path):
     assert "SINGLE-OK" in r.stdout
 
 
-@pytest.mark.slow
 def test_two_process_offload_elastic_world_change(tmp_path):
     """The sharded-state LIFECYCLE across a world-size change:
     2 real jax.distributed processes train ZeRO-2+offload and save per-process
@@ -340,7 +338,6 @@ def test_two_process_offload_elastic_world_change(tmp_path):
     run_elastic_rehearsal(str(tmp_path), repo_root)
 
 
-@pytest.mark.slow
 def test_two_process_hierarchical_comm_loss_parity(tmp_path):
     """Two-level ICI+DCN comm across REAL process boundaries: 2 launcher-spawned
     jax.distributed processes x 2 virtual devices (dp 4, auto-factorized 2x2 —
@@ -354,7 +351,6 @@ def test_two_process_hierarchical_comm_loss_parity(tmp_path):
     run_hierarchical_rehearsal(str(tmp_path), repo_root)
 
 
-@pytest.mark.slow
 def test_two_process_cluster_observatory(tmp_path):
     """Cluster observatory across REAL process boundaries (docs/cluster.md):
     2 launcher-spawned jax.distributed processes with ``telemetry.cluster``
@@ -370,7 +366,6 @@ def test_two_process_cluster_observatory(tmp_path):
     run_cluster_observatory_rehearsal(str(tmp_path), repo_root)
 
 
-@pytest.mark.slow
 def test_two_process_offload_region_checkpoint(tmp_path):
     """Multi-host ZeRO-Offload end-to-end: 2 real jax.distributed processes train with
     partitioned host-tier Adam, each writes ITS OWN region file on save, and a fresh

@@ -40,7 +40,7 @@ def test_exact_names_resolve():
                  "Train/Samples/train_loss", "Train/Samples/loss_scale",
                  "Cluster/step_skew", "Serving/tok_s", "Serving/ttft_ms",
                  "Serving/Fleet/shed", "Serving/Fleet/Goodput/fraction",
-                 "Profile/exposed_ici_ms", "Run/Goodput/goodput_fraction",
+                 "Run/Goodput/goodput_fraction",
                  "Pipeline/Goodput/bubble_fraction"):
         spec = cat.resolve(name)
         assert spec is not None, f"{name} undeclared"

@@ -134,7 +134,6 @@ def test_moe_trains_through_engine(mesh):
     assert losses[-1] < losses[0] * 0.6, (losses[0], losses[-1])
 
 
-@pytest.mark.slow  # compile-bound integration (~17s); tier-1 870s cap
 def test_gpt2_moe_trains_through_engine():
     """GPT2Config(moe_experts=..) alternates switch-MoE FFN blocks; the model
     trains through DeepSpeedEngine with ZeRO-2 and the aux loss folded in."""
@@ -193,7 +192,6 @@ def test_gpt2_moe_gspmd_expert_sharding_matches_replicated(mesh):
         g_s, g_r)
 
 
-@pytest.mark.slow  # 8-rank interpret ring + MoE (~61s); tier-1 870s cap
 def test_gpt2_moe_composes_with_sequence_parallelism():
     """MoE + ring-attention sequence parallelism: dense dispatch routes each
     rank's local chunk (per-chunk capacity), aux folds into the pmean'd loss,
@@ -271,8 +269,7 @@ def test_top2_gshard_matches_per_token_oracle():
     assert float(aux) > 0
 
 
-@pytest.mark.parametrize(
-    "top_k", [1, pytest.param(2, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("top_k", [1, 2])
 def test_scatter_dispatch_matches_einsum(mesh, top_k):
     """The scatter/gather dispatch (row scatter-add + row gather — flops-cheap,
     but slower than the default einsum on TPU, see PERF.md)
@@ -307,7 +304,6 @@ def test_scatter_dispatch_matches_einsum(mesh, top_k):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow  # compile-bound (~15s); tier-1 870s cap
 def test_top2_second_choice_queues_after_first(mesh):
     """Expert-parallel top-2 equals the dense-dispatch top-2 (the all_to_all path
     is routing-agnostic), and grads stay finite."""

@@ -74,17 +74,9 @@ def test_pass_reports_occurrence_counts():
 def test_guard_scans_the_real_files():
     files = _utils_files()
     for name in ("telemetry.py", "numerics.py", "pipeline_trace.py", "hlo.py",
-                 "profile_ingest.py", "metrics.py", "alerts.py",
+                 "metrics.py", "alerts.py",
                  os.path.join("serve", "request_trace.py")):
         assert any(f.endswith(name) for f in files), f"{name} missing from sweep"
-
-
-def test_profile_ingest_is_sync_free():
-    """The trace ingester runs inside end_step right after a window closes —
-    it must stay pure host file parsing: zero host-sync primitives."""
-    pi = os.path.join(UTILS, "profile_ingest.py")
-    vids = {v.vid for v in run_ast_passes([pi], (HostSyncPass(),), root=ROOT)}
-    assert vids == set(), f"host-sync primitive in profile_ingest: {vids}"
 
 
 def test_metric_catalog_is_sync_free():

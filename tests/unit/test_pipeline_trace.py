@@ -9,7 +9,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +16,9 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.utils.hlo import instruction_count, optimized_hlo
-from deepspeed_tpu.utils.pipeline_trace import (measured_costs, simulate_schedule,
+from deepspeed_tpu.utils.pipeline_trace import (SPAN_BUF, SPAN_DUR, SPAN_MB,
+                                                SPAN_NAME, SPAN_STAGE, SPAN_STEP,
+                                                measured_costs, simulate_schedule,
                                                 simulated_bundle, serialize_trace,
                                                 timeline_main, to_trace_events)
 from test_pipe_engine import HIDDEN, make_pipe, pipe_config, data_iter
@@ -116,34 +117,57 @@ def test_goodput_scalars_flow_through_telemetry(tmp_path):
     assert "goodput" not in eng.pipe_trace.steps[-1]
 
 
-def _padded(fn, seconds):
-    def wrapped(*args, **kwargs):
-        time.sleep(seconds)
-        return fn(*args, **kwargs)
-    return wrapped
+def _four_stage_step(micro, batch):
+    """A four-stage engine and its tracer's record of one step after a warm-up.
+
+    Every stage program is fenced: each holds an all-reduce over the eight
+    virtual devices, and with a 4 x 8 schedule's programs all in flight
+    XLA:CPU's in-process rendezvous now and then waits for participants that
+    never get a thread, and aborts the process after 40 s (``rendezvous.cc``:
+    "only 6 of them arrived on time")."""
+    def fenced(fn):
+        return lambda *args: jax.block_until_ready(fn(*args))
+
+    eng = _build(stages=4, micro=micro, batch=batch, **_trace_cfg())
+    eng._stage_fwd = [fenced(f) for f in eng._stage_fwd]
+    eng._stage_bwd = [fenced(f) for f in eng._stage_bwd]
+    eng._stage_last_bwd = fenced(eng._stage_last_bwd)
+    it = data_iter(batch=8)
+    eng.train_batch(it)  # warmup: stage-fn compiles land inside these spans
+    eng.train_batch(it)
+    return eng, eng.pipe_trace.steps[-1]
+
+
+def _replay(tracer, rec, duration_us):
+    """Hand ``tracer`` the recorded step ``rec`` again through its own
+    ``begin_step`` / ``record`` / ``end_step``, every span lasting the whole
+    microseconds ``duration_us(span)`` says: what the clock read is then not
+    under test, what the tracer makes of its spans is, and the comparison is
+    arithmetic. (The half a microsecond keeps the tracer's truncation on the
+    number that was asked for.)"""
+    tracer.begin_step(rec["step"], rec["schedule"], rec["micro_batches"])
+    for sp in rec["spans"]:
+        tracer.record(sp[SPAN_STAGE], sp[SPAN_STEP], sp[SPAN_NAME], sp[SPAN_MB],
+                      sp[SPAN_BUF], 0.0, (duration_us(sp) + 0.5) / 1e6)
+    tracer.end_step()
+    return tracer.steps[-1]
 
 
 def test_four_stage_measured_bubble_matches_simulator():
-    """Acceptance: on the 4-stage CPU-mesh pipeline, the bubble fraction
-    reconstructed from recorded spans agrees with the analytic simulator run at
-    the measured mean fwd/bwd costs, within 0.15 absolute (the stated
-    tolerance). Stage fns carry fixed sleep pads so span durations dominate
-    CPU dispatch jitter — at raw microsecond-scale spans the lockstep
-    max-over-stages reconstruction is biased upward by per-span variance and
-    the comparison is not deterministic."""
-    eng = _build(stages=4, micro=8, batch=64, **_trace_cfg())
-    it = data_iter(batch=8)
-    eng.train_batch(it)  # warmup: stage-fn compiles land inside these spans
-    for s in range(eng.num_stages - 1):
-        eng._stage_fwd[s] = _padded(eng._stage_fwd[s], 0.01)
-        eng._stage_bwd[s] = _padded(eng._stage_bwd[s], 0.02)
-    eng._stage_last_bwd = _padded(eng._stage_last_bwd, 0.02)
-    eng.train_batch(it)
-    rec = eng.pipe_trace.steps[-1]
-    measured = rec["schedule_goodput"]["bubble_fraction"]
-    t_fwd, t_bwd = measured_costs(rec)
+    """Acceptance: on the 4-stage CPU-mesh pipeline, the bubble fraction the
+    tracer reconstructs from a step's spans agrees with the analytic simulator
+    run at the measured mean fwd/bwd costs: a recorded step's own spans, every
+    forward lasting 10 ms and every backward 20."""
+    eng, rec = _four_stage_step(micro=8, batch=64)
+    cost_us = {"ForwardPass": 10_000, "BackwardPass": 20_000}
+    timed = _replay(eng.pipe_trace, rec,
+                    lambda sp: cost_us.get(sp[SPAN_NAME], sp[SPAN_DUR]))
+    measured = timed["schedule_goodput"]["bubble_fraction"]
+    t_fwd, t_bwd = measured_costs(timed)
+    assert (t_fwd, t_bwd) == pytest.approx((0.01, 0.02), rel=1e-12)
     expected = simulate_schedule(8, 4, "train", t_fwd=t_fwd, t_bwd=t_bwd)["bubble_fraction"]
-    assert measured == pytest.approx(expected, abs=0.15), (measured, expected)
+    assert 0.0 < expected < 1.0
+    assert measured == pytest.approx(expected, abs=1e-9), (measured, expected)
     # and the slot structure is EXACTLY the schedule's
     sim = simulate_schedule(8, 4, "train")
     slots = sorted({(sp[0], sp[1]) for sp in rec["spans"]
@@ -152,23 +176,23 @@ def test_four_stage_measured_bubble_matches_simulator():
 
 
 def test_injected_delay_names_the_straggler():
-    eng = _build(stages=4, micro=4, batch=32, **_trace_cfg())
-    it = data_iter(batch=8)
-    eng.train_batch(it)  # warmup
-    slow = eng._stage_fwd[2]
+    """20 ms added to every forward of stage 2, in the spans: the tracer names
+    the stage, by how much, and names nobody where every stage costs the same."""
+    eng, rec = _four_stage_step(micro=4, batch=32)
+    tracer = eng.pipe_trace
 
-    def delayed(p, x):
-        time.sleep(0.02)
-        return slow(p, x)
+    def even(sp):
+        return 1_000 if sp[SPAN_NAME] in ("ForwardPass", "BackwardPass") else sp[SPAN_DUR]
 
-    eng._stage_fwd[2] = delayed
-    try:
-        eng.train_batch(it)
-    finally:
-        eng._stage_fwd[2] = slow
-    straggler = eng.pipe_trace.divergence(threshold=3.0)
-    assert straggler is not None and straggler["stage"] == 2, straggler
-    assert eng.pipe_trace.last_schedule_goodput["straggler"]["stage"] == 2
+    _replay(tracer, rec, even)
+    assert tracer.divergence(threshold=3.0) is None
+    assert tracer.last_schedule_goodput["straggler"] is None
+    _replay(tracer, rec, lambda sp: even(sp) + 20_000 * (
+        sp[SPAN_NAME] == "ForwardPass" and sp[SPAN_STAGE] == 2))
+    straggler = tracer.divergence(threshold=3.0)
+    # stage 2: four forwards of 21 ms and four backwards of 1; its peers 8 ms
+    assert straggler == {"stage": 2, "ratio": pytest.approx(88 / 8)}, straggler
+    assert tracer.last_schedule_goodput["straggler"]["stage"] == 2
 
 
 # --------------------------------------------------------------- HLO identity

@@ -61,7 +61,7 @@ def test_flash_lse_cotangent_matches_autodiff():
                                    err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("causal", [False, pytest.param(True, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("causal", [False, True])
 def test_ring_matches_dense(mesh, causal):
     q, k, v = qkv(2)
     out = ring_attention_sharded(q, k, v, mesh, causal=causal, interpret=True)
@@ -72,11 +72,9 @@ def test_ring_matches_dense(mesh, causal):
 
 
 # The causal-grads, dropout, and GPT-2 sequence-parallel integration tests below
-# are the slow tail of this file (15-80s each on the 8-rank interpret mesh,
-# compile-bound): marked `slow` so tier-1 finishes under the ROADMAP 870s cap
-# instead of truncating. The fast parity tests above them keep ring attention
-# exercised in every tier-1 run; the slow set runs via `-m slow` standalone.
-@pytest.mark.parametrize("causal", [False, pytest.param(True, marks=pytest.mark.slow)])
+# are the slow tail of this file (15-45s each on the 8-rank interpret mesh,
+# compile-bound).
+@pytest.mark.parametrize("causal", [False, True])
 def test_ring_grads_match_dense(mesh, causal):
     q, k, v = qkv(3)
     g = jax.random.normal(jax.random.PRNGKey(7), (B, H, T, D), jnp.float32)
@@ -97,7 +95,6 @@ def test_ring_grads_match_dense(mesh, causal):
                                    err_msg=f"d{name} (causal={causal})")
 
 
-@pytest.mark.slow
 def test_ring_memory_is_chunked(mesh):
     """The per-chunk flash only ever sees [T/n]-sized operands: a sequence whose
     FULL [T, T] score matrix would be enormous still runs (no O(T^2) anywhere)."""
@@ -109,7 +106,6 @@ def test_ring_memory_is_chunked(mesh):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.slow
 def test_gpt2_sequence_parallel_matches_dense(mesh):
     """GPT-2 with with_sequence_parallel over 8 ranks: loss AND grads equal the
     plain dense model on the full sequence (positions offset per rank, ring
@@ -137,7 +133,6 @@ def test_gpt2_sequence_parallel_matches_dense(mesh):
         g_sp, g_ref)
 
 
-@pytest.mark.slow
 def test_gpt2_sequence_parallel_trains_through_engine(mesh):
     """The packaged model_fn drives DeepSpeedEngine end to end (seq sharded over
     the data axis; params replicated; loss decreases)."""
@@ -173,7 +168,6 @@ def test_gpt2_sequence_parallel_trains_through_engine(mesh):
     assert losses[-1] < losses[0] * 0.8, (losses[0], losses[-1])
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_dropout_matches_global_oracle(mesh, causal):
     """Attention dropout under the ring: every rank hashes GLOBAL coordinates, so
@@ -202,7 +196,6 @@ def test_ring_dropout_matches_global_oracle(mesh, causal):
                                    err_msg=f"d{name} (causal={causal})")
 
 
-@pytest.mark.slow
 def test_gpt2_sequence_parallel_dropout_trains(mesh):
     """Dropout under sequence parallelism (round 4): the ring threads a shared seed
     (global-coordinate attention masks) and hidden dropout folds the rank into its

@@ -164,10 +164,7 @@ def test_segmented_dropout_hashes_global_coordinates():
 
 
 # ------------------------------------------------------------------ ring parity
-# The 8-rank interpret-mode parity tests below are compile-bound (18-31s each):
-# all but the grads-parity representative are marked `slow` so tier-1 finishes
-# under the ROADMAP 870s cap; the slow set runs via `-m slow` standalone.
-@pytest.mark.slow
+# The 8-rank interpret-mode parity tests below are compile-bound (18-56s each).
 def test_zigzag_matches_dense_and_masked(mesh):
     """schedule='zigzag' (the default causal path) vs the dense oracle AND the
     schedule='masked' ring, at the existing ring tolerances."""
@@ -205,7 +202,6 @@ def test_zigzag_grads_match_dense(mesh):
                                    atol=5e-5, err_msg=f"d{name}")
 
 
-@pytest.mark.slow
 def test_zigzag_dropout_matches_global_oracle(mesh):
     """Attention dropout under the zigzag ring: the interleaved layout hashes
     global coordinates through the segment operand, so the 8-shard zigzag must
@@ -242,7 +238,6 @@ def _local_ring_fn(mesh, schedule):
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
 
 
-@pytest.mark.slow
 def test_zigzag_ppermute_count_and_bytes_match_masked(mesh):
     """Acceptance criterion: identical ppermute count AND bytes per step. Both
     schedules rotate the same [B, H, T/n, D] k/v blocks around the same ring —

@@ -62,7 +62,6 @@ class SparseBertEcho:
         return -jnp.mean(jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0])
 
 
-@pytest.mark.slow  # triple integration (~17s); tier-1 870s cap
 def test_sparse_bert_with_onebit_adam_trains(eight_devices):
     model = SparseBertEcho()
     params = model.init(jax.random.PRNGKey(0))

@@ -126,67 +126,59 @@ def test_default_telemetry_blocks_are_only_the_loss_fetch(tmp_path):
 
 
 # --------------------------------------------------------------- pillar 2:
-# trace windows around the configured step range — one window shared with the
-# profile-observatory readback assertions (docs/profile.md): trace start/stop
-# late in a long pytest process is expensive, so the artifact-layout checks
-# and the Profile/* ingest checks ride the SAME traced run
-def test_trace_window_artifacts_and_profile_readback(tmp_path):
-    trace_dir = os.path.join(str(tmp_path), "trace")
+# trace windows around the configured step range. One window serves both tests:
+# trace start/stop late in a long pytest process is expensive.
+@pytest.fixture(scope="module")
+def trace_window(tmp_path_factory):
+    tmp = str(tmp_path_factory.mktemp("trace_window"))
+    trace_dir = os.path.join(tmp, "trace")
     eng = _build(telemetry={"enabled": True, "trace_steps": [1, 2],
-                            "trace_dir": trace_dir, "peak_tflops": 1e-6,
-                            "profile": {"enabled": True},
-                            "output_path": str(tmp_path), "job_name": "prof"})
-    assert eng.telemetry.profile_enabled
-    assert eng.telemetry.watchdog.profile_scopes
+                            "trace_dir": trace_dir,
+                            "output_path": tmp, "job_name": "prof"})
     xs, ys = _batch()
-    # step 0: before the window — the trace dir must not even exist yet
-    loss = eng(xs, ys); eng.backward(loss); eng.step()
-    if eng.telemetry._trace_failed:
-        pytest.skip("profiler backend unavailable on this platform")
-    assert not os.path.exists(trace_dir)
-    # step 1: inside the window (started at its first forward)
-    loss = eng(xs, ys); eng.backward(loss); eng.step()
-    if eng.telemetry._trace_failed:
-        pytest.skip("profiler backend unavailable on this platform")
-    # step 2: past the window — must already be stopped and written
-    loss = eng(xs, ys); eng.backward(loss); eng.step()
-    assert eng.telemetry._trace_done and not eng.telemetry._trace_active
-    # the profiler session lands in the run/host-namespaced subdir
-    from deepspeed_tpu.utils.profile_ingest import (find_trace_files,
-                                                    scan_trace_dirs)
-    runs = scan_trace_dirs(trace_dir)
-    assert [(d["run"], d["host"]) for d in runs] == \
-        [(eng.telemetry.run_id, eng.telemetry.host_id)]
-    assert runs[0]["path"] == eng.telemetry.trace_output_dir
-    assert find_trace_files(runs[0]["path"]), \
-        f"no profiler artifacts under {runs[0]['path']}"
-    # profile observatory: the window was read back at close
-    prof = eng.telemetry.last_profile
-    assert prof is not None, "window closed but no profile was ingested"
-    assert prof["total_slices"] > 0
-    assert prof["classes"]["compute"]["busy_us"] > 0
-    # the compile-time catalog joined: the step program is attributed (the
-    # module name varies by engine path — jit_loss_and_grad vs the ZeRO
-    # jit_local_loss_and_grad — so key on the joined watchdog program)
-    joined = {v.get("program") for v in prof["programs"].values()}
-    assert "loss_and_grad" in joined and "apply_update" in joined
+    seen = []   # after each step: (the trace dir exists, the window is open, it has closed)
+    for _ in range(3):   # before the window, inside it, past it
+        loss = eng(xs, ys); eng.backward(loss); eng.step()
+        seen.append((os.path.exists(trace_dir), eng.telemetry._trace_active,
+                     eng.telemetry._trace_done))
+    yield eng, trace_dir, seen
     eng.telemetry.close()
-    scalars = [json.loads(l) for l in
-               open(os.path.join(str(tmp_path), "prof", "scalars.jsonl"))]
-    tags = {s["tag"] for s in scalars}
-    for tag in ("Profile/compute_ms", "Profile/collective_ici_ms",
-                "Profile/collective_dcn_ms", "Profile/host_gap_ms",
-                "Profile/step_wall_ms", "Profile/exposed_ici_ms",
-                "Profile/exposed_dcn_ms"):
-        assert tag in tags, f"missing {tag}"
-    # summary carries the condensed per-step decomposition
+
+
+def test_trace_window_artifacts(trace_window):
+    eng, trace_dir, seen = trace_window
+    if eng.telemetry._trace_failed:
+        pytest.skip("profiler backend unavailable on this platform")
+    # step 0 is before the window (the trace dir must not even exist yet), the
+    # window closes with step 1, and step 2 finds it stopped and written
+    assert seen == [(False, False, False), (True, False, True), (True, False, True)]
+    # the profiler session lands in the run/host-namespaced subdir, and
+    # nowhere else under trace_dir
+    out_dir = eng.telemetry.trace_output_dir
+    assert os.listdir(trace_dir) == [os.path.basename(out_dir)]
+    assert os.path.basename(out_dir) == \
+        f"trace_{eng.telemetry.run_id}_host{eng.telemetry.host_id}"
     summary = eng.telemetry.summary()
-    assert summary["profile"] is not None
-    assert summary["profile"]["step_wall_ms"] > 0
-    assert summary["trace"]["done"] is True
-    # and the flight-recorder embedding sees the same report
-    snap = eng.telemetry.profile_snapshot()
-    assert snap["report"] is prof and snap["trace_failed"] is False
+    assert summary["trace"] == {"trace_dir": out_dir, "steps": [1, 2],
+                                "active": False, "done": True, "failed": False}
+
+
+def test_trace_window_is_read_by_the_benchmarks_reduction(trace_window):
+    """The package keeps no reader of a trace: the window's ``.xplane.pb`` is what
+    ``benchmarks/trace_reduce.py`` finds and loads, and the recorder's spans are in
+    it as annotations under their own names (docs/telemetry.md)."""
+    from benchmarks import trace_reduce
+    eng, _, _ = trace_window
+    if eng.telemetry._trace_failed:
+        pytest.skip("profiler backend unavailable on this platform")
+    path = trace_reduce.find_xplane(eng.telemetry.trace_output_dir)
+    assert path is not None and path.endswith(".xplane.pb")
+    trace = trace_reduce.load_xplane(path)
+    assert trace["devices"] == {}      # the CPU has no device plane; a chip's has "XLA Ops"
+    held = trace_reduce.describe_xplane(path, per_line=10 ** 6)
+    # (train.step opened before the window did: a trace holds what begins inside it)
+    for name in ("train.grad_program", "train.update_program"):
+        assert f"    {name} start=" in held, name
 
 
 def test_trace_dir_namespacing_and_legacy_layout(tmp_path):
@@ -348,26 +340,3 @@ def test_session_uses_engine_monitor_when_tensorboard_enabled(tmp_path):
     # engine training scalars and telemetry scalars share the sink
     assert "Train/Samples/train_loss" in tags
     assert "Telemetry/Samples/step_time_ms" in tags
-
-
-# --------------------------------------------------------------- profile
-# observatory (docs/profile.md): the ingest/scalars assertions ride the
-# trace window in test_trace_window_artifacts_and_profile_readback above;
-# here: the zero-instruction guarantee every observatory pins
-def test_profile_enabled_is_hlo_identical(tmp_path):
-    """telemetry.profile reads trace files back on the host — the lowered
-    step program must be instruction-identical with the block on or off."""
-    eng_off = _build(telemetry={"enabled": True,
-                                "output_path": str(tmp_path)})
-    eng_on = _build(telemetry={"enabled": True, "trace_steps": [1, 2],
-                               "trace_dir": os.path.join(str(tmp_path), "tr"),
-                               "profile": {"enabled": True},
-                               "output_path": str(tmp_path)})
-    xs, ys = _batch()
-    hlos = []
-    for eng in (eng_off, eng_on):
-        hlos.append(optimized_hlo(eng._jit_loss_and_grad, eng.params,
-                                  eng.scaler_state.cur_scale, xs, ys))
-    assert instruction_count(hlos[0]) > 0
-    assert instruction_count(hlos[0]) == instruction_count(hlos[1])
-    assert collective_counts(hlos[0]) == collective_counts(hlos[1])

@@ -48,7 +48,6 @@ def _run_engine(mesh, param_shardings, steps=3):
     return losses
 
 
-@pytest.mark.slow  # two engine builds (~23s); TP parity also pinned by the 3D test
 def test_gspmd_tp_matches_replicated(eight_devices):
     base = _run_engine(build_mesh(data=8, model=1, pipe=1), None)
 
