@@ -1,8 +1,11 @@
 """Block pieces that more than one model calls: the chunked LM-head cross-entropy, RMSNorm
 and rotary position embedding. Pure functions of arrays; no parameters of their own."""
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # Bytes of float32 logits the head's cross-entropy makes at once on a chip: what the TPU
@@ -179,17 +182,51 @@ def rms_norm(x, scale, eps, zero_centred=False):
     return (out * (1.0 + scale if zero_centred else scale)).astype(x.dtype)
 
 
-def rope(x, positions, theta, width=None):
+def rope_frequencies(head_dim, theta, scaling=None):
+    """``(inv_freq [head_dim / 2] float32, factor)``: the rotary frequencies as a value, and
+    what cos and sin are both multiplied by. ``scaling`` is a published ``rope_parameters``
+    entry (None or ``rope_type`` ``default``: ``theta^(-2i/D)`` and 1). ``yarn``: pair ``i``
+    turns ``factor`` times slower from the pair that makes ``beta_slow`` turns over
+    ``original_max_position_embeddings`` positions on, unchanged up to the pair that makes
+    ``beta_fast`` turns, a linear ramp between (its ends floored and ceiled unless
+    ``truncate`` is false), and cos and sin times ``attention_factor`` (``0.1 ln(factor) +
+    1`` where the key is absent). The table is static: it does not follow the length."""
+    D = head_dim
+    inv_freq = theta ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+    kind = (scaling or {}).get("rope_type", "default")
+    assert kind in ("default", "yarn"), f"rope_type {kind!r} is not built"
+    if kind == "default":
+        return inv_freq.astype(np.float32), 1.0
+    factor, original = scaling["factor"], scaling["original_max_position_embeddings"]
+
+    def pair_of(turns):         # the pair that makes ``turns`` turns over the original length
+        return D * math.log(original / (2 * math.pi * turns)) / (2 * math.log(theta))
+    low, high = pair_of(scaling.get("beta_fast", 32)), pair_of(scaling.get("beta_slow", 1))
+    if scaling.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, D - 1)
+    ramp = np.clip((np.arange(D // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = inv_freq * ((1.0 - ramp) + ramp / factor)
+    attention_factor = scaling.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(attention_factor)
+
+
+def rope(x, positions, theta, width=None, inv_freq=None, factor=1.0):
     """Rotary embedding in the half-split convention (``rotate_half``): ``x`` is
     ``[B, H, T, D]``, ``positions`` ``[T]``; pair ``i`` of the first and second half
     of the first ``width`` features (all ``D`` where None) turns by
     ``pos * theta^(-2i/width)``, the features past ``width`` pass unchanged. Angles and
-    the rotation in float32."""
+    the rotation in float32. ``inv_freq`` and ``factor`` (``rope_frequencies``) replace
+    ``theta``'s frequencies and scale cos and sin."""
     if width is not None and width < x.shape[-1]:
-        return jnp.concatenate([rope(x[..., :width], positions, theta), x[..., width:]], axis=-1)
+        return jnp.concatenate([rope(x[..., :width], positions, theta, None, inv_freq, factor),
+                                x[..., width:]], axis=-1)
     D = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]      # [T, D/2]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)

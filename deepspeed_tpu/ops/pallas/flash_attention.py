@@ -19,6 +19,11 @@ Design (v5e; measured rows in ``_resolve`` and PERF.md, PR 25):
   triangle touches is visited once, none wholly above the diagonal, and the mask runs
   only on tiles the diagonal crosses. ``_resolve`` picks square tiles (512; 1024 for
   non-causal calls and from T = 8192), so a diagonal tile holds nothing wholly masked.
+- ``window`` (static, causal calls): a query sees the ``window`` keys up to itself and the
+  schedule is a BAND (``band_k_loops`` / ``band_q_loops``): a q-tile starts at the first
+  k-tile the window's lower edge touches, the masked body (both edges) runs on the tiles
+  an edge crosses, the backward's loop over q-tiles ends where the window ends.
+  ``band_pairs`` counts what the schedule visits against what the mask allows.
 - each kernel computes its VMEM budget from T, D and the tile sizes (``_vmem_limit``);
   head_dim <= 256.
 - ``interpret=True`` fallback keeps CPU tests honest; a dense reference implementation
@@ -40,13 +45,17 @@ from .partition import shard_over_mesh
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def dense_attention(q, k, v, causal=False, sm_scale=None, bias=None, dropout_keep=None):
+def dense_attention(q, k, v, causal=False, sm_scale=None, bias=None, dropout_keep=None,
+                    window=None):
     """Reference dense attention ([B,H,T,D] inputs), fp32 softmax.
+
+    ``window``: a causal query ``i`` sees the keys ``i - window < j <= i`` only.
 
     ``bias``: additive key bias [B, 1, T_k] (the BERT padding mask).
     ``dropout_keep``: pre-scaled multiplicative mask on the post-softmax probs
     (e.g. from ``dropout_keep_reference``) — the numerics oracle for the kernel.
     """
+    assert window is None or causal, "a window belongs to a causal call"
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * sm_scale
@@ -55,6 +64,8 @@ def dense_attention(q, k, v, causal=False, sm_scale=None, bias=None, dropout_kee
     if causal:
         T = q.shape[2]
         mask = jnp.tril(jnp.ones((T, T), jnp.bool_))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, T), jnp.bool_), -window)
         scores = jnp.where(mask, scores, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_keep is not None:
@@ -135,7 +146,7 @@ def _read_seed_ref(seed_ref, seg):
     return seed_u32, map_q, map_k
 
 
-def causal_k_tiles(q_tile, block_q, block_k):
+def causal_k_tiles(q_tile, block_q, block_k, window=None):
     """The k-tiles a causal q-tile visits, as ``(n_full, last)``: tiles ``[0, n_full)``
     lie wholly on or below the diagonal (largest key <= smallest query: no mask),
     tiles ``[n_full, last)`` are crossed by it (masked body), and no tile from
@@ -143,28 +154,104 @@ def causal_k_tiles(q_tile, block_q, block_k):
     Python int (the schedule test) or a traced ``program_id`` (the forward kernel);
     ``last`` never passes the number of k-tiles when both tile sizes divide T.
 
+    With a ``window`` (query ``i`` sees the keys ``i - window < j <= i``) the triangle
+    becomes a band and the visit ``(first, full_from, n_full, last)``: no tile before
+    ``first`` holds an allowed key, tiles ``[first, full_from)`` are crossed by the
+    window's lower edge (masked), tiles ``[full_from, n_full)`` lie wholly inside the
+    band, tiles ``[n_full, last)`` are crossed by the diagonal. Where ``full_from``
+    passes ``n_full`` (a window smaller than the tiles) the tiles between are crossed
+    by both edges, and none is full: ``band_k_loops`` has the three loops.
+
     The indices are LOCAL — exact for segmented layouts too, because causal segmented
     calls require identical, monotone q/k segment maps (zigzag: both sides are the
     same [chunk i, chunk 2n-1-i] interleave), under which local order equals global
     order."""
     n_full = (q_tile * block_q + 1) // block_k
     last = ((q_tile + 1) * block_q + block_k - 1) // block_k
-    return n_full, last
+    if window is None:
+        return n_full, last
+    # the smallest key the tile's first query sees, and the smallest the last one sees
+    first = _bound(q_tile * block_q - window + 1, low=0) // block_k
+    full_from = (_bound((q_tile + 1) * block_q - window, low=0) + block_k - 1) // block_k
+    return first, full_from, n_full, last
 
 
-def causal_q_tiles(k_tile, block_q, block_k):
+def causal_q_tiles(k_tile, block_q, block_k, window=None):
     """The q-tiles that visit a causal k-tile, as ``(first, full_from)``: no q-tile
     before ``first`` holds an unmasked element against it, tiles ``[first, full_from)``
     are crossed by the diagonal, tiles from ``full_from`` on lie wholly on or below it
     (smallest query >= largest key). The backward kernel's view of the same schedule
-    as ``causal_k_tiles``."""
+    as ``causal_k_tiles``.
+
+    With a ``window``, ``(first, full_from, full_to, end)``: tiles ``[full_from,
+    full_to)`` lie wholly inside the band, tiles ``[full_to, end)`` are crossed by the
+    window's far edge (the last query that sees the tile's first key is ``window - 1``
+    past it), and no tile from ``end`` on sees the k-tile: the backward's loop ends there
+    (``band_q_loops`` holds it to the number of q-tiles)."""
     first = (k_tile * block_k) // block_q
     full_from = ((k_tile + 1) * block_k + block_q - 2) // block_q
-    return first, full_from
+    if window is None:
+        return first, full_from
+    full_to = (k_tile * block_k + window) // block_q
+    end = ((k_tile + 1) * block_k + window - 2) // block_q + 1
+    return first, full_from, full_to, end
+
+
+def _bound(x, low=None, top=None):
+    """``x`` no smaller than ``low`` and no larger than ``top``: a Python int where all three
+    are (the schedule test), else traced (``program_id`` in a kernel)."""
+    if all(isinstance(v, int) for v in (x, low, top) if v is not None):
+        x = x if low is None else max(x, low)
+        return x if top is None else min(x, top)
+    x = x if low is None else jnp.maximum(x, low)
+    return x if top is None else jnp.minimum(x, top)
+
+
+def band_k_loops(q_tile, block_q, block_k, window):
+    """A banded q-tile's visit as three loops over k-tiles, ``((lo, hi), masked)`` each:
+    the tiles an edge crosses run the masked body (which applies both edges), the ones
+    between run the plain one. Where the window is smaller than the tiles the middle
+    loop is empty and the masked ones meet."""
+    first, full_from, n_full, last = causal_k_tiles(q_tile, block_q, block_k, window)
+    m1 = _bound(full_from, top=last)
+    m2 = _bound(n_full, low=m1)
+    return ((first, m1), True), ((m1, m2), False), ((m2, last), True)
+
+
+def band_q_loops(k_tile, block_q, block_k, window, num_q_tiles):
+    """The backward's view of ``band_k_loops``: a k-tile's visiting q-tiles as three loops."""
+    first, full_from, full_to, end = causal_q_tiles(k_tile, block_q, block_k, window)
+    end = _bound(end, top=num_q_tiles)
+    m1 = _bound(full_from, top=end)
+    m2 = _bound(full_to, low=m1, top=end)
+    return ((first, m1), True), ((m1, m2), False), ((m2, end), True)
+
+
+def band_pairs(T, block_q, block_k, window):
+    """``(visited, needed)`` query-key pairs of one causal call over ``T`` positions: what
+    the tiles of the schedule above hold, and what the band (the triangle where ``window``
+    is None) holds. Plain integers; a counter that costs nothing in a program."""
+    visited = 0
+    for i in range(T // block_q):
+        if window is None:
+            visited += causal_k_tiles(i, block_q, block_k)[1] * block_q * block_k
+        else:
+            loops = band_k_loops(i, block_q, block_k, window)
+            visited += sum(hi - lo for (lo, hi), _ in loops) * block_q * block_k
+    w = T if window is None else min(window, T)
+    return visited, w * (w + 1) // 2 + (T - w) * w
 
 
 _NT = (((1,), (1,)), ((), ()))     # dot_general dimension numbers of A.B^T
 _TN = (((0,), (0,)), ((), ()))     # ... and of A^T.B
+
+
+def _seen(q_pos, k_pos, window):
+    """Where a causal query sees a key: on or below the diagonal and, with a ``window``,
+    fewer than ``window`` positions back."""
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
 def _tile_positions(k_start, q_start, shape, maps):
@@ -186,7 +273,7 @@ def _split_refs(refs, has_seed, has_bias):
 
 
 def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, threshold,
-                has_seed, seg):
+                has_seed, seg, window=None):
     """Grid cell ``(b, i)`` holds q-tile ``i`` and walks the k-tiles it sees with the
     running (m, l, acc) of the online softmax. The tile is computed TRANSPOSED,
     S^T = K.Q^T as [block_k, block_q], so the softmax's per-query statistics are
@@ -214,7 +301,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
         maps = None
     if rate > 0:
         inv_keep = 1.0 / (1.0 - rate)
-    if causal:
+    if causal and window is None:
         n_full, last_blk = causal_k_tiles(q_blk_idx, bq, block_k)
     else:
         n_full = last_blk = seq_len // block_k
@@ -235,7 +322,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
                 q_glob, k_glob = _tile_positions(kb * block_k, q_blk_idx * bq,
                                                  (block_k, bq), maps)
             if masked:
-                s = jnp.where(q_glob >= k_glob, s, DEFAULT_MASK_VALUE)
+                s = jnp.where(_seen(q_glob, k_glob, window), s, DEFAULT_MASK_VALUE)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp2(s - m_new)
             alpha = jnp.exp2(m - m_new)
@@ -249,9 +336,18 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
             return m_new, l_new, acc * alpha + pv
         return body
 
-    carry = jax.lax.fori_loop(0, n_full, make_body(False), (m0, l0, acc0))
-    if causal:
-        carry = jax.lax.fori_loop(n_full, last_blk, make_body(True), carry)
+    if window is not None:
+        # a band: the tiles an edge crosses masked, the ones between plain. A query whose
+        # keys in the first tile are all masked carries m = the mask's value and weights
+        # of one until its first allowed key arrives, whose alpha = exp2(mask - score) is
+        # exactly zero: nothing of them is left (every query sees itself)
+        carry = (m0, l0, acc0)
+        for (lo, hi), masked in band_k_loops(q_blk_idx, bq, block_k, window):
+            carry = jax.lax.fori_loop(lo, hi, make_body(masked), carry)
+    else:
+        carry = jax.lax.fori_loop(0, n_full, make_body(False), (m0, l0, acc0))
+        if causal:
+            carry = jax.lax.fori_loop(n_full, last_blk, make_body(True), carry)
     m, l, acc = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[...] = (acc / l).T.astype(o_ref.dtype)
@@ -348,10 +444,12 @@ def _bwd_vmem_bytes(T, D, block_q, block_k, itemsize):
             + 6 * block_q * block_k * 4)
 
 
-def _flash_fwd(q, k, v, seed, bias, sm_scale, causal, rate, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, seed, bias, sm_scale, causal, rate, block_q, block_k, interpret,
+               window=None):
     return _per_shard(
         functools.partial(_flash_fwd_local, sm_scale=sm_scale, causal=causal, rate=rate,
-                          block_q=block_q, block_k=block_k, interpret=interpret),
+                          block_q=block_q, block_k=block_k, interpret=interpret,
+                          window=window),
         (q, k, v), seed, bias, rate)
 
 
@@ -364,7 +462,7 @@ def _kv_head(group):
 
 
 def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, block_k,
-                     interpret):
+                     interpret, window=None):
     B, H, T, D = q.shape
     group = H // k.shape[1]          # query heads a key/value head serves, side by side
     grid = (B * H, pl.cdiv(T, block_q))
@@ -375,7 +473,8 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_k=block_k, seq_len=T, has_bias=bias is not None,
                                rate=rate, threshold=_keep_threshold(rate),
-                               has_seed=seed is not None, seg=_is_segmented(seed))
+                               has_seed=seed is not None, seg=_is_segmented(seed),
+                               window=window)
     aux, aux_specs = _aux_operands(seed, bias, B, H, T, rate)
     call = pl.pallas_call(
         kernel,
@@ -412,7 +511,7 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, threshold,
-                has_seed, seg):
+                has_seed, seg, window=None):
     """One pass over the tiles of one (batch, head): grid cell ``(b, j)`` holds k-tile
     ``j`` and walks the q-tiles that see it; every visit computes the tile once (five
     matmuls, one ``exp2``) and feeds all three gradients. dK and dV of the k-tile ride
@@ -468,7 +567,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, thres
                 q_glob, k_glob = _tile_positions(k_blk_idx * bk, qb * block_q,
                                                  (bk, block_q), maps)
             if masked:
-                s = jnp.where(q_glob >= k_glob, s, DEFAULT_MASK_VALUE)
+                s = jnp.where(_seen(q_glob, k_glob, window), s, DEFAULT_MASK_VALUE)
             p = jnp.exp2(s - lse2)
             if rate > 0:
                 bits = _dropout_bits(seed_u32, bh_u32, q_glob, k_glob)
@@ -487,7 +586,11 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, thres
         return body
 
     init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32))
-    if causal:
+    if window is not None:
+        dk, dv = init
+        for (lo, hi), masked in band_q_loops(k_blk_idx, block_q, bk, window, num_q_blocks):
+            dk, dv = jax.lax.fori_loop(lo, hi, make_body(masked), (dk, dv))
+    elif causal:
         first_blk, full_from = causal_q_tiles(k_blk_idx, block_q, bk)
         carry = jax.lax.fori_loop(first_blk, full_from, make_body(True), init)
         dk, dv = jax.lax.fori_loop(full_from, num_q_blocks, make_body(False), carry)
@@ -502,7 +605,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, thres
 
 
 def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, interpret,
-               g_lse=None):
+               g_lse=None, window=None):
     q, k, v, out, lse = res
     do = g
     # delta = rowsum(do * o): the softmax-normalization correction term (valid under
@@ -515,12 +618,13 @@ def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, int
         delta = delta - g_lse.astype(jnp.float32)
     return _per_shard(
         functools.partial(_flash_bwd_local, sm_scale=sm_scale, causal=causal, rate=rate,
-                          block_q=block_q, block_k=block_k, interpret=interpret),
+                          block_q=block_q, block_k=block_k, interpret=interpret,
+                          window=window),
         (q, k, v, do, lse, delta), seed, bias, rate)
 
 
 def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, rate,
-                     block_q, block_k, interpret):
+                     block_q, block_k, interpret, window=None):
     B, H, T, D = q.shape
     group = H // k.shape[1]
     q3 = q.reshape(B * H, T, D)
@@ -543,7 +647,8 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
         functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, seq_len=T, has_bias=bias is not None,
                           rate=rate, threshold=_keep_threshold(rate),
-                          has_seed=seed is not None, seg=_is_segmented(seed)),
+                          has_seed=seed is not None, seg=_is_segmented(seed),
+                          window=window),
         grid=(B * H, T // block_k),
         in_specs=aux_specs + [whole, kv_tile, kv_tile, whole, row, row],
         out_specs=[whole, tile, tile],
@@ -568,15 +673,15 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
 # public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_attention_core(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k,
-                          interpret):
+                          interpret, window=None):
     out, _ = _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k,
-                            interpret)
+                            interpret, window)
     return out
 
 
-def _resolve(q, sm_scale, block_q, block_k, causal, interpret):
+def _resolve(q, sm_scale, block_q, block_k, causal, interpret, window=None):
     T = q.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -592,7 +697,18 @@ def _resolve(q, sm_scale, block_q, block_k, causal, interpret):
         #   [1, 16, 8192] full:   512 3.70 / 7.34   1024 3.14 / 6.81
         # A tile under 512 leaves the MXU waiting on the loop; past 512 a causal call
         # pays for the masked half of ever larger diagonal tiles until T is long.
+        # A windowed call (PR 45; ``--rows band``: D = 128, 32 query heads over 4 key/value
+        # heads, bf16, [1, 32 over 4, 8192], forward / backward):
+        #   window 1024: 256 3.31 / 3.58   512 1.86 / 3.60   1024 2.22 / 4.18
+        #                512x256 2.23 / 3.73   256x512 3.20 / 3.84   1024x512 2.15 / 4.17
+        #   no window:   256 10.64 / 10.48   512 4.91 / 9.12   1024 4.68 / 8.87
+        # The band visits 1.25 / 1.50 / 2.00 times the pairs it needs at 256 / 512 / 1024:
+        # 512 wins both ways (the forward of 256-tiles waits on its loop, 1024-tiles run a
+        # masked body on every tile they visit), so a windowed call takes 512 at most: the
+        # choice follows ``window`` alone, no key and no environment variable.
         side = 1024 if not causal or T >= 8192 else 512
+        if window is not None:
+            side = min(side, 512)
         block_q = block_q or side
         block_k = block_k or side
 
@@ -611,12 +727,13 @@ def _resolve(q, sm_scale, block_q, block_k, causal, interpret):
 
 
 def _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k,
-                   interpret):
+                   interpret, window=None):
     sm_scale_, bq, bk, interp = _resolve(q, sm_scale, block_q, block_k, causal,
-                                         interpret)
+                                         interpret, window)
     assert q.shape[2] % bq == 0 and q.shape[2] % bk == 0, \
         f"seq_len {q.shape[2]} must be divisible by block sizes ({bq}, {bk})"
-    out, lse = _flash_fwd(q, k, v, seed, bias, sm_scale_, causal, rate, bq, bk, interp)
+    out, lse = _flash_fwd(q, k, v, seed, bias, sm_scale_, causal, rate, bq, bk, interp,
+                          window)
     # Name what a jax.checkpoint round the call may keep, and hand the NAMED ``out`` on as
     # the primal too, so that the residual and the value the caller goes on with are one
     # variable. A name on the residual alone keeps the backward kernels' operand and still
@@ -630,12 +747,12 @@ def _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k
     return out, (q, k, v, out, checkpoint_name(lse, "attn_lse"), bias, seed)
 
 
-def _core_bwd_rule(causal, sm_scale, rate, block_q, block_k, interpret, res, g):
+def _core_bwd_rule(causal, sm_scale, rate, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse, bias, seed = res
     sm_scale_, bq, bk, interp = _resolve(q, sm_scale, block_q, block_k, causal,
-                                         interpret)
+                                         interpret, window)
     dq, dk, dv = _flash_bwd((q, k, v, out, lse), g, seed, bias, sm_scale_, causal, rate,
-                            bq, bk, interp)
+                            bq, bk, interp, window=window)
     # bias is the (non-trainable) padding mask: cotangent is zero by contract; seed is
     # integer-valued, whose tangent space is float0
     dbias = None if bias is None else jnp.zeros_like(bias)
@@ -711,7 +828,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
                              interpret: Optional[bool] = None,
                              dropout_rate: float = 0.0, dropout_seed=None,
                              dropout_q_offset=0, dropout_k_offset=0,
-                             q_segments=None, k_segments=None):
+                             q_segments=None, k_segments=None, window=None):
     """Flash attention returning ``(out, lse)``, BOTH differentiable.
 
     ``lse`` is the per-row log-sum-exp of the scaled scores ([B, H, T_q], natural
@@ -733,6 +850,11 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     bounds exact); offsets may be traced. Overrides ``dropout_*_offset`` for the
     segmented side.
     """
+    if window is not None:
+        raise ValueError(
+            f"flash_attention_with_lse: a window of {window}: the partial-attention path "
+            "(the chunked long-context kernel, the ring and its zigzag segments) has no "
+            "band; it would run the whole triangle")
     rate = float(dropout_rate)
     if rate > 0:
         assert dropout_seed is not None, "dropout_rate > 0 requires a dropout_seed"
@@ -814,7 +936,8 @@ def _chunk_for(T: int) -> int:
 def flash_attention(q, k, v, causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    bias=None, dropout_rate: float = 0.0, dropout_seed=None):
+                    bias=None, dropout_rate: float = 0.0, dropout_seed=None,
+                    window: Optional[int] = None):
     """Blocked flash attention on [B, H, T, D] tensors. Differentiable in q/k/v.
 
     ``bias``: optional additive key bias, any shape squeezable to [B, T_k] (the BERT
@@ -827,11 +950,22 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: Optional[float] = N
     probabilities (csrc/transformer/dropout_kernels.cu); the seed is a traced operand so
     remat replays identical masks. ``dropout_keep_reference`` reproduces the exact mask
     for parity tests.
+    ``window``: static; a causal query ``i`` sees the keys ``i - window < j <= i`` (a
+    sliding-window layer): the tile schedule is a band (``band_k_loops``) and tiles outside
+    it are never visited. Causal calls at ``T <= 8192`` only.
     """
     rate = float(dropout_rate)
     if rate > 0:
         assert dropout_seed is not None, "dropout_rate > 0 requires a dropout_seed"
     T_k = k.shape[2]
+    if window is not None:
+        assert causal and int(window) >= 1, "a window belongs to a causal call, and is >= 1"
+        window = int(window)
+        if T_k > _RESIDENT_T_LIMIT:
+            raise ValueError(
+                f"flash_attention: a window of {window} at seq_len {T_k}: the chunked "
+                f"long-context path (T > {_RESIDENT_T_LIMIT}) has no band; it would run the "
+                "whole triangle")
     if T_k > _RESIDENT_T_LIMIT and not (interpret or jax.default_backend() != "tpu"):
         # Past the resident kernel's scoped-VMEM ceiling (the K/V operands are
         # whole-sequence-resident regardless of block sizes): decompose into chunk
@@ -865,4 +999,4 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: Optional[float] = N
         # would otherwise silently train with zero gradient (see docstring)
         bias = jax.lax.stop_gradient(jnp.asarray(bias, jnp.float32).reshape(B, 1, T_k))
     return _flash_attention_core(q, k, v, bias, seed, bool(causal), sm_scale, rate,
-                                 block_q, block_k, interpret)
+                                 block_q, block_k, interpret, window)

@@ -254,7 +254,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
                    sm_scale: Optional[float] = None,
                    interpret: Optional[bool] = None,
                    dropout_rate: float = 0.0, dropout_seed=None,
-                   schedule: str = "zigzag"):
+                   schedule: str = "zigzag", window: Optional[int] = None):
     """Attention over a sequence sharded on ``axis_name`` (call inside shard_map).
 
     Args:
@@ -272,10 +272,14 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
       schedule: causal schedule, ``"zigzag"`` (balanced, no masked-compute tax;
         default) or ``"masked"`` (contiguous layout, kept as the oracle).
         Ignored when ``causal=False``.
+      window: a sliding window is refused here (``ValueError``): the ring has no band.
     Returns the LOCAL [B, H, T_local, D] attention output (same layout as the
     inputs). Differentiable in q/k/v.
     """
     assert schedule in SCHEDULES, f"schedule must be one of {SCHEDULES}, got {schedule!r}"
+    if window is not None:
+        raise ValueError(f"ring_attention: a window of {window}: the ring (schedule "
+                         f"{schedule!r}) has no band; it would run the whole triangle")
     if causal and schedule == "zigzag":
         return _zigzag_ring(q, k, v, axis_name, sm_scale, interpret,
                             dropout_rate, dropout_seed)

@@ -42,6 +42,22 @@ def pytest_configure(config):
 # sort: relative order within each group is unchanged.
 _HEAVY_FILES = ("test_ring_attention.py", "test_ring_zigzag.py")
 
+# Under xdist (``-n 6 --dist loadfile``, the driver's command) a file is one unit of work,
+# handed out in collection order to whichever worker is free: a file of five to ten minutes
+# that starts late ends the run alone while five workers idle. There the longest files go
+# FIRST, longest first (their seconds in the run of PR 43's tree), the ring suites among
+# them, and the hundreds of short files fill the workers' ends evenly.
+_LONGEST_FIRST = ("test_qwen3_next.py", "test_tpu_aot_compile.py", "test_nemotron_h.py",
+                  "test_rehearsal_hybrid.py", "test_granite_hybrid.py", "test_ring_attention.py",
+                  "test_moe.py", "test_rehearsal_ssm_moe.py", "test_rehearsal_ssm.py",
+                  "test_ring_zigzag.py", "test_launcher.py", "test_olmoe.py", "test_ouro.py",
+                  "test_flash_attention.py")
+
 
 def pytest_collection_modifyitems(config, items):
-    items.sort(key=lambda item: os.path.basename(str(item.fspath)) in _HEAVY_FILES)
+    name = lambda item: os.path.basename(str(item.fspath))      # noqa: E731
+    if hasattr(config, "workerinput"):          # an xdist worker: every worker sorts alike
+        rank = {f: i for i, f in enumerate(_LONGEST_FIRST)}
+        items.sort(key=lambda item: rank.get(name(item), len(rank)))
+    else:
+        items.sort(key=lambda item: name(item) in _HEAVY_FILES)

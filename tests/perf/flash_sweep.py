@@ -1,7 +1,7 @@
 """Flash-attention tile sweep on the chip: device milliseconds a call of each kernel,
 forward and backward apart, read from a profiler trace by the kernels' own names.
 
-    python tests/perf/flash_sweep.py [--rows cell,long,other] [--picked] [--out chiprun_out/flash_sweep.jsonl]
+    python tests/perf/flash_sweep.py [--rows cell,long,other,band] [--picked] [--out chiprun_out/flash_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent
 unpacked beside this one) it measures that tree's kernels with the same rows:
@@ -11,7 +11,9 @@ unpacked beside this one) it measures that tree's kernels with the same rows:
 A row is a shape [B, H, T, D] in bf16, causal or not, and a list of (block_q, block_k);
 ``None`` is what ``_resolve`` picks. The share of the roofline is the required
 operations (4.B.H.T^2.D a forward, half of it causal; twice that a backward, as
-``benchmarks/flops.py`` counts) over 197 TF/s over the measured time.
+``benchmarks/flops.py`` counts) over 197 TF/s over the measured time. A row may add
+``(key/value heads, window)``: grouped heads, and a sliding window, whose required
+operations are the pairs inside the band (``band_pairs``'s ``needed``).
 """
 
 import argparse
@@ -46,6 +48,10 @@ ROWS = {
     "other": [((8, 16, 512, 64), False, [None] + SQUARE[1:] + [(256, 512)]),
               ((4, 16, 2048, 64), True, [None] + SQUARE[1:] + [(1024, 1024), (256, 512)]),
               ((2, 8, 2048, 128), True, [None] + SQUARE[1:] + [(1024, 1024)])],
+    # a sliding-window layer and a full one of mellum2_ep4_d4_train_1chip: 32 query heads over
+    # 4 key/value heads of 128 at 8192 positions, a window of 1024 and none
+    "band": [((1, 32, 8192, 128), True, [None] + SQUARE[1:] + [(1024, 1024)] + more, 4, window)
+             for window, more in ((1024, [(512, 256), (256, 512), (1024, 512)]), (None, []))],
 }
 
 
@@ -86,18 +92,23 @@ def kernel_ms(fn, args, calls=8):
     return {name: 1e3 * s / calls for name, s in seconds.items()}
 
 
-def sweep_row(shape, causal, tiles, emit, tag="", passes=("fwd", "bwd")):
+def sweep_row(shape, causal, tiles, emit, tag="", passes=("fwd", "bwd"), kv_heads=None,
+              window=None):
     B, H, T, D = shape
     rng = np.random.default_rng(0)
-    q, k, v, do = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(4))
+    kv_shape = (B, kv_heads or H, T, D)
+    q, do = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=kv_shape), jnp.bfloat16) for _ in range(2))
     need = 4.0 * B * H * T * T * D * (0.5 if causal else 1.0)
+    if window is not None:
+        need = 4.0 * B * H * D * fa.band_pairs(T, T, T, window)[1]
     for tile in tiles:
-        sm_scale, bq, bk, _ = fa._resolve(q, None, *(tile or (None, None)), causal, False)
+        sm_scale, bq, bk, _ = fa._resolve(q, None, *(tile or (None, None)), causal, False, window)
         common = dict(shape=list(shape), causal=causal, block_q=bq, block_k=bk,
-                      picked=tile is None, tag=tag)
+                      picked=tile is None, tag=tag, kv_heads=kv_shape[1], window=window)
         try:
             fwd = lambda q, k, v: fa._flash_fwd(q, k, v, None, None, sm_scale, causal, 0.0,
-                                                bq, bk, False)
+                                                bq, bk, False, window)
             if "fwd" in passes:
                 ms = kernel_ms(fwd, (q, k, v))
                 total = sum(ms.values())
@@ -109,7 +120,7 @@ def sweep_row(shape, causal, tiles, emit, tag="", passes=("fwd", "bwd")):
             delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
             bwd = lambda q, k, v, do, lse, delta: fa._flash_bwd_local(
                 q, k, v, do, lse, delta, None, None, sm_scale=sm_scale, causal=causal,
-                rate=0.0, block_q=bq, block_k=bk, interpret=False)
+                rate=0.0, block_q=bq, block_k=bk, interpret=False, window=window)
             ms = kernel_ms(bwd, (q, k, v, do, lse, delta))
             total = sum(ms.values())
             emit(dict(common, pass_="bwd", ms=total, kernels=ms,
@@ -135,14 +146,15 @@ def main():
                 print(f"{rec['shape']} causal={int(rec['causal'])} bq={rec['block_q']} "
                       f"bk={rec['block_k']}: {rec['error']}", flush=True)
                 return
-            print(f"{rec['shape']} causal={int(rec['causal'])} {rec['pass_']} "
+            print(f"{rec['shape']} kv={rec['kv_heads']} w={rec['window']} causal={int(rec['causal'])} {rec['pass_']} "
                   f"bq={rec['block_q']:5d} bk={rec['block_k']:5d}{' *' if rec['picked'] else '  '} "
                   f"{rec['ms']:8.4f} ms {rec['roofline']:6.2f} % {rec['tag']} "
                   + " ".join(f"{n}={t:.4f}" for n, t in sorted(rec["kernels"].items())),
                   flush=True)
         for name in args.rows.split(","):
-            for shape, causal, tiles in ROWS[name]:
-                sweep_row(shape, causal, [None] if args.picked else tiles, emit)
+            for shape, causal, tiles, *more in ROWS[name]:
+                sweep_row(shape, causal, [None] if args.picked else tiles, emit,
+                          **dict(zip(("kv_heads", "window"), more)))
 
 
 if __name__ == "__main__":

@@ -1,5 +1,5 @@
 """sha256 of the ENGINE's lowered step programs (gradient and update program, and the fused step)
-for the accepted hybrid and expert models at the tests' small sizes, source locations taken out:
+for the accepted models (GPT-2 and Nemotron-H among them) at the tests' small sizes, source locations taken out:
 run it on two trees and compare the lines (a PR that says "the programs of the accepted cells are
 unchanged" shows it so): python tests/perf/step_program_digest.py <root of a checkout>"""
 import hashlib
@@ -18,6 +18,7 @@ import numpy as np  # noqa: E402
 
 import deepspeed_tpu  # noqa: E402
 import test_granite_hybrid as granite, test_olmoe as olmoe, test_ouro as ouro, test_qwen3_next as qwen  # noqa: E401,E402
+import test_nemotron_h as nemotron  # noqa: E402
 
 
 def digest(jitted, *args):
@@ -31,6 +32,19 @@ def models():
         yield name, model, params, mod.batch()
     _, model, params = olmoe.build(2)
     yield "olmoe", model, params, olmoe.batch()
+    _, model, params = nemotron.build(nemotron.published(stand_in=True), remat=True)
+    yield "nemotronh_stand_in_remat", model, params, nemotron.batch()
+    yield ("gpt2_flash",) + gpt2()
+
+
+def gpt2():
+    """GPT-2 at the cell benchmark's toy sizes (``tests/cellbench/tiny.py``), the flash kernel on."""
+    import jax
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    model = GPT2Model(GPT2Config(vocab_size=256, n_positions=64, n_embd=32, n_layer=2, n_head=2,
+                                 use_flash_attention=True, loss_chunk=16))
+    tokens = np.random.default_rng(0).integers(0, 250, (8, 64)).astype(np.int32)
+    return model, model.init(jax.random.PRNGKey(0)), (tokens, np.roll(tokens, -1, 1))
 
 
 for fused in (False, True):

@@ -83,6 +83,19 @@ def test_grouped_query_flash_attention_compiles_for_v5e(chip, backward):
     assert "tpu_custom_call" in text
 
 
+def test_sliding_window_flash_attention_compiles_for_v5e(chip):
+    """A sliding-window layer of ``mellum2_ep4_d4_train_1chip``: 32 query heads of 128 over 4
+    key/value heads at 8,192 positions under a window of 1,024, forward and backward, at the
+    tiles ``_resolve`` picks for a windowed call (the band's three loops a kernel)."""
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, interpret=False, window=1024)
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn), q, kv, kv)
+    assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("T", [8192, 1024])
 def test_the_delta_rule_kernels_compile_for_v5e(chip, T, dtype):
