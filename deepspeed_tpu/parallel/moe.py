@@ -339,9 +339,16 @@ def _take_rows_fwd(x, tok, inverse):
     return x[tok], inverse
 
 
-def _take_rows_bwd(inverse, dxs):
+def _rows_of_the_tokens(rows, inverse):
+    """``[k, n, H]``: the sorted row of every token's j-th assignment, slot by slot. The gather's
+    ``[k n, H]`` is that as it lies whatever ``k`` (``n`` fills the tiles), where ``[n, k, H]``
+    is a relayout of all the rows at a ``k`` the sublane tile of eight does not divide."""
     n, k = inverse.shape
-    dx = jnp.sum(dxs[inverse.reshape(-1)].reshape(n, k, -1).astype(jnp.float32), axis=1)
+    return rows[inverse.T.reshape(-1)].reshape(k, n, -1)
+
+
+def _take_rows_bwd(inverse, dxs):
+    dx = jnp.sum(_rows_of_the_tokens(dxs, inverse).astype(jnp.float32), axis=0)
     return dx.astype(dxs.dtype), None, None
 
 
@@ -350,25 +357,29 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 @jax.custom_vjp
 def _combine_rows(ys, weights, inverse, order):
-    """Combine: ``y[n] = sum_j weights[n, j] * ys[inverse[n, j]]``; ``order`` ``[n * k]`` is
-    the flat ``n * k + j`` of each sorted row, for the cotangent's one gather."""
+    """Combine: ``y[n] = sum_j weights[n, j] * ys[inverse[n, j]]``; ``order`` ``[n * k]`` is the
+    flat ``n * k + j`` of each sorted row, for the cotangent's one gather, of ``dy x weights``."""
     return _combine_rows_fwd(ys, weights, inverse, order)[0]
 
 
 def _combine_rows_fwd(ys, weights, inverse, order):
-    n, k = inverse.shape
-    # each token's k expert outputs side by side: what the backward keeps, not ``ys``
-    mine = checkpoint_name(ys[inverse.reshape(-1)].reshape(n, k, -1), "ds_moe_out")
-    y = jnp.einsum("nkh,nk->nh", mine.astype(jnp.float32), weights)
+    # each token's k expert outputs: what the backward keeps, not ``ys``
+    mine = checkpoint_name(_rows_of_the_tokens(ys, inverse), "ds_moe_out")
+    y = jnp.einsum("knh,nk->nh", mine.astype(jnp.float32), weights)
     return y.astype(ys.dtype), (mine, weights, order)
 
 
 def _combine_rows_bwd(res, dy):
     mine, weights, order = res
+    n, k = weights.shape
     dyf = dy.astype(jnp.float32)
-    spread = (dyf[:, None, :] * weights[:, :, None]).astype(mine.dtype)       # [n, k, H]
-    dys = spread.reshape(-1, spread.shape[-1])[order]
-    dw = jnp.einsum("nkh,nh->nk", mine.astype(jnp.float32), dyf).astype(weights.dtype)
+    # ``dy x weights`` with a token's k rows padded to the sublane tile of eight (zero weights):
+    # ``[n, slots, H]`` is ``[n slots, H]`` as it lies, so broadcast, product and cast fuse into
+    # one pass; ``[n, 6, H]`` is a float32 broadcast, a relayout and a product, a pass each
+    slots = -(-k // 8) * 8
+    spread = (dyf[:, None, :] * jnp.pad(weights, ((0, 0), (0, slots - k)))[:, :, None]).astype(mine.dtype)
+    dys = spread.reshape(n * slots, -1)[order // k * slots + order % k]
+    dw = jnp.einsum("knh,nh->nk", mine.astype(jnp.float32), dyf).astype(weights.dtype)
     return dys, dw, None, None
 
 
@@ -667,7 +678,9 @@ class DroplessMoE:
     does above: ONE sort by stand-in expert, one gather of the rows, one grouped matmul a
     product over the ``count`` groups, one gather back, the first product's output and each
     token's ``k`` expert outputs kept for the backward (``ds_moe_gate_up``, ``ds_moe_out``:
-    names a recomputed layer around this one may keep too) and cotangents that are gathers.
+    names a recomputed layer around this one may keep too; the second ``[k, n, H]``, a token's
+    outputs slot by slot) and cotangents that are gathers: the sorted rows' from ``dy x weights``
+    laid out a token's slots side by side, the tokens' from the sorted rows of the first product's.
     No passes, no branch, no scatter; the buffers are ``n * k`` rows, which the passes avoid
     for a held range that may see a sixteenth of them.
 

@@ -298,13 +298,13 @@ def kept_by_the_layers(what, stand_in=False):
     _, model, params = build(published(stand_in=stand_in), remat=True)
     tokens, _ = batch(seed=5, rows=2)
     with keeping(what):
-        return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params, rows=(80, 240))
+        return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params, rows=(3, 240))
 
 
 @pytest.mark.parametrize("shape, count", [
     ((2, 4, 40, 16), ATTENTIONS), ((2, 4, 40), ATTENTIONS), ((2, 40, 2 * 32 + 2 * 2 * 16 + 4), MIXERS),
     ((2, 40, 4), MIXERS), ((2, 40, 40), EXPERT_LAYERS), ((2, 40, 32), MIXERS + EXPERT_LAYERS + ATTENTIONS + 1),
-    ((240, 24), EXPERT_LAYERS), ((80, 3, 32), 0)],
+    ((240, 24), EXPERT_LAYERS), ((3, 80, 32), 0)],
     ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input", "ds_moe_gate_up", "ds_moe_out"])
 def test_a_layer_keeps_each_named_tensor_once(shape, count):
     """The residuals of the layers by shape: the attention keeps ONE kernel output (no second
@@ -313,13 +313,13 @@ def test_a_layer_keeps_each_named_tensor_once(shape, count):
     product's output once, every layer its input, and nothing else (the last layer's output is
     ``norm_f``'s to keep); under ``policy=None`` the inputs alone. Where the held experts stand
     in, an expert layer keeps the first grouped product's output ``[n k, F]`` besides, once, and
-    not each token's ``k`` expert outputs ``[n, k, H]`` (a set that names them too keeps them once);
+    not each token's ``k`` expert outputs ``[k, n, H]`` (a set that names them too keeps them once);
     where they do not, the passes name nothing and keep nothing."""
-    rows = shape in ((240, 24), (80, 3, 32))
+    rows = shape in ((240, 24), (3, 80, 32))
     found = kept_by_the_layers("the-kept-set", True)
     assert found[shape] == count, found
     assert sum(found.values()) == 3 * MIXERS + 3 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, found
-    assert kept_by_the_layers(ALL_THE_ROWS_KEPT, True) == found + collections.Counter({(80, 3, 32): EXPERT_LAYERS})
+    assert kept_by_the_layers(ALL_THE_ROWS_KEPT, True) == found + collections.Counter({(3, 80, 32): EXPERT_LAYERS})
     left_out = kept_by_the_layers("the-kept-set")
     assert left_out[shape] == (0 if rows else count), left_out
     assert sum(left_out.values()) == 3 * MIXERS + 2 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, left_out

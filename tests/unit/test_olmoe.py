@@ -4,6 +4,8 @@ and meshes of two and four devices with the experts split, top-2 and top-8; a ro
 sends every token to one expert; the grouped matmul, whole and in pieces, against a
 per-expert loop; the experts' exchange against the collectives it stands for."""
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +16,8 @@ import deepspeed_tpu
 from benchmarks.reference import olmoe_reference as ref
 from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
 from deepspeed_tpu.parallel.mesh import build_mesh
-from deepspeed_tpu.parallel.moe import DroplessMoE, experts_matmul, gather_pieces, piece_firsts
+from deepspeed_tpu.parallel.moe import (DroplessMoE, _combine_rows, _take_rows, experts_matmul, gather_pieces,
+                                        piece_firsts)
 from deepspeed_tpu.utils import spans
 from test_ouro import equations_by_path
 
@@ -219,6 +222,50 @@ def test_grouped_matmul_matches_a_per_expert_loop(sizes, pieces, backward):
     for a, b in zip(jax.grad(lambda l, r: jnp.sum(grouped(l, r) * cot), (0, 1))(lhs, rhs),
                     jax.grad(lambda l, r: jnp.sum(loop(l, r) * cot), (0, 1))(lhs, rhs)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("k", [6, 8])
+def test_the_row_paths_cotangents_are_the_plain_formulas(k, dtype):
+    """``_take_rows`` and ``_combine_rows`` against ``x[tok]`` and ``einsum("nkh,nk->nh",
+    ys[inverse], weights)`` under ``jax.vjp``: the rows' cotangent, the weights' and the tokens'.
+    The rows' is also, bit for bit, what laying ``dy x weights`` out as ``[n, k, H]`` in the
+    compute dtype and gathering it by ``order`` gave (the form until PR 46, which pads a token's
+    ``k`` rows to eight: at six the padding is there, at eight it is not)."""
+    n, H, E = 40, 24, 5
+    rng = np.random.default_rng(k)
+    sent_to = jnp.asarray(rng.integers(0, E, size=n * k), jnp.int32)
+    slots = jnp.arange(n * k, dtype=jnp.int32)
+    order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)[1]
+    inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
+    tok = order // k
+    x = jnp.asarray(rng.normal(size=(n, H)), dtype)
+    ys = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
+    weights = jnp.asarray(rng.random(size=(n, k)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(n, H)), dtype)
+    dxs = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
+
+    def plain_combine(ys, weights):
+        return jnp.einsum("nkh,nk->nh", ys[inverse].astype(jnp.float32), weights).astype(ys.dtype)
+
+    tolerance = 1e-5 if dtype == jnp.float32 else 3e-2
+    y, pull = jax.vjp(lambda ys, w: _combine_rows(ys, w, inverse, order), ys, weights)
+    want_y, want_pull = jax.vjp(plain_combine, ys, weights)
+    (dys, dw), (want_dys, want_dw) = pull(dy), want_pull(dy)
+    xs, pull = jax.vjp(lambda x: _take_rows(x, tok, inverse), x)
+    want_xs, want_pull = jax.vjp(lambda x: x[tok], x)
+    (dx,), (want_dx,) = pull(dxs), want_pull(dxs)
+    # the weighted sum runs over the leading axis of ``[k, n, H]``: float32's order of adding may differ
+    np.testing.assert_allclose(y.astype(jnp.float32), want_y.astype(jnp.float32), rtol=tolerance, atol=tolerance)
+    assert np.array_equal(xs, want_xs)
+    assert dys.dtype == dx.dtype == dtype and dw.dtype == jnp.float32
+    # a row is used once: the plain form's scatter rounds what this gather rounds, once
+    assert np.array_equal(dys, want_dys)
+    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-5)
+    # the plain form adds a token's k rows in the compute dtype, this one in float32
+    np.testing.assert_allclose(dx.astype(jnp.float32), want_dx.astype(jnp.float32), rtol=tolerance, atol=tolerance)
+    spread = (dy.astype(jnp.float32)[:, None, :] * weights[:, :, None]).astype(dtype)       # [n, k, H]
+    assert np.array_equal(dys, spread.reshape(-1, H)[order])
 
 
 @pytest.mark.parametrize("devices", [2, 4], ids=["2dev", "4dev"])
@@ -455,3 +502,31 @@ def test_where_every_row_is_computed_here_the_layer_sorts_once_and_multiplies_on
         assert sorted(shape for _, shape in products) == sorted(
             [(n, F), (n, H)] * 2 + [(n, F), (n, H), (count, H, F), (count, F, H)])
         assert count_of("remat2") == 0 and count_of("sort") == 1
+
+
+def test_no_token_major_rows_are_laid_out_at_a_k_the_tile_does_not_divide():
+    """The gradient's jaxpr of a layer whose held experts stand in, three experts a token: no
+    value ``[n, 3, H]`` exists (on the chip a relayout of all the rows, to and from ``[n k, H]``,
+    where eight sublanes do not divide ``k``; PERF.md, PR 46). A token's expert outputs and the
+    dispatch's gathered cotangent are ``[k, n, H]``, slot by slot, and ``dy x weights`` is
+    ``[n, 8, H]``, a token's three rows padded to eight, each ``[rows, H]`` as it lies. Of the
+    gathers of rows ``H`` wide, the forward's two read the ``n`` tokens (dispatch) and the ``n k``
+    sorted rows (combine, kept as ``ds_moe_out`` and so not made again); the backward, under the
+    layer's own ``checkpoint``, reads the tokens (the dispatch made again), the ``8 n`` padded
+    rows (the combine's cotangent) and the ``n k`` sorted rows (the dispatch's)."""
+    E, H, F, k, first, count = (HELD[name] for name in ("E", "H", "F", "k", "first", "count"))
+    layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, count),
+                        router=("sigmoid_bias", 2.5), experts="relu2", stand_in=True)
+    params = layer.init(jax.random.PRNGKey(0), 0.2)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, H)).astype(jnp.bfloat16)
+    n = 2 * 48
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(layer.apply(p, x)[0].astype(jnp.float32)),
+                                    argnums=(0, 1)))(params, x).jaxpr
+    wide = collections.Counter(var.aval.shape for _, eqn in equations_by_path(jaxpr) for var in eqn.outvars
+                               if len(var.aval.shape) == 3 and var.aval.shape[-1] == H)
+    assert (n, k, H) not in wide and wide[(k, n, H)] and wide[(n, 8, H)], wide
+    row_gathers = collections.Counter(
+        (path, eqn.invars[0].aval.shape[0]) for path, eqn in equations_by_path(jaxpr)
+        if eqn.primitive.name == "gather" and eqn.invars[0].aval.shape[1:] == (H,))
+    assert row_gathers == {((), n): 1, ((), n * k): 1, (("remat2",), n): 1, (("remat2",), n * 8): 1,
+                           (("remat2",), n * k): 1}, row_gathers

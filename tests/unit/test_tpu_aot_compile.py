@@ -245,6 +245,9 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     # in flight). With the passes it was 3.43 GB, their loops counted twice (PR 41; 1.39 GB under
     # policy None, PR 40): the mixers' first product's output is 0.68 GB of it, the shared expert's 0.49
     assert compiled.memory_analysis().temp_size_in_bytes < 3.65e9 * 1.05
+    # six rows a token: no float32 copy of all the rows is laid out, broadcast or moved to another
+    # layout between the tokens and the sorted rows (three passes a layer until PR 46)
+    assert not re.search(r"= f32\[(8192,6,2688|49152,2688)\]\S* (broadcast|reshape|copy|transpose)\(", text)
     # the rows' products once a call over all 49,152 rows, never a pass of 8,192 under a loop
     assert "49152,1856" in text and not re.search(r"bf16\[8192,1856\]\S* custom-call", text)
 
