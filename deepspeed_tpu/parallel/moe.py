@@ -385,13 +385,38 @@ def _combine_rows_bwd(res, dy):
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
-# megablox tiles (rows, contraction, columns), picked on the chip at OLMoE's per-chip
-# shapes (PERF.md, PR 26): 170 TFLOP/s forward where (512, 512, 1024) gives 155 and 136
+# megablox tiles: the rows of a tile, and the MOST a contraction and a column tile take. The
+# kernels round K and N up to whole tiles and compute every tile in full (a K remainder is
+# masked besides), so a tile that does not divide its width is issued work nobody needs:
+# (512, 1024, 1024) clipped with ``min`` padded 2304 and 2688 to 3072 and 1792 and 1856 to
+# 2048, 1.26 to 1.52 times the products, at the rate per ISSUED product of widths it divides
+# (74-86 % of a v5e's peak either way; PERF.md, PR 47, ``tests/perf/gmm_sweep.py``). What
+# bounds a tile from above is the 16 MiB of scoped VMEM that megablox's ``pallas_call`` leaves
+# no way to raise: two buffers an operand and an output and a float32 accumulator are 12 MiB
+# at (512, 1024, 1024), the kernels' own temporaries come on top, and every candidate the
+# chip's compiler refused stood at 14.3 MiB or more. What bounds it from below is the
+# operations a byte, ``tm tn / (tm + tn)`` (341 at a column tile of 1024, 284 at 640, 256 at
+# 512 against the chip's 240), and the accumulator's read and write a contraction step: at
+# OLMoE's widths (512, 512, 512) reaches 89 TFLOP/s where (512, 1024, 1024) reaches 117
+# (64 groups that end inside a row tile; 170 at PR 26), so no tile goes under half the most.
 GMM_TILES = (512, 1024, 1024)
 
 
-def _tiles(*rows_contraction_columns):
-    return tuple(min(t, d) for t, d in zip(GMM_TILES, rows_contraction_columns))
+def _width_tile(width, most):
+    """The tile for a contraction or column ``width``: the whole width where ``most`` holds
+    it; else the multiple of 128 from half of ``most`` up to it that pads the width least,
+    of two that pad alike the larger (2048 -> 1024, 2304 -> 768, 1792 and 2688 -> 896,
+    1856 -> 640: 1920, where 1024 pads it to 2048)."""
+    if width <= most:
+        return width
+    return min(range(most, most // 2 - 1, -128), key=lambda tile: -(-width // tile) * tile)
+
+
+def _tiles(rows, contraction, columns):
+    """``(tm, tk, tn)`` for a grouped product of these widths, ``tgmm``'s ``tk`` and ``tn`` its
+    output's two widths: a function of the shapes alone."""
+    tm, tk, tn = GMM_TILES
+    return min(tm, rows), _width_tile(contraction, tk), _width_tile(columns, tn)
 
 
 def _ragged_sizes(rhs, group_sizes, first):

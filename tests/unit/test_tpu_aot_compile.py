@@ -322,6 +322,30 @@ def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def test_the_stand_in_experts_products_compile_for_v5e_at_widths_the_tiles_divide(chip, monkeypatch):
+    """Mellum 2's expert layer between its gathers, at its published widths as one chip of four
+    holds it: 16 gated experts of 896 standing in for 64, 8,192 tokens of 8 experts each, value
+    and gradient. Six megablox products over 65,536 rows at 2,304, 1,792 and 896, none of which
+    1,024 divides, at the tiles ``parallel/moe._tiles`` picks (768 and 896 wide). The layer's
+    router and its two sorts of 65,536 keys are Nemotron-H's, in the whole program above (a
+    sort alone compiles in 8 s and more)."""
+    from deepspeed_tpu.parallel import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the grouped matmul's kernel
+    assert moe._tiles(65536, 2304, 1792) == (512, 768, 896) and moe._tiles(65536, 896, 2304) == (512, 896, 768)
+
+    def loss(xs, w_gate_up, w_down, sizes):
+        gate_up = moe.experts_matmul(xs, (w_gate_up,), (None,), sizes)
+        ys = moe.experts_matmul(moe._activate(moe.SILU_GATED, gate_up, xs.dtype), (w_down,), (None,), sizes)
+        return jnp.sum(ys.astype(jnp.float32) ** 2)
+
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    text = compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), shape(65536, 2304),
+                         shape(16, 2304, 1792), shape(16, 896, 2304), shape(16, dt=jnp.int32))
+    # four products of the rows and two of the weights, each one kernel over all 65,536 rows
+    assert len(re.findall(r"= bf16\[65536,(?:1792|2304|896)\]\S* custom-call\(.*tpu_custom_call", text)) == 4
+    assert len(re.findall(r"= bf16\[16,(?:2304,1792|896,2304)\]\S* custom-call\(.*tpu_custom_call", text)) == 2
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
 def test_block_sparse_attention_compiles_for_v5e(chip, backward):
     heads, seq, block = 16, 8192, 128
