@@ -48,7 +48,8 @@ _HEAVY_FILES = ("test_ring_attention.py", "test_ring_zigzag.py")
 # FIRST, longest first (their seconds in the run of PR 43's tree), the ring suites among
 # them, and the hundreds of short files fill the workers' ends evenly.
 _LONGEST_FIRST = ("test_qwen3_next.py", "test_tpu_aot_compile.py", "test_nemotron_h.py",
-                  "test_rehearsal_hybrid.py", "test_granite_hybrid.py", "test_ring_attention.py",
+                  "test_rehearsal_hybrid.py", "test_rehearsal_mla_moe.py", "test_glm_moe.py",
+                  "test_granite_hybrid.py", "test_ring_attention.py",
                   "test_moe.py", "test_rehearsal_ssm_moe.py", "test_rehearsal_ssm.py",
                   "test_ring_zigzag.py", "test_launcher.py", "test_olmoe.py", "test_ouro.py",
                   "test_flash_attention.py")

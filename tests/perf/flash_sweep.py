@@ -1,7 +1,7 @@
 """Flash-attention tile sweep on the chip: device milliseconds a call of each kernel,
 forward and backward apart, read from a profiler trace by the kernels' own names.
 
-    python tests/perf/flash_sweep.py [--rows cell,long,other,band] [--picked] [--out chiprun_out/flash_sweep.jsonl]
+    python tests/perf/flash_sweep.py [--rows cell,long,other,band,mla] [--picked] [--out chiprun_out/flash_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent
 unpacked beside this one) it measures that tree's kernels with the same rows:
@@ -52,6 +52,9 @@ ROWS = {
     # 4 key/value heads of 128 at 8192 positions, a window of 1024 and none
     "band": [((1, 32, 8192, 128), True, [None] + SQUARE[1:] + [(1024, 1024)] + more, 4, window)
              for window, more in ((1024, [(512, 256), (256, 512), (1024, 512)]), (None, []))],
+    # a latent-attention block of glm47flash_ep8_d5_train_1chip: 20 query over 20 key/value heads
+    # of 192 + 64 | 256 at 8192 positions, six calls a step
+    "mla": [((1, 20, 8192, 256), True, [None] + SQUARE[1:] + [(1024, 1024), (512, 1024), (1024, 512)])],
 }
 
 

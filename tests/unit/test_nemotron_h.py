@@ -26,6 +26,10 @@ from deepspeed_tpu.utils import spans
 from test_ouro import kernels_in_the_backward, primitives_by_path, residuals_by_shape
 
 PATTERN = "MEM*EMEM*E"          # the first seven run: three mixers, three expert layers, an attention
+# One layer of each kind, for the cases that read what a layer keeps, makes again or is named, and
+# not how deep the model is: a gradient program of three layers compiles in under half the time of
+# seven (ROADMAP.md C10). The whole model's comparisons keep the seven.
+SHALLOW = dict(hybrid_override_pattern="ME*", num_hidden_layers=3)
 
 
 def published(**more):
@@ -104,8 +108,10 @@ def test_the_engine_computes_the_reference_loss_every_gradient_and_the_rules_upd
     counts, moved by no gradient and no rate."""
     keys, model, params = build(published(stand_in=stand_in))
     tokens, labels = batch(seed=2)
-    want_loss, want = jax.jit(jax.value_and_grad(lambda p: ref.loss(p, tokens, labels, keys)))(params)
-    counts = jax.jit(lambda p: ref.forward(p, tokens, labels, keys, last=1)["counts"])(params)
+    def loss_and_counts(p):
+        out = ref.forward(p, tokens, labels, keys, last=1)
+        return out["loss"], out["counts"]
+    (want_loss, counts), want = jax.jit(jax.value_and_grad(loss_and_counts, has_aux=True))(params)
     rate = 0.5
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
         "train_batch_size": 8, "bf16": {"enabled": False},
@@ -141,7 +147,7 @@ def test_the_engine_computes_the_reference_loss_every_gradient_and_the_rules_upd
 
 @pytest.mark.parametrize("remat", [False, True], ids=["kept", "layers-recomputed"])
 def test_it_trains_in_bfloat16_through_initialize(remat):
-    _, model, params = build(compute_dtype=jnp.bfloat16, initializer_range=0.02, remat=remat)
+    _, model, params = build(published(**SHALLOW), compute_dtype=jnp.bfloat16, initializer_range=0.02, remat=remat)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
         "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
         "optimizer": {"type": "Adam", "params": {"lr": 3e-3}}, "steps_per_print": 10 ** 9})
@@ -166,8 +172,8 @@ def test_it_trains_in_bfloat16_through_initialize(remat):
 
 
 def test_recomputed_layers_give_the_same_loss_and_gradients():
-    _, kept, params = build()
-    _, again, _ = build(remat=True)
+    _, kept, params = build(published(**SHALLOW))
+    _, again, _ = build(published(**SHALLOW), remat=True)
     tokens, labels = batch(seed=5, rows=2)
     loss = lambda m: (lambda p, t, l: m.apply(p, t, l)[0])      # noqa: E731
     (l0, g0), (l1, g1) = (jax.jit(jax.value_and_grad(loss(m)))(params, tokens, labels) for m in (kept, again))
@@ -177,7 +183,7 @@ def test_recomputed_layers_give_the_same_loss_and_gradients():
 
 
 # ------------------------------------------------------------------ what a recomputed layer keeps
-MIXERS, EXPERT_LAYERS, ATTENTIONS = (PATTERN[:7].count(kind) for kind in "ME*")
+MIXERS, EXPERT_LAYERS, ATTENTIONS = (SHALLOW["hybrid_override_pattern"].count(kind) for kind in "ME*")
 ONLY_THE_INPUT = "only-the-input-kept"
 ROWS_MADE_AGAIN = "the-experts-rows-made-again"      # the kept set less ``parallel/moe.py``'s name (PR 41's)
 ALL_THE_ROWS_KEPT = "each-tokens-expert-outputs-kept-too"  # and with the other (``ds_moe_out``): PERF.md, PR 42
@@ -206,7 +212,7 @@ def loss_and_gradients(dtype, what, stand_in=False):
     operations that happen to be fused (``xla_allow_excess_precision``). ``stand_in``: every
     assignment computed by the held experts, as in the cell, where an expert layer's rows go
     through the whole range's form and the kept set names two of its tensors."""
-    _, model, params = build(published(stand_in=stand_in), remat=what != "layers-kept",
+    _, model, params = build(published(stand_in=stand_in, **SHALLOW), remat=what != "layers-kept",
                              compute_dtype=getattr(jnp, dtype))
     tokens, labels = batch(seed=6, rows=2)
     with keeping(what):
@@ -266,7 +272,7 @@ def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and
     each token's expert outputs kept too it would run none of the row path. The form's own
     backward is the remake of the gathered rows, two gathers of cotangents and each product's
     two cotangents, whatever the layer keeps."""
-    _, model, params = build(published(stand_in=stand_in), remat=True)
+    _, model, params = build(published(stand_in=stand_in, **SHALLOW), remat=True)
     tokens, labels = batch(seed=5, rows=2)
     with keeping(what):
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(p, tokens, labels)[0]))(params).jaxpr
@@ -295,7 +301,7 @@ def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and
 def kept_by_the_layers(what, stand_in=False):
     """``{shape: count}`` of the activations that the layers of two sequences keep for their
     backward, an expert layer's flat rows among them (80 tokens, 240 assignments)."""
-    _, model, params = build(published(stand_in=stand_in), remat=True)
+    _, model, params = build(published(stand_in=stand_in, **SHALLOW), remat=True)
     tokens, _ = batch(seed=5, rows=2)
     with keeping(what):
         return residuals_by_shape(lambda p: model._backbone(p, tokens)[0], params, rows=(3, 240))
@@ -331,7 +337,7 @@ def test_the_names_are_nothing_where_no_layer_is_recomputed(monkeypatch):
     """With ``remat=False`` (the reference comparison's path) a name lowers to nothing: the
     gradient program is the same text with every name of this file taken out (but for the
     numbers JAX gives its private functions, which count the traces before)."""
-    _, model, params = build(compute_dtype=jnp.bfloat16)
+    _, model, params = build(published(**SHALLOW), compute_dtype=jnp.bfloat16)
     tokens, labels = batch(seed=5, rows=2)
     lowered = lambda: re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(jax.value_and_grad(      # noqa: E731
         lambda p: model.apply(p, tokens, labels)[0])).lower(params).as_text())
@@ -414,8 +420,10 @@ def test_the_sixteen_held_ranges_add_up_to_the_uncut_layer(highest):
         part = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, 2),
                            router=("sigmoid_bias", 2.5), experts=RELU2)
         mine = dict(params, w_up=params["w_up"][first:first + 2], w_down=params["w_down"][first:first + 2])
-        y, aux, stats = part.apply(mine, x)
-        g_x, g_p = jax.grad(lambda x, p: jnp.sum(part.apply(p, x)[0] * cot), argnums=(0, 1))(x, mine)
+        # one forward and its pull-back a range (an ``apply`` and a ``grad`` were two forwards)
+        y, pull, (aux, stats) = jax.vjp(lambda x, p: (lambda out: (out[0], out[1:]))(part.apply(p, x)),
+                                        x, mine, has_aux=True)
+        g_x, g_p = pull(cot)
         total, rows, dx = total + y, rows + float(stats["rows_here"]), dx + g_x
         d_router = d_router + g_p["router_w"]
         for name in ("w_up", "w_down"):
@@ -430,7 +438,7 @@ def test_the_sixteen_held_ranges_add_up_to_the_uncut_layer(highest):
 
 # ------------------------------------------------------------------ the scopes
 def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
-    _, model, params = build(remat=True)
+    _, model, params = build(published(**SHALLOW), remat=True)
     tokens, labels = batch(seed=6, rows=2)
     text = jax.jit(jax.grad(lambda p, t, l: model.apply(p, t, l)[0])).lower(
         params, tokens, labels).compile().as_text()
@@ -450,7 +458,7 @@ def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
     # ITS ``rematted_computation``, which ``recompute_time_share`` reads; the layer's second
     # forward runs the router, the sort, ``w_down``'s product and the gather back, and gathers no
     # row for ``w_up`` (the layer keeps that product's output by name)
-    _, stands_in, its_params = build(published(stand_in=True), remat=True)
+    _, stands_in, its_params = build(published(stand_in=True, **SHALLOW), remat=True)
     text = jax.jit(jax.grad(lambda p, t, l: stands_in.apply(p, t, l)[0])).lower(
         its_params, tokens, labels).compile().as_text()
     for path in (r"ds_mlp/ds_moe_router", r"ds_mlp/ds_moe_dispatch", r"ds_mlp/checkpoint/ds_moe_dispatch",

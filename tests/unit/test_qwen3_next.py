@@ -5,6 +5,8 @@ the chunked delta rule against the token-at-a-time recurrence, the held-range ex
 layer's shares against the uncut layer, grouped-query flash attention against repeated
 keys and values, the rotary width and the zero-centred norm."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,11 +107,14 @@ def test_the_engine_computes_the_reference_loss_and_the_gradient_of_every_leaf(h
 
 
 def test_it_trains_in_bfloat16_through_initialize():
-    _, model, params = build(compute_dtype=jnp.bfloat16, initializer_range=0.02)
+    # one delta-rule layer and one full-attention layer: the case reads that a step trains, not how
+    # deep the model is (the four-layer toy's gradient program is most of this file's compile time)
+    _, model, params = build(published(num_hidden_layers=2, full_attention_interval=2),
+                             compute_dtype=jnp.bfloat16, initializer_range=0.02)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
         "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
         "optimizer": {"type": "Adam", "params": {"lr": 3e-3}}, "steps_per_print": 10 ** 9})
-    tokens, _ = batch(seed=4)
+    tokens, _ = batch(seed=4, T=64)           # one chunk of the delta rule: the interpreted kernels' time follows T
     losses = []
     for _ in range(4):
         loss = engine(tokens, np.roll(tokens, -1, 1))
@@ -146,6 +151,19 @@ def recurrence(q, k, v, g, beta):
                                     jnp.repeat(ref.unit_scaled(k, False), r, axis=2), v, g, beta)
 
 
+@functools.lru_cache(maxsize=None)
+def value_and_gradients(fn, block_chunks=None):
+    """``(*args, cot) -> (fn(*args), the gradients of sum(fn * cot) by q, k, v, g, beta)`` as ONE
+    jitted program a function (and a ``BLOCK_CHUNKS``, which a trace reads): the cases that differ
+    in their values alone (the decay) share its compile, where an eager call and a ``jax.grad`` of a
+    new lambda compiled both afresh in every case."""
+    def both(*args_and_cot):
+        *args, cot = args_and_cot
+        out, pull = jax.vjp(fn, *args)
+        return out, pull(cot.astype(out.dtype))
+    return jax.jit(both)
+
+
 @pytest.mark.parametrize("block_chunks", [16, 1], ids=["one-block", "a-chunk-a-block"])
 @pytest.mark.parametrize("decay", [0.02, 1.0], ids=["slow-decay", "fast-decay"])
 @pytest.mark.parametrize("T", [64, 100, 200, 7], ids=lambda t: f"T{t}")
@@ -154,12 +172,11 @@ def test_the_chunked_delta_rule_is_the_recurrence(T, decay, block_chunks, highes
     handed from block to block."""
     monkeypatch.setattr(delta_rule, "BLOCK_CHUNKS", block_chunks)
     args, cot = delta_inputs(T, seed=T, decay=decay)
-    got, want = gated_delta_rule(*args), recurrence(*args)
+    (got, grads), (want, want_grads) = (value_and_gradients(gated_delta_rule, block_chunks)(*args, cot),
+                                        value_and_gradients(recurrence)(*args, cot))
     assert got.shape == want.shape and got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, atol=3e-6)
-    grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * cot), argnums=(0, 1, 2, 3, 4))(*args)
-             for fn in (gated_delta_rule, recurrence)]
-    for g, w in zip(*grads):
+    for g, w in zip(grads, want_grads):
         assert float(jnp.linalg.norm(g - w)) <= 5e-5 * float(jnp.linalg.norm(w)) + 1e-9
 
 
@@ -171,11 +188,11 @@ def test_a_run_of_equal_keys_does_not_blow_the_triangular_system_up(highest):
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta = jnp.full_like(beta, 0.999)
     args = (q, k, v, g, beta)
-    np.testing.assert_allclose(gated_delta_rule(*args), recurrence(*args), atol=1e-5)
-    grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * cot), argnums=(2, 4))(*args)
-             for fn in (gated_delta_rule, recurrence)]
-    for got, want in zip(*grads):
-        assert float(jnp.linalg.norm(got - want)) <= 1e-4 * float(jnp.linalg.norm(want))
+    (got, grads), (want, want_grads) = (value_and_gradients(gated_delta_rule, delta_rule.BLOCK_CHUNKS)(*args, cot),
+                                        value_and_gradients(recurrence)(*args, cot))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for at in (2, 4):                   # the values' and the steps' gradients
+        assert float(jnp.linalg.norm(grads[at] - want_grads[at])) <= 1e-4 * float(jnp.linalg.norm(want_grads[at]))
 
 
 def test_the_delta_rule_keeps_its_inputs_dtype_and_a_float32_state():
@@ -248,12 +265,14 @@ def test_the_shares_add_up_to_the_uncut_layer(tokens):
     want = uncut(x, params)
     want_dx, want_dp = jax.grad(lambda x, p: jnp.sum(uncut(x, p) * cot), argnums=(0, 1))(x, params)
     total, rows, dx = shared_alone(x), 0.0, jax.grad(lambda x: jnp.sum(shared_alone(x) * cot))(x)
-    d_router = 0.0
+    d_router, whole_aux, shared_part = 0.0, float(whole.apply(params, x)[1]), flat(shared_alone(x))
     for first in range(0, 16, 4):
         part = DroplessMoE(32, 16, 16, 4, norm_topk_prob=True, held=(first, 4))
         mine = part_of(params, first, 4)
-        y, aux, stats = part.apply(mine, x)
-        g_x, g_p = jax.grad(lambda x, p: jnp.sum(part.apply(p, x)[0] * cot), argnums=(0, 1))(x, mine)
+        # one forward and its pull-back a range (an ``apply`` and a ``grad`` were two forwards)
+        y, pull, (aux, stats) = jax.vjp(lambda x, p: (lambda out: (out[0], out[1:]))(part.apply(p, x)),
+                                        x, mine, has_aux=True)
+        g_x, g_p = pull(cot)
         total, rows, dx = total + y, rows + float(stats["rows_here"]), dx + g_x
         d_router = d_router + g_p["router_w"]
         for name in ("w_gate_up", "w_down"):
@@ -261,8 +280,8 @@ def test_the_shares_add_up_to_the_uncut_layer(tokens):
         # what the held range gives is what the reference's held form gives
         held = ref.expert_layer(flat(x), {"moe": mine, "shared": shared},
                                 dict(keys, num_experts=4, router_width=16), held=(first, 4))[0]
-        np.testing.assert_allclose(flat(y) + flat(shared_alone(x)), held, atol=2e-4)
-        assert float(aux) == pytest.approx(float(whole.apply(params, x)[1]), rel=1e-6)
+        np.testing.assert_allclose(flat(y) + shared_part, held, atol=2e-4)
+        assert float(aux) == pytest.approx(whole_aux, rel=1e-6)
     assert rows == 2 * tokens * 4                      # every assignment landed somewhere, once
     np.testing.assert_allclose(total, want, atol=3e-4)
     np.testing.assert_allclose(dx, want_dx, atol=3e-4)

@@ -96,6 +96,25 @@ def test_sliding_window_flash_attention_compiles_for_v5e(chip):
     assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text
 
 
+def test_a_latent_attention_block_compiles_for_v5e(chip, monkeypatch):
+    """One latent (MLA) mixer of ``glm47flash_ep8_d5_train_1chip`` at its published widths and 8,192
+    positions, value and gradient: both bottlenecks, the one rotary key broadcast to 20 heads, and
+    the flash kernel at 20 query over 20 key/value heads of 192 + 64 | 256 (no grouped-query case
+    compiles 20 key/value heads of 256)."""
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners.train_mla_moe import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernel, not its interpreter
+    model = build_model(Manifest().config("glm-4.7-flash-ep8-d5"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][1]["attn"]
+    assert shapes["wkv_b"].shape == (512, 20 * (192 + 256)) and shapes["wkv_a"].shape == (2048, 512 + 64)
+    ap = jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=chip)
+    grad = jax.grad(lambda x, p: jnp.sum(model.attention(x, p).astype(jnp.float32) ** 2), argnums=(0, 1))
+    text = compiled_text(grad, x, ap)
+    assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text and "ds_attn_latent" in text
+    assert re.search(r"bf16\[1,20,8192,256\]", text)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("T", [8192, 1024])
 def test_the_delta_rule_kernels_compile_for_v5e(chip, T, dtype):
