@@ -121,14 +121,13 @@ class GlmMoeConfig:
 
 
 # What a recomputed block keeps beside its input, by name: the flash kernel's output and row
-# sums (named in its forward rule: a block's backward runs no second forward kernel), the held
-# experts' first grouped product's output and each token's four expert outputs (both named in
-# ``parallel/moe.py``: kept here, the second forward gathers no row and runs neither grouped
-# product). Named too and NOT kept: the latent projections' outputs (``attn_q``, ``attn_kv``),
+# sums (named in its forward rule: a block's backward runs no second forward kernel) and the held
+# experts' first grouped product's output (named in ``parallel/moe.py``: kept here, the second
+# forward gathers no row and runs neither grouped product; no backward reads the second product's
+# output, PR 49). Named too and NOT kept: the latent projections' outputs (``attn_q``, ``attn_kv``),
 # the dense MLP's and the shared expert's first products (``dense_gate_up``, ``shared_gate_up``).
-# Bytes and milliseconds a name: docs/glm-4.7-flash.md, PERF.md (PR 48).
-KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
-    "attn_out", "attn_lse", "ds_moe_gate_up", "ds_moe_out")
+# Bytes and milliseconds a name: docs/glm-4.7-flash.md, PERF.md (PR 48, PR 49).
+KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names("attn_out", "attn_lse", "ds_moe_gate_up")
 
 
 def _dot(x, w):

@@ -105,14 +105,14 @@ class MellumConfig:
 
 
 # What a recomputed layer keeps beside its input, by name: the flash kernel's output and row
-# sums (named in its forward rule: a layer's backward runs no second forward kernel), the held
-# experts' first grouped product's output and each token's eight expert outputs (both named in
-# ``parallel/moe.py``, whose own checkpoint keeps them for the rows' backward: kept here, the
-# second forward gathers no row and runs neither grouped product). The projections' outputs are
-# named too (``attn_q``, ``attn_kv``) and NOT kept: 0.28 GB that bought nothing on a v5e.
-# Bytes and milliseconds a name: docs/mellum2.md, PERF.md (PR 45).
-KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
-    "attn_out", "attn_lse", "ds_moe_gate_up", "ds_moe_out")
+# sums (named in its forward rule: a layer's backward runs no second forward kernel) and the held
+# experts' first grouped product's output (named in ``parallel/moe.py``, whose own checkpoint keeps
+# it for the rows' backward: kept here, the second forward gathers no row and runs neither grouped
+# product; nothing in a backward reads the second product's output since the router's weights go
+# to the rows before ``w_down``, PR 49). The projections' outputs are named too (``attn_q``,
+# ``attn_kv``) and NOT kept: 0.28 GB that bought nothing on a v5e.
+# Bytes and milliseconds a name: docs/mellum2.md, PERF.md (PR 45, PR 49).
+KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names("attn_out", "attn_lse", "ds_moe_gate_up")
 
 
 def _dot(x, w):

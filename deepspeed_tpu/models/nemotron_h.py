@@ -131,9 +131,10 @@ class NemotronHConfig:
 # first product's output before its activation, and the held experts' first grouped product's
 # output where they stand in for the absent ones (named in ``parallel/moe.py``, whose own
 # checkpoint keeps it for the rows' backward: kept here, the second forward gathers no row for
-# it and runs no ``w_up``). Every layer ends ``x + f(norm(x))``: nothing in a layer's backward
-# reads its LAST product's output, so the second forward never ran one.
-# Bytes and what each buys on a v5e: docs/nemotron-h.md, PERF.md (PR 41, PR 42).
+# it and runs no ``w_up``; and no ``w_down`` and no gather back either since the router's weights
+# go to the rows before ``w_down``, PR 49). Every layer ends ``x + f(norm(x))``: nothing in a
+# layer's backward reads its LAST product's output, so the second forward never ran one.
+# Bytes and what each buys on a v5e: docs/nemotron-h.md, PERF.md (PR 41, PR 42, PR 49).
 KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
     "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "ds_moe_gate_up")
 

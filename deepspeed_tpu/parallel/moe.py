@@ -328,15 +328,24 @@ def moe_apply_sharded(layer: MoELayer, mesh: Mesh, params, x,
 # over the experts. Nothing above this line is called from here.
 
 @jax.custom_vjp
-def _take_rows(x, tok, inverse):
-    """Dispatch: ``xs[m] = x[tok[m]]`` for the ``n * k`` sorted assignments. ``inverse``
-    ``[n, k]`` is the row that holds token n's j-th assignment, so that the cotangent is
-    one row gather and a sum over k, never a scatter."""
-    return x[tok]
+def _sort_rows(sent_to, slots, weights):
+    """The ``n * k`` assignments sorted by expert, stably: ``(by_expert, order, inverse, w_sorted)``.
+    ``order`` ``[n * k]`` is each sorted row's flat ``n * k + j``, ``inverse`` ``[n, k]`` the row that
+    holds token n's j-th assignment, ``w_sorted`` the router's ``weights`` ``[n, k]`` in the rows'
+    order: a further operand of the sort that orders the rows, where ``weights.reshape(-1)[order]``
+    is a gather of scalars of its own (0.56 ms at 65,536: PERF.md, PR 46). Their cotangent comes
+    back by ``inverse``."""
+    by_expert, order, w_sorted = jax.lax.sort((sent_to, slots, weights.reshape(-1)), num_keys=1, is_stable=True)
+    inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(weights.shape)
+    return by_expert, order, inverse, w_sorted
 
 
-def _take_rows_fwd(x, tok, inverse):
-    return x[tok], inverse
+def _sort_rows_fwd(sent_to, slots, weights):
+    out = _sort_rows(sent_to, slots, weights)
+    return out, out[2]
+
+
+_sort_rows.defvjp(_sort_rows_fwd, lambda inverse, grads: (None, None, grads[3][inverse]))
 
 
 def _rows_of_the_tokens(rows, inverse):
@@ -347,43 +356,23 @@ def _rows_of_the_tokens(rows, inverse):
     return rows[inverse.T.reshape(-1)].reshape(k, n, -1)
 
 
-def _take_rows_bwd(inverse, dxs):
-    dx = jnp.sum(_rows_of_the_tokens(dxs, inverse).astype(jnp.float32), axis=0)
-    return dx.astype(dxs.dtype), None, None
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+# Dispatch and combine are each other's transposes, so each is the other's cotangent: one row
+# gather either way (and a sum over k), never a scatter, and neither keeps a row for its backward.
+@jax.custom_vjp
+def _take_rows(x, tok, inverse):
+    """Dispatch: ``xs[m] = x[tok[m]]`` for the ``n * k`` sorted assignments (``tok = order // k``)."""
+    return x[tok]
 
 
 @jax.custom_vjp
-def _combine_rows(ys, weights, inverse, order):
-    """Combine: ``y[n] = sum_j weights[n, j] * ys[inverse[n, j]]``; ``order`` ``[n * k]`` is the
-    flat ``n * k + j`` of each sorted row, for the cotangent's one gather, of ``dy x weights``."""
-    return _combine_rows_fwd(ys, weights, inverse, order)[0]
+def _sum_rows(ys, tok, inverse):
+    """Combine: ``y[n] = sum_j ys[inverse[n, j]]``, summed in float32. The router's weights are in
+    the rows already (``_activate``), so nothing of ``ys`` is needed to pull a cotangent back."""
+    return jnp.sum(_rows_of_the_tokens(ys, inverse).astype(jnp.float32), axis=0).astype(ys.dtype)
 
 
-def _combine_rows_fwd(ys, weights, inverse, order):
-    # each token's k expert outputs: what the backward keeps, not ``ys``
-    mine = checkpoint_name(_rows_of_the_tokens(ys, inverse), "ds_moe_out")
-    y = jnp.einsum("knh,nk->nh", mine.astype(jnp.float32), weights)
-    return y.astype(ys.dtype), (mine, weights, order)
-
-
-def _combine_rows_bwd(res, dy):
-    mine, weights, order = res
-    n, k = weights.shape
-    dyf = dy.astype(jnp.float32)
-    # ``dy x weights`` with a token's k rows padded to the sublane tile of eight (zero weights):
-    # ``[n, slots, H]`` is ``[n slots, H]`` as it lies, so broadcast, product and cast fuse into
-    # one pass; ``[n, 6, H]`` is a float32 broadcast, a relayout and a product, a pass each
-    slots = -(-k // 8) * 8
-    spread = (dyf[:, None, :] * jnp.pad(weights, ((0, 0), (0, slots - k)))[:, :, None]).astype(mine.dtype)
-    dys = spread.reshape(n * slots, -1)[order // k * slots + order % k]
-    dw = jnp.einsum("knh,nh->nk", mine.astype(jnp.float32), dyf).astype(weights.dtype)
-    return dys, dw, None, None
-
-
-_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+_take_rows.defvjp(lambda x, *sort: (_take_rows(x, *sort), sort), lambda sort, dxs: (_sum_rows(dxs, *sort), None, None))
+_sum_rows.defvjp(lambda ys, *sort: (_sum_rows(ys, *sort), sort), lambda sort, dy: (_take_rows(dy, *sort), None, None))
 
 # megablox tiles: the rows of a tile, and the MOST a contraction and a column tile take. The
 # kernels round K and N up to whole tiles and compute every tile in full (a K remainder is
@@ -562,14 +551,18 @@ def piece_firsts(axis, per_chip):
 SILU_GATED, RELU2 = "silu_gated", "relu2"
 
 
-def _activate(form, up, dt):
+def _activate(form, up, dt, weights=None):
     """What lies between an expert's products, from the first one's output ``up``: the gated
     SiLU of its two halves ``silu(gate) * up`` (``w_gate_up [.., H, 2F]``), or the squared
-    ReLU of the whole (``w_up [.., H, F]``: an expert of two matrices); in float32."""
+    ReLU of the whole (``w_up [.., H, F]``: an expert of two matrices); in float32, and there
+    times the rows' ``weights`` ``[rows]`` where the router's go in BEFORE ``w_down``, which is
+    linear: one pass, one rounding, and the weights' gradient is a row sum of its backward."""
     if form == RELU2:
-        return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
-    F = up.shape[1] // 2
-    return (jax.nn.silu(up[:, :F].astype(jnp.float32)) * up[:, F:].astype(jnp.float32)).astype(dt)
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+    else:
+        F = up.shape[1] // 2
+        act = jax.nn.silu(up[:, :F].astype(jnp.float32)) * up[:, F:].astype(jnp.float32)
+    return (act if weights is None else act * weights[:, None]).astype(dt)
 
 
 # ------------------------------------------------------------ a held range's rows
@@ -700,14 +693,15 @@ class DroplessMoE:
     not). The router, its choice, its weights and ``counts`` stay those of all
     ``num_experts``; ``rows_here`` is ``n * k``. Since the rows are ``n * k`` before anything
     is traced, the layer does what a chip does after its exchange and what the whole range
-    does above: ONE sort by stand-in expert, one gather of the rows, one grouped matmul a
-    product over the ``count`` groups, one gather back, the first product's output and each
-    token's ``k`` expert outputs kept for the backward (``ds_moe_gate_up``, ``ds_moe_out``:
-    names a recomputed layer around this one may keep too; the second ``[k, n, H]``, a token's
-    outputs slot by slot) and cotangents that are gathers: the sorted rows' from ``dy x weights``
-    laid out a token's slots side by side, the tokens' from the sorted rows of the first product's.
-    No passes, no branch, no scatter; the buffers are ``n * k`` rows, which the passes avoid
-    for a held range that may see a sixteenth of them.
+    does above: ONE sort by stand-in expert (the router's weights sorted with the rows), one
+    gather of the rows, one grouped matmul a product over the ``count`` groups, the weights
+    given to the activation's rows BEFORE ``w_down`` (it is linear), one gather back and a
+    plain sum over ``k``. The first product's output is kept for the backward (``ds_moe_gate_up``:
+    a name a recomputed layer around this one may keep too) and NOTHING of the second's: the
+    cotangents are gathers, the sorted rows' from ``dy``, the tokens' from the sorted rows of the
+    first product's, and the weights' is a row sum of the activation's backward pass. No passes,
+    no branch, no scatter; the buffers are ``n * k`` rows, which the passes avoid for a held
+    range that may see a sixteenth of them.
 
     ``stats``: ``load_max_over_mean`` (float32: the busiest expert's assignments over the
     mean, over all chips and all ``num_experts``); in the held-range form also
@@ -834,15 +828,16 @@ class DroplessMoE:
                 # every row to the held expert that stands in for its own: sorted by that one
                 every = jnp.sum(sent_to[:, None] == jnp.arange(E, dtype=jnp.int32), axis=0)
                 sent_to = first + (sent_to - first) % count
-            by_expert, order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)
+            if self.every_row_here:
+                by_expert, order, inverse, w_sorted = _sort_rows(sent_to, slots, weights)
+            else:
+                by_expert, order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)
             starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
             group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
-            if self.every_row_here:
-                inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
             tok = order // k
         # the groups the whole range's products run over: standing in, the held ones hold every row
         sizes = group_sizes[first:first + count] if self.stand_in else group_sizes
-        def routed(x2, weights, w_gate_up, w_down):
+        def routed(x2, w_sorted, w_gate_up, w_down):
             dt = x2.dtype
             w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
             if axis is None:
@@ -857,20 +852,20 @@ class DroplessMoE:
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
                     experts_matmul(xs, gate_up_pieces, firsts, sizes), "ds_moe_gate_up")
-                ys = experts_matmul(_activate(form, gate_up, dt), down_pieces, firsts, sizes)
+                ys = experts_matmul(_activate(form, gate_up, dt, w_sorted), down_pieces, firsts, sizes)
             with jax.named_scope("ds_moe_combine"):
-                return _combine_rows(ys, weights, inverse, order)          # [n, H]
+                return _sum_rows(ys, tok, inverse)                        # [n, H]
 
         if self.held is not None:
             # the held range's rows are one run of the sorted order: its start and its length
             lo, rows_here = starts[first], starts[first + count] - starts[first]
-        # The backward keeps the first product's output and each token's k expert outputs.
-        # It makes the gathered rows and the gated activation again (a gather and an
-        # elementwise pass) and fetches the experts' weights again: kept, the four
-        # layers' gathered weights would be 3.2 GB a chip.
+        # The backward keeps the first product's output and nothing of the second's. It makes
+        # the gathered rows and the weighted activation again (a gather and an elementwise
+        # pass) and fetches the experts' weights again: kept, the four layers' gathered
+        # weights would be 3.2 GB a chip.
         if self.every_row_here:
             y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
-                "ds_moe_gate_up", "ds_moe_out"))(x2, weights, w_gate_up, w_down)
+                "ds_moe_gate_up"))(x2, w_sorted, w_gate_up, w_down)
         else:
             # as many rows as the router sends: in passes, with the held rows up to each held expert
             sort = (tok, order, lo, rows_here, jnp.cumsum(group_sizes[first:first + count]))

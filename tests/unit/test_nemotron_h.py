@@ -23,7 +23,7 @@ from deepspeed_tpu.models import nemotron_h
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
 from deepspeed_tpu.parallel.moe import RELU2, DroplessMoE
 from deepspeed_tpu.utils import spans
-from test_ouro import kernels_in_the_backward, primitives_by_path, residuals_by_shape
+from test_ouro import equations_by_path, kernels_in_the_backward, primitives_by_path, residuals_by_shape
 
 PATTERN = "MEM*EMEM*E"          # the first seven run: three mixers, three expert layers, an attention
 # One layer of each kind, for the cases that read what a layer keeps, makes again or is named, and
@@ -186,21 +186,19 @@ def test_recomputed_layers_give_the_same_loss_and_gradients():
 MIXERS, EXPERT_LAYERS, ATTENTIONS = (SHALLOW["hybrid_override_pattern"].count(kind) for kind in "ME*")
 ONLY_THE_INPUT = "only-the-input-kept"
 ROWS_MADE_AGAIN = "the-experts-rows-made-again"      # the kept set less ``parallel/moe.py``'s name (PR 41's)
-ALL_THE_ROWS_KEPT = "each-tokens-expert-outputs-kept-too"  # and with the other (``ds_moe_out``): PERF.md, PR 42
 STANDING_IN = {False: "absent-left-out", True: "held-stand-in"}
 
 
 @contextlib.contextmanager
 def keeping(what):
     """The model's kept set, ``policy=None`` in its place (``ONLY_THE_INPUT``), or the set without
-    the name of an expert layer's row path (``ROWS_MADE_AGAIN``) or with both (``ALL_THE_ROWS_KEPT``)."""
+    the name of an expert layer's row path (``ROWS_MADE_AGAIN``)."""
     with pytest.MonkeyPatch.context() as patch:
         if what == ONLY_THE_INPUT:
             patch.setattr(nemotron_h, "KEPT_BY_A_LAYER", None)
-        elif what in (ROWS_MADE_AGAIN, ALL_THE_ROWS_KEPT):
-            rows = ("ds_moe_gate_up", "ds_moe_out") if what == ALL_THE_ROWS_KEPT else ()
+        elif what == ROWS_MADE_AGAIN:
             patch.setattr(nemotron_h, "KEPT_BY_A_LAYER", jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", *rows))
+                "attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up"))
         yield
 
 
@@ -211,7 +209,7 @@ def loss_and_gradients(dtype, what, stand_in=False):
     value is the same bits wherever it is made: no rounding to bfloat16 dropped between two
     operations that happen to be fused (``xla_allow_excess_precision``). ``stand_in``: every
     assignment computed by the held experts, as in the cell, where an expert layer's rows go
-    through the whole range's form and the kept set names two of its tensors."""
+    through the whole range's form and the kept set names one of its tensors."""
     _, model, params = build(published(stand_in=stand_in, **SHALLOW), remat=what != "layers-kept",
                              compute_dtype=getattr(jnp, dtype))
     tokens, labels = batch(seed=6, rows=2)
@@ -227,10 +225,10 @@ def loss_and_gradients(dtype, what, stand_in=False):
 def test_what_a_layer_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, other, stand_in):
     """The kept tensors are the values the second forward would have made again, in the dtype
     the forward made them in (the shared expert's first product in float32, before its
-    activation; where the held experts stand in, the first grouped product's output and each
-    token's expert outputs in the compute dtype): the loss and every leaf's gradient are the
-    same bits as under ``policy=None``, in float32 and in bfloat16, and as with nothing
-    recomputed; the selection bias gets none."""
+    activation; where the held experts stand in, the first grouped product's output in the
+    compute dtype): the loss and every leaf's gradient are the same bits as under
+    ``policy=None``, in float32 and in bfloat16, and as with nothing recomputed; the selection
+    bias gets none."""
     (loss, got), (want_loss, want) = (loss_and_gradients(dtype, "the-kept-set", stand_in),
                                       loss_and_gradients(dtype, other, stand_in))
     assert float(loss) == float(want_loss) and np.isfinite(float(loss))
@@ -241,21 +239,24 @@ def test_what_a_layer_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, o
 
 def rows_in_the_backward(jaxpr):
     """``({primitive: count} of the second forward, {primitive: count} of the layers' own backward)``
-    over the grouped products and the gathers of the recomputed expert layers: what the gradient's
-    top-level ``remat2`` equations hold directly, and what the ``jax.checkpoint`` of the whole
-    range's form holds inside them (its remake of the gathered rows, and the cotangents)."""
+    over the grouped products and the gathers of ROWS (an operand of two axes) of the recomputed
+    expert layers: what the gradient's top-level ``remat2`` equations hold directly, and what the
+    ``jax.checkpoint`` of the whole range's form holds inside them (its remake of the gathered
+    rows, and the cotangents)."""
     second, own = collections.Counter(), collections.Counter()
     for outer in jaxpr.eqns:
         if outer.primitive.name == "remat2" and outer.params["differentiated"]:
-            for (path, name), n in primitives_by_path(outer.params["jaxpr"]).items():
-                if name in ("ragged_dot_general", "gather") and "jit" not in path:
-                    (own if "remat2" in path else second)[name] += n
+            for path, eqn in equations_by_path(outer.params["jaxpr"]):
+                name = eqn.primitive.name
+                if "jit" not in path and (name == "ragged_dot_general"
+                                          or (name == "gather" and eqn.invars[0].aval.ndim == 2)):
+                    (own if "remat2" in path else second)[name] += 1
     return second, own
 
 
 @pytest.mark.parametrize("what, stand_in", [
     ("the-kept-set", False), (ONLY_THE_INPUT, False),
-    ("the-kept-set", True), (ROWS_MADE_AGAIN, True), (ALL_THE_ROWS_KEPT, True), (ONLY_THE_INPUT, True)],
+    ("the-kept-set", True), (ROWS_MADE_AGAIN, True), (ONLY_THE_INPUT, True)],
     ids=lambda v: STANDING_IN.get(v, v))
 def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and_an_expert_layer(what, stand_in):
     """What the backward makes again, by layer: a mixer's second forward runs NO product where
@@ -265,24 +266,24 @@ def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and
     neither: every layer ends ``x + f(norm(x))`` and nothing in its backward reads that output
     (PERF.md, PR 41). A product's backward is two products; the held experts' are their own.
     Where the held experts stand in (the cell), an expert layer's rows go through the whole
-    range's form under its own ``jax.checkpoint``, which keeps two tensors: the layer's second
-    forward makes both again (a gather, two grouped products, a gather) unless the LAYER keeps
-    them by name too. The kept set holds the first product's output (PERF.md, PR 42): the second
-    forward gathers no row for it and runs ``w_down``'s product and the gather back alone; with
-    each token's expert outputs kept too it would run none of the row path. The form's own
-    backward is the remake of the gathered rows, two gathers of cotangents and each product's
-    two cotangents, whatever the layer keeps."""
+    range's form under its own ``jax.checkpoint``, which keeps the first grouped product's output
+    and NOTHING of the second's: the router's weights are in the rows before ``w_down``, so the
+    combine is a plain sum whose cotangent is a gather of ``dy`` (PERF.md, PR 49). The layer's
+    second forward makes the first product again (a gather, a grouped product) unless the LAYER
+    keeps it by name too, as the kept set does (PERF.md, PR 42): then it runs NONE of the row
+    path, and never ``w_down``'s product or the gather back, whatever is kept. So an expert layer
+    is six grouped products in all under the kept set: two forward and, in the form's own
+    backward, each product's two cotangents, beside the remake of the gathered rows and the two
+    gathers of cotangents."""
     _, model, params = build(published(stand_in=stand_in, **SHALLOW), remat=True)
     tokens, labels = batch(seed=5, rows=2)
     with keeping(what):
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.apply(p, tokens, labels)[0]))(params).jaxpr
     found, kernels = primitives_by_path(jaxpr), kernels_in_the_backward(jaxpr)
     # mixer, expert layer, flash; grouped products, row gathers of an expert layer's second forward
-    again = {"the-kept-set": (0, 1, 0, 1, 1), ROWS_MADE_AGAIN: (0, 1, 0, 2, 2), ALL_THE_ROWS_KEPT: (0, 1, 0, 0, 0),
-             ONLY_THE_INPUT: (1, 2, 1, 2, 2)}[what]
-    # standing in, the FIRST forward's combine is a product inside the form's own checkpoint
+    again = {"the-kept-set": (0, 1, 0, 0, 0), ROWS_MADE_AGAIN: (0, 1, 0, 1, 1), ONLY_THE_INPUT: (1, 2, 1, 1, 1)}[what]
     assert found[("remat2",), "dot_general"] == (MIXERS * (again[0] + 2 * 2) + ATTENTIONS * (2 + 2 * 3)
-                                                 + EXPERT_LAYERS * (again[1] + 2 * 3 + stand_in))
+                                                 + EXPERT_LAYERS * (again[1] + 2 * 3))
     assert +kernels == +collections.Counter({
         "ds_flash_fwd": ATTENTIONS * again[2], "ds_flash_bwd_dkv": ATTENTIONS,
         "ds_ssd_scan_fwd": MIXERS, "ds_ssd_scan_bwd": MIXERS})
@@ -293,6 +294,8 @@ def test_the_second_forward_runs_no_flash_kernel_and_a_product_fewer_a_mixer_and
         assert +second == +collections.Counter({"ragged_dot_general": EXPERT_LAYERS * again[3],
                                                 "gather": EXPERT_LAYERS * again[4]})
         assert own == {"ragged_dot_general": EXPERT_LAYERS * 4, "gather": EXPERT_LAYERS * 3}
+        grouped = sum(n for (_, name), n in found.items() if name == "ragged_dot_general")
+        assert grouped == EXPERT_LAYERS * (2 + again[3] + 4)
         assert not any(name in ("scan", "cond", "while") for path, name in found
                        if "remat2" in path and "jit" not in path and "pallas_call" not in path)
 
@@ -310,8 +313,9 @@ def kept_by_the_layers(what, stand_in=False):
 @pytest.mark.parametrize("shape, count", [
     ((2, 4, 40, 16), ATTENTIONS), ((2, 4, 40), ATTENTIONS), ((2, 40, 2 * 32 + 2 * 2 * 16 + 4), MIXERS),
     ((2, 40, 4), MIXERS), ((2, 40, 40), EXPERT_LAYERS), ((2, 40, 32), MIXERS + EXPERT_LAYERS + ATTENTIONS + 1),
-    ((240, 24), EXPERT_LAYERS), ((3, 80, 32), 0)],
-    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input", "ds_moe_gate_up", "ds_moe_out"])
+    ((240, 24), EXPERT_LAYERS), ((3, 80, 32), 0), ((240, 32), 0)],
+    ids=["attn_out", "attn_lse", "ssm_in", "ssm_dt", "shared_up", "input", "ds_moe_gate_up",
+         "no-tokens-expert-outputs", "no-second-products-rows"])
 def test_a_layer_keeps_each_named_tensor_once(shape, count):
     """The residuals of the layers by shape: the attention keeps ONE kernel output (no second
     ``attn_out`` at the call) and ONE set of row sums, a mixer its first product's output once
@@ -319,13 +323,13 @@ def test_a_layer_keeps_each_named_tensor_once(shape, count):
     product's output once, every layer its input, and nothing else (the last layer's output is
     ``norm_f``'s to keep); under ``policy=None`` the inputs alone. Where the held experts stand
     in, an expert layer keeps the first grouped product's output ``[n k, F]`` besides, once, and
-    not each token's ``k`` expert outputs ``[k, n, H]`` (a set that names them too keeps them once);
+    nothing of the second's: neither each token's ``k`` expert outputs ``[k, n, H]`` nor the sorted
+    rows ``[n k, H]`` (no backward reads them since PR 49, and no name is there to keep them by);
     where they do not, the passes name nothing and keep nothing."""
-    rows = shape in ((240, 24), (3, 80, 32))
+    rows = shape == (240, 24)
     found = kept_by_the_layers("the-kept-set", True)
     assert found[shape] == count, found
     assert sum(found.values()) == 3 * MIXERS + 3 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, found
-    assert kept_by_the_layers(ALL_THE_ROWS_KEPT, True) == found + collections.Counter({(3, 80, 32): EXPERT_LAYERS})
     left_out = kept_by_the_layers("the-kept-set")
     assert left_out[shape] == (0 if rows else count), left_out
     assert sum(left_out.values()) == 3 * MIXERS + 2 * EXPERT_LAYERS + 3 * ATTENTIONS + 1, left_out
@@ -456,8 +460,9 @@ def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
     # standing in (the cell), the rows go through the whole range's form under its own checkpoint:
     # the same scopes; its backward gathers the rows again and makes the activation again under
     # ITS ``rematted_computation``, which ``recompute_time_share`` reads; the layer's second
-    # forward runs the router, the sort, ``w_down``'s product and the gather back, and gathers no
-    # row for ``w_up`` (the layer keeps that product's output by name)
+    # forward runs the router and the sort and nothing of the rows: no gather and no ``w_up`` (the
+    # layer keeps that product's output by name), no ``w_down`` and no gather back (no backward
+    # reads the second product's output: the router's weights are in the rows before it, PR 49)
     _, stands_in, its_params = build(published(stand_in=True, **SHALLOW), remat=True)
     text = jax.jit(jax.grad(lambda p, t, l: stands_in.apply(p, t, l)[0])).lower(
         its_params, tokens, labels).compile().as_text()
@@ -465,9 +470,9 @@ def test_the_scopes_the_benchmark_reads_are_in_the_compiled_programs():
                  r"ds_mlp/checkpoint/ds_moe_experts", r"ds_mlp/checkpoint/ds_moe_combine",
                  r"ds_mlp/checkpoint/rematted_computation/ds_moe_dispatch",
                  r"ds_mlp/checkpoint/rematted_computation/ds_moe_experts",
-                 r"rematted_computation/ds_mlp/ds_moe_router", r"rematted_computation/ds_mlp/ds_moe_dispatch",
-                 r"rematted_computation/ds_mlp/ds_moe_experts", r"rematted_computation/ds_mlp/ds_moe_combine"):
+                 r"rematted_computation/ds_mlp/ds_moe_router", r"rematted_computation/ds_mlp/ds_moe_dispatch"):
         assert re.search(path, text), path
+    assert not re.search(r"rematted_computation/ds_mlp/ds_moe_(experts|combine)", text)
     # the rule runs inside the update program, under the optimizer's scope and its own
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config_params={
         "train_batch_size": 8, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},

@@ -16,7 +16,7 @@ import deepspeed_tpu
 from benchmarks.reference import olmoe_reference as ref
 from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
 from deepspeed_tpu.parallel.mesh import build_mesh
-from deepspeed_tpu.parallel.moe import (DroplessMoE, _combine_rows, _take_rows, experts_matmul, gather_pieces,
+from deepspeed_tpu.parallel.moe import (DroplessMoE, _sort_rows, _sum_rows, _take_rows, experts_matmul, gather_pieces,
                                         piece_firsts)
 from deepspeed_tpu.utils import spans
 from test_ouro import equations_by_path
@@ -226,46 +226,81 @@ def test_grouped_matmul_matches_a_per_expert_loop(sizes, pieces, backward):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("k", [6, 8])
-def test_the_row_paths_cotangents_are_the_plain_formulas(k, dtype):
-    """``_take_rows`` and ``_combine_rows`` against ``x[tok]`` and ``einsum("nkh,nk->nh",
-    ys[inverse], weights)`` under ``jax.vjp``: the rows' cotangent, the weights' and the tokens'.
-    The rows' is also, bit for bit, what laying ``dy x weights`` out as ``[n, k, H]`` in the
-    compute dtype and gathering it by ``order`` gave (the form until PR 46, which pads a token's
-    ``k`` rows to eight: at six the padding is there, at eight it is not)."""
+def test_dispatch_and_combine_are_each_others_transposes(k, dtype):
+    """``_take_rows`` against ``x[tok]`` and ``_sum_rows`` against ``sum_j ys[inverse[:, j]]``, and
+    under ``jax.vjp`` each one's cotangent IS the other, bit for bit: the combine pulls ``dy`` back
+    as one gather by ``tok`` and keeps no row for it (the router's weights are in the rows before
+    ``w_down``, PR 49); the dispatch pulls its rows' cotangents back as the combine's sum. The
+    sorted weights come out of the rows' own sort and their cotangent goes back by ``inverse``."""
     n, H, E = 40, 24, 5
     rng = np.random.default_rng(k)
     sent_to = jnp.asarray(rng.integers(0, E, size=n * k), jnp.int32)
     slots = jnp.arange(n * k, dtype=jnp.int32)
-    order = jax.lax.sort((sent_to, slots), num_keys=1, is_stable=True)[1]
-    inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(n, k)
+    weights = jnp.asarray(rng.random(size=(n, k)), jnp.float32)
+    (by_expert, order, inverse, w_sorted), pull = jax.vjp(lambda w: _sort_rows(sent_to, slots, w), weights)
     tok = order // k
+    assert np.array_equal(by_expert, np.sort(sent_to)) and np.array_equal(sent_to[order], by_expert)
+    assert np.array_equal(order[inverse.reshape(-1)], slots) and np.array_equal(w_sorted, weights.reshape(-1)[order])
+    d_ws = jnp.asarray(rng.normal(size=n * k), jnp.float32)
+    zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)      # noqa: E731
+    assert np.array_equal(pull((zero(by_expert), zero(order), zero(inverse), d_ws))[0],
+                          jnp.zeros(n * k).at[order].set(d_ws).reshape(n, k))
     x = jnp.asarray(rng.normal(size=(n, H)), dtype)
     ys = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
-    weights = jnp.asarray(rng.random(size=(n, k)), jnp.float32)
-    dy = jnp.asarray(rng.normal(size=(n, H)), dtype)
-    dxs = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
 
-    def plain_combine(ys, weights):
-        return jnp.einsum("nkh,nk->nh", ys[inverse].astype(jnp.float32), weights).astype(ys.dtype)
-
-    tolerance = 1e-5 if dtype == jnp.float32 else 3e-2
-    y, pull = jax.vjp(lambda ys, w: _combine_rows(ys, w, inverse, order), ys, weights)
-    want_y, want_pull = jax.vjp(plain_combine, ys, weights)
-    (dys, dw), (want_dys, want_dw) = pull(dy), want_pull(dy)
     xs, pull = jax.vjp(lambda x: _take_rows(x, tok, inverse), x)
-    want_xs, want_pull = jax.vjp(lambda x: x[tok], x)
-    (dx,), (want_dx,) = pull(dxs), want_pull(dxs)
-    # the weighted sum runs over the leading axis of ``[k, n, H]``: float32's order of adding may differ
-    np.testing.assert_allclose(y.astype(jnp.float32), want_y.astype(jnp.float32), rtol=tolerance, atol=tolerance)
-    assert np.array_equal(xs, want_xs)
-    assert dys.dtype == dx.dtype == dtype and dw.dtype == jnp.float32
-    # a row is used once: the plain form's scatter rounds what this gather rounds, once
-    assert np.array_equal(dys, want_dys)
-    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-5)
-    # the plain form adds a token's k rows in the compute dtype, this one in float32
-    np.testing.assert_allclose(dx.astype(jnp.float32), want_dx.astype(jnp.float32), rtol=tolerance, atol=tolerance)
-    spread = (dy.astype(jnp.float32)[:, None, :] * weights[:, :, None]).astype(dtype)       # [n, k, H]
-    assert np.array_equal(dys, spread.reshape(-1, H)[order])
+    assert np.array_equal(xs, x[tok]) and np.array_equal(pull(ys)[0], _sum_rows(ys, tok, inverse))
+    y, pull = jax.vjp(lambda ys: _sum_rows(ys, tok, inverse), ys)
+    assert y.dtype == dtype and np.array_equal(pull(x)[0], xs)
+    # a token's k rows are added in float32 and rounded once; the plain scatter adds in the compute dtype
+    tolerance = 1e-5 if dtype == jnp.float32 else 3e-2
+    want = jnp.sum(ys[inverse].astype(jnp.float32), axis=1)
+    np.testing.assert_allclose(y.astype(jnp.float32), want, rtol=0, atol=0 if dtype == jnp.float32 else tolerance)
+    np.testing.assert_allclose(y.astype(jnp.float32), jax.vjp(lambda x: x[tok], x)[1](ys)[0].astype(jnp.float32),
+                               rtol=tolerance, atol=tolerance)
+
+
+@pytest.mark.parametrize("held, stand_in", [(None, False), ((4, 4), True)], ids=["whole-range", "held-stand-in"])
+@pytest.mark.parametrize("router, k", [("softmax", 8), (("sigmoid_bias", 2.5), 6)], ids=["softmax-k8", "sigmoid_bias-k6"])
+@pytest.mark.parametrize("experts", ["silu_gated", "relu2"])
+def test_the_weights_before_w_down_give_what_they_give_after_it(experts, router, k, held, stand_in):
+    """The layer in float32, whose rows take the router's weights BEFORE ``w_down``
+    (``_activate``), against the plain form that weighs each token's ``k`` expert OUTPUTS
+    (``einsum("nkh,nk->nh")`` after it): ``w_down`` is linear, so the result and every gradient
+    (the input's, the router's, both expert arrays') agree to 1e-6 of their norms."""
+    E, H, F = 16, 32, 24
+    layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=held, router=router, experts=experts, stand_in=stand_in)
+    params = layer.init(jax.random.PRNGKey(k), 0.3)
+    bias = 0.05 * jnp.arange(E, dtype=jnp.float32)
+    if router != "softmax":
+        params["router_bias"] = bias
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, H))
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    first, count = held or (0, E)
+
+    def plain(params, x):
+        x2 = x.reshape(-1, H)
+        logits = jnp.dot(x2, params["router_w"], precision="highest")
+        scores = jax.nn.softmax(logits, axis=-1) if router == "softmax" else jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + (0.0 if router == "softmax" else bias), k)
+        top = jnp.take_along_axis(scores, chosen, -1)
+        top = top / (jnp.sum(top, -1, keepdims=True) + (0.0 if router == "softmax" else 1e-20))
+        top = top if router == "softmax" else 2.5 * top
+        mine = (chosen - first) % count                      # the held expert that computes the row
+        up = jnp.einsum("nh,nkhf->nkf", x2, params[layer.w_in][mine], precision="highest")
+        act = jnp.square(jax.nn.relu(up)) if experts == "relu2" else jax.nn.silu(up[..., :F]) * up[..., F:]
+        out = jnp.einsum("nkf,nkfh->nkh", act, params["w_down"][mine], precision="highest")
+        return jnp.einsum("nkh,nk->nh", out, top).reshape(x.shape)
+
+    loss = lambda f: (lambda p, x: jnp.sum(f(p, x) * cot))      # noqa: E731
+    want, want_grads = jax.jit(plain)(params, x), jax.jit(jax.grad(loss(plain), argnums=(0, 1)))(params, x)
+    got = jax.jit(lambda p, x: layer.apply(p, x)[0])(params, x)
+    grads = jax.jit(jax.grad(loss(lambda p, x: layer.apply(p, x)[0]), argnums=(0, 1)))(params, x)
+    apart = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))      # noqa: E731
+    assert apart(got, want) < 1e-6
+    assert apart(grads[1], want_grads[1]) < 1e-6
+    for name in ("router_w", layer.w_in, "w_down"):
+        assert apart(grads[0][name], want_grads[0][name]) < 1e-6, name
 
 
 @pytest.mark.parametrize("devices", [2, 4], ids=["2dev", "4dev"])
@@ -507,13 +542,14 @@ def test_where_every_row_is_computed_here_the_layer_sorts_once_and_multiplies_on
 def test_no_token_major_rows_are_laid_out_at_a_k_the_tile_does_not_divide():
     """The gradient's jaxpr of a layer whose held experts stand in, three experts a token: no
     value ``[n, 3, H]`` exists (on the chip a relayout of all the rows, to and from ``[n k, H]``,
-    where eight sublanes do not divide ``k``; PERF.md, PR 46). A token's expert outputs and the
-    dispatch's gathered cotangent are ``[k, n, H]``, slot by slot, and ``dy x weights`` is
-    ``[n, 8, H]``, a token's three rows padded to eight, each ``[rows, H]`` as it lies. Of the
-    gathers of rows ``H`` wide, the forward's two read the ``n`` tokens (dispatch) and the ``n k``
-    sorted rows (combine, kept as ``ds_moe_out`` and so not made again); the backward, under the
-    layer's own ``checkpoint``, reads the tokens (the dispatch made again), the ``8 n`` padded
-    rows (the combine's cotangent) and the ``n k`` sorted rows (the dispatch's)."""
+    where eight sublanes do not divide ``k``; PERF.md, PR 46), and none padded to eight slots
+    either (``[n, 8, H]``, the combine's cotangent ``dy x weights`` until PR 49: the weights are
+    in the rows before ``w_down``). A token's expert outputs and the dispatch's gathered cotangent
+    are ``[k, n, H]``, slot by slot, ``[rows, H]`` as it lies. Of the gathers of rows ``H`` wide,
+    the forward's two read the ``n`` tokens (dispatch) and the ``n k`` sorted rows (combine); the
+    backward, under the layer's own ``checkpoint``, reads the tokens twice (the dispatch made
+    again, and the combine's cotangent: ``dy`` as it is) and the ``n k`` sorted rows (the
+    dispatch's), and never the second product's output."""
     E, H, F, k, first, count = (HELD[name] for name in ("E", "H", "F", "k", "first", "count"))
     layer = DroplessMoE(H, F, E, k, norm_topk_prob=True, held=(first, count),
                         router=("sigmoid_bias", 2.5), experts="relu2", stand_in=True)
@@ -524,9 +560,8 @@ def test_no_token_major_rows_are_laid_out_at_a_k_the_tile_does_not_divide():
                                     argnums=(0, 1)))(params, x).jaxpr
     wide = collections.Counter(var.aval.shape for _, eqn in equations_by_path(jaxpr) for var in eqn.outvars
                                if len(var.aval.shape) == 3 and var.aval.shape[-1] == H)
-    assert (n, k, H) not in wide and wide[(k, n, H)] and wide[(n, 8, H)], wide
-    row_gathers = collections.Counter(
-        (path, eqn.invars[0].aval.shape[0]) for path, eqn in equations_by_path(jaxpr)
+    assert (n, k, H) not in wide and (n, 8, H) not in wide and wide[(k, n, H)], wide
+    row_gathers = collections.Counter(     # by the checkpoint they are under, whichever of the pair's rules holds them
+        (tuple(p for p in path if p != "custom_vjp_call"), eqn.invars[0].aval.shape[0]) for path, eqn in equations_by_path(jaxpr)
         if eqn.primitive.name == "gather" and eqn.invars[0].aval.shape[1:] == (H,))
-    assert row_gathers == {((), n): 1, ((), n * k): 1, (("remat2",), n): 1, (("remat2",), n * 8): 1,
-                           (("remat2",), n * k): 1}, row_gathers
+    assert row_gathers == {((), n): 1, ((), n * k): 1, (("remat2",), n): 2, (("remat2",), n * k): 1}, row_gathers

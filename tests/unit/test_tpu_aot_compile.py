@@ -258,15 +258,24 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     for kernel in ("ds_ssd_scan_fwd", "ds_ssd_scan_bwd", "ds_causal_conv_fwd", "ds_flash_fwd", "gmm"):
         assert kernel in text, kernel
     assert_the_flash_forward_runs_once(text)
-    # 3.65 GB as compiled here since the stand-in experts' rows go through the whole range's form and
-    # a layer keeps the first grouped product's output (PR 42: 3.08 GB with the form alone, 0.73 GB
-    # the four kept outputs; 4.70 with each token's expert outputs kept too, which leaves one step
-    # in flight). With the passes it was 3.43 GB, their loops counted twice (PR 41; 1.39 GB under
-    # policy None, PR 40): the mixers' first product's output is 0.68 GB of it, the shared expert's 0.49
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.65e9 * 1.05
+    # 3.08 GB as compiled here since the router's weights go to the rows before ``w_down`` and a
+    # layer's second forward makes nothing of the second product again (PR 49; 3.37 at PR 46, 3.65
+    # at PR 42, when the stand-in experts' rows took the whole range's form and a layer began to
+    # keep the first grouped product's output, 0.73 GB over four layers). With the passes it was
+    # 3.43 GB, their loops counted twice (PR 41; 1.39 GB under policy None, PR 40): the mixers'
+    # first product's output is 0.68 GB of it, the shared expert's 0.49
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.08e9 * 1.05
     # six rows a token: no float32 copy of all the rows is laid out, broadcast or moved to another
-    # layout between the tokens and the sorted rows (three passes a layer until PR 46)
+    # layout between the tokens and the sorted rows (three passes a layer until PR 46), and none
+    # padded to eight slots a token (the combine's cotangent ``dy x weights`` until PR 49)
     assert not re.search(r"= f32\[(8192,6,2688|49152,2688)\]\S* (broadcast|reshape|copy|transpose)\(", text)
+    assert "f32[8192,8,2688]" not in text
+    # ``w_down``'s product and its rows' cotangent once a layer, four layers, and NOT the product
+    # made again in a layer's second forward (twelve such calls until PR 49)
+    assert len(re.findall(r"= bf16\[49152,2688\]\S* custom-call\(", text)) == 8
+    # the weights' gradient is a row sum inside the ONE pass that writes the first product's
+    # cotangent (and the weighted activation made again), never a pass of its own over the rows
+    assert len(re.findall(r"= \(f32\[49152\]\S*, bf16\[49152,1856\]\S*, bf16\[49152,1856\]\S*\) fusion\(", text)) == 4
     # the rows' products once a call over all 49,152 rows, never a pass of 8,192 under a loop
     assert "49152,1856" in text and not re.search(r"bf16\[8192,1856\]\S* custom-call", text)
 
