@@ -244,127 +244,7 @@ def collective_axis_breakdown(hlo_text, slice_sets):
     return out
 
 
-# ------------------------------------------------------- async start/done pairs
-# Post-scheduling HLO splits an overlappable collective into a `-start` that
-# launches the transfer and a `-done` that blocks on it; every instruction the
-# scheduler placed between the two runs concurrently with the wire, so that
-# window is what a collective has to hide under. Two syntactic forms exist:
-# dedicated start/done ops (`all-reduce-start` / `all-reduce-done`) and the
-# generic wrapper (`async-start(...), calls=%comp` holding the collective
-# inside the called computation, optionally chained through `async-update`).
-
 _DEF_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+) = ")
-_ASYNC_DONE_RE = re.compile(
-    r"= .*?(" + "|".join(COLLECTIVE_OPS) + r"|async)-done\(([^)]*)\)")
-_ASYNC_UPDATE_RE = re.compile(r"= .*?async-update\(([^)]*)\)")
-_ASYNC_WRAPPER_RE = re.compile(r"= .*? async-start\(")
-_CALLS_RE = re.compile(r"calls=%?([\w.-]+)")
-_COMP_HEADER_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+)\s+(?:\([^{]*\))?\s*"
-                             r"(?:->\s*[^{]*)?\{\s*$")
-
-
-def _operand_name(operand_text):
-    """Instruction name from a (possibly type-annotated) operand: both
-    ``f32[1024]{0} %ars`` and ``%ars``/``ars`` yield ``ars``."""
-    toks = operand_text.strip().split()
-    return toks[-1].lstrip("%") if toks else ""
-
-
-def _called_computation_window(lines, comp_name):
-    """Line-index range (start, stop) of computation ``comp_name``'s body."""
-    for i, line in enumerate(lines):
-        m = _COMP_HEADER_RE.match(line)
-        if m and m.group(1) == comp_name:
-            for j in range(i + 1, len(lines)):
-                if lines[j].strip().startswith("}"):
-                    return i + 1, j
-            return i + 1, len(lines)
-    return None
-
-
-def parse_async_pairs(hlo_text):
-    """Pair every async collective ``-start`` with its ``-done`` across the
-    program text. Returns one dict per pair, in done order::
-
-        {"op": base op, "name": start instruction name, "done": done name,
-         "start_line": int, "done_line": int,   # indices into splitlines()
-         "bytes": per-device transfer bytes, "groups": replica groups or None}
-
-    Dedicated forms (``all-reduce-start`` ...) read bytes/groups off the start
-    line with the same tuple conventions as ``collective_results``; generic
-    ``async-start`` wrappers resolve ``calls=`` to the inner collective, and
-    ``async-update`` chains forward to the original start. A ``-done`` whose
-    operand resolves to no known start raises ``ValueError`` — a malformed
-    program must fail loudly, not silently drop a collective from the ledger.
-    """
-    lines = hlo_text.splitlines()
-    starts = {}   # start name -> pair dict (without done fields yet)
-    alias = {}    # async-update result name -> upstream operand name
-    pairs = []
-    for i, line in enumerate(lines):
-        m_op = _OP_RE.search(line)
-        if m_op and m_op.group(3):  # dedicated `<op>-start`
-            name_m = _DEF_NAME_RE.match(line)
-            if not name_m:
-                continue
-            ty, op, _ = m_op.groups()
-            b = sum(_elements(dims) * _DTYPE_BYTES[dt]
-                    for dt, dims in _result_shapes(ty, op, True)
-                    if dt in _DTYPE_BYTES)
-            starts[name_m.group(1)] = {
-                "op": op, "name": name_m.group(1), "start_line": i,
-                "bytes": b, "groups": parse_replica_groups(line),
-                "inner_line": None}
-            continue
-        if _ASYNC_WRAPPER_RE.search(line):  # generic wrapper form
-            name_m = _DEF_NAME_RE.match(line)
-            calls_m = _CALLS_RE.search(line)
-            if not name_m:
-                continue
-            op, b, groups, inner_line = None, 0, None, None
-            if calls_m:
-                window = _called_computation_window(lines, calls_m.group(1))
-                if window:
-                    for k in range(window[0], window[1]):
-                        m_in = _OP_RE.search(lines[k])
-                        if m_in:
-                            ty, op, is_start = m_in.groups()
-                            b = sum(_elements(dims) * _DTYPE_BYTES[dt]
-                                    for dt, dims in
-                                    _result_shapes(ty, op, bool(is_start))
-                                    if dt in _DTYPE_BYTES)
-                            groups = parse_replica_groups(lines[k])
-                            inner_line = k
-                            break
-            if op is not None:
-                starts[name_m.group(1)] = {
-                    "op": op, "name": name_m.group(1), "start_line": i,
-                    "bytes": b, "groups": groups, "inner_line": inner_line}
-            continue
-        m_upd = _ASYNC_UPDATE_RE.search(line)
-        if m_upd:
-            name_m = _DEF_NAME_RE.match(line)
-            if name_m:
-                alias[name_m.group(1)] = _operand_name(m_upd.group(1))
-            continue
-        m_done = _ASYNC_DONE_RE.search(line)
-        if m_done:
-            done_m = _DEF_NAME_RE.match(line)
-            operand = _operand_name(m_done.group(2))
-            seen = set()
-            while operand in alias and operand not in seen:  # update chains
-                seen.add(operand)
-                operand = alias[operand]
-            pair = starts.pop(operand, None)
-            if pair is None:
-                raise ValueError(
-                    f"async {m_done.group(1)}-done "
-                    f"{done_m.group(1) if done_m else '<unnamed>'!r} has no "
-                    f"matching -start for operand {operand!r}")
-            pair["done"] = done_m.group(1) if done_m else ""
-            pair["done_line"] = i
-            pairs.append(pair)
-    return pairs
 
 
 # ------------------------------------------------------- metadata / identity
@@ -410,37 +290,7 @@ def instruction_op_names(hlo_text):
     return out
 
 
-# per-instruction cost estimates for the overlap-window pricing: a window's
-# compute capacity is what the scheduler placed between -start and -done,
-# priced as max(dot flops / peak, result bytes / HBM bandwidth)
-_DOT_LINE_RE = re.compile(r"= (\S+) dot\(([^)]*)\)")
-_LHS_CDIMS_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _RESULT_TY_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.-]+ = (\([^)]*\)|\S+) ")
-
-
-def dot_flops_estimate(line):
-    """2 * result_elements * contraction_size for one ``dot`` instruction line,
-    reading the contraction off the lhs operand's inline type annotation
-    (optimized HLO always annotates). 0 when the line is not an annotated dot
-    — the overlap estimate stays conservative (no phantom compute credit)."""
-    m = _DOT_LINE_RE.search(line)
-    if not m:
-        return 0
-    result = _shaped_types(m.group(1))
-    cd = _LHS_CDIMS_RE.search(line)
-    if not result or not cd:
-        return 0
-    operands = _split_top_level(m.group(2))
-    lhs = _shaped_types(operands[0]) if operands else []
-    if not lhs:
-        return 0
-    cdims = [int(d) for d in cd.group(1).split(",") if d]
-    contraction = 1
-    for d in cdims:
-        if d >= len(lhs[0][1]):
-            return 0
-        contraction *= lhs[0][1][d]
-    return 2 * _elements(result[0][1]) * contraction
 
 
 def result_bytes(line):
@@ -755,3 +605,455 @@ def lossy_convert_roundtrips(hlo_text):
             if b_mid and b_end and b_mid < b_end:
                 hits.append((operand, (dst, src, dst)))
     return hits
+
+
+# ------------------------------------------------- pricing: operations and bytes
+# What every compiled operation HAS to do, from the optimized text alone: the operations
+# of its products and a floor of its HBM traffic. Counts only: no peak enters here. An
+# operation is an instruction the device runs on its own and a trace shows as an event:
+# one of the entry computation or of the body of a ``while``, ``conditional`` or ``call``
+# reached from it. What a fused computation holds is priced INTO its fusion. This JAX's
+# ``as_text()`` names operands and gives them no type, so every type is read from the
+# operand's definition line: one pass over the text fills a name -> instruction table a
+# computation, and the pricing reads that.
+_TYPE_LEAF_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{([^{}]*)\})?")
+_MEMORY_SPACE_RE = re.compile(r"S\((\d+)\)")
+_OPCODE_RE = re.compile(r"[\w-]+")
+_ATTR_NAME_RE = r"=%?([\w.-]+)"
+_CALLS_RE = re.compile("calls" + _ATTR_NAME_RE)
+_CALLED_RES = {"fusion": [_CALLS_RE],
+               "call": [re.compile("to_apply" + _ATTR_NAME_RE)],
+               "while": [re.compile("body" + _ATTR_NAME_RE), re.compile("condition" + _ATTR_NAME_RE)],
+               "conditional": [re.compile("(?:true|false)_computation" + _ATTR_NAME_RE)]}
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+_DIMS_ATTR_RE = re.compile(r"(\w+)=\{([\d,]*)\}")
+_WINDOW_RE = re.compile(r"window=\{([^}]*)\}")
+_DIM_LABELS_RE = re.compile(r"dim_labels=(\w+)_(\w+)->(\w+)")
+_BRACKET_RE = re.compile(r"[()]")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")          # ``/*index=5*/`` before every fifth operand
+
+# no bytes move: views, tuples, and the bookkeeping of a transfer that runs under other
+# operations (the wait in a ``-done`` is time over a floor of nothing)
+_FREE_OPS = frozenset(("bitcast", "tuple", "get-tuple-element", "parameter", "constant",
+                       "copy-start", "copy-done", "async-start", "async-update", "async-done",
+                       "after-all", "partition-id", "replica-id", "opt-barrier", "add-dependency"))
+# not priced (collectives, the links' work, are told by ``_is_collective``): a kernel has its
+# own metric against the ALGORITHM's need, and the control flow's own instruction encloses
+# operations that are priced
+_UNPRICED_OPS = frozenset(("custom-call", "while", "conditional", "call", "send", "send-done",
+                           "recv", "recv-done", "infeed", "outfeed"))
+_SLICING_OPS = frozenset(("slice", "dynamic-slice", "gather"))
+# what a fused computation evaluates an element at a time: a slice on the far side of
+# these reads what it takes and no more
+_ELEMENTWISE_VIEWS = frozenset(("bitcast", "reshape", "convert", "copy", "transpose"))
+
+
+class _Instruction:
+    __slots__ = ("name", "type", "opcode", "operands", "attrs", "root")
+
+    def __init__(self, name, type, opcode, operands, attrs, root):
+        self.name, self.type, self.opcode = name, type, opcode
+        self.operands, self.attrs, self.root = operands, attrs, root
+
+
+def _closing(text, at):
+    """Index of the bracket that closes the one at ``text[at]``."""
+    depth = 0
+    for m in _BRACKET_RE.finditer(text, at):
+        depth += 1 if m.group(0) == "(" else -1
+        if depth == 0:
+            return m.start()
+    return len(text) - 1
+
+
+def _parse_instruction(line):
+    """``[ROOT] %name = TYPE opcode(operands), attributes`` or None."""
+    m = _DEF_NAME_RE.match(line)
+    if m is None:
+        return None
+    rest = line[m.end():]
+    if rest.startswith("("):                     # a tuple type; its layouts hold brackets too
+        end = _closing(rest, 0) + 1
+        type_str, rest = rest[:end], rest[end:].lstrip()
+    else:
+        type_str, _, rest = rest.partition(" ")
+    op = _OPCODE_RE.match(rest)
+    if op is None or not rest.startswith("(", op.end()):
+        return None
+    end = _closing(rest, op.end())
+    inside = rest[op.end() + 1:end]
+    if op.group(0) == "parameter":
+        operands = (inside.strip(),)             # its number, not a name
+    elif op.group(0) == "constant":
+        operands = ()
+    else:
+        operands = tuple(tok.split()[-1].lstrip("%")
+                         for tok in _split_top_level(_COMMENT_RE.sub("", inside)))
+    return _Instruction(m.group(1), type_str, op.group(0), operands, rest[end + 1:],
+                        line.lstrip().startswith("ROOT"))
+
+
+def _parse_computations(hlo_text):
+    """``({computation: {instruction name: _Instruction}}, entry computation's name)``,
+    every table in the order of the text."""
+    computations, entry, current = {}, None, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            if line.endswith("{") and " = " not in line and not line.startswith((" ", "HloModule")):
+                head = line[:-1].split("(", 1)[0].split()
+                if not head:
+                    continue
+                name = head[-1].lstrip("%")
+                current = computations[name] = {}
+                if head[0] == "ENTRY":
+                    entry = name
+        elif line.startswith("}"):
+            current = None
+        else:
+            instruction = _parse_instruction(line)
+            if instruction is not None:
+                current[instruction.name] = instruction
+    return computations, entry
+
+
+def _leaves(type_str):
+    """[(dtype, dims, in HBM)] of a type, tuples flattened. A layout that names a memory
+    space (``S(1)``: the compiler put the value in on-chip memory) is not HBM's."""
+    out = []
+    for dt, dims, layout in _TYPE_LEAF_RE.findall(type_str):
+        space = _MEMORY_SPACE_RE.search(layout)
+        out.append((dt, tuple(int(d) for d in dims.split(",") if d),
+                    space is None or space.group(1) == "0"))
+    return out
+
+
+def _hbm_bytes(type_str):
+    return sum(_elements(dims) * _DTYPE_BYTES.get(dt, 0)
+               for dt, dims, in_hbm in _leaves(type_str) if in_hbm)
+
+
+def _dims_attrs(attrs):
+    return {k: [int(d) for d in v.split(",") if d] for k, v in _DIMS_ATTR_RE.findall(attrs)}
+
+
+def _product_types(instruction, table):
+    """``((dtype, dims) of the result, of the lhs, of the rhs)`` of a ``dot`` or a
+    ``convolution``, the operands' read off their DEFINITIONS; None where one is missing."""
+    found = [_leaves(instruction.type)] + [_leaves(table[name].type) if name in table else []
+                                           for name in instruction.operands[:2]]
+    if len(found) < 3 or not all(found):
+        return None
+    return [leaves[0][:2] for leaves in found]
+
+
+def _dot_product(instruction, table):
+    """``(flops, [M, K, N, types])`` of one ``dot``: 2 x result elements x contraction."""
+    types, dims = _product_types(instruction, table), _dims_attrs(instruction.attrs)
+    if types is None or "lhs_contracting_dims" not in dims:
+        return 0, None
+    (out_t, out_d), (lhs_t, lhs_d), (rhs_t, rhs_d) = types
+    try:
+        k = _elements(lhs_d[d] for d in dims["lhs_contracting_dims"])
+    except IndexError:
+        return 0, None
+    taken = set(dims.get("rhs_contracting_dims", ())) | set(dims.get("rhs_batch_dims", ()))
+    n = _elements(size for d, size in enumerate(rhs_d) if d not in taken)
+    elements = _elements(out_d)
+    return 2 * elements * k, [elements // max(n, 1), k, n, f"{lhs_t}x{rhs_t}->{out_t}"]
+
+
+def _window_pairs(n, out, size, stride, lo, lhs_dilate, rhs_dilate):
+    """How many (output position, tap) pairs of one spatial dimension read an element of
+    the input and not its padding: what a convolution multiplies there. A batched matmul
+    written as a convolution (``size=32 pad=31_31`` over an input of 1) has one a position."""
+    reach = (n - 1) * lhs_dilate                  # the last input position, dilated
+    pairs = 0
+    for tap in range(size):
+        shift = tap * rhs_dilate - lo             # position = o * stride + shift
+        first = max(0, -(shift // stride))        # ceil(-shift / stride)
+        last = min(out - 1, (reach - shift) // stride)
+        if last >= first:
+            pairs += (last - first + 1) // lhs_dilate if lhs_dilate > 1 else last - first + 1
+    return pairs
+
+
+def _window(attrs, rank):
+    found = _WINDOW_RE.search(attrs)
+    fields = dict(f.split("=", 1) for f in found.group(1).split()) if found else {}
+
+    def per_dim(key, default):
+        if key not in fields:
+            return [default] * rank
+        return [tuple(int(x) for x in d.split("_")) if "_" in d else int(d)
+                for d in fields[key].split("x")]
+    return (per_dim("size", 1), per_dim("stride", 1), per_dim("pad", (0, 0)),
+            per_dim("lhs_dilate", 1), per_dim("rhs_dilate", 1))
+
+
+def _convolution_product(instruction, table):
+    """``(flops, [M, K, N, types])`` of one ``convolution`` (how the chip's compiler
+    spells most matmuls): 2 x the multiplications that read no padding."""
+    types, labels = _product_types(instruction, table), _DIM_LABELS_RE.search(instruction.attrs)
+    if types is None or labels is None:
+        return 0, None
+    (out_t, out_d), (lhs_t, lhs_d), (rhs_t, rhs_d) = types
+    lhs_l, rhs_l, out_l = labels.groups()
+    if (len(lhs_l), len(rhs_l), len(out_l)) != (len(lhs_d), len(rhs_d), len(out_d)):
+        return 0, None
+    rank = len(out_l) - 2
+    size, stride, pad, lhs_dilate, rhs_dilate = _window(instruction.attrs, rank)
+    try:
+        n = out_d[out_l.index("f")]
+        macs = out_d[out_l.index("b")] * n * rhs_d[rhs_l.index("i")]
+        for d in range(rank):
+            macs *= _window_pairs(lhs_d[lhs_l.index(str(d))], out_d[out_l.index(str(d))],
+                                  size[d], stride[d], pad[d][0], lhs_dilate[d], rhs_dilate[d])
+    except (ValueError, IndexError, TypeError):
+        return 0, None
+    elements = max(_elements(out_d), 1)
+    k = macs // elements if macs % elements == 0 else macs / elements
+    return 2 * macs, [elements // max(n, 1), k, n, f"{lhs_t}x{rhs_t}->{out_t}"]
+
+
+_PRODUCT_OPS = {"dot": _dot_product, "convolution": _convolution_product}
+
+
+def _products(table, computations, seen=()):
+    """(flops, [[M, K, N, types], ...]) of every ``dot`` and ``convolution`` in a
+    computation and in the computations its fusions and calls name."""
+    flops, found = 0, []
+    for instruction in table.values():
+        if instruction.opcode in _PRODUCT_OPS:
+            f, product = _PRODUCT_OPS[instruction.opcode](instruction, table)
+            flops += f
+            if product is not None:
+                found.append(product)
+        elif instruction.opcode in ("fusion", "call"):
+            for name in _called(instruction):
+                if name in computations and name not in seen:
+                    f, more = _products(computations[name], computations, seen + (name,))
+                    flops += f
+                    found.extend(more)
+    return flops, found
+
+
+def _called(instruction):
+    """The computations an instruction names as its body, branches or callee."""
+    names = [m.group(1) for regex in _CALLED_RES.get(instruction.opcode, ())
+             for m in [regex.search(instruction.attrs)] if m]
+    if instruction.opcode == "conditional":
+        branches = _BRANCHES_RE.search(instruction.attrs)
+        if branches:
+            names += [b.strip().lstrip("%") for b in branches.group(1).split(",") if b.strip()]
+    return names
+
+
+def _is_collective(instruction):
+    return instruction.opcode.startswith(COLLECTIVE_OPS + ("collective-broadcast", "ragged-all-to-all"))
+
+
+def _is_kernel(instruction):
+    return instruction.opcode == "custom-call" and "tpu_custom_call" in instruction.attrs
+
+
+def _holds(table, what):
+    """Whether a fused or wrapped computation holds a kernel (``_is_kernel``: a custom call
+    that only tells the compiler something, ``AssumeGatherIndicesInBound``, is none) or a
+    collective (``_is_collective``)."""
+    return any(what(i) for i in table.values())
+
+
+def _through_views(instruction, table):
+    """The instruction at the far end of a chain of views and converts."""
+    while instruction.opcode in _ELEMENTWISE_VIEWS and instruction.operands:
+        source = table.get(instruction.operands[0])
+        if source is None:
+            break
+        instruction = source
+    return instruction
+
+
+def _uses(table):
+    """``({name: [(user, operand position)]}, {parameter number: parameter}, root)`` of a
+    computation."""
+    users, parameters, root = {}, {}, None
+    for inner in table.values():
+        if inner.opcode == "parameter":
+            parameters[int(inner.operands[0])] = inner
+        else:
+            for position, name in enumerate(inner.operands):
+                users.setdefault(name, []).append((inner, position))
+        if inner.root:
+            root = inner
+    return users, parameters, root
+
+
+def _read_of(parameter, users, full, computations):
+    """HBM bytes a fused computation reads of one parameter: what its slices take where
+    it is reached through slices alone (its own, or those of a fusion nested in it),
+    nothing where it is the buffer a ``dynamic-update-slice`` writes into, else all of it.
+    None where the computation hands the parameter on as a view (a nested
+    ``bitcast_fusion``): what is read of it is then its user's to say. The smaller where
+    in doubt."""
+    leaves = _leaves(parameter.type)
+    if not full or len(leaves) != 1:
+        return full
+    width = _DTYPE_BYTES.get(leaves[0][0], 0)
+    taken, todo, handed_on = 0, [parameter], False
+    while todo:
+        value = todo.pop()
+        handed_on = handed_on or value.root
+        for user, position in users.get(value.name, ()):
+            inner = None
+            if user.opcode == "fusion" and (_called(user) or [None])[0] in computations:
+                inner_users, inner_parameters, _ = _uses(computations[_called(user)[0]])
+                if position in inner_parameters:
+                    inner = _read_of(inner_parameters[position], inner_users, full, computations)
+                else:
+                    inner = full
+            if user.opcode in _ELEMENTWISE_VIEWS or (user.opcode == "fusion" and inner is None):
+                todo.append(user)
+            elif user.opcode in _SLICING_OPS and position == 0:
+                taken += sum(_elements(dims) for _, dims, _ in _leaves(user.type)) * width
+            elif user.opcode == "fusion":
+                taken += inner
+            elif not (user.opcode == "dynamic-update-slice" and position == 0):
+                return full
+            if taken >= full:
+                return full
+    return None if handed_on else taken
+
+
+def _written_by(root, table):
+    """HBM bytes a computation's result costs to write: every leaf once, and a leaf that
+    is a ``dynamic-update-slice`` (into a buffer the result aliases) at the update's size."""
+    elements = [table.get(n) for n in root.operands] if root.opcode == "tuple" else [root]
+    total = 0
+    for element in elements:
+        if element is None:
+            continue
+        source = _through_views(element, table)
+        update = table.get(source.operands[1]) if (source.opcode == "dynamic-update-slice"
+                                                   and len(source.operands) > 1) else None
+        for dt, dims, in_hbm in _leaves(element.type):
+            if in_hbm:
+                if update is not None:
+                    dims = max((d for _, d, _ in _leaves(update.type)), key=_elements, default=dims)
+                total += _elements(dims) * _DTYPE_BYTES.get(dt, 0)
+    return total
+
+
+def _fusion_bytes(instruction, called, computations):
+    """The floor of a fusion's HBM traffic: its result written once, each distinct operand
+    read once at what the fused computation takes of it."""
+    users, parameters, root = _uses(called)
+    written = _written_by(root, called) if root is not None else _hbm_bytes(instruction.type)
+    read = {}
+    for index, operand in enumerate(instruction.operands):
+        parameter = parameters.get(index)
+        if parameter is None:
+            continue
+        full = _hbm_bytes(parameter.type)
+        taken = _read_of(parameter, users, full, computations)
+        read[operand] = min(full, read.get(operand, 0) + (full if taken is None else taken))
+    return written + sum(read.values())
+
+
+def _plain_bytes(instruction, table):
+    """The floor of an unfused instruction's HBM traffic."""
+    written = _hbm_bytes(instruction.type)
+    sizes = {name: _hbm_bytes(table[name].type) for name in instruction.operands if name in table}
+    first = instruction.operands[0] if instruction.operands else None
+    if instruction.opcode in _SLICING_OPS and first in sizes:
+        sizes[first] = min(sizes[first], written)
+    elif instruction.opcode == "dynamic-update-slice" and len(instruction.operands) > 1:
+        update = sizes.get(instruction.operands[1], 0)
+        sizes[first], written = 0, min(written, update)
+    elif instruction.opcode == "scatter" and len(instruction.operands) > 2:
+        updates = sizes.get(instruction.operands[2], 0)
+        sizes[first], written = 0, min(written, updates)
+    return written + sum(sizes.values())
+
+
+def _async_wrapped(instruction, table, computations):
+    """The computation an ``async-start`` wraps, for the start, its updates and its done."""
+    while instruction is not None and instruction.opcode.startswith("async"):
+        wrapped = _CALLS_RE.search(instruction.attrs)
+        if wrapped:
+            return computations.get(wrapped.group(1), {})
+        instruction = table.get(instruction.operands[0]) if instruction.operands else None
+    return {}
+
+
+def instruction_costs(hlo_text):
+    """``{"cost", "products", "collectives"}`` of an optimized program's text.
+
+    ``cost``: ``{instruction: [flops, bytes]}`` for every instruction the device runs as
+    an operation of its own. ``flops`` are the PRODUCTS' alone, 2 x result elements x
+    contraction of every ``dot`` and ``convolution`` in the instruction or in what it
+    fuses (elementwise arithmetic has its bytes for a floor). ``bytes`` is a FLOOR of the
+    HBM traffic: each result written once and each distinct operand read once; an operand
+    reached only through a slice at what is taken, a ``dynamic-update-slice`` at the
+    update's size, a value the compiler keeps in on-chip memory (``S(1)`` in its layout)
+    at nothing, views and the bookkeeping of asynchronous copies at nothing. Where the
+    text does not settle a reading the smaller is taken: a share built on this reads low.
+    Kernels (``tpu_custom_call``), collectives and the control flow's own instructions are
+    absent. ``products``: ``{instruction: {"mkn": [[M, K, N, "bf16xbf16->f32"], ...],
+    "as": the tuple element a tuple-valued instruction's product fills, or None}}`` for the
+    instructions that hold a product. ``collectives``: the instructions that are a
+    collective or only wrap one (a reduce-scatter the compiler wrote as a fusion, an
+    ``async-start``), which a trace's name does not always tell; a fusion that holds a
+    product beside a small collective is priced as the product it is."""
+    computations, entry = _parse_computations(hlo_text)
+    cost, products, collectives = {}, {}, []
+    todo, run = [entry] if entry in computations else [], set()
+    while todo:
+        name = todo.pop()
+        if name in run or name not in computations:
+            continue
+        run.add(name)
+        table = computations[name]
+        for instruction in table.values():
+            opcode = instruction.opcode
+            if opcode in ("while", "conditional", "call"):
+                todo.extend(_called(instruction))
+            if _is_collective(instruction):
+                collectives.append(instruction.name)
+            elif opcode in _FREE_OPS:
+                if _holds(_async_wrapped(instruction, table, computations), _is_collective):
+                    collectives.append(instruction.name)
+                else:
+                    cost[instruction.name] = [0, 0]
+            elif opcode == "fusion":
+                called = computations.get((_called(instruction) or [None])[0])
+                if called is None or _holds(called, _is_kernel):
+                    continue
+                flops, found = _products(called, computations)
+                if not found and _holds(called, _is_collective):
+                    collectives.append(instruction.name)
+                    continue
+                cost[instruction.name] = [flops, _fusion_bytes(instruction, called, computations)]
+                if found:
+                    products[instruction.name] = {"mkn": found,
+                                                  "as": _product_element(instruction.type, found)}
+            elif opcode not in _UNPRICED_OPS:
+                flops, product = _PRODUCT_OPS.get(opcode, lambda *_: (0, None))(instruction, table)
+                cost[instruction.name] = [flops, _plain_bytes(instruction, table)]
+                if product is not None:
+                    products[instruction.name] = {"mkn": [product], "as": None}
+    return {"cost": cost, "products": products, "collectives": collectives}
+
+
+def _product_element(type_str, found):
+    """For a tuple-valued instruction, the element its largest product fills, as a trace
+    would print it (``bf16[8192,16384]``): the first whose size is the product's M x N,
+    else the largest. None for an instruction with one result."""
+    if not type_str.startswith("("):
+        return None
+    leaves = _leaves(type_str)
+    if not leaves:
+        return None
+    m, _, n, _ = max(found, key=lambda p: p[0] * p[2])
+    best = next((leaf for leaf in leaves if _elements(leaf[1]) == m * n),
+                max(leaves, key=lambda leaf: _elements(leaf[1])))
+    return f"{best[0]}[{','.join(str(d) for d in best[1])}]"

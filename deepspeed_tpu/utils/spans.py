@@ -16,7 +16,9 @@ fetches from it or adds to a compiled program.
     rec.spans()        # the ring, oldest first
     rec.counters(eid)  # {"program.builds[loss_and_grad]": 2, ...}
     rec.programs(eid)  # lazy: {program: {"module": ..., "ops": {instruction: op_name},
-                       #                  "memory": {"argument": bytes, ..., "code": bytes}}}
+                       #                  "memory": {"argument": bytes, ..., "code": bytes},
+                       #                  "cost": {instruction: [flops, bytes]}, "products": {...},
+                       #                  "collectives": [instruction, ...]}}
 
 A span's parent is the innermost span of its engine that was open on the same thread
 when it began (two engines may take turns on one thread), or of any engine if it names
@@ -222,7 +224,7 @@ class Programs:
 
     def __init__(self):
         self._kept = {}              # program -> (jitted, abstract arguments)
-        self._catalog = {}           # program -> {"module", "ops"}
+        self._catalog = {}           # program -> {"module", "ops", "memory", "cost", ...}
 
     def keep(self, program, jitted, args):
         """Remember how ``program`` was last built, so that ``catalog`` can ask the
@@ -238,12 +240,18 @@ class Programs:
 
     def catalog(self):
         """``{program: {"module": HloModule name, "ops": {instruction: op_name}, "memory":
-        {"argument", "output", "alias", "temp", "code": bytes}}}`` for the step programs the
-        engine has run: every instruction of the optimized program with the scope path JAX
-        gave it ("" where the compiler made it up), and the program's own need of device
-        memory as the compiler states it (``memory_analysis()``; None where the backend
-        states none). Computed on request and kept: it compiles (or loads from the
-        persistent cache), so nobody asks inside a measured window."""
+        {"argument", "output", "alias", "temp", "code": bytes}, "cost": {instruction:
+        [flops, bytes]}, "products": {instruction: {"mkn": [[M, K, N, types], ...], "as":
+        ...}}, "collectives": [instruction, ...]}}`` for the step programs the engine has run: every instruction of the
+        optimized program with the scope path JAX gave it ("" where the compiler made it
+        up); the program's own need of device memory as the compiler states it
+        (``memory_analysis()``; None where the backend states none); and what every
+        operation the device runs on its own HAS to do, its products' operations and a
+        floor of its HBM bytes, read from the same text (``hlo.instruction_costs``: counts,
+        no peak; kernels and collectives are absent from ``cost``, and ``collectives`` names
+        the instructions that are one or only wrap one). Computed on request and kept: it
+        compiles (or loads from the persistent cache), so nobody asks inside a measured
+        window."""
         from . import hlo
         for program, (jitted, args) in self._kept.items():
             if program not in self._catalog:
@@ -252,7 +260,8 @@ class Programs:
                 ops = dict.fromkeys(hlo.instruction_names(text), "")
                 ops.update(hlo.instruction_op_names(text))
                 self._catalog[program] = {"module": hlo.module_name(text), "ops": ops,
-                                          "memory": _memory_sizes(compiled)}
+                                          "memory": _memory_sizes(compiled),
+                                          **hlo.instruction_costs(text)}
         return dict(self._catalog)
 
 

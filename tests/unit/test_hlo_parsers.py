@@ -7,8 +7,6 @@ bracketed layout types inside entry layouts — so a parser regression fails
 here with a two-line diff instead of inside an engine-scale lint run.
 """
 
-import pytest
-
 from deepspeed_tpu.utils import hlo
 
 # async all-gather-start: (operands..., results..., u32 context scalars).
@@ -100,111 +98,6 @@ def test_entry_layout_types_split_past_bracketed_layouts():
         [("f32", (8, 8)), ("bf16", (64,)), ("f32", (4,))]
     assert hlo.entry_result_types(ALIAS_HEADER) == \
         [("f32", (8, 8)), ("pred", ()), ("bf16", (64,))]
-
-
-# post-scheduling overlap window: compute placed between -start and -done,
-# explicit replica groups on the start line
-OVERLAP_WINDOW = """
-HloModule m
-
-ENTRY main {
-  p0 = f32[1024]{0} parameter(0)
-  a = f32[64,64]{1,0} parameter(1)
-  b = f32[64,64]{1,0} parameter(2)
-  ars = f32[1024]{0} all-reduce-start(p0), replica_groups={{0,1,2,3},{4,5,6,7}}, to_apply=add
-  d = f32[64,64]{1,0} dot(f32[64,64]{1,0} a, f32[64,64]{1,0} b), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  ard = f32[1024]{0} all-reduce-done(f32[1024]{0} ars)
-  ROOT out = f32[64,64]{1,0} add(d, d)
-}
-"""
-
-# iota replica-group form on an async all-gather
-IOTA_ASYNC = """
-HloModule m
-
-ENTRY main {
-  p0 = bf16[8]{0} parameter(0)
-  ags = (bf16[8]{0}, bf16[32]{0}, u32[], u32[]) all-gather-start(p0), replica_groups=[2,4]<=[8], dimensions={0}
-  ROOT agd = bf16[32]{0} all-gather-done(ags)
-}
-"""
-
-# generic async wrapper: the collective lives in a called computation, and the
-# done chains to the start through an async-update
-NESTED_ASYNC = """
-HloModule m
-
-%wrapped_ag (param_0: bf16[8]) -> bf16[64] {
-  %param_0 = bf16[8]{0} parameter(0)
-  ROOT %ag = bf16[64]{0} all-gather(%param_0), replica_groups={{0,1,2,3,4,5,6,7}}, dimensions={0}
-}
-
-ENTRY main {
-  p0 = bf16[8]{0} parameter(0)
-  %ag-start = ((bf16[8]{0}), bf16[64]{0}, u32[]) async-start(p0), calls=%wrapped_ag
-  %ag-upd = ((bf16[8]{0}), bf16[64]{0}, u32[]) async-update(%ag-start)
-  ROOT %ag-done = bf16[64]{0} async-done(%ag-upd)
-}
-"""
-
-UNMATCHED_DONE = """
-HloModule m
-
-ENTRY main {
-  p0 = f32[16]{0} parameter(0)
-  ROOT bad = f32[16]{0} all-reduce-done(p0)
-}
-"""
-
-
-def test_parse_async_pairs_dedicated_forms():
-    (pair,) = hlo.parse_async_pairs(ASYNC_REDUCE)
-    assert pair["op"] == "all-reduce" and pair["name"] == "ars"
-    assert pair["bytes"] == 1024 * 4 and pair["groups"] is None
-    assert pair["start_line"] < pair["done_line"]
-    (gpair,) = hlo.parse_async_pairs(ASYNC_GATHER)
-    # produced halves only, same convention as collective_bytes
-    assert gpair["op"] == "all-gather" and gpair["bytes"] == 2 * 64 * 2
-    (ppair,) = hlo.parse_async_pairs(PERMUTE_START)
-    assert ppair["op"] == "collective-permute"
-    assert ppair["groups"] == [(0, 1), (1, 0)]
-
-
-def test_parse_async_pairs_explicit_groups_and_window():
-    (pair,) = hlo.parse_async_pairs(OVERLAP_WINDOW)
-    assert pair["groups"] == [(0, 1, 2, 3), (4, 5, 6, 7)]
-    lines = OVERLAP_WINDOW.splitlines()
-    window = lines[pair["start_line"] + 1:pair["done_line"]]
-    assert len(window) == 1 and " dot(" in window[0]
-
-
-def test_parse_async_pairs_iota_groups():
-    (pair,) = hlo.parse_async_pairs(IOTA_ASYNC)
-    assert pair["groups"] == [(0, 1, 2, 3), (4, 5, 6, 7)]
-    assert pair["bytes"] == 32 * 2  # the produced bf16[32] half only
-
-
-def test_parse_async_pairs_nested_wrapper():
-    (pair,) = hlo.parse_async_pairs(NESTED_ASYNC)
-    assert pair["op"] == "all-gather"
-    assert pair["name"] == "ag-start" and pair["done"] == "ag-done"
-    assert pair["bytes"] == 64 * 2
-    assert pair["groups"] == [(0, 1, 2, 3, 4, 5, 6, 7)]
-
-
-def test_parse_async_pairs_unmatched_done_raises():
-    with pytest.raises(ValueError, match="no matching -start"):
-        hlo.parse_async_pairs(UNMATCHED_DONE)
-
-
-def test_dot_flops_estimate_reads_annotated_operands():
-    line = ("  d = f32[64,64]{1,0} dot(f32[64,64]{1,0} a, f32[64,64]{1,0} b), "
-            "lhs_contracting_dims={1}, rhs_contracting_dims={0}")
-    assert hlo.dot_flops_estimate(line) == 2 * 64 * 64 * 64
-    # unannotated operands give no phantom compute credit
-    assert hlo.dot_flops_estimate(
-        "  d = f32[8,4]{1,0} dot(ca, cb), lhs_contracting_dims={1}") == 0
-    assert hlo.dot_flops_estimate("  a = f32[8]{0} add(x, y)") == 0
 
 
 def test_result_bytes_reads_the_definition_type():

@@ -404,10 +404,16 @@ def test_bytes_in_use_is_the_most_over_the_devices_and_the_limit_is_noted_once(m
     assert _steps_of(engine)[-1]["attrs"]["bytes_in_use"] == 7
 
 
-def test_the_catalog_gives_each_step_programs_memory_as_the_compiler_states_it():
+@pytest.fixture(scope="module")
+def simple_catalog():
+    """The catalog of the two-layer toy's step programs, compiled once for the cases below."""
     engine, xs, ys = _simple_engine()
     _step(engine, xs, ys)
-    catalog = spans.recorder().programs(engine._span_engine)
+    return spans.recorder().programs(engine._span_engine)
+
+
+def test_the_catalog_gives_each_step_programs_memory_as_the_compiler_states_it(simple_catalog):
+    catalog = simple_catalog
     assert set(catalog) == {"loss_and_grad", "apply_update"}
     for program in catalog.values():
         assert set(program["memory"]) == {"argument", "output", "alias", "temp", "code"}
@@ -416,3 +422,26 @@ def test_the_catalog_gives_each_step_programs_memory_as_the_compiler_states_it()
     # the update program writes its state over what it was given
     assert catalog["apply_update"]["memory"]["alias"] > 0
     assert spans._memory_sizes(type("C", (), {"memory_analysis": lambda self: None})()) is None
+
+
+def test_the_catalog_prices_each_operation_of_a_step_program(simple_catalog):
+    """``cost``: ``[flops, bytes]`` an instruction the device runs on its own, from the same
+    text as ``ops``. The toy is two 16-wide layers on ONE sample a device, so every product
+    the compiler keeps as one is a vector times a 16 x 16 matrix: 2 x 16 x 16 operations,
+    whatever is fused around it (the two forward products at least; a weight's gradient is
+    an outer product, which this compiler writes as a multiplication)."""
+    grad, update = simple_catalog["loss_and_grad"], simple_catalog["apply_update"]
+    for program in (grad, update):
+        assert set(program["cost"]) <= set(program["ops"])
+        assert all(len(c) == 2 and c[0] >= 0 and c[1] >= 0 for c in program["cost"].values())
+    products = {name: flops for name, (flops, _) in grad["cost"].items() if flops}
+    assert 2 <= len(products) <= 5 and set(products.values()) == {2 * 16 * 16}
+    assert set(grad["products"]) == set(products)
+    for name, product in grad["products"].items():
+        (m, k, n, types), = product["mkn"]
+        assert (m, k, n) == (1, 16, 16) and product["as"] is None, name
+        # what the product reads and writes at the least: a matrix, two vectors
+        assert grad["cost"][name][1] >= (16 * 16 + 16 + 16) * 2
+    # Adam moves four small leaves: no product, and what it reads and writes has a size
+    assert update["products"] == {} and all(flops == 0 for flops, _ in update["cost"].values())
+    assert sum(nbytes for _, nbytes in update["cost"].values()) >= 2 * 3 * (2 * 16 * 16 + 2 * 16) * 4 / 8
