@@ -333,8 +333,8 @@ def _sort_rows(sent_to, slots, weights):
     ``order`` ``[n * k]`` is each sorted row's flat ``n * k + j``, ``inverse`` ``[n, k]`` the row that
     holds token n's j-th assignment, ``w_sorted`` the router's ``weights`` ``[n, k]`` in the rows'
     order: a further operand of the sort that orders the rows, where ``weights.reshape(-1)[order]``
-    is a gather of scalars of its own (0.56 ms at 65,536: PERF.md, PR 46). Their cotangent comes
-    back by ``inverse``."""
+    is a gather of scalars of its own (0.56 ms at 65,536: PERF.md, PR 46). Their cotangent is the
+    sort run the other way: an operand of the sort by ``order`` that makes ``inverse``."""
     by_expert, order, w_sorted = jax.lax.sort((sent_to, slots, weights.reshape(-1)), num_keys=1, is_stable=True)
     inverse = jax.lax.sort((order, slots), num_keys=1)[1].reshape(weights.shape)
     return by_expert, order, inverse, w_sorted
@@ -342,10 +342,28 @@ def _sort_rows(sent_to, slots, weights):
 
 def _sort_rows_fwd(sent_to, slots, weights):
     out = _sort_rows(sent_to, slots, weights)
-    return out, out[2]
+    return out, out[1:3]
 
 
-_sort_rows.defvjp(_sort_rows_fwd, lambda inverse, grads: (None, None, grads[3][inverse]))
+def _sort_rows_bwd(res, grads):
+    order, inverse = res
+    # ``order`` is a permutation of the slots: sorted by it, ``d_w_sorted`` IS ``d_w_sorted[inverse]``
+    # (no two keys tie: a stable sort would carry an iota for nothing)
+    return None, None, jax.lax.sort((order, grads[3]), num_keys=1, is_stable=False)[1].reshape(inverse.shape)
+
+
+_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+
+
+def _chosen(values, experts):
+    """``values[n, experts[n, j]]`` ``[n, k]`` out of the router's ``[n, E]``, read as a compare, a
+    select and a sum over ``E`` that the compiler fuses, slot by slot (never ``[n, k, E]``): a sum
+    of one value and zeros is that value, and its cotangent is the same select the other way,
+    where ``take_along_axis`` and its scatter back move ``n k`` scalars at 8 ns each (0.3-0.7 ms
+    a layer either way: PERF.md, PR 51). A ``where``, never a product with a one-hot."""
+    lanes = jnp.arange(values.shape[1], dtype=experts.dtype)
+    return jnp.concatenate([jnp.sum(jnp.where(slot == lanes, values, 0), axis=1, keepdims=True)
+                            for slot in jnp.split(experts, experts.shape[1], axis=1)], axis=1)
 
 
 def _rows_of_the_tokens(rows, inverse):
@@ -639,7 +657,10 @@ class DroplessMoE:
 
     ``apply(params, x [B, T, H]) -> (y, aux, stats)``. The router runs in float32 at
     full precision; the ``k`` largest probabilities weigh their experts as they are
-    (``norm_topk_prob`` renormalises them). Two things follow from a model's published keys
+    (``norm_topk_prob`` renormalises them). A chosen value is read out of ``[n, E]``, and its
+    cotangent put back, by a compare and a select over ``E`` (``_chosen``), never by index: a
+    gather or scatter of ``n k`` single floats took 0.3-0.7 ms a layer where the dense form
+    takes microseconds (PERF.md, PR 51). Two things follow from a model's published keys
     and nothing else. ``router``: ``"softmax"`` of the logits, or ``("sigmoid_bias", factor)``:
     ``s = sigmoid(logits)``, the ``k`` largest of ``s + b`` chosen (``router_bias [E]``, a
     leaf no gradient reaches: a model moves it by a rule of its own, from ``stats["counts"]``),
@@ -790,18 +811,20 @@ class DroplessMoE:
 
     def _choose(self, logits, bias):
         """``(weights [n, k], experts [n, k], what ``aux`` sums over the tokens [E])`` from
-        the router's float32 logits ``[n, E]``."""
+        the router's float32 logits ``[n, E]``. ``top_k`` only chooses: the chosen values are
+        read by ``_chosen``, whose cotangent is a dense select where ``top_k``'s is a scatter."""
         k = self.top_k
         if self.scaling is None:
             probs = jax.nn.softmax(logits, axis=-1)                       # [n, E] f32
-            weights, experts = jax.lax.top_k(probs, k)                    # [n, k]
+            _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), k)   # [n, k]
+            weights = _chosen(probs, experts)
             if self.norm_topk_prob:
                 weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
             return weights, experts, jnp.sum(probs, axis=0)
         scores = jax.nn.sigmoid(logits)
         # the bias chooses and never weighs; no gradient reaches it
         _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = _chosen(scores, experts)
         if self.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
         return weights * self.scaling, experts, jnp.zeros(logits.shape[1:], jnp.float32)

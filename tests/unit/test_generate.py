@@ -12,8 +12,9 @@ def _oracle_greedy(model, params, tokens, n_new):
     """Teacher-forcing oracle: re-run the FULL forward for every step and take
     argmax of the last position — what the cached decode must reproduce."""
     toks = np.asarray(tokens)
+    forward = jax.jit(model.apply)      # a program a length; eagerly, some fifty a length
     for _ in range(n_new):
-        logits = np.asarray(model.apply(params, jnp.asarray(toks)))
+        logits = np.asarray(forward(params, jnp.asarray(toks)))
         nxt = np.argmax(logits[:, -1], axis=-1).astype(toks.dtype)
         toks = np.concatenate([toks, nxt[:, None]], axis=1)
     return toks
@@ -65,8 +66,9 @@ def test_top_k_samples_stay_in_the_top_k_set():
     k = 5
     out = np.asarray(model.generate(params, prompt, max_new_tokens=8, temperature=1.0,
                                     top_k=k, rng=jax.random.PRNGKey(10)))
+    forward = jax.jit(model.apply)      # a program a length; eagerly, some fifty a length
     for t in range(4, 12):
-        logits = np.asarray(model.apply(params, jnp.asarray(out[:, :t])))[:, -1]
+        logits = np.asarray(forward(params, jnp.asarray(out[:, :t])))[:, -1]
         topk = np.argsort(logits, axis=-1)[:, -k:]
         for b in range(out.shape[0]):
             assert out[b, t] in topk[b], (b, t, out[b, t], topk[b])

@@ -33,6 +33,13 @@ def oracle(layer, params, x2):
     return out
 
 
+def sharded(layer, mesh):
+    """``moe_apply_sharded`` as ONE compiled program, as the engine runs it. Called eagerly, a
+    ``shard_map`` compiles every primitive of its body as an 8-device program of its own (some
+    four hundred for the layer's value and gradient)."""
+    return jax.jit(lambda params, x: moe_apply_sharded(layer, mesh, params, x))
+
+
 def test_dense_dispatch_matches_per_token_oracle():
     layer = MoELayer(H, F, E, capacity_factor=8.0)  # no drops
     params = layer.init(jax.random.PRNGKey(0))
@@ -64,7 +71,7 @@ def test_expert_parallel_matches_dense_dispatch(mesh):
     x = jax.random.normal(jax.random.PRNGKey(4), (4, 8, H), jnp.float32)
 
     y_d, aux_d = dense.apply(params, x)
-    y_p, aux_p = moe_apply_sharded(ep, mesh, params, x)
+    y_p, aux_p = sharded(ep, mesh)(params, x)
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_d), rtol=2e-5,
                                atol=2e-6)
     np.testing.assert_allclose(float(aux_p), float(aux_d), rtol=1e-5)
@@ -78,7 +85,7 @@ def test_expert_parallel_matches_dense_dispatch(mesh):
         return jnp.sum(y ** 2) + 0.01 * aux
 
     g_d = jax.grad(loss_d)(params)
-    g_p = jax.grad(loss_p)(params)
+    g_p = jax.jit(jax.grad(loss_p))(params)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                                 rtol=5e-4, atol=1e-5),
@@ -211,7 +218,7 @@ def test_gpt2_moe_composes_with_sequence_parallelism():
     labels = jnp.roll(toks, -1, axis=1)
     sp_loss = model.sequence_parallel_loss_fn(sp_mesh, "data")
     l_sp = float(jax.jit(sp_loss)(params, toks, labels))
-    l_ref = float(model.apply(params, toks, labels))
+    l_ref = float(jax.jit(model.apply)(params, toks, labels))
     np.testing.assert_allclose(l_sp, l_ref, rtol=2e-5)
 
     # with the aux term on, sp and dense agree closely (the balancing statistics
@@ -222,7 +229,7 @@ def test_gpt2_moe_composes_with_sequence_parallelism():
     model2 = GPT2Model(cfg2)
     sp_loss2 = model2.sequence_parallel_loss_fn(sp_mesh, "data")
     l_sp2 = float(jax.jit(sp_loss2)(params, toks, labels))
-    np.testing.assert_allclose(l_sp2, float(model2.apply(params, toks, labels)),
+    np.testing.assert_allclose(l_sp2, float(jax.jit(model2.apply)(params, toks, labels)),
                                rtol=1e-3)
     g = jax.jit(jax.grad(sp_loss2))(params, toks, labels)
     assert all(bool(jnp.isfinite(x).all()) for x in jax.tree_util.tree_leaves(g))
@@ -298,8 +305,8 @@ def test_scatter_dispatch_matches_einsum(mesh, top_k):
     # expert-parallel: both modes through the all_to_all path
     l_sc_ep = MoELayer(**kw, dispatch="scatter", expert_axis="model")
     l_ei_ep = MoELayer(**kw, dispatch="einsum", expert_axis="model")
-    y_sc_ep, _ = moe_apply_sharded(l_sc_ep, mesh, params, x)
-    y_ei_ep, _ = moe_apply_sharded(l_ei_ep, mesh, params, x)
+    y_sc_ep, _ = sharded(l_sc_ep, mesh)(params, x)
+    y_ei_ep, _ = sharded(l_ei_ep, mesh)(params, x)
     np.testing.assert_allclose(np.asarray(y_sc_ep), np.asarray(y_ei_ep),
                                rtol=1e-5, atol=1e-5)
 
@@ -312,10 +319,10 @@ def test_top2_second_choice_queues_after_first(mesh):
     params = dense.init(jax.random.PRNGKey(13))
     x = jax.random.normal(jax.random.PRNGKey(14), (4, 8, H), jnp.float32)
     y_d, _ = dense.apply(params, x)
-    y_p, _ = moe_apply_sharded(ep, mesh, params, x)
+    y_p, _ = sharded(ep, mesh)(params, x)
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_d), rtol=2e-5,
                                atol=2e-6)
-    g = jax.grad(lambda p: jnp.sum(moe_apply_sharded(ep, mesh, p, x)[0] ** 2))(params)
+    g = jax.jit(jax.grad(lambda p: jnp.sum(moe_apply_sharded(ep, mesh, p, x)[0] ** 2)))(params)
     assert all(bool(jnp.isfinite(v).all()) for v in jax.tree_util.tree_leaves(g))
 
 

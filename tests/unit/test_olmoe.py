@@ -192,6 +192,47 @@ def test_the_routers_rule_and_the_experts_form_follow_the_constructor(router, ex
         assert float(stats["load_max_over_mean"]) == pytest.approx(64 / (64 * k / E))
 
 
+@pytest.mark.parametrize("norm", [False, True], ids=["as-they-are", "renormalised"])
+@pytest.mark.parametrize("k, E", [(1, 8), (4, 8), (1, 64), (4, 64), (10, 64), (1, 512), (4, 512), (10, 512)])
+@pytest.mark.parametrize("router", ["softmax", ("sigmoid_bias", 2.5)], ids=["softmax", "sigmoid_bias"])
+def test_the_chosen_scores_are_read_and_pulled_back_as_they_were_by_index(router, k, E, norm):
+    """``_choose`` reads the ``k`` chosen of ``[n, E]`` by a compare, a select and a sum (``_chosen``)
+    and leaves ``top_k`` the choice alone: the experts, their order and the float32 weights are, bit
+    for bit, what ``top_k``'s values (softmax) and ``take_along_axis`` (sigmoid) gave, a row whose
+    scores all tie and a row with one pair tied among them, and the logits' gradient is what the
+    scatter back gave (one non-zero term a place: a token's experts are distinct). Operation by
+    operation, as both forms are written: a compiler may fuse the two differently."""
+    n = 12
+    layer = DroplessMoE(16, 8, E, k, norm_topk_prob=norm, router=router)
+    rng = np.random.default_rng(E + k)
+    logits = rng.normal(size=(n, E)).astype(np.float32) * 2
+    logits[0] = 0.25
+    logits[1, 2] = logits[1, 5] = logits[1].max() + 1
+    logits = jnp.asarray(logits)
+    bias = jnp.asarray(0.25 * rng.integers(-1, 2, size=E), jnp.float32)       # the tied row stays tied under it
+    cot = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
+
+    def by_index(logits):
+        if router == "softmax":
+            weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            return (weights / jnp.sum(weights, axis=-1, keepdims=True) if norm else weights), experts
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(scores + bias, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        return (weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20) if norm else weights) * router[1], experts
+
+    def pulled(choose):
+        weights, pull, experts = jax.vjp(choose, logits, has_aux=True)
+        return weights, experts, pull(cot)[0]
+
+    weights, experts, grad = pulled(lambda l: layer._choose(l, bias)[:2])
+    want_weights, want_experts, want_grad = pulled(by_index)
+    assert np.array_equal(experts, want_experts)
+    assert router != "softmax" or np.array_equal(experts[0], np.arange(k))      # a tie goes to the lower index
+    assert weights.dtype == jnp.float32 and np.array_equal(weights, want_weights)
+    assert np.any(np.asarray(grad)) and np.array_equal(grad, want_grad)
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
 @pytest.mark.parametrize("pieces", [1, 2, 4], ids=["whole", "2-pieces", "4-pieces"])
 @pytest.mark.parametrize("sizes", [[16, 0, 40, 8], [64, 0, 0, 0], [0, 0, 0, 64], [16, 16, 16, 16]],
@@ -231,7 +272,8 @@ def test_dispatch_and_combine_are_each_others_transposes(k, dtype):
     under ``jax.vjp`` each one's cotangent IS the other, bit for bit: the combine pulls ``dy`` back
     as one gather by ``tok`` and keeps no row for it (the router's weights are in the rows before
     ``w_down``, PR 49); the dispatch pulls its rows' cotangents back as the combine's sum. The
-    sorted weights come out of the rows' own sort and their cotangent goes back by ``inverse``."""
+    sorted weights come out of the rows' own sort, and their cotangent comes back as an operand of a
+    sort by ``order`` (a permutation of the slots): ``d_ws[inverse]``, bit for bit."""
     n, H, E = 40, 24, 5
     rng = np.random.default_rng(k)
     sent_to = jnp.asarray(rng.integers(0, E, size=n * k), jnp.int32)
@@ -243,8 +285,8 @@ def test_dispatch_and_combine_are_each_others_transposes(k, dtype):
     assert np.array_equal(order[inverse.reshape(-1)], slots) and np.array_equal(w_sorted, weights.reshape(-1)[order])
     d_ws = jnp.asarray(rng.normal(size=n * k), jnp.float32)
     zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)      # noqa: E731
-    assert np.array_equal(pull((zero(by_expert), zero(order), zero(inverse), d_ws))[0],
-                          jnp.zeros(n * k).at[order].set(d_ws).reshape(n, k))
+    d_weights = pull((zero(by_expert), zero(order), zero(inverse), d_ws))[0]
+    assert np.array_equal(d_weights, d_ws[inverse]) and np.array_equal(d_weights.reshape(-1)[order], d_ws)
     x = jnp.asarray(rng.normal(size=(n, H)), dtype)
     ys = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
 
@@ -506,8 +548,8 @@ def shapes_of(jaxpr, primitive):
 def test_where_every_row_is_computed_here_the_layer_sorts_once_and_multiplies_once(stand_in):
     """The gradient's jaxpr of a held range. Standing in, the rows are ``n k`` whatever the
     router does, and the layer is the whole range's: no loop, no branch and no scatter (the one
-    ``scan`` is ``searchsorted``'s, the one ``scatter-add`` the cotangent of the chosen scores,
-    both the router's), each product once forward, and in the backward, under the layer's own
+    ``scan`` is ``searchsorted``'s; the chosen scores' cotangent is a select since PR 51, and the
+    sorted weights' a third sort), each product once forward, and in the backward, under the layer's own
     ``checkpoint``, each product's two cotangents once, ``[n k, .]`` for the rows and
     ``[count, ., .]`` for the weights. A plain held range's rows follow the router: it stays in
     passes of ``n`` rows, a loop and a branch forward and backward, and the backward makes each
@@ -522,15 +564,15 @@ def test_where_every_row_is_computed_here_the_layer_sorts_once_and_multiplies_on
     count_of = lambda name: len(shapes_of(jaxpr, name))      # noqa: E731
     products = shapes_of(jaxpr, "ragged_dot_general")
     if stand_in:
-        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (1, 0, 0, 1)
+        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (1, 0, 0, 0)
         assert sorted(products) == sorted([
             (("custom_vjp_call",), (n * k, F)), (("custom_vjp_call",), (n * k, H)),
             (("remat2",), (n * k, F)), (("remat2",), (n * k, H)),
             (("remat2",), (count, H, F)), (("remat2",), (count, F, H))])
-        assert count_of("remat2") == 1 and count_of("sort") == 2
+        assert count_of("remat2") == 1 and count_of("sort") == 3
     else:
         # searchsorted's, the forward's passes, the backward's; a branch a loop
-        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (3, 0, 2, 5)
+        assert (count_of("scan"), count_of("while"), count_of("cond"), count_of("scatter-add")) == (3, 0, 2, 4)
         in_passes = [(path, shape) for path, shape in products if path[:2] in (("scan", "cond"), ("custom_vjp_call", "scan"))]
         assert len(products) == len(in_passes) == 8
         # a pass of n rows: two products forward, the two made again, and four cotangents

@@ -101,7 +101,9 @@ def test_ring_memory_is_chunked(mesh):
     T_big = 1024  # scores would be [1024, 1024] per (b, h) — chunk kernel sees 128
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q, k, v = (jax.random.normal(kk, (1, 2, T_big, D), jnp.float32) for kk in ks)
-    out = ring_attention_sharded(q, k, v, mesh, interpret=True)
+    # ONE compiled program, as a model's step runs it: called eagerly, a ``shard_map`` compiles every
+    # primitive of the ring's body as an 8-device program of its own
+    out = jax.jit(lambda q, k, v: ring_attention_sharded(q, k, v, mesh, interpret=True))(q, k, v)
     ref = dense_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -122,11 +124,11 @@ def test_gpt2_sequence_parallel_matches_dense(mesh):
 
     sp_loss = model.sequence_parallel_loss_fn(mesh, "data")
     l_sp = jax.jit(sp_loss)(params, jnp.asarray(toks), jnp.asarray(labels))
-    l_ref = model.apply(params, jnp.asarray(toks), jnp.asarray(labels))
+    l_ref = jax.jit(model.apply)(params, jnp.asarray(toks), jnp.asarray(labels))
     np.testing.assert_allclose(float(l_sp), float(l_ref), rtol=2e-5)
 
     g_sp = jax.jit(jax.grad(sp_loss))(params, jnp.asarray(toks), jnp.asarray(labels))
-    g_ref = jax.grad(model.apply)(params, jnp.asarray(toks), jnp.asarray(labels))
+    g_ref = jax.jit(jax.grad(model.apply))(params, jnp.asarray(toks), jnp.asarray(labels))
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                                 rtol=1e-3, atol=1e-5),
@@ -218,7 +220,7 @@ def test_gpt2_sequence_parallel_dropout_trains(mesh):
     assert l1 == l1b, "same rng must reproduce the same masks"
     assert l1 != l2, "different rng must sample different masks"
     l_det = float(jax.jit(loss_fn)(params, toks, labels))
-    ref = float(model.apply(params, toks, labels))
+    ref = float(jax.jit(model.apply)(params, toks, labels))
     np.testing.assert_allclose(l_det, ref, rtol=2e-5)
 
     g = jax.jit(jax.grad(lambda p: loss_fn(p, toks, labels, jax.random.PRNGKey(7))))(params)

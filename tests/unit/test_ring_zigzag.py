@@ -170,9 +170,11 @@ def test_zigzag_matches_dense_and_masked(mesh):
     schedule='masked' ring, at the existing ring tolerances."""
     q, k, v = qkv(21)
     out_zz = ring_attention_sharded(q, k, v, mesh, causal=True, interpret=True,
-                                    schedule="zigzag")
-    out_mk = ring_attention_sharded(q, k, v, mesh, causal=True, interpret=True,
-                                    schedule="masked")
+                                    schedule="zigzag")      # eagerly: its layout is the caller's
+    # the ring it is compared with as ONE compiled program: called eagerly, a ``shard_map`` compiles
+    # every primitive of its body as an 8-device program of its own
+    out_mk = jax.jit(lambda q, k, v: ring_attention_sharded(q, k, v, mesh, causal=True, interpret=True,
+                                                            schedule="masked"))(q, k, v)
     ref = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out_zz), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)

@@ -9,8 +9,11 @@ contract: the script stays in sync with ROADMAP.md, and no file under
 tests/perf/ matches a collectable pattern.
 """
 
+import itertools
 import re
 from pathlib import Path
+
+import pytest
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -67,3 +70,62 @@ def test_perf_directory_has_no_conftest_collection_override():
     tricks or python_files overrides; keep the directory plugin-free."""
     ini_like = [p.name for p in (REPO / "tests" / "perf").glob("conftest.py")]
     assert not ini_like, "tests/perf/conftest.py could alter tier-1 collection"
+
+
+def _conftest():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("_suite_conftest", REPO / "tests" / "conftest.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("workers, short, a_file", [(6, 900, None), (6, 300, None), (4, 900, None), (2, 60, None),
+                                                    (6, 900, 5), (3, 400, 4)])
+def test_under_xdist_the_long_files_are_whole_and_apart_in_the_workers_first_chunks(workers, short, a_file):
+    """``--dist load`` sends every worker one chunk of consecutive tests before it balances anything
+    (a quarter of an even share): ``conftest._in_chunks`` puts each long file whole into one of those
+    chunks, the seconds about even, fills the chunks with short tests, and spaces the long files that
+    fit none, longest first, evenly through the short tests that are left. Nothing is lost or doubled, and every worker sorts alike."""
+    conf = _conftest()
+    seconds = conf._SECONDS
+    counts = {f: a_file or 3 + 7 * i % 40 for i, f in enumerate(sorted(seconds))}       # few tests a file: all fit
+    counts["test_olmoe.py"] = a_file or 107
+    items = [(f, i) for f in sorted(seconds, reverse=True) for i in range(counts[f])]
+    items += [(f"test_short_{i % 37}.py", i) for i in range(short)]
+    name = lambda item: item[0]      # noqa: E731
+    order = conf._in_chunks(list(items), name, workers)
+    assert sorted(order) == sorted(items) and order == conf._in_chunks(list(items), name, workers)
+    chunk = max(len(items) // workers // 4, 2)
+    first = [order[w * chunk:(w + 1) * chunk] for w in range(workers)]
+    placed = {}
+    for w, tests in enumerate(first):
+        for f in {name(t) for t in tests} & set(seconds):
+            assert sum(name(t) == f for t in tests) == counts[f], (f, "split over a chunk's edge")
+            placed[f] = w
+    load = [sum(seconds[f] for f, at in placed.items() if at == w) for w in range(workers)]
+    late = [f for f in seconds if f not in placed]
+    # a file is left for later only where no chunk had room for it, and the chunks' seconds differ by
+    # less than the longest file that went in after the first round
+    ahead = lambda f, w: sum(counts[g] for g, at in placed.items() if at == w and seconds[g] >= seconds[f])      # noqa: E731
+    assert all(counts[f] > chunk - min(ahead(f, w) for w in range(workers)) for f in late)
+    assert bool(late) == (a_file is None)
+    if not late:
+        assert max(load) - min(load) <= sorted(seconds.values())[-workers - 1]
+    # the late files whole, longest first, the same number of short tests after each, and a fifth of
+    # the short tests after the last of them
+    rest = [name(t) for t in order[workers * chunk:]]
+    runs = [(f, len(list(run))) for f, run in itertools.groupby("short" if f not in seconds else f for f in rest)]
+    assert [(f, n) for f, n in runs if f != "short"] == \
+        [(f, counts[f]) for f in sorted(late, key=lambda f: (-seconds[f], f))]
+    if late:
+        gaps = [n for f, n in runs if f == "short"]
+        left = len(rest) - sum(counts[f] for f in late)
+        assert len(set(gaps[:-1])) == 1 and gaps[-1] >= gaps[0] + left // 5 and gaps[0] == left * 4 // 5 // len(late)
+
+
+def test_every_file_the_order_names_is_in_the_suite():
+    """A file renamed or removed leaves a stale line in ``conftest._SECONDS``."""
+    conf = _conftest()
+    here = {p.name for p in (REPO / "tests").rglob("*.py")}
+    assert set(conf._SECONDS) <= here and all(s >= 60 for s in conf._SECONDS.values())

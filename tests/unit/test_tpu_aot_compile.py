@@ -278,6 +278,11 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     assert len(re.findall(r"= \(f32\[49152\]\S*, bf16\[49152,1856\]\S*, bf16\[49152,1856\]\S*\) fusion\(", text)) == 4
     # the rows' products once a call over all 49,152 rows, never a pass of 8,192 under a loop
     assert "49152,1856" in text and not re.search(r"bf16\[8192,1856\]\S* custom-call", text)
+    # the router's chosen scores and the sorted weights' cotangent travel by no index (PR 51). The
+    # parent's text matched sixteen times: ``f32[8192,6] gather`` twelve (the chosen scores read in
+    # both forwards of four layers, and ``d_ws[inverse]``) and ``f32[1048576] scatter`` four (the
+    # chosen scores' cotangent into zeros ``[8192, 128]``, flat), 8 ns an element each
+    assert not re.search(r"= f32\[(?:49152|8192,6|8192,128|1048576)\]\S* (?:gather|scatter)\(", text)
 
 
 def looped_gradient_program(chip, monkeypatch, layers, passes):
