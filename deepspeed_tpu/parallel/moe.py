@@ -661,10 +661,11 @@ class DroplessMoE:
     cotangent put back, by a compare and a select over ``E`` (``_chosen``), never by index: a
     gather or scatter of ``n k`` single floats took 0.3-0.7 ms a layer where the dense form
     takes microseconds (PERF.md, PR 51). Two things follow from a model's published keys
-    and nothing else. ``router``: ``"softmax"`` of the logits, or ``("sigmoid_bias", factor)``:
+    and nothing else. ``router``: ``"softmax"`` of the logits, or ``("sigmoid_bias", factor[, eps])``:
     ``s = sigmoid(logits)``, the ``k`` largest of ``s + b`` chosen (``router_bias [E]``, a
     leaf no gradient reaches: a model moves it by a rule of its own, from ``stats["counts"]``),
-    each weighted by its OWN ``s``, renormalised over the chosen where ``norm_topk_prob``,
+    each weighted by its OWN ``s``, renormalised over the chosen where ``norm_topk_prob``
+    (``s_e / (sum of the chosen s + eps)``, ``eps`` 1e-20 unless the family's code has another),
     times ``factor``; ``aux`` is then zero (such a router is balanced by its bias).
     ``experts``: ``"silu_gated"``, ``W_down(silu(W_gate x) * W_up x)`` with gate and up side by
     side in ``w_gate_up [.., H, 2F]``, or ``"relu2"``, ``W_down relu(W_up x)^2`` over
@@ -736,9 +737,11 @@ class DroplessMoE:
         self.hidden, self.ffn_dim = hidden, ffn_dim
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = norm_topk_prob
-        assert router == "softmax" or (len(router) == 2 and router[0] == "sigmoid_bias"), router
+        assert router == "softmax" or (len(router) in (2, 3) and router[0] == "sigmoid_bias"), router
         assert experts in (SILU_GATED, RELU2), experts
         self.scaling = None if router == "softmax" else float(router[1])
+        # the sigmoid router's renormalisation adds it to the chosen scores' sum
+        self.eps = float(router[2]) if router != "softmax" and len(router) == 3 else 1e-20
         self.form = experts
         self.w_in = "w_up" if experts == RELU2 else "w_gate_up"
         first, count = held or (0, num_experts)
@@ -826,7 +829,7 @@ class DroplessMoE:
         _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
         weights = _chosen(scores, experts)
         if self.norm_topk_prob:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + self.eps)
         return weights * self.scaling, experts, jnp.zeros(logits.shape[1:], jnp.float32)
 
     def _local(self, axis, details, router_w, w_gate_up, w_down, x, bias=None):

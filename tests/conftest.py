@@ -11,7 +11,22 @@ import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = _flags + " --xla_force_host_platform_device_count=8"
+    _flags += " --xla_force_host_platform_device_count=8"
+# Three fifths of the suite's case-seconds are XLA:CPU compiles of programs that then run once at
+# toy sizes, and six workers on eight cores are bound by the CPU's seconds, not the wall's (the
+# driver cut PR 52's first runs at 1,470 s, 1,707 of 1,812 tests in). So the CPU's code is
+# generated the cheap way: LLVM at -O0 (the optimized HLO text is the same, byte for byte) and
+# the elemental emitters in place of the MLIR fusion emitters (which fuse a little differently:
+# 304 fusions where 404 were in a toy gradient program). A toy gradient program and an 8-device
+# ring compile in 7.4 CPU-seconds where they took 21.3, a sample of three files runs in 178
+# CPU-seconds where it took 331, and the whole suite in 796 s where it took 1,671. Nothing a test
+# asserts is about the CPU's speed; a compile for a described TPU (``test_tpu_aot_compile.py``)
+# gives the same optimized text and temporaries under both, and a flag already in ``XLA_FLAGS``
+# is left as given. Subprocess workloads inherit the variable.
+for _cheap in ("--xla_backend_optimization_level=0", "--xla_cpu_use_fusion_emitters=false"):
+    if _cheap.split("=")[0] not in _flags:
+        _flags += " " + _cheap
+os.environ["XLA_FLAGS"] = _flags
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
@@ -60,13 +75,15 @@ _HEAVY_FILES = ("test_ring_attention.py", "test_ring_zigzag.py")
 # eight cores; ``tests/perf/suite_seconds.py <junit xml> --table`` prints them and the run's length
 # under this order); a new file of a minute or more belongs here. With every entry wrong by up to
 # a quarter the run is 25 s longer at the ninth decile (60 draws); without the spacing, 96 s.
+# Under the cheap CPU code above every file takes about half its entry, in nearly the same order:
+# this table lays PR 52's run out in 775 s, one taken anew from that run in 766.
 _SECONDS = {"test_tpu_aot_compile.py": 580, "test_rehearsal_hybrid.py": 395, "test_granite_hybrid.py": 390,
             "test_ring_attention.py": 390, "test_rehearsal_ssm_moe.py": 340, "test_nemotron_h.py": 330,
             "test_qwen3_next.py": 305, "test_rehearsal_ssm.py": 305, "test_olmoe.py": 275,
             "test_launcher.py": 235, "test_moe.py": 210, "test_flash_attention.py": 195,
             "test_ring_zigzag.py": 165, "test_rehearsal_swa_moe.py": 165, "test_rehearsal_mla_moe.py": 160,
             "test_ouro.py": 160, "run_func_test.py": 150, "test_causal_conv_kernel.py": 130,
-            "test_glm_moe.py": 125, "test_rehearsal.py": 120, "test_rehearsal_loop.py": 110,
+            "test_glm_moe.py": 125, "test_rehearsal_conv_moe.py": 125, "test_lfm2_moe.py": 110, "test_rehearsal.py": 120, "test_rehearsal_loop.py": 110,
             "test_rehearsal_moe.py": 110, "test_mellum.py": 105, "test_ssd.py": 95, "test_ssd_kernel.py": 95,
             "run_checkpoint_test.py": 90, "test_transformer_layer.py": 75, "test_delta_rule_kernel.py": 75,
             "test_chip_smoke.py": 70, "test_pipeline_spmd.py": 70, "test_generate.py": 70,

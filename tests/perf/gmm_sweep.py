@@ -2,7 +2,7 @@
 of ``gmm`` and ``tgmm`` at the benchmark's call shapes, read from a profiler trace, beside
 what the products need and what the tiles issue.
 
-    python tests/perf/gmm_sweep.py [--cells mellum2,nemotronh,olmoe,qwen3next,glm47flash] [--shapes 2304x1792]
+    python tests/perf/gmm_sweep.py [--cells mellum2,nemotronh,olmoe,qwen3next,glm47flash,lfm2] [--shapes 2304x1792]
                                    [--grid near|full] [--tm 256,1024] [--seed 0] [--check]
                                    [--out chiprun_out/gmm_sweep.jsonl]
 
@@ -12,7 +12,7 @@ absolute path there):
 
     (cd _parent && python ../tests/perf/gmm_sweep.py --out /root/repo/chiprun_out/parent.jsonl)
 
-The shapes are ``expert_calls``: every grouped product an expert layer of the five expert
+The shapes are ``expert_calls``: every grouped product an expert layer of the six expert
 cells makes in a step, read from the cell's files under ``benchmarks/`` (rows, widths, the
 groups of a call, the pieces the experts come in and whether a call writes into an existing
 buffer), forward (``gmm``), the cotangent of the rows (``gmm_t``: ``transpose_rhs``) and of
@@ -59,7 +59,7 @@ from deepspeed_tpu.parallel import moe  # noqa: E402
 
 CELLS = {"mellum2": "mellum2_ep4_d4_train_1chip", "nemotronh": "nemotronh_ep16_d9_train_1chip",
          "olmoe": "olmoe_d4_train_4chip", "qwen3next": "qwen3next_ep16_train_1chip",
-         "glm47flash": "glm47flash_ep8_d5_train_1chip"}
+         "glm47flash": "glm47flash_ep8_d5_train_1chip", "lfm2": "lfm2_ep8_d7_train_1chip"}
 CLIPPED = (512, 1024, 1024)          # what ``_tiles`` clipped with ``min`` until PR 47
 VMEM = 16 * 2 ** 20                  # a kernel's scoped VMEM on a v5e; megablox's call sets no other
 NEAR = 1.05                          # ``--grid near``: the width tiles that issue at most this over the need

@@ -34,7 +34,24 @@ def models():
     yield "olmoe", model, params, olmoe.batch()
     _, model, params = nemotron.build(nemotron.published(stand_in=True), remat=True)
     yield "nemotronh_stand_in_remat", model, params, nemotron.batch()
+    yield from toys()
     yield ("gpt2_flash",) + gpt2()
+
+
+def toys():
+    """The models whose toys live in a file of their own: Mellum 2, GLM-4.7-Flash (the other model on the
+    sigmoid router) and LFM2 (absent from a tree older than PR 52), held experts standing in, layers recomputed."""
+    import importlib
+    cut = {"mellum": dict(num_experts=4, router_width=8, first_expert=4, stand_in=True),
+           "glm": dict(n_routed_experts=4, router_width=8, first_expert=4, stand_in=True),
+           "lfm2": dict(num_experts=4, router_width=8, first_expert=4, stand_in=True)}
+    for name in cut:
+        try:
+            toy = importlib.import_module(name + "_toy")
+        except ImportError:
+            continue
+        _, model, params = toy.build(toy.published(**cut[name]), remat=True)
+        yield name + "_stand_in_remat", model, params, toy.batch()
 
 
 def gpt2():

@@ -81,7 +81,7 @@ def _conftest():
 
 
 @pytest.mark.parametrize("workers, short, a_file", [(6, 900, None), (6, 300, None), (4, 900, None), (2, 60, None),
-                                                    (6, 900, 5), (3, 400, 4)])
+                                                    (6, 900, 5), (3, 500, 4)])      # every file fits a first chunk: 35 files of 4 in 3 chunks of 53
 def test_under_xdist_the_long_files_are_whole_and_apart_in_the_workers_first_chunks(workers, short, a_file):
     """``--dist load`` sends every worker one chunk of consecutive tests before it balances anything
     (a quarter of an even share): ``conftest._in_chunks`` puts each long file whole into one of those

@@ -115,6 +115,25 @@ def test_a_latent_attention_block_compiles_for_v5e(chip, monkeypatch):
     assert re.search(r"bf16\[1,20,8192,256\]", text)
 
 
+def test_a_gated_short_convolution_operator_compiles_for_v5e(chip, monkeypatch):
+    """One gated short-convolution operator of ``lfm2_ep8_d7_train_1chip`` at its published widths and
+    8,192 positions, value and gradient: the convolution's two kernels at THREE taps over 2,048
+    channels with no bias and no SiLU, on an operand of their own (the product ``B * z``: no window
+    of the projection's output), between the 2048 -> 6144 and 2048 -> 2048 products."""
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners.train_conv_moe import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels, not their interpreter
+    model = build_model(Manifest().config("lfm2-24b-a2b-ep8-d7"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][0]["conv"]
+    assert shapes["w_in"].shape == (2048, 6144) and shapes["conv_w"].shape == (3, 2048)
+    cp = jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=chip)
+    grad = jax.grad(lambda x, p: jnp.sum(model.short_conv(x, p).astype(jnp.float32) ** 2), argnums=(0, 1))
+    text = compiled_text(grad, x, cp)
+    assert "ds_causal_conv_fwd" in text and "ds_causal_conv_bwd" in text and "ds_short_conv_gate" in text
+    assert re.search(r"bf16\[1,8192,6144\]", text)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("T", [8192, 1024])
 def test_the_delta_rule_kernels_compile_for_v5e(chip, T, dtype):
