@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.pallas import rows_sum
 from .mesh import MODEL_AXIS
 
 
@@ -369,28 +370,55 @@ def _chosen(values, experts):
 def _rows_of_the_tokens(rows, inverse):
     """``[k, n, H]``: the sorted row of every token's j-th assignment, slot by slot. The gather's
     ``[k n, H]`` is that as it lies whatever ``k`` (``n`` fills the tiles), where ``[n, k, H]``
-    is a relayout of all the rows at a ``k`` the sublane tile of eight does not divide."""
+    is a relayout of all the rows at a ``k`` the sublane tile of eight does not divide. This gather
+    FROM the ``n k`` sorted rows is bound by issuing rows, not by bytes: less the write of its
+    output at HBM's rate it costs 29-36 ns a ROW whatever the row's width (2.09 ms at 49,152 x
+    2,688, 2.45 at 65,536 x 2,304, 2.24 at 65,536 x 2,048, 1.10 at 32,768 x 2,048), where the
+    gather from the ``n`` tokens' rows, a source the compiler holds in fast memory, is its output's
+    write at 650 GB/s and nothing more (0.40, 0.47, 0.41, 0.21 ms: PERF.md, PR 53,
+    ``tests/perf/rows_sum_probe.py``)."""
     n, k = inverse.shape
     return rows[inverse.T.reshape(-1)].reshape(k, n, -1)
 
 
 # Dispatch and combine are each other's transposes, so each is the other's cotangent: one row
 # gather either way (and a sum over k), never a scatter, and neither keeps a row for its backward.
+# ``sort = (tok, inverse, runs)``: each sorted row's token, the row of every token's j-th
+# assignment, and where the combine reads its rows in runs their bounds (``_run_bounds``), else None.
 @jax.custom_vjp
-def _take_rows(x, tok, inverse):
+def _take_rows(x, tok, inverse, runs):
     """Dispatch: ``xs[m] = x[tok[m]]`` for the ``n * k`` sorted assignments (``tok = order // k``)."""
     return x[tok]
 
 
 @jax.custom_vjp
-def _sum_rows(ys, tok, inverse):
+def _sum_rows(ys, tok, inverse, runs):
     """Combine: ``y[n] = sum_j ys[inverse[n, j]]``, summed in float32. The router's weights are in
-    the rows already (``_activate``), so nothing of ``ys`` is needed to pull a cotangent back."""
-    return jnp.sum(_rows_of_the_tokens(ys, inverse).astype(jnp.float32), axis=0).astype(ys.dtype)
+    the rows already (``_activate``), so nothing of ``ys`` is needed to pull a cotangent back.
+    Two forms of the same sum. Without ``runs``: the compiled one, a gather by ``inverse`` and a
+    sum over ``k`` (``_rows_of_the_tokens``): off the TPU, as ``grouped_matmul`` keeps
+    ``lax.ragged_dot`` there, and wherever ``_run_bounds`` says. With ``runs``: the kernel
+    ``ds_moe_rows_sum`` (``ops/pallas/rows_sum.py``), which never reads ``inverse``: the sort is
+    stable, so a token tile's rows in a group are one run of ``ys``, streamed in whole chunks and
+    added in fast memory through a one-hot product, each token's in float32 in sorted-row order
+    (slot order in the compiled form: at most ``k`` float32 additions in another order, one
+    rounding to the compute type either way). Alone on the chip, with the sum over ``k`` behind
+    the gather: 2.53 -> 0.70 ms a call at Nemotron-H's (k 6, 8 groups, 2,688), 2.97 -> 0.98 at
+    Mellum 2's (8, 16, 2,304), 1.35 -> 0.45 at GLM's and LFM2's (4, 8, 2,048), a balanced router
+    or a leaning one; at OLMoE's 64 groups, runs of 32 rows, 2.68 -> 2.35 balanced and 2.65 -> 1.27
+    at the load its cell's router has, +3.3 % of the cell's tokens a second (PERF.md, PR 53). As the
+    dispatch's cotangent it serves the second slow gather of a layer: two calls an expert layer,
+    neither made again by a recomputed layer. A row that is NOT finite: the gather confines it to
+    its own token; the kernel's one-hot product meets it with a zero (``0 x inf``), so every token
+    of a 256-token tile that visits the row's chunk of 128 comes out not finite, and no other
+    tile (``test_olmoe.py -k not_finite``): the step's overflow check fires either way."""
+    if runs is None:
+        return jnp.sum(_rows_of_the_tokens(ys, inverse).astype(jnp.float32), axis=0).astype(ys.dtype)
+    return rows_sum.rows_sum(ys, tok, runs, inverse.shape[0], interpret=jax.default_backend() != "tpu")
 
 
-_take_rows.defvjp(lambda x, *sort: (_take_rows(x, *sort), sort), lambda sort, dxs: (_sum_rows(dxs, *sort), None, None))
-_sum_rows.defvjp(lambda ys, *sort: (_sum_rows(ys, *sort), sort), lambda sort, dy: (_take_rows(dy, *sort), None, None))
+_take_rows.defvjp(lambda x, *sort: (_take_rows(x, *sort), sort), lambda sort, dxs: (_sum_rows(dxs, *sort), None, None, None))
+_sum_rows.defvjp(lambda ys, *sort: (_sum_rows(ys, *sort), sort), lambda sort, dy: (_take_rows(dy, *sort), None, None, None))
 
 # megablox tiles: the rows of a tile, and the MOST a contraction and a column tile take. The
 # kernels round K and N up to whole tiles and compute every tile in full (a K remainder is
@@ -424,6 +452,18 @@ def _tiles(rows, contraction, columns):
     output's two widths: a function of the shapes alone."""
     tm, tk, tn = GMM_TILES
     return min(tm, rows), _width_tile(contraction, tk), _width_tile(columns, tn)
+
+
+def _run_bounds(n, k, G, H, group, tok):
+    """``runs`` for ``_take_rows`` and ``_sum_rows``: where the combine reads its rows in runs
+    (``ops/pallas/rows_sum.py``), the kernel's visits from each token tile's bounds in each of the
+    ``G`` groups of the sorted rows (``group [n k]`` counts from 0 and ascends, ``tok`` ascends
+    inside a group), made once a layer for the forward's call and the backward's; None
+    where it gathers them by index: off the TPU and at shapes the kernel does not take. Static
+    shapes alone."""
+    if jax.default_backend() != "tpu" or not rows_sum.fits(n, n * k, H):
+        return None
+    return rows_sum.visits(rows_sum.run_bounds(group, tok, n, G), n * k)
 
 
 def _ragged_sizes(rhs, group_sizes, first):
@@ -861,6 +901,8 @@ class DroplessMoE:
             starts = jnp.searchsorted(by_expert, jnp.arange(E + 1, dtype=jnp.int32))
             group_sizes = jnp.diff(starts).astype(jnp.int32)              # [E]
             tok = order // k
+            # on the TPU, where the kernel takes the shapes, the bounds of the combine's runs (else None)
+            runs = _run_bounds(n, k, count, H, by_expert - first, tok) if self.every_row_here else None
         # the groups the whole range's products run over: standing in, the held ones hold every row
         sizes = group_sizes[first:first + count] if self.stand_in else group_sizes
         def routed(x2, w_sorted, w_gate_up, w_down):
@@ -874,13 +916,13 @@ class DroplessMoE:
                     down_pieces = gather_pieces(w_down, axis)
                     firsts = piece_firsts(axis, w_down.shape[0])
             with jax.named_scope("ds_moe_dispatch"):
-                xs = _take_rows(x2, tok, inverse)                         # [n * k, H]
+                xs = _take_rows(x2, tok, inverse, runs)                   # [n * k, H]
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
                     experts_matmul(xs, gate_up_pieces, firsts, sizes), "ds_moe_gate_up")
                 ys = experts_matmul(_activate(form, gate_up, dt, w_sorted), down_pieces, firsts, sizes)
             with jax.named_scope("ds_moe_combine"):
-                return _sum_rows(ys, tok, inverse)                        # [n, H]
+                return _sum_rows(ys, tok, inverse, runs)                  # [n, H]
 
         if self.held is not None:
             # the held range's rows are one run of the sorted order: its start and its length

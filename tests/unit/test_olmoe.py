@@ -16,6 +16,8 @@ import deepspeed_tpu
 from benchmarks.reference import olmoe_reference as ref
 from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
 from deepspeed_tpu.parallel.mesh import build_mesh
+from deepspeed_tpu.ops.pallas import rows_sum
+from deepspeed_tpu.parallel import moe
 from deepspeed_tpu.parallel.moe import (DroplessMoE, _sort_rows, _sum_rows, _take_rows, experts_matmul, gather_pieces,
                                         piece_firsts)
 from deepspeed_tpu.utils import spans
@@ -265,20 +267,47 @@ def test_grouped_matmul_matches_a_per_expert_loop(sizes, pieces, backward):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("k", [6, 8])
-def test_dispatch_and_combine_are_each_others_transposes(k, dtype):
+# ``(k, groups, tokens, tokens a tile, how the rows are sent)`` of the combine's kernel cases: the four stand-in cells'
+# ``(k, G)`` at toy sizes, then the runs a router can make of them
+_IN_RUNS = {"nemotronh-k6-G8": (6, 8, 64, 16, "even"), "mellum2-k8-G16": (8, 16, 64, 16, "even"),
+            "glm47flash-lfm2-k4-G8": (4, 8, 64, 32, "even"),
+            "a-token-has-several-rows-in-a-group": (6, 2, 64, 16, "even"),     # the stand-in's fold
+            "an-empty-group": (4, 8, 64, 16, "none-to-group-3"),
+            "one-group-holds-every-row": (4, 8, 64, 16, "all-to-group-5"),
+            "runs-cross-the-chunks-and-start-off-the-sublane-tile": (3, 2, 128, 32, "even"),
+            "one-row-a-token": (1, 4, 128, 16, "even")}
+
+
+def _sorted(k, E, n, sent, rng):
+    """``(sent_to, slots, weights)`` for ``_sort_rows``: ``n k`` assignments over ``E`` groups."""
+    sent_to = rng.integers(0, E, size=n * k)
+    if sent == "none-to-group-3":
+        sent_to = np.where(sent_to == 3, 4, sent_to)
+    elif sent == "all-to-group-5":
+        sent_to = np.full(n * k, 5)
+    return (jnp.asarray(sent_to, jnp.int32), jnp.arange(n * k, dtype=jnp.int32),
+            jnp.asarray(rng.random(size=(n, k)), jnp.float32))
+
+
+@pytest.mark.parametrize("case, dtype", [
+    pytest.param(case, dtype, id=f"{case}-{name}") for case, name, dtype in
+    [(k, name, dtype) for name, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)) for k in (6, 8)]
+    + [(case, "bf16", jnp.bfloat16) for case in _IN_RUNS]
+    + [(case, "f32", jnp.float32) for case in ("nemotronh-k6-G8", "a-token-has-several-rows-in-a-group")]])
+def test_dispatch_and_combine_are_each_others_transposes(case, dtype):
     """``_take_rows`` against ``x[tok]`` and ``_sum_rows`` against ``sum_j ys[inverse[:, j]]``, and
     under ``jax.vjp`` each one's cotangent IS the other, bit for bit: the combine pulls ``dy`` back
     as one gather by ``tok`` and keeps no row for it (the router's weights are in the rows before
     ``w_down``, PR 49); the dispatch pulls its rows' cotangents back as the combine's sum. The
     sorted weights come out of the rows' own sort, and their cotangent comes back as an operand of a
-    sort by ``order`` (a permutation of the slots): ``d_ws[inverse]``, bit for bit."""
-    n, H, E = 40, 24, 5
+    sort by ``order`` (a permutation of the slots): ``d_ws[inverse]``, bit for bit. A case of
+    ``_IN_RUNS`` takes the combine through its kernel (``ops/pallas/rows_sum.py``, interpreted here:
+    ``runs`` given), which reads the rows in runs: the float32 sum of the same rows in another order
+    (one float32 rounding before the cast), the same bits where a token has one row."""
+    k, E, n, T, sent = _IN_RUNS.get(case, (case, 5, 40, None, "even"))
+    H = 24 if T is None else 128
     rng = np.random.default_rng(k)
-    sent_to = jnp.asarray(rng.integers(0, E, size=n * k), jnp.int32)
-    slots = jnp.arange(n * k, dtype=jnp.int32)
-    weights = jnp.asarray(rng.random(size=(n, k)), jnp.float32)
+    sent_to, slots, weights = _sorted(k, E, n, sent, rng)
     (by_expert, order, inverse, w_sorted), pull = jax.vjp(lambda w: _sort_rows(sent_to, slots, w), weights)
     tok = order // k
     assert np.array_equal(by_expert, np.sort(sent_to)) and np.array_equal(sent_to[order], by_expert)
@@ -289,17 +318,86 @@ def test_dispatch_and_combine_are_each_others_transposes(k, dtype):
     assert np.array_equal(d_weights, d_ws[inverse]) and np.array_equal(d_weights.reshape(-1)[order], d_ws)
     x = jnp.asarray(rng.normal(size=(n, H)), dtype)
     ys = jnp.asarray(rng.normal(size=(n * k, H)), dtype)
+    runs = None
+    if T is not None:
+        runs = rows_sum.run_bounds(by_expert, tok, n, E, T)
+        bounds = np.asarray(runs)
+        group_of, tok_of = np.asarray(by_expert), np.asarray(tok)
+        for i, g in np.ndindex(n // T, E):        # a tile's run in a group: exactly its tokens' rows there
+            mine = np.flatnonzero((group_of == g) & (tok_of // T == i))
+            assert np.array_equal(mine, np.arange(bounds[i, g], bounds[i + 1, g])), (i, g)
+        if "cross" in case:
+            assert np.any(bounds[1:-1] % 16 != 0) and np.any(bounds[1:] // 128 > bounds[:-1] // 128)
+        runs = rows_sum.visits(runs, n * k)
+        assert int(runs[0][-1]) == rows_sum.chunks_visited(bounds.tolist()) <= runs[1].shape[0]
 
-    xs, pull = jax.vjp(lambda x: _take_rows(x, tok, inverse), x)
-    assert np.array_equal(xs, x[tok]) and np.array_equal(pull(ys)[0], _sum_rows(ys, tok, inverse))
-    y, pull = jax.vjp(lambda ys: _sum_rows(ys, tok, inverse), ys)
-    assert y.dtype == dtype and np.array_equal(pull(x)[0], xs)
+    def both_ways(x, ys):
+        xs, pull_xs = jax.vjp(lambda x: _take_rows(x, tok, inverse, runs), x)
+        y, pull_y = jax.vjp(lambda ys: _sum_rows(ys, tok, inverse, runs), ys)
+        return xs, pull_xs(ys)[0], y, pull_y(x)[0], _sum_rows(ys, tok, inverse, runs)
+
+    # the interpreted kernel as one program (a compile a case); the compiled form call by call, as it was
+    xs, d_x, y, d_ys, summed = (both_ways if T is None else jax.jit(both_ways))(x, ys)
+    assert np.array_equal(xs, x[tok]) and np.array_equal(d_x, summed)
+    assert y.dtype == dtype and y.shape == (n, H) and np.array_equal(d_ys, xs) and np.array_equal(y, summed)
     # a token's k rows are added in float32 and rounded once; the plain scatter adds in the compute dtype
     tolerance = 1e-5 if dtype == jnp.float32 else 3e-2
     want = jnp.sum(ys[inverse].astype(jnp.float32), axis=1)
-    np.testing.assert_allclose(y.astype(jnp.float32), want, rtol=0, atol=0 if dtype == jnp.float32 else tolerance)
+    if T is None or k == 1:
+        assert np.array_equal(y, want.astype(dtype))
+    else:           # the same rows in sorted order: apart by the float32 sum's own roundings, before the one cast
+        ulp = (k - 1) * np.spacing(np.sum(np.abs(np.asarray(ys[inverse], np.float32)), axis=1))
+        assert np.all(np.abs(np.asarray(y, np.float32) - np.asarray(want.astype(dtype), np.float32))
+                      <= (ulp if dtype == jnp.float32 else np.abs(np.asarray(want)) * 2.0 ** -7 + ulp))
     np.testing.assert_allclose(y.astype(jnp.float32), jax.vjp(lambda x: x[tok], x)[1](ys)[0].astype(jnp.float32),
                                rtol=tolerance, atol=tolerance)
+
+
+def test_the_combines_kernel_refuses_what_is_no_whole_tile():
+    """``rows_sum.fits`` names the shapes the kernel takes (whole token tiles, whole chunks of rows, whole
+    registers of lanes), the entry point refuses the others, and ``_run_bounds`` keeps the compiled gather
+    for them and off the TPU; on it every cell's ``(k, G)`` that calls the helpers reads its rows in runs."""
+    assert rows_sum.fits(8192, 8192 * 6, 2688) and not rows_sum.fits(8200, 8200 * 6, 2688)
+    assert not rows_sum.fits(8192, 8192 * 6, 2688 + 64) and not rows_sum.fits(256, 256 * 3 + 64, 128)
+    with pytest.raises(AssertionError):
+        rows_sum.rows_sum(jnp.zeros((40 * 2, 128)), jnp.zeros(80, jnp.int32),
+                          rows_sum.visits(jnp.zeros((3, 2), jnp.int32), 80), 40, interpret=True)
+    group, tok = jnp.zeros(8192 * 8, jnp.int32), jnp.zeros(8192 * 8, jnp.int32)
+    assert moe._run_bounds(8192, 8, 16, 2304, group, tok) is None          # off the TPU
+    with pytest.MonkeyPatch.context() as monkey:
+        monkey.setattr(jax, "default_backend", lambda: "tpu")
+        took = {cell: moe._run_bounds(8192, k, G, H, group[:8192 * k], tok[:8192 * k]) is not None
+                for cell, (k, G, H) in dict(nemotronh=(6, 8, 2688), mellum2=(8, 16, 2304), glm47flash=(4, 8, 2048),
+                                            lfm2=(4, 8, 2048), olmoe=(8, 64, 2048)).items()}
+        assert all(took.values()), took
+        assert moe._run_bounds(8200, 8, 16, 2304, group[:8200 * 8], tok[:8200 * 8]) is None
+    # what a balanced router's runs visit over what the rows fill: the count a trace and a test can both state
+    assert rows_sum.rows_sum_chunks(8192, 8, 16) == (512, 512) and rows_sum.rows_sum_chunks(8192, 6, 8) == (512, 384)
+    assert rows_sum.rows_sum_chunks(8192, 4, 8) == (256, 256) and rows_sum.rows_sum_chunks(8192, 8, 64) == (2048, 512)
+
+
+@pytest.mark.parametrize("bad", [np.inf, np.nan], ids=["inf", "nan"])
+def test_a_row_that_is_not_finite_stays_in_the_tiles_that_visit_its_chunk(bad):
+    """What the kernel promises of a row that is not finite: its own token's sum is not finite (the
+    step's overflow check sees it as it saw the gather's), and every token tile that visits no chunk
+    holding the row keeps its bits. A tile that does visit the chunk meets ``0 x inf`` in the one-hot
+    product and is not finite as a whole: wider than the compiled form, which confines it to the token."""
+    k, G, n, T, H = 3, 2, 256, 32, 128
+    rng = np.random.default_rng(7)
+    by_expert, order, inverse, _ = _sort_rows(*_sorted(k, G, n, "even", rng))
+    tok = order // k
+    runs = rows_sum.visits(rows_sum.run_bounds(by_expert, tok, n, G, T), n * k)
+    ys = jnp.asarray(rng.normal(size=(n * k, H)), jnp.bfloat16)
+    row = 10
+    combine = jax.jit(lambda ys: _sum_rows(ys, tok, inverse, runs))
+    clean, spoilt = np.asarray(combine(ys), np.float32), np.asarray(combine(ys.at[row, 5].set(bad)), np.float32)
+    first, chunks = np.asarray(runs[0]), np.asarray(runs[1])
+    visiting = [i for i in range(n // T) if row // rows_sum.CHUNK in chunks[first[i]:first[i + 1]]]
+    assert 0 < len(visiting) < n // T and int(tok[row]) // T in visiting
+    assert not np.isfinite(spoilt[int(tok[row]), 5])
+    finite = np.setdiff1d(np.arange(n // T), visiting)
+    assert np.array_equal(spoilt.reshape(n // T, T, H)[finite], clean.reshape(n // T, T, H)[finite])
+    assert np.all(np.isfinite(spoilt[:, np.arange(H) != 5]))               # a column of its own, too
 
 
 @pytest.mark.parametrize("held, stand_in", [(None, False), ((4, 4), True)], ids=["whole-range", "held-stand-in"])

@@ -253,6 +253,20 @@ def test_the_grouped_state_space_scan_compiles_for_v5e(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
 
 
+def expert_cells_gradient_program(chip, monkeypatch, build_model, config):
+    """``(compiled, model, the leaves' shapes)``: a cell's whole gradient program at its
+    configuration's widths and 1 x 8,192 positions, bf16 leaves, compiled for the described chip."""
+    from benchmarks.manifest import Manifest
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels, not their interpreters
+    model = build_model(Manifest().config(config))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
+    return compiled, model, shapes
+
+
 def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, monkeypatch):
     """The gradient program of ``nemotronh_ep16_d9_train_1chip`` WHOLE: the published widths, the
     nine layers MEMEM*EME with 8 of 128 experts held, 1 x 8,192 positions, whole layers
@@ -262,17 +276,10 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     flash forward ONCE (a layer keeps the kernel's output by name since PR 41), and what it needs
     beside its parameters and their gradients stays under the 5.3 GB that 10.67 GB of training
     state leave on the chip."""
-    from benchmarks.manifest import Manifest
     from benchmarks.runners.train_ssm_moe import build_model
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels, not their interpreters
-    model = build_model(Manifest().config("nemotron-twotower-30b-a3b-ep16-d9"))
+    compiled, model, shapes = expert_cells_gradient_program(chip, monkeypatch, build_model, "nemotron-twotower-30b-a3b-ep16-d9")
     assert model.config.kinds == "MEMEM*EME" and model.config.remat
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 666_963_456
-    params = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
-    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
-    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
     text = compiled.as_text()
     for kernel in ("ds_ssd_scan_fwd", "ds_ssd_scan_bwd", "ds_causal_conv_fwd", "ds_flash_fwd", "gmm"):
         assert kernel in text, kernel
@@ -283,7 +290,7 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     # keep the first grouped product's output, 0.73 GB over four layers). With the passes it was
     # 3.43 GB, their loops counted twice (PR 41; 1.39 GB under policy None, PR 40): the mixers'
     # first product's output is 0.68 GB of it, the shared expert's 0.49
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.08e9 * 1.05
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.08e9 * 1.05          # 3.01 GB since PR 53
     # six rows a token: no float32 copy of all the rows is laid out, broadcast or moved to another
     # layout between the tokens and the sorted rows (three passes a layer until PR 46), and none
     # padded to eight slots a token (the combine's cotangent ``dy x weights`` until PR 49)
@@ -302,6 +309,55 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     # both forwards of four layers, and ``d_ws[inverse]``) and ``f32[1048576] scatter`` four (the
     # chosen scores' cotangent into zeros ``[8192, 128]``, flat), 8 ns an element each
     assert not re.search(r"= f32\[(?:49152|8192,6|8192,128|1048576)\]\S* (?:gather|scatter)\(", text)
+    assert_the_combine_reads_its_rows_in_runs(text, "49152,2688", "8192,2688", layers=4)
+
+
+@pytest.mark.parametrize("k, G, H, dtype", [(6, 8, 2688, jnp.bfloat16), (8, 16, 2304, jnp.bfloat16), (4, 8, 2048, jnp.bfloat16),
+                                            (8, 64, 2048, jnp.bfloat16), (4, 8, 2048, jnp.float32)],
+                         ids=["nemotronh", "mellum2", "glm47flash-lfm2", "olmoe", "float32"])
+def test_the_expert_cells_combine_kernel_compiles_for_v5e(chip, k, G, H, dtype):
+    """``ops/pallas/rows_sum.py`` at the five expert cells' ``(k, G, H)`` and 8,192 tokens, with
+    the bounds of its runs made beside it (a compare and a sum, no search's loop): one kernel
+    call, its copies from rows left in HBM, the chunks' buffers and a tile's float32 sum in
+    fast memory; float32 rows take six passes of the MXU each and more of that memory."""
+    from deepspeed_tpu.ops.pallas import rows_sum
+    n = 8192
+    rows, index = ((jax.ShapeDtypeStruct(shape, dt, sharding=chip)) for shape, dt in (((n * k, H), dtype), ((n * k,), jnp.int32)))
+    text = jax.jit(lambda ys, group, tok: rows_sum.rows_sum(
+        ys, tok, rows_sum.visits(rows_sum.run_bounds(group, tok, n, G), n * k), n)).lower(rows, index, index).compile().as_text()
+    assert len(re.findall(r"custom-call\(.*tpu_custom_call", text)) == 1 and "ds_moe_rows_sum" in text
+    assert " while(" not in text and " gather(" not in text
+
+
+def assert_the_combine_reads_its_rows_in_runs(text, rows, tokens, layers):
+    """In an expert cell's whole gradient program: ``ds_moe_rows_sum`` twice an expert layer (the
+    forward's combine and the dispatch's cotangent, neither made again by a recomputed layer), no
+    gather that writes the sorted rows ``[n k, H]`` FROM the sorted rows (2.1-2.4 ms a call at a
+    price a ROW, PERF.md, PR 53; the parent's text held two a layer), and the three gathers a layer
+    that are left (the dispatch, its second forward, the combine's cotangent) read the ``n``
+    tokens' rows from a source the compiler holds in fast memory (``S(1)``) beside the kernel."""
+    calls = [line for line in text.splitlines() if "ds_moe_rows_sum" in line and "custom-call(" in line]
+    assert len(calls) == 2 * layers and all("tpu_custom_call" in line for line in calls)
+    layouts = dict(re.findall(r"%(\S+) = (\w+\[[0-9,]*\]\S*) parameter\(", text))
+    sources = [layouts[operand] for operand in re.findall(r"= bf16\[%s\]\S* gather\(%%(\S+?)," % rows, text)]
+    assert len(sources) == 3 * layers and all(source.startswith(f"bf16[{tokens}]") and "S(1)" in source
+                                              for source in sources), sources
+
+
+def test_the_sliding_window_expert_cells_gradient_program_compiles_for_v5e(chip, monkeypatch):
+    """The gradient program of ``mellum2_ep4_d4_train_1chip`` WHOLE (the published widths, three
+    sliding-window layers and a full one, 16 of 64 experts held and standing in, 1 x 8,192
+    positions, whole layers recomputed but for ``mellum.KEPT_BY_A_LAYER``): the banded flash kernel
+    and the megablox products are in it, the combine reads its 65,536 sorted rows in runs, and
+    what it needs beside its parameters and their gradients is 1.81 GB as compiled here (2.19
+    until the combine's ``[k n, H]`` intermediate went, PR 53; 3.13 before PR 49)."""
+    from benchmarks.runners.train_swa_moe import build_model
+    compiled, _, _ = expert_cells_gradient_program(chip, monkeypatch, build_model, "mellum2-12b-a2.5b-ep4-d4")
+    text = compiled.as_text()
+    for kernel in ("ds_flash_fwd", "ds_flash_bwd_dkv", "gmm"):
+        assert kernel in text, kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.81e9 * 1.05
+    assert_the_combine_reads_its_rows_in_runs(text, "65536,2304", "8192,2304", layers=4)
 
 
 def looped_gradient_program(chip, monkeypatch, layers, passes):
