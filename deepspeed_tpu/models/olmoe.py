@@ -129,14 +129,14 @@ class OlmoeModel:
         return jnp.dot(y, lp["wo"].astype(x.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
 
-    def _block(self, x, lp, positions, details=False):
+    def _block(self, x, lp, positions, details=False, keep=False):
         c = self.config
         with jax.named_scope("ds_attn"):
             x = x + self._attention(rms_norm(x, lp["norm_1"], c.rms_norm_eps), lp, positions)
         # the expert layer is this block's MLP: its ds_moe_* scopes nest under ds_mlp
         with jax.named_scope("ds_mlp"):
             m, aux, stats = self.moe.apply(
-                lp["moe"], rms_norm(x, lp["norm_2"], c.rms_norm_eps), details)
+                lp["moe"], rms_norm(x, lp["norm_2"], c.rms_norm_eps), details, keep)
             return x + m, aux, stats
 
     def _backbone(self, params, tokens, details=False):
@@ -145,8 +145,10 @@ class OlmoeModel:
         with jax.named_scope("ds_embed"):
             x = params["embed"][tokens].astype(c.compute_dtype)
         aux, stats = jnp.zeros((), jnp.float32), []
+        # whether a layer's backward finds the experts it fetched still there: as the chip has room
+        keep = self.moe.fetches_kept(len(params["layers"]), x)
         for lp in params["layers"]:
-            x, a, s = self._block(x, lp, positions, details)
+            x, a, s = self._block(x, lp, positions, details, keep)
             aux = aux + a
             stats.append(s)
         with jax.named_scope("ds_loss"):      # the last norm feeds the head

@@ -31,6 +31,7 @@ GShard/Switch-Transformer recipe expressed TPU-first:
 it slots into ``PipelineModule`` stacks and the engine unchanged.
 """
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -605,6 +606,40 @@ def piece_firsts(axis, per_chip):
     return tuple(((me - back) % n * per_chip).astype(jnp.int32) for back in _nearest_first(n))
 
 
+# What a layer fetched for its forward, by name (``checkpoint_name``): beside ``ds_moe_gate_up``
+# in the policy of a layer whose backward finds the pieces still there.
+FETCHED = ("ds_moe_fetched_gate_up", "ds_moe_fetched_down")
+
+_room = lambda: 0     # noqa: E731    (bytes; replaced while a program is traced under ``room_for_fetched_experts``)
+
+
+@contextlib.contextmanager
+def room_for_fetched_experts(room):
+    """While a model is traced under this, its expert layers may keep ``room()`` bytes a chip
+    of the experts they fetched for the forward (``fetches_kept``); asked only by a layer that
+    fetches. The engine enters it around every trace of a model with what it knows of the chip
+    (``DeepSpeedEngine._room_beside_state``); outside it nothing is kept."""
+    global _room
+    before, _room = _room, room
+    try:
+        yield
+    finally:
+        _room = before
+
+
+def fetches_kept(layers, layer_bytes, room):
+    """Whether ``layers`` stacked expert layers keep the experts they fetched from their forward
+    for their backward: a pure function of a chip's bytes, a layer's ``n - 1`` fetched pieces of
+    both arrays (``layer_bytes``) and the ``room`` for all layers'. Kept, a piece crosses the
+    chips twice a step (fetched, its gradient sent home) and not three times. ALL OR NOTHING:
+    with ``w_down``'s pieces alone kept (a third of the bytes, and the fetch a backward was seen
+    waiting for) ``olmoe_d4_train_4chip``'s step read 323.6, 339.4 and 325.9 ms under three
+    schedules where it reads 318.7 with nothing kept and 297.6 with everything: the second fetch
+    that is left still stands between the gradients' sends, and what is kept costs the second
+    step in flight all the same (PERF.md, PR 54)."""
+    return layers * layer_bytes <= room
+
+
 # ------------------------------------------------------------------ the experts' form
 SILU_GATED, RELU2 = "silu_gated", "relu2"
 
@@ -720,9 +755,10 @@ class DroplessMoE:
     parameter: where the context mesh's ``data`` axis (the engine traces the model under
     its own) has several devices that divide the experts, each chip owns ``E / ep`` of
     them, master copy and optimizer state with them; a layer fetches the other chips' bf16
-    expert weights for the forward pass and again for the backward (``gather_pieces``:
-    ``ep - 1`` chip-to-chip transfers the compiler runs under the kernels, each arrived
-    piece going straight into its own grouped-matmul call), every chip computes every
+    expert weights for the forward pass (``gather_pieces``: ``ep - 1`` chip-to-chip transfers
+    the compiler runs under the kernels, each arrived piece going straight into its own
+    grouped-matmul call) and, where the chip has no room to keep them (``fetches_kept``),
+    again for the backward, every chip computes every
     expert on its own tokens, and each piece's gradient goes straight back to its owner,
     who sums them: complete there and not averaged. Tokens never cross the chips: this is
     not expert parallelism by a token all-to-all. That needs a static bound on what a
@@ -827,17 +863,29 @@ class DroplessMoE:
             return None, None
         return DATA_AXIS, mesh
 
-    def apply(self, params, x, details=False):
+    def fetches_kept(self, layers, x):
+        """For a model of ``layers`` such layers in a row on inputs like ``x``: whether each keeps
+        the experts it fetched (``apply``'s ``keep``), by the module's rule from the room the
+        program is traced under. Never without an expert axis: nothing is fetched."""
+        axis, mesh = self._expert_axis(x.shape[0])
+        if axis is None:
+            return False
+        ep = mesh.shape[axis]
+        one = self.ffn_dim * self.hidden * (3 if self.form == SILU_GATED else 2) * x.dtype.itemsize
+        return fetches_kept(layers, (ep - 1) * (self.num_experts // ep) * one, _room())
+
+    def apply(self, params, x, details=False, keep=False):
         """``details`` adds to ``stats`` what a comparison with a reference reads (never
         a step): ``experts``, each token's choices ``[B, T, k]`` in ascending order, and
-        ``router_logits`` ``[B, T, E]`` float32."""
+        ``router_logits`` ``[B, T, E]`` float32. ``keep``: ``fetches_kept`` of the model that
+        stacks the layers."""
         axis, mesh = self._expert_axis(x.shape[0])
         # the leaves in a fixed order; the selection bias, where the router has one, last
         leaves = [params["router_w"], params[self.w_in], params["w_down"]]
         if self.scaling is not None:
             leaves.append(params["router_bias"])
         if axis is None:
-            return self._local(None, details, *leaves[:3], x, *leaves[3:])
+            return self._local(None, False, details, *leaves[:3], x, *leaves[3:])
         specs = self.expert_specs(axis)
         stats_specs = {"load_max_over_mean": P()}
         if self.scaling is not None:
@@ -845,7 +893,7 @@ class DroplessMoE:
         if details:
             stats_specs.update(experts=P(axis), router_logits=P(axis))
         fn = jax.shard_map(
-            lambda r, gu, d, xl, *b: self._local(axis, details, r, gu, d, xl, *b),
+            lambda r, gu, d, xl, *b: self._local(axis, keep, details, r, gu, d, xl, *b),
             in_specs=(specs["router_w"], specs[self.w_in], specs["w_down"], P(axis))
             + (P(),) * (len(leaves) - 3),
             out_specs=(P(axis), P(), stats_specs),
@@ -872,9 +920,10 @@ class DroplessMoE:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + self.eps)
         return weights * self.scaling, experts, jnp.zeros(logits.shape[1:], jnp.float32)
 
-    def _local(self, axis, details, router_w, w_gate_up, w_down, x, bias=None):
+    def _local(self, axis, keep, details, router_w, w_gate_up, w_down, x, bias=None):
         """One chip's part: ``x`` its tokens, the expert arrays the experts it owns
-        (``w_gate_up``: the first product's, whatever the experts' form)."""
+        (``w_gate_up``: the first product's, whatever the experts' form); ``keep``: whether
+        the backward finds the pieces it fetched still there (``FETCHED``)."""
         H, E, k = self.hidden, self.num_experts, self.top_k
         form = self.form
         shape = x.shape
@@ -908,15 +957,29 @@ class DroplessMoE:
         def routed(x2, w_sorted, w_gate_up, w_down):
             dt = x2.dtype
             w_gate_up, w_down = w_gate_up.astype(dt), w_down.astype(dt)
+            with jax.named_scope("ds_moe_dispatch"):
+                xs = _take_rows(x2, tok, inverse, runs)                   # [n * k, H]
             if axis is None:
                 gate_up_pieces, down_pieces, firsts = (w_gate_up,), (w_down,), (None,)
             else:
+                if keep:
+                    # One barrier for the first product's rows and this chip's ``w_down``. Its
+                    # transpose holds their cotangents together: ``w_down``'s gradients are home
+                    # when the first product's backward is through, eight kernels to hide under,
+                    # and the compiler spreads ``w_gate_up``'s over the next layer's backward.
+                    # Alone, once no second fetch stands between them, it leaves all 24 sends to
+                    # the end of the program (21 ms a step of waits after the last kernel). Forward,
+                    # ``w_down``'s fetch starts with the layer and not, all four layers' at once,
+                    # before the first kernel of the step (PERF.md, PR 54: 321.6 ms a step without
+                    # it, 309.2 as a tie of the cotangents alone, 297.6 so; the parent 318.7)
+                    xs, w_down = jax.lax.optimization_barrier((xs, w_down))
                 with jax.named_scope("ds_moe_exchange"):
-                    gate_up_pieces = gather_pieces(w_gate_up, axis)
-                    down_pieces = gather_pieces(w_down, axis)
+                    # this chip's own piece is the parameter it holds: the others' have a name
+                    fetched = gather_pieces(w_gate_up, axis), gather_pieces(w_down, axis)
+                    gate_up_pieces, down_pieces = (
+                        pieces[:1] + tuple(checkpoint_name(piece, name) for piece in pieces[1:])
+                        for name, pieces in zip(FETCHED, fetched))
                     firsts = piece_firsts(axis, w_down.shape[0])
-            with jax.named_scope("ds_moe_dispatch"):
-                xs = _take_rows(x2, tok, inverse, runs)                   # [n * k, H]
             with jax.named_scope("ds_moe_experts"):
                 gate_up = checkpoint_name(
                     experts_matmul(xs, gate_up_pieces, firsts, sizes), "ds_moe_gate_up")
@@ -929,11 +992,11 @@ class DroplessMoE:
             lo, rows_here = starts[first], starts[first + count] - starts[first]
         # The backward keeps the first product's output and nothing of the second's. It makes
         # the gathered rows and the weighted activation again (a gather and an elementwise
-        # pass) and fetches the experts' weights again: kept, the four layers' gathered
-        # weights would be 3.2 GB a chip.
+        # pass). The experts' weights it fetches again unless ``keep``: what a chip fetched for
+        # OLMoE's four layers is 2.42 GB (604 MB a layer), kept where the chip has the room.
         if self.every_row_here:
             y = jax.checkpoint(routed, policy=jax.checkpoint_policies.save_only_these_names(
-                "ds_moe_gate_up"))(x2, w_sorted, w_gate_up, w_down)
+                "ds_moe_gate_up", *(FETCHED if keep else ())))(x2, w_sorted, w_gate_up, w_down)
         else:
             # as many rows as the router sends: in passes, with the held rows up to each held expert
             sort = (tok, order, lo, rows_here, jnp.cumsum(group_sizes[first:first + count]))

@@ -151,19 +151,23 @@ def make_engine(args=None, model=None, optimizer=None, model_parameters=None, tr
                            config_params=config_params)
 
 
-def _traced_under(mesh, fn):
+def _traced_under(mesh, fn, room):
     """``fn`` traced with ``mesh`` in context, so that code XLA cannot partition
     for it (the Pallas kernels, ops/pallas/partition.py) sees which axes to split
     itself over. A mesh already in context stays: a ``shard_map`` body carries its
-    own, with the axes it made manual."""
+    own, with the axes it made manual. And with ``room``, which gives a chip's bytes beside
+    the engine's state, in context too: what the expert layers may keep of the experts they
+    fetched (``parallel/moe.room_for_fetched_experts``)."""
+    from ..parallel.moe import room_for_fetched_experts
     abstract = mesh.abstract_mesh
 
     @functools.wraps(fn)
     def traced(*args):
-        if not jax.sharding.get_abstract_mesh().empty:
-            return fn(*args)
-        with jax.sharding.use_abstract_mesh(abstract):
-            return fn(*args)
+        with room_for_fetched_experts(room):
+            if not jax.sharding.get_abstract_mesh().empty:
+                return fn(*args)
+            with jax.sharding.use_abstract_mesh(abstract):
+                return fn(*args)
 
     return traced
 
@@ -286,7 +290,7 @@ class DeepSpeedEngine:
                                 "sequence_parallel_loss_fn(mesh, axis, schedule=...)")
             self.model_fn = sp_build(self.mesh, self.config.sequence_parallel_axis,
                                      schedule=self.config.sequence_parallel_schedule)
-        self.model_fn = _traced_under(self.mesh, self.model_fn)
+        self.model_fn = _traced_under(self.mesh, self.model_fn, self._room_beside_state)
         if param_shardings is None and hasattr(model, "engine_shardings"):
             # the model's own layout over this mesh (experts that live split over the
             # data axis); ZeRO claims what it leaves free, as for a caller's layout
@@ -2113,6 +2117,17 @@ class DeepSpeedEngine:
                 "grad_nonfinite": host["grad_nonfinite"],
             }
         return overflow
+
+    def _room_beside_state(self):
+        """A chip's bytes for what a gradient program keeps beyond need, by ``utils/hbm``'s rule
+        from the device's limit and this engine's state; none where the backend reports no
+        limit (the CPU). Read when a model is traced, never in a step."""
+        from ..utils import hbm
+        stats = [device_memory_stats(d) for d in self.mesh.local_devices]
+        if not all(s and "bytes_limit" in s for s in stats):
+            return 0
+        _, class_bytes = hbm.manifest_signatures(self.memory_manifest())
+        return hbm.room_beside_state(min(s["bytes_limit"] for s in stats), class_bytes)
 
     def _note_memory_in_use(self):
         """``bytes_in_use`` of the open ``train.step``: the most any of this process's

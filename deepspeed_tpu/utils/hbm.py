@@ -83,6 +83,27 @@ def device_memory_stats(device=None) -> Optional[Dict[str, int]]:
     return dict(stats) if stats else None
 
 
+# What a gradient program needs beside what it KEEPS beyond need (``room_beside_state``), set from
+# ``olmoe_d4_train_4chip`` on four v5e chips (PERF.md, PR 54; ``tests/perf/kept_fetches_probe.py``):
+# the device's limit 16.909 GB, the engine's state 7.006 GB a chip and 0.942 GB a set of gradients;
+# the gradient program's temporaries 6.366 GB with nothing kept (the margin, rounded up) and
+# 7.980 GB with the 2.416 GB a chip fetches for four expert layers kept: +1.61, since a layer's
+# second fetch was a temporary too. 0.98 GB then stay free while the program runs alone.
+TEMPORARIES_MARGIN = 6_400_000_000
+
+
+def room_beside_state(limit, class_bytes):
+    """Bytes of a chip that a gradient program may spend on what it keeps beyond need (the
+    experts an expert layer fetched: ``parallel/moe.fetches_kept``): the device's ``limit``
+    less the engine's state (``class_bytes``: a chip's bytes by ``memory_manifest`` class), ONE
+    set of gradients, the program's own output, and ``TEMPORARIES_MARGIN``; never negative.
+    One set: a program that keeps more no longer fits beside the update program that holds the
+    set before, so the runtime starts it when that has ended (one step in flight where the
+    parent has two: the price, measured at 8.8 against 10.3 GB in use)."""
+    state = sum(b for cls, b in class_bytes.items() if cls != "grads")
+    return max(0, limit - state - class_bytes.get("grads", 0) - TEMPORARIES_MARGIN)
+
+
 # ----------------------------------------------------------------- parsed
 def leaf_signature(leaf):
     """(hlo_dtype, per-device shape, per-device bytes) of one manifest leaf.

@@ -5,6 +5,7 @@ sends every token to one expert; the grouped matmul, whole and in pieces, agains
 per-expert loop; the experts' exchange against the collectives it stands for."""
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -484,6 +485,116 @@ def test_the_exchange_moves_what_the_collectives_moved(devices):
     assert np.all(np.abs(np.asarray(summed, np.float32) - np.asarray(exact)) <= rounding)
     assert np.all(np.abs(np.asarray(summed, np.float32) - np.asarray(collective, np.float32))
                   <= 2 * rounding)
+
+
+@pytest.mark.parametrize("room, kept", [(10 ** 12, True), (4 * 604, True), (4 * 604 - 1, False), (4 * 201, False), (0, False)],
+                         ids=["everything", "to-the-byte", "a-byte-short", "w-down-alone-would-fit", "no-room"])
+def test_what_a_backward_keeps_of_its_fetches_is_a_function_of_the_sizes(room, kept):
+    """All or nothing: a part kept (``w_down``'s third) measured slower than nothing kept."""
+    assert moe.fetches_kept(4, 604, room) is kept
+
+
+@pytest.mark.parametrize("limit, classes, room", [
+    (16_000, {"params": 1_000, "master": 2_000, "optimizer": 4_000, "grads": 500}, 16_000 - 7_000 - 500),
+    (16_000, {"params": 1_000}, 15_000),                     # a fused step holds no gradients between programs
+    (7_400, {"params": 1_000, "master": 2_000, "optimizer": 4_000, "grads": 500}, 0),       # never negative
+], ids=["state-and-a-set-of-gradients", "no-gradients-held", "no-room"])
+def test_the_room_beside_the_state_is_a_function_of_the_sizes(limit, classes, room, monkeypatch):
+    from deepspeed_tpu.utils import hbm
+    assert hbm.room_beside_state(limit + hbm.TEMPORARIES_MARGIN, classes) == room
+    monkeypatch.setattr(hbm, "TEMPORARIES_MARGIN", 0)
+    assert hbm.room_beside_state(limit, classes) == room
+
+
+def test_olmoe_on_four_v5e_chips_has_the_room_for_all_it_fetches():
+    """The sizes the margin was set from (PERF.md, PR 54): the four layers' fetched experts are
+    2.416 GB a chip and fit; a fifth layer's would not."""
+    from deepspeed_tpu.utils import hbm
+    room = hbm.room_beside_state(16_909_334_528, {"params": 1_352_798_208, "master": 1_884_329_984, "optimizer": 3_768_659_968,
+                                                  "grads": 942_163_968})
+    layer = DroplessMoE(2048, 1024, 64, 8)
+    fetched = 3 * 16 * 3 * 1024 * 2048 * 2
+    assert fetched == 603_979_776 and moe.fetches_kept(4, fetched, room) and not moe.fetches_kept(5, fetched, room)
+    assert layer.fetches_kept(4, jnp.zeros((8, 16, 2048), jnp.bfloat16)) is False      # no mesh in context: nothing is fetched
+
+
+def two_layers_on_four_devices(top_k=2, dtype=jnp.float32):
+    """The toy of two layers placed on four devices as the engine places it, ``gradient(room)``:
+    its loss and gradients as a program traced with ``room`` bytes for the fetched experts (a
+    new ``jit`` a call: the room is read when a program is traced), its arguments, and the bytes
+    a layer fetches."""
+    _, model, params = build(top_k, compute_dtype=dtype)
+    params = jax.tree_util.tree_map(lambda p: p.astype(dtype), params)
+    tokens, labels = batch()
+    params, tokens, labels, under = on_mesh(model, params, tokens, labels, 4)
+
+    def gradient(room):
+        def traced(*args):
+            with moe.room_for_fetched_experts(lambda: room):
+                return jax.value_and_grad(lambda p, t, l: model.apply(p, t, l)[0])(*args)
+        return jax.jit(under(traced))
+    # a layer's three fetched pieces of both arrays (the toy: E 8, F 32, H 64)
+    return gradient, (params, tokens, labels), 3 * 2 * 3 * 32 * 64 * jnp.dtype(dtype).itemsize
+
+
+FORWARD, AGAIN, HOME = (("jit", "shard_map") + rest for rest in (("custom_vjp_call",), ("remat2", "custom_vjp_call"), ("remat2",)))
+
+
+@pytest.mark.parametrize("layers_that_fit", [0, 1, 2], ids=["no-room", "room-for-one-layer", "room-for-both"])
+def test_a_kept_piece_crosses_the_chips_twice_a_step_and_not_three_times(layers_that_fit):
+    """Two stacked expert layers on four devices: a layer's experts move as six transfers
+    forward (three fetched pieces of two arrays), six gradients sent home, and six more in the
+    backward's second forward where they were NOT kept: 36 in all as the parent has them, two
+    thirds of that with the pieces kept, and nothing kept where not all fit. Counted where they
+    stand in the gradient's jaxpr and again in the lowered program."""
+    gradient, args, layer = two_layers_on_four_devices()
+    kept = layers_that_fit == 2
+    equations = list(equations_by_path(jax.make_jaxpr(gradient(layers_that_fit * layer))(*args).jaxpr))
+    moved = collections.Counter(path for path, eqn in equations if eqn.primitive.name == "ppermute")
+    assert moved == collections.Counter({FORWARD: 12, HOME: 12, **({} if kept else {AGAIN: 12})})
+    # a layer that keeps its pieces passes the first product's rows and ``w_down`` through one barrier,
+    # forward and (its transpose) backward, and again in the second forward; one that keeps nothing is the parent's
+    barriers = collections.Counter(path for path, eqn in equations if eqn.primitive.name == "optimization_barrier")
+    assert barriers == (collections.Counter({FORWARD[:2]: 2, HOME: 4}) if kept else {})
+    assert gradient(layers_that_fit * layer).lower(*args).as_text().count("collective_permute") == (24 if kept else 36)
+
+
+@functools.lru_cache(maxsize=None)
+def not_kept(top_k, dtype):
+    gradient, args, _ = two_layers_on_four_devices(top_k, dtype)
+    return jax.device_get(gradient(0)(*args))
+
+
+@pytest.mark.parametrize("top_k", [2, 8], ids=["top2", "top8"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_kept_pieces_change_no_bit_of_the_loss_or_of_any_gradient(dtype, top_k):
+    """What is kept is what would be fetched again: the loss and every parameter's gradient are
+    the not-kept program's, compared with ``==`` and no tolerance, on seeded weights."""
+    gradient, args, layer = two_layers_on_four_devices(top_k, dtype)
+    (loss, grads), (want_loss, want_grads) = jax.device_get(gradient(2 * layer)(*args)), not_kept(top_k, dtype)
+    assert np.isfinite(loss) and loss == want_loss
+    same = jax.tree_util.tree_map(np.array_equal, grads, want_grads)
+    assert all(jax.tree_util.tree_leaves(same)), same
+    assert np.abs(np.asarray(grads["layers"][0]["moe"]["w_down"], np.float32)).max() > 0
+
+
+@pytest.mark.parametrize("limit, transfers", [(None, 36), (10 ** 12, 24)], ids=["no-limit-known", "a-chip-with-room"])
+def test_the_engine_gives_its_gradient_program_the_room_its_device_reports(limit, transfers, monkeypatch):
+    """The engine reads the room when it traces the model: the device's limit less its own
+    state, by ``utils/hbm.room_beside_state``. The CPU reports no limit and keeps nothing."""
+    from deepspeed_tpu.runtime import engine as engine_module
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    if limit is not None:
+        monkeypatch.setattr(engine_module, "device_memory_stats", lambda device=None: {"bytes_limit": limit})
+    _, model, params = build(2, compute_dtype=jnp.bfloat16, initializer_range=0.02)
+    engine = DeepSpeedEngine(model=model, model_parameters=params, mesh=build_mesh(data=4, devices=jax.devices()[:4]),
+                             config_params={"train_batch_size": 4, "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+                                            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}, "steps_per_print": 10 ** 9})
+    assert (engine._room_beside_state() > 0) == (limit is not None)
+    tokens, _ = batch(seed=4)
+    lowered = engine._jit_loss_and_grad.lower(engine.params, engine.scaler_state.cur_scale,
+                                              *map(engine.shard_batch, (tokens, np.roll(tokens, -1, 1))))
+    assert lowered.as_text().count("collective_permute") == transfers
 
 
 @pytest.mark.parametrize("fused_step", [False, True], ids=["two-programs", "fused_step"])

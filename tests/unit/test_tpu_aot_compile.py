@@ -8,6 +8,7 @@ here) and asserts the compiled program holds a ``tpu_custom_call``. Nothing runs
 so these say nothing about results or times; ``chip_smoke.py`` does that on the chip.
 """
 
+import collections
 import os
 import re
 
@@ -590,3 +591,32 @@ def test_the_experts_exchange_is_scheduled_under_the_kernels(topo, monkeypatch):
             kernels += 1
     assert not started, f"never ended: {sorted(started)}"
     assert covered["forward"] and covered["backward"], covered
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "fetched-again"])
+def test_the_four_chip_expert_cells_layers_fetch_no_kept_piece_twice_on_a_v5e(topo, monkeypatch, kept):
+    """``olmoe_d4_train_4chip``'s expert layer twice in a row, its gradient compiled for the four
+    described chips with the room to keep what it fetched (``moe.fetches_kept``), and without. A
+    layer moves its experts as six transfers forward and six gradients home; kept, no weight is
+    the operand of a second fetch over the same pairs of chips, and the chip's compiler leaves
+    24 transfers where the not-kept program has 33 (PERF.md, PR 54)."""
+    from deepspeed_tpu.parallel import moe
+    mesh, layer, one, x = expert_layer_on_four_chips(topo, monkeypatch)
+    between = jax.ShapeDtypeStruct((2048, 2048), jnp.bfloat16, sharding=NamedSharding(mesh, P()))
+
+    def loss(params, between, x):
+        keep = layer.fetches_kept(2, x)
+        y, aux_a, _ = layer.apply(params["a"], x, keep=keep)
+        y, aux_b, _ = layer.apply(params["b"], jnp.dot(y, between), keep=keep)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux_a + aux_b
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh), moe.room_for_fetched_experts(lambda: 2 ** 40 * kept):
+        entry = scheduled_entry(compiled_text(jax.grad(loss), {"a": one, "b": one}, between, x))
+    starts = [rest for _, opcode, _, rest in entry if opcode == "collective-permute-start"]
+    fetched = collections.Counter(
+        (re.match(r"%?(param[\w.]*)\)", rest).group(1), re.search(r"source_target_pairs=\{[^ ]*\}", rest).group(0))
+        for rest in starts if re.match(r"%?param", rest))
+    # four weight arrays, each to the three other chips: once where kept; twice where not, but for the
+    # first layer's ``w_gate_up``, which no backward reads here (nothing asks for its input's gradient)
+    assert len(fetched) == 12 and sorted(fetched.values()) == ([1] * 12 if kept else [1] * 3 + [2] * 9), fetched
+    assert len(starts) == (24 if kept else 33)
