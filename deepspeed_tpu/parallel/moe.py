@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.pallas import rows_sum
+from ..ops.pallas import grouped_matmul as grouped, rows_sum
+from ..utils import spans
 from .mesh import MODEL_AXIS
 
 
@@ -421,38 +422,49 @@ def _sum_rows(ys, tok, inverse, runs):
 _take_rows.defvjp(lambda x, *sort: (_take_rows(x, *sort), sort), lambda sort, dxs: (_sum_rows(dxs, *sort), None, None, None))
 _sum_rows.defvjp(lambda ys, *sort: (_sum_rows(ys, *sort), sort), lambda sort, dy: (_take_rows(dy, *sort), None, None, None))
 
-# megablox tiles: the rows of a tile, and the MOST a contraction and a column tile take. The
-# kernels round K and N up to whole tiles and compute every tile in full (a K remainder is
-# masked besides), so a tile that does not divide its width is issued work nobody needs:
-# (512, 1024, 1024) clipped with ``min`` padded 2304 and 2688 to 3072 and 1792 and 1856 to
-# 2048, 1.26 to 1.52 times the products, at the rate per ISSUED product of widths it divides
-# (74-86 % of a v5e's peak either way; PERF.md, PR 47, ``tests/perf/gmm_sweep.py``). What
-# bounds a tile from above is the 16 MiB of scoped VMEM that megablox's ``pallas_call`` leaves
-# no way to raise: two buffers an operand and an output and a float32 accumulator are 12 MiB
-# at (512, 1024, 1024), the kernels' own temporaries come on top, and every candidate the
-# chip's compiler refused stood at 14.3 MiB or more. What bounds it from below is the
-# operations a byte, ``tm tn / (tm + tn)`` (341 at a column tile of 1024, 284 at 640, 256 at
-# 512 against the chip's 240), and the accumulator's read and write a contraction step: at
-# OLMoE's widths (512, 512, 512) reaches 89 TFLOP/s where (512, 1024, 1024) reaches 117
-# (64 groups that end inside a row tile; 170 at PR 26), so no tile goes under half the most.
+# The grouped products' tiles: the rows of a tile, and the MOST a contraction and a column tile
+# take where the contraction does not stay whole. The kernels (``ops/pallas/grouped_matmul.py``)
+# round K and N up to whole tiles and compute every tile in full (a K remainder is masked
+# besides), so a tile that does not divide its width is issued work nobody needs: (512, 1024,
+# 1024) clipped with ``min`` padded 2304 and 2688 to 3072 and 1792 and 1856 to 2048, 1.26 to 1.52
+# times the products (PERF.md, PR 47, ``tests/perf/gmm_sweep.py``). What bounds a tile from below
+# is the operations a byte, ``tm tn / (tm + tn)`` (341 at a column tile of 1024, 284 at 640, 256
+# at 512 against the chip's 240): no column tile goes under 512.
 GMM_TILES = (512, 1024, 1024)
+# What bounds them from above was read on the chip (PERF.md, PR 55, ``tests/perf/gmm_sweep.py``).
+# A WHOLE contraction is 5-16 % faster a call than one cut in pieces (each piece a round trip of
+# ``ds_gmm``'s float32 accumulator and a fetch of the weights' block a row tile), at every width
+# read, up to 3,072: megablox's call could not state its fast memory, and its 16 MiB cut every
+# contraction over 1,024; ``ops/pallas/grouped_matmul.py`` asks the compiler for what its blocks
+# take. Beside a whole contraction ``ds_gmm`` reads level at any column tile (1.5 % between 640
+# and the whole 1,856), and ``ds_tgmm`` falls off a cliff once its float32 accumulator ``[tk,
+# tn]`` passes these elements (16 MiB: ``[2304, 1792]`` and ``[2048, 2048]`` level, ``[2688,
+# 1856]`` 4.81 ms for 2.96, ``[2048, 3072]`` 3.93 for 2.45). So the one bound is ``ds_tgmm``'s,
+# and the two products of a pair of widths share their tiles: K stays whole where a column tile
+# of 512 beside it stays under the bound, and N takes the widest tile under it of those that pad
+# it least. (A wide N beside a narrow K is bounded the same way by ``ds_gmm``'s float32 product
+# ``[tm, tn]``.)
+GMM_ACC = 2048 * 2048
 
 
 def _width_tile(width, most):
-    """The tile for a contraction or column ``width``: the whole width where ``most`` holds
-    it; else the multiple of 128 from half of ``most`` up to it that pads the width least,
-    of two that pad alike the larger (2048 -> 1024, 2304 -> 768, 1792 and 2688 -> 896,
-    1856 -> 640: 1920, where 1024 pads it to 2048)."""
+    """The tile for a contraction or column ``width`` that ``most`` bounds: the whole width
+    where ``most`` holds it; else the multiple of 128 from 512 up to ``most`` that pads the
+    width least, of two that pad alike the larger (with 1,024: 2048 -> 1024, 2304 -> 768, 1792
+    and 2688 -> 896, 1856 -> 640: 1920, where 1024 pads it to 2048)."""
     if width <= most:
         return width
-    return min(range(most, most // 2 - 1, -128), key=lambda tile: -(-width // tile) * tile)
+    return min(range(most - most % 128, 512 - 1, -128), key=lambda tile: -(-width // tile) * tile)
 
 
 def _tiles(rows, contraction, columns):
     """``(tm, tk, tn)`` for a grouped product of these widths, ``tgmm``'s ``tk`` and ``tn`` its
     output's two widths: a function of the shapes alone."""
-    tm, tk, tn = GMM_TILES
-    return min(tm, rows), _width_tile(contraction, tk), _width_tile(columns, tn)
+    tm = min(GMM_TILES[0], rows)
+    most = GMM_ACC // max(contraction, tm)          # the widest column tile beside the whole contraction
+    if most < 512:                                  # past every cell's widths: cut, as under megablox
+        return tm, _width_tile(contraction, GMM_TILES[1]), _width_tile(columns, GMM_TILES[2])
+    return tm, contraction, _width_tile(columns, most)
 
 
 def _run_bounds(n, k, G, H, group, tok):
@@ -479,17 +491,30 @@ def _ragged_sizes(rhs, group_sizes, first):
             jnp.concatenate([before[None].astype(group_sizes.dtype), mine]))
 
 
+def _count_product(kind, widths, tiles=None):
+    """While a step program is traced, every grouped product leaves in the recorder how it will
+    run: ``moe.<kind>.whole_k[<program>] <K>x<N> in <tm>x<tk>x<tn>`` where its contraction stays
+    ONE tile in fast memory (``tgmm``: its output's first width), ``.cut_k`` where it is cut in
+    pieces, ``.ragged_dot`` off the TPU; once a trace of the call (a layer traced once and run
+    four times counts once). ``docs/telemetry.md``."""
+    how = "ragged_dot" if tiles is None else "whole_k" if tiles[1] == widths[0] else "cut_k"
+    spans.recorder().count_in_program(f"moe.{kind}.{how}", " %dx%d" % widths + (" in %dx%dx%d" % tiles if tiles else ""))
+
+
 def grouped_matmul(lhs, rhs, group_sizes, first=None, out=None, transpose_rhs=False):
     """``out[m] = lhs[m] @ rhs[g(m)]`` for rows sorted by group: ``lhs [M, K]``,
     ``rhs [G, K, N]`` (``[G, N, K]`` with ``transpose_rhs``), ``group_sizes [G]`` summing
     to ``M``. With ``first`` (an int32 scalar, traced or not) ``rhs`` holds only the groups
     ``first .. first + len(rhs) - 1`` of a longer ``group_sizes``: their rows are computed,
     every other row is ``out``'s (unspecified where ``out`` is None), so that a chain of
-    calls over pieces of the experts fills one buffer in place. On the TPU this is JAX's
-    megablox kernel (``jax.experimental.pallas.ops.tpu.megablox``), elsewhere
+    calls over pieces of the experts fills one buffer in place. On the TPU this is the kernel
+    ``ds_gmm`` (``ops/pallas/grouped_matmul.py``) at the tiles ``_tiles`` picks, elsewhere
     ``lax.ragged_dot``, which XLA's CPU backend runs and whose TPU lowering reached 55 % of
     megablox's rate on the chip."""
-    if jax.default_backend() != "tpu":
+    columns = rhs.shape[1 if transpose_rhs else 2]
+    tiles = _tiles(lhs.shape[0], lhs.shape[1], columns) if jax.default_backend() == "tpu" else None
+    _count_product("gmm_t" if transpose_rhs else "gmm", (lhs.shape[1], columns), tiles)
+    if tiles is None:
         if transpose_rhs:
             rhs = rhs.swapaxes(1, 2)
         rhs, sizes = _ragged_sizes(rhs, group_sizes, first)
@@ -498,30 +523,26 @@ def grouped_matmul(lhs, rhs, group_sizes, first=None, out=None, transpose_rhs=Fa
             return y
         rows = jnp.arange(lhs.shape[0])
         return jnp.where(((rows >= sizes[0]) & (rows < jnp.sum(sizes)))[:, None], y, out)
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    columns = rhs.shape[1 if transpose_rhs else 2]
     if first is not None and out is None:
         # the kernel writes the tiles it visits and the pieces' calls together visit all:
-        # the first starts from a buffer nothing has written (megablox alone would mask
-        # the whole output after every call)
+        # the first starts from a buffer nothing has written
         out = jax.lax.empty((lhs.shape[0], columns), lhs.dtype)
-    tiles = _tiles(lhs.shape[0], lhs.shape[1], columns)
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiles, first, out, transpose_rhs=transpose_rhs)
+    return grouped.gmm(lhs, rhs, group_sizes, lhs.dtype, tiles, first, out, transpose_rhs=transpose_rhs)
 
 
 def grouped_matmul_weight_grad(lhs, grad, group_sizes, first, like):
     """The cotangent of ``grouped_matmul``'s ``rhs`` (shaped and typed ``like`` it):
     ``d_rhs[g] = lhs[rows of g].T @ grad[rows of g]`` for the groups ``first .. first +
-    len(like) - 1`` (all of them where ``first`` is None). megablox's ``tgmm`` on the TPU."""
-    if jax.default_backend() != "tpu":
+    len(like) - 1`` (all of them where ``first`` is None). The kernel ``ds_tgmm`` on the TPU."""
+    tiles = _tiles(lhs.shape[0], *like.shape[1:]) if jax.default_backend() == "tpu" else None
+    _count_product("tgmm", like.shape[1:], tiles)
+    if tiles is None:
         padded, sizes = _ragged_sizes(like, group_sizes, first)
         d_rhs, = jax.linear_transpose(
             lambda r: jax.lax.ragged_dot(lhs, r, sizes, preferred_element_type=grad.dtype),
             padded)(grad)
         return d_rhs if first is None else d_rhs[1:]
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
-    return tgmm(lhs.swapaxes(0, 1), grad, group_sizes, like.dtype,
-                _tiles(lhs.shape[0], *like.shape[1:]), first, like.shape[0])
+    return grouped.tgmm(lhs, grad, group_sizes, like.dtype, tiles, first, like.shape[0])
 
 
 @jax.custom_vjp

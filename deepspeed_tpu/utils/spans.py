@@ -175,6 +175,18 @@ class Recorder:
         with self._lock:
             return {name: v for (e, name), v in self._counters.items() if e == engine}
 
+    def _program_call(self):
+        """The innermost open span of this thread that is a step program's call, or None."""
+        return next((s for s in reversed(self._stack()) if s.attrs and "program" in s.attrs), None)
+
+    def count_in_program(self, name, what=""):
+        """Count ``<name>[<program>]<what>`` against the engine whose step program's call is open
+        on this thread: for what a program leaves WHILE IT IS TRACED (its Python runs inside the
+        call that builds it), once a trace; nothing outside such a call."""
+        owner = self._program_call()
+        if owner is not None:
+            self.count(owner.engine, f"{name}[{owner.attrs['program']}]{what}")
+
     # --------------------------------------------------------- device scalars
     def keep_device_scalars(self, engine, step, scalars):
         """Keep a step's device scalars (a dict of arrays the step program returned beside
@@ -205,7 +217,7 @@ class Recorder:
             span.end = now
             self._ring.append(span)
         if name == BUILD_SPAN:
-            owner = next((s for s in reversed(stack) if s.attrs and "program" in s.attrs), None)
+            owner = self._program_call()
             if owner is not None:
                 owner.attrs["builds"] = owner.attrs.get("builds", 0) + 1
                 self.count(owner.engine, f"program.builds[{owner.attrs['program']}]")

@@ -1,14 +1,14 @@
-"""The megablox grouped products alone on the chip, tile by tile: device milliseconds a call
-of ``gmm`` and ``tgmm`` at the benchmark's call shapes, read from a profiler trace, beside
-what the products need and what the tiles issue.
+"""The grouped products alone on the chip, tile by tile: device milliseconds a call of
+``ds_gmm`` and ``ds_tgmm`` (``ops/pallas/grouped_matmul.py``) at the benchmark's call shapes,
+read from a profiler trace, beside what the products need and what the tiles issue.
 
     python tests/perf/gmm_sweep.py [--cells mellum2,nemotronh,olmoe,qwen3next,glm47flash,lfm2] [--shapes 2304x1792]
-                                   [--grid near|full] [--tm 256,1024] [--seed 0] [--check]
+                                   [--grid near|full|picked] [--tm 256,1024] [--vmem 64] [--seed 0] [--check]
                                    [--out chiprun_out/gmm_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent unpacked
-under ``_parent/``) it marks what THAT tree's ``parallel/moe._tiles`` picks (give ``--out`` an
-absolute path there):
+under ``_parent/``) it measures THAT tree's kernels (megablox's, in a tree from before PR 55) and
+marks what its ``parallel/moe._tiles`` picks (give ``--out`` an absolute path there):
 
     (cd _parent && python ../tests/perf/gmm_sweep.py --out /root/repo/chiprun_out/parent.jsonl)
 
@@ -27,14 +27,20 @@ held experts where they stand in. A held range that follows the router (Qwen3-Ne
 pass of 8,192 rows of which ``even`` fills what the ledger's ``moe_rows_here_share`` does.
 
 A candidate is ``(tm, tk, tn)``. Tiles for K and for N are the multiples of 128 from 512 to
-1,152 under the width, and the whole width, where the blocks fit 16 MiB (``block_bytes``).
-``--grid near`` (the default) takes the pairs that issue at most 1.05 times the work the
-widths need, ``(512, 1024, 1024)`` clipped and what this tree's ``_tiles`` picks;
-``--grid full`` every pair. ``--tm`` adds this tree's pick at other row tiles. A line holds ``ms`` (the kernels' device time a call, all pieces),
+1,152 under the width, the halves and thirds of the width that are multiples of 128, and the
+whole width, where the blocks fit ``--vmem`` MiB (``block_bytes``; 64 by default, well past what
+``_tiles`` picks from, so that the rule is read from both sides; 16 in a tree before PR 55, all
+megablox's call has). ``--grid near`` (the default) takes a
+whole K and what ``(512, 1024, 1024)`` bounded it to until PR 55, beside the N tiles that issue
+at most 1.05 times the work the widths need, ``(512, 1024, 1024)`` clipped and what this tree's
+``_tiles`` picks; ``--grid full`` every pair; ``--grid picked`` this tree's pick alone (a tree's
+kernels read again in a few minutes). ``--tm`` adds this tree's pick at other row tiles.
+A line holds ``ms`` (the kernels' device time a call, all pieces),
 ``tflops`` of the NEEDED operations (2 x rows in the call's groups x K x N),
 ``issued_over_needed`` by the width tiles and ``row_tiles_over_even`` by the group
-boundaries, ``vmem_bytes``, ``picked`` (this tree's ``_tiles``) and ``clipped``. ``--check``
-adds the relative error against a per-expert float32 loop on the same values.
+boundaries, ``vmem_bytes``, ``first_call_s`` (the candidate's first call: its compile),
+``picked`` (this tree's ``_tiles``) and ``clipped``. ``--check`` adds the relative error against
+a per-expert float32 loop on the same values.
 """
 
 import argparse
@@ -47,6 +53,7 @@ import re
 import shutil
 import sys
 import tempfile
+import time
 
 import numpy as np
 
@@ -56,12 +63,16 @@ import jax.numpy as jnp
 sys.path.insert(0, ".")
 from benchmarks.manifest import Manifest  # noqa: E402
 from deepspeed_tpu.parallel import moe  # noqa: E402
+try:
+    from deepspeed_tpu.ops.pallas import grouped_matmul as grouped  # noqa: E402
+except ImportError:          # a tree from before PR 55: megablox's kernels under 16 MiB
+    grouped = None
 
 CELLS = {"mellum2": "mellum2_ep4_d4_train_1chip", "nemotronh": "nemotronh_ep16_d9_train_1chip",
          "olmoe": "olmoe_d4_train_4chip", "qwen3next": "qwen3next_ep16_train_1chip",
          "glm47flash": "glm47flash_ep8_d5_train_1chip", "lfm2": "lfm2_ep8_d7_train_1chip"}
 CLIPPED = (512, 1024, 1024)          # what ``_tiles`` clipped with ``min`` until PR 47
-VMEM = 16 * 2 ** 20                  # a kernel's scoped VMEM on a v5e; megablox's call sets no other
+VMEM = (64 if grouped else 16) * 2 ** 20    # what a candidate's blocks may take (megablox's calls: a kernel's 16 MiB)
 NEAR = 1.05                          # ``--grid near``: the width tiles that issue at most this over the need
 ROWS_HERE = {"qwen3next": 0.0615}    # ledger, PR 46: ``moe_rows_here_share`` of a held range alone
 
@@ -92,9 +103,12 @@ def expert_calls(manifest, key):
 
 
 def block_bytes(call, tiles, itemsize=2):
-    """The VMEM a call's blocks take: two buffers an operand and an output (and the existing
-    output where the experts come in pieces), and the float32 accumulator: ``[tm, tn]`` in
-    ``gmm``, ``[tk, tn]`` in ``tgmm``, whose operands are the two row blocks."""
+    """The VMEM a call's blocks take, as the kernels reckon it (an existing output, where the
+    experts come in pieces, is one more block). In a tree before PR 55, megablox's: it keeps
+    its float32 accumulator ``[tm, tn]`` at a whole K too."""
+    if grouped is not None:
+        return (grouped.tgmm_block_bytes(tiles, itemsize) if call.kind == "tgmm"
+                else grouped.gmm_block_bytes(tiles, call.K, itemsize, call.pieces > 1))
     tm, tk, tn = tiles
     if call.kind == "tgmm":
         return 2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
@@ -112,20 +126,28 @@ def clipped(call):
     return tuple(min(t, d) for t, d in zip(CLIPPED, (call.rows, call.K, call.N)))
 
 
+def clipped_rule(call):
+    """What ``_tiles`` picked under ``(512, 1024, 1024)`` from PR 47 to PR 54: the whole width
+    up to 1,024, else the multiple of 128 from 512 to 1,024 that pads it least, the larger of two."""
+    width = lambda w: w if w <= 1024 else min(range(1024, 511, -128), key=lambda t: -(-w // t) * t)     # noqa: E731
+    return min(512, call.rows), width(call.K), width(call.N)
+
+
 def width_tiles(width):
-    return [t for t in range(512, 1152 + 1, 128) if t < width] + [width]
+    parts = [width // d for d in (2, 3) if width % (128 * d) == 0 and width // d > 1152]
+    return [t for t in range(512, 1152 + 1, 128) if t < width] + sorted(parts) + [width]
 
 
-def candidates(call, grid, row_tiles):
-    fits = lambda t: block_bytes(call, t) <= VMEM      # noqa: E731
+def candidates(call, grid, row_tiles, vmem=VMEM):
+    fits = lambda t: block_bytes(call, t) <= vmem      # noqa: E731
+    picked = moe._tiles(call.rows, call.K, call.N)
+    more = [picked, *((tm,) + picked[1:] for tm in row_tiles if call.rows % tm == 0)]
+    if grid == "picked":
+        return more
     out = [(512, tk, tn) for tk in width_tiles(call.K) for tn in width_tiles(call.N) if fits((512, tk, tn))]
     if grid == "near":
-        out = [t for t in out if issued_over_needed(t, call.K, call.N) <= NEAR]
-    picked = moe._tiles(call.rows, call.K, call.N)
-    for tiles in (clipped(call), picked, *((tm,) + picked[1:] for tm in row_tiles if call.rows % tm == 0)):
-        if tiles not in out and fits(tiles):
-            out.append(tiles)
-    return out
+        out = [t for t in out if t[1] in (call.K, clipped_rule(call)[1]) and issued_over_needed(t, call.K, call.N) <= NEAR]
+    return out + [tiles for tiles in dict.fromkeys((clipped(call), *more)) if tiles not in out and fits(tiles)]
 
 
 def group_sizes(call, model, how, rng):
@@ -158,14 +180,17 @@ def row_tiles_visited(sizes, tm):
 def kernel(call, tiles):
     """``fn(lhs, other, sizes) -> out``: the call as ``parallel/moe.py`` makes it on the TPU,
     every piece in turn, with ``tiles`` in place of ``_tiles``'."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    if grouped is None:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm as rows_last
+        tgmm = lambda lhs, *rest: rows_last(lhs.swapaxes(0, 1), *rest)       # noqa: E731
+    else:
+        gmm, tgmm = grouped.gmm, grouped.tgmm
     per = call.groups // call.pieces
     firsts = [None] if call.pieces == 1 else [jnp.int32(i * per) for i in range(call.pieces)]
 
     def fn(lhs, other, sizes):
         if call.kind == "tgmm":
-            return tuple(tgmm(lhs.swapaxes(0, 1), other, sizes, lhs.dtype, tiles, first, per)
-                         for first in firsts)
+            return tuple(tgmm(lhs, other, sizes, lhs.dtype, tiles, first, per) for first in firsts)
         out = None
         for i, first in enumerate(firsts):
             if first is not None and out is None:
@@ -203,7 +228,7 @@ def rel(got, want, rows):
 
 
 def kernel_events(trace_dir):
-    """The device durations (ms) of the megablox kernels in the trace, in time order."""
+    """The device durations (ms) of the grouped products' kernels in the trace, in time order."""
     from jax.profiler import ProfileData
     path = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     found, others = [], collections.Counter()
@@ -214,7 +239,7 @@ def kernel_events(trace_dir):
             if line.name != "XLA Ops":
                 continue
             for e in line.events:
-                if re.match(r"%?t?gmm\b", e.name.lstrip()):
+                if re.match(r"%?(?:ds_)?t?gmm\b", e.name.lstrip()):
                     found.append((e.start_ns, e.duration_ns * 1e-6))
                 else:
                     others[re.sub(r"[.\d]+$", "", e.name.split(" ")[0])] += 1
@@ -242,7 +267,9 @@ def measure(call, model, tiles_of, seed, check, calls=3):
         try:
             fn = jax.jit(kernel(call, tiles))
             for how, sizes in ways.items():
+                start = time.perf_counter()
                 got = jax.block_until_ready(fn(lhs, other, on_chip[how]))
+                line.setdefault("first_call_s", time.perf_counter() - start)
                 if check:
                     line.setdefault("rel", {})[how] = rel(
                         got, want[how], None if call.kind == "tgmm" else int(sizes.sum()))
@@ -262,7 +289,7 @@ def measure(call, model, tiles_of, seed, check, calls=3):
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
     if len(events) != len(compiled) * len(ways) * calls * call.pieces:
-        raise RuntimeError(f"{len(events)} megablox kernels in the trace where {len(compiled)} candidates x "
+        raise RuntimeError(f"{len(events)} grouped-product kernels in the trace where {len(compiled)} candidates x "
                            f"{len(ways)} x {calls} calls x {call.pieces} pieces ran; it holds {others.most_common(8)}")
     each = iter(events)
     for line, _ in compiled:
@@ -280,8 +307,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--shapes", default="", help="only the calls whose kind:KxN holds this, e.g. tgmm:2304x1792")
-    ap.add_argument("--grid", default="near", choices=("near", "full"))
+    ap.add_argument("--grid", default="near", choices=("near", "full", "picked"))
     ap.add_argument("--tm", default="", help="further row tiles for this tree's pick, e.g. 256,1024")
+    ap.add_argument("--vmem", type=float, default=VMEM / 2 ** 20, help="MiB of blocks a candidate may take")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--out", default="chiprun_out/gmm_sweep.jsonl")
@@ -298,7 +326,7 @@ def main():
                 if opts.shapes not in f"{call.kind}:{call.K}x{call.N}":
                     continue
                 try:
-                    lines = measure(call, model, candidates(call, opts.grid, row_tiles), opts.seed, opts.check)
+                    lines = measure(call, model, candidates(call, opts.grid, row_tiles, opts.vmem * 2 ** 20), opts.seed, opts.check)
                 except RuntimeError as e:       # a trace that does not hold the calls: say so, go on
                     print(f"{key} {call.kind} {call.K} -> {call.N}: {e}", flush=True)
                     continue
@@ -309,7 +337,7 @@ def main():
                     mark = ("*" if line["picked"] else " ") + ("c" if line["clipped"] else " ")
                     head = (f"{key:9s} {call.kind:5s} {call.rows:6d} x {call.K:4d} -> {call.N:4d} "
                             f"{str(tuple(line['tiles'])):18s}{mark} issued {line['issued_over_needed']:.3f} "
-                            f"vmem {line['vmem_bytes'] / 2 ** 20:5.1f} MiB")
+                            f"vmem {line['vmem_bytes'] / 2 ** 20:5.1f} MiB first {line.get('first_call_s', 0):4.1f} s")
                     if "error" in line:
                         print(head, line["error"][:160], flush=True)
                         continue

@@ -667,6 +667,11 @@ def test_initialize_takes_the_model_as_it_takes_gpt2():
     engine.backward(loss)
     engine.step()
     assert np.isfinite(float(loss))
+    # every grouped product left in the recorder how it runs, while the gradient program was traced:
+    # off the TPU as ``lax.ragged_dot`` (on the chip ``.whole_k`` or ``.cut_k`` and its tiles)
+    products = {name: n for name, n in spans.recorder().counters(engine._span_engine).items() if name.startswith("moe.")}
+    assert products and all(".ragged_dot[loss_and_grad] " in name for name in products)
+    assert {name.split(".")[1] for name in products} == {"gmm", "gmm_t", "tgmm"}
 
 
 HELD = dict(E=16, H=64, F=32, k=3, first=4, count=4)

@@ -25,6 +25,7 @@ from deepspeed_tpu.ops.pallas.block_sparse_attention import block_sparse_attenti
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
 from deepspeed_tpu.ops.sparse_attention import BSLongformerSparsityConfig
+from deepspeed_tpu.utils import spans
 
 
 @pytest.fixture(scope="module")
@@ -264,7 +265,12 @@ def expert_cells_gradient_program(chip, monkeypatch, build_model, config):
     params = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
     tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
-    compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
+    # traced as the engine traces it, inside a step program's call: every grouped product leaves
+    # in the recorder how it runs (``moe._count_product``)
+    with spans.recorder().span("train.grad_program", engine=spans.recorder().new_engine(), program="loss_and_grad") as call:
+        compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
+    products = {name: n for name, n in spans.recorder().counters(call.engine).items() if name.startswith("moe.")}
+    assert products and all(".whole_k[loss_and_grad] " in name for name in products), products     # no contraction is cut
     return compiled, model, shapes
 
 
@@ -273,7 +279,7 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     nine layers MEMEM*EME with 8 of 128 experts held, 1 x 8,192 positions, whole layers
     recomputed, the untied head's cross-entropy over 16,384 words. The grouped scan, the
     convolution over 6,144 channels, the flash kernel at sixteen query heads a key/value head
-    and the megablox products over experts 1,856 wide (no multiple of 128) are all in it, the
+    and the grouped products over experts 1,856 wide (no multiple of 128) are all in it, the
     flash forward ONCE (a layer keeps the kernel's output by name since PR 41), and what it needs
     beside its parameters and their gradients stays under the 5.3 GB that 10.67 GB of training
     state leave on the chip."""
@@ -282,7 +288,7 @@ def test_the_state_space_expert_cells_gradient_program_compiles_for_v5e(chip, mo
     assert model.config.kinds == "MEMEM*EME" and model.config.remat
     assert sum(s.size for s in jax.tree_util.tree_leaves(shapes)) == 666_963_456
     text = compiled.as_text()
-    for kernel in ("ds_ssd_scan_fwd", "ds_ssd_scan_bwd", "ds_causal_conv_fwd", "ds_flash_fwd", "gmm"):
+    for kernel in ("ds_ssd_scan_fwd", "ds_ssd_scan_bwd", "ds_causal_conv_fwd", "ds_flash_fwd", "ds_gmm", "ds_tgmm"):
         assert kernel in text, kernel
     assert_the_flash_forward_runs_once(text)
     # 3.08 GB as compiled here since the router's weights go to the rows before ``w_down`` and a
@@ -330,6 +336,100 @@ def test_the_expert_cells_combine_kernel_compiles_for_v5e(chip, k, G, H, dtype):
     assert " while(" not in text and " gather(" not in text
 
 
+def _grouped_products():
+    """Every grouped product the expert cells make, once a shape, by the cells that make it and
+    its kind: ``tests/perf/gmm_sweep.py: expert_calls`` reads them from the cells' files
+    (arithmetic on JSON: nothing is described or compiled while this module is imported)."""
+    import importlib.util
+    from benchmarks.manifest import Manifest
+    spec = importlib.util.spec_from_file_location("gmm_sweep", os.path.join(
+        os.path.dirname(__file__), "..", "perf", "gmm_sweep.py"))
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    cells_of = collections.defaultdict(list)
+    for key in sweep.CELLS:
+        for call in sweep.expert_calls(Manifest(), key):
+            cells_of[call[1:]].append(key)
+    cases = collections.defaultdict(list)
+    for shape, cells in cells_of.items():
+        cases["+".join(cells), shape[0]].append(sweep.Call(cells[0], *shape))
+    return [pytest.param(calls, id=f"{cells}-{kind}") for (cells, kind), calls in cases.items()]
+
+
+@pytest.mark.parametrize("calls", _grouped_products())
+def test_the_expert_cells_grouped_products_compile_for_v5e_at_the_tiles_picked(chip, calls):
+    """``ops/pallas/grouped_matmul.py`` at every call shape of the six expert cells (two of a kind
+    a cell), at the tiles ``parallel/moe._tiles`` picks and under the ``vmem_limit_bytes`` the
+    kernel reckons from its blocks: a block set the chip's compiler refuses (fast memory, a slice
+    off the tiling) fails here. Of a chain of pieces the call that writes into the buffer before it."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as grouped
+    from deepspeed_tpu.parallel import moe
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
+    assert len(calls) == 2
+    for call in calls:
+        tiles = moe._tiles(call.rows, call.K, call.N)
+        held, sizes = call.groups // call.pieces, shape(call.groups, dt=jnp.int32)
+        first = jnp.int32(held) if call.pieces > 1 else None
+        if call.kind == "tgmm":
+            text = compiled_text(lambda lhs, grad, sizes: grouped.tgmm(lhs, grad, sizes, lhs.dtype, tiles, first, held),
+                                 shape(call.rows, call.K), shape(call.rows, call.N), sizes)
+        else:
+            weights = shape(held, call.N, call.K) if call.kind == "gmm_t" else shape(held, call.K, call.N)
+            out = (shape(call.rows, call.N),) if call.pieces > 1 else ()
+            text = compiled_text(lambda lhs, rhs, sizes, *out: grouped.gmm(
+                lhs, rhs, sizes, lhs.dtype, tiles, first, *(out or (None,)), transpose_rhs=call.kind == "gmm_t"),
+                shape(call.rows, call.K), weights, sizes, *out)
+        assert ("ds_tgmm" if call.kind == "tgmm" else "ds_gmm") in text, (call, tiles)
+        assert len(re.findall(r"custom-call\(.*tpu_custom_call", text)) == 1, (call, tiles)
+
+
+def assert_the_grouped_products_stay_in_hbm(text, products):
+    """In a compiled program: ``products`` grouped products, none with an operand past its five
+    scalars or a result that the compiler laid out in fast memory (``S(1)``)."""
+    calls = [line for line in text.splitlines() if re.search(r"%ds_t?gmm\S* = ", line) and "custom-call(" in line]
+    assert len(calls) == products, (len(calls), products)
+    layouts = dict(re.findall(r"%(\S+) = (\w+\[[0-9,]*\]\S*) ", text))
+    for line in calls:
+        result, operands = re.search(r"= (\S+) custom-call\(([^)]*)\)", line).groups()
+        big = [layouts[name] for name in re.findall(r"%([^\s,)]+)", operands)[5:]]
+        assert "S(1)" not in result and not any("S(1)" in layout for layout in big), line[:300]
+
+
+@pytest.mark.parametrize("runner, config, handed, products", [
+    ("train_ssm_moe", "nemotron-twotower-30b-a3b-ep16-d9", ("moe", "shared"), 5),
+    ("train_hybrid", "qwen3-next-80b-a3b-ep16-d4", ("moe", "shared"), 6),         # a held range: its pass makes the first product again
+    ("train_swa_moe", "mellum2-12b-a2.5b-ep4-d4", ("moe",), 5), ("train_mla_moe", "glm-4.7-flash-ep8-d5", ("moe", "shared"), 5),
+    ("train_conv_moe", "lfm2-24b-a2b-ep8-d7", None, 5)], ids=["nemotronh", "qwen3next", "mellum2", "glm47flash", "lfm2"])
+def test_a_small_programs_grouped_products_stay_in_hbm_on_a_v5e(chip, monkeypatch, runner, config, handed, products):
+    """A one-chip expert cell's SET-UP reads its expert layer ALONE against the reference, with
+    float32 parameters (``compare_layers`` of its runner, through ``train_hybrid.Alone``; ``handed``
+    the part of a layer's parameters it hands the layer): the gradients of ``sum(y * cot)`` on the
+    last 1,024 positions (the first product, the two row cotangents, the two weight cotangents:
+    nothing reads the second product's value) and the output on all 8,192. Programs small enough
+    for the compiler to lay a kernel's operands and outputs out in fast memory (``S(1)``), an
+    expert array's 80 MB among them. Beside the scoped region the grouped products ask for,
+    Nemotron-H's gradients never ended on the chip (PERF.md, PR 55), so their operands and outputs
+    are held to HBM (``grouped_matmul._in_hbm``); the combine's kernel, under 18 MB of scoped
+    memory, keeps the compiler's choice."""
+    import importlib
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners.train_hybrid import Alone
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = Manifest().config(config)
+    model = importlib.import_module("benchmarks.runners." + runner).build_model(config)
+    layer = next(lp for lp in jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"] if "moe" in lp)
+    params = jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32, sharding=chip),
+                                    {name: layer[name] for name in handed} if handed else layer["moe"])
+    x = lambda t, dt: jax.ShapeDtypeStruct((1, t, model.config.hidden_size), dt, sharding=chip)      # noqa: E731
+    system = lambda p, x: model.expert_layer(x, p)[0]      # noqa: E731
+    alone = Alone(system, system)
+    rows = config["reference"]["grad_positions"]
+    assert rows == 1024
+    gradients = alone.grads[0].lower(params, x(rows, jnp.bfloat16), x(rows, jnp.float32)).compile().as_text()
+    assert_the_grouped_products_stay_in_hbm(gradients, products)
+    assert_the_grouped_products_stay_in_hbm(alone.fns[0].lower(params, x(8192, jnp.bfloat16)).compile().as_text(), 2)
+
+
 def assert_the_combine_reads_its_rows_in_runs(text, rows, tokens, layers):
     """In an expert cell's whole gradient program: ``ds_moe_rows_sum`` twice an expert layer (the
     forward's combine and the dispatch's cotangent, neither made again by a recomputed layer), no
@@ -349,13 +449,13 @@ def test_the_sliding_window_expert_cells_gradient_program_compiles_for_v5e(chip,
     """The gradient program of ``mellum2_ep4_d4_train_1chip`` WHOLE (the published widths, three
     sliding-window layers and a full one, 16 of 64 experts held and standing in, 1 x 8,192
     positions, whole layers recomputed but for ``mellum.KEPT_BY_A_LAYER``): the banded flash kernel
-    and the megablox products are in it, the combine reads its 65,536 sorted rows in runs, and
+    and the grouped products are in it, the combine reads its 65,536 sorted rows in runs, and
     what it needs beside its parameters and their gradients is 1.81 GB as compiled here (2.19
     until the combine's ``[k n, H]`` intermediate went, PR 53; 3.13 before PR 49)."""
     from benchmarks.runners.train_swa_moe import build_model
     compiled, _, _ = expert_cells_gradient_program(chip, monkeypatch, build_model, "mellum2-12b-a2.5b-ep4-d4")
     text = compiled.as_text()
-    for kernel in ("ds_flash_fwd", "ds_flash_bwd_dkv", "gmm"):
+    for kernel in ("ds_flash_fwd", "ds_flash_bwd_dkv", "ds_gmm", "ds_tgmm"):
         assert kernel in text, kernel
     assert compiled.memory_analysis().temp_size_in_bytes < 1.81e9 * 1.05
     assert_the_combine_reads_its_rows_in_runs(text, "65536,2304", "8192,2304", layers=4)
@@ -410,7 +510,7 @@ def test_a_looped_models_block_passes_keep_each_named_tensor_once_on_a_v5e(chip,
 
 def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
     """Qwen3-Next's expert layer at its published widths as one chip of sixteen holds it: a
-    router over 512, 32 experts of 512 held, 8,192 tokens; the megablox grouped matmul inside
+    router over 512, 32 experts of 512 held, 8,192 tokens; the grouped products' kernels inside
     the passes' ``cond``, forward and the hand-written backward."""
     from deepspeed_tpu.parallel.moe import DroplessMoE
     layer = DroplessMoE(2048, 512, 512, 10, norm_topk_prob=True, held=(0, 32))
@@ -434,13 +534,14 @@ def test_the_held_range_expert_layer_compiles_for_v5e(chip, monkeypatch):
 def test_the_stand_in_experts_products_compile_for_v5e_at_widths_the_tiles_divide(chip, monkeypatch):
     """Mellum 2's expert layer between its gathers, at its published widths as one chip of four
     holds it: 16 gated experts of 896 standing in for 64, 8,192 tokens of 8 experts each, value
-    and gradient. Six megablox products over 65,536 rows at 2,304, 1,792 and 896, none of which
-    1,024 divides, at the tiles ``parallel/moe._tiles`` picks (768 and 896 wide). The layer's
+    and gradient. Six grouped products (``ops/pallas/grouped_matmul.py``) over 65,536 rows at
+    2,304, 1,792 and 896, none of which 1,024 divides, at the tiles ``parallel/moe._tiles`` picks
+    (every contraction and every width whole since PR 55; 768 and 896 wide under megablox). The layer's
     router and its two sorts of 65,536 keys are Nemotron-H's, in the whole program above (a
     sort alone compiles in 8 s and more)."""
     from deepspeed_tpu.parallel import moe
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the grouped matmul's kernel
-    assert moe._tiles(65536, 2304, 1792) == (512, 768, 896) and moe._tiles(65536, 896, 2304) == (512, 896, 768)
+    assert moe._tiles(65536, 2304, 1792) == (512, 2304, 1792) and moe._tiles(65536, 896, 2304) == (512, 896, 2304)
 
     def loss(xs, w_gate_up, w_down, sizes):
         gate_up = moe.experts_matmul(xs, (w_gate_up,), (None,), sizes)
@@ -521,7 +622,7 @@ def expert_layer_on_four_chips(topo, monkeypatch):
 
 
 def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
-    """The megablox grouped matmul inside the layer's own ``shard_map``, the experts'
+    """The grouped products inside the layer's own ``shard_map``, the experts'
     weights fetched chip to chip over ``data`` and their gradients sent back to the owners."""
     mesh, layer, params, x = expert_layer_on_four_chips(topo, monkeypatch)
 
@@ -532,6 +633,39 @@ def test_the_expert_layer_compiles_under_a_four_chip_mesh(topo, monkeypatch):
     with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         text = compiled_text(jax.grad(loss), params, x)
     assert "tpu_custom_call" in text and "collective-permute-start" in text
+
+
+def test_the_four_chip_cells_small_programs_grouped_products_stay_in_hbm_on_a_v5e(topo, monkeypatch):
+    """``olmoe_d4_train_4chip``'s SET-UP reads every expert layer ALONE under the mesh, a copy of the
+    sequence a chip, with the engine's float32 parameters (``train_moe.system_layer_fn``: the same
+    two programs): the output on 4,096 positions and the gradients of ``sum(y * cot)`` on the last
+    ``GRAD_ROWS``, the experts crossing the chips in four pieces chained through one buffer. No
+    grouped product's operand or result, the aliased buffer among them, lies in fast memory."""
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners import train_moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    manifest = Manifest()
+    cell = manifest.cell("olmoe_d4_train_4chip")
+    model = train_moe.build_model(manifest.config(cell["config"]))
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1), ("pipe", "data", "model"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][0]["moe"]
+    params = {name: jax.ShapeDtypeStruct(shapes[name].shape, jnp.float32, sharding=sharding)
+              for name, sharding in model.engine_shardings(mesh)["layers"][0]["moe"].items()}
+    x = lambda t, dt: jax.ShapeDtypeStruct((4, t, model.config.hidden_size), dt,      # noqa: E731
+                                           sharding=NamedSharding(mesh, P("data")))
+
+    def fwd(mp, x):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return model.moe.apply(mp, x, details=True)[::2]
+
+    def grads(mp, x, cot):
+        scalar = lambda x, *w: jnp.sum(fwd(dict(zip(train_moe.WEIGHTS, w)), x)[0].astype(jnp.float32) * cot)     # noqa: E731
+        return jax.grad(scalar, argnums=(0, 1, 2, 3))(x, *(mp[n] for n in train_moe.WEIGHTS))
+
+    seq_len = manifest.traffic(cell["traffic"])["seq_len"]
+    assert_the_grouped_products_stay_in_hbm(compiled_text(fwd, params, x(seq_len, jnp.bfloat16)), 2 * 4)
+    rows = train_moe.GRAD_ROWS
+    assert_the_grouped_products_stay_in_hbm(compiled_text(grads, params, x(rows, jnp.bfloat16), x(rows, jnp.float32)), 5 * 4)
 
 
 SHAPE_RE = re.compile(r"(pred|[a-z]+\d+)\[([\d,]*)\]")
