@@ -34,17 +34,6 @@ class GPT2Config:
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
     use_flash_attention: bool = False
-    # Fused Pallas transformer-block kernel (ops/pallas/fused_block.py): the
-    # whole attention half — LN + fused qkv + causal attention + output
-    # projection + residual — runs as ONE kernel, so none of the block's
-    # intermediate [B, T, E] tensors round-trip through HBM (an HBM-bound
-    # path). Takes precedence over use_flash_attention when eligible;
-    # requires dropout == 0 and no
-    # sparse_attention, and falls back to the unfused path under manual TP /
-    # sequence parallelism (the kernel is single-chip, whole-row K/V). Interpreter
-    # only so far: the chip's compiler refuses it at model widths (VMEM, see the
-    # kernel's docstring).
-    fused_block: bool = False
     remat: bool = False            # activation checkpointing over blocks
     remat_policy: Any = None       # None=full recompute; "dots"=save matmul outputs
     # 0 = materialize the full [B, T, vocab] logits; any other value = do not (the fused
@@ -121,11 +110,6 @@ class GPT2Model:
         if config.sparse_attention is not None:
             assert config.dropout == 0.0, \
                 "sparse_attention has no in-kernel dropout; set dropout=0"
-        if config.fused_block:
-            assert config.dropout == 0.0, \
-                "fused_block has no in-kernel dropout; set dropout=0"
-            assert config.sparse_attention is None, \
-                "fused_block and sparse_attention are mutually exclusive"
         self._moe = None
         if config.moe_experts > 0:
             assert config.moe_every >= 1, \
@@ -420,24 +404,12 @@ class GPT2Model:
         # program, no instruction: the device table reads a step's parts from them.
         # A layer norm falls under the part it feeds.
         with jax.named_scope("ds_attn"):
-            if (c.fused_block and self.tp_axis is None and self.seq_axis is None
-                    and k_attn is None):
-                # whole attention half (LN + qkv + attention + proj + residual) in
-                # one Pallas kernel; the parallel model copies fall through to the
-                # unfused path (the kernel needs the full row on one chip)
-                from ..ops.pallas.fused_block import fused_transformer_block
-                ap = bp["attn"]
-                x = fused_transformer_block(
-                    x, bp["ln_1"]["scale"], bp["ln_1"]["bias"],
-                    ap["c_attn_w"], ap["c_attn_b"], ap["c_proj_w"], ap["c_proj_b"],
-                    c.n_head, causal=True, eps=c.layer_norm_epsilon)
-            else:
-                a = self._attention(
-                    self._layer_norm(x, bp["ln_1"], c.layer_norm_epsilon),
-                    bp["attn"], dropout_rng=k_attn)
-                if k_res1 is not None:
-                    a = self._dropout(a, k_res1)
-                x = x + a
+            a = self._attention(
+                self._layer_norm(x, bp["ln_1"], c.layer_norm_epsilon),
+                bp["attn"], dropout_rng=k_attn)
+            if k_res1 is not None:
+                a = self._dropout(a, k_res1)
+            x = x + a
         with jax.named_scope("ds_mlp"):
             h = self._layer_norm(x, bp["ln_2"], c.layer_norm_epsilon)
             if "moe" in bp:

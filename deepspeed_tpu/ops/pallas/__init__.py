@@ -1,2 +1,1 @@
 from .flash_attention import flash_attention, dense_attention
-from .fused_block import fused_transformer_block, fused_block_reference

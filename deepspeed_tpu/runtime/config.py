@@ -362,70 +362,6 @@ class DeepSpeedConfig:
                 "DeepSpeedConfig: telemetry.hbm.enabled must be a bool, got "
                 f"{self.telemetry_hbm_enabled!r}")
 
-        met_dict = tel_dict.get(TELEMETRY_METRICS, {}) or {}
-        self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_METRICS}",
-                                  met_dict, METRICS_CONFIG_KEYS)
-        self.telemetry_metrics_enabled = get_scalar_param(
-            met_dict, METRICS_ENABLED, METRICS_ENABLED_DEFAULT)
-        if not isinstance(self.telemetry_metrics_enabled, bool):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.metrics.enabled must be a bool, "
-                f"got {self.telemetry_metrics_enabled!r}")
-        if self.telemetry_metrics_enabled and not self.telemetry_enabled:
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.metrics.enabled requires "
-                "telemetry.enabled — the catalog router rides the "
-                "SummaryMonitor the telemetry session owns")
-        self.telemetry_metrics_ring_len = get_scalar_param(
-            met_dict, METRICS_RING_LEN, METRICS_RING_LEN_DEFAULT)
-        rl = self.telemetry_metrics_ring_len
-        if isinstance(rl, bool) or not isinstance(rl, int) or rl < 1:
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.metrics.ring_len must be an "
-                f"int >= 1, got {rl!r}")
-        self.telemetry_metrics_strict_catalog = get_scalar_param(
-            met_dict, METRICS_STRICT_CATALOG, METRICS_STRICT_CATALOG_DEFAULT)
-        if not isinstance(self.telemetry_metrics_strict_catalog, bool):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.metrics.strict_catalog must be a "
-                f"bool, got {self.telemetry_metrics_strict_catalog!r}")
-        self.telemetry_metrics_export_path = get_scalar_param(
-            met_dict, METRICS_EXPORT_PATH, METRICS_EXPORT_PATH_DEFAULT)
-        if not isinstance(self.telemetry_metrics_export_path, str):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.metrics.export_path must be a "
-                f"string, got {self.telemetry_metrics_export_path!r}")
-
-        al_dict = tel_dict.get(TELEMETRY_ALERTS, {}) or {}
-        self._warn_unknown_nested(f"{TELEMETRY}.{TELEMETRY_ALERTS}",
-                                  al_dict, ALERTS_CONFIG_KEYS)
-        self.telemetry_alerts_enabled = get_scalar_param(
-            al_dict, ALERTS_ENABLED, ALERTS_ENABLED_DEFAULT)
-        if not isinstance(self.telemetry_alerts_enabled, bool):
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.alerts.enabled must be a bool, "
-                f"got {self.telemetry_alerts_enabled!r}")
-        if self.telemetry_alerts_enabled and not self.telemetry_enabled:
-            raise ValueError(
-                "DeepSpeedConfig: telemetry.alerts.enabled requires "
-                "telemetry.enabled — the rules evaluate on the end_step "
-                "boundary the telemetry session drives")
-        rules = get_scalar_param(al_dict, ALERTS_RULES, ALERTS_RULES_DEFAULT)
-        if rules is not None:
-            if not isinstance(rules, (list, tuple)):
-                raise ValueError(
-                    "DeepSpeedConfig: telemetry.alerts.rules must be a list "
-                    f"of rule dicts (or null for the default ruleset), got "
-                    f"{rules!r}")
-            from ..utils.alerts import validate_rules
-            from ..utils.metrics import default_catalog
-            try:
-                rules = validate_rules(list(rules), default_catalog())
-            except ValueError as e:
-                raise ValueError(
-                    f"DeepSpeedConfig: telemetry.alerts.rules: {e}")
-        self.telemetry_alerts_rules = rules
-
         num_dict = param_dict.get(NUMERICS, {})
         self._warn_unknown_nested(NUMERICS, num_dict, NUMERICS_CONFIG_KEYS)
         self.numerics_enabled = get_scalar_param(num_dict, NUMERICS_ENABLED, NUMERICS_ENABLED_DEFAULT)

@@ -241,43 +241,6 @@ TELEMETRY_HBM = "hbm"
 HBM_ENABLED = "enabled"
 HBM_ENABLED_DEFAULT = False
 
-# telemetry.metrics sub-block: unified metric catalog + per-host time-series
-# ring — every scalar any observatory emits is resolved against the declared
-# catalog (utils/metrics.py: unit, direction, class, description; unknown
-# names warn-once, strict mode raises) and recorded into a bounded ring with
-# fixed geometry, exactly mergeable across hosts via the dump plane
-# (docs/metrics.md). Host-side only; the lowered step program is
-# HLO-instruction-identical with the block on or off.
-TELEMETRY_METRICS = "metrics"
-METRICS_ENABLED = "enabled"
-METRICS_ENABLED_DEFAULT = False
-# observations kept per metric (the ring's fixed geometry)
-METRICS_RING_LEN = "ring_len"
-METRICS_RING_LEN_DEFAULT = 512
-# strict catalog mode: a scalar emitted under an undeclared name raises
-# instead of warning once — the test drift guard
-METRICS_STRICT_CATALOG = "strict_catalog"
-METRICS_STRICT_CATALOG_DEFAULT = False
-# "" = no export; a path writes an OpenMetrics text exposition of the ring's
-# latest values when the telemetry session closes
-METRICS_EXPORT_PATH = "export_path"
-METRICS_EXPORT_PATH_DEFAULT = ""
-
-# telemetry.alerts sub-block: the alert plane — deterministic host-side rules
-# (threshold / delta / stuck / slo_burn) evaluated on the end_step boundary
-# against the metric ring; a firing rule emits an Alerts/* scalar, a
-# structured monitor event, and (severity "page") a flight-recorder dump
-# (docs/alerts.md). Zero new device syncs; the lowered step program is
-# HLO-instruction-identical with the block on or off.
-TELEMETRY_ALERTS = "alerts"
-ALERTS_ENABLED = "enabled"
-ALERTS_ENABLED_DEFAULT = False
-# None arms the shipped default ruleset (utils/alerts.default_rules: MFU
-# regression, fleet shed-rate SLO burn, loss-scale death spiral, dispatch
-# skew); a list of rule dicts replaces it (validated at config parse)
-ALERTS_RULES = "rules"
-ALERTS_RULES_DEFAULT = None
-
 #############################################
 # Numerics observatory (TPU-native health layer on top of telemetry; no
 # reference key — in-graph per-subtree anomaly sentinel, loss-scale event
@@ -610,8 +573,6 @@ TELEMETRY_CONFIG_KEYS = frozenset({
     TELEMETRY_CLUSTER,
     TELEMETRY_GOODPUT,
     TELEMETRY_HBM,
-    TELEMETRY_METRICS,
-    TELEMETRY_ALERTS,
 })
 
 PIPELINE_TRACE_CONFIG_KEYS = frozenset({
@@ -639,18 +600,6 @@ GOODPUT_CONFIG_KEYS = frozenset({
 
 HBM_CONFIG_KEYS = frozenset({
     HBM_ENABLED,
-})
-
-METRICS_CONFIG_KEYS = frozenset({
-    METRICS_ENABLED,
-    METRICS_RING_LEN,
-    METRICS_STRICT_CATALOG,
-    METRICS_EXPORT_PATH,
-})
-
-ALERTS_CONFIG_KEYS = frozenset({
-    ALERTS_ENABLED,
-    ALERTS_RULES,
 })
 
 NUMERICS_CONFIG_KEYS = frozenset({

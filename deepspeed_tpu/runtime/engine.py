@@ -531,22 +531,6 @@ class DeepSpeedEngine:
                 # _compile_steps so the step programs analyze against it
                 self.telemetry.set_comm_topology(
                     self._comm_topo.slice_device_sets(self.mesh))
-            # metric catalog router + alert plane (docs/metrics.md,
-            # docs/alerts.md): hooks the SummaryMonitor so EVERY observatory's
-            # scalars resolve against the declared catalog and land in the
-            # per-host ring; alert rules evaluate on the end_step boundary.
-            # Host bookkeeping only — the step programs stay
-            # HLO-instruction-identical with these blocks on (tested).
-            if self.config.telemetry_metrics_enabled \
-                    or self.config.telemetry_alerts_enabled:
-                self.telemetry.configure_metrics(
-                    ring_len=self.config.telemetry_metrics_ring_len,
-                    strict=self.config.telemetry_metrics_strict_catalog,
-                    export_path=(self.config.telemetry_metrics_export_path
-                                 or None))
-            if self.config.telemetry_alerts_enabled:
-                self.telemetry.configure_alerts(
-                    rules=self.config.telemetry_alerts_rules)
 
         # ---- numerics observatory (docs/numerics.md): in-graph sentinel,
         # loss-scale journal, cross-rank desync audit, flight recorder. Built
@@ -585,11 +569,6 @@ class DeepSpeedEngine:
                 audit_interval=self.config.numerics_audit_interval,
                 consecutive_skip_trigger=self.config.numerics_consecutive_skip_trigger,
                 trigger_on_nonfinite_loss=self.config.numerics_trigger_on_nonfinite_loss)
-            # page-severity alerts dump through the same flight recorder, so
-            # the post-mortem bundle carries the metric ring + alert state
-            if self.telemetry is not None \
-                    and self.telemetry.alert_engine is not None:
-                self.telemetry.alert_engine.recorder = recorder
 
         # ---- cluster observatory (docs/cluster.md): cross-host heartbeat
         # aggregation, straggler naming, hang watchdog. Entirely host-side —
@@ -621,11 +600,6 @@ class DeepSpeedEngine:
             # heartbeat history + clock offsets ride along in every dump so
             # cluster-dump / timeline --cluster can merge hosts coherently
             cluster_recorder.cluster = self._cluster
-            if self.telemetry.alert_engine is not None \
-                    and self.telemetry.alert_engine.recorder is None:
-                # no numerics recorder took the alert plane: page alerts dump
-                # through the cluster watchdog's recorder instead
-                self.telemetry.alert_engine.recorder = cluster_recorder
 
         # ---- run-lifecycle goodput ledger (docs/goodput.md): classifies the
         # run's entire wall-clock into a closed badput taxonomy (init, compile,

@@ -1,7 +1,8 @@
 """TPU-native serving engine: block-paged KV cache + continuous batching.
 
 The reference DeepSpeed 0.3.0 ships no inference engine; this package is the
-serving layer the ROADMAP's "millions of users" north star needs. Three parts:
+serving layer (ROADMAP.md's first aim names its metrics; A18 is what keeps it
+out of the benchmark). Three parts:
 
 - :mod:`block_allocator` — host-side free-list allocator over a fixed HBM pool
   of KV pages, with per-sequence block tables and refcounted copy-on-write

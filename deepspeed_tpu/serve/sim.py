@@ -884,7 +884,7 @@ def main(argv=None):
         # stream over 4 virtual replicas, rebuild per-replica latency
         # sketches, merge, and require the fleet percentiles to EQUAL the
         # single-stream read-out (the HistogramSketch mergeability contract
-        # ROADMAP item 2c's router gates on). Wall-derived values, but the
+        # the fleet router, serve/router.py, gates on). Wall-derived values, but the
         # equality itself is exact by construction, so the boolean is stable.
         finished_recs = [r for r in tracer.requests
                          if r.get("status") == "finished"]

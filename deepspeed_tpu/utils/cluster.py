@@ -761,13 +761,6 @@ def assemble_cluster_report(by_host, run_key=""):
     if goodput_by_host:
         from .goodput import fleet_goodput
         fleet_gp = fleet_goodput(goodput_by_host)
-    # fleet alert plane (utils/alerts.py): when any host's dump carries an
-    # alerts block, merge them — the report names the first-firing host +
-    # rule, i.e. where the incident started
-    alerts_fleet = None
-    if any(isinstance(by_host[h].get("alerts"), dict) for h in hosts):
-        from .alerts import merge_fleet_alerts
-        alerts_fleet = merge_fleet_alerts(by_host)
     return {
         "version": 1,
         "kind": "cluster_report",
@@ -782,7 +775,6 @@ def assemble_cluster_report(by_host, run_key=""):
         "first_bad_host": fb_host,
         "stragglers": stragglers,
         "goodput": fleet_gp,
-        "alerts_fleet": alerts_fleet,
     }
 
 

@@ -27,8 +27,7 @@ compiled HLO against them remains a real cross-check.
 The registry sweep (``ds-tpu hbm``) runs all three over every lint-registry
 entry and gates parsed-vs-modeled within a pinned tolerance; ``--forecast``
 is the pure-host feasibility predicate that re-derives the round-5 OOM
-frontier (PERF.md) without executing anything — the prerequisite the
-autotuner's config pruning needs (ROADMAP item 3).
+frontier (PERF.md) without executing anything.
 """
 
 import argparse

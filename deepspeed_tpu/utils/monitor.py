@@ -27,11 +27,6 @@ class SummaryMonitor:
         self._tb = None
         self._jsonl = None
         self._events = None
-        # MetricStore hook (utils/metrics.py), set by
-        # TelemetrySession.configure_metrics. Lives on EVERY rank and is fed
-        # before the rank-0 early return so each host's metric ring is
-        # populated even though only process 0 writes files.
-        self.metrics = None
         # log_dir is part of the public surface on EVERY rank (rank-agnostic
         # callers read it), so it must be set before the disabled early-return.
         output_path = output_path or os.path.join(os.environ.get("DLWS_JOB_ID", "."),
@@ -54,12 +49,6 @@ class SummaryMonitor:
                         f"scalars go to {self.log_dir}/scalars.jsonl only")
 
     def add_scalar(self, name: str, value, global_step: int):
-        if self.metrics is not None:
-            # catalog routing + ring recording happens on every rank and for
-            # every emitter (engine, serving, router, cluster, numerics all
-            # share this monitor object) — strict mode may raise here, which
-            # is the drift guard doing its job.
-            self.metrics.observe(name, value, global_step)
         if not self.enabled:
             return
         value = float(value)

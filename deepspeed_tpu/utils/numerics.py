@@ -317,30 +317,6 @@ class FlightRecorder:
                 out["hbm"] = oom_forensics(snap)
             except Exception:
                 out["hbm"] = {"error": "oom_forensics failed", "snapshot": snap}
-        if self.telemetry is not None:
-            # measured-time observatory: the last closed trace window's
-            # summary rides along so a post-mortem sees what the device
-            # timeline actually did (guarded like hbm — forensics must never
-            # block the dump)
-            prof_snapper = getattr(self.telemetry, "profile_snapshot", None)
-            if prof_snapper is not None:
-                try:
-                    prof = prof_snapper()
-                except Exception:
-                    prof = None
-                if prof is not None:
-                    out["profile"] = prof
-            # alert plane: rules/fired/active state + the full metric ring
-            # (utils/alerts.py) — a page-severity alert triggers this dump,
-            # so the bundle must carry the evidence it fired on
-            alert_snapper = getattr(self.telemetry, "alerts_snapshot", None)
-            if alert_snapper is not None:
-                try:
-                    alerts = alert_snapper()
-                except Exception:
-                    alerts = None
-                if alerts is not None:
-                    out["alerts"] = alerts
         return out
 
     def _span(self):

@@ -304,11 +304,6 @@ class TelemetrySession:
                          f"trace_{self.run_id}_host{self.host_id}")
             if self.run_id else self.trace_dir)
         self.trace_steps = tuple(trace_steps) if trace_steps is not None else None
-        # metric catalog + alert plane (docs/metrics.md, docs/alerts.md):
-        # off until configure_metrics / configure_alerts
-        self.metric_store = None
-        self.alert_engine = None
-        self.metrics_export_path = None
         self._owns_monitor = monitor is None
         if monitor is None:
             from .monitor import SummaryMonitor
@@ -395,53 +390,6 @@ class TelemetrySession:
             "temp_peak_bytes": self.watchdog.peak_temp_bytes(),
             "forecast_config": self._forecast_config,
         }
-
-    def configure_metrics(self, enabled: bool = True, ring_len: int = 512,
-                          strict: bool = False,
-                          export_path: Optional[str] = None):
-        """Switch the metric catalog router on: every scalar any observatory
-        emits through this session's SummaryMonitor is resolved against the
-        MetricCatalog (unknown names warn-once; ``strict`` raises — the test
-        drift guard) and recorded into a bounded per-host time-series ring.
-        Pure host bookkeeping — the step programs are untouched
-        (HLO-instruction-identity pinned in tests). ``export_path`` writes an
-        OpenMetrics text exposition of the ring's latest values on close."""
-        if not enabled:
-            return
-        from .metrics import MetricStore, default_catalog
-        self.metric_store = MetricStore(catalog=default_catalog(),
-                                        ring_len=ring_len, strict=strict,
-                                        host=self.host_id)
-        if self.monitor is not None:
-            self.monitor.metrics = self.metric_store
-        self.metrics_export_path = export_path or None
-
-    def configure_alerts(self, rules=None, recorder=None,
-                         ring_len: int = 512):
-        """Arm the alert plane: deterministic host-side rules (utils/alerts)
-        evaluated once per end_step against the metric ring — zero new
-        device syncs, zero step-program changes. ``rules=None`` arms the
-        shipped default ruleset. The flight recorder can be attached later
-        (engine wiring builds it after the session)."""
-        if self.metric_store is None:
-            self.configure_metrics(ring_len=ring_len)
-        from .alerts import AlertEngine
-        self.alert_engine = AlertEngine(rules=rules, store=self.metric_store,
-                                        monitor=self.monitor,
-                                        recorder=recorder)
-
-    def alerts_snapshot(self) -> Optional[Dict[str, Any]]:
-        """Flight-recorder embedding: alert rules/fired/active state plus the
-        full metric ring, so a page-triggered post-mortem carries the
-        evidence the rule fired on. None when the plane is off."""
-        if self.metric_store is None and self.alert_engine is None:
-            return None
-        out: Dict[str, Any] = {}
-        if self.alert_engine is not None:
-            out.update(self.alert_engine.snapshot())
-        if self.metric_store is not None:
-            out["ring"] = self.metric_store.to_dict()
-        return out
 
     def set_comm_topology(self, slice_sets):
         """Install the slice factorization (list of per-slice device-id sets,
@@ -635,11 +583,6 @@ class TelemetrySession:
         if self._trace_active and self.trace_steps is not None \
                 and global_step >= self.trace_steps[1]:
             self._stop_trace()
-        if self.alert_engine is not None:
-            # alert rules run on the end_step boundary, on the same axis the
-            # scalars above were recorded at — pure reads of the host-side
-            # metric ring, no device work (pinned by the no-sync guard)
-            self.alert_engine.evaluate(samples)
         return numerics_host
 
     # ------------------------------------------------------------- breakdown gate
@@ -701,11 +644,5 @@ class TelemetrySession:
         self._closed = True
         if self._trace_active:
             self._stop_trace()
-        if self.metrics_export_path and self.metric_store is not None:
-            try:
-                from .metrics import export_store
-                export_store(self.metric_store, self.metrics_export_path)
-            except OSError as e:  # export failure must never kill shutdown
-                logger.warning(f"[deepspeed_tpu] metrics export failed: {e}")
         if self._owns_monitor and self.monitor is not None:
             self.monitor.close()

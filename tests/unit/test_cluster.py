@@ -413,6 +413,19 @@ def test_assemble_cluster_report_orders_stalls_by_corrected_time(tmp_path):
     assert report["run"] == "runX" and report["n_dumps"] == 2
 
 
+def test_a_cluster_report_folds_no_alerts_block():
+    """Dumps written before the alert plane left may carry an ``alerts`` block: the report reads
+    past it, and its keys are the ones the hang-sim golden holds."""
+    by_host = {0: {"host": 0, "events": [], "alerts": {"fired": [{"rule": "mfu_drop", "step": 3}]}},
+               1: {"host": 1, "events": []}}
+    report = assemble_cluster_report(by_host, "runY")
+    assert "alerts_fleet" not in report
+    assert set(report) == {"version", "kind", "run", "hosts", "n_dumps", "hangs", "first_stall",
+                           "first_bad_step", "first_bad_host", "stragglers", "goodput"}
+    golden = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "hang_sim_transcript.json")))
+    assert set(golden["report"]) == set(report)
+
+
 # ----------------------------------------------------------------- the CLIs
 def _run_hang_sim(tmp_path, tag):
     out = str(tmp_path / f"transcript_{tag}.json")

@@ -140,51 +140,6 @@ SWEEP = {
          ("raise", ValueError)),
         ({"enabled": True, "cluster": {"enabled": True, "warmup_steps": True}},
          ("raise", ValueError)),
-        # metric catalog router (docs/metrics.md)
-        ({"enabled": True, "metrics": {"enabled": True}},
-         ("attr", "telemetry_metrics_enabled", True)),
-        ({"enabled": True, "metrics": {"enabled": True, "ring_len": 128}},
-         ("attr", "telemetry_metrics_ring_len", 128)),
-        ({"enabled": True, "metrics": {"enabled": True,
-                                       "strict_catalog": True}},
-         ("attr", "telemetry_metrics_strict_catalog", True)),
-        ({"enabled": True, "metrics": {"enabled": True,
-                                       "export_path": "/tmp/om.txt"}},
-         ("attr", "telemetry_metrics_export_path", "/tmp/om.txt")),
-        # the router rides the monitor the telemetry session owns
-        ({"metrics": {"enabled": True}}, ("raise", ValueError)),
-        ({"enabled": True, "metrics": {"enabled": True, "ring_len": 0}},
-         ("raise", ValueError)),
-        ({"enabled": True, "metrics": {"enabled": True, "ring_len": True}},
-         ("raise", ValueError)),
-        ({"enabled": True, "metrics": {"enabled": True, "strict_catalog": 1}},
-         ("raise", ValueError)),
-        ({"enabled": True, "metrics": {"enabled": True, "export_path": 5}},
-         ("raise", ValueError)),
-        ({"enabled": True, "metrics": {"enabled": 1}}, ("raise", ValueError)),
-        # alert plane (docs/alerts.md)
-        ({"enabled": True, "alerts": {"enabled": True}},
-         ("attr", "telemetry_alerts_enabled", True)),
-        ({"enabled": True,
-          "alerts": {"enabled": True,
-                     "rules": [{"name": "hot", "kind": "threshold",
-                                "metric": "Cluster/step_skew",
-                                "above": 3.0}]}},
-         ("attr", "telemetry_alerts_enabled", True)),
-        # the rules evaluate on the end_step boundary telemetry drives
-        ({"alerts": {"enabled": True}}, ("raise", ValueError)),
-        ({"enabled": True, "alerts": {"enabled": 1}}, ("raise", ValueError)),
-        ({"enabled": True, "alerts": {"enabled": True, "rules": "mfu"}},
-         ("raise", ValueError)),
-        ({"enabled": True,
-          "alerts": {"enabled": True,
-                     "rules": [{"name": "x", "kind": "gradient"}]}},
-         ("raise", ValueError)),
-        ({"enabled": True,
-          "alerts": {"enabled": True,
-                     "rules": [{"name": "x", "kind": "threshold",
-                                "metric": "Bogus/metric", "above": 1}]}},
-         ("raise", ValueError)),
     ),
     "numerics": (
         ({"enabled": True, "audit_interval": 7}, ("attr", "numerics_audit_interval", 7)),
@@ -367,33 +322,18 @@ def _state(cfg):
             for k, v in vars(cfg).items() if k != "_param_dict"}
 
 
-@pytest.mark.parametrize("block", ["anatomy", "profile"])
+@pytest.mark.parametrize("block", ["anatomy", "profile", "metrics", "alerts"])
 def test_a_removed_telemetry_block_warns_and_changes_nothing(capture, block):
-    """``telemetry.anatomy`` and ``telemetry.profile`` named modules that are
-    gone: a config that still carries one is told so like any unknown key, and
-    parses to what it would without it."""
+    """``telemetry.anatomy``, ``telemetry.profile``, ``telemetry.metrics`` and
+    ``telemetry.alerts`` named modules that are gone: a config that still
+    carries one is told so like any unknown key, and parses to what it would
+    without it."""
     with_block = _cfg(telemetry={"enabled": True, "trace_steps": [2, 5],
                                  block: {"enabled": True}})
     assert "unknown telemetry config key" in capture.text
     assert f"['{block}']" in capture.text
     without = _cfg(telemetry={"enabled": True, "trace_steps": [2, 5]})
     assert _state(with_block) == _state(without)
-
-
-def test_unknown_metrics_key_warns(capture):
-    _cfg(telemetry={"enabled": True,
-                    "metrics": {"enabled": True, "ring_length": 128}})
-    assert "unknown telemetry.metrics config key" in capture.text
-    assert "ring_length" in capture.text
-    assert "ring_len" in capture.text  # the known-keys hint points at the fix
-
-
-def test_unknown_alerts_key_warns(capture):
-    _cfg(telemetry={"enabled": True,
-                    "alerts": {"enabled": True, "ruleset": []}})
-    assert "unknown telemetry.alerts config key" in capture.text
-    assert "ruleset" in capture.text
-    assert "rules" in capture.text     # the known-keys hint points at the fix
 
 
 def test_unknown_goodput_key_warns(capture):
@@ -477,13 +417,6 @@ def test_known_nested_keys_do_not_warn(capture):
                     "pipeline_trace": {"enabled": True, "capacity": 7},
                     "goodput": {"enabled": True, "ledger_dir": "/tmp/gp",
                                 "emit_scalars": True, "eval_tag": "eval"},
-                    "metrics": {"enabled": True, "ring_len": 128,
-                                "strict_catalog": True,
-                                "export_path": "/tmp/om.txt"},
-                    "alerts": {"enabled": True,
-                               "rules": [{"name": "hot", "kind": "threshold",
-                                          "metric": "Cluster/step_skew",
-                                          "above": 3.0}]},
                     "cluster": {"enabled": True, "heartbeat_interval": 2,
                                 "hang_deadline_s": 120.0, "dump_dir": "/tmp/cl",
                                 "straggler_threshold": 3.0,

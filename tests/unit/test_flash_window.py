@@ -81,6 +81,60 @@ def test_the_counter_at_the_cells_shape():
     assert ratios == [2.0, 1.5, 1.25]
 
 
+def census(T, bq, bk, window):
+    """``(touched, full, allowed)`` by brute force over every (query, key) pair of ``T`` positions,
+    a row of q-tiles at a time: which (q tile, k tile) hold an allowed pair, which hold nothing
+    else, and how many pairs the mask allows."""
+    touched = np.zeros((T // bq, T // bk), bool)
+    full = np.zeros_like(touched)
+    keys = np.arange(T, dtype=np.int32)[None, :]
+    allowed_pairs = 0
+    for i in range(T // bq):
+        queries = np.arange(i * bq, (i + 1) * bq, dtype=np.int32)[:, None]
+        mask = keys <= queries
+        if window is not None:
+            mask &= queries - keys < window
+        a_tile = mask.reshape(bq, T // bk, bk).sum(axis=(0, 2))
+        touched[i], full[i] = a_tile > 0, a_tile == bq * bk
+        allowed_pairs += int(a_tile.sum())
+    return touched, full, allowed_pairs
+
+
+# the cells' calls (1,024 positions under 512-tiles, 4,096 under 512, 8,192 under 1,024, and under
+# 512 where a window of 1,024 makes a band) and the tiles ``tests/perf/flash_sweep.py`` reads
+# beside them on the chip, square and not
+AT_THE_CELLS = [(1024, 128, 128, None), (1024, 256, 256, None), (1024, 512, 512, None), (1024, 1024, 1024, None),
+                (1024, 512, 512, 1024), (4096, 512, 512, None), (4096, 1024, 1024, None), (4096, 512, 512, 1024),
+                (8192, 512, 512, None), (8192, 1024, 1024, None), (8192, 256, 256, 1024), (8192, 512, 512, 1024),
+                (8192, 1024, 1024, 1024), (8192, 512, 256, 1024), (8192, 256, 512, 1024), (8192, 1024, 512, 1024)]
+
+
+@pytest.mark.parametrize("T, bq, bk, window", AT_THE_CELLS,
+                         ids=[f"{T}-{bq}x{bk}-{'band' if w else 'triangle'}" for T, bq, bk, w in AT_THE_CELLS])
+def test_the_schedule_s_integers_at_the_cells_sizes(T, bq, bk, window):
+    """What the forward walks a q-tile, what the backward walks a k-tile and what ``band_pairs``
+    counts, against the census: every touched tile visited once and no other, the plain body on
+    exactly the tiles the mask fills, the same tiles both ways round."""
+    touched, full, allowed_pairs = census(T, bq, bk, window)
+    for i in range(T // bq):
+        if window is None:
+            n_full, last = fa.causal_k_tiles(i, bq, bk)
+            seen = {j: j >= n_full for j in range(last)}
+        else:
+            seen = visits(fa.band_k_loops(i, bq, bk, window))
+        assert sorted(seen) == np.flatnonzero(touched[i]).tolist(), i
+        assert [j for j in seen if not seen[j]] == np.flatnonzero(full[i]).tolist(), i
+    for j in range(T // bk):
+        if window is None:
+            first, full_from = fa.causal_q_tiles(j, bq, bk)
+            seen = {i: i < full_from for i in range(first, T // bq)}
+        else:
+            seen = visits(fa.band_q_loops(j, bq, bk, window, T // bq))
+        assert sorted(seen) == np.flatnonzero(touched[:, j]).tolist(), j
+        assert sorted(i for i in seen if not seen[i]) == np.flatnonzero(full[:, j]).tolist(), j
+    assert fa.band_pairs(T, bq, bk, window) == (int(touched.sum()) * bq * bk, allowed_pairs)
+
+
 # ------------------------------------------------------------------ the kernel, interpreted
 B, H, G, T, D = 1, 8, 1, 128, 32
 

@@ -74,27 +74,8 @@ def test_pass_reports_occurrence_counts():
 def test_guard_scans_the_real_files():
     files = _utils_files()
     for name in ("telemetry.py", "numerics.py", "pipeline_trace.py", "hlo.py",
-                 "metrics.py", "alerts.py",
                  os.path.join("serve", "request_trace.py")):
         assert any(f.endswith(name) for f in files), f"{name} missing from sweep"
-
-
-def test_metric_catalog_is_sync_free():
-    """The metric catalog routes EVERY add_scalar call on every rank — any
-    host-sync primitive here would tax every scalar emission in the step
-    loop: zero tolerance."""
-    m = os.path.join(UTILS, "metrics.py")
-    vids = {v.vid for v in run_ast_passes([m], (HostSyncPass(),), root=ROOT)}
-    assert vids == set(), f"host-sync primitive in the metric catalog: {vids}"
-
-
-def test_alert_engine_is_sync_free():
-    """The alert engine evaluates on the end_step boundary over the host-side
-    metric ring — it must never reach back to the device: zero host-sync
-    primitives."""
-    a = os.path.join(UTILS, "alerts.py")
-    vids = {v.vid for v in run_ast_passes([a], (HostSyncPass(),), root=ROOT)}
-    assert vids == set(), f"host-sync primitive in the alert engine: {vids}"
 
 
 def test_request_trace_ledger_is_sync_free():

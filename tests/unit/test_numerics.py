@@ -293,6 +293,25 @@ def test_flight_recorder_ring_is_bounded_and_dumps(tmp_path):
     assert bundle["events"][0]["event"] == "loss_scale"
 
 
+def test_a_dump_holds_no_alerts_block(tmp_path):
+    """The alert plane is gone: a session that still answers ``alerts_snapshot`` (an older
+    caller's object) adds nothing to a dump, and the dump's keys are the ones listed here."""
+    class Session:
+        def memory_snapshot(self):
+            return None
+
+        def alerts_snapshot(self):
+            return {"rules": [], "fired": [], "ring": {}}
+
+    rec = FlightRecorder(capacity=4, dump_dir=str(tmp_path), telemetry=Session())
+    rec.record_step({"step": 1, "overflow": False, "anomaly": None})
+    bundle = rec.bundle("r", None)
+    assert bundle["reason"] == "r" and [s["step"] for s in bundle["steps"]] == [1]
+    assert set(bundle) - {"run", "span"} == {
+        "version", "reason", "detail", "host", "time", "first_bad_step", "offending_subtree",
+        "loss_scale_trajectory", "steps", "events", "compile_records"}
+
+
 def test_flight_recorder_first_bad_step(tmp_path):
     rec = FlightRecorder(capacity=8, dump_dir=str(tmp_path))
     rec.record_step({"step": 1, "overflow": False, "anomaly": None})

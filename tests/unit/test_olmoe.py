@@ -821,3 +821,66 @@ def test_no_token_major_rows_are_laid_out_at_a_k_the_tile_does_not_divide():
         (tuple(p for p in path if p != "custom_vjp_call"), eqn.invars[0].aval.shape[0]) for path, eqn in equations_by_path(jaxpr)
         if eqn.primitive.name == "gather" and eqn.invars[0].aval.shape[1:] == (H,))
     assert row_gathers == {((), n): 1, ((), n * k): 1, (("remat2",), n): 2, (("remat2",), n * k): 1}, row_gathers
+
+
+# ---- ``engine_shardings``: the one model-side layout rule the engine reads (``runtime/engine.py``
+# takes it where the caller names no ``param_shardings``). Shapes and specs alone: nothing is placed.
+LEAF_KINDS = {
+    # kind: (paths into the tree, the spec on a mesh whose ``data`` divides the experts)
+    "experts": ((("layers", 0, "moe", "w_gate_up"), ("layers", 1, "moe", "w_down")), P("data")),
+    "router": ((("layers", 0, "moe", "router_w"), ("layers", 1, "moe", "router_w")), P()),
+    "norms": ((("layers", 0, "norm_1"), ("layers", 1, "norm_2"), ("layers", 0, "q_norm"),
+               ("layers", 1, "k_norm"), ("norm_f",)), P()),
+    "attention": ((("layers", 0, "wqkv"), ("layers", 1, "wo")), P()),
+    "embedding": ((("embed",),), P()),
+    "head": ((("head",),), P()),
+}
+
+
+def _leaf(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+@functools.lru_cache(maxsize=None)
+def _layout(data, experts=8):
+    model = OlmoeModel(OlmoeConfig.from_published(dict(published(2), num_experts=experts)))
+    mesh = build_mesh(data=data, devices=jax.devices()[:data])
+    return model, jax.eval_shape(model.init, jax.random.PRNGKey(0)), model.engine_shardings(mesh)
+
+
+def test_the_engine_s_layout_has_the_tree_init_makes():
+    model, shapes, layout = _layout(4)
+    assert model.moe.w_in == "w_gate_up"
+    assert jax.tree_util.tree_structure(shapes) == jax.tree_util.tree_structure(layout)
+    named = {path for paths, _ in LEAF_KINDS.values() for path in paths}
+    every = {tuple(getattr(k, "key", getattr(k, "idx", None)) for k in path)
+             for path, _ in jax.tree_util.tree_leaves_with_path(layout)}
+    # every kind of leaf of a layer is named above, in one layer or the other
+    assert {p[2:] for p in every if p[0] == "layers"} == {p[2:] for p in named if p[0] == "layers"}
+    assert {p for p in every if p[0] != "layers"} == {p for p in named if p[0] != "layers"}
+
+
+@pytest.mark.parametrize("kind", sorted(LEAF_KINDS))
+def test_the_engine_s_layout_on_four_devices(kind):
+    """Experts over ``data`` on their leading axis, a quarter a device; everything else whole on
+    every device (the engine's ZeRO layout then claims what is free)."""
+    _, shapes, layout = _layout(4)
+    paths, spec = LEAF_KINDS[kind]
+    for path in paths:
+        sharding, shape = _leaf(layout, path), _leaf(shapes, path).shape
+        assert sharding.spec == spec and sharding.mesh.shape["data"] == 4, path
+        a_device = sharding.shard_shape(shape)
+        assert a_device == ((shape[0] // 4, *shape[1:]) if kind == "experts" else shape), path
+
+
+@pytest.mark.parametrize("data, experts", [(1, 8), (4, 6), (2, 6)], ids=["one-device", "4-over-6-experts", "2-over-6-experts"])
+def test_experts_that_data_does_not_divide_stay_whole(data, experts):
+    _, shapes, layout = _layout(data, experts)
+    split = data > 1 and experts % data == 0
+    for path in LEAF_KINDS["experts"][0]:
+        sharding, shape = _leaf(layout, path), _leaf(shapes, path).shape
+        assert shape[0] == experts and sharding.spec == (P("data") if split else P())
+        assert sharding.shard_shape(shape)[0] == (experts // data if split else experts)
+    assert all(_leaf(layout, path).spec == P() for kind, (paths, _) in LEAF_KINDS.items() if kind != "experts" for path in paths)

@@ -9,6 +9,7 @@ so these say nothing about results or times; ``chip_smoke.py`` does that on the 
 """
 
 import collections
+import functools
 import os
 import re
 
@@ -98,23 +99,64 @@ def test_sliding_window_flash_attention_compiles_for_v5e(chip):
     assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text
 
 
-def test_a_latent_attention_block_compiles_for_v5e(chip, monkeypatch):
+@functools.lru_cache(maxsize=None)
+def latent_attention_block_text(chip):
     """One latent (MLA) mixer of ``glm47flash_ep8_d5_train_1chip`` at its published widths and 8,192
-    positions, value and gradient: both bottlenecks, the one rotary key broadcast to 20 heads, and
-    the flash kernel at 20 query over 20 key/value heads of 192 + 64 | 256 (no grouped-query case
-    compiles 20 key/value heads of 256)."""
+    positions, value and gradient, as the chip's compiler leaves it: compiled once a process."""
     from benchmarks.manifest import Manifest
     from benchmarks.runners.train_mla_moe import build_model
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernel, not its interpreter
-    model = build_model(Manifest().config("glm-4.7-flash-ep8-d5"))
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][1]["attn"]
-    assert shapes["wkv_b"].shape == (512, 20 * (192 + 256)) and shapes["wkv_a"].shape == (2048, 512 + 64)
-    ap = jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
-    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=chip)
-    grad = jax.grad(lambda x, p: jnp.sum(model.attention(x, p).astype(jnp.float32) ** 2), argnums=(0, 1))
-    text = compiled_text(grad, x, ap)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")     # the kernel, not its interpreter
+        model = build_model(Manifest().config("glm-4.7-flash-ep8-d5"))
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][1]["attn"]
+        assert shapes["wkv_b"].shape == (512, 20 * (192 + 256)) and shapes["wkv_a"].shape == (2048, 512 + 64)
+        ap = jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=chip), shapes)
+        x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=chip)
+        grad = jax.grad(lambda x, p: jnp.sum(model.attention(x, p).astype(jnp.float32) ** 2), argnums=(0, 1))
+        return compiled_text(grad, x, ap)
+
+
+def test_a_latent_attention_block_compiles_for_v5e(chip):
+    """Both bottlenecks, the one rotary key broadcast to 20 heads, and the flash kernel at 20 query
+    over 20 key/value heads of 192 + 64 | 256 (no grouped-query case compiles 20 key/value heads
+    of 256)."""
+    text = latent_attention_block_text(chip)
     assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text and "ds_attn_latent" in text
     assert re.search(r"bf16\[1,20,8192,256\]", text)
+
+
+def test_every_product_and_fusion_of_a_v5e_program_is_priced(chip):
+    """``utils/hlo.instruction_costs`` over the same program: every ``fusion``, ``dot`` and
+    ``convolution`` the entry computation runs (a kernel's wrapper aside) has a ``cost``, its bytes
+    above zero unless all it writes stays in fast memory (``S(1)``: traffic the floor leaves out),
+    and every one that holds a product is among ``products`` with its ``[M, K, N, types]`` and
+    flops above zero. A JAX whose compiler prints its text another way is red here, where on the
+    chip the only sign is a cell's ``unpriced`` share rising."""
+    from deepspeed_tpu.utils import hlo
+    text = latent_attention_block_text(chip)
+    priced = hlo.instruction_costs(text)
+    bodies = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M))
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    seen = collections.Counter()
+    for match in map(INSTRUCTION_RE.match, entry.splitlines()):
+        name, result, opcode, rest = match.groups() if match else (None,) * 4
+        if opcode not in ("fusion", "dot", "convolution"):
+            continue
+        body = bodies[re.search(r"calls=%?([\w.\-]+)", rest).group(1)] if opcode == "fusion" else f" {opcode}("
+        if "tpu_custom_call" in body:
+            assert name not in priced["cost"]           # a kernel is priced by its own reckoning
+            continue
+        holds_a_product = bool(re.search(r" (dot|convolution)\(", body))
+        stays_in_fast_memory = all("S(1)" in layout for layout in re.findall(r"\[[\d,]*\]\{([^}]*)\}", result))
+        seen[holds_a_product, stays_in_fast_memory] += 1
+        flops, nbytes = priced["cost"][name]
+        assert nbytes > 0 or stays_in_fast_memory, name
+        assert (name in priced["products"]) == holds_a_product == (flops > 0), name
+        for m, k, n, types in priced["products"].get(name, {"mkn": []})["mkn"]:
+            assert m > 0 and k > 0 and n > 0 and re.fullmatch(r"\w+x\w+->\w+", types), (name, types)
+    # the block's projections, forward and backward, and the elementwise passes between them
+    assert seen[True, False] >= 10 and seen[False, False] >= 10, seen
+    assert priced["collectives"] == []
 
 
 def test_a_gated_short_convolution_operator_compiles_for_v5e(chip, monkeypatch):
