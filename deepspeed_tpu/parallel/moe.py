@@ -422,8 +422,8 @@ def _sum_rows(ys, tok, inverse, runs):
 _take_rows.defvjp(lambda x, *sort: (_take_rows(x, *sort), sort), lambda sort, dxs: (_sum_rows(dxs, *sort), None, None, None))
 _sum_rows.defvjp(lambda ys, *sort: (_sum_rows(ys, *sort), sort), lambda sort, dy: (_take_rows(dy, *sort), None, None, None))
 
-# The grouped products' tiles: the rows of a tile, and the MOST a contraction and a column tile
-# take where the contraction does not stay whole. The kernels (``ops/pallas/grouped_matmul.py``)
+# The grouped products' tiles: the rows of a tile where the contraction is cut, and the MOST a
+# contraction and a column tile take there. The kernels (``ops/pallas/grouped_matmul.py``)
 # round K and N up to whole tiles and compute every tile in full (a K remainder is masked
 # besides), so a tile that does not divide its width is issued work nobody needs: (512, 1024,
 # 1024) clipped with ``min`` padded 2304 and 2688 to 3072 and 1792 and 1856 to 2048, 1.26 to 1.52
@@ -457,14 +457,52 @@ def _width_tile(width, most):
     return min(range(most - most % 128, 512 - 1, -128), key=lambda tile: -(-width // tile) * tile)
 
 
-def _tiles(rows, contraction, columns):
-    """``(tm, tk, tn)`` for a grouped product of these widths, ``tgmm``'s ``tk`` and ``tn`` its
-    output's two widths: a function of the shapes alone."""
-    tm = min(GMM_TILES[0], rows)
+# Where the contraction stays whole the ROW tile goes by the rows a group holds (PERF.md, PR 57).
+# The kernels walk megablox's schedule: one grid step a (group, row tile) pair and column tile,
+# and a row tile that a group's boundary cuts is visited once for EACH group in it and computed
+# whole under a mask, so a call of ``M`` rows in ``G`` groups takes up to ``M / tm + G - 1``
+# visits where ``M / tm`` are needed: 191 for 128 at OLMoE's 65,536 rows in 64 groups under a
+# row tile of 512. With K whole the weights' block is fetched once a group whatever ``tm``, so a
+# smaller row tile costs no traffic; what it costs is grid steps. Read on the chip at 128 | 256 |
+# 512 | 1,024 rows (``tests/perf/gmm_sweep.py --grid picked``, ``chiprun_out/pr57a/``), a step
+# takes a FIXED part, the same at every row tile (0.26-0.45 us in ``ds_gmm``, 0.5-0.55 in
+# ``ds_tgmm``, which adds into its float32 accumulator), and its ``[tm, tk] x [tk, tn]`` product
+# at the MXU's own rate (to 1 % in ``ds_gmm``; the masks' selects add 2.5 % in ``ds_tgmm``); and
+# the step that OPENS a group is no shorter than the fetch of the group's weights (``ds_tgmm``:
+# the write of its gradient), which the step before it hides: the bytes of ``tk tn`` values, as
+# long as the product of ``GMM_FETCH`` rows (the chip's 240 operations a byte). That last part is
+# why 128 is no faster than 256 at OLMoE's 1,024 rows a group, though it visits fewer rows.
+# ``GMM_STEP`` is the fixed part BY KIND in the multiply-adds the MXU makes in that time, and the
+# row tile is the one of ``GMM_ROW_TILES`` that makes the walk's most steps times a step, and the
+# groups' openings, cost least.
+GMM_ROW_TILES = (128, 256, 512)
+GMM_STEP = {"gmm": 26e6, "gmm_t": 26e6, "tgmm": 49e6}
+GMM_FETCH = 240
+
+
+def _row_tile(kind, rows, groups, volume):
+    """The row tile of a grouped product of ``kind`` whose ``rows`` lie in ``groups`` groups and
+    whose grid step multiplies a row by ``volume = tk tn`` weights: the one of ``GMM_ROW_TILES``
+    that divides the rows and makes ``(rows / tm + groups - 1) step + groups max(0, GMM_FETCH
+    volume - step)`` least, ``step = GMM_STEP + tm volume``; of two alike the smaller. Fewer rows
+    a group never pick a larger tile."""
+    def cost(tm):
+        step = GMM_STEP[kind] + tm * volume
+        return (rows // tm + groups - 1) * step + groups * max(0, GMM_FETCH * volume - step)
+
+    return min((tm for tm in GMM_ROW_TILES if rows % tm == 0), key=cost, default=min(GMM_TILES[0], rows))
+
+
+def _tiles(kind, rows, groups, contraction, columns):
+    """``(tm, tk, tn)`` for a grouped product of ``kind`` (``gmm``, its transposed form ``gmm_t``,
+    ``tgmm``) over ``rows`` rows in ``groups`` groups at these widths, ``tgmm``'s ``tk`` and
+    ``tn`` its output's two widths: a function of the kind and the shapes alone."""
+    tm = min(GMM_TILES[0], rows)                    # the row tile beside a CUT contraction
     most = GMM_ACC // max(contraction, tm)          # the widest column tile beside the whole contraction
     if most < 512:                                  # past every cell's widths: cut, as under megablox
         return tm, _width_tile(contraction, GMM_TILES[1]), _width_tile(columns, GMM_TILES[2])
-    return tm, contraction, _width_tile(columns, most)
+    tn = _width_tile(columns, most)
+    return _row_tile(kind, rows, groups, contraction * tn), contraction, tn
 
 
 def _run_bounds(n, k, G, H, group, tok):
@@ -491,14 +529,20 @@ def _ragged_sizes(rhs, group_sizes, first):
             jnp.concatenate([before[None].astype(group_sizes.dtype), mine]))
 
 
-def _count_product(kind, widths, tiles=None):
+def _count_product(kind, widths, rows, groups, tiles=None):
     """While a step program is traced, every grouped product leaves in the recorder how it will
-    run: ``moe.<kind>.whole_k[<program>] <K>x<N> in <tm>x<tk>x<tn>`` where its contraction stays
-    ONE tile in fast memory (``tgmm``: its output's first width), ``.cut_k`` where it is cut in
-    pieces, ``.ragged_dot`` off the TPU; once a trace of the call (a layer traced once and run
-    four times counts once). ``docs/telemetry.md``."""
+    run: ``moe.<kind>.whole_k[<program>] <K>x<N> in <tm>x<tk>x<tn>, <M / G> rows a group, visits
+    <= <(M / tm + G - 1) / (M / tm)>`` where its contraction stays ONE tile in fast memory
+    (``tgmm``: its output's first width): what ``_tiles``' row tile saw and the most (group, row
+    tile) pairs the walk can take over the row tiles the rows need. ``.cut_k`` where the
+    contraction is cut in pieces, ``.ragged_dot`` off the TPU (no tiles); once a trace of the
+    call (a layer traced once and run four times counts once). ``docs/telemetry.md``."""
     how = "ragged_dot" if tiles is None else "whole_k" if tiles[1] == widths[0] else "cut_k"
-    spans.recorder().count_in_program(f"moe.{kind}.{how}", " %dx%d" % widths + (" in %dx%dx%d" % tiles if tiles else ""))
+    text = " %dx%d" % widths
+    if tiles:
+        needed = -(-rows // tiles[0])
+        text += " in %dx%dx%d, %d rows a group, visits <= %.2f" % (*tiles, rows // groups, (needed + groups - 1) / needed)
+    spans.recorder().count_in_program(f"moe.{kind}.{how}", text)
 
 
 def grouped_matmul(lhs, rhs, group_sizes, first=None, out=None, transpose_rhs=False):
@@ -511,9 +555,10 @@ def grouped_matmul(lhs, rhs, group_sizes, first=None, out=None, transpose_rhs=Fa
     ``ds_gmm`` (``ops/pallas/grouped_matmul.py``) at the tiles ``_tiles`` picks, elsewhere
     ``lax.ragged_dot``, which XLA's CPU backend runs and whose TPU lowering reached 55 % of
     megablox's rate on the chip."""
-    columns = rhs.shape[1 if transpose_rhs else 2]
-    tiles = _tiles(lhs.shape[0], lhs.shape[1], columns) if jax.default_backend() == "tpu" else None
-    _count_product("gmm_t" if transpose_rhs else "gmm", (lhs.shape[1], columns), tiles)
+    kind, columns = "gmm_t" if transpose_rhs else "gmm", rhs.shape[1 if transpose_rhs else 2]
+    rows, groups = lhs.shape[0], group_sizes.shape[0]
+    tiles = _tiles(kind, rows, groups, lhs.shape[1], columns) if jax.default_backend() == "tpu" else None
+    _count_product(kind, (lhs.shape[1], columns), rows, groups, tiles)
     if tiles is None:
         if transpose_rhs:
             rhs = rhs.swapaxes(1, 2)
@@ -534,8 +579,9 @@ def grouped_matmul_weight_grad(lhs, grad, group_sizes, first, like):
     """The cotangent of ``grouped_matmul``'s ``rhs`` (shaped and typed ``like`` it):
     ``d_rhs[g] = lhs[rows of g].T @ grad[rows of g]`` for the groups ``first .. first +
     len(like) - 1`` (all of them where ``first`` is None). The kernel ``ds_tgmm`` on the TPU."""
-    tiles = _tiles(lhs.shape[0], *like.shape[1:]) if jax.default_backend() == "tpu" else None
-    _count_product("tgmm", like.shape[1:], tiles)
+    rows, groups = lhs.shape[0], group_sizes.shape[0]
+    tiles = _tiles("tgmm", rows, groups, *like.shape[1:]) if jax.default_backend() == "tpu" else None
+    _count_product("tgmm", like.shape[1:], rows, groups, tiles)
     if tiles is None:
         padded, sizes = _ragged_sizes(like, group_sizes, first)
         d_rhs, = jax.linear_transpose(

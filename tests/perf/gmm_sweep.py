@@ -3,7 +3,7 @@
 read from a profiler trace, beside what the products need and what the tiles issue.
 
     python tests/perf/gmm_sweep.py [--cells mellum2,nemotronh,olmoe,qwen3next,glm47flash,lfm2] [--shapes 2304x1792]
-                                   [--grid near|full|picked] [--tm 256,1024] [--vmem 64] [--seed 0] [--check]
+                                   [--grid near|full|picked] [--tm 128,256,512,tgmm:1024] [--vmem 64] [--seed 0] [--check]
                                    [--out chiprun_out/gmm_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent unpacked
@@ -34,11 +34,15 @@ megablox's call has). ``--grid near`` (the default) takes a
 whole K and what ``(512, 1024, 1024)`` bounded it to until PR 55, beside the N tiles that issue
 at most 1.05 times the work the widths need, ``(512, 1024, 1024)`` clipped and what this tree's
 ``_tiles`` picks; ``--grid full`` every pair; ``--grid picked`` this tree's pick alone (a tree's
-kernels read again in a few minutes). ``--tm`` adds this tree's pick at other row tiles.
+kernels read again in a few minutes). ``--tm`` adds this tree's pick at other row tiles, BY KIND:
+an entry is a row tile for every kind (``128``) or for one (``tgmm:1024``); 128, 256 and 512 by
+default, since PR 57's ``_tiles`` picks among them by the kind, the rows and the groups.
 A line holds ``ms`` (the kernels' device time a call, all pieces),
 ``tflops`` of the NEEDED operations (2 x rows in the call's groups x K x N),
-``issued_over_needed`` by the width tiles and ``row_tiles_over_even`` by the group
-boundaries, ``vmem_bytes``, ``first_call_s`` (the candidate's first call: its compile),
+``issued_over_needed`` by the width tiles, ``row_tiles_visited`` / ``row_tiles_needed`` (the
+(group, row tile) pairs the walk takes at these group sizes over the row tiles that hold the
+rows; ``visits_bound`` is the most any sizes can make it, ``(M / tm + G - 1) / (M / tm)``),
+``vmem_bytes``, ``first_call_s`` (the candidate's first call: its compile),
 ``picked`` (this tree's ``_tiles``) and ``clipped``. ``--check`` adds the relative error against
 a per-expert float32 loop on the same values.
 """
@@ -47,6 +51,7 @@ import argparse
 import collections
 import functools
 import glob
+import inspect
 import json
 import os
 import re
@@ -75,6 +80,7 @@ CLIPPED = (512, 1024, 1024)          # what ``_tiles`` clipped with ``min`` unti
 VMEM = (64 if grouped else 16) * 2 ** 20    # what a candidate's blocks may take (megablox's calls: a kernel's 16 MiB)
 NEAR = 1.05                          # ``--grid near``: the width tiles that issue at most this over the need
 ROWS_HERE = {"qwen3next": 0.0615}    # ledger, PR 46: ``moe_rows_here_share`` of a held range alone
+ROW_TILES = "128,256,512"            # ``--tm``: what ``_tiles`` picks among since PR 57
 
 Call = collections.namedtuple("Call", "cell kind rows K N groups pieces")
 
@@ -138,10 +144,28 @@ def width_tiles(width):
     return [t for t in range(512, 1152 + 1, 128) if t < width] + sorted(parts) + [width]
 
 
+def picked(call):
+    """This tree's ``parallel/moe._tiles`` for the call: by the kind, the rows, the groups and the
+    widths since PR 57, by the rows and the widths alone in a tree before it."""
+    if len(inspect.signature(moe._tiles).parameters) == 3:
+        return moe._tiles(call.rows, call.K, call.N)
+    return moe._tiles(call.kind, call.rows, call.groups, call.K, call.N)
+
+
+def row_tiles_by_kind(text):
+    """``--tm``: ``{kind: [tm, ...]}`` from entries ``tm`` (every kind) and ``kind:tm``."""
+    by_kind = {kind: [] for kind in ("gmm", "gmm_t", "tgmm")}
+    for entry in filter(None, text.split(",")):
+        kind, _, tm = entry.rpartition(":")
+        for each in [kind] if kind else by_kind:
+            by_kind[each].append(int(tm))
+    return by_kind
+
+
 def candidates(call, grid, row_tiles, vmem=VMEM):
     fits = lambda t: block_bytes(call, t) <= vmem      # noqa: E731
-    picked = moe._tiles(call.rows, call.K, call.N)
-    more = [picked, *((tm,) + picked[1:] for tm in row_tiles if call.rows % tm == 0)]
+    pick = picked(call)
+    more = list(dict.fromkeys([pick, *((tm,) + pick[1:] for tm in row_tiles[call.kind] if call.rows % tm == 0)]))
     if grid == "picked":
         return more
     out = [(512, tk, tn) for tk in width_tiles(call.K) for tn in width_tiles(call.N) if fits((512, tk, tn))]
@@ -261,7 +285,8 @@ def measure(call, model, tiles_of, seed, check, calls=3):
     for tiles in tiles_of:
         line = dict(call._asdict(), tiles=list(tiles), vmem_bytes=block_bytes(call, tiles),
                     issued_over_needed=issued_over_needed(tiles, call.K, call.N),
-                    picked=tuple(tiles) == moe._tiles(call.rows, call.K, call.N),
+                    visits_bound=(call.rows // tiles[0] + call.groups - 1) / (call.rows // tiles[0]),
+                    picked=tuple(tiles) == picked(call),
                     clipped=tuple(tiles) == clipped(call))
         lines.append(line)
         try:
@@ -298,8 +323,8 @@ def measure(call, model, tiles_of, seed, check, calls=3):
             needed = 2.0 * int(sizes.sum()) * call.K * call.N
             line[how] = dict(ms=ms, tflops=needed / (ms * 1e-3) / 1e12, rows=int(sizes.sum()),
                              load_max_over_mean=float(sizes.max() / sizes.mean()),
-                             row_tiles_over_even=row_tiles_visited(sizes, line["tiles"][0])
-                             / (-(-int(sizes.sum()) // line["tiles"][0])))
+                             row_tiles_visited=row_tiles_visited(sizes, line["tiles"][0]),
+                             row_tiles_needed=-(-int(sizes.sum()) // line["tiles"][0]))
     return lines
 
 
@@ -308,7 +333,7 @@ def main():
     ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--shapes", default="", help="only the calls whose kind:KxN holds this, e.g. tgmm:2304x1792")
     ap.add_argument("--grid", default="near", choices=("near", "full", "picked"))
-    ap.add_argument("--tm", default="", help="further row tiles for this tree's pick, e.g. 256,1024")
+    ap.add_argument("--tm", default=ROW_TILES, help="further row tiles for this tree's pick, for every kind (256) or one (tgmm:1024)")
     ap.add_argument("--vmem", type=float, default=VMEM / 2 ** 20, help="MiB of blocks a candidate may take")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check", action="store_true")
@@ -318,7 +343,7 @@ def main():
         sys.exit("gmm_sweep.py measures the compiled kernels: it needs a TPU")
     os.makedirs(os.path.dirname(os.path.abspath(opts.out)), exist_ok=True)
     manifest = Manifest()
-    row_tiles = [int(t) for t in opts.tm.split(",") if t]
+    row_tiles = row_tiles_by_kind(opts.tm)
     with open(opts.out, "w") as f:
         for key in opts.cells.split(","):
             model = manifest.config(manifest.cell(CELLS[key])["config"])["model"]
@@ -343,7 +368,7 @@ def main():
                         continue
                     print(head, " | ".join(
                         f"{how} {line[how]['ms']:7.3f} ms {line[how]['tflops']:6.1f} TF/s "
-                        f"rows x{line[how]['row_tiles_over_even']:.3f}"
+                        f"row tiles {line[how]['row_tiles_visited']}/{line[how]['row_tiles_needed']}"
                         + (f" rel {line['rel'][how]:.1e}" if "rel" in line else "")
                         for how in ("even", "lean")), flush=True)
 

@@ -61,23 +61,28 @@ def check(sizes, m, tm, first=0, held=None):
     return want
 
 
-CELL_SHAPES = {key: sweep.expert_calls(MANIFEST, key)[0] for key in sweep.CELLS}
+KINDS = ("gmm", "gmm_t", "tgmm")
+# a cell's first product, by kind (the walk goes by rows, groups, pieces and the row tile the rule picks the kind)
+CELL_SHAPES = {(key, kind): next(c for c in sweep.expert_calls(MANIFEST, key) if c.kind == kind)
+               for key in sweep.CELLS for kind in KINDS}
 
 
 def test_the_six_expert_cells_walk_five_shapes():
-    assert {key: (c.rows, c.groups, c.pieces) for key, c in CELL_SHAPES.items()} == {
+    assert {key: (c.rows, c.groups, c.pieces) for (key, _), c in CELL_SHAPES.items()} == {
         "mellum2": (65536, 16, 1), "nemotronh": (49152, 8, 1), "olmoe": (65536, 64, 4),
         "qwen3next": (8192, 32, 1), "glm47flash": (32768, 8, 1), "lfm2": (32768, 8, 1)}
 
 
 @pytest.mark.parametrize("how", ["even", "lean"])
+@pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("key", sorted(sweep.CELLS))
-def test_the_walk_at_a_cell_s_rows_groups_and_row_tile(key, how):
-    call = CELL_SHAPES[key]
+def test_the_walk_at_a_cell_s_rows_groups_and_row_tile(key, kind, how):
+    """At the row tile ``_tiles`` picks the kind since PR 57 (128, 256 or 512 by the rows a group holds)."""
+    call = CELL_SHAPES[key, kind]
     model = MANIFEST.config(MANIFEST.cell(sweep.CELLS[key])["config"])["model"]
     sizes = sweep.group_sizes(call, model, how, np.random.default_rng(56))
-    tm = _tiles(call.rows, call.K, call.N)[0]
-    assert tm == 512 and len(sizes) == call.groups and sizes.sum() <= call.rows
+    tm = _tiles(call.kind, call.rows, call.groups, call.K, call.N)[0]
+    assert tm in (128, 256, 512) and call.rows % tm == 0 and len(sizes) == call.groups and sizes.sum() <= call.rows
     a_piece = call.groups // call.pieces
     walked = 0
     for piece in range(call.pieces):        # the experts of four chips arrive in four pieces

@@ -68,6 +68,39 @@ def test_a_chain_of_four_pieces_fills_one_buffer(transpose_rhs, tk):
     close(out, jax.lax.ragged_dot(lhs, rhs, sizes))
 
 
+# twelve groups that arrive three at a time, 1,024 rows: one group EMPTY, and a boundary inside every
+# row tile of 128 (so of 256 too): 70 | 160 | 260 370 | 450 | 545 | 650 710 | 830 | 920
+PIECES_SIZES = (70, 90, 0, 100, 110, 80, 95, 105, 60, 120, 90, 104)
+
+
+@pytest.mark.parametrize("tm", [128, 256])
+@pytest.mark.parametrize("kind", ["gmm", "gmm_t", "tgmm"])
+def test_the_row_tiles_the_rule_picks_from_walk_a_chain_of_four_pieces(kind, tm):
+    """OLMoE's chain at the row tiles ``moe._tiles`` picks for it since PR 57 (``moe.GMM_ROW_TILES``
+    under 512): the rows' products written piece by piece, in arrival order, into a buffer of NaNs
+    (a row no call wrote would stay one), the weights' cotangent a piece at a time."""
+    assert {128, 256} < set(moe.GMM_ROW_TILES)
+    rows, K, N, a_piece = 1024, 256, 128, 3
+    rng = np.random.default_rng(4)
+    lhs, rhs, grad = (jnp.asarray(rng.normal(size=shape), jnp.float32) for shape in ((rows, K), (12, K, N), (rows, N)))
+    sizes = jnp.asarray(PIECES_SIZES, jnp.int32)
+    ends = np.cumsum(PIECES_SIZES)
+    assert ends[-1] == rows and all(np.any((ends > t) & (ends < t + 128)) for t in range(0, rows, 128))
+    if kind == "tgmm":
+        want, = jax.linear_transpose(lambda r: jax.lax.ragged_dot(lhs, r, sizes), rhs)(grad)
+        for i in (2, 0, 3, 1):
+            got = grouped.tgmm(lhs, grad, sizes, jnp.float32, (tm, K, N), jnp.int32(a_piece * i), a_piece, interpret=True)
+            close(got, want[a_piece * i:a_piece * (i + 1)])
+        assert not np.any(np.asarray(grouped.tgmm(lhs, grad, sizes, jnp.float32, (tm, K, N), interpret=True)[2]))
+        return
+    out = jnp.full((rows, N), jnp.nan, jnp.float32)
+    for i in (2, 0, 3, 1):                         # in the order they arrive, not the groups'
+        piece = rhs[a_piece * i:a_piece * (i + 1)]
+        out = grouped.gmm(lhs, piece.swapaxes(1, 2) if kind == "gmm_t" else piece, sizes, jnp.float32, (tm, K, N),
+                          jnp.int32(a_piece * i), out, transpose_rhs=kind == "gmm_t", interpret=True)
+    close(out, jax.lax.ragged_dot(lhs, rhs, sizes))
+
+
 @pytest.mark.parametrize("tk", [256, 128], ids=["whole-k", "cut-k"])
 def test_a_held_range_leaves_the_rows_outside_every_group_untouched(tk):
     """Two of five groups held, the rows before and after them the existing output's, the rows
@@ -129,12 +162,19 @@ def test_a_traced_program_leaves_how_each_grouped_product_runs(monkeypatch, K, h
     assert rec.counters(engine) == {}
     with rec.span("train.grad_program", engine=engine, program="loss_and_grad"):
         jax.make_jaxpr(lambda *args: products(*args))(rows, weights, wide, sizes)      # a trace of its own
-    tiles, other = ("%dx%dx%d" % moe._tiles(1024, *widths) for widths in ((K, 1856), (1856, K)))
-    assert (moe._tiles(1024, K, 1856)[1] == K) == (how == "whole_k")
+    def said(kind, *widths):
+        """What the rule saw and what it bought: 128 rows a group here, and the most (group, row tile)
+        pairs the walk can take over the row tiles the 1,024 rows need."""
+        tm, tk, tn = moe._tiles(kind, 1024, 8, *widths)
+        return f"{widths[0]}x{widths[1]} in {tm}x{tk}x{tn}, 128 rows a group, visits <= {(1024 // tm + 7) / (1024 // tm):.2f}"
+
+    assert (moe._tiles("gmm", 1024, 8, K, 1856)[1] == K) == (how == "whole_k")
     # the rows' cotangent contracts the OTHER width, 1,856, which stays whole either way
-    assert rec.counters(engine) == {f"moe.gmm.{how}[loss_and_grad] {K}x1856 in {tiles}": 1,
-                                    f"moe.gmm_t.whole_k[loss_and_grad] 1856x{K} in {other}": 1,
-                                    f"moe.tgmm.{how}[loss_and_grad] {K}x1856 in {tiles}": 1}
+    assert rec.counters(engine) == {f"moe.gmm.{how}[loss_and_grad] " + said("gmm", K, 1856): 1,
+                                    "moe.gmm_t.whole_k[loss_and_grad] " + said("gmm_t", 1856, K): 1,
+                                    f"moe.tgmm.{how}[loss_and_grad] " + said("tgmm", K, 1856): 1}
+    # a whole contraction beside eight short groups takes the smallest row tile, a cut one keeps 512
+    assert f" in {128 if how == 'whole_k' else 512}x" in next(name for name in rec.counters(engine) if ".tgmm." in name)
     whole = sum(n for name, n in rec.counters(engine).items() if ".whole_k[loss_and_grad]" in name)
     assert whole == (3 if how == "whole_k" else 1)
 

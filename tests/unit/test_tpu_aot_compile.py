@@ -313,6 +313,11 @@ def expert_cells_gradient_program(chip, monkeypatch, build_model, config):
         compiled = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile()
     products = {name: n for name, n in spans.recorder().counters(call.engine).items() if name.startswith("moe.")}
     assert products and all(".whole_k[loss_and_grad] " in name for name in products), products     # no contraction is cut
+    # and each says what its row tile saw and bought: the rows a group holds, the walk's most visits over those needed
+    said = [re.fullmatch(r"moe\.\w+\.whole_k\[loss_and_grad\] \d+x\d+ in (\d+)x\d+x\d+, (\d+) rows a group, visits <= (\d\.\d\d)", name)
+            for name in products]
+    assert all(said), products
+    assert all(int(m[1]) in (128, 256, 512) and 1.0 <= float(m[3]) <= 1.0 + 512 / int(m[2]) for m in said), products
     return compiled, model, shapes
 
 
@@ -409,7 +414,8 @@ def test_the_expert_cells_grouped_products_compile_for_v5e_at_the_tiles_picked(c
     shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=chip)      # noqa: E731
     assert len(calls) == 2
     for call in calls:
-        tiles = moe._tiles(call.rows, call.K, call.N)
+        tiles = moe._tiles(call.kind, call.rows, call.groups, call.K, call.N)
+        assert tiles[0] in moe.GMM_ROW_TILES and call.rows % tiles[0] == 0
         held, sizes = call.groups // call.pieces, shape(call.groups, dt=jnp.int32)
         first = jnp.int32(held) if call.pieces > 1 else None
         if call.kind == "tgmm":
@@ -583,7 +589,10 @@ def test_the_stand_in_experts_products_compile_for_v5e_at_widths_the_tiles_divid
     sort alone compiles in 8 s and more)."""
     from deepspeed_tpu.parallel import moe
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the grouped matmul's kernel
-    assert moe._tiles(65536, 2304, 1792) == (512, 2304, 1792) and moe._tiles(65536, 896, 2304) == (512, 896, 2304)
+    for kind, widths in (("gmm", (2304, 1792)), ("gmm", (896, 2304)), ("gmm_t", (1792, 2304)), ("gmm_t", (2304, 896)),
+                         ("tgmm", (2304, 1792)), ("tgmm", (896, 2304))):
+        tiles = moe._tiles(kind, 65536, 16, *widths)         # 4,096 rows a group: no row tile of 512 since PR 57
+        assert tiles[1:] == widths and tiles[0] in (128, 256), (kind, tiles)
 
     def loss(xs, w_gate_up, w_down, sizes):
         gate_up = moe.experts_matmul(xs, (w_gate_up,), (None,), sizes)
