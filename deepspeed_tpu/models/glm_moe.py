@@ -30,9 +30,13 @@ position has no ``t_{i+2}`` and is left out of L_2). The selection bias ``b`` is
 model names it to the engine as a leaf updated by a rule of its own (``rule_updated_leaves``,
 ``rule_sums``, ``apply_rule``, as ``models/nemotron_h.py``). The rotary turn pairs feature ``i``
 with ``i + rope / 2`` (``layers.rope``); the family's code pairs neighbours, which with seeded
-weights is a fixed permutation of W_qb's and W_kva's rotary columns. Not here: ``rope_scaling``,
-``n_group > 1`` (the group-limited choice), more than one prediction depth, attention biases,
-value heads narrower than the query's (all refused), the latent cache and the absorbed
+weights is a fixed permutation of W_qb's and W_kva's rotary columns. The values may be narrower
+than the keys (``v_head_dim`` beside ``qk_nope + qk_rope``: the flash kernel takes both widths
+since PR 58), and ``attention`` takes a rotary table and a softmax scale from a caller that has
+them (``models/xing_moe.py``: YaRN). Not here, all refused by ``from_published``: a published
+``rope_scaling`` (this model builds no table of its own: ``glm4_moe_lite`` publishes null, and a
+scaled table needs the family's own reading of ``mscale``), ``n_group > 1`` (the group-limited
+choice), more than one prediction depth, attention biases; nor the latent cache and the absorbed
 projections of the served path, dropout. Packed documents are not masked at their boundaries.
 
 The model follows the repo's convention (``init(rng) -> params``, ``apply(params, tokens[,
@@ -93,7 +97,8 @@ class GlmMoeConfig:
     def from_published(cls, keys, **more):
         """From the keys of the model's ``config.json``; keys that say nothing this model
         could do otherwise are checked, not stored."""
-        assert keys.get("rope_scaling") is None, f"rope_scaling {keys['rope_scaling']}: only null is built"
+        assert keys.get("rope_scaling") is None, \
+            f"rope_scaling {keys['rope_scaling']}: this model builds no scaled table (hand ``attention`` one)"
         assert keys.get("n_group", 1) == 1 and keys.get("topk_group", 1) == 1, \
             "n_group > 1: the group-limited choice is not built"
         assert keys.get("num_nextn_predict_layers", 1) == 1, \
@@ -107,10 +112,7 @@ class GlmMoeConfig:
         heads = keys.get("num_attention_heads", cls.num_attention_heads)
         assert keys.get("num_key_value_heads", heads) == heads, "as many key/value heads as query heads"
         stored = {k: v for k, v in keys.items() if k in cls.__dataclass_fields__}
-        c = cls(**dict(stored, **more))
-        assert c.qk_nope_head_dim + c.qk_rope_head_dim == c.v_head_dim, \
-            "v_head_dim: the flash kernel takes values as wide as the keys"
-        return c
+        return cls(**dict(stored, **more))
 
     @property
     def qk_head_dim(self):
@@ -201,14 +203,21 @@ class GlmMoeModel:
     def _norm(self, x, w):
         return rms_norm(x, w, self.config.rms_norm_eps)
 
-    def attention(self, x, ap):
-        """The latent attention on the normed block input ``x [B, T, H]``."""
+    def attention(self, x, ap, rotary=None, sm_scale=None):
+        """The latent attention on the normed block input ``x [B, T, H]``; the values are
+        ``v_head_dim`` wide beside keys of ``qk_nope + qk_rope`` (the kernel takes two widths).
+        ``rotary`` is a table of ``layers.rope_frequencies`` ``(inv_freq, factor)`` in the place of
+        ``rope_theta``'s, ``sm_scale`` the softmax's scale in the place of ``1 / sqrt(nope + rope)``
+        (``models/xing_moe.py`` hands both: YaRN's frequencies and its ``m^2``)."""
         from ..ops.pallas.flash_attention import flash_attention
         c = self.config
         B, T, _ = x.shape
         n, nope, turned, R = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
         heads = lambda a: a.transpose(0, 2, 1, 3)      # noqa: E731
-        turn = lambda a: rope(a, jnp.arange(T), c.rope_theta)      # noqa: E731
+        if rotary is None:
+            turn = lambda a: rope(a, jnp.arange(T), c.rope_theta)      # noqa: E731
+        else:
+            turn = lambda a: rope(a, jnp.arange(T), c.rope_theta, inv_freq=rotary[0], factor=rotary[1])      # noqa: E731
         with jax.named_scope(SCOPE):
             x = checkpoint_name(x, "ds_dot:qkv")      # the remat policies classify dots by tag
             c_q = self._norm(_dot(x, ap["wq_a"]).astype(x.dtype), ap["q_norm"])
@@ -222,7 +231,7 @@ class GlmMoeModel:
             k_rope = jnp.broadcast_to(turn(k_rope[:, None]), (B, n, T, turned))
             q = jnp.concatenate([q_nope, turn(q_rope)], axis=-1)
             k = jnp.concatenate([k_nope, k_rope], axis=-1)
-            y = flash_attention(q, k, v, True)          # the scale is 1 / sqrt(nope + rope)
+            y = flash_attention(q, k, v, True, sm_scale)          # None: 1 / sqrt(nope + rope)
             y = checkpoint_name(heads(y).reshape(B, T, n * c.v_head_dim), "ds_dot:proj")
             return _dot(y, ap["wo"]).astype(x.dtype)
 

@@ -26,6 +26,10 @@ Design (v5e; measured rows in ``_resolve`` and PERF.md, PR 25):
   ``band_pairs`` counts what the schedule visits against what the mask allows.
 - each kernel computes its VMEM budget from T, D and the tile sizes (``_vmem_limit``);
   head_dim <= 256.
+- the values may be narrower (or wider) than the queries and keys (latent attention at 192 | 128):
+  ``v``, ``out``, ``dO`` and ``dV`` are ``v.shape[-1]`` wide, ``q``, ``k``, ``dQ`` and ``dK``
+  ``q.shape[-1]``; the width is a static property of the shapes, and a call at equal widths is the
+  program it was before the kernels took two.
 - ``interpret=True`` fallback keeps CPU tests honest; a dense reference implementation
   (``dense_attention``) is the numerics oracle.
 """
@@ -308,7 +312,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
 
     m0 = jnp.full((1, bq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
-    acc0 = jnp.zeros((d, bq), jnp.float32)
+    acc0 = jnp.zeros((v_ref.shape[-1], bq), jnp.float32)
 
     def make_body(masked):
         def body(kb, carry):
@@ -332,7 +336,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_k, seq_len, has_bias, rate, thres
                 bits = _dropout_bits(seed_u32, bh_u32, q_glob, k_glob)
                 p = p * ((bits >= jnp.uint32(threshold)).astype(jnp.float32) * inv_keep)
             pv = jax.lax.dot_general(v_ref[keys, :], p.astype(v_ref.dtype), _TN,
-                                     preferred_element_type=jnp.float32)     # [d, bq]
+                                     preferred_element_type=jnp.float32)     # [dv, bq]
             return m_new, l_new, acc * alpha + pv
         return body
 
@@ -426,20 +430,22 @@ def _padded(rows, D, itemsize):
     return rows * (-(-D // 128) * 128) * itemsize
 
 
-def _fwd_vmem_bytes(T, D, block_q, block_k, itemsize):
+def _fwd_vmem_bytes(T, D, Dv, block_q, block_k, itemsize):
     """K and V whole (two pipeline buffers each), the q and out tiles and the lse row,
-    and four float32 tiles of working set (s, p and the mask/dropout temporaries)."""
-    return (2 * 2 * _padded(T, D, itemsize) + 2 * 2 * _padded(block_q, D, itemsize)
+    and four float32 tiles of working set (s, p and the mask/dropout temporaries); K and q
+    are ``D`` wide, V and out ``Dv``."""
+    return (2 * (_padded(T, D, itemsize) + _padded(T, Dv, itemsize))
+            + 2 * (_padded(block_q, D, itemsize) + _padded(block_q, Dv, itemsize))
             + 2 * 8 * block_q * 4 + 4 * block_q * block_k * 4)
 
 
-def _bwd_vmem_bytes(T, D, block_q, block_k, itemsize):
+def _bwd_vmem_bytes(T, D, Dv, block_q, block_k, itemsize):
     """q and dO whole (two pipeline buffers each), the lse and delta rows (a [1, T]
     float32 block pads to 8 sublanes), the k/v/dk/dv tiles, the dq block and its
     float32 accumulator [D, T], and six float32 tiles of working set (s, p, dp, ds and
-    the mask/dropout temporaries)."""
-    return (2 * 2 * _padded(T, D, itemsize) + 2 * 2 * 8 * T * 4
-            + 4 * 2 * _padded(block_k, D, itemsize)
+    the mask/dropout temporaries); q, k, dq and dk are ``D`` wide, v, dO and dv ``Dv``."""
+    return (2 * (_padded(T, D, itemsize) + _padded(T, Dv, itemsize)) + 2 * 2 * 8 * T * 4
+            + 2 * 2 * (_padded(block_k, D, itemsize) + _padded(block_k, Dv, itemsize))
             + 2 * _padded(T, D, itemsize) + max(D, 8) * T * 4
             + 6 * block_q * block_k * 4)
 
@@ -464,11 +470,12 @@ def _kv_head(group):
 def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, block_k,
                      interpret, window=None):
     B, H, T, D = q.shape
+    Dv = v.shape[-1]                 # the values' width, the output's too
     group = H // k.shape[1]          # query heads a key/value head serves, side by side
     grid = (B * H, pl.cdiv(T, block_q))
     q3 = q.reshape(B * H, T, D)
     k3 = k.reshape(B * H // group, T, D)
-    v3 = v.reshape(B * H // group, T, D)
+    v3 = v.reshape(B * H // group, T, Dv)
 
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_k=block_k, seq_len=T, has_bias=bias is not None,
@@ -482,28 +489,28 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
         in_specs=aux_specs + [
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, T, D), _kv_head(group)),
-            pl.BlockSpec((None, T, D), _kv_head(group)),
+            pl.BlockSpec((None, T, Dv), _kv_head(group)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             # LSE carried as [B*H, 1, T]: TPU block shapes need the trailing two dims
             # tileable, so the per-row scalar rides in a (1, block_q) lane layout
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=_vmem_limit(_fwd_vmem_bytes(T, D, block_q, block_k,
+            vmem_limit_bytes=_vmem_limit(_fwd_vmem_bytes(T, D, Dv, block_q, block_k,
                                                          q.dtype.itemsize))),
         interpret=interpret,
         name="ds_flash_fwd",
     )
     with jax.named_scope("ds_flash_fwd"):
         out, lse = call(*aux, q3, k3, v3)
-    return out.reshape(B, H, T, D), lse.reshape(B, H, T)
+    return out.reshape(B, H, T, Dv), lse.reshape(B, H, T)
 
 
 # ---------------------------------------------------------------------------
@@ -585,7 +592,7 @@ def _bwd_kernel(*refs, sm_scale, causal, block_q, seq_len, has_bias, rate, thres
             return dk, dv
         return body
 
-    init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32))
+    init = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, v_ref.shape[-1]), jnp.float32))
     if window is not None:
         dk, dv = init
         for (lo, hi), masked in band_q_loops(k_blk_idx, block_q, bk, window, num_q_blocks):
@@ -626,11 +633,12 @@ def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, int
 def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, rate,
                      block_q, block_k, interpret, window=None):
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     group = H // k.shape[1]
     q3 = q.reshape(B * H, T, D)
     k3 = k.reshape(B * H // group, T, D)
-    v3 = v.reshape(B * H // group, T, D)
-    do3 = do.reshape(B * H, T, D)
+    v3 = v.reshape(B * H // group, T, Dv)
+    do3 = do.reshape(B * H, T, Dv)
     lse3 = lse.reshape(B * H, 1, T)
     delta3 = delta.reshape(B * H, 1, T)
 
@@ -638,11 +646,12 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     aux, aux_specs = _aux_operands(
         seed, bias, B, H, T, rate,
         block_k_map=(block_k, lambda b, j, H=H: (b // H, 0, j)))
-    whole = pl.BlockSpec((None, T, D), lambda b, j: (b, 0, 0))
     row = pl.BlockSpec((None, 1, T), lambda b, j: (b, 0, 0))
-    tile = pl.BlockSpec((None, block_k, D), lambda b, j: (b, j, 0))
-    kv_tile = tile if group == 1 else pl.BlockSpec((None, block_k, D),
-                                                   lambda b, j: (b // group, j, 0))
+    # q, k, dq and dk are D wide; v, dO and dv as wide as the values
+    whole = lambda width: pl.BlockSpec((None, T, width), lambda b, j: (b, 0, 0))              # noqa: E731
+    tile = lambda width: pl.BlockSpec((None, block_k, width), lambda b, j: (b, j, 0))         # noqa: E731
+    kv_tile = tile if group == 1 else lambda width: pl.BlockSpec(                             # noqa: E731
+        (None, block_k, width), lambda b, j: (b // group, j, 0))
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, seq_len=T, has_bias=bias is not None,
@@ -650,13 +659,13 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
                           has_seed=seed is not None, seg=_is_segmented(seed),
                           window=window),
         grid=(B * H, T // block_k),
-        in_specs=aux_specs + [whole, kv_tile, kv_tile, whole, row, row],
-        out_specs=[whole, tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((B * H, T, D), q.dtype)] * 3,
+        in_specs=aux_specs + [whole(D), kv_tile(D), kv_tile(Dv), whole(Dv), row, row],
+        out_specs=[whole(D), tile(D), tile(Dv)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, width), q.dtype) for width in (D, D, Dv)],
         scratch_shapes=[pltpu.VMEM((D, T), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(_bwd_vmem_bytes(T, D, block_q, block_k,
+            vmem_limit_bytes=_vmem_limit(_bwd_vmem_bytes(T, D, Dv, block_q, block_k,
                                                          q.dtype.itemsize))),
         interpret=interpret,
         name="ds_flash_bwd_dkv",
@@ -664,7 +673,7 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
     with jax.named_scope("ds_flash_bwd_dkv"):
         dq, dk, dv = call(*aux, q3, k3, v3, do3, lse3, delta3)
         if group > 1:      # a query head's dK and dV each: summed over the group in float32
-            dk, dv = (jnp.sum(a.reshape(B, H // group, group, T, D).astype(jnp.float32),
+            dk, dv = (jnp.sum(a.reshape(B, H // group, group, T, a.shape[-1]).astype(jnp.float32),
                               axis=2).astype(k.dtype) for a in (dk, dv))
     return dq.reshape(B, H, T, D), dk.reshape(k.shape), dv.reshape(v.shape)
 

@@ -83,6 +83,7 @@ _SECONDS = {"test_tpu_aot_compile.py": 800, "test_rehearsal_hybrid.py": 395, "te
             "test_launcher.py": 235, "test_moe.py": 210, "test_flash_attention.py": 195,
             "test_ring_zigzag.py": 165, "test_rehearsal_swa_moe.py": 165, "test_rehearsal_mla_moe.py": 160,
             "test_ouro.py": 160, "run_func_test.py": 150, "test_causal_conv_kernel.py": 130,
+            "test_rehearsal_hc_moe.py": 140, "test_xing_moe.py": 125,
             "test_glm_moe.py": 125, "test_rehearsal_conv_moe.py": 125, "test_lfm2_moe.py": 110, "test_rehearsal.py": 120, "test_rehearsal_loop.py": 110,
             "test_rehearsal_moe.py": 110, "test_mellum.py": 105, "test_ssd.py": 95, "test_ssd_kernel.py": 95,
             "run_checkpoint_test.py": 90, "test_transformer_layer.py": 75, "test_delta_rule_kernel.py": 75,

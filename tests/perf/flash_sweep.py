@@ -1,7 +1,7 @@
 """Flash-attention tile sweep on the chip: device milliseconds a call of each kernel,
 forward and backward apart, read from a profiler trace by the kernels' own names.
 
-    python tests/perf/flash_sweep.py [--rows cell,long,other,band,mla] [--picked] [--out chiprun_out/flash_sweep.jsonl]
+    python tests/perf/flash_sweep.py [--rows cell,long,other,band,mla,latent] [--picked] [--out chiprun_out/flash_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent
 unpacked beside this one) it measures that tree's kernels with the same rows:
@@ -12,8 +12,9 @@ A row is a shape [B, H, T, D] in bf16, causal or not, and a list of (block_q, bl
 ``None`` is what ``_resolve`` picks. The share of the roofline is the required
 operations (4.B.H.T^2.D a forward, half of it causal; twice that a backward, as
 ``benchmarks/flops.py`` counts) over 197 TF/s over the measured time. A row may add
-``(key/value heads, window)``: grouped heads, and a sliding window, whose required
-operations are the pairs inside the band (``band_pairs``'s ``needed``).
+``(key/value heads, window, value width)``: grouped heads, a sliding window, whose required
+operations are the pairs inside the band (``band_pairs``'s ``needed``), and values of another
+width than the keys' (the required operations are then 2.B.H.T^2.(D + Dv) a forward).
 """
 
 import argparse
@@ -55,6 +56,10 @@ ROWS = {
     # a latent-attention block of glm47flash_ep8_d5_train_1chip: 20 query over 20 key/value heads
     # of 192 + 64 | 256 at 8192 positions, six calls a step
     "mla": [((1, 20, 8192, 256), True, [None] + SQUARE[1:] + [(1024, 1024), (512, 1024), (1024, 512)])],
+    # a latent-attention block of xing4_ep8_d5_train_1chip: 32 heads, keys of 128 + 64 beside values
+    # of 128, at 4096 positions, five calls a step; and the same call with the values filled up to 192
+    "latent": [((1, 32, 4096, 192), True, [None] + SQUARE[1:] + [(1024, 1024)], None, None, 128),
+               ((1, 32, 4096, 192), True, [None], None, None, None)],
 }
 
 
@@ -96,18 +101,19 @@ def kernel_ms(fn, args, calls=8):
 
 
 def sweep_row(shape, causal, tiles, emit, tag="", passes=("fwd", "bwd"), kv_heads=None,
-              window=None):
+              window=None, v_width=None):
     B, H, T, D = shape
+    Dv = v_width or D
     rng = np.random.default_rng(0)
     kv_shape = (B, kv_heads or H, T, D)
-    q, do = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16) for _ in range(2))
-    k, v = (jnp.asarray(rng.normal(size=kv_shape), jnp.bfloat16) for _ in range(2))
-    need = 4.0 * B * H * T * T * D * (0.5 if causal else 1.0)
+    q, k = (jnp.asarray(rng.normal(size=s), jnp.bfloat16) for s in (shape, kv_shape))
+    v, do = (jnp.asarray(rng.normal(size=s[:-1] + (Dv,)), jnp.bfloat16) for s in (kv_shape, shape))
+    need = 2.0 * B * H * T * T * (D + Dv) * (0.5 if causal else 1.0)
     if window is not None:
         need = 4.0 * B * H * D * fa.band_pairs(T, T, T, window)[1]
     for tile in tiles:
         sm_scale, bq, bk, _ = fa._resolve(q, None, *(tile or (None, None)), causal, False, window)
-        common = dict(shape=list(shape), causal=causal, block_q=bq, block_k=bk,
+        common = dict(shape=list(shape), causal=causal, block_q=bq, block_k=bk, v_width=Dv,
                       picked=tile is None, tag=tag, kv_heads=kv_shape[1], window=window)
         try:
             fwd = lambda q, k, v: fa._flash_fwd(q, k, v, None, None, sm_scale, causal, 0.0,
@@ -149,7 +155,7 @@ def main():
                 print(f"{rec['shape']} causal={int(rec['causal'])} bq={rec['block_q']} "
                       f"bk={rec['block_k']}: {rec['error']}", flush=True)
                 return
-            print(f"{rec['shape']} kv={rec['kv_heads']} w={rec['window']} causal={int(rec['causal'])} {rec['pass_']} "
+            print(f"{rec['shape']} | {rec['v_width']} kv={rec['kv_heads']} w={rec['window']} causal={int(rec['causal'])} {rec['pass_']} "
                   f"bq={rec['block_q']:5d} bk={rec['block_k']:5d}{' *' if rec['picked'] else '  '} "
                   f"{rec['ms']:8.4f} ms {rec['roofline']:6.2f} % {rec['tag']} "
                   + " ".join(f"{n}={t:.4f}" for n, t in sorted(rec["kernels"].items())),
@@ -157,7 +163,7 @@ def main():
         for name in args.rows.split(","):
             for shape, causal, tiles, *more in ROWS[name]:
                 sweep_row(shape, causal, [None] if args.picked else tiles, emit,
-                          **dict(zip(("kv_heads", "window"), more)))
+                          **dict(zip(("kv_heads", "window", "v_width"), more)))
 
 
 if __name__ == "__main__":

@@ -1,6 +1,7 @@
 """One hybrid cell with another kept set than the model's, for the choice of what a recomputed
 layer keeps (``granite_hybrid.KEPT_BY_A_BLOCK``, ``nemotron_h.KEPT_BY_A_LAYER``,
-``mellum.KEPT_BY_A_LAYER``, ``glm_moe.KEPT_BY_A_LAYER``, ``lfm2_moe.KEPT_BY_A_LAYER``; PERF.md, PR 41, PR 45, PR 48, PR 52).
+``mellum.KEPT_BY_A_LAYER``, ``glm_moe.KEPT_BY_A_LAYER``, ``lfm2_moe.KEPT_BY_A_LAYER``,
+``xing_moe.KEPT_BY_A_LAYER``; PERF.md, PR 41, PR 45, PR 48, PR 52, PR 58).
 
     chiprun --timeout 1800 -- python tests/perf/kept_sets.py --workload granite4h_d10_train_1chip \
         --keep attn_out,attn_lse,mixer_out --seed 4100000101 --seconds 40 --trace 0
@@ -21,7 +22,8 @@ CONSTANTS = {"granite4h_d10_train_1chip": ("deepspeed_tpu.models.granite_hybrid"
              "nemotronh_ep16_d9_train_1chip": ("deepspeed_tpu.models.nemotron_h", "KEPT_BY_A_LAYER"),
              "mellum2_ep4_d4_train_1chip": ("deepspeed_tpu.models.mellum", "KEPT_BY_A_LAYER"),
              "glm47flash_ep8_d5_train_1chip": ("deepspeed_tpu.models.glm_moe", "KEPT_BY_A_LAYER"),
-             "lfm2_ep8_d7_train_1chip": ("deepspeed_tpu.models.lfm2_moe", "KEPT_BY_A_LAYER")}
+             "lfm2_ep8_d7_train_1chip": ("deepspeed_tpu.models.lfm2_moe", "KEPT_BY_A_LAYER"),
+             "xing4_ep8_d5_train_1chip": ("deepspeed_tpu.models.xing_moe", "KEPT_BY_A_LAYER")}
 
 
 def main():

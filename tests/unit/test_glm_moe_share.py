@@ -64,13 +64,24 @@ def test_the_eight_held_ranges_add_up_to_the_uncut_layer():
     ({"n_group": 4, "topk_group": 2}, "n_group"),
     ({"num_nextn_predict_layers": 2}, "num_nextn_predict_layers"),
     ({"attention_bias": True}, "attention_bias"),
-    ({"v_head_dim": 8}, "v_head_dim"),
     ({"num_key_value_heads": 2}, "key/value heads"),
     ({"tie_word_embeddings": True}, "head"),
     ({"partial_rotary_factor": 0.5}, "rotary")])
 def test_from_published_refuses_what_is_not_built(change, names):
     with pytest.raises(AssertionError, match=names):
         GlmMoeConfig.from_published(published(**change))
+
+
+def test_value_heads_narrower_than_the_keys_are_built_and_match_the_reference():
+    """``v_head_dim`` 8 beside keys of 12 + 4 (refused until the flash kernel took two widths, PR 58):
+    the toy's latent mixer against the reference's, and the shapes that follow the values' width."""
+    keys = published(v_head_dim=8)
+    model = GlmMoeModel(GlmMoeConfig.from_published(keys, compute_dtype=jnp.float32, initializer_range=0.1))
+    ap = model.init(jax.random.PRNGKey(0))["layers"][0]["attn"]
+    assert ap["wkv_b"].shape == (12, 4 * (12 + 8)) and ap["wo"].shape == (4 * 8, 32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(jax.jit(model.attention)(x, ap), ref.attention(x, ap, keys), atol=2e-5)
 
 
 def test_from_published_reads_the_catalogs_row(row):

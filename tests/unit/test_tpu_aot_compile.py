@@ -74,6 +74,20 @@ def test_flash_attention_compiles_for_v5e(chip, shape, backward):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_at_keys_wider_than_the_values_compiles_for_v5e(chip, backward):
+    """Xing4.0's latent attention as ``xing4_ep8_d5_train_1chip`` calls it: 32 heads, keys of
+    128 + 64 beside values of 128, at 4,096 positions (192 is no multiple of the 128 lanes, and
+    the kernels' blocks take two widths since PR 58); nothing is padded outside the kernel."""
+    def attn(q, k, v):
+        return flash_attention(q, k, v, True, 0.15, interpret=False)
+
+    qk = jax.ShapeDtypeStruct((1, 32, 4096, 192), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((1, 32, 4096, 128), jnp.bfloat16, sharding=chip)
+    text = compiled_text(sumsq_grad(attn) if backward else attn, qk, qk, v)
+    assert "tpu_custom_call" in text and "bf16[32,4096,128]" in text and "pad(" not in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
 def test_grouped_query_flash_attention_compiles_for_v5e(chip, backward):
     """Qwen3-Next's full-attention layer as ``qwen3next_ep16_train_1chip`` calls it: 16 query
     heads of 256 over 2 key/value heads at 8,192 positions, K and V tiles indexed by group."""
