@@ -135,9 +135,11 @@ class XingMoeConfig:
 # What a recomputed block keeps beside its input (the four streams), by name: ``glm_moe``'s set
 # (the flash kernel's output and row sums, the held experts' first grouped product's output) and
 # both sub-layers' projections onto the 24 coefficient columns (``hc_proj``: 2 x 24 float32 a token,
-# 0.8 MB a block at 4,096 tokens), with which the second forward runs no n C-deep product: 2.5 ms of
-# a 240 ms step on the chip; the three coefficient sets by name bought nothing (their chain is made
-# again for its own backward). Bytes and milliseconds either way: docs/xing4.0-29b-a4b.md.
+# 0.8 MB a block at 4,096 tokens), with which the second forward of the ``jnp`` form runs no n C-deep
+# product: 2.5 ms of a 240 ms step on the chip; the three coefficient sets by name bought nothing
+# (their chain is made again for its own backward). The hyper-connection's kernels name nothing (on
+# the TPU the second forward's ``ds_hc_read`` makes the product again under its tile's transfer).
+# Bytes and milliseconds either way: docs/xing4.0-29b-a4b.md.
 KEPT_BY_A_LAYER = jax.checkpoint_policies.save_only_these_names(
     "attn_out", "attn_lse", "ds_moe_gate_up", hc.KEPT_NAME)
 
@@ -182,13 +184,11 @@ class XingMoeModel(GlmMoeModel):
 
     def connected(self, x, hp, sub_layer):
         """One sub-layer inside its hyper-connection on the flat streams ``x``: ``(X', stats)``,
-        ``stats`` what ``sub_layer(u) -> (f, stats)`` said and ``H_res``'s two readings."""
-        with jax.named_scope(hc.SCOPE):
-            h_pre, h_post, h_res = self.coefficients(x, hp)
-            u = hc.read(x, h_pre)
-        f, stats = sub_layer(u)
-        with jax.named_scope(hc.SCOPE):
-            return hc.write(x, f, h_post, h_res), dict(stats, **hc.readings(h_res))
+        ``stats`` what ``sub_layer(u) -> (f, stats)`` said and ``H_res``'s two readings
+        (``hyper_connections.connected``: four Pallas kernels on the TPU, the ``jnp`` form elsewhere)."""
+        c = self.config
+        return hc.connected(x, hp, sub_layer, c.hc_mult, c.hc_sinkhorn_iters, c.hc_eps,
+                            (c.mhc_h_res_clamp_min, c.mhc_h_res_clamp_max), c.rms_norm_eps)
 
     def _block(self, x, lp, details=False):
         """One block on the flat streams: ``(X'', stats)``; ``stats`` holds both sub-layers'

@@ -128,3 +128,118 @@ def test_init_starts_near_a_plain_residual():
     np.testing.assert_allclose(h_pre, 0.5, atol=1e-2)
     np.testing.assert_allclose(h_post, 1.0, atol=2e-2)
     assert 0.94 < float(hc.readings(h_res)["hc_res_diag_mean"]) < 0.96
+
+
+# ------------------------------------------------------------- the Pallas kernels (PR 59), interpreted
+# ``ops/pallas/hyper_connection.py``: on the TPU ``hc.connected`` is four kernels; here they run in
+# ``interpret`` mode against the ``jnp`` form above, on one lane-aligned shape (the toy's is none).
+from deepspeed_tpu.ops.pallas import hyper_connection as kernels      # noqa: E402
+
+WIDE, TOKENS = 128, (2, 128)          # n = 4 streams of 128, 256 tokens: two tiles of 128
+LEAVES = ("norm", "phi_pre", "phi_post", "phi_res", "b_pre", "b_post", "b_res", "gates")
+READ = ("u", "coefficients", "streams", "f") + LEAVES        # what each kernel's case reads, by name
+LIMIT = {"float32": 1e-5, "bfloat16": 1e-2}                     # relative L2; bf16: the rounding of an output or a cotangent
+
+
+def wide_toy(dtype):
+    hp = hc.init(jax.random.PRNGKey(0), N, WIDE, 0.05)
+    k = jax.random.split(jax.random.PRNGKey(1), 8)
+    hp = dict(hp, norm=hp["norm"] + 0.1 * jax.random.normal(k[0], hp["norm"].shape),
+              b_pre=0.3 * jax.random.normal(k[1], (N,)), b_post=0.3 * jax.random.normal(k[2], (N,)),
+              b_res=hp["b_res"] + 0.5 * jax.random.normal(k[3], (N, N)), gates=jnp.asarray([0.4, -0.3, 0.5]))
+    draw = lambda key, width: jax.random.normal(key, TOKENS + (width,)).astype(dtype)       # noqa: E731
+    return hp, draw(k[4], N * WIDE), draw(k[5], WIDE), draw(k[6], N * WIDE), draw(k[7], WIDE)
+
+
+def everything(form, hp, x, f_in, cot, cot_u):
+    """``{name: value}`` of one connection round ``F(u) = sin(u) + f_in``: ``u``, ``X'``, the
+    readings, and the gradients of ``<X', cot> + <u, cot_u>`` by the streams, ``f`` and every leaf."""
+    f32 = lambda a: a.astype(jnp.float32)      # noqa: E731
+
+    def loss(hp, x, f_in):
+        out, stats = form(x, hp, lambda u: ((jnp.sin(f32(u)) + f32(f_in)).astype(u.dtype), {"u": u}))
+        return jnp.sum(f32(out) * f32(cot)) + jnp.sum(f32(stats["u"]) * f32(cot_u)), (out, stats)
+
+    (_, (out, stats)), (by_leaf, by_streams, by_f) = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(hp, x, f_in)
+    return dict(by_leaf, out=out, streams=by_streams, f=by_f, **stats)
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def both_forms(request):
+    """``(the streams' type, the jnp form's values, the interpreted kernels' values, the
+    coefficients [T, 128] of ds_hc_read alone)`` on the lane-aligned shape."""
+    hp, *arrays = wide_toy(jnp.dtype(request.param))
+    with jax.default_matmul_precision("highest"):
+        want = everything(lambda x, hp, F: hc.connected(x, hp, F, *ARGS), hp, *arrays)
+        got = everything(lambda x, hp, F: hc.connected_by_kernels(x, hp, F, *ARGS, tm=128, interpret=True), hp, *arrays)
+        x = arrays[0].reshape(-1, N * WIDE)
+        phi, gate_bias = hc._packed(hp, N)
+        g, cols = hc._operands(x, hp["norm"], gate_bias)
+        _, co, _ = kernels.read(x, g, phi.astype(x.dtype), cols, n=N, iters=20, eps=1e-6, clamp=(-30.0, 30.0),
+                                norm_eps=1e-6, tm=128, interpret=True)
+        want["coefficients"] = jnp.concatenate([jnp.moveaxis(h.reshape(-1, *TOKENS), 0, -1).reshape(x.shape[0], -1)
+                                                for h in hc.coefficients(arrays[0], hp, *ARGS)], axis=-1)
+        got["coefficients"] = co[:, np.asarray(kernels.columns(N))]
+    return request.param, want, got
+
+
+def apart(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("name", ("out",) + READ)
+def test_the_kernels_read_what_the_jnp_form_reads(both_forms, name):
+    """``ds_hc_read`` (``u``, the 24 coefficients), ``ds_hc_write`` (``X'``), ``ds_hc_write_bwd``
+    (the gradient by ``f``) and ``ds_hc_read_bwd`` (by the streams and every leaf, through all 20
+    rounds): float32 streams to 1e-5, bf16 streams to the rounding of an output."""
+    dtype, want, got = both_forms
+    assert got[name].shape == want[name].shape and got[name].dtype == want[name].dtype
+    assert float(np.linalg.norm(np.asarray(want[name], np.float64))) > 0
+    assert apart(got[name], want[name]) <= LIMIT[dtype], (name, apart(got[name], want[name]))
+
+
+def test_the_kernels_readings_are_the_jnp_forms(both_forms):
+    dtype, want, got = both_forms
+    for name in hc.READINGS:
+        assert float(got[name]) == pytest.approx(float(want[name]), rel=1e-5 if dtype == "float32" else 1e-3)
+
+
+def test_the_kernels_gradients_stop_a_round_short_are_other_gradients():
+    """All 20 rounds are pulled back: the kernels at 19 rounds give ``B_res`` another gradient."""
+    hp, *arrays = wide_toy(jnp.float32)
+    run = lambda iters: everything(lambda x, hp, F: hc.connected_by_kernels(      # noqa: E731
+        x, hp, F, N, iters, *ARGS[2:], tm=128, interpret=True), hp, *arrays)["b_res"]
+    with jax.default_matmul_precision("highest"):
+        full, short = run(20), run(3)
+    assert apart(short, full) > 1e-2
+
+
+@pytest.mark.parametrize("tokens, width, n, dtype, tile", [
+    (4096, 3584, 4, jnp.bfloat16, 256),       # xing4_ep8_d5_train_1chip's step
+    (1024, 3584, 4, jnp.bfloat16, 256),       # its set-up's sequences
+    (256, 128, 4, jnp.float32, 256), (384, 128, 4, jnp.float32, 128),
+    (12, 8, 4, jnp.float32, None),            # the toy: no whole register
+    (4096, 3584 + 64, 4, jnp.bfloat16, None), (4000, 3584, 4, jnp.bfloat16, None), (4096, 128, 9, jnp.bfloat16, None),
+    (4096, 8192, 4, jnp.float32, None)])      # four float32 streams of 8,192: no tile's blocks fit
+def test_the_tile_goes_by_the_shapes(tokens, width, n, dtype, tile):
+    assert kernels.tile(tokens, n, width, jnp.dtype(dtype).itemsize) == tile
+    if tile is not None:
+        assert all(kernels._limit(kind, tile, n, width, jnp.dtype(dtype).itemsize) < kernels.VMEM_CAP
+                   for kind in ("read", "write", "write_bwd", "read_bwd"))
+
+
+def test_shapes_the_kernels_do_not_take_fall_to_the_jnp_form(toy, highest, monkeypatch):
+    """Off the TPU, and on it at the toy's shapes (streams of 8), ``connected`` is the ``jnp`` form:
+    the same numbers as ``coefficients`` / ``read`` / ``write`` called one by one."""
+    hp, x = toy
+    F = lambda u: (jnp.sin(u), {})                             # noqa: E731
+    wide = jnp.zeros(TOKENS + (N * WIDE,), jnp.bfloat16)
+    assert hc.kernel_tile(x, N) is None and hc.kernel_tile(wide, N) is None       # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert hc.kernel_tile(x, N) is None and hc.kernel_tile(wide, N) == 256
+    assert hc.kernel_tile(wide[:, :100], N) is None                                # no whole tile of tokens
+    got, stats = jax.jit(lambda x: hc.connected(x, hp, F, *ARGS))(x)
+    np.testing.assert_array_equal(got, jax.jit(lambda x: connected(x, hp, lambda u: F(u)[0]))(x))
+    assert set(stats) == set(hc.READINGS)

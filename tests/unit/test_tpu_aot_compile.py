@@ -819,3 +819,68 @@ def test_the_four_chip_expert_cells_layers_fetch_no_kept_piece_twice_on_a_v5e(to
     # first layer's ``w_gate_up``, which no backward reads here (nothing asks for its input's gradient)
     assert len(fetched) == 12 and sorted(fetched.values()) == ([1] * 12 if kept else [1] * 3 + [2] * 9), fetched
     assert len(starts) == (24 if kept else 33)
+
+
+# ------------------------------------------------------------- the hyper-connection's kernels (PR 59)
+HC_KERNELS = ("ds_hc_read", "ds_hc_write", "ds_hc_write_bwd", "ds_hc_read_bwd")
+
+
+@pytest.mark.parametrize("tokens", [4096, 1024], ids=["a_step", "the_set_up"])
+@pytest.mark.parametrize("kernel", HC_KERNELS)
+def test_the_hyper_connections_kernels_compile_for_v5e(chip, kernel, tokens):
+    """``ops/pallas/hyper_connection.py`` at ``xing4_ep8_d5_train_1chip``'s shapes: four bf16 streams
+    of 3,584 (``[4096, 14336]`` a step, 1,024 tokens in the set-up's comparisons), 24 columns, 20
+    rounds, at the tile the rule picks."""
+    from deepspeed_tpu.ops.pallas import hyper_connection as hc
+    n, C = 4, 3584
+    tm = hc.tile(tokens, n, C, 2)
+    assert tm == 256
+    kw = dict(n=n, iters=20, eps=1e-6, clamp=(-30.0, 30.0), tm=tm)
+    a = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)      # noqa: E731
+    x, one, co = a((tokens, n * C)), a((tokens, C)), a((tokens, 128), jnp.float32)
+    g, cols = a((8, n * C), jnp.float32), a((2, 128, 128), jnp.float32)
+    fn, args = {
+        "ds_hc_read": (lambda *o: hc.read(*o, norm_eps=1e-6, **kw), (x, g, a((n * C, 128)), cols)),
+        "ds_hc_write": (lambda *o: hc.write(*o, n=n, tm=tm), (x, one, co)),
+        "ds_hc_write_bwd": (lambda *o: hc.write_bwd(*o, n=n, tm=tm), (x, x, one, co)),
+        "ds_hc_read_bwd": (lambda *o: hc.read_bwd(*o, **kw), (x, one, x, co, co, g, a((128, n * C)), cols)),
+    }[kernel]
+    text = compiled_text(fn, *args)
+    assert "tpu_custom_call" in text and re.search(rf"%{kernel}(\.\d+)? = ", text)
+    # the streams stay in HBM, the small program's too (PERF.md, PR 55): no operand of 4 x 3,584 columns in S(1)
+    assert not re.search(r"bf16\[\d+,14336\]\{[^}]*S\(1\)", text)
+
+
+def test_a_hyper_connected_block_pairs_gradient_program_runs_the_kernels_on_a_v5e(chip, monkeypatch):
+    """The gradient program of ``xing4_ep8_d5_train_1chip`` at its widths and 1 x 4,096 positions,
+    cut to one dense and one expert block, whole blocks recomputed: the mechanism's engagement
+    counter is the program's ``ds_hc_*`` custom calls by name. Four sub-layers: a ``ds_hc_read``
+    each in the forward and in the second forward, a ``ds_hc_write`` each in the forward and in
+    the second forward of a block's FIRST sub-layer alone (its last one's ``X'`` is read by nothing
+    there: dead, as the ``jnp`` form's was), one of each backward kernel; every one under ``ds_hc``
+    and one of its two parts; and no float32 copy of the four streams under ``ds_hc``."""
+    import collections
+    from benchmarks.manifest import Manifest
+    from benchmarks.runners.train_hc_moe import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # the kernels, not the jnp form
+    config = Manifest().config("xing4.0-29b-a4b-ep8-d5")
+    assert config["remat"] and config["model"]["first_k_dense_replace"] == 1
+    model = build_model(dict(config, model=dict(config["model"], num_hidden_layers=2)))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map_with_path(lambda path, s: jax.ShapeDtypeStruct(
+        s.shape, jnp.float32 if "router_bias" in jax.tree_util.keystr(path) else jnp.bfloat16, sharding=chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    text = jax.jit(jax.value_and_grad(lambda *a: model.apply(*a)[0])).lower(params, tokens, tokens).compile().as_text()
+    calls = [line for line in text.splitlines() if re.search(r"%ds_hc_[\w.]+ = .*custom-call\(", line)]
+    by_name = collections.Counter(re.search(r"%(ds_hc_\w+?)(\.\d+)? = ", line)[1] for line in calls)
+    assert by_name == {"ds_hc_read": 8, "ds_hc_write": 6, "ds_hc_write_bwd": 4, "ds_hc_read_bwd": 4}
+    part = {"ds_hc_read": "ds_hc_coef", "ds_hc_read_bwd": "ds_hc_coef", "ds_hc_write": "ds_hc_mix", "ds_hc_write_bwd": "ds_hc_mix"}
+    for line in calls:
+        name, path = re.search(r"%(ds_hc_\w+?)(\.\d+)? = ", line)[1], re.search(r'op_name="([^"]*)"', line)[1]
+        assert re.search(rf"ds_(attn|mlp)\)?/ds_hc/{part[name]}/", path), path
+        assert ("rematted_computation" in path) == (path.count("/checkpoint/") == 1 and "_bwd" not in name), path
+    second_forward = collections.Counter(re.search(r"%(ds_hc_\w+?)(\.\d+)? = ", line)[1]
+                                         for line in calls if "rematted_computation" in line)
+    assert second_forward == {"ds_hc_read": 4, "ds_hc_write": 2}
+    wide = [line for line in text.splitlines() if re.search(r"= f32\[(1,)?4096,14336\]", line) and "ds_hc" in line]
+    assert not wide, wide[:3]
