@@ -209,7 +209,7 @@ class GlmMoeModel:
         ``rotary`` is a table of ``layers.rope_frequencies`` ``(inv_freq, factor)`` in the place of
         ``rope_theta``'s, ``sm_scale`` the softmax's scale in the place of ``1 / sqrt(nope + rope)``
         (``models/xing_moe.py`` hands both: YaRN's frequencies and its ``m^2``)."""
-        from ..ops.pallas.flash_attention import flash_attention
+        from ..ops.pallas.flash_attention import flash_attention_rows
         c = self.config
         B, T, _ = x.shape
         n, nope, turned, R = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
@@ -231,8 +231,10 @@ class GlmMoeModel:
             k_rope = jnp.broadcast_to(turn(k_rope[:, None]), (B, n, T, turned))
             q = jnp.concatenate([q_nope, turn(q_rope)], axis=-1)
             k = jnp.concatenate([k_nope, k_rope], axis=-1)
-            y = flash_attention(q, k, v, True, sm_scale)          # None: 1 / sqrt(nope + rope)
-            y = checkpoint_name(heads(y).reshape(B, T, n * c.v_head_dim), "ds_dot:proj")
+            # q, k and v are written head-major by the passes that split and turn them (the compiler
+            # folds the turn of the axes into them); the output comes out [B, T, n * v] as W_o reads it
+            y = flash_attention_rows(q, k, v, n, n, True, sm_scale)          # None: 1 / sqrt(nope + rope)
+            y = checkpoint_name(y, "ds_dot:proj")
             return _dot(y, ap["wo"]).astype(x.dtype)
 
     def dense_mlp(self, x, mp):

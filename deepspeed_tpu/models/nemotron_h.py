@@ -261,17 +261,14 @@ class NemotronHModel:
 
     def attention(self, x, mp):
         """The position-free grouped-query attention on the normed layer input ``x [B, T, H]``."""
-        from ..ops.pallas.flash_attention import flash_attention
+        from ..ops.pallas.flash_attention import flash_attention_rows
         c = self.config
-        B, T, _ = x.shape
-        nq, nkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-        heads = lambda a: a.transpose(0, 2, 1, 3)      # noqa: E731
+        nq, nkv = c.num_attention_heads, c.num_key_value_heads
         x = checkpoint_name(x, "ds_dot:qkv")
-        q = _dot(x, mp["wq"]).astype(x.dtype).reshape(B, T, nq, D)
+        q = _dot(x, mp["wq"]).astype(x.dtype)
         x = checkpoint_name(x, "ds_dot:qkv")
-        k, v = jnp.split(_dot(x, mp["wkv"]).astype(x.dtype).reshape(B, T, 2 * nkv, D), 2, axis=2)
-        y = flash_attention(heads(q), heads(k), heads(v), True)
-        y = checkpoint_name(heads(y).reshape(B, T, nq * D), "ds_dot:proj")
+        k, v = jnp.split(_dot(x, mp["wkv"]).astype(x.dtype), 2, axis=-1)     # nkv heads each, side by side
+        y = checkpoint_name(flash_attention_rows(q, k, v, nq, nkv, True), "ds_dot:proj")
         return _dot(y, mp["wo"]).astype(x.dtype)
 
     def expert_layer(self, x, lp, details=False):

@@ -142,14 +142,15 @@ class OuroModel:
         return rms_norm(x, w, self.config.rms_norm_eps)
 
     def attention(self, x, lp, positions):
-        from ..ops.pallas.flash_attention import flash_attention
+        from ..ops.pallas.flash_attention import flash_attention_rows
         c = self.config
         B, T, _ = x.shape
         heads = lambda a: a.reshape(B, T, c.num_attention_heads, c.head_dim).transpose(0, 2, 1, 3)   # noqa: E731
-        q, k, v = (heads(_dot(x, lp[name])) for name in ("wq", "wk", "wv"))
-        q, k = rope(q, positions, c.rope_theta), rope(k, positions, c.rope_theta)
-        y = flash_attention(q, k, v, True)
-        return _dot(y.transpose(0, 2, 1, 3).reshape(B, T, -1), lp["wo"])
+        # q and k are written head-major by the pass that turns them (the compiler folds the turn of the
+        # axes into it); v goes in, and the output comes out, where the projections write and read them
+        q, k = (rope(heads(_dot(x, lp[name])), positions, c.rope_theta) for name in ("wq", "wk"))
+        n = c.num_attention_heads
+        return _dot(flash_attention_rows(q, k, _dot(x, lp["wv"]), n, n, True), lp["wo"])
 
     def mlp(self, x, lp):
         hidden = jax.nn.silu(_dot(x, lp["w_gate"]).astype(jnp.float32)) * _dot(x, lp["w_up"])

@@ -175,7 +175,7 @@ class Qwen3NextModel:
 
     def full_attention(self, x, mp, positions):
         """The gated grouped-query attention on the normed block input ``x [B, T, H]``."""
-        from ..ops.pallas.flash_attention import flash_attention
+        from ..ops.pallas.flash_attention import flash_attention_rows
         c = self.config
         B, T, _ = x.shape
         nq, nkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -187,8 +187,8 @@ class Qwen3NextModel:
         heads = lambda a: a.transpose(0, 2, 1, 3)      # noqa: E731
         width = int(D * c.partial_rotary_factor)
         q, k = (rope(heads(a), positions, c.rope_theta, width) for a in (q, k))
-        y = checkpoint_name(flash_attention(q, k, heads(v), True), "attn_out")
-        y = heads(y).reshape(B, T, nq * D)
+        # q and k head-major from the rotary pass, v and the output as the projections write and read them
+        y = checkpoint_name(flash_attention_rows(q, k, v.reshape(B, T, nkv * D), nq, nkv, True), "attn_out")
         y = y * jax.nn.sigmoid(gate.reshape(B, T, nq * D).astype(jnp.float32)).astype(y.dtype)
         y = checkpoint_name(y, "ds_dot:proj")
         return _dot(y, mp["wo"]).astype(x.dtype)

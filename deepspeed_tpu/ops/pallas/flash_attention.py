@@ -30,6 +30,20 @@ Design (v5e; measured rows in ``_resolve`` and PERF.md, PR 25):
   ``v``, ``out``, ``dO`` and ``dV`` are ``v.shape[-1]`` wide, ``q``, ``k``, ``dQ`` and ``dK``
   ``q.shape[-1]``; the width is a static property of the shapes, and a call at equal widths is the
   program it was before the kernels took two.
+- layout: ``flash_attention`` takes and gives ``[B, H, T, D]``. ``flash_attention_rows`` gives the
+  output ``[B, T, H * Dv]`` as the output projection reads it (and reads its cotangent so), and
+  takes each of q, k and v where its producer leaves it: ``[B, T, heads * width]`` from a
+  projection, or head-major from a pass the compiler folds the turn of the axes into. A head's
+  ``[rows, width]`` tile is lane block ``h`` of a row-major operand wherever ``width`` is a multiple
+  of the 128 lanes, so the kernel bodies, tiles and names are the same and only the index maps
+  differ (``lanes``: ``_head_spec``; one ``pallas_call`` a kernel serves both layouts).
+  ``layout_of`` chooses by what the call shows, its widths and its band; any other call is turned
+  head-major by the entry (width 64, two heads a lane block, among them: a kernel that computes
+  both in one grid cell ran 3.4 % faster end to end at GPT-2 XL's 25 heads and took 2.3 times as
+  long to compile, a layer; the band's backward ran 6.9 % slower on row-major operands: PERF.md,
+  PR 60). ``delta`` is a kernel of its own there (``ds_flash_delta``), and a group's dK and dV
+  are summed as slices of the last axis: the chip's compiler lays a ``[B, T, heads, width]`` view
+  of such an array out head-major and copies.
 - ``interpret=True`` fallback keeps CPU tests honest; a dense reference implementation
   (``dense_attention``) is the numerics oracle.
 """
@@ -386,13 +400,14 @@ def _aux_operands(seed, bias, B, H, T, rate, block_k_map=None):
     return operands, specs
 
 
-def _per_shard(kernel_fn, arrays, seed, bias, rate):
+def _per_shard(kernel_fn, arrays, seed, bias, rate, dims=None, out_dims="bh", heads=None):
     """``kernel_fn(*arrays, seed, bias)`` on each shard of the context mesh
-    (partition.py): ``arrays`` are [B, H, ...], ``bias`` [B, 1, T], ``seed`` the
+    (partition.py): ``arrays`` are [B, H, ...] (or as ``dims`` says, the results as
+    ``out_dims``, the heads cut in ``heads`` pieces at most), ``bias`` [B, 1, T], ``seed`` the
     packed dropout operand. The dropout hash counts (batch, head) from a shard's
     own first row, so every shard but the first folds its index into the seed:
     shards then draw different masks, and one device draws the reference's."""
-    operands, dims = list(arrays), ["bh"] * len(arrays)
+    operands, dims = list(arrays), list(dims or ["bh"] * len(arrays))
     if seed is not None:
         operands.append(jnp.asarray(seed, jnp.int32))
         dims.append("")
@@ -408,7 +423,7 @@ def _per_shard(kernel_fn, arrays, seed, bias, rate):
             s = s.at[0].add(shard.astype(jnp.int32) * jnp.int32(-1640531527))
         return kernel_fn(*ops, s, b)
 
-    return shard_over_mesh(local, operands, dims)
+    return shard_over_mesh(local, operands, dims, out_dims, heads)
 
 
 # ---------------------------------------------------------------------------
@@ -459,23 +474,57 @@ def _flash_fwd(q, k, v, seed, bias, sm_scale, causal, rate, block_q, block_k, in
         (q, k, v), seed, bias, rate)
 
 
-def _kv_head(group):
-    """The forward's index map of K and V: query row ``b`` of ``[B * H]`` reads the
-    key/value head of its group (itself where every head has its own)."""
-    if group == 1:
-        return lambda b, i: (b, 0, 0)
-    return lambda b, i: (b // group, 0, 0)
+LANES = 128
+
+
+def _is_rows(a):
+    """Whether an operand lies as its projection wrote it, [B, T, heads * width] (else [B, heads, T, width])."""
+    return a.ndim == 3
+
+
+def _rows_dims(q, k, v, widths):
+    """(B, T, H, Hkv, group) of operands ``widths = (D, Dv)`` wide a head, each row-major or
+    head-major: what a shard holds, read off its own operands."""
+    count = lambda a, width: a.shape[-1] // width if _is_rows(a) else a.shape[1]      # noqa: E731
+    B, T = q.shape[0], q.shape[1 if _is_rows(q) else 2]
+    H, Hkv = count(q, widths[0]), count(k, widths[0])
+    return B, T, H, Hkv, H // Hkv
+
+
+def _head_spec(like, rows, width, H, group, tiled):
+    """The BlockSpec of an operand or a result laid out as ``like``: ``rows`` of head
+    ``b % H // group`` of batch row ``b // H`` (``group`` 1: a query head's own), the grid's
+    second index counting them where ``tiled``. That is row ``b // group`` of ``[B * heads, T,
+    width]`` (``_flat``), or lane block ``head`` of ``[B, T, heads * width]``."""
+    at = (lambda j: j) if tiled else (lambda j: 0)
+    if not _is_rows(like):
+        head = (lambda b: b) if group == 1 else (lambda b: b // group)
+        return pl.BlockSpec((None, rows, width), lambda b, j: (head(b), at(j), 0))
+    # lax.div / lax.rem, not ``//`` / ``%``: the grid's indices are never negative, and the flooring
+    # forms trace and lower a sign and a select each, in every index map of every call
+    div, rem = jax.lax.div, jax.lax.rem
+    return pl.BlockSpec((None, rows, width), lambda b, j: (div(b, H), at(j), div(rem(b, H), group)))
+
+
+def _flat(a):
+    """An operand as a kernel takes it: row-major as it is, head-major with batch and heads merged."""
+    return a if _is_rows(a) else a.reshape(-1, *a.shape[2:])
+
+
+def _flat_shape(like, B, T, H, width):
+    """The ``_flat`` shape of a result laid out as ``like`` at ``H`` heads of ``width``."""
+    return jax.ShapeDtypeStruct((B, T, H * width) if _is_rows(like) else (B * H, T, width), like.dtype)
 
 
 def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, block_k,
-                     interpret, window=None):
-    B, H, T, D = q.shape
-    Dv = v.shape[-1]                 # the values' width, the output's too
-    group = H // k.shape[1]          # query heads a key/value head serves, side by side
-    grid = (B * H, pl.cdiv(T, block_q))
-    q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H // group, T, D)
-    v3 = v.reshape(B * H // group, T, Dv)
+                     interpret, window=None, widths=None):
+    """The forward kernel on one shard. ``widths`` None: ``[B, heads, T, width]`` operands, and the
+    output so. ``widths = (D, Dv)`` (``flash_attention_rows``): each operand where it lies
+    (``_is_rows``) and the output ``[B, T, H * Dv]``: the same kernel, a head's tiles found as lane
+    blocks (``_head_spec``). ``lse`` is ``[B, H, T]`` either way."""
+    D, Dv = widths or (q.shape[-1], v.shape[-1])       # q and k | v and the output
+    B, T, H, _, group = _rows_dims(q, k, v, (D, Dv))   # group: query heads a key/value head serves, side by side
+    out_like = jax.ShapeDtypeStruct((B, T, H * Dv) if widths else (B, H, T, Dv), q.dtype)
 
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                                block_k=block_k, seq_len=T, has_bias=bias is not None,
@@ -485,18 +534,18 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
     aux, aux_specs = _aux_operands(seed, bias, B, H, T, rate)
     call = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B * H, pl.cdiv(T, block_q)),
         in_specs=aux_specs + [
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, T, D), _kv_head(group)),
-            pl.BlockSpec((None, T, Dv), _kv_head(group)),
+            _head_spec(q, block_q, D, H, 1, True),
+            _head_spec(k, T, D, H, group, False),
+            _head_spec(v, T, Dv, H, group, False),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
+            _head_spec(out_like, block_q, Dv, H, 1, True),
             pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
+            _flat_shape(out_like, B, T, H, Dv),
             # LSE carried as [B*H, 1, T]: TPU block shapes need the trailing two dims
             # tileable, so the per-row scalar rides in a (1, block_q) lane layout
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
@@ -509,8 +558,8 @@ def _flash_fwd_local(q, k, v, seed, bias, *, sm_scale, causal, rate, block_q, bl
         name="ds_flash_fwd",
     )
     with jax.named_scope("ds_flash_fwd"):
-        out, lse = call(*aux, q3, k3, v3)
-    return out.reshape(B, H, T, Dv), lse.reshape(B, H, T)
+        out, lse = call(*aux, _flat(q), _flat(k), _flat(v))
+    return out.reshape(out_like.shape), lse.reshape(B, H, T)
 
 
 # ---------------------------------------------------------------------------
@@ -630,28 +679,62 @@ def _flash_bwd(res, g, seed, bias, sm_scale, causal, rate, block_q, block_k, int
         (q, k, v, do, lse, delta), seed, bias, rate)
 
 
+def _sum_lane_blocks(a, group, width):
+    """``[B, T, n * group * width] -> [B, T, n * width]``: the sum, in float32, of each ``group``
+    neighbouring blocks of ``width`` lanes, as static slices of the last axis (a reduction over
+    ``[B, T, n, group, width]`` makes the chip's compiler lay the whole array out anew)."""
+    block = lambda i: a[..., i * width:(i + 1) * width].astype(jnp.float32)      # noqa: E731
+    return jnp.concatenate([sum(block(c * group + g) for g in range(group)).astype(a.dtype)
+                            for c in range(a.shape[-1] // (group * width))], axis=-1)
+
+
+def _delta_kernel(o_ref, do_ref, delta_ref):
+    """``delta`` of one head's rows: the sum of ``o * dO`` over its lanes, as the [1, rows] row the
+    backward reads (the product turned once, summed down the sublanes)."""
+    turned = (o_ref[...].astype(jnp.float32) * do_ref[...].astype(jnp.float32)).T
+    delta_ref[...] = jnp.sum(turned, axis=0, keepdims=True)
+
+
+_DELTA_ROWS = 512      # of a head, a grid cell of ``_delta_rows``
+
+
+def _delta_rows(out, do, width, interpret):
+    """``rowsum(do * o)`` a head (``_flash_bwd``'s ``delta``) from the output and its cotangent as
+    they lie, ``[B, T, H * width]``: ``[B, H, T]`` float32. A kernel of its own: a reduction over
+    ``[B, T, H, width]`` makes the chip's compiler lay both arrays out anew."""
+    B, T, lanes = out.shape
+    H = lanes // width
+    rows = math.gcd(_DELTA_ROWS, T)
+    tile = pl.BlockSpec((None, rows, width), lambda b, i: (jax.lax.div(b, H), i, jax.lax.rem(b, H)))
+    with jax.named_scope("ds_flash_delta"):
+        return pl.pallas_call(
+            _delta_kernel,
+            grid=(B * H, T // rows),
+            in_specs=[tile, tile],
+            out_specs=pl.BlockSpec((None, 1, rows), lambda b, i: (b, 0, i)),
+            out_shape=jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+            name="ds_flash_delta",
+        )(out, do).reshape(B, H, T)
+
+
 def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, rate,
-                     block_q, block_k, interpret, window=None):
-    B, H, T, D = q.shape
-    Dv = v.shape[-1]
-    group = H // k.shape[1]
-    q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H // group, T, D)
-    v3 = v.reshape(B * H // group, T, Dv)
-    do3 = do.reshape(B * H, T, Dv)
-    lse3 = lse.reshape(B * H, 1, T)
-    delta3 = delta.reshape(B * H, 1, T)
+                     block_q, block_k, interpret, window=None, widths=None):
+    """The backward kernel on one shard; ``widths`` as ``_flash_fwd_local`` takes it. Under
+    ``widths`` ``do`` lies as the output went, ``[B, T, H * Dv]``, ``delta`` is that OUTPUT (its
+    row sums with ``do`` are made here, ``_delta_rows``), and dQ, dK and dV go out laid as q, k
+    and v came."""
+    D, Dv = widths or (q.shape[-1], v.shape[-1])
+    B, T, H, Hkv, group = _rows_dims(q, k, v, (D, Dv))
+    if widths:
+        delta = _delta_rows(delta, do, Dv, interpret)
 
     # the grid walks k-tiles, so the bias operand is tiled per k-tile
     aux, aux_specs = _aux_operands(
         seed, bias, B, H, T, rate,
         block_k_map=(block_k, lambda b, j, H=H: (b // H, 0, j)))
     row = pl.BlockSpec((None, 1, T), lambda b, j: (b, 0, 0))
-    # q, k, dq and dk are D wide; v, dO and dv as wide as the values
-    whole = lambda width: pl.BlockSpec((None, T, width), lambda b, j: (b, 0, 0))              # noqa: E731
-    tile = lambda width: pl.BlockSpec((None, block_k, width), lambda b, j: (b, j, 0))         # noqa: E731
-    kv_tile = tile if group == 1 else lambda width: pl.BlockSpec(                             # noqa: E731
-        (None, block_k, width), lambda b, j: (b // group, j, 0))
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, seq_len=T, has_bias=bias is not None,
@@ -659,9 +742,14 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
                           has_seed=seed is not None, seg=_is_segmented(seed),
                           window=window),
         grid=(B * H, T // block_k),
-        in_specs=aux_specs + [whole(D), kv_tile(D), kv_tile(Dv), whole(Dv), row, row],
-        out_specs=[whole(D), tile(D), tile(Dv)],
-        out_shape=[jax.ShapeDtypeStruct((B * H, T, width), q.dtype) for width in (D, D, Dv)],
+        # q, k, dq and dk are D wide; v, dO and dv as wide as the values
+        in_specs=aux_specs + [_head_spec(q, T, D, H, 1, False), _head_spec(k, block_k, D, H, group, True),
+                              _head_spec(v, block_k, Dv, H, group, True), _head_spec(do, T, Dv, H, 1, False),
+                              row, row],
+        # a query head's dK and dV each: laid out as k and v, at H heads
+        out_specs=[_head_spec(q, T, D, H, 1, False), _head_spec(k, block_k, D, H, 1, True),
+                   _head_spec(v, block_k, Dv, H, 1, True)],
+        out_shape=[_flat_shape(q, B, T, H, D), _flat_shape(k, B, T, H, D), _flat_shape(v, B, T, H, Dv)],
         scratch_shapes=[pltpu.VMEM((D, T), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -671,11 +759,13 @@ def _flash_bwd_local(q, k, v, do, lse, delta, seed, bias, *, sm_scale, causal, r
         name="ds_flash_bwd_dkv",
     )
     with jax.named_scope("ds_flash_bwd_dkv"):
-        dq, dk, dv = call(*aux, q3, k3, v3, do3, lse3, delta3)
+        dq, dk, dv = call(*aux, _flat(q), _flat(k), _flat(v), _flat(do), lse.reshape(B * H, 1, T),
+                          delta.reshape(B * H, 1, T))
         if group > 1:      # a query head's dK and dV each: summed over the group in float32
-            dk, dv = (jnp.sum(a.reshape(B, H // group, group, T, a.shape[-1]).astype(jnp.float32),
-                              axis=2).astype(k.dtype) for a in (dk, dv))
-    return dq.reshape(B, H, T, D), dk.reshape(k.shape), dv.reshape(v.shape)
+            dk, dv = (_sum_lane_blocks(a, group, a.shape[-1] // H) if _is_rows(like) else
+                      jnp.sum(a.reshape(B, H // group, group, T, a.shape[-1]).astype(jnp.float32),
+                              axis=2).astype(k.dtype) for a, like in ((dk, k), (dv, v)))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -735,10 +825,23 @@ def _resolve(q, sm_scale, block_q, block_k, causal, interpret, window=None):
     return sm_scale, block_q, block_k, interpret
 
 
+def _count_call(way, layout, heads, widths, T):
+    """While a step program is traced every call leaves in the recorder which layout it took:
+    ``flash.<fwd|bwd>.<lanes|heads_major>[<program>] <H>/<Hkv>x<D>|<Dv> at <T>``, once a
+    trace of the call (``docs/telemetry.md``)."""
+    from ...utils import spans
+    spans.recorder().count_in_program(f"flash.{way}.{layout}", " %d/%dx%d|%d at %d" % (*heads, *widths, T))
+
+
+def _count_heads_major(way, q, k, v):
+    _count_call(way, "heads_major", (q.shape[1], k.shape[1]), (q.shape[-1], v.shape[-1]), q.shape[2])
+
+
 def _core_fwd_rule(q, k, v, bias, seed, causal, sm_scale, rate, block_q, block_k,
                    interpret, window=None):
     sm_scale_, bq, bk, interp = _resolve(q, sm_scale, block_q, block_k, causal,
                                          interpret, window)
+    _count_heads_major("fwd", q, k, v)
     assert q.shape[2] % bq == 0 and q.shape[2] % bk == 0, \
         f"seq_len {q.shape[2]} must be divisible by block sizes ({bq}, {bk})"
     out, lse = _flash_fwd(q, k, v, seed, bias, sm_scale_, causal, rate, bq, bk, interp,
@@ -760,6 +863,7 @@ def _core_bwd_rule(causal, sm_scale, rate, block_q, block_k, interpret, window, 
     q, k, v, out, lse, bias, seed = res
     sm_scale_, bq, bk, interp = _resolve(q, sm_scale, block_q, block_k, causal,
                                          interpret, window)
+    _count_heads_major("bwd", q, k, v)
     dq, dk, dv = _flash_bwd((q, k, v, out, lse), g, seed, bias, sm_scale_, causal, rate,
                             bq, bk, interp, window=window)
     # bias is the (non-trainable) padding mask: cotangent is zero by contract; seed is
@@ -792,6 +896,7 @@ def _core_lse_bwd(causal, sm_scale, rate, block_q, block_k, interpret, res, g):
     q, k, v, out, lse, bias, seed = res
     sm_scale_, bq, bk, interp = _resolve(q, sm_scale, block_q, block_k, causal,
                                          interpret)
+    _count_heads_major("bwd", q, k, v)
     dq, dk, dv = _flash_bwd((q, k, v, out, lse), g_out, seed, bias, sm_scale_, causal,
                             rate, bq, bk, interp, g_lse=g_lse)
     dbias = None if bias is None else jnp.zeros_like(bias)
@@ -1009,3 +1114,115 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: Optional[float] = N
         bias = jax.lax.stop_gradient(jnp.asarray(bias, jnp.float32).reshape(B, 1, T_k))
     return _flash_attention_core(q, k, v, bias, seed, bool(causal), sm_scale, rate,
                                  block_q, block_k, interpret, window)
+
+
+# ---------------------------------------------------------------------------
+# the output where the output projection reads it, each operand where its producer leaves it
+# ---------------------------------------------------------------------------
+# A projection writes, and reads, [B, T, H * D]. Head ``h``'s [rows, D] tile is lane block ``h``
+# of that array wherever D is a multiple of the 128 lanes, so the kernels above read and write it
+# through their index maps alone (``lanes``, ``_head_spec``). Any other call is turned head-major by
+# the entry. An operand may still come head-major, [B, heads, T, width] (``_is_rows``): a rotary turn
+# or a norm a head between a projection and the kernel is a pass that writes that layout for nothing,
+# and the chip's compiler lays a [B, T, heads, width] result out head-major whatever the program says.
+
+def layout_of(D, Dv, window=None):
+    """Which way a call runs: ``lanes`` (a head is a lane block of a row-major array) or
+    ``heads_major``. What the call itself shows decides: its widths, and whether it is banded. The
+    band's backward reads whole strided blocks for a few tiles' worth of work, and measured 6.9 %
+    slower a call on row-major operands (``mellum2_ep4_d4_train_1chip`` -0.33 %: PERF.md, PR 60)."""
+    return "lanes" if D % LANES == 0 and Dv % LANES == 0 and window is None else "heads_major"
+
+
+def _turned_shape(a, heads):
+    """``(B, heads, T, width)`` of an operand that holds ``heads`` heads, whichever way it lies."""
+    return (a.shape[0], heads, a.shape[1], a.shape[2] // heads) if _is_rows(a) else a.shape
+
+
+def _rows_plan(q, k, v, heads, sm_scale, block_q, block_k, causal, interpret):
+    """``(widths, T, pieces, tiles...)`` of a call whose operands each lie row-major or head-major:
+    ``_resolve``'s tiles at the head-major shape, and the most pieces the heads may be cut in over
+    a mesh."""
+    H, Hkv = heads
+    like = jax.ShapeDtypeStruct(_turned_shape(q, H), q.dtype)
+    T, widths = like.shape[2], (like.shape[3], _turned_shape(v, Hkv)[3])
+    return (widths, T, math.gcd(H, Hkv)) + _resolve(like, sm_scale, block_q, block_k, causal, interpret)
+
+
+def _dims(*arrays):
+    return ["bth" if _is_rows(a) else "bh" for a in arrays]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_rows_core(q, k, v, seed, heads, causal, sm_scale, rate, block_q, block_k, interpret):
+    return _rows_fwd_rule(q, k, v, seed, heads, causal, sm_scale, rate, block_q, block_k, interpret)[0]
+
+
+def _rows_fwd_rule(q, k, v, seed, heads, causal, sm_scale, rate, block_q, block_k, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+    widths, T, pieces, sm_scale_, bq, bk, interp = _rows_plan(
+        q, k, v, heads, sm_scale, block_q, block_k, causal, interpret)
+    assert T % bq == 0 and T % bk == 0, f"seq_len {T} must be divisible by block sizes ({bq}, {bk})"
+    _count_call("fwd", "lanes", heads, widths, T)
+    out, lse = _per_shard(
+        functools.partial(_flash_fwd_local, widths=widths, sm_scale=sm_scale_, causal=causal,
+                          rate=rate, block_q=bq, block_k=bk, interpret=interp),
+        (q, k, v), seed, None, rate, dims=_dims(q, k, v), out_dims=("bth", "bh"), heads=pieces)
+    # the names ``_core_fwd_rule`` gives, for the same reason: what a jax.checkpoint keeps is
+    # the residual and the primal at once
+    out = checkpoint_name(out, "attn_out")
+    return out, (q, k, v, out, checkpoint_name(lse, "attn_lse"), seed)
+
+
+def _rows_bwd_rule(heads, causal, sm_scale, rate, block_q, block_k, interpret, res, g):
+    q, k, v, out, lse, seed = res
+    widths, T, pieces, sm_scale_, bq, bk, interp = _rows_plan(
+        q, k, v, heads, sm_scale, block_q, block_k, causal, interpret)
+    _count_call("bwd", "lanes", heads, widths, T)
+    dq, dk, dv = _per_shard(
+        functools.partial(_flash_bwd_local, widths=widths, sm_scale=sm_scale_, causal=causal,
+                          rate=rate, block_q=bq, block_k=bk, interpret=interp),
+        (q, k, v, g, lse, out), seed, None, rate,
+        dims=_dims(q, k, v, g) + ["bh", "bth"], out_dims=tuple(_dims(q, k, v)), heads=pieces)
+    dseed = None if seed is None else np.zeros(np.shape(seed), jax.dtypes.float0)
+    return dq, dk, dv, dseed
+
+
+_flash_rows_core.defvjp(_rows_fwd_rule, _rows_bwd_rule)
+
+
+def flash_attention_rows(q, k, v, num_heads: int, num_kv_heads: Optional[int] = None,
+                         causal: bool = False, sm_scale: Optional[float] = None,
+                         block_q: Optional[int] = None, block_k: Optional[int] = None,
+                         interpret: Optional[bool] = None, dropout_rate: float = 0.0,
+                         dropout_seed=None, window: Optional[int] = None):
+    """``flash_attention`` giving its output as the output projection reads it, ``[B, T, H * Dv]``,
+    on operands where their producers leave them: each of ``q``, ``k`` and ``v`` is either what a
+    projection wrote, ``[B, T, heads * width]`` (three axes), or head-major ``[B, heads, T,
+    width]`` (four: where a rotary turn or a norm a head sits between the projection and the kernel
+    the compiler folds the turn of the axes into that pass, and writes head-major for nothing);
+    ``num_kv_heads`` None: every head its own. The cotangents come back as each primal lay, the
+    output's is read ``[B, T, H * Dv]``. No head is turned to the front where the shapes let the
+    kernels find it in place (``layout_of``): at widths that are multiples of 128 a head is a lane
+    block of a row-major operand. Any other width, a banded call (``window``) and a sequence past the
+    resident kernel's ``_RESIDENT_T_LIMIT`` are turned head-major here and go through ``flash_attention``
+    as it is. ``dropout_*`` and ``window`` as there: a seed draws the mask ``flash_attention`` draws for it."""
+    H = int(num_heads)
+    Hkv = H if num_kv_heads is None else int(num_kv_heads)
+    assert H % Hkv == 0, f"{H} query heads over {Hkv} key/value heads"
+    split = lambda a, n: a.reshape(*a.shape[:2], n, -1).transpose(0, 2, 1, 3) if _is_rows(a) else a      # noqa: E731
+    B, _, T, D = _turned_shape(q, H)
+    Dv = _turned_shape(v, Hkv)[-1]
+    assert _turned_shape(k, Hkv) == (B, Hkv, T, D) and _turned_shape(v, Hkv)[:3] == (B, Hkv, T), \
+        f"{q.shape} / {k.shape} / {v.shape} do not hold {H} over {Hkv} heads at one length and width"
+    rate = float(dropout_rate)
+    if rate > 0:
+        assert dropout_seed is not None, "dropout_rate > 0 requires a dropout_seed"
+    if layout_of(D, Dv, window) == "heads_major" or T > _RESIDENT_T_LIMIT:
+        y = flash_attention(split(q, H), split(k, Hkv), split(v, Hkv), causal, sm_scale, block_q,
+                            block_k, interpret, dropout_rate=rate, dropout_seed=dropout_seed,
+                            window=window)
+        return y.transpose(0, 2, 1, 3).reshape(B, T, H * Dv)
+    seed = _seed_vec(dropout_seed, 0, 0) if rate > 0 else None
+    return _flash_rows_core(q, k, v, seed, (H, Hkv), bool(causal), sm_scale, rate, block_q,
+                            block_k, interpret)

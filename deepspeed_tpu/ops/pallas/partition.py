@@ -21,14 +21,17 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def shard_over_mesh(fn, operands, dims):
+def shard_over_mesh(fn, operands, dims, out_dims="bh", heads=None):
     """``fn(*operands)`` with every operand split over the context mesh.
 
-    ``dims[i]`` says which leading dimensions operand ``i`` has: ``"bh"`` (batch,
-    heads, ...; the first operand is one), ``"b"`` (batch, ...) or ``""``
-    (neither: replicated). ``fn`` returns an array or a tuple of arrays, all
-    ``"bh"``. It is also handed the index of its shard among the batch-and-head
-    shards (0 when the call is direct)."""
+    ``dims[i]`` says which dimensions operand ``i`` has: ``"bh"`` (batch, heads, ...),
+    ``"bth"`` (batch, positions, the heads side by side in the last axis: the layout a
+    projection writes), ``"b"`` (batch, ...) or ``""`` (neither: replicated); the first
+    operand has a batch. ``fn`` returns an array or a tuple of arrays, laid out as
+    ``out_dims`` says (one word for all, or one each). ``heads`` is the number of pieces
+    the heads may be cut in at most (a caller of ``"bth"`` operands gives it: a width does
+    not say how many heads it holds); None: what the ``"bh"`` operands share. ``fn`` is also
+    handed the index of its shard among the batch-and-head shards (0 when the call is direct)."""
     from ...parallel.mesh import DATA_AXIS, MODEL_AXIS  # parallel/ imports this package
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or all(mesh.shape[a] == 1 for a in mesh.auto_axes):
@@ -41,9 +44,11 @@ def shard_over_mesh(fn, operands, dims):
     # K and V may have fewer heads than the queries they serve (a group of query heads a
     # key/value head): the heads are split only as finely as every such operand allows
     batch = operands[0].shape[0]
-    heads = math.gcd(*(o.shape[1] for o, d in zip(operands, dims) if d == "bh"))
+    if heads is None:
+        heads = math.gcd(*(o.shape[1] for o, d in zip(operands, dims) if d == "bh"))
     b_axis, h_axis = axis_for(DATA_AXIS, batch), axis_for(MODEL_AXIS, heads)
-    spec = {"bh": P(b_axis, h_axis), "b": P(b_axis), "": P()}
+    spec = {"bh": P(b_axis, h_axis), "bth": P(b_axis, None, h_axis), "b": P(b_axis), "": P()}
+    out_specs = spec[out_dims] if isinstance(out_dims, str) else tuple(spec[d] for d in out_dims)
 
     def body(*local):
         index = 0
@@ -52,5 +57,5 @@ def shard_over_mesh(fn, operands, dims):
                 index = index * mesh.shape[axis] + jax.lax.axis_index(axis)
         return fn(index, *local)
 
-    return jax.shard_map(body, in_specs=tuple(spec[d] for d in dims), out_specs=spec["bh"],
+    return jax.shard_map(body, in_specs=tuple(spec[d] for d in dims), out_specs=out_specs,
                          axis_names=frozenset(mesh.auto_axes), check_vma=False)(*operands)

@@ -1,7 +1,7 @@
 """Flash-attention tile sweep on the chip: device milliseconds a call of each kernel,
 forward and backward apart, read from a profiler trace by the kernels' own names.
 
-    python tests/perf/flash_sweep.py [--rows cell,long,other,band,mla,latent] [--picked] [--out chiprun_out/flash_sweep.jsonl]
+    python tests/perf/flash_sweep.py [--rows cell,long,other,band,mla,latent,layout] [--picked] [--out chiprun_out/flash_sweep.jsonl]
 
 Run it from the root of a checkout; from the root of another checkout (a parent
 unpacked beside this one) it measures that tree's kernels with the same rows:
@@ -15,6 +15,14 @@ operations (4.B.H.T^2.D a forward, half of it causal; twice that a backward, as
 ``(key/value heads, window, value width)``: grouped heads, a sliding window, whose required
 operations are the pairs inside the band (``band_pairs``'s ``needed``), and values of another
 width than the keys' (the required operations are then 2.B.H.T^2.(D + Dv) a forward).
+
+``--rows layout`` is another table: at the ten cells' attention shapes, one attention layer with
+nothing between its projections and the kernel (``x W_q``, ``x W_k``, ``x W_v``, the kernel,
+``W_o``; value and every gradient), the operands turned head-major round ``flash_attention`` as
+the models did before PR 60 against ``flash_attention_rows`` on them as they lie: device ms a
+call of the WHOLE program, of its ``ds_flash_*`` kernels by name, and of the rest (the four
+projections and their gradients, the same products both ways, and the copies). On a tree without
+``flash_attention_rows`` the second line is absent.
 """
 
 import argparse
@@ -63,8 +71,17 @@ ROWS = {
 }
 
 
-def kernel_ms(fn, args, calls=8):
-    """{kernel name: device ms a call} for the Pallas kernels ``fn(*args)`` runs."""
+# the ten cells' attention calls: (cells, B, T, query heads, key/value heads, D, Dv, window)
+LAYOUT_ROWS = [("glm47flash", 1, 8192, 20, 20, 256, 256, None), ("qwen3next", 1, 8192, 16, 2, 256, 256, None),
+               ("mellum2 window", 1, 8192, 32, 4, 128, 128, 1024), ("mellum2 full", 1, 8192, 32, 4, 128, 128, None),
+               ("nemotronh", 1, 8192, 32, 2, 128, 128, None), ("ouro olmoe", 2, 4096, 16, 16, 128, 128, None),
+               ("xl_d20", 4, 1024, 25, 25, 64, 64, None), ("lfm2 granite4h", 1, 8192, 32, 8, 64, 64, None),
+               ("xing4", 1, 4096, 32, 32, 192, 128, None)]
+
+
+def device_events(fn, args, calls):
+    """``[(name, ns)]`` of every operation the device runs in ``calls`` calls of ``jit(fn)(*args)``
+    after a first, from a profiler trace."""
     from jax.profiler import ProfileData
     f = jax.jit(fn)
     jax.block_until_ready(f(*args))
@@ -77,26 +94,73 @@ def kernel_ms(fn, args, calls=8):
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
         path = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
-        seconds, calls_seen, others = (collections.Counter() for _ in range(3))
-        for plane in ProfileData.from_file(path).planes:
-            if not plane.name.startswith("/device:TPU:"):
-                continue
-            for line in plane.lines:
-                if line.name != "XLA Ops":
-                    continue
-                for e in line.events:
-                    m = re.search(r"ds_flash_\w+?(?=\.\d+|$|[^\w])", e.name)
-                    if m:
-                        seconds[m.group(0)] += e.duration_ns * 1e-9
-                        calls_seen[m.group(0)] += 1
-                    else:
-                        others[e.name] += 1
-        if not seconds or any(n % calls for n in calls_seen.values()):
-            raise RuntimeError(
-                f"the trace names no ds_flash_* kernel {calls} times over: {dict(calls_seen)}; "
-                f"it holds {others.most_common(6)}")
+        return [(e.name, e.duration_ns) for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/device:TPU:")
+                for line in plane.lines if line.name == "XLA Ops" for e in line.events]
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+KERNEL = re.compile(r"ds_flash_\w+?(?=\.\d+|$|[^\w])")
+
+
+def program_ms(fn, args, calls=6):
+    """``(whole, {kernel name: ms})``: device ms a call of everything ``fn(*args)`` runs, and of its
+    ``ds_flash_*`` kernels."""
+    whole, kernels = 0.0, collections.Counter()
+    for name, ns in device_events(fn, args, calls):
+        whole += ns
+        m = KERNEL.search(name)
+        if m:
+            kernels[m.group(0)] += ns
+    per = 1e-6 / calls
+    return whole * per, {name: ns * per for name, ns in kernels.items()}
+
+
+def sweep_layout(emit, hidden=1024):
+    """One attention layer at each of ``LAYOUT_ROWS``, head-major with its transposes and row-major."""
+    rng = np.random.default_rng(0)
+    for cells, B, T, H, Hkv, D, Dv, window in LAYOUT_ROWS:
+        def layer(rows):
+            def attend(x, wq, wk, wv, wo):
+                q, k, v = (jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype) for w in (wq, wk, wv))
+                if rows:
+                    y = fa.flash_attention_rows(q, k, v, H, Hkv, True, window=window)
+                else:
+                    heads = lambda a, n: a.reshape(B, T, n, -1).transpose(0, 2, 1, 3)      # noqa: E731
+                    y = fa.flash_attention(heads(q, H), heads(k, Hkv), heads(v, Hkv), True, window=window)
+                    y = y.transpose(0, 2, 1, 3).reshape(B, T, H * Dv)
+                return jnp.sum(jnp.dot(y, wo, preferred_element_type=jnp.float32) ** 2)
+            return jax.grad(attend, argnums=(0, 1, 2, 3, 4))
+        widths = ((hidden, H * D), (hidden, Hkv * D), (hidden, Hkv * Dv), (H * Dv, hidden))
+        args = [jnp.asarray(rng.normal(size=(B, T, hidden)), jnp.bfloat16)] + [
+            jnp.asarray(rng.normal(size=w) * 0.03, jnp.bfloat16) for w in widths]
+        ways = [("heads_major", False)]
+        if hasattr(fa, "flash_attention_rows"):
+            ways.append((fa.layout_of(D, Dv, window), True))
+        for way, rows in ways:
+            common = dict(cells=cells, shape=[B, T, H, Hkv, D, Dv], window=window, way=way, rows=rows)
+            try:
+                whole, kernels = program_ms(layer(rows), args)
+                emit(dict(common, pass_="layout", ms=whole, kernels=kernels, rest_ms=whole - sum(kernels.values())))
+            except Exception as e:
+                emit(dict(common, pass_="layout_error", error=f"{type(e).__name__}: {str(e)[:300]}"))
+
+
+def kernel_ms(fn, args, calls=8):
+    """{kernel name: device ms a call} for the Pallas kernels ``fn(*args)`` runs."""
+    seconds, calls_seen, others = (collections.Counter() for _ in range(3))
+    for name, ns in device_events(fn, args, calls):
+        m = KERNEL.search(name)
+        if m:
+            seconds[m.group(0)] += ns * 1e-9
+            calls_seen[m.group(0)] += 1
+        else:
+            others[name] += 1
+    if not seconds or any(n % calls for n in calls_seen.values()):
+        raise RuntimeError(
+            f"the trace names no ds_flash_* kernel {calls} times over: {dict(calls_seen)}; "
+            f"it holds {others.most_common(6)}")
     return {name: 1e3 * s / calls for name, s in seconds.items()}
 
 
@@ -151,6 +215,11 @@ def main():
         def emit(rec):
             f.write(json.dumps(rec) + "\n")
             f.flush()
+            if rec["pass_"].startswith("layout"):
+                print(f"{rec['cells']:16s} {rec['shape']} w={rec['window']} {rec['way']:11s} "
+                      + (rec.get("error") or f"{rec['ms']:8.4f} ms a call, outside the kernels {rec['rest_ms']:7.4f} "
+                         + " ".join(f"{n}={t:.4f}" for n, t in sorted(rec["kernels"].items()))), flush=True)
+                return
             if rec["pass_"] == "error":
                 print(f"{rec['shape']} causal={int(rec['causal'])} bq={rec['block_q']} "
                       f"bk={rec['block_k']}: {rec['error']}", flush=True)
@@ -161,6 +230,9 @@ def main():
                   + " ".join(f"{n}={t:.4f}" for n, t in sorted(rec["kernels"].items())),
                   flush=True)
         for name in args.rows.split(","):
+            if name == "layout":
+                sweep_layout(emit)
+                continue
             for shape, causal, tiles, *more in ROWS[name]:
                 sweep_row(shape, causal, [None] if args.picked else tiles, emit,
                           **dict(zip(("kv_heads", "window", "v_width"), more)))

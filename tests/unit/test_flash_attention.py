@@ -376,3 +376,88 @@ def test_kernel_splits_itself_over_the_context_mesh(eight_devices):
     # the scalar loss is summed across shards; the kernel's tensors never move
     assert set(collective_counts(text)) <= {"all-reduce"}
     assert got[1][0].sharding.is_equivalent_to(args[0].sharding, 4)
+
+
+# the row-major entry at the cells' head shapes (query heads, key/value heads, D | Dv) and a toy
+# T of two tiles a side: lanes (GLM-4.7-Flash, Qwen3-Next, Nemotron-H, Ouro), and what the entry
+# turns head-major itself: Mellum 2's band, GPT-2 XL's 25 heads of 64, LFM2's and Granite's 32
+# over 8 of 64, Xing's 192 | 128. ``given`` says which of q, k and v arrive head-major
+# [B, heads, T, width] (a model's rotary pass writes them so) beside the others as projected
+_ROW_CASES = [(20, 20, 256, 256, None, "lanes", ""), (16, 2, 256, 256, None, "lanes", ""),
+              (32, 4, 128, 128, 96, "heads_major", ""), (16, 16, 128, 128, None, "lanes", ""),
+              (25, 25, 64, 64, None, "heads_major", ""), (32, 8, 64, 64, None, "heads_major", ""),
+              (32, 32, 192, 128, None, "heads_major", ""),
+              (20, 20, 256, 256, None, "lanes", "qkv"), (32, 4, 128, 128, 96, "heads_major", "qk"),
+              (16, 2, 256, 256, None, "lanes", "qk"), (32, 8, 64, 64, None, "heads_major", "qk"),
+              (32, 4, 128, 128, None, "lanes", "")]
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.25], ids=["plain", "dropout"])
+@pytest.mark.parametrize("H,Hkv,D,Dv,window,layout,given", _ROW_CASES,
+                         ids=[f"{c[0]}over{c[1]}x{c[2]}-{c[3]}{'-band' if c[4] else ''}{'-' + c[6] + '-head-major' if c[6] else ''}"
+                              for c in _ROW_CASES])
+def test_row_major_entry_matches_head_major(H, Hkv, D, Dv, window, layout, given, rate):
+    """Forward and all three gradients of ``flash_attention_rows`` against ``flash_attention`` on
+    the same values turned head-major; under dropout the same mask for a seed."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_rows, layout_of
+    assert layout_of(D, Dv, window) == layout
+    B, T = (2, 256) if H <= 20 else (1, 256)
+    keys = jax.random.split(jax.random.PRNGKey(H + D), 4)
+    q = jax.random.normal(keys[0], (B, T, H * D), jnp.float32)
+    k = jax.random.normal(keys[1], (B, T, Hkv * D), jnp.float32)
+    v = jax.random.normal(keys[2], (B, T, Hkv * Dv), jnp.float32)
+    g = jax.random.normal(keys[3], (B, T, H * Dv), jnp.float32)
+    kw = dict(block_q=128, block_k=128, interpret=True, dropout_rate=rate,
+              dropout_seed=jnp.int32(11) if rate else None, window=window)
+    heads = lambda a, n: a.reshape(B, T, n, -1).transpose(0, 2, 1, 3)      # noqa: E731
+
+    def rows(q, k, v):
+        q, k, v = (heads(a, n) if name in given else a for a, n, name in ((q, H, "q"), (k, Hkv, "k"), (v, Hkv, "v")))
+        y = flash_attention_rows(q, k, v, H, Hkv, True, **kw)
+        return jnp.sum(y * g), y
+
+    def head_major(q, k, v):
+        y = flash_attention(heads(q, H), heads(k, Hkv), heads(v, Hkv), True, **kw)
+        y = y.transpose(0, 2, 1, 3).reshape(B, T, H * Dv)
+        return jnp.sum(y * g), y
+
+    (_, y_r), grads_r = jax.jit(jax.value_and_grad(rows, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, y_h), grads_h = jax.jit(jax.value_and_grad(head_major, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert y_r.shape == (B, T, H * Dv) and np.isfinite(np.asarray(y_r)).all()
+    np.testing.assert_allclose(np.asarray(y_r), np.asarray(y_h), rtol=2e-5, atol=2e-5)
+    for a, b, name in zip(grads_r, grads_h, "qkv"):
+        assert a.shape == b.shape and np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("H,Hkv,D,given", [(4, 2, 128, "qk"), (4, 4, 128, "")], ids=["q-and-k-head-major", "all-row-major"])
+def test_the_row_major_kernels_split_themselves_over_the_context_mesh(eight_devices, H, Hkv, D, given):
+    """``flash_attention_rows`` under the engine's mesh (batch over ``data``, the heads over
+    ``model``: the lane blocks of a row-major operand's last axis): one device's values and gradients, each in its operand's layout and
+    sharding, and nothing left for XLA to partition."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_rows
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.utils.hlo import collective_counts
+
+    mesh = build_mesh(data=4, model=2, pipe=1)
+    B, T = 4, 128
+    shape = lambda n, name: (B, n, T, D) if name in given else (B, T, n * D)      # noqa: E731
+    spec = lambda name: P("data", "model") if name in given else P("data", None, "model")      # noqa: E731
+    q, k, v = (jax.random.normal(key, shape(n, name), jnp.float32)
+               for key, n, name in zip(jax.random.split(jax.random.PRNGKey(0), 3), (H, Hkv, Hkv), "qkv"))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_rows(q, k, v, H, Hkv, True, interpret=True) ** 2)
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    want = step(q, k, v)
+    args = tuple(jax.device_put(x, NamedSharding(mesh, spec(name))) for x, name in zip((q, k, v), "qkv"))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        got = step(*args)
+        text = step.lower(*args).compile().as_text()
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-4), got, want)
+    assert set(collective_counts(text)) <= {"all-reduce"}
+    for grad, arg in zip(got[1], args):
+        assert grad.sharding.is_equivalent_to(arg.sharding, grad.ndim)

@@ -23,7 +23,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from deepspeed_tpu.ops.pallas.block_sparse_attention import block_sparse_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, flash_attention_rows
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
 from deepspeed_tpu.ops.sparse_attention import BSLongformerSparsityConfig
 from deepspeed_tpu.utils import spans
@@ -113,6 +113,54 @@ def test_sliding_window_flash_attention_compiles_for_v5e(chip):
     assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text
 
 
+# the row-major entry's lanes branch: (B, T, query heads, key/value heads, width, which of q, k and v
+# arrive head-major). GLM-4.7-Flash's 20 heads of 256 with q from the pass that splits and turns it,
+# and with all three head-major as the cell calls it; 32 over 4 of 128 with q and k from a rotary
+# pass and v as projected (a group's dK summed head-major and its dV as lane blocks; no cell's call:
+# Mellum 2's 32 over 4 are banded, and a banded call is turned head-major by the entry,
+# ``test_sliding_window_flash_attention_compiles_for_v5e``); Nemotron-H's position-free 32 over 2
+# (all three as projected)
+ROW_MAJOR_CALLS = [(1, 8192, 20, 20, 256, "q"), (1, 8192, 20, 20, 256, "qkv"),
+                   (1, 8192, 32, 4, 128, "qk"), (1, 8192, 32, 2, 128, "")]
+
+
+@pytest.mark.parametrize("B,T,H,Hkv,D,head_major", ROW_MAJOR_CALLS,
+                         ids=[f"{c[2]}over{c[3]}x{c[4]}{'-' + c[5] if c[5] else ''}" for c in ROW_MAJOR_CALLS])
+def test_the_row_major_flash_kernels_compile_for_v5e(chip, B, T, H, Hkv, D, head_major):
+    """The lanes branch of ``flash_attention_rows`` at the cells' shapes, value and gradient in one
+    program (this file's cases stay few enough to go whole into one worker's first chunk:
+    ``tests/conftest.py``): the kernels under their names, the row sums of ``o * dO`` as a kernel of
+    their own, and no operand copied or turned on its way in or out."""
+    def attn(q, k, v):
+        return flash_attention_rows(q, k, v, H, Hkv, True, interpret=False)
+
+    shape = lambda n, name: (B, n, T, D) if name in head_major else (B, T, n * D)      # noqa: E731
+    q, k, v = (jax.ShapeDtypeStruct(shape(n, name), jnp.bfloat16, sharding=chip)
+               for n, name in ((H, "q"), (Hkv, "k"), (Hkv, "v")))
+    text = compiled_text(sumsq_grad(attn), q, k, v)
+    assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text and "ds_flash_delta" in text
+    assert f"bf16[{B},{T},{H * D}]{{2,1,0" in text             # the output, as a projection reads it
+    assert not re.search(r"= bf16\[\S* (copy|transpose)\(", text)
+
+
+def test_the_row_major_flash_kernels_compile_under_a_four_chip_mesh(topo):
+    """The row-major entry under the engine's mesh, at ``olmoe_d4_train_4chip``'s shapes (the cell itself
+    keeps the head-major call: PERF.md, PR 60): q and k head-major from a rotary pass, v as projected,
+    the batch over ``data``: the kernels split themselves over the mesh in both layouts at once."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1), ("pipe", "data", "model"))
+    rows = NamedSharding(mesh, P("data"))
+    qk = jax.ShapeDtypeStruct((8, 16, 4096, 128), jnp.bfloat16, sharding=rows)
+    v = jax.ShapeDtypeStruct((8, 4096, 16 * 128), jnp.bfloat16, sharding=rows)
+
+    def attn(q, k, v):
+        return flash_attention_rows(q, k, v, 16, 16, True, interpret=False)
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = compiled_text(sumsq_grad(attn), qk, qk, v)
+    assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text and "ds_flash_delta" in text
+    assert "bf16[2,4096,2048]" in text and "bf16[32,4096,128]" in text        # one chip's rows, both ways
+
+
 @functools.lru_cache(maxsize=None)
 def latent_attention_block_text(chip):
     """One latent (MLA) mixer of ``glm47flash_ep8_d5_train_1chip`` at its published widths and 8,192
@@ -135,8 +183,15 @@ def test_a_latent_attention_block_compiles_for_v5e(chip):
     over 20 key/value heads of 192 + 64 | 256 (no grouped-query case compiles 20 key/value heads
     of 256)."""
     text = latent_attention_block_text(chip)
-    assert "ds_flash_fwd" in text and "ds_flash_bwd_dkv" in text and "ds_attn_latent" in text
-    assert re.search(r"bf16\[1,20,8192,256\]", text)
+    assert "ds_attn_latent" in text and re.search(r"bf16\[1,20,8192,256\]", text)
+    for kernel in ("ds_flash_fwd", "ds_flash_bwd_dkv"):
+        assert re.search(r'custom-call\(.*op_name="[^"]*ds_attn_latent[^"]*/%s/' % kernel, text), kernel
+    # the output leaves the kernel [1, 8192, 20 * 256] as W_o reads it, and its cotangent goes in as
+    # W_o's backward writes it: no copy turns either (PR 60; two of the four copies of
+    # bf16[1,8192,20,256] this program had). The two left lay the q projection's output out head-major
+    # for the pass that splits and turns it, and that pass's cotangent back
+    assert re.search(r"= \(bf16\[1,8192,5120\]\{2,1,0\S*, f32\[20,1,8192\]\S*\) custom-call\(", text)
+    assert len(re.findall(r"= bf16\[1,8192,20,256\]\S* copy\(", text)) == 2
 
 
 def test_every_product_and_fusion_of_a_v5e_program_is_priced(chip):
@@ -565,8 +620,11 @@ def test_a_looped_models_block_passes_keep_each_named_tensor_once_on_a_v5e(chip,
     assert len(forward_calls) == 6 and not any("rematted_computation" in line for line in forward_calls)
     forward_loop = next(line for line in lines if re.search(r"= \(.*\) while\(", line)
                         and "jvp()/while" in line and "transpose" not in line)
-    assert forward_loop.count("bf16[4,2,16,4096,128]") == 6 and forward_loop.count("f32[4,2,16,4096]") == 6
-    assert forward_loop.count("bf16[4,2,4096,2048]") == 6 * 2 + 1          # a block's input, w_down's output; the exits
+    # the kernel's output is [2, 4096, 16 * 128] as ``wo`` reads it since PR 60 (head-major before:
+    # six of ``bf16[4,2,16,4096,128]``), its row sums stay [2, 16, 4096]
+    assert "bf16[4,2,16,4096,128]" not in forward_loop and forward_loop.count("f32[4,2,16,4096]") == 6
+    # a block's input, w_down's output and the kernel's; the exits
+    assert forward_loop.count("bf16[4,2,4096,2048]") == 6 * 3 + 1
     assert compiled.memory_analysis().temp_size_in_bytes < 10.354e9 * 1.05
 
 
